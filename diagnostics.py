@@ -284,8 +284,7 @@ def main() -> int:
     # 8 (--mixed-parity): the ragged kernel behind --mixed-step serving —
     # one batch mixing decode rows (q_len 1) and prefill chunks (q_len up
     # to block_size+1, crossing a block boundary) against the XLA gather
-    # reference. On a TPU host this validates the Mosaic compile the
-    # tunnel-watchdog campaign needs before re-enabling mixed mode.
+    # reference. On a TPU host this validates the Mosaic compile.
     if args.mixed_parity:
         n = 6 + int(args.kernel_parity) + 1
         try:

@@ -8,16 +8,11 @@ cd "$(dirname "$0")"
 
 echo "== tpu_engine setup =="
 
-# 1. Native C++ core (LRU cache, hash ring, breaker, batch queue).
-if command -v cmake >/dev/null && command -v ninja >/dev/null; then
-    cmake -S tpu_engine/native -B build/native -G Ninja >/dev/null
-    ninja -C build/native >/dev/null
-    cp build/native/libtpucore.so tpu_engine/native/libtpucore.so
-    echo "[1/3] native core built (cmake+ninja)"
-else
-    bash tpu_engine/native/build.sh >/dev/null
-    echo "[1/3] native core built (g++ direct)"
-fi
+# 1. Native C++ core (LRU cache, hash ring, breaker, batch queue): built by
+# the loader itself from the tracked sources (g++ via native/build.sh),
+# and rebuilt whenever they change.
+python -c "from tpu_engine.core import native; assert native.available()"
+echo "[1/3] native core built"
 
 # 2. Python deps present?
 python - <<'EOF'

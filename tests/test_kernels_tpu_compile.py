@@ -1,0 +1,188 @@
+"""Tier-1 compile gate for the TPU kernels, without a chip.
+
+The Pallas interpreter (what every other kernel test runs) applies none
+of the TPU lowering's block-shape rules and never runs Mosaic, so a
+kernel can pass every parity test and still be refused by the compiler
+at its first trace on the chip. The installed libtpu can describe a v5e
+topology with no chip attached, and `jit(...).lower(shapes placed on a
+topology device).compile()` runs the real Pallas TPU lowering and Mosaic
+— so every `pallas_call` site is compiled here at the head geometry of
+the registry's gpt2 and llama, plus one tensor-parallel paged tick and
+the rules around start-up that only bite on the chip machine.
+
+These tests FAIL when the topology cannot be built: a skip would be the
+same silent pass the interpreter gives.
+"""
+
+import ast
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tpu_engine.ops import kernel_check
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    assert len(topo.devices) == 4
+    assert topo.devices[0].platform == "tpu"
+    return topo.devices
+
+
+@pytest.mark.parametrize("model", kernel_check.MODELS)
+def test_every_kernel_site_compiles_for_v5e(v5e_devices, model):
+    names = []
+    for case in kernel_check.kernel_cases(model, interpret=False):
+        kernel_check.compile_for_topology(case, v5e_devices[0])
+        names.append(case.name.split("/", 1)[1])
+    # The list itself is part of the gate: flash forward at every prompt
+    # bucket, flash backward, and all four paged read paths.
+    assert [n for n in names if n.startswith("flash_fwd")] == [
+        f"flash_fwd/S{s}" for s in kernel_check.FLASH_BUCKETS]
+    assert {n.split("/")[0] for n in names} == {
+        "flash_fwd", "flash_bwd", "paged_decode", "ragged", "quant_decode",
+        "quant_ragged"}
+
+
+def test_tp2_paged_tick_compiles_for_v5e(v5e_devices):
+    """One --tp 2 mixed tick (transformer_step_rows_ragged over an
+    H_kv-sharded pool, params placed by the registry's TP rule) on two
+    v5e devices. GSPMD refuses to partition a Mosaic kernel ("wrap the
+    call in a shard_map"), so this compiles only because the read path
+    runs per head shard (ops.paged_attention.shard_over_heads) — the
+    wrapper the scheduler applies to every tp > 1 lane."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+        tp_shardings,
+    )
+    from tpu_engine.models.transformer import transformer_step_rows_ragged
+    from tpu_engine.ops.attention import KVCache
+    from tpu_engine.ops.paged_attention import (
+        ragged_paged_attention,
+        shard_over_heads,
+    )
+    from tpu_engine.parallel.mesh import tp_mesh
+
+    _ensure_builtin_models_imported()
+    spec = create_model("gpt2", n_layers=2)   # published widths, depth cut
+    cfg = spec.config
+    mesh = tp_mesh(2, v5e_devices)
+    attn_fn = shard_over_heads(
+        functools.partial(ragged_paged_attention, interpret=False), mesh)
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return transformer_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=attn_fn, sample_slot=jnp.zeros_like(pos0))
+
+    param_shapes = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        param_shapes, tp_shardings(spec, param_shapes, mesh))
+    rows, nb, bs = 8, 513, 16
+    pool = jax.ShapeDtypeStruct(
+        (cfg.n_layers, nb, bs, cfg.kv_heads, cfg.d_head), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, None, None, "model", None)))
+    rep = NamedSharding(mesh, P())
+
+    def host(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+
+    compiled = jax.jit(tick).lower(
+        params, KVCache(pool, pool), host((rows, cfg.max_seq // bs)),
+        host((rows, 16)), host((rows,)), host((rows,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, no code names a cache
+    directory (JAX reads the variable itself); unset, the directory is
+    <checkout>/.jax_cache — a fixed path, never ~, a temp name, a pid or
+    a time — exported so children take the first branch."""
+    from tpu_engine.utils import checkpoint
+
+    updates = []
+    monkeypatch.setattr(checkpoint.jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert checkpoint.enable_compilation_cache() == str(tmp_path)
+    assert "jax_compilation_cache_dir" not in dict(updates)
+
+    updates.clear()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    expected = os.path.join(REPO, ".jax_cache")
+    assert checkpoint.enable_compilation_cache() == expected
+    assert dict(updates)["jax_compilation_cache_dir"] == expected
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == expected
+
+
+def test_chip_smoke_parent_is_stdlib_only():
+    """One process per chip: chip_smoke.py's parent must never import jax
+    (or anything under tpu_engine, which does) — a parent that touched
+    JAX would hold the chip its serving children need."""
+    with open(os.path.join(REPO, "chip_smoke.py")) as f:
+        tree = ast.parse(f.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported.update(a.name.split(".")[0] for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            assert node.level == 0, "relative import in chip_smoke.py"
+            imported.add(node.module.split(".")[0])
+        elif isinstance(node, ast.Call):
+            # No side door: __import__ / importlib.import_module.
+            fn = node.func
+            name = getattr(fn, "id", None) or getattr(fn, "attr", None)
+            assert name not in ("__import__", "import_module"), name
+    assert imported, "chip_smoke.py imports nothing?"
+    assert imported <= sys.stdlib_module_names, sorted(
+        imported - sys.stdlib_module_names)
+
+
+def test_serve_refuses_to_start_without_a_tpu(monkeypatch):
+    """The entry point decides the platform once: without
+    TPU_ENGINE_PLATFORM=cpu, a backend that is not a TPU (JAX's own
+    silent drop to the CPU) is a failed start that names the missing
+    TPU — never a serving process."""
+    from tpu_engine.serving import cli
+
+    monkeypatch.delenv("TPU_ENGINE_PLATFORM", raising=False)
+    with pytest.raises(SystemExit) as exc:
+        cli.main(["serve", "--model", "mlp", "--lanes", "1"])
+    assert "TPU" in str(exc.value) and "TPU_ENGINE_PLATFORM=cpu" in str(
+        exc.value)
+
+
+def test_failed_warmup_is_a_failed_start(monkeypatch):
+    """A warm-up that raises (on the chip: a kernel Mosaic rejects at the
+    first tick's trace) must fail `serve`, not print "skipped" and come
+    up ready with a generation lane that can never answer."""
+    from tpu_engine.ops import paged_attention
+    from tpu_engine.serving.app import serve_combined
+    from tpu_engine.utils.config import WorkerConfig
+
+    def rejected(*_args, **_kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel (forced)")
+
+    monkeypatch.setattr(paged_attention, "default_ragged_attention",
+                        lambda: rejected)
+    cfg = WorkerConfig(gen_kv_block_size=16, gen_mixed_step=True,
+                       gen_prefill_chunk=16, batch_buckets=(1,),
+                       max_batch_size=1)
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        serve_combined(model="gpt2-small-test", lanes=1, port=0,
+                       worker_config=cfg, warmup=True, native_front=False)

@@ -5,6 +5,7 @@ implementations via tests/impl_params.py parametrization; these tests cover
 what is native-only.
 """
 
+import os
 import threading
 import time
 
@@ -146,8 +147,6 @@ def test_json_encode_f32_roundtrips():
     a = np.random.default_rng(1).standard_normal(257).astype(np.float32)
     a *= np.float32(10.0) ** np.random.default_rng(2).integers(-8, 8, 257)
     frag = native.json_encode_f32(a)
-    if frag is None:  # a pre-symbol libtpucore.so: rebuild to pick it up
-        pytest.skip("libtpucore.so predates tpu_json_encode_f32")
     back = np.asarray(json.loads(frag), np.float32)
     rel = np.max(np.abs(back - a) / (np.abs(a) + 1e-30))
     assert rel < 1e-5, rel
@@ -177,3 +176,24 @@ def test_encode_output_fallback_is_full_precision(monkeypatch):
     a = np.asarray([1e-9, -2.5e-30, 3.25, 0.0], np.float32)
     back = np.asarray(json.loads(worker_mod._encode_output(a)), np.float32)
     np.testing.assert_array_equal(back, a)
+
+
+def test_library_is_rebuilt_when_sources_change(monkeypatch, tmp_path):
+    """The library is git-ignored but a copied working tree can carry one
+    built from other sources. The loader trusts it only when the hash
+    written beside it at build time matches the tracked sources."""
+    import shutil
+
+    native_dir = tmp_path / "native"
+    shutil.copytree(native._NATIVE_DIR, native_dir)
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(native_dir))
+    monkeypatch.setattr(native, "_LIB_PATH",
+                        str(native_dir / "libtpucore.so"))
+    current = native._source_hash()
+    assert native._built_from(current)          # copied with its hash
+    with open(native_dir / "core.h", "a") as f:
+        f.write("\n// edited\n")
+    edited = native._source_hash()
+    assert edited != current and not native._built_from(edited)
+    os.remove(native_dir / "libtpucore.so.sha256")
+    assert not native._built_from(current)      # no hash: not trusted

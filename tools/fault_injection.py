@@ -142,6 +142,7 @@ def launch_combined(model: str = "mlp", lanes: int = 3,
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env.setdefault("TPU_ENGINE_PLATFORM", "cpu")
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
 
     def spawn(port: int):

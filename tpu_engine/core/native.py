@@ -5,14 +5,19 @@ and ``NativeBatchQueue`` with the same Python API as the pure-Python
 implementations in ``tpu_engine.core`` so the two are interchangeable (and
 are tested against the same suite, see ``tests/impl_params.py``).
 
-The shared library is built from ``tpu_engine/native`` (CMake or
-``build.sh``). If it is absent, ``available()`` triggers a one-shot quiet
-build attempt with g++; failing that, callers fall back to pure Python.
+The shared library is built from the tracked sources in
+``tpu_engine/native`` by ``build.sh`` (plain g++). It is git-ignored, so a
+checkout starts without one and a copied working tree may carry one built
+from other sources: ``available()`` loads the library only when the
+content hash stored beside it matches ``core_api.cc``/``core.h``/
+``http_front.h``, and otherwise (re)builds it first. Where it cannot be
+built, callers fall back to pure Python.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import pickle
 import subprocess
@@ -22,10 +27,9 @@ from typing import Any, List, Optional
 from tpu_engine.core.circuit_breaker import CircuitState
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
-_LIB_CANDIDATES = [
-    os.path.join(_NATIVE_DIR, "libtpucore.so"),
-    os.path.join(os.path.dirname(_NATIVE_DIR), "..", "build", "native", "libtpucore.so"),
-]
+_LIB_PATH = os.path.join(_NATIVE_DIR, "libtpucore.so")
+# Everything build.sh compiles: core_api.cc and the headers it includes.
+_SOURCES = ("core_api.cc", "core.h", "http_front.h")
 
 _lib = None
 _load_lock = threading.Lock()
@@ -106,14 +110,12 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.tpu_front_lane_hits.argtypes = [P, ctypes.c_char_p]
     lib.tpu_front_reply.argtypes = [ctypes.c_void_p, ctypes.c_int,
                                     ctypes.c_char_p, c_size]
-    if hasattr(lib, "tpu_front_reply2"):  # older .so: plain reply only
-        lib.tpu_front_reply2.argtypes = [ctypes.c_void_p, ctypes.c_int,
-                                         ctypes.c_char_p, c_size,
-                                         ctypes.c_char_p]
-    if hasattr(lib, "tpu_json_encode_f32"):  # older .so: python fallback
-        lib.tpu_json_encode_f32.restype = c_size
-        lib.tpu_json_encode_f32.argtypes = [
-            ctypes.c_void_p, c_size, ctypes.POINTER(ctypes.c_void_p)]
+    lib.tpu_front_reply2.argtypes = [ctypes.c_void_p, ctypes.c_int,
+                                     ctypes.c_char_p, c_size,
+                                     ctypes.c_char_p]
+    lib.tpu_json_encode_f32.restype = c_size
+    lib.tpu_json_encode_f32.argtypes = [
+        ctypes.c_void_p, c_size, ctypes.POINTER(ctypes.c_void_p)]
     return lib
 
 
@@ -121,6 +123,48 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
 HANDLER_FN = ctypes.CFUNCTYPE(None, ctypes.c_void_p, ctypes.c_char_p,
                               ctypes.c_char_p, ctypes.c_char_p,
                               ctypes.c_size_t)
+
+
+def _source_hash() -> str:
+    digest = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            digest.update(name.encode() + b"\0" + f.read() + b"\0")
+    return digest.hexdigest()
+
+
+def _built_from(source_hash: str) -> bool:
+    """True when the library on disk was built from exactly these sources
+    (the hash build time wrote beside it)."""
+    try:
+        with open(_LIB_PATH + ".sha256") as f:
+            return os.path.exists(_LIB_PATH) and f.read().strip() == source_hash
+    except OSError:
+        return False
+
+
+def _build(source_hash: str) -> bool:
+    # Build to a pid-suffixed temp name, then atomically rename: two
+    # processes cold-starting together must not interleave g++ output
+    # into the same file (a corrupt .so would poison all future runs).
+    # The hash lands after the library, so a crash between the two
+    # renames leaves a library that is rebuilt, never a stale one trusted.
+    tmp_name = f"libtpucore.so.tmp.{os.getpid()}"
+    tmp_path = os.path.join(_NATIVE_DIR, tmp_name)
+    try:
+        subprocess.run(["bash", os.path.join(_NATIVE_DIR, "build.sh"), tmp_name],
+                       check=True, capture_output=True, timeout=120)
+        os.replace(tmp_path, _LIB_PATH)
+        with open(tmp_path, "w") as f:
+            f.write(source_hash + "\n")
+        os.replace(tmp_path, _LIB_PATH + ".sha256")
+        return True
+    except (OSError, subprocess.SubprocessError):
+        try:
+            os.unlink(tmp_path)
+        except OSError:
+            pass
+        return False
 
 
 def _try_load() -> Optional[ctypes.CDLL]:
@@ -131,30 +175,15 @@ def _try_load() -> Optional[ctypes.CDLL]:
         if _load_attempted:
             return None
         _load_attempted = True
-        path = next((p for p in _LIB_CANDIDATES if os.path.exists(p)), None)
-        if path is None and os.environ.get("TPU_ENGINE_NO_NATIVE_BUILD") != "1":
-            # Build to a pid-suffixed temp name, then atomically rename: two
-            # processes cold-starting together must not interleave g++ output
-            # into the same file (a corrupt .so would poison all future runs).
-            tmp_name = f"libtpucore.so.tmp.{os.getpid()}"
-            try:
-                subprocess.run(
-                    ["bash", os.path.join(_NATIVE_DIR, "build.sh"), tmp_name],
-                    check=True, capture_output=True, timeout=120,
-                )
-                os.replace(os.path.join(_NATIVE_DIR, tmp_name), _LIB_CANDIDATES[0])
-                path = _LIB_CANDIDATES[0]
-            except Exception:
-                try:
-                    os.unlink(os.path.join(_NATIVE_DIR, tmp_name))
-                except OSError:
-                    pass
+        source_hash = _source_hash()
+        if not _built_from(source_hash):
+            if os.environ.get("TPU_ENGINE_NO_NATIVE_BUILD") == "1":
                 return None
-        if path is None or not os.path.exists(path):
-            return None
+            if not _build(source_hash):
+                return None
         try:
-            _lib = _configure(ctypes.CDLL(path))
-        except Exception:
+            _lib = _configure(ctypes.CDLL(_LIB_PATH))
+        except (OSError, AttributeError):
             _lib = None
         return _lib
 
@@ -173,10 +202,10 @@ def _take_bytes(lib, ptr: ctypes.c_void_p, length: int) -> bytes:
 def json_encode_f32(arr) -> Optional[bytes]:
     """``[a,b,...]`` JSON fragment for a float array via the C encoder
     (%.6g, ~10x faster than json.dumps and GIL-free for the duration).
-    None when the native core (or the symbol, in an older .so) is absent —
-    callers fall back to a Python encode."""
+    None when the native core is absent — callers fall back to a Python
+    encode."""
     lib = _try_load()
-    if lib is None or not hasattr(lib, "tpu_json_encode_f32"):
+    if lib is None:
         return None
     import numpy as np
 
@@ -436,8 +465,6 @@ class NativeHttpFront:
         self._lanes: List[str] = []
         lib = self._lib
 
-        can_ctype = hasattr(lib, "tpu_front_reply2")
-
         def _handler(reply_ctx, method, path, body, body_len):
             ctype = None
             try:
@@ -450,7 +477,7 @@ class NativeHttpFront:
             except Exception as exc:  # never let an exception cross ctypes
                 status, payload = 500, (
                     b'{"error": ' + _json_str(str(exc)) + b"}")
-            if ctype is not None and can_ctype:
+            if ctype is not None:
                 lib.tpu_front_reply2(reply_ctx, status, payload,
                                      len(payload), ctype.encode())
             else:

@@ -176,15 +176,12 @@ _ATTN_CACHE = {}
 
 
 def default_attention():
-    """The serving-path attention implementation.
-
-    On TPU this is the Pallas flash kernel (ops.flash) — the framework's
-    hot op: measured at parity with the XLA-fused path through S2048,
-    faster beyond (1.18x at S4096), and still running at S8192+ where the
-    fused path cannot compile (O(S^2) score temps exceed HBM; see
-    ops/flash.py docstring for the on-chip numbers) — selected once per
-    process. `TPU_ENGINE_FLASH` overrides:
-    "1" forces flash (Pallas interpreter off-TPU — slow, for parity tests),
+    """The serving-path attention implementation, selected once per
+    process: the Pallas flash kernel (ops.flash) on a TPU, the XLA
+    reference elsewhere. Against the XLA-fused path it was reported on
+    an earlier stack at parity through S2048 and ahead beyond; not
+    measured on this one (PERF.md). `TPU_ENGINE_FLASH` overrides: "1"
+    forces flash (Pallas interpreter off-TPU — slow, for parity tests),
     "0" forces the XLA reference path, unset/"auto" picks by backend.
     """
     import os

@@ -1,6 +1,7 @@
 #!/usr/bin/env bash
-# Minimal no-cmake build of libtpucore.so (used as the fallback by
-# tpu_engine.core.native when the library has not been built yet).
+# The build of libtpucore.so: plain g++ over the tracked sources. Run by
+# tpu_engine.core.native whenever the library is missing or was built
+# from other sources (it records the sources' hash beside the library).
 set -euo pipefail
 cd "$(dirname "$0")"
 out="${1:-libtpucore.so}"

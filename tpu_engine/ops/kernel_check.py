@@ -1,0 +1,192 @@
+"""Every Pallas kernel site at the geometry of the models the registry serves.
+
+One list of cases (`kernel_cases`) covers the flash kernel (forward at
+every prompt bucket, backward) and the paged read paths (decode, ragged
+at q_len 1 and at the prefill-chunk width, both int8 variants) at the
+head geometry of a registered model. Two consumers:
+
+- `python -m tpu_engine.ops.kernel_check` — chip_smoke.py's kernel
+  phase: on the attached TPU, compile every case with `interpret=False`
+  and compare it with its XLA reference (`dot_product_attention`, the
+  `*_reference` gathers) run on f32 copies of the same operands at the
+  highest matmul precision. A miss, a compile error or a non-TPU backend
+  exits non-zero.
+- tests/test_kernels_tpu_compile.py — AOT-compiles the same cases for a
+  v5e topology from this sandbox, no chip attached
+  (`compile_for_topology`). That is also the recipe for kernel work:
+  Mosaic's errors can be iterated on without a chip call.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import json
+import sys
+import time
+from typing import Callable, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from tpu_engine.ops import paged_attention as pa
+from tpu_engine.ops.attention import dot_product_attention
+from tpu_engine.ops.flash import flash_attention
+
+# The models whose geometry the checks run at: gpt2 is the full-MHA
+# serving model chip_smoke.py launches; llama is the grouped case
+# (32 query heads over 4 KV heads).
+MODELS = ("gpt2", "llama")
+# Serving shapes: the smoke's launch (--kv-block-size 16, 8 decode slots,
+# max_seq 1024 -> 64-block tables over the auto-sized 513-block pool,
+# --gen-prefill-chunk 256) and the scheduler's prompt buckets.
+BLOCK_SIZE = 16
+ROWS = 8
+TABLE_LEN = 64
+N_BLOCKS = ROWS * TABLE_LEN + 1
+CHUNK = 256
+FLASH_BUCKETS = (16, 32, 64, 128, 256, 512, 1024)
+FLASH_BACKWARD_SEQ = 256
+# Max |kernel - reference| accepted for bf16 operands (and for the int8
+# pools, whose queries are bf16): the kernels keep f32 accumulators but
+# round P to the MXU dtype before the PV matmul and the output to bf16 —
+# 2^-8 relative on values of order 1.
+BF16_TOLERANCE = 2e-2
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelCase:
+    """`kernel(*operands())` is the Pallas side (jit-able); `operands` is
+    a traceable thunk, so its shapes come from `jax.eval_shape` without
+    generating a value; `check(out, operands)` returns max |kernel -
+    reference|, or is None for a compile-only case (the flash backward:
+    its gradients are pinned to the XLA path in interpret mode by
+    tests/test_flash_backward.py)."""
+    name: str
+    kernel: Callable
+    operands: Callable[[], Tuple]
+    check: Optional[Callable]
+
+
+def _geometry(model: str) -> dict:
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+
+    _ensure_builtin_models_imported()
+    cfg = create_model(model).config
+    return {"n_heads": cfg.n_heads, "n_kv_heads": cfg.kv_heads,
+            "d_head": cfg.d_head}
+
+
+def _flash_cases(model: str, geo: dict, interpret: bool):
+    h, d = geo["n_heads"], geo["d_head"]
+    kernel = functools.partial(flash_attention, causal=True,
+                               interpret=interpret)
+
+    def qkv(s):
+        # Heads are already expanded to n_heads when flash runs
+        # (models.transformer repeat_kv), so k/v carry H, not H_kv.
+        return tuple(jax.random.normal(key, (1, s, h, d), jnp.bfloat16)
+                     for key in jax.random.split(jax.random.PRNGKey(s), 3))
+
+    def check(out, operands):
+        with jax.default_matmul_precision("highest"):
+            ref = dot_product_attention(
+                *(x.astype(jnp.float32) for x in operands), causal=True)
+        return float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref)))
+
+    for s in FLASH_BUCKETS:
+        yield KernelCase(f"{model}/flash_fwd/S{s}", kernel,
+                         functools.partial(qkv, s), check)
+
+    def loss(q, k, v):
+        return kernel(q, k, v).astype(jnp.float32).sum()
+
+    yield KernelCase(f"{model}/flash_bwd/S{FLASH_BACKWARD_SEQ}",
+                     jax.grad(loss, argnums=(0, 1, 2)),
+                     functools.partial(qkv, FLASH_BACKWARD_SEQ), None)
+
+
+def _paged_cases(model: str, geo: dict, interpret: bool):
+    # One decode-shaped batch and one mixed batch: decode rows beside
+    # full and partial prefill chunks, one a block past a boundary.
+    mixed = (1, CHUNK, 130, BLOCK_SIZE + 1, 1, 1, 77, CHUNK)
+    for kind, label, q_lens in (
+            ("paged", "paged_decode", (1,) * ROWS),
+            ("ragged", "ragged/W1", (1,) * ROWS),
+            ("ragged", f"ragged/W{CHUNK}", mixed),
+            ("quant_paged", "quant_decode", (1,) * ROWS),
+            ("quant_ragged", "quant_ragged/W1", (1,) * ROWS),
+            ("quant_ragged", f"quant_ragged/W{CHUNK}", mixed)):
+        kernel_fn, reference_fn = pa.READ_PATHS[kind]
+        workload = functools.partial(
+            pa.parity_workload, kind, q_lens, block_size=BLOCK_SIZE,
+            n_blocks=N_BLOCKS, table_len=TABLE_LEN, dtype=jnp.bfloat16,
+            **geo)
+        qlen = jnp.asarray(q_lens, jnp.int32)
+
+        def check(out, operands, reference_fn=reference_fn, qlen=qlen):
+            return pa.reference_error(reference_fn, out, operands, qlen)
+
+        yield KernelCase(f"{model}/{label}",
+                         functools.partial(kernel_fn, interpret=interpret),
+                         lambda workload=workload: workload()[0], check)
+
+
+def kernel_cases(model: str, interpret: bool = False):
+    """Every Pallas kernel site at `model`'s registry geometry."""
+    geo = _geometry(model)
+    yield from _flash_cases(model, geo, interpret)
+    yield from _paged_cases(model, geo, interpret)
+
+
+def compile_for_topology(case: KernelCase, device) -> None:
+    """AOT-compile `case` for `device` of a
+    `jax.experimental.topologies.get_topology_desc(platform="tpu", ...)`
+    description: lowers through the Pallas TPU rules and runs Mosaic
+    with no chip attached. Raises what the compiler raises."""
+    from jax.sharding import SingleDeviceSharding
+
+    sharding = SingleDeviceSharding(device)
+    shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+              for x in jax.eval_shape(case.operands)]
+    jax.jit(case.kernel).lower(*shapes).compile()
+
+
+def main() -> int:
+    from tpu_engine.utils.checkpoint import enable_compilation_cache
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        print(f"kernel_check: JAX backend is {backend!r}, not a TPU — the "
+              f"compiled kernels cannot be checked here", file=sys.stderr)
+        return 1
+    enable_compilation_cache()
+    worst, failed = 0.0, []
+    for model in MODELS:
+        for case in kernel_cases(model, interpret=False):
+            t0 = time.monotonic()
+            operands = case.operands()
+            if case.check is None:
+                jax.jit(case.kernel).lower(*operands).compile()
+                err = None
+            else:
+                err = case.check(jax.block_until_ready(
+                    jax.jit(case.kernel)(*operands)), operands)
+                worst = max(worst, err)
+                if not err <= BF16_TOLERANCE:   # NaN fails too
+                    failed.append(case.name)
+            print(json.dumps({"kernel": case.name, "interpret": False,
+                              "max_abs_err": err,
+                              "seconds": round(time.monotonic() - t0, 2)}),
+                  flush=True)
+    print(json.dumps({"kernel_check": "failed" if failed else "ok",
+                      "tolerance": BF16_TOLERANCE, "worst": worst,
+                      "failed": failed}), flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -26,7 +26,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from tpu_engine.utils.jax_compat import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -95,7 +94,7 @@ def pipeline_apply(block_fn: Callable, stacked_params, x, mesh: Mesh, *,
     fn = functools.partial(_pipeline_shard_fn, block_fn=block_fn,
                            axis_name=axis_name, n_stages=n_stages,
                            n_micro=n_micro)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis_name), stacked_params),
                   P()),

@@ -35,7 +35,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from tpu_engine.utils.jax_compat import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 _NEG_INF = float("-inf")
@@ -141,7 +140,7 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis_name: str = "seq",
     fn = functools.partial(
         _ring_shard_fn, axis_name=axis_name, axis_size=n, chunk=chunk,
         causal=causal, has_mask=has_mask)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(spec4, spec4, spec4, spec2),
         out_specs=spec4,
@@ -193,7 +192,7 @@ def ulysses_attention(q, k, v, mesh: Mesh, *, axis_name: str = "seq",
     spec2 = P(bspec, axis_name)
     fn = functools.partial(_ulysses_shard_fn, axis_name=axis_name,
                            causal=causal, has_mask=has_mask)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(spec4, spec4, spec4, spec2),
         out_specs=spec4,

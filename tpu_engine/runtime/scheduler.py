@@ -1068,6 +1068,24 @@ class ContinuousGenerator:
                          wsc(scales.v, self._scale_pin))
         return caches, scales
 
+    def _paged_attn_fn(self, ragged: bool):
+        """The paged attention read path this lane's step executables
+        trace: decode or ragged, the int8 or the full-precision variant
+        by the pool, and under tp > 1 run once per head shard — a Mosaic
+        kernel cannot be partitioned by GSPMD, and heads are independent
+        (no collective inside)."""
+        from tpu_engine.ops import paged_attention as pa
+
+        if ragged:
+            fn = (pa.default_quant_ragged_attention() if self._quant
+                  else pa.default_ragged_attention())
+        else:
+            fn = (pa.default_quant_paged_attention() if self._quant
+                  else pa.default_paged_attention())
+        if self._tp_mesh is not None:
+            fn = pa.shard_over_heads(fn, self._tp_mesh)
+        return fn
+
     def _gather(self, nb: int):
         """Prefix gather for one bucket width: (pool, nb block ids) ->
         the row's (L, 1, nb*bs, H, D) cache view. Read-only on the pool
@@ -1133,15 +1151,9 @@ class ContinuousGenerator:
             return exe
         with self._exe_lock:
             if ("paged", controls) not in self._decode_exe:
-                from tpu_engine.ops.paged_attention import (
-                    default_paged_attention,
-                    default_quant_paged_attention,
-                )
-
                 cfg, dtype, chunk = self.cfg, self._dtype, self._step_chunk
                 quant = self._quant
-                attn_fn = (default_quant_paged_attention() if quant
-                           else default_paged_attention())
+                attn_fn = self._paged_attn_fn(ragged=False)
                 max_col = self.max_seq - 1
 
                 def chunk_scan(params, caches, scales, tables, tok, pos,
@@ -1267,15 +1279,9 @@ class ContinuousGenerator:
             return exe
         with self._exe_lock:
             if key not in self._decode_exe:
-                from tpu_engine.ops.paged_attention import (
-                    default_quant_ragged_attention,
-                    default_ragged_attention,
-                )
-
                 cfg, dtype = self.cfg, self._dtype
                 quant = self._quant
-                attn_fn = (default_quant_ragged_attention() if quant
-                           else default_ragged_attention())
+                attn_fn = self._paged_attn_fn(ragged=True)
 
                 def step_core(params, caches, scales, tables, tokens,
                               pos0, qlen, sample_slot, fold_pos, active,
@@ -1386,10 +1392,6 @@ class ContinuousGenerator:
             return exe
         with self._exe_lock:
             if key not in self._decode_exe:
-                from tpu_engine.ops.paged_attention import (
-                    default_quant_ragged_attention,
-                    default_ragged_attention,
-                )
                 from tpu_engine.runtime.speculative import (
                     _TAG_ACCEPT,
                     _TAG_RESID,
@@ -1399,8 +1401,7 @@ class ContinuousGenerator:
 
                 cfg, dtype = self.cfg, self._dtype
                 quant = self._quant
-                attn_fn = (default_quant_ragged_attention() if quant
-                           else default_ragged_attention())
+                attn_fn = self._paged_attn_fn(ragged=True)
                 S = self._spec_k + 1
 
                 def spec_core(params, caches, scales, tables, tokens,

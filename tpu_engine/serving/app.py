@@ -14,6 +14,7 @@ Three launchable shapes:
 
 from __future__ import annotations
 
+import os
 from typing import List, Optional, Tuple
 
 from tpu_engine.serving.autoscaler import (InProcessLaneProvider,
@@ -353,25 +354,31 @@ def serve_combined(
         # Pre-compile every batch bucket before accepting traffic — the
         # reference pays its graph compile at session load the same way
         # (inference_engine.cpp:31). Lanes pinned to the same device share
-        # XLA's compile cache, so this is ~one compile per bucket.
-        for w in workers:
-            w.engine.warmup()
-            if getattr(w.generator, "_stateless", False):
-                # Stateless-family scheduler: no generation lane to
-                # warm — engine.warmup() above already compiled every
-                # one-shot bucket the single-tick rows dispatch into.
-                continue
-            if w.generator is not None:
-                # Also compile the generation lane (smallest prompt bucket
-                # + one decode chunk) — a cold /generate otherwise pays
-                # tens of seconds of XLA compiles on its first request.
-                # Straight to the generator: the worker's request path would
-                # pollute the reference-exact /health counters and the trace
-                # with a phantom request.
-                try:
+        # XLA's compile cache, so this is ~one compile per bucket. A
+        # warm-up that raises is a FAILED START: a kernel the compiler
+        # rejects here would reject every request after it, and a lane
+        # that came up "ready" anyway would look healthy and idle.
+        try:
+            for w in workers:
+                w.engine.warmup()
+                if getattr(w.generator, "_stateless", False):
+                    # Stateless-family scheduler: no generation lane to
+                    # warm — engine.warmup() above already compiled every
+                    # one-shot bucket the single-tick rows dispatch into.
+                    continue
+                if w.generator is not None:
+                    # Also compile the generation lane (smallest prompt
+                    # bucket + one decode chunk) — a cold /generate
+                    # otherwise pays tens of seconds of XLA compiles on
+                    # its first request. Straight to the generator: the
+                    # worker's request path would pollute the
+                    # reference-exact /health counters and the trace with
+                    # a phantom request.
                     w.generator.generate([[1, 2, 3]], max_new_tokens=2)
-                except Exception as exc:  # warmup is best-effort
-                    print(f"generate warmup skipped: {exc}")
+        except BaseException:
+            for w in workers:
+                w.stop()
+            raise
     gateway = Gateway(workers, gateway_config)
     # Fleet prefix tier, combined-mode transport: in-process lanes have
     # no URL to dial, so a peer fetch is a direct handle_export_prefix
@@ -640,9 +647,8 @@ def serve_combined(
             return 200, tracing.profiler_stop()
         # Tick-bounded capture (observability plane): {"ticks": N
         # [, "node": id]} arms ONE lane's scheduler to stop the trace
-        # after exactly N ticks — the bounded stages onchip_campaign.py
-        # drives (needs the lane's --profile-dir). {"action": "status"}
-        # reports ticks left + the last capture.
+        # after exactly N ticks (needs the lane's --profile-dir).
+        # {"action": "status"} reports ticks left + the last capture.
         node = body.get("node")
         targets = [w for w in workers
                    if node in (None, "*") or w.node_id == node]
@@ -752,9 +758,14 @@ def serve_combined(
     topo = (f"mesh {dict(mesh.shape)}" if mesh is not None
             else f"{n_lanes} lanes over {len(devices)} device(s)")
     print(f"tpu_engine combined serving: {topo}, port {port} ({kind})")
+    _print_runtime_banner(workers, kind)
+    # Listen only now: a client that sees the port answer may signal the
+    # process, and the banner above must already be in the log.
     if isinstance(server, JsonHttpServer):
         server.start(background=background)
-    elif not background:
+        return gateway, workers, server
+    server.start()
+    if not background:
         import time as _time
 
         try:
@@ -867,8 +878,46 @@ def _make_front_server(port: int, routes: dict, workers, gateway,
         w.external_counters = (lambda name=w.node_id: front.lane_counters(name))
         w.on_fault_change(lambda healthy, name=w.node_id:
                           front.set_lane_enabled(name, healthy))
-    front.start()
     return front
+
+
+def _print_runtime_banner(workers, front: str) -> None:
+    """What this process will actually run on, as log lines (not wire
+    fields — /health and /stats schemas do not change): the backend and
+    device kind, each lane's device(s), the attention implementation
+    every path selected with its interpret flag, the front, and the
+    compile-cache directory. A lane that fell to the CPU, to an XLA
+    reference, or to the Pallas interpreter is visible here at start-up;
+    chip_smoke.py asserts on these lines."""
+    import jax
+
+    from tpu_engine.models.transformer import default_attention
+    from tpu_engine.ops.flash import flash_attention
+    from tpu_engine.ops.paged_attention import selected_implementations
+
+    devices = jax.devices()
+    backend = jax.default_backend()
+    print(f"  backend: {backend}, device_kind: {devices[0].device_kind}, "
+          f"devices: {len(devices)}")
+    for w in workers:
+        mesh = getattr(w.engine, "_mesh", None)
+        lane_devices = (list(mesh.devices.flat) if mesh is not None
+                        else w._tp_devices()
+                        or [getattr(w.engine, "_device", None)])
+        print(f"  lane {w.node_id} -> device "
+              + ",".join("default" if d is None else str(d.id)
+                         for d in lane_devices))
+    interpret = backend != "tpu"
+    impls = {"flash": ("pallas" if default_attention() is flash_attention
+                       else "xla"), **selected_implementations()}
+    for path, impl in impls.items():
+        print(f"  attention {path}: {impl}"
+              + (f" interpret={interpret}" if impl == "pallas" else ""))
+    print(f"  front: {front}")
+    # The entry point exports the directory it placed (utils.checkpoint).
+    print(f"  compile cache: "
+          f"{os.environ.get('JAX_COMPILATION_CACHE_DIR') or 'off'}",
+          flush=True)
 
 
 def _print_worker_banner(worker: WorkerNode, config: WorkerConfig) -> None:
@@ -883,4 +932,5 @@ def _print_worker_banner(worker: WorkerNode, config: WorkerConfig) -> None:
     print(f"   Batch Size:        {config.max_batch_size} requests")
     print(f"   Batch Timeout:     {int(config.batch_timeout_ms)}ms")
     print(bar)
+    _print_runtime_banner([worker], "python front")
     print("Ready to accept requests!")

@@ -101,16 +101,26 @@ def load_train_state(path: str, like) -> Any:
                       step=got["step"])
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
-    """Persist XLA compilations across process restarts (the reference pays
-    graph compile every session load; we pay once per machine)."""
-    cache_dir = cache_dir or os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "tpu_engine_xla"))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+def enable_compilation_cache() -> str:
+    """Persist XLA compilations across process restarts (the reference
+    pays its graph compile every session load; we pay once per checkout).
+    Called once per entry point, before the first compile.
+
+    The directory is placed from OUTSIDE: where `JAX_COMPILATION_CACHE_DIR`
+    is set, JAX itself reads it and this function names no directory at
+    all; unset, it is `<checkout>/.jax_cache` — a fixed path, because the
+    path is part of what makes a later launch find the entries — and the
+    variable is exported so child processes land on the same rule's first
+    branch. Returns the directory in use."""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))), ".jax_cache")
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # Cache every compile, including fast ones — serving restarts replay the
-    # same small executables.
+    # same small executables, and a relaunch must add nothing.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return cache_dir
