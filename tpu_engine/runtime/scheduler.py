@@ -102,6 +102,7 @@ from tpu_engine.utils.sampling import (
     expand_stopping_params,
     truncate_at_stops,
 )
+from tpu_engine.utils.tracing import TickClock, compile_counter
 
 
 @dataclass
@@ -126,10 +127,12 @@ class _Request:
     # cancelled between decode chunks (the row frees for live work).
     deadline: Optional[Deadline] = None
     # Tracing (utils.tracing.TraceSink, optional): the scheduler records
-    # queue_wait (submit→prefill start), prefill, and decode stage spans
-    # against the request's worker-root span. None = zero overhead.
+    # queue_wait (submit→prefill start), slot_wait (hand-off to the
+    # decode loop→a row), prefill, and decode stage spans against the
+    # request's worker-root span. None = zero overhead.
     sink: Optional[object] = None
     t_submit: float = 0.0
+    t_ready: float = 0.0
     t_admit: float = 0.0
     # Migration: `tag` names the row for export_row (the worker passes
     # request_id); `migrate` holds an import chain snapshot — the row
@@ -814,6 +817,18 @@ class ContinuousGenerator:
         # `mixed_step` spans carrying prefill_tokens/decode_rows attrs.
         self.tracer = None
         self.trace_node = "scheduler"
+        # The tick clock every mixed/spec tick function marks (phases on
+        # the `mixed_step` span, profiler annotations, host gap), and
+        # the process-wide compile counter it reads `compile_us` from.
+        self._compiles = compile_counter()
+        self._clock = TickClock(self._compiles)
+        # Per-row prefill accounting for the `prefill` span: ticks that
+        # fed the row, and ticks (with their summed duration) in which
+        # the row was prefilling and the token budget gave it nothing.
+        self._row_chunks = [0] * self.n_slots
+        self._row_starved_ticks = [0] * self.n_slots
+        self._row_starved_us = [0.0] * self.n_slots
+        self._tick_starved: List[int] = []
         # Staged brownout degradations (set_brownout; driven by the
         # serving worker's overload control loop, DESIGN.md "Overload
         # control"). Plain attribute writes from the control thread,
@@ -2344,7 +2359,8 @@ class ContinuousGenerator:
         out = dict(self._stats, n_slots=self.n_slots,
                    active=int(sum(r is not None for r in rows)),
                    last_tick_age_s=round(age, 3),
-                   prefix_cache=self._prefix_cache.stats())
+                   prefix_cache=self._prefix_cache.stats(),
+                   compile=self._compiles.snapshot())
         if self._mixed:
             # Snapshot, not the live nested dict — callers diff stats()
             # across time (bench warm-up subtraction) and must not see
@@ -2678,9 +2694,7 @@ class ContinuousGenerator:
                     continue
                 t0 = time.perf_counter()
                 if req.sink is not None:
-                    wait_us = (t0 - req.t_submit) * 1e6
-                    req.sink.stage("queue_wait", wait_us,
-                                   start_ts=time.time() - wait_us / 1e6)
+                    req.sink.between("queue_wait", req.t_submit, t0)
                 try:
                     item = self._run_prefill(req)
                 except Exception as exc:
@@ -2695,10 +2709,8 @@ class ContinuousGenerator:
                     # with ~µs samples. One-shot rows have no prefill at
                     # all (their device work is the tick's grouped
                     # dispatch — batch_form/device_compute spans there).
-                    dur_us = (time.perf_counter() - t0) * 1e6
-                    req.sink.stage("prefill", dur_us,
-                                   start_ts=time.time() - dur_us / 1e6,
-                                   prompt_len=len(req.prompt))
+                    req.sink.between("prefill", t0,
+                                     prompt_len=len(req.prompt))
                 if req.oneshot is not None:
                     # Single-tick work stages on its own unbounded lane
                     # (see _oneshot_ready above) and joins the next
@@ -2707,6 +2719,9 @@ class ContinuousGenerator:
                     continue
                 # Bounded put with a running check: if the decode loop
                 # already exited, don't block forever on a full queue.
+                # `slot_wait` starts here, so a put that blocks on a full
+                # queue is inside it.
+                req.t_ready = time.perf_counter()
                 placed = False
                 while self._running:
                     try:
@@ -3118,11 +3133,13 @@ class ContinuousGenerator:
         win_exe = self._slab_prefill_window(W)
         states = ssd_init_states(self.cfg, 1)
         conv, ssm = states.conv, states.ssm
-        tokens = np.zeros((1, W), np.int32)
         logits = None
         for w0 in range(0, Leff, W):
             n_valid = min(W, Leff - w0)
-            tokens[:] = 0
+            # A buffer of its own per window: on the CPU backend
+            # jnp.asarray aliases an aligned numpy array, and the
+            # previous window's dispatch may not have read it yet.
+            tokens = np.zeros((1, W), np.int32)
             if L:
                 tokens[0, :n_valid] = prompt[w0:w0 + n_valid]
             logits, conv, ssm = win_exe(
@@ -3403,6 +3420,7 @@ class ContinuousGenerator:
                 jnp.asarray(row_counts[0]))
         self._set_row_params(req, row, pos=first_col, start=0)
         self._prefilling[row] = True
+        self._reset_prefill_accounting(row)
         self._row_prompt[row] = right_pad_prompt(prompt, pb)[0]
         self._row_prompt_toks[row] = prompt
         self._row_L[row] = L
@@ -3566,6 +3584,7 @@ class ContinuousGenerator:
         self._set_row_params(req, row, pos=min(L, self.max_seq - 1),
                              start=0)
         self._prefilling[row] = True
+        self._reset_prefill_accounting(row)
         self._row_prompt[row] = right_pad_prompt(prompt, max(L, 1))[0]
         self._row_prompt_toks[row] = prompt
         self._row_L[row] = L
@@ -4073,6 +4092,7 @@ class ContinuousGenerator:
         try:
             self._loop_body()
         finally:
+            self._clock.idle()  # closes the loop's open annotation
             # Exit (stop() sentinel, _running flip, or the loop body itself
             # raising): mark the scheduler dead FIRST so submit() fails fast
             # and the prefill thread's bounded put stops retrying, then fail
@@ -4195,11 +4215,13 @@ class ContinuousGenerator:
                 self._pool.radix.insert(self._row_prompt_toks[r],
                                         self._row_blocks[r])
         if req.sink is not None:
-            dur_us = (time.perf_counter() - req.t_admit) * 1e6
-            req.sink.stage("prefill", dur_us,
-                           start_ts=time.time() - dur_us / 1e6,
-                           prompt_len=self._row_L[r])
-            req.t_admit = time.perf_counter()  # decode span start
+            now = time.perf_counter()
+            req.sink.between("prefill", req.t_admit, now,
+                             prompt_len=self._row_L[r],
+                             chunks=self._row_chunks[r],
+                             starved_ticks=self._row_starved_ticks[r],
+                             starved_us=int(self._row_starved_us[r]))
+            req.t_admit = now  # decode span start
         self._tok[r] = first_tok
         self._done[r] = done
         self._row_emitted[r] = [first_tok]
@@ -4207,6 +4229,59 @@ class ContinuousGenerator:
         self._push_stream(r, req)
         self._maybe_complete(r)
         self._maybe_hold(r, req)
+
+    def _reset_prefill_accounting(self, row: int) -> None:
+        self._row_chunks[row] = 0
+        self._row_starved_ticks[row] = 0
+        self._row_starved_us[row] = 0.0
+
+    def _tick_formed(self, width: int, prefill_rows: List[int], chunk,
+                     qlen, pos0=None) -> None:
+        """The tick's batch is formed and its arguments are on their way:
+        note which prefilling rows the token budget fed and which it
+        starved, then mark the clock's `dispatch`. `ctx_tokens` is the
+        context the attention kernel reads for the rows in the dispatch:
+        `pos0 + qlen` (a decode row's pos + 1, a prefilling row's
+        w0 + chunk); a recurrent step (no `pos0`) attends none."""
+        self._tick_starved = []
+        for r in prefill_rows:
+            if chunk[r] > 0:
+                self._row_chunks[r] += 1
+            else:
+                self._tick_starved.append(r)
+        fed = qlen > 0
+        ctx_tokens = (int((pos0[fed] + qlen[fed]).sum())
+                      if pos0 is not None else 0)
+        self._clock.dispatch(width, int(fed.sum()), ctx_tokens)
+
+    def _tick_done(self, prefill_tokens: int, decode_rows: int, width: int,
+                   spec: Optional[dict] = None) -> None:
+        """End of a mixed or speculative tick: close the clock and record
+        the tick's span(s). `mixed_step` keeps the fields its readers
+        know (duration_us, width, prefill_tokens, decode_rows) and
+        carries the clock's phases; a speculative tick also records
+        `spec_verify` (and `mixed_step` only on a mixed lane)."""
+        live = any(req is not None and not self._held[r]
+                   for r, req in enumerate(self._row_req))
+        start_ts, dur_us, phases = self._clock.end(live, self.trace_node)
+        for r in self._tick_starved:
+            self._row_starved_ticks[r] += 1
+            self._row_starved_us[r] += dur_us
+        if self.tracer is None:
+            return
+        if spec is not None:
+            self.tracer.record(
+                "tick", "spec_verify", self.trace_node, dur_us,
+                start_ts=start_ts,
+                attrs={"decode_rows": int(decode_rows), **spec,
+                       "width": int(width)})
+        if spec is None or self._mixed:
+            self.tracer.record(
+                "tick", "mixed_step", self.trace_node, dur_us,
+                start_ts=start_ts,
+                attrs={"prefill_tokens": int(prefill_tokens),
+                       "decode_rows": int(decode_rows),
+                       "width": int(width), **phases})
 
     def _tick_mixed(self) -> None:
         """One mixed tick: form the ragged batch (decode rows x 1 token +
@@ -4218,7 +4293,7 @@ class ContinuousGenerator:
         never deadlock behind a saturated decode batch."""
         pool = self._pool
         B = self.n_slots
-        t0 = time.perf_counter()
+        self._clock.begin()
         eos_vec = np.full((B,), -1, np.int32)
         controls = False
         n_decode = 0
@@ -4297,6 +4372,7 @@ class ContinuousGenerator:
                       jnp.asarray(self._temps), jnp.asarray(self._topps),
                       jnp.asarray(self._topks), jnp.asarray(self._minps),
                       jnp.asarray(eos_vec))
+            self._tick_formed(width, prefill_rows, chunk, qlen, pos0)
             if controls:
                 out = self._mixed_step_exe(width, True)(
                     *common, self._ensure_counts(),
@@ -4313,9 +4389,11 @@ class ContinuousGenerator:
                 nxt, done, self._counts = out
             else:
                 nxt, done = out
+        self._clock.wait()
         start_host_copies(nxt, done)
         nxt = np.array(nxt)
         done_new = np.array(done)
+        self._clock.apply()
         # Dispatch counted only past the host sync above — a device-step
         # failure surfaces asynchronously AT that sync (not at the
         # enqueue), and a recovered failure must leave dispatches and
@@ -4358,14 +4436,7 @@ class ContinuousGenerator:
             self._push_stream(r, req)
             self._maybe_complete(r)
 
-        if self.tracer is not None:
-            dur_us = (time.perf_counter() - t0) * 1e6
-            self.tracer.record(
-                "tick", "mixed_step", self.trace_node, dur_us,
-                start_ts=time.time() - dur_us / 1e6,
-                attrs={"prefill_tokens": int(prefill_tokens),
-                       "decode_rows": int(n_decode),
-                       "width": int(width)})
+        self._tick_done(prefill_tokens, n_decode, width)
 
     def _tick_spec(self) -> None:
         """One SPECULATIVE ragged tick — the spec_k>0 replacement for
@@ -4381,7 +4452,7 @@ class ContinuousGenerator:
         pool = self._pool
         B = self.n_slots
         S = self._spec_k + 1
-        t0 = time.perf_counter()
+        self._clock.begin()
         eos_vec = np.full((B,), -1, np.int32)
         controls = False
         n_decode = 0
@@ -4510,6 +4581,7 @@ class ContinuousGenerator:
                       jnp.asarray(self._temps), jnp.asarray(self._topps),
                       jnp.asarray(self._topks), jnp.asarray(self._minps),
                       jnp.asarray(eos_vec))
+            self._tick_formed(width, prefill_rows, chunk, qlen, pos0)
             if controls:
                 out = self._spec_step_exe(width, True, stochastic)(
                     *common, self._ensure_counts(),
@@ -4526,11 +4598,13 @@ class ContinuousGenerator:
                 emitted, n_emit, n_acc, done, self._counts = out
             else:
                 emitted, n_emit, n_acc, done = out
+        self._clock.wait()
         start_host_copies(emitted, n_emit, n_acc, done)
         emitted_h = np.array(emitted)
         n_emit_h = np.array(n_emit)
         n_acc_h = np.array(n_acc)
         done_new = np.array(done)
+        self._clock.apply()
         # Dispatch counted only past the host sync (failure surfaces AT
         # the sync; a recovered failure must leave dispatches == ticks).
         # Separate statement/site from the tick counters below, so the
@@ -4596,23 +4670,9 @@ class ContinuousGenerator:
         if self._mixed:
             self._stats["mixed"]["decode_tokens"] += decode_emitted
 
-        if self.tracer is not None:
-            dur_us = (time.perf_counter() - t0) * 1e6
-            start_ts = time.time() - dur_us / 1e6
-            self.tracer.record(
-                "tick", "spec_verify", self.trace_node, dur_us,
-                start_ts=start_ts,
-                attrs={"decode_rows": int(n_decode),
-                       "proposed": int(proposed),
-                       "accepted": int(accepted),
-                       "width": int(width)})
-            if self._mixed:
-                self.tracer.record(
-                    "tick", "mixed_step", self.trace_node, dur_us,
-                    start_ts=start_ts,
-                    attrs={"prefill_tokens": int(prefill_tokens),
-                           "decode_rows": int(n_decode),
-                           "width": int(width)})
+        self._tick_done(prefill_tokens, n_decode, width,
+                        spec={"proposed": int(proposed),
+                              "accepted": int(accepted)})
 
     def _tick_slab(self) -> None:
         """One two-path decode chunk for the state_slab family — the
@@ -4704,7 +4764,7 @@ class ContinuousGenerator:
         and stream identity carry over unchanged (tested)."""
         spool = self._spool
         B = self.n_slots
-        t0 = time.perf_counter()
+        self._clock.begin()
         eos_vec = np.full((B,), -1, np.int32)
         controls = False
         n_decode = 0
@@ -4777,6 +4837,7 @@ class ContinuousGenerator:
                       jnp.asarray(self._temps), jnp.asarray(self._topps),
                       jnp.asarray(self._topks), jnp.asarray(self._minps),
                       jnp.asarray(eos_vec))
+            self._tick_formed(width, prefill_rows, chunk, qlen)
             if controls:
                 out = self._slab_mixed_exe(width, True)(
                     *common, self._ensure_counts(),
@@ -4789,9 +4850,11 @@ class ContinuousGenerator:
                 nxt, done, self._counts = out
             else:
                 nxt, done = out
+        self._clock.wait()
         start_host_copies(nxt, done)
         nxt = np.array(nxt)
         done_new = np.array(done)
+        self._clock.apply()
         # Dispatch counted only past the host sync (the `_tick_mixed`
         # rule: a recovered failure must leave dispatches == ticks).
         self._stats["mixed"]["dispatches"] += 1
@@ -4831,17 +4894,12 @@ class ContinuousGenerator:
             self._push_stream(r, req)
             self._maybe_complete(r)
 
-        if self.tracer is not None:
-            dur_us = (time.perf_counter() - t0) * 1e6
-            self.tracer.record(
-                "tick", "mixed_step", self.trace_node, dur_us,
-                start_ts=time.time() - dur_us / 1e6,
-                attrs={"prefill_tokens": int(prefill_tokens),
-                       "decode_rows": int(n_decode),
-                       "width": int(width)})
+        self._tick_done(prefill_tokens, n_decode, width)
 
     def _loop_body(self) -> None:
         while self._running:
+            if self._mixed or self._spec:  # the lanes whose ticks are marked
+                self._clock.admit()
             now = time.monotonic()
             if self._flight_capacity:
                 # One bounded record per tick; the wall delta since the
@@ -4901,6 +4959,12 @@ class ContinuousGenerator:
                     if from_pending:
                         self._pending.popleft()
                     admitted_any = True
+                    if req.sink is not None and req.t_ready:
+                        # Hand-off by the prefill thread -> a row, time
+                        # parked under pool pressure included; ends where
+                        # the prefill (or alloc) stage starts.
+                        req.sink.between("slot_wait", req.t_ready,
+                                         req.t_admit, parked=from_pending)
                 except PoolExhausted as exc:
                     if req.migrate is not None:
                         # Imports are never parked: their transfer runs
@@ -4992,12 +5056,14 @@ class ContinuousGenerator:
                     if self._row_req[r] is not None
                     and self._row_req[r].oneshot is None]
             if not live:
+                self._clock.idle()
                 continue
             if (self._paged or self._slab) and all(self._held[r]
                                                    for r in live):
                 # Only parked handoff rows: no dispatchable work this
                 # tick — idle briefly instead of spinning while the
                 # export command (or the park bound) arrives.
+                self._clock.idle()
                 time.sleep(0.002)
                 continue
 
@@ -5015,6 +5081,7 @@ class ContinuousGenerator:
                     else:
                         self._tick_mixed()
                 except Exception as exc:
+                    self._clock.idle()  # the tick raised between marks
                     self._recover(exc)
                 continue
 

@@ -2106,6 +2106,7 @@ class WorkerNode:
         submissions and migration imports. Owns the admission release."""
         def events():
             sent = 0  # tokens relayed to the client so far (resume offset)
+            ttft_us = None  # receipt by the lane -> first token event out
             completed = False
             try:
                 while True:
@@ -2122,6 +2123,8 @@ class WorkerNode:
                     if item is None:
                         break
                     sent += len(item)
+                    if ttft_us is None:
+                        ttft_us = int((time.perf_counter() - t_admit) * 1e6)
                     yield sse_event({"tokens": item})
                 elapsed_us = int((time.perf_counter() - t0) * 1e6)
                 try:
@@ -2140,7 +2143,9 @@ class WorkerNode:
                     span_id=tctx.span_id,
                     parent_id=(parent.span_id if parent is not None
                                else None),
-                    start_ts=t_start_wall)
+                    start_ts=t_start_wall,
+                    attrs=(None if ttft_us is None
+                           else {"ttft_us": ttft_us}))
                 completed = True
                 yield sse_event({"done": True, "request_id": request_id,
                                  "tokens": tokens, "node_id": self.node_id,

@@ -324,6 +324,17 @@ def render_prometheus(healths: List[Dict], stats: Optional[Dict] = None,
            "(quantized pools; must stay 0)",
            [(node(h), t.get("scale_slots_leaked")) for h, t in kvh])
 
+    # XLA compilations, process-wide (utils.tracing.CompileCounter): a
+    # count that moves on a warm lane is a shape the warm-up missed.
+    cp = [(h, g.get("compile")) for h, g in gen
+          if isinstance(g, dict) and g.get("compile")]
+    metric("tpu_engine_compile_total", "counter",
+           "XLA executables built in this process (cache loads included)",
+           [(node(h), c.get("count")) for h, c in cp])
+    metric("tpu_engine_compile_seconds_total", "counter",
+           "Seconds spent building them",
+           [(node(h), c.get("seconds")) for h, c in cp])
+
     # Mixed prefill+decode stepping (continuous scheduler --mixed-step):
     # one ragged dispatch per tick — ticks and dispatches are counted at
     # different sites precisely so scrapers can assert they stay equal.

@@ -32,6 +32,12 @@ request. This module now carries a real tracing subsystem:
 - `profiler_start` / `profiler_stop` — ``jax.profiler`` session wrappers
   (XLA device traces viewable in TensorBoard / Perfetto), driven by
   ``POST /admin/profile`` on the combined server.
+- `TickClock` — the scheduler's one tick clock: four contiguous phases
+  (form, dispatch, wait, apply) as attrs of the tick span AND as
+  ``jax.profiler.TraceAnnotation``s on the device trace's clock, the
+  host gap between ticks, and the slow-tick stderr line.
+- `CompileCounter` / `compile_counter()` — XLA compilations and their
+  seconds, process-wide, from ``jax.monitoring``.
 """
 
 from __future__ import annotations
@@ -39,11 +45,13 @@ from __future__ import annotations
 import hashlib
 import math
 import re
+import statistics
+import sys
 import threading
 import time
 import uuid
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from tpu_engine.utils.metrics import LatencyHistogram
 
@@ -55,6 +63,10 @@ _REQUEST_OPS = frozenset({"infer", "generate", "generate_stream", "score",
 
 _TRACEPARENT_RE = re.compile(
     r"^00-([0-9a-f]{32})-([0-9a-f]{16})-([0-9a-f]{2})$")
+
+# A scheduler tick's four contiguous phases (`TickClock`): `<phase>_us`
+# attrs on the tick span, `tick.<phase>` profiler annotations.
+TICK_PHASES = ("form", "dispatch", "wait", "apply")
 
 
 def new_span_id() -> str:
@@ -234,6 +246,16 @@ class TraceSink:
             parent_id=self.ctx.span_id, start_ts=start_ts,
             attrs=attrs or None)
 
+    def between(self, op: str, t0: float, t1: Optional[float] = None,
+                **attrs) -> None:
+        """A stage span from two ``time.perf_counter()`` marks (`t1`
+        omitted: now). The wall-clock start is derived from the marks,
+        so consecutive stages that share a mark leave no hole."""
+        now = time.perf_counter()
+        end = now if t1 is None else t1
+        self.stage(op, (end - t0) * 1e6,
+                   start_ts=time.time() - (now - t0), **attrs)
+
 
 def percentile(vals: List, p: float):
     """Nearest-rank (ceil) percentile: the smallest value with at least
@@ -272,6 +294,25 @@ def _span_event(s: dict, tid: int) -> dict:
         "dur": max(0, int(s["duration_us"])),
         "pid": 1, "tid": tid, "args": args,
     }
+
+
+def _tick_phase_events(s: dict, tid: int) -> List[dict]:
+    """The four phases of a tick span as child events: the span carries
+    one start and four contiguous durations (`TickClock`), so the ring
+    holds one entry a tick and the export still shows where it went."""
+    attrs = s.get("attrs") or {}
+    if any(f"{p}_us" not in attrs for p in TICK_PHASES):
+        return []
+    out, ts = [], _span_start_ts(s) * 1e6
+    for p in TICK_PHASES:
+        out.append({"name": f"tick.{p}", "cat": "serving", "ph": "X",
+                    "ts": ts, "dur": max(0.0, attrs[f"{p}_us"]),
+                    "pid": 1, "tid": tid,
+                    "args": {"request_id": s["request_id"],
+                             "parent_op": s["op"],
+                             "seq": attrs.get("seq")}})
+        ts += attrs[f"{p}_us"]
+    return out
 
 
 def _synthesize_evicted_roots(events: List[dict]) -> List[dict]:
@@ -319,6 +360,7 @@ def spans_to_chrome(named_spans: Dict[str, List[dict]]) -> dict:
                        "tid": tid, "args": {"name": name}})
         for s in named_spans[name]:
             events.append(_span_event(s, tid))
+            events.extend(_tick_phase_events(s, tid))
     events.extend(_synthesize_evicted_roots(events))
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
@@ -398,3 +440,181 @@ def profiler_stop() -> dict:
         jax.profiler.stop_trace()
         out, _profile_dir = _profile_dir, None
     return {"ok": True, "log_dir": out}
+
+
+# -- the scheduler's tick clock and the compile counter -----------------------
+
+SLOW_TICK_FACTOR = 5.0       # a tick this many medians long says where
+SLOW_TICK_HISTORY = 20       # ... the median of the last ticks of its width
+SLOW_TICK_MIN_HISTORY = 5
+SLOW_TICK_LINE_EVERY_S = 10.0
+
+
+class CompileCounter:
+    """XLA compilations and their seconds, process-wide: a
+    ``jax.monitoring`` duration listener on the backend-compile event,
+    which jax 0.9.0 records around every executable it builds (a hit in
+    the persistent compilation cache included: the program still had no
+    executable in this process). A compile on ANY thread counts, so with
+    several lanes in one process a tick may carry a neighbour's."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.count = 0
+        self.seconds = 0.0
+
+    def __call__(self, event: str, duration_secs: float, **_kwargs) -> None:
+        if event == self.EVENT:
+            with self._lock:
+                self.count += 1
+                self.seconds += duration_secs
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"count": self.count, "seconds": round(self.seconds, 6)}
+
+
+_compile_counter: Optional[CompileCounter] = None
+_compile_counter_lock = threading.Lock()
+
+
+def compile_counter() -> CompileCounter:
+    """The process's one `CompileCounter`; the first call registers it
+    (``jax.monitoring`` listeners are process-global, so is the count)."""
+    global _compile_counter
+    with _compile_counter_lock:
+        if _compile_counter is None:
+            from jax import monitoring
+
+            counter = CompileCounter()
+            monitoring.register_event_duration_secs_listener(counter)
+            _compile_counter = counter
+    return _compile_counter
+
+
+class TickClock:
+    """One lane's tick clock, marked by the decode thread only.
+
+    A tick is four contiguous phases: ``begin()`` opens `form`,
+    ``dispatch()``, ``wait()`` and ``apply()`` each close the phase
+    before and open their own, ``end()`` closes the tick and returns its
+    start, duration and the span attrs. The same marks open and close
+    ``jax.profiler.TraceAnnotation``s (`tick` with children `tick.form`
+    ... `tick.apply`, and `loop.admit` for the loop's work between and
+    before ticks), which cost nothing measurable without a profiler
+    session and otherwise land on the host plane of the device trace.
+
+    `gap_us`: from the end of the previous tick's `wait` to this tick's
+    `dispatch`, the time the device had nothing queued because of the
+    host. Only between back-to-back ticks: ``end(live=False)`` and
+    ``idle()`` break the chain."""
+
+    def __init__(self, compiles: CompileCounter):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._compiles = compiles
+        self._open: List[object] = []
+        self._marks: List[float] = []
+        self._wall = 0.0
+        self._compile_s0 = 0.0
+        self._width = 0
+        self._ctx_tokens = 0
+        self._prev_wait_end: Optional[float] = None
+        self._recent: Dict[int, deque] = {}
+        self._last_slow_line = 0.0
+        self.seq = 0
+
+    def _push(self, name: str) -> None:
+        annotation = self._annotation(name)
+        annotation.__enter__()
+        self._open.append(annotation)
+
+    def _close(self) -> None:
+        while self._open:
+            self._open.pop().__exit__(None, None, None)
+
+    def _mark(self, phase: str) -> None:
+        self._marks.append(time.perf_counter())
+        self._open.pop().__exit__(None, None, None)
+        self._push(phase)
+
+    def admit(self) -> None:
+        """Top of a scheduler loop iteration: the host's work before a
+        tick (exports, pool growth, admission). After a tick the
+        annotation is already open (``end()`` opened it), so the time
+        between two ticks is one `loop.admit` with no hole."""
+        if not self._open:
+            self._push("loop.admit")
+
+    def idle(self) -> None:
+        """The loop found nothing to dispatch, or the tick raised between
+        its marks: close what is open; the next tick follows an idle
+        lane, not a host gap."""
+        self._close()
+        self._prev_wait_end = None
+
+    def begin(self) -> None:
+        self._close()
+        self.seq += 1
+        self._wall = time.time()
+        self._compile_s0 = self._compiles.seconds
+        self._marks = [time.perf_counter()]
+        self._push("tick")
+        self._push("tick.form")
+
+    def dispatch(self, width: int, rows: int, ctx_tokens: int) -> None:
+        """The batch is formed; the step executable is called next."""
+        self._width, self._ctx_tokens = int(width), int(ctx_tokens)
+        self._mark("tick.dispatch")
+        self._open[0].set_metadata(seq=self.seq, width=self._width,
+                                   rows=int(rows),
+                                   ctx_tokens=self._ctx_tokens)
+
+    def wait(self) -> None:
+        """The step is enqueued; the host now blocks on its results."""
+        self._mark("tick.wait")
+
+    def apply(self) -> None:
+        """The host has the results."""
+        self._mark("tick.apply")
+
+    def end(self, live: bool, node: str) -> Tuple[float, float, dict]:
+        """(start_ts, duration_us, attrs) of the tick just finished.
+        `live`: a row was still dispatchable when it ended."""
+        self._marks.append(time.perf_counter())
+        self._close()
+        self._push("loop.admit")
+        t = self._marks
+        dur_us = (t[4] - t[0]) * 1e6
+        attrs = {f"{p}_us": round((b - a) * 1e6, 1)
+                 for p, a, b in zip(TICK_PHASES, t, t[1:])}
+        if self._prev_wait_end is not None:
+            attrs["gap_us"] = round((t[1] - self._prev_wait_end) * 1e6, 1)
+        self._prev_wait_end = t[3] if live else None
+        attrs["ctx_tokens"] = self._ctx_tokens
+        attrs["compile_us"] = int(
+            (self._compiles.seconds - self._compile_s0) * 1e6)
+        attrs["seq"] = self.seq
+        self._say_if_slow(dur_us, attrs, node, t[4])
+        return self._wall, dur_us, attrs
+
+    def _say_if_slow(self, dur_us: float, attrs: dict, node: str,
+                     now: float) -> None:
+        recent = self._recent.setdefault(
+            self._width, deque(maxlen=SLOW_TICK_HISTORY))
+        if (len(recent) >= SLOW_TICK_MIN_HISTORY
+                and now - self._last_slow_line >= SLOW_TICK_LINE_EVERY_S):
+            median_us = statistics.median(recent)
+            if dur_us > SLOW_TICK_FACTOR * median_us:
+                self._last_slow_line = now
+                said = " ".join(f"{k}={attrs[k]}" for k in
+                                (*(f"{p}_us" for p in TICK_PHASES), "gap_us",
+                                 "compile_us") if k in attrs)
+                print(f"slow tick: node={node} seq={self.seq} "
+                      f"width={self._width} duration_us={dur_us:.0f} "
+                      f"median_us={median_us:.0f} {said}",
+                      file=sys.stderr, flush=True)
+        recent.append(dur_us)
