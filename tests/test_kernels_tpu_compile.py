@@ -16,7 +16,10 @@ same silent pass the interpreter gives.
 
 import ast
 import functools
+import json
+import math
 import os
+import re
 import sys
 
 import jax
@@ -24,6 +27,7 @@ import jax.numpy as jnp
 import pytest
 
 from tpu_engine.ops import kernel_check
+from tpu_engine.ops.attention import KVCache
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -54,9 +58,22 @@ def test_every_kernel_site_compiles_for_v5e(v5e_devices, model):
         "quant_ragged"}
 
 
+def _mixed_tick(cfg, attn_fn):
+    """The mixed step as the scheduler traces it, sampling left out:
+    (params, caches, tables, tokens, pos0, qlen) -> (logits, caches)."""
+    from tpu_engine.models.transformer import transformer_step_rows_ragged
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return transformer_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=attn_fn, sample_slot=jnp.zeros_like(pos0))
+
+    return tick
+
+
 def test_tp2_paged_tick_compiles_for_v5e(v5e_devices):
-    """One --tp 2 mixed tick (transformer_step_rows_ragged over an
-    H_kv-sharded pool, params placed by the registry's TP rule) on two
+    """One --tp 2 mixed tick (transformer_step_rows_ragged over a
+    head-sharded pool, params placed by the registry's TP rule) on two
     v5e devices. GSPMD refuses to partition a Mosaic kernel ("wrap the
     call in a shard_map"), so this compiles only because the read path
     runs per head shard (ops.paged_attention.shard_over_heads) — the
@@ -68,8 +85,6 @@ def test_tp2_paged_tick_compiles_for_v5e(v5e_devices):
         create_model,
         tp_shardings,
     )
-    from tpu_engine.models.transformer import transformer_step_rows_ragged
-    from tpu_engine.ops.attention import KVCache
     from tpu_engine.ops.paged_attention import (
         ragged_paged_attention,
         shard_over_heads,
@@ -80,13 +95,8 @@ def test_tp2_paged_tick_compiles_for_v5e(v5e_devices):
     spec = create_model("gpt2", n_layers=2)   # published widths, depth cut
     cfg = spec.config
     mesh = tp_mesh(2, v5e_devices)
-    attn_fn = shard_over_heads(
-        functools.partial(ragged_paged_attention, interpret=False), mesh)
-
-    def tick(params, caches, tables, tokens, pos0, qlen):
-        return transformer_step_rows_ragged(
-            params, tokens, caches, tables, pos0, qlen, cfg,
-            attn_fn=attn_fn, sample_slot=jnp.zeros_like(pos0))
+    tick = _mixed_tick(cfg, shard_over_heads(
+        functools.partial(ragged_paged_attention, interpret=False), mesh))
 
     param_shapes = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
     params = jax.tree.map(
@@ -94,8 +104,8 @@ def test_tp2_paged_tick_compiles_for_v5e(v5e_devices):
         param_shapes, tp_shardings(spec, param_shapes, mesh))
     rows, nb, bs = 8, 513, 16
     pool = jax.ShapeDtypeStruct(
-        (cfg.n_layers, nb, bs, cfg.kv_heads, cfg.d_head), jnp.bfloat16,
-        sharding=NamedSharding(mesh, P(None, None, None, "model", None)))
+        (cfg.n_layers, nb, bs, cfg.kv_heads * cfg.d_head), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, None, None, "model")))
     rep = NamedSharding(mesh, P())
 
     def host(shape):
@@ -105,6 +115,78 @@ def test_tp2_paged_tick_compiles_for_v5e(v5e_devices):
         params, KVCache(pool, pool), host((rows, cfg.max_seq // bs)),
         host((rows, 16)), host((rows,)), host((rows,))).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the tick copies no layer of the pool --------------------------------------
+
+_POOL_MOVERS = re.compile(
+    r"= \w+\[([\d,]+)\]\S* (copy|dynamic-slice|dynamic-update-slice)\(")
+
+
+@pytest.mark.parametrize("width", [1, 256])
+@pytest.mark.parametrize("config", ["gpt2-large", "mistral-7b-v0.2-8l"])
+def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
+    """The mixed step at a benchmark configuration's serving shapes
+    (its rows, its pool, shapes only), pool donated, compiled for one
+    v5e: the optimized program holds no `copy`, `dynamic-slice` or
+    `dynamic-update-slice` whose result is a layer of the pool or the
+    whole pool — the pool is scattered into in place on the layer
+    loop's carry and read by the kernel where it lies — and its
+    temporaries, past the layer weights' hoisted bf16 casts (the
+    benchmark keeps float32 weights; for Mistral the casts alone exceed
+    the pool), stay below the pool's size. The parent of PR 26 held 17
+    such copies and 7.18 GB of temporaries at gpt2-large's shapes."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+    from tpu_engine.runtime.kv_blocks import BlockPool
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           config + ".json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    assert serving["gen_mixed_step"]
+    assert width in (1, serving["gen_prefill_chunk"])
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg = spec.config
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    # The pool's shape is BlockPool's to state: ask it, at two blocks.
+    block = BlockPool(cfg, 2, bs, jnp.bfloat16).caches.k
+    pool = placed(jax.ShapeDtypeStruct(
+        (block.shape[0], serving["gen_kv_blocks"]) + block.shape[2:],
+        block.dtype))
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+    tick = _mixed_tick(
+        cfg, functools.partial(ragged_paged_attention, interpret=False))
+
+    def host(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+        params, KVCache(pool, pool), host(rows, -(-cfg.max_seq // bs)),
+        host(rows, width), host(rows), host(rows)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    whole = math.prod(pool.shape)
+    moved = [(op, dims) for dims, op in _POOL_MOVERS.findall(hlo)
+             if math.prod(map(int, dims.split(",")))
+             in (whole, whole // cfg.n_layers)]
+    assert not moved, moved
+    cast_weights = 2 * sum(math.prod(x.shape)
+                           for x in jax.tree.leaves(params["blocks"]))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp - cast_weights < 2 * whole * pool.dtype.itemsize, temp
 
 
 def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):

@@ -55,8 +55,8 @@ def _pool(spec, blocks=6, host=4):
 
 
 def _pattern(pool, base: float):
-    shape = (pool.cfg.n_layers, pool.block_size, pool.cfg.kv_heads,
-             pool.cfg.d_head)
+    shape = (pool.cfg.n_layers, pool.block_size,
+             pool.cfg.kv_heads * pool.cfg.d_head)
     return (np.arange(np.prod(shape), dtype=np.float32)
             .reshape(shape) + base)
 

@@ -66,8 +66,9 @@ def _fill_block(pool, bid, seed):
              pool.cfg.d_head)
     qk, sk = quantize_kv(jnp.asarray(rng.normal(size=shape), jnp.float32))
     qv, sv = quantize_kv(jnp.asarray(-rng.normal(size=shape), jnp.float32))
-    pool.caches = KVCache(pool.caches.k.at[:, bid].set(qk),
-                          pool.caches.v.at[:, bid].set(qv))
+    lanes = shape[:2] + (-1,)     # the pool's (L, bs, H_kv*D) block
+    pool.caches = KVCache(pool.caches.k.at[:, bid].set(qk.reshape(lanes)),
+                          pool.caches.v.at[:, bid].set(qv.reshape(lanes)))
     pool.scales = KVCache(pool.scales.k.at[:, bid].set(sk),
                           pool.scales.v.at[:, bid].set(sv))
     return _block_bytes(pool, bid)

@@ -91,6 +91,71 @@ def test_chain_round_trip_bit_exact(quant):
     assert chain2["blocks"] == chain["blocks"]
 
 
+@pytest.mark.parametrize("quant", ["", "int8"])
+def test_chain_wire_format_is_the_documented_one(quant):
+    """The pool's layout changed on the DEVICE (PR 26: blocks are
+    (L, bs, H_kv*D) there), not on the wire: a block's `k`/`v` is still
+    the C-order bytes of (L, bs, H_kv, D) at the storage dtype, `ks`/`vs`
+    of (L, bs, H_kv) f32, the checksum a crc32 over them in chain order —
+    built here with numpy from the values alone — and a chain written by
+    hand in that format imports and reads back as those values."""
+    import jax
+    import ml_dtypes
+
+    from tpu_engine.ops.quant import quantize_kv
+
+    cfg = _cfg(n_heads=4, n_kv_heads=2)     # grouped: H_kv is not H
+    L, bs, H, D = cfg.n_layers, 4, cfg.kv_heads, cfg.d_head
+    assert H * D != cfg.d_model
+    n, seed = 3, 7
+    pool = BlockPool(cfg, 8, bs, jnp.bfloat16, quantize=quant)
+    ids = _fill_blocks(pool, n, seed=seed)
+    # The values _fill_blocks scattered: one dense (L, 1, n*bs, H, D) row.
+    rng = np.random.RandomState(seed)
+    rows = [rng.randn(L, 1, n * bs, H, D).astype(np.float32)
+            for _ in "kv"]
+    if quant:
+        # The values the int8 pool holds: the one production quantizer
+        # (compiled, as the admission scatter runs it) over the row.
+        quantized = [jax.jit(quantize_kv)(jnp.asarray(r)) for r in rows]
+        rows = [np.asarray(q) for q, _ in quantized] \
+            + [np.asarray(sc) for _, sc in quantized]
+    else:
+        rows = [r.astype(ml_dtypes.bfloat16) for r in rows]
+    want = []
+    for j in range(n):
+        arrays = [r[:, 0, j * bs:(j + 1) * bs] for r in rows]  # (L,bs,H..)
+        assert arrays[0].shape == (L, bs, H, D)
+        want.append(dict(zip(("k", "v", "ks", "vs"), arrays)))
+    with pool.lock:
+        chain = pool.export_chain(ids)
+    crc = 0
+    for entry, arrays in zip(chain["blocks"], want):
+        assert sorted(entry) == sorted(arrays)
+        for name, arr in arrays.items():
+            assert base64.b64decode(entry[name]) == arr.tobytes(), name
+            crc = zlib.crc32(arr.tobytes(), crc)
+    assert chain["checksum"] == crc
+    # The other direction: a chain built by hand in the documented
+    # format is accepted and lands as the same values.
+    hand = {"version": 1, "dtype": "int8" if quant else "bfloat16",
+            "quantized": bool(quant), "block_size": bs, "n_layers": L,
+            "kv_heads": H, "d_head": D, "checksum": crc, "generation": 0,
+            "blocks": [{name: base64.b64encode(arr.tobytes()).decode()
+                        for name, arr in arrays.items()}
+                       for arrays in want]}
+    other = BlockPool(cfg, 8, bs, jnp.bfloat16, quantize=quant)
+    with other.lock:
+        assert other.chain_compatible(hand) is None
+        assert BlockPool.verify_chain(hand)
+        ids2 = other.alloc(n)
+        other.import_chain(hand, hand["blocks"], ids2)
+        assert other.export_chain(ids2)["blocks"] == chain["blocks"]
+    for j, bid in enumerate(ids2):
+        got = np.asarray(other.caches.k[:, bid]).reshape(L, bs, H, D)
+        assert got.tobytes() == want[j]["k"].tobytes()
+
+
 def test_chain_export_host_demoted_without_swap_in():
     """A demoted radix leaf exports from its pinned HOST buffers —
     bit-identical to the pre-demotion device bytes, with zero swap-in

@@ -133,8 +133,8 @@ def test_copy_on_write(spec):
     new, copied = pool.ensure_writable(src)
     assert copied and new != src
     assert pool.refcount(src) == 1 and pool.refcount(new) == 1
-    assert float(pool.caches.k[0, new, 0, 0, 0]) == 7.0
-    assert float(pool.caches.v[0, new, 0, 0, 0]) == 3.0
+    assert float(pool.caches.k[0, new, 0, 0]) == 7.0
+    assert float(pool.caches.v[0, new, 0, 0]) == 3.0
     assert pool.cow_copies == 1
 
 
@@ -373,6 +373,83 @@ def test_paged_kernel_matches_reference():
     assert parity_check(n_heads=8, n_kv_heads=2, d_head=16,
                         block_size=8, n_blocks=17, table_len=6) < 2e-5
     assert parity_check(dtype=jnp.bfloat16) < 2e-2
+
+
+# Head shapes of the one pool layout (L, NB, bs, H_kv*D): the small test
+# models' (H_kv*D = 16, no multiple of the 128-lane tile), gpt2-large's
+# (G = 1, D = 64) and Mistral's (G = 4, D = 128).
+HEAD_SHAPES = {
+    "small-HD16": dict(n_heads=4, n_kv_heads=2, d_head=8),
+    "G1-D64": dict(n_heads=3, n_kv_heads=3, d_head=64),
+    "G4-D128": dict(n_heads=8, n_kv_heads=2, d_head=128),
+}
+
+
+@pytest.mark.parametrize("heads", sorted(HEAD_SHAPES))
+@pytest.mark.parametrize("kind", ["paged", "ragged", "quant_paged",
+                                  "quant_ragged"])
+def test_every_read_path_at_every_head_shape(kind, heads):
+    """Kernel (interpreter) against its XLA reference through the
+    (pool, layer) signature, reading the SECOND layer of a two-layer
+    pool: a path that ignored the layer index would miss."""
+    from tpu_engine.ops import paged_attention as pa
+
+    q_lens = (1, 1, 1) if "ragged" not in kind else (1, 5, 9)
+    err = pa._parity(kind, q_lens, block_size=8, n_blocks=13, table_len=3,
+                     dtype=jnp.float32, seed=3, interpret=True,
+                     **HEAD_SHAPES[heads])
+    assert err < 5e-5
+
+
+@pytest.mark.parametrize("quant", ["", "int8"])
+@pytest.mark.parametrize("step", ["decode", "ragged"])
+def test_step_writes_layer_l_into_layer_l_only(spec, params, step, quant):
+    """One multi-layer step over a pool full of recognisable bytes: in
+    EVERY layer exactly the written slots change (the rows' (block,
+    offset) pairs, and the null block that absorbs padding), and every
+    other byte of every layer is bit-equal before and after — the layer
+    loop carries the whole pool and must not smear a layer's write over
+    its neighbours."""
+    from tpu_engine.models.transformer import (
+        transformer_decode_rows_paged,
+        transformer_step_rows_ragged,
+    )
+
+    cfg = spec.config
+    assert cfg.n_layers > 1
+    bs, n_blocks = 16, 6
+    pool = BlockPool(cfg, n_blocks, bs, jnp.float32, quantize=quant)
+    rng = np.random.default_rng(0)
+
+    def noise(x, lo, hi):
+        return jnp.asarray(rng.integers(lo, hi, x.shape), x.dtype)
+
+    caches = jax.tree.map(lambda x: noise(x, -100, 100), pool.caches)
+    scales = (jax.tree.map(lambda x: noise(x, 1, 9), pool.scales)
+              if quant else None)
+    tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    pos0 = jnp.asarray([17, 3], jnp.int32)
+    if step == "decode":
+        out = transformer_decode_rows_paged(
+            params, jnp.asarray([5, 9]), caches, tables, pos0, cfg,
+            dtype=jnp.float32, scales=scales)
+        written = {(2, 1), (3, 3)}
+    else:
+        # Row 0 consumes 3 tokens (columns 17..19 of block 2), row 1 one
+        # (column 3 of block 3); its two padding slots hit the null block.
+        out = transformer_step_rows_ragged(
+            params, jnp.asarray([[5, 6, 7], [9, 0, 0]]), caches, tables,
+            pos0, jnp.asarray([3, 1], jnp.int32), cfg, dtype=jnp.float32,
+            scales=scales)
+        written = {(2, 1), (2, 2), (2, 3), (3, 3), (0, 4), (0, 5)}
+    before = list(caches) + (list(scales) if quant else [])
+    after = [x for pair in out[1:] for x in pair]
+    assert len(after) == len(before)
+    for old, new in zip(before, after):
+        changed = np.any(np.asarray(old) != np.asarray(new), axis=-1)
+        for layer in range(cfg.n_layers):
+            got = set(zip(*map(np.ndarray.tolist, np.nonzero(changed[layer]))))
+            assert got == written, (layer, got)
 
 
 def test_paged_kernel_in_scheduler(spec, params, monkeypatch):

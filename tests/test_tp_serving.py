@@ -261,9 +261,10 @@ def test_quantized_pool_tp2_deterministic(spec, params):
         rerun = gen.generate(PROMPTS, max_new_tokens=10)
         st = gen.stats()
         assert out == rerun == base
-        # Scales committed to the scale sharding (H_kv axis).
+        # Scales committed to the pool's sharding (heads on the last
+        # axis of both).
         assert gen._pool.scales.k.sharding.is_equivalent_to(
-            gen._pool.scale_sharding, 4)
+            gen._pool.kv_sharding, 4)
         assert pool_leak_free(st)
     finally:
         gen.stop()

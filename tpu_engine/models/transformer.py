@@ -434,31 +434,69 @@ def transformer_decode_rows(params, token_t, caches: KVCache, pos_vec,
     return logits[:, 0], KVCache(k_new, v_new)
 
 
-def _block_decode_rows_paged(bp, h, cache_kv, tables, pos_vec,
-                             cfg: TransformerConfig, *, dtype, attn_fn):
-    """One decode step against the PAGED pool: cache_kv arrays are
-    (NB, bs, H_kv, D) block pools shared by every row; ``tables`` (B, nb)
-    maps row b's logical column c to pool block ``tables[b, c // bs]``,
-    offset ``c % bs``. Paged rows are 0-aligned (token i at logical
-    column i — the alignment radix sharing needs), so pos_vec IS the
-    logical position. The new token's K/V is scattered into its block
-    BEFORE the attention read (write-before-attend, like every other
-    decode path).
-
-    QUANTIZED pool (cache_kv = (ck, cv, ks, vs) with int8 payloads and
-    per-slot f32 scales): the new token's K/V quantizes HERE, exactly
-    once — its own (kv-head) vectors get their own scales, so the write
-    never touches (or is constrained by) neighbours already in the block
-    — and ``attn_fn`` must be a quantized read path
-    (ops.paged_attention.default_quant_paged_attention)."""
-    quantized = len(cache_kv) == 4
-    if quantized:
-        from tpu_engine.ops.quant import quantize_kv
-
-        ck, cv, ks, vs = cache_kv
-    else:
+def _write_pool(cache_kv, layer, blk, off, k, v):
+    """Scatter new-token K/V — (..., H_kv, D), one vector per leading
+    index — into layer `layer` of the paged pool at (blk, off), in place
+    on the WHOLE pool tensors: (L, NB, bs, H_kv*D), head h in lanes
+    [h*D, (h+1)*D). A QUANTIZED pool (cache_kv = (ck, cv, ks, vs), int8
+    payloads and (L, NB, bs, H_kv) f32 scales) quantizes HERE, exactly
+    once: each (kv-head) vector gets its own scale, so the write never
+    touches (or is constrained by) neighbours already in the block."""
+    lanes = k.shape[:-2] + (-1,)
+    if len(cache_kv) == 2:
         ck, cv = cache_kv
-    bs = ck.shape[1]
+        return (ck.at[layer, blk, off].set(k.reshape(lanes).astype(ck.dtype)),
+                cv.at[layer, blk, off].set(v.reshape(lanes).astype(cv.dtype)))
+    from tpu_engine.ops.quant import quantize_kv
+
+    ck, cv, ks, vs = cache_kv
+    qk, sk = quantize_kv(k)       # (..., H_kv, D) -> int8 + (..., H_kv) f32
+    qv, sv = quantize_kv(v)
+    return (ck.at[layer, blk, off].set(qk.reshape(lanes)),
+            cv.at[layer, blk, off].set(qv.reshape(lanes)),
+            ks.at[layer, blk, off].set(sk),
+            vs.at[layer, blk, off].set(sv))
+
+
+def _scan_layers_paged(block_fn, params, h, caches: KVCache,
+                       scales: Optional[KVCache]):
+    """Run `block_fn(bp, h, cache_kv, layer) -> (h, cache_kv)` over the
+    layers with the WHOLE pool (and scales) in the scan's carry beside
+    `h`: every layer writes its tokens in place at `[layer, blk, off]`
+    and reads through the layer index, so no layer of the pool is ever
+    sliced out or stacked back. Returns (h, caches[, scales])."""
+    cache_kv = tuple(caches) + (tuple(scales) if scales is not None else ())
+
+    def body(carry, layer):
+        bp, l = layer
+        h, cache_kv = block_fn(bp, carry[0], carry[1], l)
+        return (h, cache_kv), None
+
+    n_layers = caches.k.shape[0]
+    (h, cache_kv), _ = jax.lax.scan(
+        body, (h, cache_kv),
+        (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)))
+    if scales is not None:
+        return h, KVCache(*cache_kv[:2]), KVCache(*cache_kv[2:])
+    return h, KVCache(*cache_kv)
+
+
+def _block_decode_rows_paged(bp, h, cache_kv, layer, tables, pos_vec,
+                             cfg: TransformerConfig, *, dtype, attn_fn):
+    """One decode step against the PAGED pool: cache_kv arrays are the
+    whole (L, NB, bs, H_kv*D) block pools shared by every row and layer
+    (`runtime.kv_blocks.BlockPool` states the layout); ``tables``
+    (B, nb) maps row b's logical column c to pool block
+    ``tables[b, c // bs]``, offset ``c % bs``. Paged rows are 0-aligned
+    (token i at logical column i — the alignment radix sharing needs),
+    so pos_vec IS the logical position. The new token's K/V is scattered
+    into its block of layer ``layer`` BEFORE the attention read
+    (write-before-attend, like every other decode path).
+
+    QUANTIZED pool (cache_kv = (ck, cv, ks, vs)): see `_write_pool`;
+    ``attn_fn`` must be a quantized read path
+    (ops.paged_attention.default_quant_paged_attention)."""
+    bs = cache_kv[0].shape[2]
     b = h.shape[0]
     x = _norm(bp["ln1"], h, cfg)
     q, k, v = _project_qkv(bp, x, cfg, dtype=dtype,
@@ -466,24 +504,12 @@ def _block_decode_rows_paged(bp, h, cache_kv, tables, pos_vec,
     rows = jnp.arange(b)
     blk = tables[rows, pos_vec // bs]
     off = pos_vec % bs
-    if quantized:
-        qk, sk = quantize_kv(k[:, 0])     # (B, H_kv, D) -> int8 + (B, H_kv)
-        qv, sv = quantize_kv(v[:, 0])
-        ck = ck.at[blk, off].set(qk)
-        cv = cv.at[blk, off].set(qv)
-        ks = ks.at[blk, off].set(sk)
-        vs = vs.at[blk, off].set(sv)
-        a = attn_fn(q, ck, cv, ks, vs, tables, pos_vec)
-    else:
-        ck = ck.at[blk, off].set(k[:, 0].astype(ck.dtype))
-        cv = cv.at[blk, off].set(v[:, 0].astype(cv.dtype))
-        a = attn_fn(q, ck, cv, tables, pos_vec)  # grouped, unexpanded
+    cache_kv = _write_pool(cache_kv, layer, blk, off, k[:, 0], v[:, 0])
+    a = attn_fn(q, *cache_kv, layer, tables, pos_vec)  # grouped, unexpanded
     a = a.astype(dtype)
     h = h + nn.dense(bp["attn"]["wo"], a.reshape(b, 1, -1), dtype=dtype)
     h = h + _mlp(bp["mlp"], _norm(bp["ln2"], h, cfg), dtype, cfg)
-    if quantized:
-        return h.astype(dtype), (ck, cv, ks, vs)
-    return h.astype(dtype), (ck, cv)
+    return h.astype(dtype), cache_kv
 
 
 def transformer_decode_rows_paged(params, token_t, caches: KVCache, tables,
@@ -491,7 +517,8 @@ def transformer_decode_rows_paged(params, token_t, caches: KVCache, tables,
                                   dtype=jnp.bfloat16, attn_fn=None,
                                   scales: Optional[KVCache] = None):
     """`transformer_decode_rows` over a block pool instead of per-row
-    cache stripes. caches: (L, NB, bs, H_kv, D) pool pair; tables:
+    cache stripes. caches: (L, NB, bs, H_kv*D) pool pair, updated in
+    place through the layer loop (`_scan_layers_paged`); tables:
     (B, nb) int32 per-row block tables (0 = the reserved null block —
     masked by pos); pos_vec: (B,) logical write positions (0-aligned
     rows: no start_vec). ``attn_fn`` defaults to
@@ -524,44 +551,28 @@ def transformer_decode_rows_paged(params, token_t, caches: KVCache, tables,
         h = h + params["pos_embed"]["table"][logical][:, None, :]
     h = h.astype(dtype)
 
-    def body(carry, layer):
-        bp, *kv = layer
-        h, kv = _block_decode_rows_paged(
-            bp, carry, tuple(kv), tables, pos_vec, cfg, dtype=dtype,
+    def block(bp, h, cache_kv, layer):
+        return _block_decode_rows_paged(
+            bp, h, cache_kv, layer, tables, pos_vec, cfg, dtype=dtype,
             attn_fn=attn_fn)
-        return h, kv
 
-    if scales is not None:
-        h, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-            body, h, (params["blocks"], caches.k, caches.v,
-                      scales.k, scales.v))
-    else:
-        h, (k_new, v_new) = jax.lax.scan(
-            body, h, (params["blocks"], caches.k, caches.v))
+    h, *pool = _scan_layers_paged(block, params, h, caches, scales)
     h = _norm(params["ln_f"], h, cfg)
     logits = nn.dense(params["head"], h, dtype=dtype).astype(jnp.float32)
-    if scales is not None:
-        return (logits[:, 0], KVCache(k_new, v_new),
-                KVCache(ks_new, vs_new))
-    return logits[:, 0], KVCache(k_new, v_new)
+    return (logits[:, 0], *pool)
 
 
-def _block_step_rows_ragged(bp, h, cache_kv, tables, pos0, qlen,
+def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
                             cfg: TransformerConfig, *, dtype, attn_fn):
     """One ragged mixed step against the PAGED pool: row b consumes
     qlen[b] new tokens at logical columns [pos0[b], pos0[b]+qlen[b])
     (decode rows: qlen 1; admitting rows: a prefill chunk). All W slots'
-    K/V scatter into the rows' pool blocks BEFORE the attention read
-    (write-before-attend); padding slots (i >= qlen) scatter into the
-    null block and their outputs are garbage the scheduler ignores."""
-    quantized = len(cache_kv) == 4
-    if quantized:
-        from tpu_engine.ops.quant import quantize_kv
-
-        ck, cv, ks, vs = cache_kv
-    else:
-        ck, cv = cache_kv
-    bs = ck.shape[1]
+    K/V scatter into the rows' pool blocks of layer ``layer`` BEFORE the
+    attention read (write-before-attend; a quantized pool quantizes at
+    THIS write, `_write_pool`); padding slots (i >= qlen) scatter into
+    the null block and their outputs are garbage the scheduler
+    ignores."""
+    bs = cache_kv[0].shape[2]
     b, w = h.shape[:2]
     x = _norm(bp["ln1"], h, cfg)
     offs = jnp.arange(w)[None, :]
@@ -573,27 +584,12 @@ def _block_step_rows_ragged(bp, h, cache_kv, tables, pos0, qlen,
     blk = tables[rows, cols // bs]
     blk = jnp.where(offs < qlen[:, None], blk, 0)  # padding -> null block
     off = cols % bs
-    if quantized:
-        # Prefill-chunk / decode-append slots quantize at THIS write —
-        # one int8 vector + f32 scale per (slot, kv-head), exactly once;
-        # padding slots' vectors (and scales) dump into the null block.
-        qk, sk = quantize_kv(k)           # (B, W, H_kv, D) + (B, W, H_kv)
-        qv, sv = quantize_kv(v)
-        ck = ck.at[blk, off].set(qk)
-        cv = cv.at[blk, off].set(qv)
-        ks = ks.at[blk, off].set(sk)
-        vs = vs.at[blk, off].set(sv)
-        a = attn_fn(q, ck, cv, ks, vs, tables, pos0, qlen)
-    else:
-        ck = ck.at[blk, off].set(k.astype(ck.dtype))
-        cv = cv.at[blk, off].set(v.astype(cv.dtype))
-        a = attn_fn(q, ck, cv, tables, pos0, qlen)  # grouped, unexpanded
+    cache_kv = _write_pool(cache_kv, layer, blk, off, k, v)
+    a = attn_fn(q, *cache_kv, layer, tables, pos0, qlen)  # grouped
     a = a.astype(dtype)
     h = h + nn.dense(bp["attn"]["wo"], a.reshape(b, w, -1), dtype=dtype)
     h = h + _mlp(bp["mlp"], _norm(bp["ln2"], h, cfg), dtype, cfg)
-    if quantized:
-        return h.astype(dtype), (ck, cv, ks, vs)
-    return h.astype(dtype), (ck, cv)
+    return h.astype(dtype), cache_kv
 
 
 def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
@@ -605,9 +601,11 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
     --mixed-step): one ragged batch where each row consumes qlen[b] >= 0
     new tokens, writing their KV straight into the row's pool blocks in
     the SAME dispatch. tokens: (B, W) int32 right-aligned at slot 0;
-    caches: (L, NB, bs, H_kv, D) pool pair; tables: (B, nb) block
-    tables; pos0: (B,) logical column of each row's first slot; qlen:
-    (B,) valid slots. ``attn_fn`` defaults to
+    caches: (L, NB, bs, H_kv*D) pool pair, updated in place through the
+    layer loop (`_scan_layers_paged`: donate it and a tick copies no
+    layer of it); tables: (B, nb) block tables; pos0: (B,) logical
+    column of each row's first slot; qlen: (B,) valid slots.
+    ``attn_fn`` defaults to
     `ops.paged_attention.default_ragged_attention()`.
 
     ``sample_slot`` (B,) selects ONE slot per row to project through the
@@ -650,21 +648,12 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
         h = h + params["pos_embed"]["table"][logical]
     h = h.astype(dtype)
 
-    def body(carry, layer):
-        bp, *kv = layer
-        h, kv = _block_step_rows_ragged(
-            bp, carry, tuple(kv), tables, pos0, qlen, cfg, dtype=dtype,
+    def block(bp, h, cache_kv, layer):
+        return _block_step_rows_ragged(
+            bp, h, cache_kv, layer, tables, pos0, qlen, cfg, dtype=dtype,
             attn_fn=attn_fn)
-        return h, kv
 
-    if scales is not None:
-        h, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-            body, h, (params["blocks"], caches.k, caches.v,
-                      scales.k, scales.v))
-        new_scales = KVCache(ks_new, vs_new)
-    else:
-        h, (k_new, v_new) = jax.lax.scan(
-            body, h, (params["blocks"], caches.k, caches.v))
+    h, *pool = _scan_layers_paged(block, params, h, caches, scales)
     if sample_slot is not None:
         slots = jnp.minimum(sample_slot[:, None]
                             + jnp.arange(sample_width)[None, :], w - 1)
@@ -673,9 +662,7 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
     logits = nn.dense(params["head"], h, dtype=dtype).astype(jnp.float32)
     if sample_slot is not None and sample_width == 1:
         logits = logits[:, 0]
-    if scales is not None:
-        return logits, KVCache(k_new, v_new), new_scales
-    return logits, KVCache(k_new, v_new)
+    return (logits, *pool)
 
 
 def _block_decode_window(bp, h, cache_kv, pos_vec, cfg: TransformerConfig, *,

@@ -15,8 +15,13 @@ XLA reference:
 - the two int8-pool variants (`quant_*`, --kv-quantize int8), which
   apply the per-slot scales inside the read.
 
+Every read path takes the WHOLE pool, (L, NB, bs, H_kv*D) as
+`runtime.kv_blocks.BlockPool` holds it (head h in lanes
+[h*D, (h+1)*D)), plus the `layer` to read: the step carries the pool
+through its layer loop and never slices a layer out.
+
 The `*_reference` functions are XLA `take`: gather the row's blocks
-into a dense (B, S, H_kv, D) view and run the exact
+of that layer into a dense (B, S, H_kv, D) view and run the exact
 `ops.attention.dot_product_attention` math (grouped, un-expanded,
 masked). They are the correctness anchor and the CPU serving path: the
 gathered view puts every logical column at the same index the dense
@@ -27,10 +32,10 @@ The kernel side is ONE Pallas TPU kernel (`_paged_kernel`) behind all
 four entry points — decode is the ragged read at q_len 1, and the pool
 dtype is a static flag. Grid (B, row tiles, n_blocks) with the block
 axis sequential; each step DMAs ONE whole physical block — all KV heads,
-read as a (bs, H_kv*D) tile of the pool viewed (NB, bs, H_kv*D), a free
-reshape of the (NB, bs, H_kv, D) layout the pool, the chain wire format
-and the --tp shard axis all share — chosen by the block table via
-scalar prefetch (the index map reads `tables[b, j]`; the gather never
+a (bs, H_kv*D) tile of the pool, the same bytes in the same order as the
+(bs, H_kv, D) block of the chain wire format — chosen by the layer index
+and the block table via scalar prefetch (the index map reads
+`(layer[0], tables[b, j])`; neither the layer nor the gather ever
 materializes). A static loop over KV heads slices each head's (bs, D)
 lanes and folds it into running flash accumulators (f32 max /
 denominator / weighted sum in VMEM scratch). Blocks entirely past what
@@ -48,7 +53,8 @@ Selection mirrors `models.transformer.default_attention`:
 `TPU_ENGINE_PAGED` "1" forces the kernel (interpreter off-TPU), "0"
 forces the XLA reference, unset/"auto" picks the kernel on TPU only.
 Under tensor-parallel serving the chosen path runs per head shard
-(`shard_over_heads`): Mosaic kernels cannot be partitioned by GSPMD.
+(`shard_over_heads`; the merged H_kv*D axis shards in whole heads):
+Mosaic kernels cannot be partitioned by GSPMD.
 """
 
 from __future__ import annotations
@@ -72,21 +78,53 @@ _NEG_INF = float("-inf")
 _ROW_TILE = 128
 
 
-def paged_attention_reference(q, k_pool, v_pool, tables, pos_vec):
-    """XLA gather path. q: (B, 1, H, D); k_pool/v_pool: (NB, bs, H_kv, D);
-    tables: (B, nb) int32 block ids (0 = the reserved null block — its
-    columns must be masked by `pos_vec`); pos_vec: (B,) last valid
-    logical column per row (columns kpos <= pos are attended). Returns
-    (B, 1, H, D)."""
-    bs = k_pool.shape[1]
-    kk = k_pool[tables]                    # (B, nb, bs, H_kv, D)
-    vv = v_pool[tables]
+def _dense_rows(q, k_pool, v_pool, k_scale, v_scale, layer, tables):
+    """The rows' K and V of layer `layer` as the dense (B, nb*bs, H_kv, D)
+    view the attention math reads: one XLA gather per tensor through the
+    block tables, the layer never sliced out whole. An int8 pool
+    dequantizes on the gathered copy (exact: int8 * f32 scale)."""
     b, nb = tables.shape
-    kk = kk.reshape(b, nb * bs, kk.shape[3], kk.shape[4])
-    vv = vv.reshape(b, nb * bs, vv.shape[3], vv.shape[4])
-    kpos = jnp.arange(nb * bs)[None, :]
+    cols = nb * k_pool.shape[2]
+
+    def rows(pool, *heads):       # (L, NB, bs, X) -> (B, nb*bs, *heads)
+        return pool[layer, tables].reshape(b, cols, *heads)
+
+    d = q.shape[-1]
+    kk, vv = rows(k_pool, -1, d), rows(v_pool, -1, d)
+    if k_scale is not None:
+        from tpu_engine.ops.quant import dequantize_kv
+
+        kk = dequantize_kv(kk, rows(k_scale, -1))
+        vv = dequantize_kv(vv, rows(v_scale, -1))
+    return kk, vv
+
+
+def _decode_reference(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
+                      pos_vec):
+    kk, vv = _dense_rows(q, k_pool, v_pool, k_scale, v_scale, layer, tables)
+    kpos = jnp.arange(kk.shape[1])[None, :]
     valid = (kpos <= pos_vec[:, None]).astype(jnp.int32)
     return dot_product_attention(q, kk, vv, mask=valid)
+
+
+def _ragged_reference(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
+                      pos0):
+    kk, vv = _dense_rows(q, k_pool, v_pool, k_scale, v_scale, layer, tables)
+    kpos = jnp.arange(kk.shape[1])
+    qpos = pos0[:, None] + jnp.arange(q.shape[1])[None, :]     # (B, W)
+    valid = (kpos[None, None, :] <= qpos[:, :, None]).astype(jnp.int32)
+    return dot_product_attention(q, kk, vv, mask=valid)
+
+
+def paged_attention_reference(q, k_pool, v_pool, layer, tables, pos_vec):
+    """XLA gather path. q: (B, 1, H, D); k_pool/v_pool:
+    (L, NB, bs, H_kv*D), the whole pool; layer: int32 scalar, the layer
+    read; tables: (B, nb) int32 block ids (0 = the reserved null block —
+    its columns must be masked by `pos_vec`); pos_vec: (B,) last valid
+    logical column per row (columns kpos <= pos are attended). Returns
+    (B, 1, H, D)."""
+    return _decode_reference(q, k_pool, v_pool, None, None, layer, tables,
+                             pos_vec)
 
 
 # -- ragged (mixed prefill+decode) reference ---------------------------------
@@ -100,25 +138,17 @@ def paged_attention_reference(q, k_pool, v_pool, tables, pos_vec):
 # whose output the scheduler ignores.
 
 
-def ragged_paged_attention_reference(q, k_pool, v_pool, tables, pos0, qlen):
+def ragged_paged_attention_reference(q, k_pool, v_pool, layer, tables, pos0,
+                                     qlen):
     """XLA gather path, ragged queries. q: (B, W, H, D);
-    k_pool/v_pool: (NB, bs, H_kv, D); tables: (B, nb) int32 block ids;
-    pos0: (B,) logical position of each row's FIRST query slot;
-    qlen: (B,) valid query slots (padding slots produce garbage the
-    caller must ignore — masking them costs more than ignoring).
-    Returns (B, W, H, D)."""
+    k_pool/v_pool: (L, NB, bs, H_kv*D); layer: int32 scalar; tables:
+    (B, nb) int32 block ids; pos0: (B,) logical position of each row's
+    FIRST query slot; qlen: (B,) valid query slots (padding slots
+    produce garbage the caller must ignore — masking them costs more
+    than ignoring). Returns (B, W, H, D)."""
     del qlen  # padding slots are ignored by contract, not masked
-    bs = k_pool.shape[1]
-    b, w = q.shape[:2]
-    nb = tables.shape[1]
-    kk = k_pool[tables].reshape(b, nb * bs, k_pool.shape[2],
-                                k_pool.shape[3])
-    vv = v_pool[tables].reshape(b, nb * bs, v_pool.shape[2],
-                                v_pool.shape[3])
-    kpos = jnp.arange(nb * bs)
-    qpos = pos0[:, None] + jnp.arange(w)[None, :]              # (B, W)
-    valid = (kpos[None, None, :] <= qpos[:, :, None]).astype(jnp.int32)
-    return dot_product_attention(q, kk, vv, mask=valid)
+    return _ragged_reference(q, k_pool, v_pool, None, None, layer, tables,
+                             pos0)
 
 
 # -- quantized (int8 block pool) references ----------------------------------
@@ -133,60 +163,43 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, tables, pos0, qlen):
 
 
 def quant_paged_attention_reference(q, k_pool, v_pool, k_scale, v_scale,
-                                    tables, pos_vec):
+                                    layer, tables, pos_vec):
     """`paged_attention_reference` over the int8 pool. k_pool/v_pool:
-    (NB, bs, H_kv, D) int8; k_scale/v_scale: (NB, bs, H_kv) f32. The
-    gathered view dequantizes to f32 (exact: int8 * f32 scale), then the
-    identical dense attention math runs."""
-    from tpu_engine.ops.quant import dequantize_kv
-
-    bs = k_pool.shape[1]
-    b, nb = tables.shape
-    kk = dequantize_kv(k_pool[tables], k_scale[tables])
-    vv = dequantize_kv(v_pool[tables], v_scale[tables])
-    kk = kk.reshape(b, nb * bs, kk.shape[3], kk.shape[4])
-    vv = vv.reshape(b, nb * bs, vv.shape[3], vv.shape[4])
-    kpos = jnp.arange(nb * bs)[None, :]
-    valid = (kpos <= pos_vec[:, None]).astype(jnp.int32)
-    return dot_product_attention(q, kk, vv, mask=valid)
+    (L, NB, bs, H_kv*D) int8; k_scale/v_scale: (L, NB, bs, H_kv) f32.
+    The gathered view dequantizes to f32 (exact: int8 * f32 scale), then
+    the identical dense attention math runs."""
+    return _decode_reference(q, k_pool, v_pool, k_scale, v_scale, layer,
+                             tables, pos_vec)
 
 
 def quant_ragged_paged_attention_reference(q, k_pool, v_pool, k_scale,
-                                           v_scale, tables, pos0, qlen):
+                                           v_scale, layer, tables, pos0,
+                                           qlen):
     """`ragged_paged_attention_reference` over the int8 pool (same
     contract; padding slots produce garbage the caller ignores)."""
-    from tpu_engine.ops.quant import dequantize_kv
-
     del qlen
-    bs = k_pool.shape[1]
-    b, w = q.shape[:2]
-    nb = tables.shape[1]
-    kk = dequantize_kv(k_pool[tables], k_scale[tables]).reshape(
-        b, nb * bs, k_pool.shape[2], k_pool.shape[3])
-    vv = dequantize_kv(v_pool[tables], v_scale[tables]).reshape(
-        b, nb * bs, v_pool.shape[2], v_pool.shape[3])
-    kpos = jnp.arange(nb * bs)
-    qpos = pos0[:, None] + jnp.arange(w)[None, :]              # (B, W)
-    valid = (kpos[None, None, :] <= qpos[:, :, None]).astype(jnp.int32)
-    return dot_product_attention(q, kk, vv, mask=valid)
+    return _ragged_reference(q, k_pool, v_pool, k_scale, v_scale, layer,
+                             tables, pos0)
 
 
 # -- the kernel (all four read paths) -----------------------------------------
 
 
-def _paged_kernel(tables_ref, pos0_ref, lengths_ref, q_ref, k_ref, v_ref,
-                  *rest, block_size: int, scale: float, group: int,
+def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_ref,
+                  v_ref, *rest, block_size: int, scale: float, group: int,
                   n_kv_heads: int, d_head: int, quant: bool):
     """One (row, row-tile, block) grid step over ALL KV heads.
     q_ref/o_ref (1, H_kv, T, D) — T query rows of the row's W*G (row
     r = slot r//G, head r%G); k_ref/v_ref (1, bs, H_kv*D) — the physical
-    block the index map picked from the table, head h in lanes
-    [h*D, (h+1)*D); quantized pools add ks_ref/vs_ref (1, bs, H_kv) f32.
+    block the index map picked by `layer_ref` and the table (only the
+    index maps read `layer_ref`), head h in lanes [h*D, (h+1)*D);
+    quantized pools add ks_ref/vs_ref (1, bs, H_kv) f32.
     Scratch (m/l: (H_kv, T, 1), acc: (H_kv, T, D), f32) carries the
     online softmax across the sequential block axis; the statistics
     stay (T, 1) columns so no step moves a vector between lanes and
     sublanes. Causal masking within the new-token window: score row r
     keeps kpos <= pos0 + r//G."""
+    del layer_ref
     if quant:
         ks_ref, vs_ref, o_ref, m_sc, l_sc, acc_sc = rest
     else:
@@ -258,15 +271,18 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, q_ref, k_ref, v_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_call(q, k_pool, v_pool, k_scale, v_scale, tables, pos0, lengths,
-                *, interpret: bool):
+def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
+                lengths, *, interpret: bool):
     """The one pallas_call behind every read path. q: (B, W, H, D);
-    k_pool/v_pool: (NB, bs, H_kv, D); k_scale/v_scale: (NB, bs, H_kv)
-    f32 or None (full-precision pool); tables: (B, nb); pos0: (B,)
-    logical position of each row's first query slot; lengths: (B,)
-    pos0 + qlen. Returns (B, W, H, D) in q's dtype."""
+    k_pool/v_pool: (L, NB, bs, H_kv*D), the whole pool as it lives on
+    the device — an operand, never reshaped or sliced; k_scale/v_scale:
+    (L, NB, bs, H_kv) f32 or None (full-precision pool); layer: (1,)
+    the layer read; tables: (B, nb); pos0: (B,) logical position of each
+    row's first query slot; lengths: (B,) pos0 + qlen. Returns
+    (B, W, H, D) in q's dtype."""
     b, w, h, d = q.shape
-    nb_pool, bs, h_kv, _ = k_pool.shape
+    bs = k_pool.shape[2]
+    h_kv = k_pool.shape[3] // d
     nb = tables.shape[1]
     g = h // h_kv
     quant = k_scale is not None
@@ -283,20 +299,21 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, tables, pos0, lengths,
         qh = jnp.pad(qh, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
     q_spec = pl.BlockSpec(
         (1, h_kv, rows, d),
-        lambda b, t, j, tables, pos0, lengths: (b, 0, t, 0))
-    # The block table IS the index map: step (b, t, j) DMAs physical
-    # block tables[b, j] — no gathered copy exists.
-    kv_spec = pl.BlockSpec(
-        (1, bs, h_kv * d),
-        lambda b, t, j, tables, pos0, lengths: (tables[b, j], 0, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qh, k_pool.reshape(nb_pool, bs, h_kv * d),
-                v_pool.reshape(nb_pool, bs, h_kv * d)]
+        lambda b, t, j, tables, pos0, lengths, layer: (b, 0, t, 0))
+
+    # The layer index and the block table ARE the index map: step
+    # (b, t, j) DMAs physical block tables[b, j] of layer layer[0] — no
+    # layer is sliced out and no gathered copy exists.
+    def block_spec(last):
+        return pl.BlockSpec(
+            (None, 1, bs, last),
+            lambda b, t, j, tables, pos0, lengths, layer:
+                (layer[0], tables[b, j], 0, 0))
+
+    in_specs = [q_spec, block_spec(h_kv * d), block_spec(h_kv * d)]
+    operands = [qh, k_pool, v_pool]
     if quant:
-        scale_spec = pl.BlockSpec(
-            (1, bs, h_kv),
-            lambda b, t, j, tables, pos0, lengths: (tables[b, j], 0, 0))
-        in_specs += [scale_spec, scale_spec]
+        in_specs += [block_spec(h_kv), block_spec(h_kv)]
         operands += [k_scale, v_scale]
     kernel = functools.partial(
         _paged_kernel, block_size=bs, scale=1.0 / math.sqrt(d), group=g,
@@ -304,7 +321,7 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, tables, pos0, lengths,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,        # tables, pos0, lengths
+            num_scalar_prefetch=4,        # tables, pos0, lengths, layer
             grid=(b, r_pad // rows, nb),
             in_specs=in_specs,
             out_specs=q_spec,
@@ -318,12 +335,12 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, tables, pos0, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(tables, pos0, lengths, *operands)
+    )(tables, pos0, lengths, layer, *operands)
     return (out[:, :, :r].reshape(b, h_kv, w, g, d)
             .transpose(0, 2, 1, 3, 4).reshape(b, w, h, d))
 
 
-def _paged(q, k_pool, v_pool, k_scale, v_scale, tables, pos0, qlen,
+def _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0, qlen,
            interpret):
     """Entry-point glue: `interpret=None` auto-selects (compiled on TPU,
     the Pallas interpreter elsewhere); host ints become int32 arrays."""
@@ -331,41 +348,43 @@ def _paged(q, k_pool, v_pool, k_scale, v_scale, tables, pos0, qlen,
         interpret = jax.default_backend() != "tpu"
     pos0 = jnp.asarray(pos0, jnp.int32)
     return _paged_call(q, k_pool, v_pool, k_scale, v_scale,
+                       jnp.asarray(layer, jnp.int32).reshape(1),
                        jnp.asarray(tables, jnp.int32), pos0,
                        pos0 + jnp.asarray(qlen, jnp.int32),
                        interpret=bool(interpret))
 
 
-def paged_attention(q, k_pool, v_pool, tables, pos_vec, *, interpret=None):
+def paged_attention(q, k_pool, v_pool, layer, tables, pos_vec, *,
+                    interpret=None):
     """Pallas-kernel drop-in for `paged_attention_reference` (same
     signature/contract): the ragged read at q_len 1."""
-    return _paged(q, k_pool, v_pool, None, None, tables, pos_vec, 1,
+    return _paged(q, k_pool, v_pool, None, None, layer, tables, pos_vec, 1,
                   interpret)
 
 
-def ragged_paged_attention(q, k_pool, v_pool, tables, pos0, qlen, *,
+def ragged_paged_attention(q, k_pool, v_pool, layer, tables, pos0, qlen, *,
                            interpret=None):
     """Pallas-kernel drop-in for `ragged_paged_attention_reference` (same
     signature/contract)."""
-    return _paged(q, k_pool, v_pool, None, None, tables, pos0, qlen,
+    return _paged(q, k_pool, v_pool, None, None, layer, tables, pos0, qlen,
                   interpret)
 
 
-def quant_paged_attention(q, k_pool, v_pool, k_scale, v_scale, tables,
+def quant_paged_attention(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
                           pos_vec, *, interpret=None):
     """Pallas-kernel drop-in for `quant_paged_attention_reference` (same
     signature/contract): the block DMA is int8 + a scale vector — about
     half the bf16 bytes per block — and dequant happens in VMEM."""
-    return _paged(q, k_pool, v_pool, k_scale, v_scale, tables, pos_vec, 1,
-                  interpret)
+    return _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
+                  pos_vec, 1, interpret)
 
 
-def quant_ragged_paged_attention(q, k_pool, v_pool, k_scale, v_scale,
+def quant_ragged_paged_attention(q, k_pool, v_pool, k_scale, v_scale, layer,
                                  tables, pos0, qlen, *, interpret=None):
     """Pallas-kernel drop-in for `quant_ragged_paged_attention_reference`
     (same signature/contract)."""
-    return _paged(q, k_pool, v_pool, k_scale, v_scale, tables, pos0, qlen,
-                  interpret)
+    return _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
+                  qlen, interpret)
 
 
 # The four read paths: name -> (kernel entry point, XLA reference). The
@@ -384,19 +403,22 @@ def shard_over_heads(attn_fn, mesh, axis: str = "model"):
     reference): run `attn_fn` once per head shard under `jax.shard_map`.
     Heads are independent, so there is no collective inside, and a
     Mosaic kernel — which GSPMD refuses to partition — sees only its
-    local heads. Every operand with >= 3 dims carries its heads on
-    axis 2 (q (B, W, H, D); pools (NB, bs, H_kv, D); scales
-    (NB, bs, H_kv)) and shards there; tables and per-row vectors
-    replicate."""
-    def spec(x):
-        if x.ndim < 3:
+    local heads. q (B, W, H, D), the first operand, shards its heads on
+    axis 2; the pool tensors (L, NB, bs, H_kv*D) and scales
+    (L, NB, bs, H_kv) shard their last axis, whole heads to a shard
+    (`BlockPool` requires H_kv % tp == 0); the layer index, the tables
+    and the per-row vectors replicate."""
+    def spec(i, x):
+        if x.ndim != 4:
             return P()
-        return P(*(axis if i == 2 else None for i in range(x.ndim)))
+        return P(None, None, axis, None) if i == 0 \
+            else P(None, None, None, axis)
 
     def sharded(*args):
         return jax.shard_map(
-            attn_fn, mesh=mesh, in_specs=tuple(spec(a) for a in args),
-            out_specs=spec(args[0]), check_vma=False)(*args)
+            attn_fn, mesh=mesh,
+            in_specs=tuple(spec(i, a) for i, a in enumerate(args)),
+            out_specs=spec(0, args[0]), check_vma=False)(*args)
 
     return sharded
 
@@ -456,11 +478,17 @@ def selected_implementations() -> dict:
             for kind, (kernel_fn, _) in READ_PATHS.items()}
 
 
+# Layers of a parity workload's pool, and the one read: not the first, so
+# a read path that ignored `layer` misses parity.
+_PARITY_LAYERS, _PARITY_LAYER = 2, 1
+
+
 def parity_workload(kind: str, q_lens, *, n_heads: int, n_kv_heads: int,
                     d_head: int, block_size: int, n_blocks: int,
                     table_len: int, dtype, seed: int = 0):
     """One random workload for the `READ_PATHS[kind]` pair, one row per
-    entry of `q_lens`: (operands, qlen). Rows get distinct
+    entry of `q_lens`: (operands, qlen). The pool has two layers, both
+    random, and the second is read. Rows get distinct
     shuffled tables and ragged positions so the skip/mask paths are
     exercised. Traceable (`jax.eval_shape` gives the operand shapes
     without generating them — ops.kernel_check AOT-compiles from those).
@@ -471,7 +499,7 @@ def parity_workload(kind: str, q_lens, *, n_heads: int, n_kv_heads: int,
     rng = np.random.default_rng(seed)
     batch = len(q_lens)
     w = max(q_lens)
-    shape = (n_blocks, block_size, n_kv_heads, d_head)
+    shape = (_PARITY_LAYERS, n_blocks, block_size, n_kv_heads * d_head)
     if quant:
         # The int8 pool + f32 scales come from quantizing a random f32
         # pool with the ONE production write path, so parity inputs
@@ -479,10 +507,11 @@ def parity_workload(kind: str, q_lens, *, n_heads: int, n_kv_heads: int,
         from tpu_engine.ops.quant import quantize_kv
 
         kq, kpool = jax.random.split(jax.random.PRNGKey(seed), 2)
-        kk, kv = jax.random.split(kpool, 2)
-        k_pool, k_scale = quantize_kv(jax.random.normal(kk, shape))
-        v_pool, v_scale = quantize_kv(jax.random.normal(kv, shape))
-        pool = (k_pool, v_pool, k_scale, v_scale)
+        pool, scales = zip(*(
+            quantize_kv(jax.random.normal(
+                key, shape[:3] + (n_kv_heads, d_head)))
+            for key in jax.random.split(kpool, 2)))
+        pool = tuple(x.reshape(shape) for x in pool) + scales
     else:
         kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
         pool = (jax.random.normal(kk, shape, dtype),
@@ -495,7 +524,8 @@ def parity_workload(kind: str, q_lens, *, n_heads: int, n_kv_heads: int,
         # Row history + this chunk must fit the table.
         pos0[r] = int(rng.integers(0, table_len * block_size - ql + 1))
     qlen = jnp.asarray(np.asarray(q_lens, np.int32))
-    where = (jnp.asarray(tables), jnp.asarray(pos0))
+    where = (jnp.int32(_PARITY_LAYER), jnp.asarray(tables),
+             jnp.asarray(pos0))
     if not decode:
         where += (qlen,)
     return (q, *pool, *where), qlen

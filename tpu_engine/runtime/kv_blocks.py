@@ -8,10 +8,11 @@ whole-prompt repeat (`_PrefixCache`). This module replaces that with the
 vLLM-style layout, kept TPU-native:
 
 - **One static device tensor** per K/V of shape
-  ``(L, num_blocks, block_size, H_kv, D)`` — allocated once, donated
-  through every decode chunk exactly like the dense cache, so the layout
-  stays compiler-visible and nothing retraces as rows come and go
-  (PAPERS.md "Compiler-First … Portable O(1) Autoregressive Caching").
+  ``(L, num_blocks, block_size, H_kv*D)`` (`BlockPool` states the
+  layout) — allocated once, donated through every step and updated in
+  place there, so the layout stays compiler-visible and nothing
+  retraces as rows come and go (PAPERS.md "Compiler-First … Portable
+  O(1) Autoregressive Caching").
   Block 0 is the reserved **null block**: unallocated page-table entries
   point at it, padding scatters dump into it, and it is never attended
   (the position mask ends at each row's `pos`).
@@ -359,7 +360,23 @@ class RadixTree:
 
 
 class BlockPool:
-    """Device block pool + host bookkeeping for the paged KV cache."""
+    """Device block pool + host bookkeeping for the paged KV cache.
+
+    **The pool's layout, stated once.** ``caches`` is a K/V pair of
+    ``(L, num_blocks, block_size, H_kv*D)`` tensors: the two minor axes
+    are a block's slots and, merged, its KV heads — head ``h`` in lanes
+    ``[h*D, (h+1)*D)``. It is the operand the paged-attention kernel
+    reads (`ops.paged_attention._paged_call`: one ``(bs, H_kv*D)`` tile
+    a block, picked by layer index and block table) and the carry the
+    step functions scatter into (`models.transformer._write_pool`), so
+    a tick neither reshapes nor copies it; a minor axis of ``H_kv*D``
+    also tiles on the TPU where ``(H_kv, D)`` = (20, 64) does not. An
+    int8 pool's payload has the same shape, its ``scales`` are
+    ``(L, num_blocks, block_size, H_kv)`` f32. Under tensor parallelism
+    the last axis of both shards in whole heads. One block on the host —
+    a demoted block, a block of a chain on the wire — is
+    ``(L, block_size, H_kv*D)``: the same bytes in the same order as the
+    wire format's documented ``(L, block_size, H_kv, D)``."""
 
     def __init__(self, cfg: TransformerConfig, num_blocks: int,
                  block_size: int, dtype=jnp.bfloat16, device=None,
@@ -367,8 +384,9 @@ class BlockPool:
                  tp_axis: str = "model"):
         """``mesh`` (tensor-parallel serving, DESIGN.md "Tensor-parallel
         serving"): a 1-axis ``model`` mesh — the pool tensors shard
-        their ``H_kv`` dim over it (scale arrays alongside for int8
-        pools), matching the heads-axis model placement so each tick's
+        their merged ``H_kv*D`` axis over it in whole heads (scale
+        arrays their ``H_kv`` axis, for int8 pools), matching the
+        heads-axis model placement so each tick's
         pool-donating dispatch stays one SPMD program with zero
         resharding. ``kv_heads`` must divide by the axis size. None
         (default) keeps today's single-device pool; ``device`` and
@@ -379,8 +397,9 @@ class BlockPool:
             raise ValueError(f"unsupported KV quantize mode {quantize!r} "
                              "(only 'int8')")
         self.tp = 1
-        self.kv_sharding = None      # NamedSharding of the payload pools
-        self.scale_sharding = None   # ... and of the int8 scale arrays
+        # NamedSharding of the payload pools AND the int8 scale arrays:
+        # both carry their heads on the last of four axes.
+        self.kv_sharding = None
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -392,11 +411,9 @@ class BlockPool:
                 raise ValueError(
                     f"kv_heads={cfg.kv_heads} must divide by the "
                     f"tensor-parallel degree {tp} (the pool shards its "
-                    f"H_kv axis)")
+                    f"H_kv*D axis in whole heads)")
             self.tp = tp
             self.kv_sharding = NamedSharding(
-                mesh, P(None, None, None, tp_axis, None))
-            self.scale_sharding = NamedSharding(
                 mesh, P(None, None, None, tp_axis))
         self.cfg = cfg
         self.num_blocks = int(num_blocks)
@@ -439,7 +456,7 @@ class BlockPool:
         self._host_ks = self._host_vs = None
         if self.host_blocks > 0:
             hshape = (self.host_blocks, cfg.n_layers, self.block_size,
-                      cfg.kv_heads, cfg.d_head)
+                      cfg.kv_heads * cfg.d_head)
             hdtype = jnp.zeros((), self._dtype).dtype  # numpy-compat dtype
             self._host_k = np.zeros(hshape, hdtype)
             self._host_v = np.zeros(hshape, hdtype)
@@ -467,26 +484,24 @@ class BlockPool:
         self.swapped_in_tokens = 0
 
     def _init_device(self) -> KVCache:
-        shape = (self.cfg.n_layers, self.num_blocks, self.block_size,
-                 self.cfg.kv_heads, self.cfg.d_head)
-        caches = KVCache(jnp.zeros(shape, self._dtype),
-                         jnp.zeros(shape, self._dtype))
-        if self.kv_sharding is not None:
-            # Tensor-parallel pool: committed H_kv-sharded from birth,
-            # so every consumer executable compiles SPMD over the mesh.
-            caches = jax.device_put(caches, self.kv_sharding)
-        elif self._device is not None:
-            caches = jax.device_put(caches, self._device)
+        cfg = self.cfg
+        slots = (cfg.n_layers, self.num_blocks, self.block_size)
+        # Tensor-parallel pool: committed head-sharded from birth, so
+        # every consumer executable compiles SPMD over the mesh.
+        where = self.kv_sharding or self._device
+
+        def place(pair):
+            return pair if where is None else jax.device_put(pair, where)
+
+        shape = slots + (cfg.kv_heads * cfg.d_head,)
+        caches = place(KVCache(jnp.zeros(shape, self._dtype),
+                               jnp.zeros(shape, self._dtype)))
         if self.quantized:
             # Scale 1.0 everywhere: unwritten (and null-block) slots
             # dequantize to exact zeros, like a fresh bf16 pool.
-            scales = KVCache(jnp.ones(shape[:-1], jnp.float32),
-                             jnp.ones(shape[:-1], jnp.float32))
-            if self.scale_sharding is not None:
-                scales = jax.device_put(scales, self.scale_sharding)
-            elif self._device is not None:
-                scales = jax.device_put(scales, self._device)
-            self.scales = scales
+            shape = slots + (cfg.kv_heads,)
+            self.scales = place(KVCache(jnp.ones(shape, jnp.float32),
+                                        jnp.ones(shape, jnp.float32)))
         return caches
 
     # -- bookkeeping (hold self.lock) -----------------------------------------
@@ -894,17 +909,19 @@ class BlockPool:
             return False
 
     def _chain_block_arrays(self, chain: dict, entry: dict):
-        """One wire block -> host arrays shaped for a device write."""
-        shape = (self.cfg.n_layers, self.block_size, self.cfg.kv_heads,
-                 self.cfg.d_head)
+        """One wire block -> host arrays shaped for a device write: the
+        wire's (L, bs, H_kv, D) payload bytes ARE the pool's
+        (L, bs, H_kv*D) block, in order."""
+        cfg = self.cfg
+        slots = (cfg.n_layers, self.block_size)
         dt = jnp.zeros((), self._dtype).dtype
-        out = [np.frombuffer(base64.b64decode(entry["k"]),
-                             dtype=dt).reshape(shape),
-               np.frombuffer(base64.b64decode(entry["v"]),
-                             dtype=dt).reshape(shape)]
+        out = [np.frombuffer(base64.b64decode(entry[name]), dtype=dt)
+               .reshape(slots + (cfg.kv_heads * cfg.d_head,))
+               for name in ("k", "v")]
         if self.quantized:
             out += [np.frombuffer(base64.b64decode(entry[name]),
-                                  dtype=np.float32).reshape(shape[:-1])
+                                  dtype=np.float32)
+                    .reshape(slots + (cfg.kv_heads,))
                     for name in ("ks", "vs")]
         return out
 
@@ -936,7 +953,7 @@ class BlockPool:
 
                 self._import_exe[n] = jax.jit(write_n, donate_argnums=(0,))
         per = [self._chain_block_arrays(chain, e) for e in entries]
-        # (n, L, bs, H, D) -> (L, n, bs, H, D): the pool's block axis.
+        # (n, L, bs, H*D) -> (L, n, bs, H*D): the pool's block axis.
         stacked = [np.stack([p[i] for p in per]).swapaxes(0, 1)
                    for i in range(len(per[0]))]
         host = [jnp.asarray(a) for a in stacked]
@@ -1262,16 +1279,14 @@ class StateSlabPool:
 
 # -- device-side block movement (jitted by the scheduler per bucket) ----------
 
-def gather_blocks(pool_k, pool_v, ids):
-    """(L, NB, bs, H, D) pools + (nb,) block ids -> one row-cache KVCache
-    (L, 1, nb*bs, H, D): logical column j*bs+o reads pool[ids[j], o].
-    Padding entries point at the null block; their columns carry garbage
-    the position mask must exclude."""
-    L, _, bs, h, d = pool_k.shape
-    nb = ids.shape[0]
-    k = pool_k[:, ids].reshape(L, 1, nb * bs, h, d)
-    v = pool_v[:, ids].reshape(L, 1, nb * bs, h, d)
-    return KVCache(k, v)
+def gather_blocks(pool_k, pool_v, ids, *, kv_heads: int):
+    """(L, NB, bs, H*D) pools + (nb,) block ids -> one row-cache KVCache
+    (L, 1, nb*bs, H, D), H = ``kv_heads``: logical column j*bs+o reads
+    pool[ids[j], o]. Padding entries point at the null block; their
+    columns carry garbage the position mask must exclude."""
+    L, _, bs, _ = pool_k.shape
+    row = (L, 1, ids.shape[0] * bs, kv_heads, -1)
+    return KVCache(pool_k[:, ids].reshape(row), pool_v[:, ids].reshape(row))
 
 
 def scatter_blocks(caches, row_k, row_v, ids):
@@ -1279,10 +1294,10 @@ def scatter_blocks(caches, row_k, row_v, ids):
     ``ids`` (the admission half of paging). Entries mapped to 0 dump
     into the null block — the scheduler points radix-matched prefix
     blocks there so shared blocks are never rewritten. Donate `caches`."""
-    L, nb = caches.k.shape[0], ids.shape[0]
-    bs, h, d = caches.k.shape[2], caches.k.shape[3], caches.k.shape[4]
-    rk = row_k.reshape(L, nb, bs, h, d).astype(caches.k.dtype)
-    rv = row_v.reshape(L, nb, bs, h, d).astype(caches.v.dtype)
+    L, _, bs, hd = caches.k.shape
+    blocks = (L, ids.shape[0], bs, hd)
+    rk = row_k.reshape(blocks).astype(caches.k.dtype)
+    rv = row_v.reshape(blocks).astype(caches.v.dtype)
     return KVCache(caches.k.at[:, ids].set(rk), caches.v.at[:, ids].set(rv))
 
 
@@ -1293,12 +1308,12 @@ def gather_blocks_quant(pool_k, pool_v, k_scale, v_scale, ids, *, dtype):
     this row's dense view is full-precision."""
     from tpu_engine.ops.quant import dequantize_kv
 
-    L, _, bs, h, d = pool_k.shape
-    nb = ids.shape[0]
-    k = dequantize_kv(pool_k[:, ids], k_scale[:, ids], dtype)
-    v = dequantize_kv(pool_v[:, ids], v_scale[:, ids], dtype)
-    return KVCache(k.reshape(L, 1, nb * bs, h, d),
-                   v.reshape(L, 1, nb * bs, h, d))
+    L, _, bs, h = k_scale.shape
+    row = (L, 1, ids.shape[0] * bs, h)
+    return KVCache(*(
+        dequantize_kv(pool[:, ids].reshape(row + (-1,)),
+                      scale[:, ids].reshape(row), dtype)
+        for pool, scale in ((pool_k, k_scale), (pool_v, v_scale))))
 
 
 def scatter_blocks_quant(caches, scales, row_k, row_v, ids):
@@ -1309,11 +1324,11 @@ def scatter_blocks_quant(caches, scales, row_k, row_v, ids):
     movement copies these bytes verbatim. Donate `caches` AND `scales`."""
     from tpu_engine.ops.quant import quantize_kv
 
-    L, nb = caches.k.shape[0], ids.shape[0]
-    bs, h, d = caches.k.shape[2], caches.k.shape[3], caches.k.shape[4]
-    qk, sk = quantize_kv(row_k.reshape(L, nb, bs, h, d))
-    qv, sv = quantize_kv(row_v.reshape(L, nb, bs, h, d))
-    return (KVCache(caches.k.at[:, ids].set(qk),
-                    caches.v.at[:, ids].set(qv)),
+    L, _, bs, h = scales.k.shape
+    blocks = (L, ids.shape[0], bs)
+    qk, sk = quantize_kv(row_k.reshape(blocks + (h, -1)))
+    qv, sv = quantize_kv(row_v.reshape(blocks + (h, -1)))
+    return (KVCache(caches.k.at[:, ids].set(qk.reshape(blocks + (-1,))),
+                    caches.v.at[:, ids].set(qv.reshape(blocks + (-1,)))),
             KVCache(scales.k.at[:, ids].set(sk),
                     scales.v.at[:, ids].set(sv)))

@@ -344,7 +344,7 @@ class ContinuousGenerator:
         params place by the registry-declared partition rule
         (models.registry.tp_shardings — heads-axis QKV/MLP up,
         row-parallel wo/proj, replicated norms/embeddings), the block
-        pool shards its H_kv axis (scale arrays alongside on int8
+        pool shards its heads (scale arrays alongside on int8
         pools), and every pool-donating executable pins its pool
         outputs to the same sharding, so each tick stays ONE SPMD
         ragged dispatch with donation intact. Greedy streams are
@@ -447,8 +447,7 @@ class ContinuousGenerator:
         # the contract, never a silently single-device lane.
         self._tp = int(tp)
         self._tp_mesh = None
-        self._kv_pin = None     # pool payloads' NamedSharding pin
-        self._scale_pin = None  # ... and the int8 scale arrays'
+        self._kv_pin = None     # the pool's (and int8 scales') sharding pin
         if self._tp > 1:
             if device is not None:
                 raise ValueError(
@@ -614,7 +613,6 @@ class ContinuousGenerator:
                 # output sharding must EQUAL the input's or donation is
                 # wasted (and XLA free to re-lay the pool per tick).
                 self._kv_pin = self._pool.kv_sharding
-                self._scale_pin = self._pool.scale_sharding
             self._tables = np.zeros((self.n_slots, width), np.int32)
             self._row_blocks: List[List[int]] = [[] for _ in
                                                  range(self.n_slots)]
@@ -1070,8 +1068,8 @@ class ContinuousGenerator:
         sharding, so output sharding provably equals input sharding —
         donation holds and XLA never re-lays the pool mid-serve.
         Identity when tp == 1 (the compiled programs are unchanged
-        byte-for-byte). Also pins prefix-gather row caches: their H_kv
-        axis shares the same 5-dim spec."""
+        byte-for-byte). Pool and scales carry their heads on the last
+        of four axes: one spec pins both."""
         if self._kv_pin is None:
             return caches if scales is None else (caches, scales)
         wsc = jax.lax.with_sharding_constraint
@@ -1079,8 +1077,8 @@ class ContinuousGenerator:
                          wsc(caches.v, self._kv_pin))
         if scales is None:
             return caches
-        scales = KVCache(wsc(scales.k, self._scale_pin),
-                         wsc(scales.v, self._scale_pin))
+        scales = KVCache(wsc(scales.k, self._kv_pin),
+                         wsc(scales.v, self._kv_pin))
         return caches, scales
 
     def _paged_attn_fn(self, ragged: bool):
@@ -1115,15 +1113,25 @@ class ContinuousGenerator:
                     fn = functools.partial(gather_blocks_quant,
                                            dtype=self._dtype)
                 else:
-                    fn = gather_blocks
+                    fn = functools.partial(gather_blocks,
+                                           kv_heads=self.cfg.kv_heads)
                 if self._kv_pin is not None:
-                    # TP: the gathered row cache keeps the pool's H_kv
-                    # sharding, so the prefill windows that consume it
-                    # compile SPMD over the same mesh.
+                    # TP: the gathered (L, 1, S, H_kv, D) row cache
+                    # keeps the pool's head sharding, so the prefill
+                    # windows that consume it compile SPMD over the
+                    # same mesh.
+                    from jax.sharding import NamedSharding, PartitionSpec
+
                     base = fn
+                    row_pin = NamedSharding(
+                        self._tp_mesh,
+                        PartitionSpec(None, None, None,
+                                      self._kv_pin.spec[3], None))
 
                     def fn(*args, _base=base):
-                        return self._pin_pool_out(_base(*args))
+                        return jax.tree.map(
+                            lambda x: jax.lax.with_sharding_constraint(
+                                x, row_pin), _base(*args))
                 exe = self._gather_exe.setdefault(nb, jax.jit(fn))
         return exe
 
