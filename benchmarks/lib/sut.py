@@ -124,13 +124,16 @@ class Served:
 class Sampler:
     """The traced run's thread: reads the lanes' pool counters every
     `period_s` (the program only keeps their current value), and starts and
-    stops the profiler for the slice [begin, end) of time.monotonic()."""
+    stops the profiler for the slice [begin, end) of time.monotonic().
+    `traced` is the slice as it came out, on time.time(): from the
+    profiler's start having returned to its stop being called."""
 
     def __init__(self, served, trace_dir, begin, end, period_s=0.5):
         self.served = served
         self.trace_dir, self.begin, self.end = trace_dir, begin, end
         self.period_s = period_s
         self.samples = []
+        self.traced = None
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._run, daemon=True)
 
@@ -148,9 +151,10 @@ class Sampler:
                 options.python_tracer_level = 0
                 jax.profiler.start_trace(self.trace_dir,
                                          profiler_options=options)
+                self.traced = {"begin": time.time(), "end": None}
                 state = "tracing"
             elif state == "tracing" and now >= self.end:
-                jax.profiler.stop_trace()
+                self._stop_trace()
                 state = "done"
             stats = self.served.generator_stats()
             self.samples.append(
@@ -158,7 +162,13 @@ class Sampler:
                                        for node, s in stats.items()}})
             self._stop.wait(self.period_s)
         if state == "tracing":
-            jax.profiler.stop_trace()
+            self._stop_trace()
+
+    def _stop_trace(self):
+        import jax
+
+        self.traced["end"] = time.time()
+        jax.profiler.stop_trace()
 
     def stop(self):
         self._stop.set()

@@ -14,9 +14,11 @@ What one run does, in order (everything before the window is `setup_s`):
     compile cache in <checkout>/.jax_cache;
  2. register the configuration and bring up `serve_combined`: HTTP front,
     gateway, one lane a chip, weights from --seed;
- 3. decide `correct` against lib/reference.py (which also compiles the two
-    step programs the window uses), then warm the cell's own traffic for a
-    few seconds through the load generator;
+ 3. decide `correct` against the configuration's plain reference, the file
+    benchmarks/references/<reference.dialect>.py (lib/reference.py has the
+    contract; serving the sample also compiles the two step programs the
+    window uses), then warm the cell's own traffic for a few seconds
+    through the load generator;
  4. measure: the load generator, a stdlib child process, offers the cell's
     traffic for --seconds and writes one record per request;
  5. print every metric by name, then ONE last line of JSON:
@@ -39,6 +41,14 @@ The run object handed to benchmarks/layer_metrics/<metric>.py `compute(run)`:
   peaks         lib/peaks.json's entry for this device kind
   device        the `device` object of the last line
   seconds       the window's length
+  config        the configuration file's dict: the sizes a reader counts
+                operations and bytes from (lib/roofline.py has the counting)
+  cell          the cell's entry of `workloads`
+  slice         {"begin", "end"}: the traced slice on the clock of the spans'
+                `start_ts` (time.time()), from the profiler's start having
+                returned to its stop being called, so that a tick wholly
+                inside it ran wholly inside the trace; None if nothing was
+                traced
 A reader that finds nothing to read returns None and the metric is left out.
 
 Every run leaves benchmarks/out/runs/<cell>.<n>.json behind: its seed, what
@@ -90,7 +100,8 @@ def fail(message):
 def load_cell(name, bench_file):
     """The cell's entry, its configuration and traffic files, and the
     metrics BENCHMARK.json lists for it. A traffic file is looked for in
-    benchmarks/traffic/, then beside `bench_file` (the tests' own cells)."""
+    benchmarks/traffic/, then beside `bench_file` (the tests' own cells);
+    the configuration's reference in references/, the same way."""
     with open(bench_file) as f:
         bench = json.load(f)
     cell = next((w for w in bench["workloads"] if w["name"] == name), None)
@@ -104,28 +115,36 @@ def load_cell(name, bench_file):
         return [m for m in bench[kind]
                 if "workloads" not in m or name in m["workloads"]]
 
-    traffic_path = next(
-        (p for p in (os.path.join(d, "traffic", cell["traffic"] + ".json")
-                     for d in (HERE, os.path.dirname(bench_file)))
-         if os.path.exists(p)), None)
-    if traffic_path is None:
-        fail(f"no traffic file for {cell['traffic']!r}")
+    def found(kind, file_name):
+        path = next((p for p in (os.path.join(d, kind, file_name) for d in
+                                 (HERE, os.path.dirname(bench_file)))
+                     if os.path.exists(p)), None)
+        if path is None:
+            fail(f"no file {kind}/{file_name} in benchmarks/ or beside "
+                 f"{os.path.basename(bench_file)}")
+        return path
+
+    traffic_path = found("traffic", cell["traffic"] + ".json")
     with open(traffic_path) as f:
         traffic = json.load(f)
     return {"cell": cell, "config": config, "traffic": traffic,
             "traffic_path": traffic_path,
+            "reference_path": found(
+                "references", config["reference"]["dialect"] + ".py"),
             "end_to_end": listed("end_to_end"),
             "per_layer": listed("per_layer")}
 
 
-def load_reader(metric_name):
-    path = os.path.join(HERE, "layer_metrics", metric_name + ".py")
+def load_file(path, function):
+    """`function` of the Python file at `path`: a per-layer reader's
+    `compute`, a reference's `forward`. Files are found by a name that
+    BENCHMARK.json or a configuration gives, never imported by name here."""
+    stem = os.path.basename(path)[:-len(".py")]
     spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + metric_name.replace(".", "_").replace("-", "_"),
-        path)
+        "benchmark_file_" + stem.replace(".", "_").replace("-", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.compute
+    return getattr(module, function)
 
 
 # -- the device ---------------------------------------------------------------
@@ -210,7 +229,8 @@ def decide_correct(cell, served, seed):
                    == served.generate("repeat-2", short, few))
     params = served.workers[0].engine.params
     ok, details = reference.check_served(
-        params, cell["config"]["reference"], samples,
+        load_file(cell["reference_path"], "forward"), params,
+        cell["config"]["reference"], samples,
         float(spec["tolerance_in_logit_std"]),
         float(spec["min_exact_share"]), int(spec["pad_to"]))
     details["repeat_identical"] = repeat_same
@@ -362,10 +382,13 @@ def measure(cell, served, device, peaks, seed, seconds, trace):
            "pool_samples": [s for s in sampler.samples
                             if t0 <= s["t"] < t0 + seconds],
            "trace": reduced, "records": records, "peaks": peaks,
-           "device": device, "seconds": seconds}
+           "device": device, "seconds": seconds,
+           "config": cell["config"], "cell": cell["cell"],
+           "slice": sampler.traced}
     result["metrics"] = {}
     for m in cell["per_layer"]:
-        value = load_reader(m["name"])(run)
+        value = load_file(os.path.join(HERE, "layer_metrics",
+                                       m["name"] + ".py"), "compute")(run)
         if value is not None:
             result["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
         else:

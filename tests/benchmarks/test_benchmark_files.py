@@ -118,8 +118,29 @@ def test_cells_and_their_files(bench):
         assert sorted(data["reduced"]) == sorted(c["reduced"])
         for key in ("factory", "kwargs", "serving", "reference", "correct"):
             assert key in data, (c["name"], key)
+        dialect = data["reference"]["dialect"]
+        with open(os.path.join(BENCH, "references", dialect + ".py")) as f:
+            tree = ast.parse(f.read())
+        assert any(isinstance(n, ast.FunctionDef) and n.name == "forward"
+                   for n in tree.body), dialect
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert set(m.get("workloads", cells)) <= cells
+
+
+def test_a_metric_bound_to_a_mechanism_names_its_cells(bench):
+    """A per-layer metric without a `workloads` key is asked of every cell
+    a later PR adds, whatever its family. One that reads a kernel or the
+    block pool finds nothing in a cell without that mechanism, so it lists
+    the cells that have it (PERF.md, section 3)."""
+    for m in bench["per_layer"]:
+        if m["name"].startswith(("kernel.", "kv.")):
+            assert m.get("workloads"), m["name"]
+    without = [m["name"] for m in bench["per_layer"] if "workloads" not in m]
+    assert sorted(without) == [
+        "device.hbm_peak_gb", "device.idle", "device.idle_host",
+        "sched.decode_rows_per_tick", "sched.host_gap_ms",
+        "sched.prefill_tick_share", "step.compiles",
+        "step.prefill_device_ms", "step.prefill_ms"]
 
 
 def test_every_cell_reports_what_the_contract_asks(bench):
@@ -203,3 +224,26 @@ def test_peaks_table_has_the_chip_and_its_source():
     assert v5e["bf16_flops_per_s"] == 197e12
     assert v5e["hbm_bytes_per_s"] == 819e9
     assert v5e["hbm_bytes"] == 16e9 and v5e["source"]
+
+
+def test_a_cell_is_asked_for_every_metric_without_a_key(bench):
+    """run.py's own reading of the list: the `workloads` keys are the one
+    way to say which cell has which metric. A metric without a key is every
+    cell's; one with a key is the listed cells' and nobody else's."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "run_under_test", os.path.join(BENCH, "run.py"))
+    run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(run)
+    path = os.path.join(ROOT, "BENCHMARK.json")
+    for w in bench["workloads"]:
+        cell = run.load_cell(w["name"], path)
+        for kind in ("end_to_end", "per_layer"):
+            assert [m["name"] for m in cell[kind]] == [
+                m["name"] for m in bench[kind]
+                if w["name"] in m.get("workloads", [w["name"]])]
+    docqa = {m["name"] for m in
+             run.load_cell("mistral-7b-v0.2-8l.docqa", path)["per_layer"]}
+    assert "step.compiles" in docqa and "kernel.paged_attn_busy" in docqa
+    # Its every tick carries a chunk, whose FLOPs the span cannot count.
+    assert "kernel.paged_attn_roofline" not in docqa

@@ -1,5 +1,14 @@
 """Every per-layer reader on a small made-up run object: what it reads,
-its arithmetic, and that it returns nothing where there is nothing."""
+its arithmetic, and that it returns nothing where there is nothing.
+
+`OLDER_RUN` is a program before PR 25 (one lane, the older spans and
+counters only); `RUN` adds a second lane whose program marks its ticks'
+phases, counts its compilations and records the request's stages, and what
+run.py hands a reader since PR 27: the configuration, the cell and the
+traced slice. The second lane's ticks have the first's durations and
+widths, so no reading of an older metric moves."""
+
+import copy
 
 import importlib.util
 import json
@@ -20,11 +29,14 @@ def _reader(name):
     return module.compute
 
 
-def _span(op, us, **attrs):
-    return {"op": op, "duration_us": us, "attrs": attrs, "ts": 0.0}
+def _span(op, us, start_ts=None, **attrs):
+    span = {"op": op, "duration_us": us, "attrs": attrs, "ts": 0.0}
+    if start_ts is not None:
+        span["start_ts"] = start_ts
+    return span
 
 
-RUN = {
+OLDER_RUN = {
     "stats_before": {"worker_1": {"mixed": {"ticks": 10,
                                             "decode_tokens": 100}}},
     "stats_after": {"worker_1": {"mixed": {"ticks": 30,
@@ -62,7 +74,49 @@ RUN = {
 
 EMPTY = {"stats_before": {}, "stats_after": {}, "spans": {},
          "pool_samples": [], "trace": None, "records": [], "peaks": None,
-         "device": {}, "seconds": 10.0}
+         "device": {}, "seconds": 10.0, "config": {}, "cell": {},
+         "slice": None}
+
+# `host_phases` is the trace's host plane as lib/host_phases.py reduces it.
+# The slice is [100, 103): of worker_2's four ticks the first two lie wholly
+# inside it, the third ends after it and the fourth began before it.
+RUN = copy.deepcopy(OLDER_RUN)
+RUN["stats_before"]["worker_2"] = {"compile": {"count": 7, "seconds": 1.5}}
+RUN["stats_after"]["worker_2"] = {"compile": {"count": 9, "seconds": 2.5}}
+RUN["spans"]["worker_2"] = [
+    _span("mixed_step", 150000, 100.5, width=1, form_us=4000.0,
+          dispatch_us=1000.0, wait_us=140000.0, apply_us=5000.0,
+          ctx_tokens=1000),
+    _span("mixed_step", 160000, 101.0, width=1, form_us=4000.0,
+          dispatch_us=2000.0, wait_us=150000.0, apply_us=4000.0,
+          gap_us=9000.0, ctx_tokens=3000),
+    _span("mixed_step", 170000, 102.9, width=1, form_us=5000.0,
+          dispatch_us=1000.0, wait_us=158000.0, apply_us=6000.0,
+          gap_us=12000.0, ctx_tokens=5000),
+    _span("mixed_step", 400000, 99.9, width=256, form_us=6000.0,
+          dispatch_us=3000.0, wait_us=387000.0, apply_us=4000.0,
+          gap_us=10000.0, ctx_tokens=7000),
+    _span("prefill", 500000, prompt_len=600, chunks=3, starved_ticks=0,
+          starved_us=0),
+    _span("prefill", 9000000, prompt_len=900, chunks=4, starved_ticks=17,
+          starved_us=8400000),
+    _span("slot_wait", 700, parked=False),
+    _span("slot_wait", 300, parked=False),
+    _span("slot_wait", 90000, parked=True),
+    _span("generate_stream", 900000, ttft_us=480000),
+    _span("generate_stream", 800000, ttft_us=520000),
+    _span("generate_stream", 100000, segment="error"),
+]
+RUN["host_phases"] = {"idle_host_s": 0.4}
+RUN["trace"]["planes"] = 1
+RUN["slice"] = {"begin": 100.0, "end": 103.0}
+# 4000 context tokens x 2 layers x (K, V) x 2 KV heads x 16 x 2 bytes =
+# 1 024 000 bytes: 0.4 s at this made-up memory, against 0.8 s of kernel.
+RUN["config"] = {"kwargs": {"n_layers": 2, "d_model": 64, "n_heads": 4,
+                            "n_kv_heads": 2},
+                 "serving": {"dtype": "bfloat16"}}
+RUN["peaks"] = {"hbm_bytes_per_s": 2.56e6, "bf16_flops_per_s": 1e12}
+RUN["cell"] = {"name": "made-up", "chips": 2}
 
 WANT = {
     "client.ttft_p50_ms": 300.0,
@@ -76,7 +130,21 @@ WANT = {
     "kernel.paged_attn_busy": 40.0,
     "device.idle": 20.0,
     "device.hbm_peak_gb": 8.36400896,
+    # PR 25's readers of the tick's phases and the request's stages
+    "sched.host_gap_ms": 10.0,
+    "step.decode_device_ms": 152.0,
+    "step.prefill_device_ms": 390.0,
+    "sched.budget_wait_ms": 4200.0,
+    "lane.slot_wait_ms": 0.7,
+    "lane.ttft_p50_ms": 480.0,
+    "step.compiles": 2,
+    "device.idle_host": 16.0,
+    # PR 27's
+    "kernel.paged_attn_roofline": 50.0,
 }
+SINCE_PR_25 = {"sched.host_gap_ms", "step.decode_device_ms",
+               "step.prefill_device_ms", "sched.budget_wait_ms",
+               "lane.slot_wait_ms", "lane.ttft_p50_ms", "step.compiles"}
 
 
 def _listed():
@@ -102,3 +170,75 @@ def test_a_trace_in_which_no_op_ran_gives_no_device_number():
     idle = dict(RUN, trace={"busy_s": 0.0, "window_s": 0.0, "op_seconds": {}})
     assert _reader("device.idle")(idle) is None
     assert _reader("kernel.paged_attn_busy")(idle) is None
+
+
+# -- PR 25's readers (folded in from test_benchmark_layer_metrics_tracing.py) --
+
+@pytest.mark.parametrize("name", sorted(SINCE_PR_25))
+def test_reader_returns_nothing_on_a_program_without_the_marks(name):
+    """The older run object: the older spans and counters only.
+    (`device.idle_host` would go and look for a trace file on the disk; its
+    case is test_benchmark_host_phases.py's.)"""
+    assert _reader(name)(OLDER_RUN) is None
+
+
+def test_the_second_lane_moves_no_older_reading():
+    for name in sorted(set(WANT) - SINCE_PR_25):
+        if name in ("device.idle_host", "kernel.paged_attn_roofline"):
+            continue
+        assert _reader(name)(OLDER_RUN) == pytest.approx(WANT[name]), name
+
+
+def test_a_program_that_does_not_count_compilations_reads_nothing_not_zero():
+    warm = dict(RUN, stats_after={"worker_2": {"compile": {"count": 7}}},
+                stats_before={"worker_2": {"compile": {"count": 7}}})
+    assert _reader("step.compiles")(warm) == 0
+    older = dict(RUN, stats_after={"worker_1": {"mixed": {}}},
+                 stats_before={"worker_1": {"mixed": {}}})
+    assert _reader("step.compiles")(older) is None
+
+
+def test_idle_host_reads_nothing_from_a_trace_without_annotations():
+    assert _reader("device.idle_host")(dict(RUN, host_phases={})) is None
+
+
+# -- PR 27's readers -----------------------------------------------------------
+
+def test_the_roofline_counts_only_ticks_wholly_inside_the_slice():
+    reader = _reader("kernel.paged_attn_roofline")
+    wide = dict(RUN, slice={"begin": 99.0, "end": 104.0})
+    # All four ticks: 16 000 context tokens, four times the work.
+    assert reader(wide) == pytest.approx(200.0)
+    none_inside = dict(RUN, slice={"begin": 100.6, "end": 100.9})
+    assert reader(none_inside) is None
+
+
+@pytest.mark.parametrize("missing", ["slice", "peaks", "trace"])
+def test_the_roofline_reads_nothing_without_slice_peaks_or_trace(missing):
+    assert _reader("kernel.paged_attn_roofline")(
+        dict(RUN, **{missing: None})) is None
+
+
+def test_the_roofline_spreads_the_lanes_work_over_the_device_planes():
+    """Two lanes on two chips: op_seconds are seconds a plane, so the work
+    of both lanes' ticks is divided by the number of planes."""
+    two = dict(RUN, trace=dict(RUN["trace"], planes=2))
+    assert _reader("kernel.paged_attn_roofline")(two) == pytest.approx(25.0)
+
+
+def test_the_roofline_of_a_real_shape_on_the_real_peaks_is_far_under_100():
+    """A decode tick of the 36-layer MHA configuration: 32 rows at 176
+    tokens is 1.04 GB of K and V, 1.27 ms of the v5e's memory; the kernel's
+    33.0 ms a tick (PERF.md, PR 26) gives under 4 %."""
+    with open(os.path.join(BENCH, "lib", "peaks.json")) as f:
+        peaks = json.load(f)["TPU v5 lite"]
+    with open(os.path.join(BENCH, "configs", "gpt2-large.json")) as f:
+        config = json.load(f)
+    run = dict(RUN, peaks=peaks, config=config,
+               trace={"busy_s": 0.045, "window_s": 0.057, "planes": 1,
+                      "op_seconds": {"%_paged_call f32[32,20,1,64]": 0.033}},
+               spans={"worker_1": [_span("mixed_step", 50000, 101.0, width=1,
+                                         ctx_tokens=32 * 176)]})
+    share = _reader("kernel.paged_attn_roofline")(run)
+    assert share == pytest.approx(100 * (1038090240 / 819e9) / 0.033)
+    assert 3.0 < share < 5.0

@@ -1,9 +1,11 @@
 """run.py end to end on the CPU at test-only sizes (kept apart from
 benchmarks/configs/): the contract's last line, the device printed as the
-CPU it was, both loops, both dialects, and the refusals."""
+CPU it was, both loops, both attention dialects, a recurrent family that
+arrives as new files only, and the refusals."""
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -86,6 +88,56 @@ def test_traced_closed_loop_run_reports_layers_and_no_device_number(cells):
     assert line["device"]["platform"] == "cpu"
     # Every metric is also printed by name and unit on an earlier line.
     assert "step.decode_ms = " in proc.stdout
+
+
+def test_a_family_the_harness_has_no_word_for_runs_from_new_files_only(cells):
+    """The registry's recurrent test model on the slab pool: a configuration
+    file, a reference found beside the benchmark file, an entry. Every
+    metric listed for the cell is read but what only a device trace gives:
+    the readers bound to the paged kernel and the block pool name their
+    cells and are not asked."""
+    proc = _run("--benchmark-file", CELLS, "--workload", "small.slab",
+                "--seed", str(2**31 + 29), "--seconds", "2", "--trace", "1")
+    line = _last_line(proc)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] >= 3
+    wanted = _listed(cells, "per_layer", "small.slab")
+    assert not {"kv.blocks_peak_share", "kernel.paged_attn_busy"} & wanted
+    assert set(line["metrics"]) == wanted - {"device.idle",
+                                             "device.hbm_peak_gb"}
+    said = [ln for ln in proc.stderr.splitlines()
+            if "found nothing to read" in ln]
+    assert sorted(ln.split()[1] for ln in said) == ["device.hbm_peak_gb",
+                                                    "device.idle"]
+    details = json.loads(next(ln for ln in proc.stdout.splitlines()
+                              if ln.startswith('{"correct"')))
+    assert details["positions"] == 24 and details["exact_share"] == 1.0
+
+
+def test_a_reference_with_a_dropped_term_reads_not_correct():
+    """The same served recurrence, judged by the control beside the
+    benchmark file: the run goes to its end and says `correct` false."""
+    line = _last_line(_run("--benchmark-file", CELLS, "--workload",
+                           "small.slab-dropped", "--seed", str(2**31 + 29),
+                           "--seconds", "1", "--trace", "0"))
+    assert line["correct"] is False
+    assert line["failed"] == 0 and line["attempted"] >= 3
+
+
+def test_a_configuration_whose_reference_file_is_missing_is_refused(tmp_path):
+    with open(CELLS) as f:
+        cells = json.load(f)
+    with open(os.path.join(ROOT, cells["configs"][0]["file"])) as f:
+        config = json.load(f)
+    config["reference"]["dialect"] = "no-such-dialect"
+    (tmp_path / "config.json").write_text(json.dumps(config))
+    cells["configs"][0]["file"] = str(tmp_path / "config.json")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(cells))
+    shutil.copytree(os.path.join(DATA, "traffic"), tmp_path / "traffic")
+    proc = _run("--benchmark-file", str(tmp_path / "BENCHMARK.json"),
+                "--workload", "small.open", timeout=60)
+    assert proc.returncode != 0 and '"correct"' not in proc.stdout
+    assert "references/no-such-dialect.py" in proc.stderr
 
 
 def test_no_accelerator_means_no_result():
