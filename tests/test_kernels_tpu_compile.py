@@ -58,6 +58,15 @@ def test_every_kernel_site_compiles_for_v5e(v5e_devices, model):
         "quant_ragged"}
 
 
+def test_the_latent_read_compiles_for_v5e_at_both_widths(v5e_devices):
+    names = []
+    for model in kernel_check.LATENT_MODELS:
+        for case in kernel_check.kernel_cases(model, interpret=False):
+            kernel_check.compile_for_topology(case, v5e_devices[0])
+            names.append(case.name)
+    assert names == ["moonlight/latent/W1", "moonlight/latent/W256"]
+
+
 def _mixed_tick(cfg, attn_fn):
     """The mixed step as the scheduler traces it, sampling left out:
     (params, caches, tables, tokens, pos0, qlen) -> (logits, caches)."""
@@ -187,6 +196,77 @@ def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
                            for x in jax.tree.leaves(params["blocks"]))
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp - cast_weights < 2 * whole * pool.dtype.itemsize, temp
+
+
+@pytest.mark.parametrize("width", [1, 256])
+def test_latent_mixed_step_copies_neither_the_pool_nor_a_bank(v5e_devices,
+                                                             width):
+    """The Moonlight cell's mixed step at its serving shapes (shapes only),
+    latent pool donated, compiled for one v5e: no `copy`, `slice`,
+    `dynamic-slice` or `dynamic-update-slice` whose result is the pool, a
+    layer of it, the stacked expert banks or one layer's bank (the grouped
+    product takes the bank whole; a slice would be 1.2 GB a layer and
+    tick), and temporaries of a few MB: the step runs over the tick's
+    tokens (68 tiles of 8), not over 32 x 256 slots."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.moonlight import moonlight_step_rows_ragged
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops.latent_attention import latent_attention
+    from tpu_engine.runtime.kv_blocks import BlockPool
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "moonlight-16b-a3b-7l.json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    assert width in (1, serving["gen_prefill_chunk"])
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg = spec.config
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    small = BlockPool(cfg, 2, bs, jnp.bfloat16).caches
+    pool = KVCache(*(placed(jax.ShapeDtypeStruct(
+        (x.shape[0], serving["gen_kv_blocks"]) + x.shape[2:], x.dtype))
+        for x in small))
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+    n_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    assert 8.5e9 < n_bytes < 8.6e9
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return moonlight_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=functools.partial(latent_attention, interpret=False),
+            sample_slot=jnp.zeros_like(pos0),
+            max_tokens=serving["gen_prefill_chunk"] + rows)
+
+    def host(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+        params, pool, host(rows, -(-cfg.max_seq // bs)), host(rows, width),
+        host(rows), host(rows)).compile()
+    hlo = compiled.as_text()
+    assert "mla_latent_read" in hlo and "ragged-dot" in hlo
+    banks = jax.tree.leaves(params["moe"]["mlp"]["experts"])
+    sizes = set()
+    for x in list(pool) + banks:
+        sizes |= {math.prod(x.shape), math.prod(x.shape[1:])}
+    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
+                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
+    moved = [(op, dims) for dims, op in movers.findall(hlo)
+             if math.prod(map(int, dims.split(","))) in sizes]
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
 
 
 def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):

@@ -1,0 +1,369 @@
+"""The Moonlight family at a small size on the CPU: the latent pool and its
+absorbed read against the full forward, experts routed without drops (a
+row never sees its tick-mates, padding reaches no expert), the start-up
+fences of the kv_latent family, and what the tick counts."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_engine.models.moonlight import (
+    MoonlightConfig,
+    moonlight_apply,
+    moonlight_step_rows_ragged,
+)
+from tpu_engine.models.registry import (
+    FAMILY_CAPABILITIES,
+    _ensure_builtin_models_imported,
+    create_model,
+)
+from tpu_engine.ops import latent_attention as la
+from tpu_engine.ops import moe
+from tpu_engine.runtime.kv_blocks import BlockPool, dense_block_bytes
+from tpu_engine.runtime.scheduler import ContinuousGenerator
+
+BS = 16
+
+
+@pytest.fixture(scope="module")
+def spec():
+    _ensure_builtin_models_imported()
+    return create_model("moonlight-small-test")
+
+
+@pytest.fixture(scope="module")
+def params(spec):
+    return jax.jit(spec.init)(jax.random.PRNGKey(3))
+
+
+def _step(spec, rows, width, **kw):
+    cfg = spec.config
+
+    def step(params, caches, tables, tokens, pos0, qlen):
+        return moonlight_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            dtype=jnp.float32, **kw)
+
+    return jax.jit(step)
+
+
+def _tables(rows, blocks_each):
+    """Row r owns blocks 1 + r * blocks_each ... (block 0 is null)."""
+    return jnp.asarray(1 + np.arange(rows * blocks_each, dtype=np.int32)
+                       .reshape(rows, blocks_each))
+
+
+# -- registry, configuration, pool --------------------------------------------
+
+def test_family_capabilities_and_stated_widths(spec):
+    cfg = spec.config
+    assert isinstance(cfg, MoonlightConfig)
+    assert spec.state_family == "kv_latent"
+    assert spec.capabilities == FAMILY_CAPABILITIES["kv_latent"]
+    for missing in ("kv_quantize", "kv_host_tier", "migration", "handoff",
+                    "tensor_parallel", "spec_decode", "two_path"):
+        assert not spec.supports(missing)
+    assert spec.supports("mixed_step") and spec.supports("prefix_sharing")
+    assert spec.tp_rule.startswith("unshardable")
+    # A head's width is what the model states, not d_model / n_heads.
+    assert cfg.d_model // cfg.n_heads == 16 and cfg.d_head == 16 + 8
+    full = create_model("moonlight").config
+    assert (full.d_head, full.kv_lanes, full.n_moe_layers) == (
+        192, (128, 512), 26)
+    assert full.attn_scale == pytest.approx(192 ** -0.5)
+
+
+def test_the_pool_is_sized_by_the_lanes_the_model_states(spec):
+    cfg = spec.config
+    pool = BlockPool(cfg, 5, BS, jnp.float32)
+    assert pool.caches.k.shape == (3, 5, BS, la.PE_LANES)
+    assert pool.caches.v.shape == (3, 5, BS, cfg.kv_lora_rank)
+    assert dense_block_bytes(cfg, BS, jnp.float32) == 3 * BS * (128 + 32) * 4
+    gpt2 = create_model("gpt2-small-test").config
+    assert gpt2.kv_lanes == (64, 64)
+    assert dense_block_bytes(gpt2, BS, jnp.bfloat16) == 2 * 2 * BS * 64 * 2
+    stated = dataclasses.replace(gpt2, head_dim=24)
+    assert stated.d_head == 24 and stated.kv_lanes == (96, 96)
+
+
+def test_init_makes_the_stated_type_directly():
+    _ensure_builtin_models_imported()
+    spec = create_model("moonlight-small-test", param_dtype="bfloat16")
+    shapes = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
+    flat = {"/".join(str(getattr(k, "key", k)) for k in path): leaf
+            for path, leaf in jax.tree_util.tree_leaves_with_path(shapes)}
+    for name, leaf in flat.items():
+        plain = (name.endswith(("scale", "bias")) or "router" in name)
+        assert leaf.dtype == (jnp.float32 if plain else jnp.bfloat16), name
+    assert flat["moe/mlp/experts/gate_up"].shape == (2, 8, 64, 64)
+    assert flat["dense/mlp/gate/kernel"].shape == (1, 64, 128)
+    # One jit, no float32 intermediate of a bank: nothing wider than
+    # bfloat16 of a bank's shape anywhere in the program.
+    text = jax.jit(spec.init).lower(jax.random.PRNGKey(0)).as_text()
+    assert "tensor<2x8x64x64xf32>" not in text
+    assert "tensor<8x64x64xf32>" not in text
+
+
+# -- attention -------------------------------------------------------------------
+
+def test_absorbed_and_expanded_attention_agree(spec, params):
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        0, 256, size=(2, 24)), jnp.int32)
+    expanded = moonlight_apply(params, tokens, spec.config,
+                               dtype=jnp.float32)
+    absorbed = moonlight_apply(params, tokens, spec.config,
+                               dtype=jnp.float32, absorbed=True)
+    assert np.abs(expanded - absorbed).max() < 1e-5 * np.abs(expanded).max()
+
+
+@pytest.mark.parametrize("q_lens, kw", [
+    ((1, 1, 1), {}),
+    ((1, 7, 16, 17), {}),
+    ((40, 1, 3), {"table_len": 5, "n_blocks": 12, "seed": 3}),
+    # 16 heads: tiles of 8 slots; at most 44 valid slots bound the tiles
+    ((1, 40, 3, 0, 0, 0), {"n_heads": 16, "table_len": 5, "n_blocks": 12,
+                           "max_tokens": 44, "seed": 4}),
+])
+def test_latent_kernel_in_interpret_mode_equals_its_xla_reference(q_lens,
+                                                                  kw):
+    assert la.parity_check(q_lens, interpret=True, **kw) < 1e-5
+
+
+def test_prefill_then_decode_through_the_latent_pool_equals_the_forward(
+        spec, params):
+    """Two rows: a 37-token prompt in three chunks of 16 beside a 5-token
+    one, then six decode steps each, teacher-forced: every logit the step
+    returns equals the full forward's at that position."""
+    cfg = spec.config
+    rng = np.random.default_rng(1)
+    seqs = [rng.integers(0, 256, size=n).astype(np.int32)
+            for n in (37 + 6, 5 + 6)]
+    prompt = [37, 5]
+    full = [np.asarray(moonlight_apply(params, jnp.asarray(s)[None], cfg,
+                                       dtype=jnp.float32)[0]) for s in seqs]
+    pool = BlockPool(cfg, 9, BS, jnp.float32)
+    caches, tables = pool.caches, _tables(2, 4)
+    wide, narrow = _step(spec, 2, 16), _step(spec, 2, 1)
+    done = [0, 0]
+    while any(d < p for d, p in zip(done, prompt)):
+        qlen = [min(16, p - d) for d, p in zip(done, prompt)]
+        tokens = np.zeros((2, 16), np.int32)
+        for r in range(2):
+            tokens[r, :qlen[r]] = seqs[r][done[r]:done[r] + qlen[r]]
+        logits, caches, _ = wide(params, caches, tables, jnp.asarray(tokens),
+                                 jnp.asarray(done, jnp.int32),
+                                 jnp.asarray(qlen, jnp.int32))
+        for r in range(2):
+            if not qlen[r]:
+                continue
+            got = np.asarray(logits[r, :qlen[r]])
+            want = full[r][done[r]:done[r] + qlen[r]]
+            assert np.abs(got - want).max() < 2e-4 * np.abs(want).max()
+            done[r] += qlen[r]
+    for _ in range(6):
+        tokens = np.asarray([[seqs[r][done[r]]] for r in range(2)], np.int32)
+        logits, caches, _ = narrow(
+            params, caches, tables, jnp.asarray(tokens),
+            jnp.asarray(done, jnp.int32), jnp.ones((2,), jnp.int32))
+        for r in range(2):
+            want = full[r][done[r]]
+            assert np.abs(np.asarray(logits[r, 0]) - want).max() \
+                < 2e-4 * np.abs(want).max()
+            done[r] += 1
+
+
+# -- the expert layer ----------------------------------------------------------
+
+def _moe_case(n=24, d=32, f=16, e=8, k=2, layers=3):
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    x = jax.random.normal(keys[0], (n, d))
+    router = {"kernel": jax.random.normal(keys[1], (d, e)),
+              "bias": 0.5 * jax.random.normal(keys[2], (e,))}
+    bank = {"gate_up": jax.random.normal(keys[3], (layers * e, d, 2 * f)) / 6,
+            "down": jax.random.normal(keys[4], (layers * e, f, d)) / 4}
+    return x, router, bank, e, k, f
+
+
+def _dense_experts(x, valid, experts, weights, bank, first, f, held=None):
+    out = np.zeros(x.shape, np.float32)
+    for n in range(x.shape[0]):
+        for j in range(experts.shape[1]):
+            e = int(experts[n, j])
+            if not valid[n] or (held and not held[0] <= e < sum(held)):
+                continue
+            gu = np.asarray(x[n]) @ np.asarray(bank["gate_up"][first + e])
+            h = np.asarray(jax.nn.silu(gu[:f])) * gu[f:]
+            out[n] += float(weights[n, j]) * (
+                h @ np.asarray(bank["down"][first + e]))
+    return out
+
+
+@pytest.mark.parametrize("held, max_tokens", [(None, None), (None, 16),
+                                              ((2, 4), None)])
+def test_routed_experts_equal_every_expert_applied_and_masked(held,
+                                                              max_tokens):
+    x, router, bank, e, k, f = _moe_case()
+    valid = np.arange(x.shape[0]) % 3 != 0              # 16 valid slots
+    experts, weights = moe.sigmoid_topk_route(x, router, k, 2.5)
+    y, rows = jax.jit(lambda *a: moe.routed_experts(
+        *a, first_group=jnp.int32(e), n_experts=e, held=held,
+        max_tokens=max_tokens, dtype=jnp.float32))(
+        x, jnp.asarray(valid), experts, weights, bank)
+    want = _dense_experts(x, valid, np.asarray(experts), np.asarray(weights),
+                          bank, e, f, held)
+    assert np.abs(np.asarray(y) - want).max() < 1e-5
+    assert not np.asarray(y)[~valid].any()
+    taken = np.asarray(experts)[valid].reshape(-1)
+    if held:
+        taken = taken[(taken >= held[0]) & (taken < sum(held))]
+    assert np.array_equal(np.asarray(rows), np.bincount(taken, minlength=e))
+
+
+def test_the_selection_bias_chooses_and_does_not_weigh():
+    x, router, _, e, k, _ = _moe_case()
+    experts, weights = moe.sigmoid_topk_route(x, router, k, 2.446)
+    scores = jax.nn.sigmoid(x @ router["kernel"])
+    assert np.array_equal(np.asarray(experts), np.asarray(
+        jax.lax.top_k(scores + router["bias"], k)[1]))
+    unbiased = jax.lax.top_k(scores, k)[1]
+    assert not np.array_equal(np.asarray(experts), np.asarray(unbiased))
+    picked = np.take_along_axis(np.asarray(scores), np.asarray(experts), 1)
+    assert np.allclose(np.asarray(weights),
+                       picked / picked.sum(-1, keepdims=True) * 2.446,
+                       rtol=1e-5)
+
+
+@pytest.mark.parametrize("width", [1, 8])
+def test_a_row_s_logits_are_bit_identical_whatever_its_tick_mates(
+        spec, params, width):
+    """The no-drop contract: row 5 alone in a 32-row tick, and the same
+    row among 31 others, through the same compiled step."""
+    cfg = spec.config
+    rng = np.random.default_rng(2)
+    rows = 32
+    step = _step(spec, rows, width)
+    tables = _tables(rows, 2)
+    tokens = rng.integers(0, 256, size=(rows, width)).astype(np.int32)
+    pos0 = rng.integers(0, BS, size=rows).astype(np.int32)
+    pool = BlockPool(cfg, 1 + rows * 2, BS, jnp.float32)
+    filled = jax.tree.map(
+        lambda a: jax.random.normal(jax.random.PRNGKey(7), a.shape), pool.caches)
+    full_q = np.full((rows,), width, np.int32)
+    alone_q = np.where(np.arange(rows) == 5, width, 0).astype(np.int32)
+    out = {}
+    for name, qlen in (("alone", alone_q), ("full", full_q)):
+        logits, _, counts = step(params, filled, tables, jnp.asarray(tokens),
+                                 jnp.asarray(pos0), jnp.asarray(qlen))
+        out[name] = (np.asarray(logits[5]), np.asarray(counts))
+    assert np.array_equal(out["alone"][0], out["full"][0])
+    assert out["alone"][1].sum() == width * cfg.top_k * cfg.n_moe_layers
+    assert out["full"][1].sum() == rows * width * cfg.top_k * cfg.n_moe_layers
+
+
+def test_padding_slots_reach_no_expert_and_no_counter(spec, params):
+    cfg = spec.config
+    rows, width = 4, 8
+    step = _step(spec, rows, width, max_tokens=12)
+    tables = _tables(rows, 2)
+    pool = BlockPool(cfg, 1 + rows * 2, BS, jnp.float32)
+    qlen = np.asarray([8, 3, 0, 1], np.int32)
+    pos0 = np.asarray([0, 4, 0, 9], np.int32)
+    rng = np.random.default_rng(3)
+    tokens = rng.integers(0, 256, size=(rows, width)).astype(np.int32)
+    other = tokens.copy()
+    pad = np.arange(width)[None, :] >= qlen[:, None]
+    other[pad] = rng.integers(0, 256, size=int(pad.sum()))
+    got = [step(params, pool.caches, tables, jnp.asarray(t),
+                jnp.asarray(pos0), jnp.asarray(qlen)) for t in (tokens, other)]
+    (logits_a, caches_a, rows_a), (logits_b, caches_b, rows_b) = got
+    assert int(rows_a.sum()) == int(qlen.sum()) * cfg.top_k * cfg.n_moe_layers
+    assert np.array_equal(np.asarray(rows_a), np.asarray(rows_b))
+    valid = ~pad
+    assert np.array_equal(np.asarray(logits_a)[valid],
+                          np.asarray(logits_b)[valid])
+    # Padding wrote into the null block only.
+    for a, b in zip(caches_a, caches_b):
+        assert np.array_equal(np.asarray(a[:, 1:]), np.asarray(b[:, 1:]))
+
+
+# -- the scheduler: what runs, what is counted, what is refused --------------
+
+def test_the_mixed_tick_serves_it_and_counts_its_experts(spec, params):
+    from tpu_engine.utils.tracing import SpanRecorder
+
+    tracer = SpanRecorder(capacity=4096)
+    gen = ContinuousGenerator(spec, params=params, n_slots=4,
+                              dtype="float32", kv_block_size=BS,
+                              mixed_step=True, prefill_chunk=16)
+    gen.tracer, gen.trace_node = tracer, "lane"
+    try:
+        rng = np.random.default_rng(0)
+        prompts = [[int(t) for t in rng.integers(1, 256, size=n)]
+                   for n in (40, 5, 23)]
+        outs = gen.generate(prompts, max_new_tokens=6)
+        stats = gen.stats()
+    finally:
+        gen.stop()
+    cfg = spec.config
+    for prompt, out in zip(prompts, outs):
+        seq = list(prompt)
+        for _ in range(6):
+            logits = moonlight_apply(params, jnp.asarray([seq]), cfg,
+                                     dtype=jnp.float32)
+            seq.append(int(jnp.argmax(logits[0, -1])))
+        assert [int(t) for t in out] == seq[len(prompt):]
+    mixed, counted = stats["mixed"], stats["moe"]
+    fed = mixed["prefill_tokens"] + mixed["decode_tokens"]
+    assert counted["assignments"] == fed * cfg.top_k * cfg.n_moe_layers
+    by_expert = np.asarray(counted["rows_by_expert"])
+    assert by_expert.shape == (cfg.n_moe_layers, cfg.n_routed)
+    assert by_expert.sum() == counted["assignments"]
+    assert 0 < counted["experts_touched"] <= (
+        mixed["ticks"] * cfg.n_moe_layers * cfg.n_routed)
+    spans = [s for s in tracer.snapshot() if s["op"] == "mixed_step"]
+    assert len(spans) == mixed["ticks"]
+    assert sum(s["attrs"]["moe_assignments"] for s in spans) \
+        == counted["assignments"]
+    assert sum(s["attrs"]["moe_experts_touched"] for s in spans) \
+        == counted["experts_touched"]
+    assert all("ctx_tokens" in s["attrs"] for s in spans)
+    assert "kv_pool" in stats
+
+
+@pytest.mark.parametrize("kwargs, error, message", [
+    ({"mixed_step": False}, ValueError,
+     "served by the mixed tick over the block pool only"),
+    ({"kv_block_size": 0}, ValueError,
+     "served by the mixed tick over the block pool only"),
+    ({"kv_quantize": "int8"}, ValueError,
+     "kv_quantize needs the 'kv_quantize' capability"),
+    ({"kv_host_blocks": 8}, ValueError,
+     "kv_host_blocks needs the 'kv_host_tier' capability"),
+    ({"spec_k": 2}, ValueError,
+     "spec_k needs the 'spec_decode' capability"),
+    ({"tp": 2}, RuntimeError, "cannot serve tensor-parallel"),
+])
+def test_what_the_latent_pool_cannot_do_is_refused_at_start_up(
+        spec, params, kwargs, error, message):
+    base = {"n_slots": 2, "dtype": "float32", "kv_block_size": BS,
+            "mixed_step": True, "prefill_chunk": 16}
+    with pytest.raises(error, match=message):
+        ContinuousGenerator(spec, params=params, **{**base, **kwargs})
+
+
+def test_the_chain_wire_format_is_refused_by_name(spec, params):
+    gen = ContinuousGenerator(spec, params=params, n_slots=2,
+                              dtype="float32", kv_block_size=BS,
+                              mixed_step=True, prefill_chunk=16)
+    try:
+        refusal = "needs the 'migration' capability"
+        assert refusal in gen.export_row("nobody")["reason"]
+        assert refusal in gen.export_prefix([1] * 32)["reason"]
+        with pytest.raises(ValueError, match=refusal):
+            gen.submit_import({"prompt": [1], "emitted": [], "pos": 1,
+                               "tok": 1, "max_new": 1, "chain": {}})
+    finally:
+        gen.stop()

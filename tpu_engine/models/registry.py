@@ -25,7 +25,14 @@ from typing import Callable, Dict, List, Optional, Tuple
 # SINGLE-TICK rows in the continuous scheduler's shared slot pool
 # (DESIGN.md "Unified stateless serving"), so the family has no
 # generation lane but is a first-class scheduler citizen, not a side
-# path.
+# path. "kv_latent": a kv_paged chain whose blocks hold ONE latent vector
+# and one shared rope key a token and layer (models.moonlight) and whose
+# FFN routes experts inside the tick. It is served by the mixed tick
+# alone; what the latent pool cannot do yet is ABSENT here and refused
+# at start-up (runtime.scheduler): int8 KV scales are per KV head,
+# `--tp` shards H_kv (here 1), the host tier and the chain wire format
+# (migration, handoff, prefix fetch) carry a K and a V of equal width,
+# speculative verify and the two-path prefill have no latent read.
 FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
     "kv_paged": ("generate", "two_path", "mixed_step", "spec_decode",
                  "paged_kv", "prefix_sharing", "kv_quantize",
@@ -34,6 +41,8 @@ FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
     "state_slab": ("generate", "two_path", "mixed_step", "migration",
                    "handoff", "oneshot_rows"),
     "stateless": ("oneshot_rows",),
+    "kv_latent": ("generate", "mixed_step", "paged_kv", "prefix_sharing",
+                  "oneshot_rows"),
 }
 
 # -- tensor-parallel partition rules ------------------------------------------
@@ -235,7 +244,7 @@ class ModelSpec:
             # rank heuristic.
             rule = getattr(self.config, "tp_partition_rule", None)
             if rule is None:
-                if self.state_family == "kv_paged":
+                if self.state_family in ("kv_paged", "kv_latent"):
                     rule = "transformer"
                 elif self.state_family == "state_slab":
                     # Defensive default for undeclared recurrent models:
@@ -299,6 +308,6 @@ def _ensure_builtin_models_imported():
 
     from tpu_engine.models import mlp, resnet  # noqa: F401
 
-    for optional in ("bert", "gpt2", "llama", "yolo", "ssd"):
+    for optional in ("bert", "gpt2", "llama", "yolo", "ssd", "moonlight"):
         if importlib.util.find_spec(f"tpu_engine.models.{optional}") is not None:
             importlib.import_module(f"tpu_engine.models.{optional}")

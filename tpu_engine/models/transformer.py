@@ -75,14 +75,25 @@ class TransformerConfig:
     n_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
+    # A head's width where the model states one (None: d_model / n_heads).
+    head_dim: Optional[int] = None
 
     @property
     def d_head(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_dim or self.d_model // self.n_heads
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def kv_lanes(self) -> Tuple[int, int]:
+        """Lanes a token occupies, a layer, in the block pool's two
+        tensors: what `runtime.kv_blocks.BlockPool` and its byte
+        accounting size a block by. Here every KV head's key and every KV
+        head's value; a latent-attention family states its own
+        (models.moonlight)."""
+        return (self.kv_heads * self.d_head,) * 2
 
     @property
     def moe(self):
@@ -108,7 +119,7 @@ def _block_init(key, cfg: TransformerConfig):
     out = {
         "ln1": _norm_init(cfg),
         "attn": mha_init(k_attn, cfg.d_model, cfg.n_heads,
-                         n_kv_heads=cfg.n_kv_heads),
+                         d_head=cfg.head_dim, n_kv_heads=cfg.n_kv_heads),
         "ln2": _norm_init(cfg),
     }
     if cfg.n_experts > 0:
@@ -438,7 +449,11 @@ def _write_pool(cache_kv, layer, blk, off, k, v):
     """Scatter new-token K/V — (..., H_kv, D), one vector per leading
     index — into layer `layer` of the paged pool at (blk, off), in place
     on the WHOLE pool tensors: (L, NB, bs, H_kv*D), head h in lanes
-    [h*D, (h+1)*D). A QUANTIZED pool (cache_kv = (ck, cv, ks, vs), int8
+    [h*D, (h+1)*D). A LATENT pool (models.moonlight) is written through
+    here too: `k` the rotated shared rope key padded to its lane tile,
+    `v` the normalised latent, one "head" each, of different widths (the
+    two tensors' last axes are `cfg.kv_lanes`). A QUANTIZED pool
+    (cache_kv = (ck, cv, ks, vs), int8
     payloads and (L, NB, bs, H_kv) f32 scales) quantizes HERE, exactly
     once: each (kv-head) vector gets its own scale, so the write never
     touches (or is constrained by) neighbours already in the block."""

@@ -3,7 +3,9 @@
 One list of cases (`kernel_cases`) covers the flash kernel (forward at
 every prompt bucket, backward) and the paged read paths (decode, ragged
 at q_len 1 and at the prefill-chunk width, both int8 variants) at the
-head geometry of a registered model. Two consumers:
+head geometry of a registered model; for a latent-attention model
+(`LATENT_MODELS`) it is the latent read at both widths instead. Two
+consumers:
 
 - `python -m tpu_engine.ops.kernel_check` — chip_smoke.py's kernel
   phase: on the attached TPU, compile every case with `interpret=False`
@@ -29,6 +31,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from tpu_engine.ops import latent_attention as la
 from tpu_engine.ops import paged_attention as pa
 from tpu_engine.ops.attention import dot_product_attention
 from tpu_engine.ops.flash import flash_attention
@@ -37,6 +40,8 @@ from tpu_engine.ops.flash import flash_attention
 # serving model chip_smoke.py launches; llama is the grouped case
 # (32 query heads over 4 KV heads).
 MODELS = ("gpt2", "llama")
+# The kv_latent family: one kernel, the absorbed read over the latent pool.
+LATENT_MODELS = ("moonlight",)
 # Serving shapes: the smoke's launch (--kv-block-size 16, 8 decode slots,
 # max_seq 1024 -> 64-block tables over the auto-sized 513-block pool,
 # --gen-prefill-chunk 256) and the scheduler's prompt buckets.
@@ -135,8 +140,37 @@ def _paged_cases(model: str, geo: dict, interpret: bool):
                          lambda workload=workload: workload()[0], check)
 
 
+def _latent_cases(model: str, interpret: bool):
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+
+    _ensure_builtin_models_imported()
+    cfg = create_model(model).config
+    kernel = functools.partial(la.latent_attention, scale=cfg.attn_scale,
+                               interpret=interpret)
+    mixed = (1, CHUNK, 130, BLOCK_SIZE + 1, 1, 1, 77, CHUNK)
+    for label, q_lens in (("latent/W1", (1,) * ROWS),
+                          (f"latent/W{CHUNK}", mixed)):
+        workload = functools.partial(
+            la.parity_workload, q_lens, n_heads=cfg.n_heads,
+            latent=cfg.kv_lora_rank, rope=cfg.qk_rope,
+            block_size=BLOCK_SIZE, n_blocks=N_BLOCKS, table_len=TABLE_LEN,
+            dtype=jnp.bfloat16)
+
+        def check(out, operands):
+            return la.reference_error(out, operands, cfg.attn_scale)
+
+        yield KernelCase(f"{model}/{label}", kernel,
+                         workload, check)
+
+
 def kernel_cases(model: str, interpret: bool = False):
     """Every Pallas kernel site at `model`'s registry geometry."""
+    if model in LATENT_MODELS:
+        yield from _latent_cases(model, interpret)
+        return
     geo = _geometry(model)
     yield from _flash_cases(model, geo, interpret)
     yield from _paged_cases(model, geo, interpret)
@@ -150,8 +184,9 @@ def compile_for_topology(case: KernelCase, device) -> None:
     from jax.sharding import SingleDeviceSharding
 
     sharding = SingleDeviceSharding(device)
-    shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
-              for x in jax.eval_shape(case.operands)]
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        jax.eval_shape(case.operands))
     jax.jit(case.kernel).lower(*shapes).compile()
 
 
@@ -165,7 +200,7 @@ def main() -> int:
         return 1
     enable_compilation_cache()
     worst, failed = 0.0, []
-    for model in MODELS:
+    for model in MODELS + LATENT_MODELS:
         for case in kernel_cases(model, interpret=False):
             t0 = time.monotonic()
             operands = case.operands()

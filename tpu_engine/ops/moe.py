@@ -10,9 +10,12 @@ TPU-first design (Mesh-TensorFlow/GShard style, static shapes throughout):
   renormalized weights.
 - **Dispatch/combine as einsums**: tokens route via a dense one-hot
   dispatch tensor (B·T, E, C) built with capacity-slot assignment
-  (cumsum over the token order per expert, overflow dropped — the
-  standard capacity-factor contract). No gather/scatter, no dynamic
-  shapes: everything lowers to MXU matmuls XLA can shard.
+  (cumsum over the token order per expert, overflow DROPPED — the
+  capacity-factor contract of training). No gather/scatter, no dynamic
+  shapes: everything lowers to MXU matmuls XLA can shard. A dropped
+  token's output depends on which other tokens shared its batch, so this
+  path is for the models that name it (`TransformerConfig.n_experts`),
+  never for a served model whose rows must not see their tick-mates.
 - **Expert parallelism**: expert FFN params are stacked on a leading E
   axis and sharded `P("expert")`; under jit the dispatch einsum's expert
   dim shards the same way, so XLA inserts the all-to-all over ICI —
@@ -20,6 +23,19 @@ TPU-first design (Mesh-TensorFlow/GShard style, static shapes throughout):
 
 `moe_apply` is exact w.r.t. its single-device evaluation: sharding the
 expert axis changes placement, not math (tests assert equality).
+
+**The served path drops nothing** (`routed_experts`, models.moonlight):
+sigmoid scores with a selection bias, top-k weights normalised and
+scaled (`sigmoid_topk_route`), the tick's VALID (token, expert) pairs
+sorted by expert (a stable sort, so a token's own pairs keep their
+order whatever its tick-mates), one grouped matrix product over the
+experts held (`jax.lax.ragged_dot`: on a TPU XLA lowers it to a Mosaic
+grouped matmul that visits only the row tiles and expert banks in
+use), SwiGLU experts, weighted scatter-add back. Padding slots form no
+pair, reach no expert and count in no load. The bank is handed over
+WHOLE, all layers' experts on one leading axis, with the layer's
+offset into it: a slice of a layer's bank would be copied for the
+kernel's operand, 1.2 GB a layer and tick at Moonlight's widths.
 """
 
 from __future__ import annotations
@@ -152,3 +168,63 @@ def shard_moe_params(params, mesh, axis: str = "expert"):
     flat, tree = jax.tree_util.tree_flatten_with_path(params)
     shardings = [spec(pl) for pl in flat]
     return jax.tree_util.tree_unflatten(tree, shardings)
+
+
+# -- the served path: no drop --------------------------------------------------
+
+def sigmoid_topk_route(x, router, top_k: int, scale: float):
+    """DeepSeek-V3 routing without groups. x: (N, d); router: {"kernel"
+    (d, E), "bias" (E,)} float32, the bias being the selection bias
+    (`e_score_correction_bias`). Scores are sigmoids in float32; the top
+    `top_k` of score + bias are CHOSEN, the weights are the chosen
+    scores themselves (not biased), normalised to sum one and times
+    `scale`. Returns (experts (N, k) int32, weights (N, k) float32)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router["kernel"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(scores + router["bias"], top_k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scale
+    return experts.astype(jnp.int32), weights
+
+
+def routed_experts(x, valid, experts, weights, bank, *, first_group,
+                   n_experts: int, held=None, max_tokens=None,
+                   dtype=jnp.bfloat16):
+    """Apply each valid token's chosen experts and sum them, weighted.
+
+    x: (N, d); valid: (N,) bool, padding slots False; experts, weights:
+    (N, k) from the router; bank: {"gate_up" (G, d, 2f), "down" (G, f, d)}
+    with G >= n_experts groups, this layer's expert e at group
+    `first_group + e` (a traced scalar: the bank of every layer, whole).
+    `held` = (first, count): the experts this shard holds (default all);
+    a pair whose expert lies outside is another shard's and forms no row
+    here. `max_tokens`: a static bound on how many slots can be valid
+    (the scheduler's token budget plus its rows); it sizes the sorted
+    pair list, so padding costs no gather either. Returns (y (N, d)
+    float32, rows (n_experts,) int32: the rows each expert took)."""
+    n, k = experts.shape
+    first, count = held or (0, n_experts)
+    mine = (valid[:, None] & (experts >= first) & (experts < first + count))
+    # Pairs that form no row sort behind every expert.
+    eid = jnp.where(mine, experts, n_experts).reshape(-1)
+    pairs = min(n, max_tokens or n) * k
+    order = jnp.argsort(eid, stable=True)[:pairs]
+    eid_sorted = eid[order]
+    token = order // k
+    rows = jnp.zeros((n_experts + 1,), jnp.int32).at[eid].add(1)[:n_experts]
+    groups = bank["gate_up"].shape[0]
+    sizes = jax.lax.dynamic_update_slice(
+        jnp.zeros((groups,), jnp.int32), rows, (first_group,))
+    xs = x[token].astype(dtype)
+    gate_up = jax.lax.ragged_dot(xs, bank["gate_up"].astype(dtype), sizes,
+                                 preferred_element_type=jnp.float32)
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    hidden = (jax.nn.silu(gate) * up).astype(dtype)
+    out = jax.lax.ragged_dot(hidden, bank["down"].astype(dtype), sizes,
+                             preferred_element_type=jnp.float32)
+    # Rows past the last group hold whatever the kernel left there.
+    live = (eid_sorted < n_experts)[:, None]
+    out = jnp.where(live, out * weights.reshape(-1)[order][:, None], 0.0)
+    y = jnp.zeros((n, x.shape[-1]), jnp.float32).at[token].add(out)
+    return y, rows

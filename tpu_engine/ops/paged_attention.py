@@ -20,6 +20,12 @@ Every read path takes the WHOLE pool, (L, NB, bs, H_kv*D) as
 [h*D, (h+1)*D)), plus the `layer` to read: the step carries the pool
 through its layer loop and never slices a layer out.
 
+What a block holds here: `block_size` tokens' keys (in `k_pool`) and
+values (in `v_pool`) of every KV head, D lanes a head as the MODEL
+states it (`cfg.d_head`); int8 pools add a scale a (slot, KV head). A
+latent pool (one latent and one rope key a token, two tensors of
+unequal width) has its own read, `ops.latent_attention`.
+
 The `*_reference` functions are XLA `take`: gather the row's blocks
 of that layer into a dense (B, S, H_kv, D) view and run the exact
 `ops.attention.dot_product_attention` math (grouped, un-expanded,

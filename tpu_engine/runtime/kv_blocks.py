@@ -105,11 +105,14 @@ from tpu_engine.ops.attention import KVCache
 
 
 def dense_block_bytes(cfg: TransformerConfig, block_size: int, dtype) -> int:
-    """HBM bytes one K+V block costs at a full-precision `dtype` — the
+    """HBM bytes one block costs at a full-precision `dtype` — the
     single source of the pool-layout formula (BlockPool.stats() and the
-    bench's equal-byte-budget sizing must never disagree)."""
-    return int(2 * cfg.n_layers * block_size * cfg.kv_heads
-               * cfg.d_head * jnp.dtype(dtype).itemsize)
+    bench's equal-byte-budget sizing must never disagree). The lanes a
+    token takes are what the model STATES (`cfg.kv_lanes`): K and V of
+    every KV head at the model's head width, or a latent and its rope
+    key — never `d_model / n_heads` assumed here."""
+    return int(cfg.n_layers * block_size * sum(cfg.kv_lanes)
+               * jnp.dtype(dtype).itemsize)
 
 
 def quant_block_bytes(cfg: TransformerConfig, block_size: int) -> int:
@@ -362,7 +365,19 @@ class RadixTree:
 class BlockPool:
     """Device block pool + host bookkeeping for the paged KV cache.
 
-    **The pool's layout, stated once.** ``caches`` is a K/V pair of
+    **What a block holds, by kind of pool.** Dense (every kv_paged
+    model): ``block_size`` tokens' keys in ``caches.k`` and values in
+    ``caches.v``, all KV heads, layout below. Quantized: the same in
+    int8 plus a scale a (slot, KV head). Latent (the kv_latent family,
+    models.moonlight): ``caches.k`` holds each token's rotated shared
+    rope key, zero-padded to one lane tile, and ``caches.v`` its
+    normalised latent — ``cfg.kv_lanes`` = (128, kv_lora_rank); free
+    list, refcounts, radix tree, tables and copy-on-write treat the pair
+    as they treat K and V, while the host tier, int8 and the chain wire
+    format, which assume two tensors of ``H_kv*D`` lanes, are refused
+    for it at start-up (runtime.scheduler).
+
+    **The dense pool's layout, stated once.** ``caches`` is a K/V pair of
     ``(L, num_blocks, block_size, H_kv*D)`` tensors: the two minor axes
     are a block's slots and, merged, its KV heads — head ``h`` in lanes
     ``[h*D, (h+1)*D)``. It is the operand the paged-attention kernel
@@ -493,9 +508,9 @@ class BlockPool:
         def place(pair):
             return pair if where is None else jax.device_put(pair, where)
 
-        shape = slots + (cfg.kv_heads * cfg.d_head,)
-        caches = place(KVCache(jnp.zeros(shape, self._dtype),
-                               jnp.zeros(shape, self._dtype)))
+        k_lanes, v_lanes = cfg.kv_lanes
+        caches = place(KVCache(jnp.zeros(slots + (k_lanes,), self._dtype),
+                               jnp.zeros(slots + (v_lanes,), self._dtype)))
         if self.quantized:
             # Scale 1.0 everywhere: unwritten (and null-block) slots
             # dequantize to exact zeros, like a fresh bf16 pool.

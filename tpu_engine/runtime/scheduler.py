@@ -395,6 +395,12 @@ class ContinuousGenerator:
             fam = ("state_slab" if isinstance(model.config, SSDConfig)
                    else "kv_paged")
         self._slab = fam == "state_slab"
+        # "kv_latent" (models.moonlight): a kv_paged chain whose blocks
+        # hold a latent and a rope key, stepped by its own ragged step.
+        # Everything the pool does by block id — tables, radix sharing,
+        # refcounts — is shared; what assumes K and V of H_kv*D lanes is
+        # fenced below.
+        self._latent = fam == "kv_latent"
         # Unified stateless serving (DESIGN.md): score/infer/embed
         # models admit as SINGLE-TICK rows — no autoregressive state at
         # all, so every state-machinery branch below is skipped and the
@@ -556,6 +562,10 @@ class ContinuousGenerator:
                 "state_rows applies to the state_slab family; model "
                 f"'{model.name}' serves the "
                 f"{getattr(model, 'state_family', 'kv_paged')} family")
+        if self._latent:
+            self._fence_latent(model, mixed_step=mixed_step,
+                               kv_host_blocks=kv_host_blocks,
+                               kv_quantize=kv_quantize, spec_k=spec_k)
         if int(kv_host_blocks) > 0 and not self._paged:
             raise ValueError("kv_host_blocks requires the paged KV cache "
                              "(set kv_block_size > 0)")
@@ -803,6 +813,14 @@ class ContinuousGenerator:
                 "token_budget": self._mixed_budget,
                 "chunk_cap": self._chunk_cap,
             }
+            if self._latent and self.cfg.n_moe_layers:
+                # What the expert layers routed, summed over ticks: the
+                # step returns each layer's per-expert row counts with
+                # the tick's other results. Padding slots form no pair.
+                self._moe_rows = np.zeros(
+                    (self.cfg.n_moe_layers, self.cfg.n_routed), np.int64)
+                self._stats["moe"] = {"assignments": 0,
+                                      "experts_touched": 0}
         # TTFT / inter-token-latency histograms — the two numbers mixed
         # stepping exists to improve, scrapeable at /metrics
         # (tpu_engine_ttft_seconds / tpu_engine_itl_seconds) on every
@@ -1062,6 +1080,38 @@ class ContinuousGenerator:
 
     # -- paged compiled stages -------------------------------------------------
 
+    def _fence_latent(self, model, *, mixed_step, kv_host_blocks,
+                      kv_quantize, spec_k) -> None:
+        """Start-up fences of the kv_latent family (registry
+        FAMILY_CAPABILITIES): what a latent pool cannot do yet is refused
+        by name, never served wrong. (`tp > 1` is refused above through
+        the model's unshardable TP rule.)"""
+        name = f"model '{model.name}' (kv_latent family)"
+        if not (self._paged and mixed_step):
+            raise ValueError(
+                f"{name} is served by the mixed tick over the block "
+                f"pool only: set kv_block_size > 0 and mixed_step (the "
+                f"two-path prefill and the dense per-slot cache have no "
+                f"latent read)")
+        for flag, value, cap in (
+                ("kv_quantize", kv_quantize, "kv_quantize"),
+                ("kv_host_blocks", int(kv_host_blocks), "kv_host_tier"),
+                ("spec_k", int(spec_k), "spec_decode")):
+            if value:
+                raise ValueError(
+                    f"{flag} needs the '{cap}' capability, which {name} "
+                    f"does not declare: a latent block holds one latent "
+                    f"and one rope key a token, not a K and a V a head")
+
+    def _refuse_latent_chain(self, what: str) -> Optional[str]:
+        """The chain wire format carries a K and a V of H_kv*D lanes:
+        migration, handoff and prefix fetch refuse for a latent pool."""
+        if not self._latent:
+            return None
+        return (f"{what} needs the 'migration' capability, which the "
+                f"kv_latent family does not declare (the chain wire "
+                f"format carries a K and a V a head)")
+
     def _pin_pool_out(self, caches, scales=None):
         """TRACED helper for the pool-donating executables: constrain
         their pool (and scale) outputs to the pool's tensor-parallel
@@ -1304,7 +1354,18 @@ class ContinuousGenerator:
             if key not in self._decode_exe:
                 cfg, dtype = self.cfg, self._dtype
                 quant = self._quant
-                attn_fn = self._paged_attn_fn(ragged=True)
+                latent = self._latent
+                if latent:
+                    from tpu_engine.models.moonlight import (
+                        moonlight_step_rows_ragged,
+                    )
+
+                    # No tick feeds more slots than the token budget
+                    # plus a token a row: the routed pairs' static size.
+                    max_tokens = self._mixed_budget + self.n_slots
+                    attn_fn = None
+                else:
+                    attn_fn = self._paged_attn_fn(ragged=True)
 
                 def step_core(params, caches, scales, tables, tokens,
                               pos0, qlen, sample_slot, fold_pos, active,
@@ -1312,7 +1373,14 @@ class ContinuousGenerator:
                               eos_vec, counts, pens, stops):
                     # sample_slot gathers the hidden state BEFORE the LM
                     # head: one (B, vocab) projection per tick, not W.
-                    if quant:
+                    if latent:
+                        logits, caches, moe_rows = \
+                            moonlight_step_rows_ragged(
+                                params, tokens, caches, tables, pos0,
+                                qlen, cfg, dtype=dtype,
+                                sample_slot=sample_slot,
+                                max_tokens=max_tokens)
+                    elif quant:
                         logits, caches, scales = \
                             transformer_step_rows_ragged(
                                 params, tokens, caches, tables, pos0,
@@ -1347,6 +1415,8 @@ class ContinuousGenerator:
                     out += (nxt, done)
                     if controls:
                         out += (counts,)
+                    if latent:
+                        out += (moe_rows,)
                     return out
 
                 if quant:
@@ -1935,6 +2005,9 @@ class ContinuousGenerator:
         if not (self._paged or self._slab):
             return {"ok": False,
                     "reason": "migration requires the paged KV cache"}
+        refused = self._refuse_latent_chain("row export")
+        if refused:
+            return {"ok": False, "reason": refused}
         if not self._running:
             return {"ok": False, "reason": "scheduler stopped"}
         fut: Future = Future()
@@ -1969,6 +2042,9 @@ class ContinuousGenerator:
         if not (self._paged or self._slab):
             raise ValueError("migration import requires the paged KV "
                              "cache (kv_block_size > 0)")
+        refused = self._refuse_latent_chain("migration import")
+        if refused:
+            raise ValueError(refused)
         if not isinstance(snapshot, dict):
             raise ValueError("migration snapshot must be an object")
         missing = [k for k in ("prompt", "emitted", "pos", "tok",
@@ -2018,6 +2094,9 @@ class ContinuousGenerator:
             return {"ok": False,
                     "reason": "prefix export requires the paged KV "
                               "cache with prefix sharing on"}
+        refused = self._refuse_latent_chain("prefix export")
+        if refused:
+            return {"ok": False, "reason": refused}
         if not self._running:
             return {"ok": False, "reason": "scheduler stopped"}
         toks = [int(t) for t in tokens]
@@ -2374,6 +2453,12 @@ class ContinuousGenerator:
             # across time (bench warm-up subtraction) and must not see
             # their baseline mutate under them.
             out["mixed"] = dict(self._stats["mixed"])
+        if "moe" in self._stats:
+            # Gated additive block (kv_latent lanes with expert layers).
+            # `experts_touched`: (layer, expert) pairs that took at least
+            # one row, summed over ticks.
+            out["moe"] = dict(self._stats["moe"],
+                              rows_by_expert=self._moe_rows.tolist())
         if self._spec:
             spec = dict(self._stats["spec"])
             spec["accept_ratio"] = (
@@ -4262,6 +4347,18 @@ class ContinuousGenerator:
                       if pos0 is not None else 0)
         self._clock.dispatch(width, int(fed.sum()), ctx_tokens)
 
+    def _count_moe(self, rows) -> None:
+        """`rows` (L_moe, E): what each expert of each expert layer took
+        this tick, back with the tick's other results. Into stats()["moe"]
+        and onto the tick's span (before `TickClock.end`)."""
+        assignments, touched = int(rows.sum()), int((rows > 0).sum())
+        self._moe_rows += rows
+        moe = self._stats["moe"]
+        moe["assignments"] += assignments
+        moe["experts_touched"] += touched
+        self._clock.note(moe_assignments=assignments,
+                         moe_experts_touched=touched)
+
     def _tick_done(self, prefill_tokens: int, decode_rows: int, width: int,
                    spec: Optional[dict] = None) -> None:
         """End of a mixed or speculative tick: close the clock and record
@@ -4393,14 +4490,20 @@ class ContinuousGenerator:
                 out = out[2:]
             else:
                 out = out[1:]
+            moe_rows = None
+            if self._latent:
+                out, moe_rows = out[:-1], out[-1]
             if controls:
                 nxt, done, self._counts = out
             else:
                 nxt, done = out
         self._clock.wait()
-        start_host_copies(nxt, done)
+        start_host_copies(nxt, done,
+                          *(() if moe_rows is None else (moe_rows,)))
         nxt = np.array(nxt)
         done_new = np.array(done)
+        if moe_rows is not None and "moe" in self._stats:
+            self._count_moe(np.asarray(moe_rows))
         self._clock.apply()
         # Dispatch counted only past the host sync above — a device-step
         # failure surfaces asynchronously AT that sync (not at the

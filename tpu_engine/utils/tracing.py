@@ -522,6 +522,7 @@ class TickClock:
         self._compile_s0 = 0.0
         self._width = 0
         self._ctx_tokens = 0
+        self._notes: Dict[str, int] = {}
         self._prev_wait_end: Optional[float] = None
         self._recent: Dict[int, deque] = {}
         self._last_slow_line = 0.0
@@ -562,6 +563,7 @@ class TickClock:
         self._wall = time.time()
         self._compile_s0 = self._compiles.seconds
         self._marks = [time.perf_counter()]
+        self._notes = {}
         self._push("tick")
         self._push("tick.form")
 
@@ -581,6 +583,12 @@ class TickClock:
         """The host has the results."""
         self._mark("tick.apply")
 
+    def note(self, **attrs) -> None:
+        """Counts the step brought back with the tick's results (a
+        routed model's `moe_assignments`, `moe_experts_touched`): they
+        ride the tick's span beside `ctx_tokens`."""
+        self._notes.update(attrs)
+
     def end(self, live: bool, node: str) -> Tuple[float, float, dict]:
         """(start_ts, duration_us, attrs) of the tick just finished.
         `live`: a row was still dispatchable when it ended."""
@@ -595,6 +603,7 @@ class TickClock:
             attrs["gap_us"] = round((t[1] - self._prev_wait_end) * 1e6, 1)
         self._prev_wait_end = t[3] if live else None
         attrs["ctx_tokens"] = self._ctx_tokens
+        attrs.update(self._notes)
         attrs["compile_us"] = int(
             (self._compiles.seconds - self._compile_s0) * 1e6)
         attrs["seq"] = self.seq
