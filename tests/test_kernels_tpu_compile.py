@@ -15,6 +15,7 @@ same silent pass the interpreter gives.
 """
 
 import ast
+import dataclasses
 import functools
 import json
 import math
@@ -65,6 +66,45 @@ def test_the_latent_read_compiles_for_v5e_at_both_widths(v5e_devices):
             kernel_check.compile_for_topology(case, v5e_devices[0])
             names.append(case.name)
     assert names == ["moonlight/latent/W1", "moonlight/latent/W256"]
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every `pallas_call` in a jaxpr, nested calls included."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
+@pytest.mark.parametrize("cell", sorted(kernel_check.CELL_SHAPES))
+def test_paged_grid_is_the_query_tiles_not_the_table(v5e_devices, cell):
+    """The ragged read at a benchmark cell's own shapes
+    (`kernel_check.CELL_SHAPES`: docqa's 16 rows x 32 / 8 heads x 128
+    with a 256-slot chunk under a 2048-column table; batch's 32 rows x
+    20 x 64 at width 1 under 64 columns) compiles for a v5e, and its
+    grid is (rows, query tiles a row) at that table and at one half as
+    wide: no grid step for a table column (the parent's grid had the
+    table's width as its third axis)."""
+    shape = kernel_check.CELL_SHAPES[cell]
+    rows = len(shape["rows"])
+    width = max(q for q, _ in shape["rows"])
+    geo = shape["geo"]
+    tiles = -(-width * geo["n_heads"] // geo["n_kv_heads"] // 128)
+    (case,) = [c for c in kernel_check.cell_cases(interpret=False)
+               if c.name == cell]
+    kernel_check.compile_for_topology(case, v5e_devices[0])
+    full = jax.eval_shape(case.operands)
+    assert full[4].shape == (rows, shape["table_len"])
+    half = full[:4] + (jax.ShapeDtypeStruct(
+        (rows, shape["table_len"] // 2), full[4].dtype),) + full[5:]
+    for operands in (full, half):
+        grids = _pallas_grids(jax.make_jaxpr(case.kernel)(*operands).jaxpr)
+        assert grids == [(rows, tiles)], grids
+    kernel_check.compile_for_topology(
+        dataclasses.replace(case, operands=lambda: half), v5e_devices[0])
 
 
 def _mixed_tick(cfg, attn_fn):

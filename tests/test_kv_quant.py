@@ -179,6 +179,24 @@ def test_quant_kernel_parity_ragged(monkeypatch):
         block_size=16, n_blocks=33, table_len=8) < 2e-4
 
 
+@pytest.mark.parametrize("kind,case", [
+    ("quant_paged", "ends-on-group-boundary-width-1"),
+    ("quant_paged", "table-four-times-wider-width-1"),
+    ("quant_ragged", "dead-row-between-live-rows"),
+    ("quant_ragged", "decode-row-in-wide-tick"),
+    ("quant_ragged", "ends-on-group-boundary"),
+    ("quant_ragged", "ends-on-group-boundary-width-1"),
+    ("quant_ragged", "table-four-times-wider"),
+    ("quant_ragged", "chunk-tiles-straddle-a-group")])
+def test_quant_kernel_walks_each_tiles_own_context(kind, case):
+    """The int8 reads over `ops.paged_attention.WALK_CASES`: the scales
+    ride beside the walk a group at a time, so every case that moves a
+    group boundary moves them too."""
+    from tpu_engine.ops.paged_attention import walk_parity_check
+
+    assert walk_parity_check(kind, case, interpret=True) < 2e-4
+
+
 # -- scheduler end-to-end -----------------------------------------------------
 
 _PROMPTS = [[5, 9, 3, 7], [7, 2], list(range(1, 20)), [42] * 9]

@@ -171,6 +171,21 @@ def test_ragged_kernel_parity():
     assert ragged_parity_check(q_lens=(1, 7, 16, 17)) < 2e-5
 
 
+@pytest.mark.parametrize("case", [
+    "dead-row-between-live-rows", "decode-row-in-wide-tick",
+    "ends-on-group-boundary", "ends-on-group-boundary-width-1",
+    "table-four-times-wider", "chunk-tiles-straddle-a-group"])
+def test_ragged_kernel_walks_each_tiles_own_context(case):
+    """The ragged read over the workloads the tile walk can get wrong
+    (`ops.paged_attention.WALK_CASES`): a dead row between live ones, a
+    decode row inside a wide tick, contexts on and around a group
+    boundary, a table four times wider than its longest row, a chunk
+    whose tiles straddle a group at G = 4."""
+    from tpu_engine.ops.paged_attention import walk_parity_check
+
+    assert walk_parity_check("ragged", case, interpret=True) < 2e-5
+
+
 def test_cancelled_mid_prefill_returns_blocks(spec, params, mixed):
     """Deadline-expired rows — queued or mid-prefill-chunk — return
     every block; the scheduler keeps serving identical streams after."""

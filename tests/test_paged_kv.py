@@ -401,6 +401,34 @@ def test_every_read_path_at_every_head_shape(kind, heads):
     assert err < 5e-5
 
 
+@pytest.mark.parametrize("case", ["ends-on-group-boundary-width-1",
+                                  "table-four-times-wider-width-1"])
+def test_decode_kernel_walks_each_rows_own_context(case):
+    """The decode read (the ragged read at q_len 1) over the workloads
+    the tile walk can get wrong (`ops.paged_attention.WALK_CASES`):
+    contexts ending on, one past and far before a group boundary, and a
+    table whose tail is the null block."""
+    from tpu_engine.ops import paged_attention as pa
+
+    assert pa.walk_parity_check("paged", case, interpret=True) < 2e-5
+
+
+@pytest.mark.parametrize("width", [1, 40])
+@pytest.mark.parametrize("kind", ["ragged", "quant_ragged"])
+def test_a_tick_with_every_row_dead_reads_nothing(kind, width):
+    """q_len 0 in every row: no tile is live, the kernel writes zeros
+    (and nothing it could have read, garbage or not, shows)."""
+    from tpu_engine.ops import paged_attention as pa
+
+    operands, _ = pa.parity_workload(
+        kind, (width, 1, width), n_heads=8, n_kv_heads=2, d_head=16,
+        block_size=16, n_blocks=61, table_len=20, dtype=jnp.float32)
+    out = pa.READ_PATHS[kind][0](*operands[:-1],
+                                 jnp.zeros((3,), jnp.int32), interpret=True)
+    assert out.shape == operands[0].shape
+    assert not np.any(np.asarray(out))
+
+
 @pytest.mark.parametrize("quant", ["", "int8"])
 @pytest.mark.parametrize("step", ["decode", "ragged"])
 def test_step_writes_layer_l_into_layer_l_only(spec, params, step, quant):

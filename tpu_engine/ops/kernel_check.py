@@ -4,8 +4,9 @@ One list of cases (`kernel_cases`) covers the flash kernel (forward at
 every prompt bucket, backward) and the paged read paths (decode, ragged
 at q_len 1 and at the prefill-chunk width, both int8 variants) at the
 head geometry of a registered model; for a latent-attention model
-(`LATENT_MODELS`) it is the latent read at both widths instead. Two
-consumers:
+(`LATENT_MODELS`) it is the latent read at both widths instead.
+`cell_cases` adds the ragged read at the shapes the benchmark's cells
+serve it at (`CELL_SHAPES`). Two consumers:
 
 - `python -m tpu_engine.ops.kernel_check` — chip_smoke.py's kernel
   phase: on the attached TPU, compile every case with `interpret=False`
@@ -23,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import json
 import sys
 import time
@@ -57,6 +59,25 @@ FLASH_BACKWARD_SEQ = 256
 # round P to the MXU dtype before the PV matmul and the output to bf16 —
 # 2^-8 relative on values of order 1.
 BF16_TOLERANCE = 2e-2
+
+
+# The ragged read as the benchmark's cells call it (benchmarks/configs/
+# gpt2-large.json, mistral-7b-v0.2-8l.json; stated here, the package does
+# not read the benchmark): heads, rows = slots, the table's width
+# (max_seq / 16), the pool's blocks, and one tick's rows as (q_len, pos0).
+# batch: 32 decode rows of 40-320 columns. docqa: a 256-token chunk whose
+# context ends on a group boundary beside four decode rows of 0.6-1.6 k
+# columns and eleven free slots, under a table of 2048 columns.
+CELL_SHAPES = {
+    "gpt2-large.batch/ragged/W1": dict(
+        geo=dict(n_heads=20, n_kv_heads=20, d_head=64), table_len=64,
+        n_blocks=1537, rows=tuple((1, 39 + 9 * r) for r in range(32))),
+    "mistral-7b-v0.2-8l.docqa/ragged/W256": dict(
+        geo=dict(n_heads=32, n_kv_heads=8, d_head=128), table_len=2048,
+        n_blocks=2817,
+        rows=((256, 1024), (1, 600), (1, 1023), (1, 1290), (1, 1567))
+        + ((0, 0),) * 11),
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,6 +187,30 @@ def _latent_cases(model: str, interpret: bool):
                          workload, check)
 
 
+def cell_cases(interpret: bool = False):
+    """The ragged read at every entry of `CELL_SHAPES`."""
+    kernel_fn, reference_fn = pa.READ_PATHS["ragged"]
+    for name, shape in CELL_SHAPES.items():
+        q_lens, pos0 = zip(*shape["rows"])
+        workload = functools.partial(
+            pa.parity_workload, "ragged", q_lens, block_size=BLOCK_SIZE,
+            n_blocks=shape["n_blocks"], table_len=shape["table_len"],
+            dtype=jnp.bfloat16, pos0=pos0, **shape["geo"])
+        # The gather reference over the columns a row reaches, not the
+        # table's width (2048 columns of 16 rows x 32 heads x 256 slots
+        # are 17 GB of scores).
+        reach = -(-max(q + p for q, p in shape["rows"]) // BLOCK_SIZE)
+
+        def check(out, operands, reach=reach,
+                  qlen=jnp.asarray(q_lens, jnp.int32)):
+            near = operands[:4] + (operands[4][:, :reach],) + operands[5:]
+            return pa.reference_error(reference_fn, out, near, qlen)
+
+        yield KernelCase(name,
+                         functools.partial(kernel_fn, interpret=interpret),
+                         lambda workload=workload: workload()[0], check)
+
+
 def kernel_cases(model: str, interpret: bool = False):
     """Every Pallas kernel site at `model`'s registry geometry."""
     if model in LATENT_MODELS:
@@ -200,23 +245,24 @@ def main() -> int:
         return 1
     enable_compilation_cache()
     worst, failed = 0.0, []
-    for model in MODELS + LATENT_MODELS:
-        for case in kernel_cases(model, interpret=False):
-            t0 = time.monotonic()
-            operands = case.operands()
-            if case.check is None:
-                jax.jit(case.kernel).lower(*operands).compile()
-                err = None
-            else:
-                err = case.check(jax.block_until_ready(
-                    jax.jit(case.kernel)(*operands)), operands)
-                worst = max(worst, err)
-                if not err <= BF16_TOLERANCE:   # NaN fails too
-                    failed.append(case.name)
-            print(json.dumps({"kernel": case.name, "interpret": False,
-                              "max_abs_err": err,
-                              "seconds": round(time.monotonic() - t0, 2)}),
-                  flush=True)
+    for case in itertools.chain(
+            *(kernel_cases(model) for model in MODELS + LATENT_MODELS),
+            cell_cases()):
+        t0 = time.monotonic()
+        operands = case.operands()
+        if case.check is None:
+            jax.jit(case.kernel).lower(*operands).compile()
+            err = None
+        else:
+            err = case.check(jax.block_until_ready(
+                jax.jit(case.kernel)(*operands)), operands)
+            worst = max(worst, err)
+            if not err <= BF16_TOLERANCE:   # NaN fails too
+                failed.append(case.name)
+        print(json.dumps({"kernel": case.name, "interpret": False,
+                          "max_abs_err": err,
+                          "seconds": round(time.monotonic() - t0, 2)}),
+              flush=True)
     print(json.dumps({"kernel_check": "failed" if failed else "ok",
                       "tolerance": BF16_TOLERANCE, "worst": worst,
                       "failed": failed}), flush=True)

@@ -36,24 +36,43 @@ token streams match the dense path.
 
 The kernel side is ONE Pallas TPU kernel (`_paged_kernel`) behind all
 four entry points — decode is the ragged read at q_len 1, and the pool
-dtype is a static flag. Grid (B, row tiles, n_blocks) with the block
-axis sequential; each step DMAs ONE whole physical block — all KV heads,
-a (bs, H_kv*D) tile of the pool, the same bytes in the same order as the
-(bs, H_kv, D) block of the chain wire format — chosen by the layer index
-and the block table via scalar prefetch (the index map reads
-`(layer[0], tables[b, j])`; neither the layer nor the gather ever
-materializes). A static loop over KV heads slices each head's (bs, D)
-lanes and folds it into running flash accumulators (f32 max /
-denominator / weighted sum in VMEM scratch). Blocks entirely past what
-the row tile can see are skipped with `pl.when`, so a short row in a
-long-table batch costs only its own blocks — the ragged-batch win the
-TPU paged-attention kernel exists for (PAPERS.md "Ragged Paged
-Attention").
-
+dtype is a static flag. **The grid is the query tiles, not the table.**
 Query slots stack with the group heads on the sublane axis: q is laid
 out (B, H_kv, W*G, D) with G = n_heads/kv_heads (row r = slot r//G,
-head r%G), tiled `_ROW_TILE` rows at a time, so VMEM holds
+head r%G), tiled `_ROW_TILE` rows at a time, and the grid is
+(B, ceil(W*G / rows)) whatever the table's width: a tile of a row with
+no new token there (q_len 0, or a decode row's tiles past its first)
+writes zeros and costs one empty step. A live tile walks ITS OWN
+context: K and V stay whole in HBM, and a loop over
+ceil(horizon / (blocks * bs)) groups — `horizon` is what the tile's
+last slot sees — DMAs each group's physical blocks, all KV heads, a
+(bs, H_kv*D) tile of the pool each, the same bytes in the same order as
+the (bs, H_kv, D) block of the chain wire format, chosen by the layer
+index and the block table in SMEM (neither the layer nor the gather
+ever materializes), into one of two VMEM buffers while the other is
+folded into running flash accumulators (f32 max / denominator /
+weighted sum in VMEM scratch). No step, DMA or table read is spent on a
+column past a tile's horizon, so a short row under a long table costs
+its own blocks and nothing else — the ragged-batch win the TPU
+paged-attention kernel exists for (PAPERS.md "Ragged Paged Attention").
+Columns past the horizon inside the last group are masked as scores and
+zeroed as values.
+
+Tile geometry comes from the shapes the call sees (`_tile_geometry`),
+one walk for both: a chunk's tile is 128 query rows a KV head against
+groups of 16 blocks, (128, D) x (D, 256) a head; a decode row has ONE
+query row a head, so its heads are packed into one score tile (row i =
+head i: each head's product with its own key lanes, queries zero in the
+other heads' rows, added up) against groups of 8 blocks. VMEM holds
 O(H_kv * _ROW_TILE * D) whatever W*G is.
+
+An int8 pool's scales, (L, NB, bs, H_kv), cannot be fetched by the walk
+(Mosaic refuses a DMA whose minor dimension is H_kv): the call gathers
+the scales of the rows' tables, tokens on the lanes, and the kernel
+multiplies a head's scores and probabilities by them — the reference's
+product (int8 -> f32 is exact), the slot's scale applied once a
+(query, token) instead of once a lane. That gather is the one part of
+the int8 read that still grows with the table's width.
 
 Selection mirrors `models.transformer.default_attention`:
 `TPU_ENGINE_PAGED` "1" forces the kernel (interpreter off-TPU), "0"
@@ -82,6 +101,14 @@ _NEG_INF = float("-inf")
 # (q/out tiles, the f32 accumulators and their lane-padded (rows, 1)
 # softmax statistics) independently of the chunk width and group size.
 _ROW_TILE = 128
+# Physical blocks a live tile folds per group of its walk. A chunk's tile:
+# 16 blocks of 16 slots are a 256-token key tile a matrix product (two
+# 640 KB buffers a tensor at gpt2-large's 1280 lanes, 512 KB at Mistral's
+# 1024). A decode row's (heads packed, `_tile_geometry`): 8, so a row of a
+# few hundred tokens already overlaps its second group's DMAs with the
+# first group's products (measured, PERF.md section 6, PR 29).
+_BLOCKS_PER_GROUP = 16
+_BLOCKS_PER_GROUP_PACKED = 8
 
 
 def _dense_rows(q, k_pool, v_pool, k_scale, v_scale, layer, tables):
@@ -161,11 +188,12 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, layer, tables, pos0,
 #
 # The quantized pool (runtime.kv_blocks, --kv-quantize int8) stores block
 # payloads int8 with one f32 scale per (block slot, kv-head) vector per
-# layer. Both sides dequantize the same way (int8 -> f32 is exact, times
-# the slot's f32 scale) — the reference on its gathered copy, the kernel
-# per streamed block in VMEM, so the dequantized pool never materializes
-# in HBM — and rounding error comes only from the one-time int8 write at
-# block-fill time.
+# layer. Both sides use int8 -> f32 (exact) times the slot's f32 scale —
+# the reference on its gathered copy, lane by lane; the kernel on each
+# streamed group in VMEM, the scale applied to the head's score and
+# probability (module docstring) — so the dequantized pool never
+# materializes in HBM, and rounding error comes only from the one-time
+# int8 write at block-fill time.
 
 
 def quant_paged_attention_reference(q, k_pool, v_pool, k_scale, v_scale,
@@ -191,88 +219,167 @@ def quant_ragged_paged_attention_reference(q, k_pool, v_pool, k_scale,
 # -- the kernel (all four read paths) -----------------------------------------
 
 
-def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_ref,
-                  v_ref, *rest, block_size: int, scale: float, group: int,
-                  n_kv_heads: int, d_head: int, quant: bool):
-    """One (row, row-tile, block) grid step over ALL KV heads.
-    q_ref/o_ref (1, H_kv, T, D) — T query rows of the row's W*G (row
-    r = slot r//G, head r%G); k_ref/v_ref (1, bs, H_kv*D) — the physical
-    block the index map picked by `layer_ref` and the table (only the
-    index maps read `layer_ref`), head h in lanes [h*D, (h+1)*D);
-    quantized pools add ks_ref/vs_ref (1, bs, H_kv) f32.
-    Scratch (m/l: (H_kv, T, 1), acc: (H_kv, T, D), f32) carries the
-    online softmax across the sequential block axis; the statistics
-    stay (T, 1) columns so no step moves a vector between lanes and
-    sublanes. Causal masking within the new-token window: score row r
-    keeps kpos <= pos0 + r//G."""
-    del layer_ref
-    if quant:
-        ks_ref, vs_ref, o_ref, m_sc, l_sc, acc_sc = rest
-    else:
-        o_ref, m_sc, l_sc, acc_sc = rest
-    b = pl.program_id(0)
-    t = pl.program_id(1)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
-    rows = q_ref.shape[2]
+def _tile_geometry(rows_a_row: int, n_kv_heads: int):
+    """(rows, pack, blocks) for a call whose batch rows hold `rows_a_row`
+    query rows (slot x group head) a KV head. `rows`: query rows a tile
+    and KV head, `_ROW_TILE` at most. `pack`: KV heads whose scores share
+    ONE score tile of pack * rows rows, the largest divisor of H_kv that
+    keeps it within `_ROW_TILE`: 1 for a chunk (128 rows a head fill the
+    MXU's rows alone), every head for a decode row (gpt2-large: 20 rows,
+    Mistral: 8 x 4), so a head with one query row is not a product and a
+    softmax of its own. `blocks`: physical blocks a group of the walk."""
+    rows = min(rows_a_row, _ROW_TILE)
+    pack = max(p for p in range(1, n_kv_heads + 1)
+               if n_kv_heads % p == 0 and p * rows <= _ROW_TILE)
+    return rows, pack, (_BLOCKS_PER_GROUP if pack == 1
+                        else _BLOCKS_PER_GROUP_PACKED)
 
-    @pl.when(j == 0)
-    def _init():
+
+def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
+                  v_hbm, *rest, block_size: int, blocks: int, scale: float,
+                  group: int, pack: int, quant: bool):
+    """One query TILE a grid step (b, t): `rows` query rows of batch row
+    b (row r = slot r // G, group head r % G) for ALL its KV heads.
+    q_ref/o_ref (1, H_kv, rows, D); k_hbm/v_hbm: the whole pools, left in
+    HBM. A tile with no valid slot writes zeros; a live one walks ITS
+    OWN context, `blocks` physical blocks a group: the blocks' DMAs
+    (picked by `layer_ref` and the block table) fill one of two VMEM
+    buffers (blocks, bs, H_kv*D) while the other is folded into the
+    flash accumulators, so nothing is spent on a table column past what
+    the tile's last slot sees.
+
+    `pack` KV heads share a score tile of M = pack * rows rows (row
+    i = head i // rows of the chunk, query row i % rows): head h's
+    queries sit in their rows of a zero (M, D) tile, the heads'
+    products with their own key lanes add up to the (M, span) scores,
+    and of the (M, D) product with head h's values its rows alone are
+    kept. Statistics (m/l: (H_kv/pack, M, 1)) stay columns, so no step
+    moves a vector between lanes and sublanes; acc: (H_kv, M, D) f32.
+    Causal masking within the new-token window: query row r keeps
+    kpos <= pos0 + r // G.
+
+    An int8 pool adds ks_ref/vs_ref (1, groups, H_kv', span) f32: the
+    scales of the row's table, a group's tokens on the lanes (Mosaic
+    takes no DMA whose minor dimension is H_kv, so the call gathers
+    them). int8 -> f32 is exact and the slot's scale multiplies the
+    head's score and probability instead of its D key and value lanes:
+    the reference's product, rounded once at the int8 write."""
+    if quant:
+        ks_ref, vs_ref, *rest = rest
+    o_ref, k_buf, v_buf, sems, *scratch = rest
+    qx_sc = scratch.pop(0) if pack > 1 else None
+    m_sc, l_sc, acc_sc = scratch
+    b = pl.program_id(0)
+    n_kv_heads, rows, d_head = q_ref.shape[1:]
+    m_rows = pack * rows
+    span = blocks * block_size
+    pos0 = pos0_ref[b]
+    first = pl.program_id(1) * rows            # the tile's first query row
+    live = first < (lengths_ref[b] - pos0) * group
+
+    @pl.when(jnp.logical_not(live))
+    def _dead():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(live)
+    def _tile():
+        layer = layer_ref[0]
+        # Columns the tile's LAST query row sees, capped at pos0 + q_len.
+        horizon = jnp.minimum(lengths_ref[b],
+                              pos0 + (first + rows - 1) // group + 1)
+        groups = (horizon + span - 1) // span
+
+        def copies(g, slot):
+            out = []
+            for i in range(blocks):
+                blk = tables_ref[b, g * blocks + i]
+                out += [pltpu.make_async_copy(
+                    pool.at[layer, blk], buf.at[slot, i], sems.at[n, slot, i])
+                    for n, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                                     (v_hbm, v_buf)))]
+            return out
+
+        for copy in copies(0, 0):
+            copy.start()
         m_sc[...] = jnp.full(m_sc.shape, _NEG_INF, jnp.float32)
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+        if pack > 1:
+            qx_sc[...] = jnp.zeros(qx_sc.shape, qx_sc.dtype)
+            for h in range(n_kv_heads):
+                qx_sc[h, pl.ds(h % pack * rows, rows), :] = q_ref[0, h]
+        # qpos - (column inside a group): a column of group g is kept
+        # where this reaches g * span.
+        shape = (m_rows, span)
+        reach = pos0 + (first + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0) % rows) // group \
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1)
 
-    pos0 = pos0_ref[b]
-    # Columns this tile's LAST query row can see, capped at the row's
-    # pos0 + qlen: later blocks are fully masked for every row here.
-    horizon = jnp.minimum(lengths_ref[b],
-                          pos0 + (t * rows + rows - 1) // group + 1)
-
-    # Blocks wholly past the horizon do no work at all — a decode row
-    # (q_len 1) in a batch with a wide prefill chunk costs only its own
-    # history's blocks, and a chunk's early row tiles skip its late
-    # columns.
-    @pl.when(j * block_size < horizon)
-    def _live_block():
-        shape = (rows, block_size)
-        kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        qpos = pos0 + (t * rows + jax.lax.broadcasted_iota(
-            jnp.int32, shape, 0)) // group
-        keep = kpos <= qpos
-        for h in range(n_kv_heads):
-            lanes = slice(h * d_head, (h + 1) * d_head)
-            q = q_ref[0, h]                    # (T, D)
-            k = k_ref[0, :, lanes]             # (bs, D)
-            v = v_ref[0, :, lanes]
+        def head(buf, slot, h):                 # -> (span, D)
+            x = buf[slot, :, :, h * d_head:(h + 1) * d_head]
             if quant:
-                # Fused dequant in VMEM, the reference's own arithmetic
-                # (int8 -> f32 is exact, then one f32 multiply by the
-                # slot's scale): rounding error comes only from the
-                # one-time int8 write at block-fill time.
-                k = k.astype(jnp.float32) * ks_ref[0, :, h:h + 1]
-                v = v.astype(jnp.float32) * vs_ref[0, :, h:h + 1]
-                q = q.astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale   # (T, bs)
-            s = jnp.where(keep, s, _NEG_INF)
-            m = m_sc[h]                                       # (T, 1)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            safe_m = jnp.where(m_new == _NEG_INF, 0.0, m_new)
-            p = jnp.exp(s - safe_m)
-            corr = jnp.where(m == _NEG_INF, 0.0, jnp.exp(m - safe_m))
-            l_sc[h] = l_sc[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
-            acc_sc[h] = acc_sc[h] * corr + jax.lax.dot_general(
-                p.astype(v.dtype), v,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_sc[h] = m_new
+                x = x.astype(jnp.float32)
+            return x.reshape(span, d_head)
 
-    @pl.when(j == nb - 1)
-    def _finalize():
+        def fold(g, carry):
+            slot = g % 2
+
+            @pl.when(g + 1 < groups)
+            def _prefetch():
+                for copy in copies(g + 1, 1 - slot):
+                    copy.start()
+
+            for copy in copies(g, slot):
+                copy.wait()
+            keep = reach >= g * span
+            # Columns past the horizon hold the null block's or a later
+            # block's bytes: masked as scores, zeroed as values (0 * NaN).
+            if quant:
+                seen = (g * span + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, span), 1)) < horizon
+                ks, vs = ks_ref[0, g], jnp.where(seen, vs_ref[0, g], 0.0)
+            else:
+                seen = (g * span + jax.lax.broadcasted_iota(
+                    jnp.int32, (span, 1), 0)) < horizon
+            for c in range(n_kv_heads // pack):
+                heads = range(c * pack, (c + 1) * pack)
+                s = None
+                for h in heads:
+                    q = q_ref[0, h] if pack == 1 else qx_sc[h]
+                    if quant:
+                        q = q.astype(jnp.float32)
+                    part = jax.lax.dot_general(
+                        q, head(k_buf, slot, h),
+                        dimension_numbers=(((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    if quant:
+                        part = part * ks[h:h + 1]
+                    s = part if s is None else s + part
+                s = jnp.where(keep, s * scale, _NEG_INF)       # (M, span)
+                m = m_sc[c]                                    # (M, 1)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                safe_m = jnp.where(m_new == _NEG_INF, 0.0, m_new)
+                p = jnp.exp(s - safe_m)
+                corr = jnp.where(m == _NEG_INF, 0.0, jnp.exp(m - safe_m))
+                l_sc[c] = l_sc[c] * corr + jnp.sum(p, axis=-1, keepdims=True)
+                m_sc[c] = m_new
+                for h in heads:
+                    v = head(v_buf, slot, h)
+                    if quant:
+                        pv = p * vs[h:h + 1]
+                    else:
+                        v = jnp.where(seen, v, 0).astype(v.dtype)
+                        pv = p.astype(v.dtype)
+                    acc_sc[h] = acc_sc[h] * corr + jax.lax.dot_general(
+                        pv, v, dimension_numbers=(((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, groups, fold, 0)
         for h in range(n_kv_heads):
-            l = l_sc[h]
-            o_ref[0, h] = (acc_sc[h] / jnp.where(l == 0.0, 1.0, l)
+            own = pl.ds(h % pack * rows, rows)    # head h's rows of the M
+            l = l_sc[h // pack, own, :]
+            o_ref[0, h] = (acc_sc[h, own, :] / jnp.where(l == 0.0, 1.0, l)
                            ).astype(o_ref.dtype)
 
 
@@ -281,15 +388,15 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
                 lengths, *, interpret: bool):
     """The one pallas_call behind every read path. q: (B, W, H, D);
     k_pool/v_pool: (L, NB, bs, H_kv*D), the whole pool as it lives on
-    the device — an operand, never reshaped or sliced; k_scale/v_scale:
-    (L, NB, bs, H_kv) f32 or None (full-precision pool); layer: (1,)
-    the layer read; tables: (B, nb); pos0: (B,) logical position of each
-    row's first query slot; lengths: (B,) pos0 + qlen. Returns
-    (B, W, H, D) in q's dtype."""
+    the device — an operand left in HBM, never reshaped or sliced;
+    k_scale/v_scale: (L, NB, bs, H_kv) f32 or None (full-precision
+    pool); layer: (1,) the layer read; tables: (B, nb); pos0: (B,)
+    logical position of each row's first query slot; lengths: (B,) pos0
+    + qlen. Returns (B, W, H, D) in q's dtype. The grid is the query
+    tiles, (B, ceil(W*G / rows)), whatever the table's width."""
     b, w, h, d = q.shape
     bs = k_pool.shape[2]
     h_kv = k_pool.shape[3] // d
-    nb = tables.shape[1]
     g = h // h_kv
     quant = k_scale is not None
     # (B, W, H, D) -> (B, H_kv, W*G, D): slot-major within each KV head so
@@ -298,48 +405,63 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
     r = w * g
     qh = (q.reshape(b, w, h_kv, g, d).transpose(0, 2, 1, 3, 4)
           .reshape(b, h_kv, r, d))
-    rows = min(r, _ROW_TILE)
+    rows, pack, blocks = _tile_geometry(r, h_kv)
     r_pad = pl.cdiv(r, rows) * rows
     if r_pad != r:
         # Zero rows past W*G: computed like padding slots, sliced off.
         qh = jnp.pad(qh, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
-    q_spec = pl.BlockSpec(
-        (1, h_kv, rows, d),
-        lambda b, t, j, tables, pos0, lengths, layer: (b, 0, t, 0))
+    blocks = min(blocks, tables.shape[1])
+    # A whole number of groups: entries past the table's width point at
+    # the null block (and lie past every horizon).
+    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % blocks)))
+    n_groups = tables.shape[1] // blocks
 
-    # The layer index and the block table ARE the index map: step
-    # (b, t, j) DMAs physical block tables[b, j] of layer layer[0] — no
-    # layer is sliced out and no gathered copy exists.
-    def block_spec(last):
-        return pl.BlockSpec(
-            (None, 1, bs, last),
-            lambda b, t, j, tables, pos0, lengths, layer:
-                (layer[0], tables[b, j], 0, 0))
+    def q_map(b, t, tables, pos0, lengths, layer):
+        # A dead tile names the row's last live one: no DMA for it.
+        last = jnp.maximum((lengths[b] - pos0[b]) * g - 1, 0) // rows
+        return (b, 0, jnp.minimum(t, last), 0)
 
-    in_specs = [q_spec, block_spec(h_kv * d), block_spec(h_kv * d)]
-    operands = [qh, k_pool, v_pool]
+    def tile_spec(index_map):
+        return pl.BlockSpec((1, h_kv, rows, d), index_map)
+
+    def table_scales(pool):
+        """(L, NB, bs, H_kv) -> (B, groups, H_kv', span): the scales of
+        each row's table, a group's tokens on the lanes; H_kv' a whole
+        number of sublane tiles."""
+        x = pool[layer[0], tables].reshape(b, n_groups, blocks * bs, h_kv)
+        return jnp.pad(x.transpose(0, 1, 3, 2),
+                       ((0, 0), (0, 0), (0, -h_kv % 8), (0, 0)))
+
+    whole = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs, operands = [tile_spec(q_map), whole, whole], [qh, k_pool, v_pool]
     if quant:
-        in_specs += [block_spec(h_kv), block_spec(h_kv)]
-        operands += [k_scale, v_scale]
+        operands += [table_scales(k_scale), table_scales(v_scale)]
+        in_specs += [pl.BlockSpec((1,) + operands[-1].shape[1:],
+                                  lambda b, t, *_: (b, 0, 0, 0))] * 2
     kernel = functools.partial(
-        _paged_kernel, block_size=bs, scale=1.0 / math.sqrt(d), group=g,
-        n_kv_heads=h_kv, d_head=d, quant=quant)
+        _paged_kernel, block_size=bs, blocks=blocks,
+        scale=1.0 / math.sqrt(d), group=g, pack=pack, quant=quant)
+    m_rows = pack * rows
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,        # tables, pos0, lengths, layer
-            grid=(b, r_pad // rows, nb),
+            grid=(b, r_pad // rows),
             in_specs=in_specs,
-            out_specs=q_spec,
+            out_specs=tile_spec(lambda b, t, *_: (b, 0, t, 0)),
             scratch_shapes=[
-                pltpu.VMEM((h_kv, rows, 1), jnp.float32),
-                pltpu.VMEM((h_kv, rows, 1), jnp.float32),
-                pltpu.VMEM((h_kv, rows, d), jnp.float32),
+                pltpu.VMEM((2, blocks, bs, h_kv * d), k_pool.dtype),
+                pltpu.VMEM((2, blocks, bs, h_kv * d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2, blocks)),
+            ] + [pltpu.VMEM((h_kv, m_rows, d), q.dtype)] * (pack > 1) + [
+                pltpu.VMEM((h_kv // pack, m_rows, 1), jnp.float32),
+                pltpu.VMEM((h_kv // pack, m_rows, 1), jnp.float32),
+                pltpu.VMEM((h_kv, m_rows, d), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h_kv, r_pad, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(tables, pos0, lengths, layer, *operands)
     return (out[:, :, :r].reshape(b, h_kv, w, g, d)
@@ -379,8 +501,8 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, tables, pos0, qlen, *,
 def quant_paged_attention(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
                           pos_vec, *, interpret=None):
     """Pallas-kernel drop-in for `quant_paged_attention_reference` (same
-    signature/contract): the block DMA is int8 + a scale vector — about
-    half the bf16 bytes per block — and dequant happens in VMEM."""
+    signature/contract): the block DMAs are int8, about half the bf16
+    bytes, and the scales are applied in VMEM."""
     return _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
                   pos_vec, 1, interpret)
 
@@ -491,14 +613,17 @@ _PARITY_LAYERS, _PARITY_LAYER = 2, 1
 
 def parity_workload(kind: str, q_lens, *, n_heads: int, n_kv_heads: int,
                     d_head: int, block_size: int, n_blocks: int,
-                    table_len: int, dtype, seed: int = 0):
+                    table_len: int, dtype, seed: int = 0, pos0=None):
     """One random workload for the `READ_PATHS[kind]` pair, one row per
     entry of `q_lens`: (operands, qlen). The pool has two layers, both
     random, and the second is read. Rows get distinct
     shuffled tables and ragged positions so the skip/mask paths are
-    exercised. Traceable (`jax.eval_shape` gives the operand shapes
-    without generating them — ops.kernel_check AOT-compiles from those).
-    Shared by the parity checks below and ops.kernel_check."""
+    exercised; with `pos0` (one column a row) the positions are those,
+    and a row's table past its own pos0 + q_len columns is the null
+    block, as the scheduler leaves it. Traceable (`jax.eval_shape` gives
+    the operand shapes without generating them — ops.kernel_check
+    AOT-compiles from those). Shared by the parity checks below and
+    ops.kernel_check."""
     import numpy as np
 
     decode, quant = "ragged" not in kind, kind.startswith("quant")
@@ -524,11 +649,15 @@ def parity_workload(kind: str, q_lens, *, n_heads: int, n_kv_heads: int,
                 jax.random.normal(kv, shape, dtype))
     q = jax.random.normal(kq, (batch, w, n_heads, d_head), dtype)
     tables = np.zeros((batch, table_len), np.int32)
-    pos0 = np.zeros((batch,), np.int32)
+    placed, pos0 = pos0, np.zeros((batch,), np.int32)
     for r, ql in enumerate(q_lens):
         tables[r] = 1 + rng.permutation(n_blocks - 1)[:table_len]
-        # Row history + this chunk must fit the table.
-        pos0[r] = int(rng.integers(0, table_len * block_size - ql + 1))
+        if placed is None:
+            # Row history + this chunk must fit the table.
+            pos0[r] = int(rng.integers(0, table_len * block_size - ql + 1))
+        else:
+            pos0[r] = placed[r]
+            tables[r, -(-(placed[r] + ql) // block_size):] = 0
     qlen = jnp.asarray(np.asarray(q_lens, np.int32))
     where = (jnp.int32(_PARITY_LAYER), jnp.asarray(tables),
              jnp.asarray(pos0))
@@ -560,6 +689,45 @@ def _parity(kind: str, q_lens, *, interpret, **shape) -> float:
     return reference_error(reference_fn,
                            kernel_fn(*operands, interpret=interpret),
                            operands, qlen)
+
+
+# What the tile walk can get wrong, one workload each: name -> (q_lens,
+# pos0, table_len) at block size 16 with 8 query heads over 2 KV heads of
+# 16 lanes (G = 4). A width-1 call packs both heads into one 8-row score
+# tile and walks 8-block groups (128 columns); a wider one tiles 128
+# query rows (32 slots) a head and walks 16-block groups (256 columns).
+WALK_CASES = {
+    # A row with no new token between live rows: its tiles write zeros
+    # and the rows around it are read as before.
+    "dead-row-between-live-rows": ((1, 0, 7, 0, 1), (37, 9, 20, 50, 3), 4),
+    # A decode row inside a wide tick: ONE live tile, 301 columns (two
+    # groups), beside a 40-slot chunk of two tiles.
+    "decode-row-in-wide-tick": ((1, 40, 1), (300, 9, 0), 20),
+    # Contexts that end exactly on a group boundary (256, 512), one
+    # column past it (257) and inside the first block (5).
+    "ends-on-group-boundary": ((40, 40, 40, 3), (216, 217, 472, 2), 32),
+    # The same at width 1 (groups of 128 columns): 128, 129, 256, 6.
+    "ends-on-group-boundary-width-1": ((1, 1, 1, 1), (127, 128, 255, 5),
+                                       16),
+    # A table four times wider than the longest row (57 columns = 4
+    # blocks of 16): everything past a row's own blocks is the null block.
+    "table-four-times-wider": ((1, 17, 5), (30, 40, 0), 16),
+    "table-four-times-wider-width-1": ((1, 1, 1), (30, 56, 0), 16),
+    # A 64-slot chunk at G = 4 is two tiles of 32 slots; the first one's
+    # horizon (272) already crosses the group boundary at 256.
+    "chunk-tiles-straddle-a-group": ((64, 1), (240, 255), 20),
+}
+
+
+def walk_parity_check(kind: str, case: str, *, interpret=None,
+                      dtype=jnp.float32, seed: int = 0) -> float:
+    """Max |kernel - reference| of `READ_PATHS[kind]` over one of
+    `WALK_CASES` (a decode path takes the width-1 cases only)."""
+    q_lens, pos0, table_len = WALK_CASES[case]
+    return _parity(kind, q_lens, n_heads=8, n_kv_heads=2, d_head=16,
+                   block_size=16, n_blocks=1 + len(q_lens) * table_len,
+                   table_len=table_len, dtype=dtype, seed=seed, pos0=pos0,
+                   interpret=interpret)
 
 
 def parity_check(batch: int = 2, n_heads: int = 4, n_kv_heads: int = 2,
