@@ -387,8 +387,8 @@ def main() -> int:
     # scheduler (registry-declared param placement, H_kv-sharded pool)
     # against the single-device arm, in-process. Greedy streams must be
     # byte-identical and mixed ticks == dispatches; on a multi-chip
-    # host this validates the SPMD compile the tp-ab campaign stage
-    # needs before serving --tp.
+    # host this validates the SPMD compile a lane needs before
+    # serving --tp.
     if args.tp_parity:
         n = (6 + int(args.kernel_parity) + int(args.mixed_parity)
              + int(args.spec_parity) + int(args.quant_parity)

@@ -15,6 +15,7 @@ import contextlib
 import io
 import json
 import os
+import re
 import sys
 
 import pytest
@@ -43,7 +44,7 @@ def test_record_partial_writes_incrementally():
 
 
 def test_error_line_carries_partials(monkeypatch):
-    bench.record_partial("compute", {"mfu": 0.24})
+    bench.record_partial("serving", {"throughput_req_s": 100.0})
 
     def wedge():
         raise RuntimeError("device probe hung >120s")
@@ -55,7 +56,7 @@ def test_error_line_carries_partials(monkeypatch):
     line = json.loads(buf.getvalue())
     assert rc == 1
     assert line["metric"] == "bench_error"
-    assert line["partial"]["compute"]["mfu"] == 0.24
+    assert line["partial"]["serving"]["throughput_req_s"] == 100.0
 
 
 def test_failed_device_probe_fails_the_run(monkeypatch):
@@ -92,20 +93,33 @@ def test_device_probe_requires_a_tpu_backend(monkeypatch):
     bench.probe_device()
 
 
-def test_unknown_device_kind_has_no_peak(monkeypatch):
-    """A device_kind missing from the peaks table is an error, never
-    another chip's peak (the old ("v5", 459e12) catch-all gave any
-    unknown v5 kind v5p's)."""
-    import jax
+def test_scenarios_are_the_infer_harness_and_each_fails_without_a_chip(
+        monkeypatch, capsys):
+    """The surface: `--help` offers the reference's `/infer` load, its
+    mixed-shape config and the miss-path sweep, and nothing else (the
+    CPU arms are gone; `/generate` is benchmarks/run.py's). Each of them
+    ends non-zero with ONE JSON line naming it when the probe fails."""
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--help"])
+    with pytest.raises(SystemExit) as done:
+        bench._main()
+    assert done.value.code == 0
+    usage = " ".join(capsys.readouterr().out.split())
+    offered = re.search(r"--scenario \{([^}]*)\}", usage).group(1)
+    assert offered.split(",") == ["infer", "mixed", "miss-sweep"]
+    assert "--no-compute" not in usage
 
-    class _Dev:
-        device_kind = "TPU v5 mystery"
+    def no_chip():
+        raise RuntimeError("device probe failed: no TPU found")
 
-    monkeypatch.setattr(jax, "devices", lambda: [_Dev()])
-    with pytest.raises(KeyError, match="TPU v5 mystery"):
-        bench.chip_peak_flops()
-    _Dev.device_kind = "TPU v5 lite"
-    assert bench.chip_peak_flops() == ("TPU v5 lite", 197e12)
+    monkeypatch.setattr(bench, "probe_device", no_chip)
+    for scenario in offered.split(","):
+        monkeypatch.setattr(
+            sys, "argv", ["bench.py", "--scenario", scenario, "--quick"])
+        assert bench.main() == 1
+        (out,) = capsys.readouterr().out.splitlines()
+        line = json.loads(out)
+        assert line["metric"] == "bench_error"
+        assert line["scenario"] == scenario and "partial" not in line
 
 
 def test_error_line_without_partials_stays_clean(monkeypatch):

@@ -16,7 +16,10 @@ engine-lint run surfaced in ``serving/gateway.py`` (membership dicts
 read outside the gateway lock in ``_route_inner``/``_try_node``).
 """
 
+import ast
 import json
+import os
+import re
 import time
 
 import pytest
@@ -97,6 +100,55 @@ def test_baseline_file_sorted_and_deduped():
         data = json.load(f)
     keys = data["findings"]
     assert keys == sorted(set(keys))
+
+
+def _bench_scenarios():
+    """The `choices` of bench.py's `--scenario`, read from its source."""
+    with open(os.path.join(REPO_ROOT, "bench.py"), encoding="utf-8") as f:
+        tree = ast.parse(f.read())
+    (call,) = [n for n in ast.walk(tree)
+               if isinstance(n, ast.Call) and n.args
+               and isinstance(n.args[0], ast.Constant)
+               and n.args[0].value == "--scenario"]
+    return set(ast.literal_eval(
+        next(k.value for k in call.keywords if k.arg == "choices")))
+
+
+_CITING = ("tpu_engine", "tools", "diagnostics.py", "README.md", "DESIGN.md",
+           "MIGRATION.md", ".claude/skills/verify/SKILL.md")
+
+
+def test_nothing_cites_a_deleted_bench_arm_or_a_pre_chip_record():
+    """A speed of this system is a PERF_LEDGER.jsonl line from
+    benchmarks/run.py on the chip; a guarantee is a tier-1 test. So no
+    source file, help string or document sends a reader to a
+    `bench.py --scenario` that bench.py does not accept (only bench.py
+    has that flag), or to a `BENCH_r*` / `MULTICHIP_r*` file: both were
+    CPU runs of a toy model (deleted in PR 31)."""
+    accepted = _bench_scenarios()
+    assert accepted == {"infer", "mixed", "miss-sweep"}
+    # A mention may be broken over lines of a help string or a comment.
+    scenario = re.compile(r"--scenario(?:[\s\"'`#=]|\\n)+([a-z][a-z0-9-]*)")
+    record = re.compile(r"(?:BENCH|MULTICHIP)_r[0-9*]")
+    paths = []
+    for root in _CITING:
+        full = os.path.join(REPO_ROOT, root)
+        if os.path.isfile(full):
+            paths.append(full)
+        for d, _dirs, files in os.walk(full):
+            paths += [os.path.join(d, f) for f in files
+                      if f.endswith((".py", ".md", ".sh", ".json"))]
+    assert len(paths) > 50
+    bad = []
+    for path in paths:
+        with open(path, encoding="utf-8") as f:
+            text = f.read()
+        rel = os.path.relpath(path, REPO_ROOT)
+        bad += [f"{rel}: --scenario {m.group(1)}"
+                for m in scenario.finditer(text)
+                if m.group(1) not in accepted]
+        bad += [f"{rel}: {m.group(0)}" for m in record.finditer(text)]
+    assert not bad, "\n".join(bad)
 
 
 # -- lock discipline ----------------------------------------------------------

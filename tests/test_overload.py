@@ -424,6 +424,63 @@ def test_worker_brownout_clamps_low_tiers_only():
 # -- scheduler brownout application (one compiled scheduler) ------------------
 
 
+def test_fleet_stream_under_the_knee_is_identical_with_control_on():
+    """The control plane decides WHETHER a request runs, never what it
+    says: on an idle fleet of real lanes a background-tier stream
+    through a gateway with every overload feature on (bounded depth,
+    tiered admission, the brownout ladder, the in-flight gauge) carries
+    the tokens the plain fleet's does, and nothing is shed."""
+    import jax
+
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.runtime.engine import InferenceEngine
+    from tpu_engine.serving.gateway import _parse_sse
+    from tpu_engine.serving.worker import WorkerNode
+
+    _ensure_builtin_models_imported()
+    spec = create_model("gpt2-small-test")
+    params = spec.init(jax.random.PRNGKey(0))
+    req = {"request_id": "ident", "prompt_tokens": [5, 9, 3, 7],
+           "max_new_tokens": 12, "priority": "background",
+           "temperature": 0.9, "seed": 11}
+    streams = {}
+    for control in (False, True):
+        lanes = [WorkerNode(WorkerConfig(
+            node_id=f"ov_lane_{i}", model="gpt2-small-test",
+            gen_max_batch_size=2, gen_prefix_cache_mb=0,
+            gen_kv_block_size=16, gen_mixed_step=True,
+            gen_mixed_token_budget=16, gen_prefill_chunk=16,
+            max_queue_depth=2 if control else 0,
+            priority_admission=control, brownout=control,
+            brownout_interval_s=0.15),
+            engine=InferenceEngine(spec, params=params, dtype="float32"))
+            for i in range(2)]
+        gw = Gateway(lanes, GatewayConfig(
+            overload_control=control,
+            overload_max_inflight=8 if control else 0))
+        try:
+            toks = []
+            for frame in gw.route_generate_stream(dict(req)):
+                evt = _parse_sse(frame)
+                if evt and not evt.get("done"):
+                    toks.extend(evt.get("tokens", ()))
+            streams[control] = toks
+            stats = gw.get_stats()
+            if control:
+                assert stats["overload"]["shed_tier"] == 0
+            else:
+                assert "overload" not in stats
+        finally:
+            gw.stop()
+            for w in lanes:
+                w.stop()
+    assert len(streams[False]) == 12
+    assert streams[True] == streams[False]
+
+
 @pytest.fixture(scope="module")
 def bo_sched():
     import jax

@@ -312,6 +312,39 @@ def test_eviction_under_scheduler_pressure(dense, spec, params):
         s.stop()
 
 
+def test_equal_kv_bytes_hold_more_short_rows_than_the_dense_layout(
+        dense, spec, params):
+    """The pool's reason to exist, as a count: given exactly the KV
+    bytes of TWO dense rows (2 x max_seq/16 = 16 blocks + the null
+    block), rows that end at 43 tokens reserve at most 3 blocks each,
+    so five fit (the slot count is sized to that, as an operator would)
+    and the scheduler keeps at least twice as many of them resident at
+    once as the dense layout's two slots — and every stream is the
+    dense scheduler's. Mixed stepping admits the burst's prompts in its
+    first ticks, so the peak does not hang on thread timing."""
+    s = ContinuousGenerator(spec, params=params, dtype="float32",
+                            n_slots=5, max_seq=128, kv_block_size=16,
+                            kv_blocks=2 * (128 // 16) + 1,
+                            prefix_sharing=False, mixed_step=True,
+                            prefill_chunk=16)
+    try:
+        prompts = [[i + 1, i + 2, i + 3] for i in range(8)]
+        s.generate(prompts[:1], max_new_tokens=2)  # warm the executables
+        futs = [s.submit(p, max_new_tokens=40) for p in prompts]
+        peak = 0
+        while any(not f.done() for f in futs):
+            peak = max(peak, s.stats()["active"])
+            time.sleep(0.001)
+        outs = [f.result(60) for f in futs]
+        assert outs == dense.generate(prompts, max_new_tokens=40)
+        assert peak >= 4, peak            # the dense layout holds 2
+        st = s.stats()
+        assert st.get("pool_starved", 0) == 0   # nobody was truncated
+        assert st["kv_pool"]["blocks_free"] == st["kv_pool"]["blocks_total"]
+    finally:
+        s.stop()
+
+
 def test_cancelled_rows_return_blocks(spec, params):
     """Deadline-expired rows — before admission and mid-decode — must
     return every block to the pool."""

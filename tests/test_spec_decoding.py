@@ -29,6 +29,7 @@ fixture's output.
 """
 
 import queue as _queue
+import random
 import time
 
 import jax
@@ -156,6 +157,29 @@ def test_oracle_draft_full_acceptance(spec, params, plain, specgen):
     d_acc = st["accepted_tokens"] - before["accepted_tokens"]
     assert d_acc == d_prop > 0
     assert d_emit / d_ticks >= 2.0, (d_emit, d_ticks)
+
+
+def test_ngram_drafter_on_looping_text_beats_one_token_a_row_tick(
+        plain, specgen):
+    """The same count with the drafter that ships (prompt-lookup
+    n-gram, no stub): on prompts that loop, its proposals are accepted
+    often enough that a row advances well over one token a verify
+    dispatch it takes part in (plain decode: exactly 1; this fixture at
+    k=3 counts 188 tokens in 142 row ticks), and the streams are the
+    plain scheduler's."""
+    rnd = random.Random(42)
+    prompts = [([rnd.randrange(1, 200) for _ in range(6)] * 5)[:24]
+               for _ in range(4)]
+    want = plain.generate(prompts, max_new_tokens=48)
+    before = specgen.stats()["spec"]
+    assert before["draft"] == "ngram"
+    assert specgen.generate(prompts, max_new_tokens=48) == want
+    st = specgen.stats()["spec"]
+    d_emit = st["emitted_tokens"] - before["emitted_tokens"]
+    d_rows = st["row_ticks"] - before["row_ticks"]
+    assert st["accepted_tokens"] > before["accepted_tokens"]
+    assert d_emit / d_rows >= 1.25, (d_emit, d_rows)
+    assert st["ticks"] == st["dispatches"]
 
 
 def test_accepted_counter_counts_stop_on_accepted_draft(plain, specgen):

@@ -350,15 +350,21 @@ def test_penalty_and_stop_controls(spec, params):
         gen.stop()
 
 
-def test_deferred_admission_under_row_exhaustion(spec, params):
+@pytest.mark.parametrize("prompt_len", [2, 20])
+def test_deferred_admission_under_row_exhaustion(spec, params, prompt_len):
     """state_rows binds concurrency: with both usable rows OCCUPIED by
     long streams, two late submissions must PARK (pending_admissions >
     0), then admit as rows free — never fail, never hang (pins the
     from_pending retry gate covering the slab family), and the pool
-    accounts for every row after."""
+    accounts for every row after. The same two rows are resident
+    whether a stream's prompt is 2 tokens or 20 (of max_seq 64): a row
+    costs the same bytes at any sequence length, where a paged row's
+    blocks grow."""
     gen = _gen(spec, params, state_rows=3)  # 2 usable + null
     try:
-        long_futs = [gen.submit([9, i], max_new_tokens=40)
+        bytes_per_row = gen.stats()["state_pool"]["bytes_per_row"]
+        long_futs = [gen.submit([9, i] * (prompt_len // 2),
+                                max_new_tokens=40)
                      for i in range(2)]
         deadline = time.monotonic() + 60
         while (gen.stats()["active"] < 2
@@ -379,6 +385,7 @@ def test_deferred_admission_under_row_exhaustion(spec, params):
         assert st["rows_total"] == 2
         assert st["rows_free"] == 2
         assert st["rows_admitted"] == st["rows_released"] == 4
+        assert st["bytes_per_row"] == bytes_per_row
     finally:
         gen.stop()
 

@@ -138,6 +138,34 @@ def test_scheduler_tp_fences(spec, params):
                             kv_block_size=16)
 
 
+def test_a_block_budget_one_chip_refuses_serves_the_same_row_at_tp2(
+        spec, params):
+    """The model-size unlock in pool terms: at 3 blocks a chip (bs 16,
+    max_seq 64 = 4 blocks a row) one device cannot hold even one
+    full-length row and REFUSES at construction; a tp=2 lane given the
+    same bytes a chip (its blocks hold half the heads each, so twice as
+    many) holds the row and streams a 40-token prompt to the end,
+    byte-identically to an unconstrained single-device lane."""
+    per_chip = 3
+    with pytest.raises(ValueError, match="cannot hold even one max_seq row"):
+        make_gen(spec, params, kv_blocks=per_chip)
+    long_prompt = [(i * 7) % 90 + 1 for i in range(40)]
+    want = run_streams(make_gen(spec, params, mixed_step=True,
+                                mixed_token_budget=32), [long_prompt],
+                       max_new=20)
+    gen = make_gen(spec, params, tp=2, kv_blocks=2 * per_chip,
+                   mixed_step=True, mixed_token_budget=32)
+    try:
+        pool = gen.stats()["kv_pool"]
+        assert pool["tp"] == 2 and pool["blocks_total"] == 2 * per_chip - 1
+        assert gen.generate([long_prompt], max_new_tokens=20) == want
+        st = gen.stats()
+        assert st["mixed"]["ticks"] == st["mixed"]["dispatches"] > 0
+        assert pool_leak_free(st)
+    finally:
+        gen.stop()
+
+
 def test_worker_tp_fences():
     from tpu_engine.serving.worker import WorkerNode
     from tpu_engine.utils.config import WorkerConfig

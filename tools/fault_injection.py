@@ -689,8 +689,8 @@ def drive_streams_with_kill(gw, requests, victim_rids, kill, rng,
                             arrival_rate: float = 8.0,
                             kill_window_s: float = 120.0,
                             kill_when: str = "any"):
-    """The shared chaos drive (also used by ``bench.py --scenario
-    crash-ab`` / ``drain-ab``): fire each request as a /generate/stream
+    """The shared chaos drive of the crash, drain and kill scenarios
+    below: fire each request as a /generate/stream
     through ``gw`` at Poisson arrivals, invoke ``kill()`` once, the
     moment victim-primary streams are provably mid-generation (>= 3
     tokens relayed, not yet finished), then join. ``kill_when="any"``
@@ -795,7 +795,7 @@ def tally_streams(results, control):
 
 def rid_for_lane(ring, lane: str, tag: str, cap: int = 4000) -> str:
     """Mine a request id whose ring primary is ``lane`` (shared by the
-    chaos harness, bench crash-ab, and diagnostics --failover). The
+    chaos harness and diagnostics --failover). The
     reference-faithful FNV-1a ring is SKEWED — its own published split is
     46.8/24.7/38.5 — so similar-prefix candidates can run long streaks on
     one lane; iterate plenty before giving up."""

@@ -1006,7 +1006,7 @@ class BlockPool:
 
     def dense_bytes_per_block(self) -> int:
         """What the SAME block would cost unquantized (at io_dtype) — the
-        equal-byte-budget baseline the quant-ab bench sizes pools by."""
+        equal-byte-budget baseline `capacity_multiplier` is quoted against."""
         return dense_block_bytes(self.cfg, self.block_size, self.io_dtype)
 
     def _demoted_nodes(self) -> int:
@@ -1087,7 +1087,7 @@ class StateSlabPool:
     row per live stream, CONSTANT in sequence length. The paged pool's
     "KV capacity" becomes "state capacity" here: a row costs the same
     HBM at token 1 and token 100k, so peak concurrent rows are
-    independent of stream length (bench.py --scenario recurrent-ab).
+    independent of stream length (tests/test_ssd.py holds the count).
 
     Same host-side discipline as ``BlockPool`` — one lock over the free
     list/refcounts that ALSO orders pool-touching device dispatches
@@ -1263,8 +1263,8 @@ class StateSlabPool:
 
     def bytes_per_row(self) -> int:
         """HBM bytes ONE stream's whole autoregressive state costs —
-        constant in sequence length (the family's capacity story; the
-        recurrent-ab bench sizes equal-HBM arms with this and
+        constant in sequence length (the family's capacity story; an
+        equal-HBM comparison with a paged pool sizes both with this and
         dense_block_bytes, never a re-derivation)."""
         return int(self.n_layers * self.state_dim
                    * jnp.zeros((), self._dtype).dtype.itemsize)

@@ -2744,8 +2744,8 @@ class ContinuousGenerator:
         """Device dispatches issued by the ADMISSION side of the two-path
         scheduler (prefill forwards/windows, prefix gathers, row
         scatters) — the dispatches mixed stepping folds into the decode
-        tick. `bench.py --scenario mixed-ab` reads chunks +
-        admission_dispatches as the baseline's dispatch count. Lock: the
+        tick. chunks + admission_dispatches is the two-path lane's
+        dispatch count, beside the mixed lane's `dispatches`. Lock: the
         prefill and decode threads both increment."""
         with self._stats_lock:
             self._stats["admission_dispatches"] = (

@@ -131,7 +131,7 @@ class InProcessLaneProvider:
     """Spawn lanes as in-process worker objects from a factory —
     ``factory(index) -> WorkerNode``-like object with a ``node_id`` and
     ``get_health()``. Powers ``serve_combined --autoscale`` and the
-    ``bench.py --scenario elastic-ab`` elastic arm, where a "lane" is a
+    in-process fleets of ``tests/test_autoscaler.py``, where a "lane" is a
     scheduler instance, not a remote process. Retired lanes are looked
     up by either the object or its lane NAME (the controller retires by
     name), stopped, and reported to ``on_retire`` so the host app can

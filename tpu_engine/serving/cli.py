@@ -842,7 +842,7 @@ def main(argv=None) -> int:
                                  "blocks back in asynchronously instead "
                                  "of recomputing its prefill — host RAM "
                                  "becomes prefix-cache capacity "
-                                 "(bench.py --scenario affinity-ab). "
+                                 "(tests/test_kv_offload.py). "
                                  "0 = off")
         parser.add_argument("--kv-quantize", default="",
                             choices=("", "int8"),
@@ -852,7 +852,7 @@ def main(argv=None) -> int:
                                  "scales, quantized once at block write "
                                  "and dequantized inside the paged "
                                  "attention read — ~2x blocks on the same "
-                                 "HBM (bench.py --scenario quant-ab). "
+                                 "HBM (tests/test_kv_quant.py). "
                                  "Greedy streams stay deterministic but "
                                  "are not byte-identical to the bf16 "
                                  "pool. Default off = today's pool")
@@ -864,7 +864,7 @@ def main(argv=None) -> int:
                                  "(n_layers, state_dim) f32 row for its "
                                  "whole life — peak concurrent rows are "
                                  "independent of sequence length, "
-                                 "bench.py --scenario recurrent-ab. "
+                                 "tests/test_ssd.py. "
                                  "0 = auto: decode slots + 1)")
         parser.add_argument("--tp", type=int, default=None,
                             help="tensor-parallel serving (needs "
@@ -873,8 +873,8 @@ def main(argv=None) -> int:
                                  "local devices on a `model`-axis mesh "
                                  "— registry-declared param placement, "
                                  "H_kv-sharded KV pool, one SPMD "
-                                 "ragged dispatch per tick (bench.py "
-                                 "--scenario tp-ab); default lane "
+                                 "ragged dispatch per tick (tests/"
+                                 "test_tp_serving.py); default lane "
                                  "count becomes devices//tp; "
                                  "unshardable families (mamba2) "
                                  "refuse at startup (unset/1 = "
@@ -916,7 +916,7 @@ def main(argv=None) -> int:
                                  "chain peer-to-peer (checksum-verified) "
                                  "instead of re-prefilling — every "
                                  "failure falls back to local prefill "
-                                 "(bench.py --scenario fleet-prefix-ab)")
+                                 "(tests/test_fleet_prefix.py)")
         parser.add_argument("--prefix-fetch-timeout", type=float,
                             default=None,
                             help="per-fetch peer budget in seconds "
@@ -928,7 +928,7 @@ def main(argv=None) -> int:
                                  "rows (1 token each) and admitting rows' "
                                  "prefill chunks together — long prompts "
                                  "stop spiking in-flight rows' inter-token "
-                                 "latency (bench.py --scenario mixed-ab)")
+                                 "latency (tests/test_mixed_step.py)")
         parser.add_argument("--mixed-token-budget", type=int, default=0,
                             help="new tokens per mixed tick (decode rows "
                                  "count 1 each; the rest splits over "
@@ -944,7 +944,7 @@ def main(argv=None) -> int:
                                  "verifies every window — rows advance "
                                  "1..k+1 tokens per dispatch, greedy "
                                  "streams byte-identical to plain decode "
-                                 "(bench.py --scenario spec-ab). 0 = off")
+                                 "(tests/test_spec_decoding.py). 0 = off")
         parser.add_argument("--spec-draft", choices=["ngram", "model"],
                             default="ngram",
                             help="drafter for --spec-k: ngram = host-side "
@@ -975,8 +975,8 @@ def main(argv=None) -> int:
                                  "to a decode lane picked by load — "
                                  "zero re-prefilled tokens, every "
                                  "failure falls back to local decode "
-                                 "or the replay resume (bench.py "
-                                 "--scenario disagg-ab)")
+                                 "or the replay resume (tests/"
+                                 "test_disagg.py)")
         parser.add_argument("--handoff-timeout", type=float, default=None,
                             help="per-stream prefill→decode handoff "
                                  "budget in seconds, clamped to the "

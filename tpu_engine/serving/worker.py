@@ -836,7 +836,7 @@ class WorkerNode:
         draft_spec, draft_params = self._resolve_draft_spec()
         if draft_params is None:
             # A random-init draft accepts ~nothing: the lane degrades to
-            # pure overhead (bench.py spec-ab's measured floor). Loud
+            # pure overhead (nothing is accepted, every round pays). Loud
             # warning, not an error — random drafts are the test fixture.
             print(f"[{self.node_id}] WARNING: speculative draft "
                   f"'{draft_spec.name}' is randomly initialized (no "

@@ -51,9 +51,9 @@ class WorkerConfig:
     # "batch": collect a batch, decode it to completion (generator.py).
     # "continuous": iteration-level scheduling — requests join/leave the
     # running decode batch between chunks (scheduler.py). Continuous is the
-    # default: under Poisson arrivals it was reported at several times the
-    # batch lane's tokens/s on an earlier stack (bench.py --scenario
-    # decode-ab); not measured on this one.
+    # default: requests join and leave between chunks instead of waiting
+    # for a whole batch to finish. Its speed against the batch lane is not
+    # measured on this stack (no cell runs the batch lane).
     # "speculative": batch-mode lane where a DRAFT model proposes
     # gen_spec_k tokens per round and the target verifies them in one
     # windowed pass (runtime.speculative); temperature sampling only.
