@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from tpu_engine.models.registry import ModelSpec, register
 from tpu_engine.models.transformer import (
     TransformerConfig,
+    step_weights,
     transformer_apply,
     transformer_init,
 )
@@ -55,6 +56,10 @@ def _spec_from_config(name: str, cfg: TransformerConfig, seq_len: int) -> ModelS
         # this helper (gpt2, distilgpt2, llama, mistral; MoE expert
         # banks ride replicated under the catch-all).
         tp_rule="transformer",
+        # The dense step's kernels are cast to the lane's dtype once, not
+        # every tick (transformer.step_weights); an MoE FFN casts its own
+        # expert banks (ops.moe), so that family's steps read `params`.
+        step_weights=step_weights if cfg.n_experts == 0 else None,
     )
 
 

@@ -222,6 +222,11 @@ class ModelSpec:
     # Resolved through tp_shardings / tp_unshardable_reason — consumers
     # fence on the declaration, never on isinstance.
     tp_rule: str = ""
+    # (params, dtype) -> the tree a lane's compiled steps read instead of
+    # `params` (runtime.scheduler builds it once, when the lane gets its
+    # weights). None: the steps read `params` itself — every family whose
+    # step has no per-tick cast to save, or casts what it must itself.
+    step_weights: Optional[Callable] = None
 
     def __post_init__(self):
         if not self.state_family:

@@ -22,6 +22,8 @@ mlp proj shard their input dim; embeddings replicate or shard on vocab.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import re
 from typing import Optional, Tuple
 
 import jax
@@ -166,6 +168,42 @@ def transformer_init(key, cfg: TransformerConfig):
         params["type_embed"] = nn.embedding_init(k_type, cfg.type_vocab,
                                                  cfg.d_model)
     return params
+
+
+# The leaves a compiled step hands to `nn.dense(..., dtype=dtype)`: the
+# attention projections, the dense MLP's and the LM head ('/'-joined
+# paths, as the registry's TP rules name them).
+_STEP_KERNELS = re.compile(
+    r"^(blocks/attn/w[qkvo]|blocks/mlp/(fc|gate|up|proj)|head)/kernel$")
+
+
+@functools.partial(jax.jit, static_argnames="dtype")
+def _cast_leaves(leaves, dtype):
+    return [leaf.astype(dtype) for leaf in leaves]
+
+
+def step_weights(params, dtype):
+    """The tree a lane's compiled steps read: `params` with the kernels
+    of `_STEP_KERNELS` cast to the step's dtype ONCE, by the `astype`
+    `nn.dense` would apply to them every tick (there it is then the
+    identity, and the tick has no cast left to hoist). Every other leaf
+    — biases, norm scales, embedding tables, a weight-quantized
+    `kernel_q` — IS the array in `params`, and so is a kernel already in
+    `dtype`; with nothing to cast, `params` itself comes back. The casts
+    run under one jit, so each copy stays where (and sharded as) its
+    master is."""
+    dtype = jnp.dtype(dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    leaves = [leaf for _, leaf in flat]
+    cast = [i for i, (path, leaf) in enumerate(flat)
+            if leaf.dtype != dtype and _STEP_KERNELS.search(
+                jax.tree_util.keystr(path, simple=True, separator="/"))]
+    if not cast:
+        return params
+    for i, leaf in zip(cast, _cast_leaves([leaves[i] for i in cast],
+                                          dtype)):
+        leaves[i] = leaf
+    return treedef.unflatten(leaves)
 
 
 def _mlp(params, h, dtype, cfg: TransformerConfig = None):

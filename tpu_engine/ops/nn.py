@@ -7,8 +7,12 @@ parallelism shards these dicts directly) and lets XLA fuse elementwise work
 into the surrounding matmuls/convs.
 
 Conventions: NHWC activations, HWIO conv kernels (TPU-native layouts),
-bfloat16-friendly — params are stored float32 and cast at apply time so the
-MXU runs bf16 while accumulation stays f32.
+bfloat16-friendly — master params are stored float32; `dense` and `conv2d`
+cast a kernel that is not yet in the apply dtype, so the MXU runs bf16
+while accumulation stays f32. A caller that applies the same kernels again
+and again hands them over already cast (a generation lane's compiled steps
+read `models.transformer.step_weights`' tree, made once when the lane gets
+its weights): the cast here is then the identity, and no step repeats it.
 """
 
 from __future__ import annotations

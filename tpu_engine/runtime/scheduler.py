@@ -494,6 +494,7 @@ class ContinuousGenerator:
                                           self._tp_mesh))
         elif device is not None:
             self.params = jax.device_put(self.params, device)
+        self._place_step_params()
 
         # Device state: one persistent KV cache + per-row vectors. Paged
         # mode replaces the dense per-slot cache with a block pool +
@@ -2389,6 +2390,28 @@ class ContinuousGenerator:
                 for i, p in enumerate(prompts)]
         return [f.result(timeout=600) for f in futs]
 
+    def _place_step_params(self) -> None:
+        """Build `_step_params`, the tree every compiled step of this
+        lane is handed: what the model's family declares
+        (`ModelSpec.step_weights`: the dense transformer's kernels cast
+        to the lane's dtype once, where the step would cast them every
+        tick), else `self.params` itself. `self.params` stays the master
+        tree — the engine's, `/infer`'s and `/score`'s; the drafter keeps
+        its own. `_weights` (stats) counts the master's bytes and the
+        step tree's OWN leaves, the copies: 0 where the steps read the
+        master tree itself."""
+        make = getattr(self.spec, "step_weights", None)
+        step = make(self.params, self._dtype) if make else self.params
+        master = jax.tree.leaves(self.params)
+        self._weights = {
+            "master_bytes": sum(leaf.nbytes for leaf in master),
+            "step_bytes": sum(
+                copy.nbytes for copy, leaf
+                in zip(jax.tree.leaves(step), master) if copy is not leaf),
+            "step_dtype": jnp.dtype(self._dtype).name,
+        }
+        self._step_params = step
+
     def set_params(self, params) -> None:
         """Hot weight swap. The prefix cache holds (logits, KV) computed
         under the OLD weights — serving them against new weights would mix
@@ -2399,6 +2422,7 @@ class ContinuousGenerator:
         subsequent chunks use the new weights (acceptable for a reload;
         stop the scheduler first for a hard cut)."""
         self.params = params
+        self._place_step_params()
         self._prefix_cache = _PrefixCache(self._prefix_cache.budget)
         if self._paged:
             with self._pool.lock:
@@ -2447,7 +2471,8 @@ class ContinuousGenerator:
                    active=int(sum(r is not None for r in rows)),
                    last_tick_age_s=round(age, 3),
                    prefix_cache=self._prefix_cache.stats(),
-                   compile=self._compiles.snapshot())
+                   compile=self._compiles.snapshot(),
+                   weights=dict(self._weights))
         if self._mixed:
             # Snapshot, not the live nested dict — callers diff stats()
             # across time (bench warm-up subtraction) and must not see
@@ -3081,7 +3106,7 @@ class ContinuousGenerator:
                 width = min(w, pb - w0)
                 head = "all" if w0 <= Leff - 1 < w0 + width else "none"
                 wlog, row_caches = win_exe(
-                    self.params, jnp.asarray(tokens[:, w0:w0 + width]),
+                    self._step_params, jnp.asarray(tokens[:, w0:w0 + width]),
                     row_caches, jnp.asarray([w0], jnp.int32),
                     jnp.asarray([0], jnp.int32), head)
                 self._count_admission_dispatch()
@@ -3236,7 +3261,7 @@ class ContinuousGenerator:
             if L:
                 tokens[0, :n_valid] = prompt[w0:w0 + n_valid]
             logits, conv, ssm = win_exe(
-                self.params, jnp.asarray(tokens), conv, ssm,
+                self._step_params, jnp.asarray(tokens), conv, ssm,
                 jnp.asarray([n_valid], jnp.int32))
             self._count_admission_dispatch()
         first_tok, row_counts = self._first_token(req, logits, prompt, L)
@@ -3362,7 +3387,7 @@ class ContinuousGenerator:
                     # its last slot only.
                     head = "last" if w0 == starts[-1] else "none"
                     wlog, row_caches = win_exe(
-                        self.params,
+                        self._step_params,
                         jnp.asarray(tokens[:, w0:min(w0 + w, pb)]),
                         row_caches, jnp.asarray([w0], jnp.int32),
                         start_vec, head)
@@ -3370,7 +3395,7 @@ class ContinuousGenerator:
                 logits = wlog[0, -1]
             else:
                 logits, row_caches = self._prefill()(
-                    self.params, jnp.asarray(tokens), jnp.asarray(attn),
+                    self._step_params, jnp.asarray(tokens), jnp.asarray(attn),
                     jnp.asarray(pos_ids))
                 self._count_admission_dispatch()
             if prefix_cache.budget > 0:
@@ -4469,7 +4494,7 @@ class ContinuousGenerator:
             pool_args = (pool.caches,)
             if self._quant:
                 pool_args += (pool.scales,)
-            common = (self.params, *pool_args, jnp.asarray(self._tables),
+            common = (self._step_params, *pool_args, jnp.asarray(self._tables),
                       jnp.asarray(tokens), jnp.asarray(pos0),
                       jnp.asarray(qlen), jnp.asarray(sample_slot),
                       jnp.asarray(fold_pos), jnp.asarray(active),
@@ -4683,7 +4708,7 @@ class ContinuousGenerator:
             pool_args = (pool.caches,)
             if self._quant:
                 pool_args += (pool.scales,)
-            common = (self.params, *pool_args, jnp.asarray(self._tables),
+            common = (self._step_params, *pool_args, jnp.asarray(self._tables),
                       jnp.asarray(tokens), jnp.asarray(pos0),
                       jnp.asarray(qlen), jnp.asarray(sample_slot),
                       jnp.asarray(fold0), jnp.asarray(n_draft),
@@ -4818,7 +4843,7 @@ class ContinuousGenerator:
         # Slab-donating dispatch under the pool lock (exports and
         # admission writes order against it).
         with spool.lock:
-            common = (self.params, spool.slab, jnp.asarray(row_ids),
+            common = (self._step_params, spool.slab, jnp.asarray(row_ids),
                       jnp.asarray(self._tok), jnp.asarray(self._pos),
                       jnp.asarray(done_in), jnp.asarray(self._seeds),
                       jnp.asarray(self._temps), jnp.asarray(self._topps),
@@ -4940,7 +4965,7 @@ class ContinuousGenerator:
 
         # ONE dispatch, under the pool lock (it donates the slab).
         with spool.lock:
-            common = (self.params, spool.slab, jnp.asarray(row_ids),
+            common = (self._step_params, spool.slab, jnp.asarray(row_ids),
                       jnp.asarray(tokens), jnp.asarray(qlen),
                       jnp.asarray(sample_slot), jnp.asarray(fold_pos),
                       jnp.asarray(step_ok), jnp.asarray(active),
@@ -5240,7 +5265,7 @@ class ContinuousGenerator:
                         pool_args = (self._pool.caches,)
                         if self._quant:
                             pool_args += (self._pool.scales,)
-                        common = (self.params, *pool_args,
+                        common = (self._step_params, *pool_args,
                                   jnp.asarray(self._tables),
                                   jnp.asarray(self._tok),
                                   jnp.asarray(self._pos),
@@ -5271,7 +5296,8 @@ class ContinuousGenerator:
                 elif controls:
                     (self._caches, tok, pos, done, self._counts,
                      toks) = self._decode(True)(
-                        self.params, self._caches, jnp.asarray(self._tok),
+                        self._step_params, self._caches,
+                        jnp.asarray(self._tok),
                         jnp.asarray(self._pos), jnp.asarray(self._start),
                         jnp.asarray(self._done), jnp.asarray(self._seeds),
                         jnp.asarray(self._temps), jnp.asarray(self._topps),
@@ -5281,7 +5307,8 @@ class ContinuousGenerator:
                         jnp.asarray(self._stops))
                 else:
                     self._caches, tok, pos, done, toks = self._decode(False)(
-                        self.params, self._caches, jnp.asarray(self._tok),
+                        self._step_params, self._caches,
+                        jnp.asarray(self._tok),
                         jnp.asarray(self._pos), jnp.asarray(self._start),
                         jnp.asarray(self._done), jnp.asarray(self._seeds),
                         jnp.asarray(self._temps), jnp.asarray(self._topps),

@@ -457,7 +457,7 @@ def serve_combined(
         dense deployments)."""
         out = gateway.get_stats()
         kv, mixed, spec, state, pfetch = {}, {}, {}, {}, {}
-        stateless = {}
+        stateless, weights = {}, {}
         for w in workers:
             gen = getattr(w, "generator", None)
             if gen is None or not hasattr(gen, "stats"):
@@ -473,6 +473,11 @@ def serve_combined(
                 stateless[w.node_id] = st["stateless"]
             if st.get("kv_pool"):
                 kv[w.node_id] = st["kv_pool"]
+            if st.get("weights", {}).get("step_bytes"):
+                # Lanes that keep a copy of the step's kernels in the
+                # step's dtype beside the master tree (absent where the
+                # steps read the master itself).
+                weights[w.node_id] = st["weights"]
             if st.get("prefix_fetch"):
                 # Fleet prefix tier, lane half: peer-fetch attempts and
                 # fallback rungs per lane (present only once a hint was
@@ -501,6 +506,8 @@ def serve_combined(
             out["prefix_fetch"] = pfetch
         if stateless:
             out["stateless"] = stateless
+        if weights:
+            out["weights"] = weights
         return 200, out
 
     routes[("GET", "/stats")] = _stats

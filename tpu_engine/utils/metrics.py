@@ -335,6 +335,19 @@ def render_prometheus(healths: List[Dict], stats: Optional[Dict] = None,
            "Seconds spent building them",
            [(node(h), c.get("seconds")) for h, c in cp])
 
+    # What the lane keeps of its weights: the master tree, and the copy
+    # of the step's kernels in the step's dtype where the model's family
+    # declares one (ModelSpec.step_weights; 0 = the steps read the master).
+    wt = [(h, g.get("weights")) for h, g in gen
+          if isinstance(g, dict) and g.get("weights")]
+    metric("tpu_engine_weights_master_bytes", "gauge",
+           "Bytes of the lane's master parameter tree",
+           [(node(h), w.get("master_bytes")) for h, w in wt])
+    metric("tpu_engine_weights_step_bytes", "gauge",
+           "Bytes of the kernels copied once into the step's dtype",
+           [({**node(h), "dtype": w.get("step_dtype")}, w.get("step_bytes"))
+            for h, w in wt])
+
     # Mixed prefill+decode stepping (continuous scheduler --mixed-step):
     # one ragged dispatch per tick — ticks and dispatches are counted at
     # different sites precisely so scrapers can assert they stay equal.
