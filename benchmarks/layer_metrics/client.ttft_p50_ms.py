@@ -1,9 +1,10 @@
 """Median time to first token as the client saw it in the traced run, from
-when each request was due, in milliseconds: the same arithmetic as the
-end-to-end `ttft_p50_ms`, kept as an unbounded reading in the cells where
-about fifty requests a window make the median too unsteady to carry a bound
-(PERF.md, section 2). Layer: client. Moves itl_p95_ms (below the knee both
-are set by the length of a prefill tick)."""
+when each request was due, in milliseconds: the arithmetic of the end-to-end
+`ttft_mean_ms` with a median in the mean's place, kept as an unbounded
+reading in the cells whose TTFT is judged by its mean: about fifty requests
+a window (chat) or some hundred quantised to ticks (docqa, where it was the
+judged `ttft_p50_ms` until PR 35) make the median too unsteady to carry a
+bound (PERF.md, section 2). Layer: client. Moves ttft_mean_ms."""
 
 from lib.metrics import percentile, ttft_ms
 

@@ -1,5 +1,5 @@
 """Median time the gateway spent routing one request (its `route` spans),
-in milliseconds. Layer: HTTP front and gateway. Moves ttft_p50_ms."""
+in milliseconds. Layer: HTTP front and gateway. Moves ttft_mean_ms."""
 
 from lib.metrics import percentile
 

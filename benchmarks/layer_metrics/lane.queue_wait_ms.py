@@ -1,6 +1,6 @@
 """Median time a request waited in the lane's admission queue before the
 scheduler took it (the `queue_wait` stage spans of every lane), in
-milliseconds. Layer: lane and admission. Moves ttft_p50_ms."""
+milliseconds. Layer: lane and admission. Moves ttft_mean_ms."""
 
 from lib.metrics import lane_spans, percentile
 

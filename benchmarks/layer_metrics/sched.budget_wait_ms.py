@@ -3,7 +3,7 @@ while it was prefilling: the `starved_us` attr of the window's `prefill`
 spans (the token budget goes to the lowest-numbered prefilling row), in
 milliseconds. The mean and not the median, because the finding is a few
 requests that wait for tens of seconds among many that wait for none.
-Layer: scheduler tick. Moves ttft_p50_ms."""
+Layer: scheduler tick. Moves ttft_mean_ms."""
 
 from lib.metrics import lane_spans
 

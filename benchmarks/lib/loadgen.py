@@ -21,6 +21,19 @@ the bounds do not cover it. A closed loop's clients start STAGGER_S apart, so
 that the order in which the server sees the first requests is not a race
 between threads. Every request is greedy (temperature 0).
 
+A closed loop whose file says `"wave": true` sends in waves: its clients send
+together, with no stagger, and each sends its next request only when ALL of
+the wave have completed, as a job that calls generate on a batch of prompts
+and then on the next. Client k's n-th request is the plan's (n * clients +
+k)-th, so a wave is one block of the plan. What the file fixes by this is
+where a wave's prefill ticks fall: together, at the wave's start, however
+long any tick takes (PERF.md, PR 35). With `"lead_ms": m` client 0 sends
+at the wave's start and the others m milliseconds after it: an idle lane
+begins its first tick on the first request it sees, so without a lead how
+many of a wave's requests that tick holds, and with it how many ticks the
+wave's prompts take, is a race between the clients' sends and the lane's
+wake-up; with it the first tick holds one request, every time.
+
 Clock: time.monotonic(), which on Linux is one clock for every process of
 the machine; the parent hands over the window's start on that clock.
 
@@ -239,6 +252,29 @@ def run_open(plan, args, out, lock):
     return threads
 
 
+def send_closed(item, args, out, lock):
+    """One request of a closed loop: due when it is sent, cut off at the
+    window's end."""
+    sent = time.monotonic()
+    result = stream_request(args.port, f"{args.tag}-{item['i']}", item,
+                            args.request_timeout,
+                            cutoff=args.t0 + args.seconds)
+    with lock:
+        out.append(record(item, args.t0, sent, sent, result))
+
+
+def plan_ran_out(plan, args, out, lock):
+    with lock:
+        out.append(failed(len(plan), args.t0, None, "the plan ran out before "
+                          "the window ended: raise the traffic file's pool"))
+
+
+def started(threads):
+    for t in threads:
+        t.start()
+    return threads
+
+
 def run_closed(plan, args, out, lock, clients):
     """`clients` threads, started STAGGER_S apart; each takes the plan's
     next request when its last completed, until the window ends."""
@@ -252,22 +288,51 @@ def run_closed(plan, args, out, lock, clients):
         while time.monotonic() < end:
             with lock:
                 item = next(cursor, None)
-                if item is None:   # the load fell short of the window
-                    out.append(failed(
-                        len(plan), args.t0, None, "the plan ran out before "
-                        "the window ended: raise the traffic file's pool"))
-                    return
-            sent = time.monotonic()
-            result = stream_request(args.port, f"{args.tag}-{item['i']}",
-                                    item, args.request_timeout, cutoff=end)
-            with lock:
-                out.append(record(item, args.t0, sent, sent, result))
+            if item is None:       # the load fell short of the window
+                return plan_ran_out(plan, args, out, lock)
+            send_closed(item, args, out, lock)
 
-    threads = [threading.Thread(target=client, args=(k,), daemon=True)
-               for k in range(clients)]
-    for t in threads:
-        t.start()
-    return threads
+    return started([threading.Thread(target=client, args=(k,), daemon=True)
+                    for k in range(clients)])
+
+
+def run_waves(plan, args, out, lock, clients, lead_s=0.0):
+    """`clients` threads that meet before every request: they send
+    together, client k the plan's (n * clients + k)-th request in wave n,
+    and nobody sends before the last of the wave before completed. Whether
+    the window is over is decided once a wave, for all of them. With a
+    lead the others send `lead_s` after client 0 went ahead."""
+    end = args.t0 + args.seconds
+    go, ahead = {}, threading.Event()
+
+    def decide():                  # run by one client while the rest wait
+        ahead.clear()
+        go["on"] = time.monotonic() < end
+
+    meet = threading.Barrier(clients, action=decide)
+
+    def client(k):
+        delay = args.t0 - time.monotonic()
+        if delay > 0:
+            time.sleep(delay)
+        try:
+            for n in range(k, len(plan), clients):
+                meet.wait()
+                if not go["on"]:
+                    return
+                if k == 0:
+                    ahead.set()
+                elif lead_s > 0:
+                    ahead.wait(timeout=10.0)
+                    time.sleep(lead_s)
+                send_closed(plan[n], args, out, lock)
+        except threading.BrokenBarrierError:
+            return
+        meet.abort()               # the others must not wait for this one
+        plan_ran_out(plan, args, out, lock)
+
+    return started([threading.Thread(target=client, args=(k,), daemon=True)
+                    for k in range(clients)])
 
 
 def main(argv=None):
@@ -309,7 +374,12 @@ def main(argv=None):
     if traffic["loop"] == "open":
         threads = run_open(plan, args, out, lock)
     else:
-        threads = run_closed(plan, args, out, lock, int(traffic["clients"]))
+        clients = int(traffic["clients"])
+        if traffic.get("wave"):
+            threads = run_waves(plan, args, out, lock, clients,
+                                float(traffic.get("lead_ms", 0)) / 1e3)
+        else:
+            threads = run_closed(plan, args, out, lock, clients)
     limit = args.t0 + args.seconds + args.drain
     for t in threads:
         t.join(timeout=max(0.0, limit - time.monotonic()))

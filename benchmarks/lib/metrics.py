@@ -45,17 +45,23 @@ def ttft_ms(records):
             if r["ok"] and r["first"] is not None]
 
 
+def itl_gaps(records):
+    """(start, end, tokens) of every gap between two token events of a
+    request that did not fail: what the inter-token samples are made of."""
+    for r in records:
+        if r["ok"]:
+            events = r["events"]
+            for (t_prev, _), (t, n) in zip(events, events[1:]):
+                yield t_prev, t, n
+
+
 def itl_ms(records):
     """The gap between output tokens, one sample per token after a
     request's first event: an event that carries n tokens after a gap g
     gives n samples of g / n."""
     out = []
-    for r in records:
-        if not r["ok"]:
-            continue
-        events = r["events"]
-        for (t_prev, _), (t, n) in zip(events, events[1:]):
-            out.extend([1e3 * (t - t_prev) / n] * n)
+    for t_prev, t, n in itl_gaps(records):
+        out.extend([1e3 * (t - t_prev) / n] * n)
     return out
 
 

@@ -41,6 +41,8 @@ The run object handed to benchmarks/layer_metrics/<metric>.py `compute(run)`:
   peaks         lib/peaks.json's entry for this device kind
   device        the `device` object of the last line
   seconds       the window's length
+  window_start  the window's start on the clock of the spans' `start_ts`:
+                a record's times count from it
   config        the configuration file's dict: the sizes a reader counts
                 operations and bytes from (lib/roofline.py has the counting)
   cell          the cell's entry of `workloads`
@@ -237,6 +239,17 @@ def decide_correct(cell, served, seed):
     return bool(ok and repeat_same), details
 
 
+def compared(details):
+    """Each number `correct` compared, beside its limit."""
+    return {"worst_gap_in_logit_std": {
+                "value": details["worst_gap_in_logit_std"],
+                "at_most": details["tolerance"]},
+            "exact_share": {"value": details["exact_share"],
+                            "at_least": details["min_exact_share"]},
+            "repeat_identical": {"value": int(details["repeat_identical"]),
+                                 "at_least": 1}}
+
+
 # -- set-up -------------------------------------------------------------------
 
 def bring_up(cell, seed):
@@ -382,7 +395,7 @@ def measure(cell, served, device, peaks, seed, seconds, trace):
            "pool_samples": [s for s in sampler.samples
                             if t0 <= s["t"] < t0 + seconds],
            "trace": reduced, "records": records, "peaks": peaks,
-           "device": device, "seconds": seconds,
+           "device": device, "seconds": seconds, "window_start": wall0,
            "config": cell["config"], "cell": cell["cell"],
            "slice": sampler.traced}
     result["metrics"] = {}
@@ -424,7 +437,13 @@ def run_cell(args):
             "device": result["device"]}
     if "breakdown" in result:
         line["breakdown"] = result["breakdown"]
+    # What decided `correct` comes last, here and on standard error: the
+    # check keeps the end of each where a run reads not correct.
+    line["compared"] = compared(details)
     print(json.dumps(line), flush=True)
+    for name, pair in line["compared"].items():
+        print(f"benchmark: correct: {name} {json.dumps(pair)}",
+              file=sys.stderr, flush=True)
     return 0
 
 

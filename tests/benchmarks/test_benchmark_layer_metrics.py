@@ -117,6 +117,13 @@ RUN["config"] = {"kwargs": {"n_layers": 2, "d_model": 64, "n_heads": 4,
                  "serving": {"dtype": "bfloat16"}}
 RUN["peaks"] = {"hbm_bytes_per_s": 2.56e6, "bf16_flops_per_s": 1e12}
 RUN["cell"] = {"name": "made-up", "chips": 2}
+# PR 35: the window began at 98.4 on the spans' clock, so worker_2's
+# width-256 tick (99.9 to 100.3) has its midpoint 1.7 s into the window,
+# inside the second request's first gap; that request's last event brings
+# two tokens at once. Four samples, one across the prefill tick.
+RUN["window_start"] = 98.4
+RUN["records"][1]["events"] = [[1.5, 1], [1.9, 1], [2.0, 2]]
+RUN["records"][2]["events"] = [[2.3, 1], [2.4, 1]]
 
 WANT = {
     "client.ttft_p50_ms": 300.0,
@@ -141,10 +148,13 @@ WANT = {
     "device.idle_host": 16.0,
     # PR 27's
     "kernel.paged_attn_roofline": 50.0,
+    # PR 35's
+    "sched.itl_prefill_share": 25.0,
 }
 SINCE_PR_25 = {"sched.host_gap_ms", "step.decode_device_ms",
                "step.prefill_device_ms", "sched.budget_wait_ms",
-               "lane.slot_wait_ms", "lane.ttft_p50_ms", "step.compiles"}
+               "lane.slot_wait_ms", "lane.ttft_p50_ms", "step.compiles",
+               "sched.itl_prefill_share"}
 
 
 def _listed():
@@ -242,3 +252,25 @@ def test_the_roofline_of_a_real_shape_on_the_real_peaks_is_far_under_100():
     share = _reader("kernel.paged_attn_roofline")(run)
     assert share == pytest.approx(100 * (1038090240 / 819e9) / 0.033)
     assert 3.0 < share < 5.0
+
+
+# -- PR 35's reader ------------------------------------------------------------
+
+def test_an_event_of_n_tokens_across_a_prefill_tick_counts_n_times():
+    """The samples are `itl_p95_ms`'s own: an event that brings n tokens
+    after a gap gives n samples, all across the tick or none."""
+    reader = _reader("sched.itl_prefill_share")
+    run = copy.deepcopy(RUN)
+    run["records"][1]["events"] = [[1.5, 1], [1.9, 3], [2.0, 1]]
+    assert reader(run) == pytest.approx(100.0 * 3 / 5)
+    # Decode ticks' midpoints count for nothing; a window without a prefill
+    # tick reads 0, not nothing: every sample was looked at.
+    for span in run["spans"]["worker_2"]:
+        if span["op"] == "mixed_step":
+            span["attrs"]["width"] = 1
+    run["spans"]["worker_1"] = []
+    assert reader(run) == 0.0
+    # A failed request has no samples; no sample at all reads nothing.
+    for r in run["records"]:
+        r["ok"] = False
+    assert reader(run) is None

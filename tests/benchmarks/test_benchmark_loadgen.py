@@ -1,8 +1,12 @@
 """The load generator's plan and the arithmetic from records to metrics."""
 
+from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 import json
 import os
 import sys
+import threading
+import time
+from types import SimpleNamespace
 
 import pytest
 
@@ -102,6 +106,182 @@ def test_shared_prefixes_are_data_not_code():
     assert all(len(item["prompt"]) >= 64 + 16 for item in plan)
 
 
+# -- the closed loop against a stub server ------------------------------------
+
+class _Stub(BaseHTTPRequestHandler):
+    """Two token events and the terminal event; request i answers after
+    30 ms x (i % 4), so a wave's requests end at different times."""
+
+    def do_POST(self):
+        body = json.loads(self.rfile.read(int(self.headers["Content-Length"])))
+        self.send_response(200)
+        self.end_headers()
+        time.sleep(0.03 * (body["seed"] % 4))
+        try:
+            for frame in ({"tokens": [1]}, {"tokens": [2]}, {"done": True}):
+                self.wfile.write(b"data: " + json.dumps(frame).encode()
+                                 + b"\n\n")
+                self.wfile.flush()
+        except OSError:
+            pass          # the window ended and the client hung up
+
+    def log_message(self, *args):
+        pass
+
+
+@pytest.fixture()
+def stub_port():
+    server = ThreadingHTTPServer(("127.0.0.1", 0), _Stub)
+    thread = threading.Thread(target=server.serve_forever, daemon=True)
+    thread.start()
+    yield server.server_address[1]
+    server.shutdown()
+    server.server_close()
+    thread.join(timeout=10)
+
+
+def _closed(port, monkeypatch, clients, n_plan, seconds, wave=False,
+            **how):
+    """run_closed or run_waves to its end; the records by request index."""
+    monkeypatch.setattr(loadgen, "STAGGER_S", 0.1)
+    plan = [{"i": i, "due": None, "prompt": [1, 2], "max_new_tokens": 2}
+            for i in range(n_plan)]
+    args = SimpleNamespace(port=port, tag="t", request_timeout=10.0,
+                           t0=time.monotonic() + 0.05, seconds=seconds)
+    out, lock = [], threading.Lock()
+    run = loadgen.run_waves if wave else loadgen.run_closed
+    threads = run(plan, args, out, lock, clients, **how)
+    for t in threads:
+        t.join(timeout=20)
+    assert not any(t.is_alive() for t in threads)
+    return sorted(out, key=lambda r: r["i"])
+
+
+def test_a_wave_is_sent_together_and_only_after_the_wave_before_it(
+        stub_port, monkeypatch):
+    clients = 4
+    recs = _closed(stub_port, monkeypatch, clients, 400, 0.8, wave=True)
+    assert all(r["ok"] for r in recs) and len(recs) >= 3 * clients
+    assert [r["i"] for r in recs] == list(range(len(recs)))
+    assert len(recs) % clients == 0          # whole waves, up to the last
+    waves = [recs[n:n + clients] for n in range(0, len(recs), clients)]
+    # No stagger: a staggered start would spread the first sends by 0.3 s.
+    for wave in waves:
+        sent = [r["sent"] for r in wave]
+        assert max(sent) - min(sent) < 0.15
+    assert min(r["sent"] for r in waves[0]) >= 0.0
+    # The answers end 0-90 ms apart, and nobody sends before the last did.
+    for before, after in zip(waves, waves[1:]):
+        done = [r["done"] for r in before]
+        assert max(done) - min(done) > 0.05
+        assert min(r["sent"] for r in after) >= max(done)
+
+
+def test_with_a_lead_a_wave_s_first_request_goes_ahead_of_the_others(
+        stub_port, monkeypatch):
+    """`lead_ms`: client 0 sends when the wave is decided, the others the
+    lead after it and together, so that an idle lane's first tick holds
+    one request whatever the race between their sends would have given."""
+    clients, lead = 4, 0.08
+    recs = _closed(stub_port, monkeypatch, clients, 400, 0.9, wave=True,
+                   lead_s=lead)
+    assert all(r["ok"] for r in recs) and len(recs) >= 3 * clients
+    waves = [recs[n:n + clients] for n in range(0, len(recs), clients)]
+    for wave in waves:
+        first, others = wave[0]["sent"], [r["sent"] for r in wave[1:]]
+        assert lead - 0.005 <= min(others) - first < lead + 0.05
+        assert max(others) - min(others) < 0.05
+    for before, after in zip(waves, waves[1:]):
+        assert after[0]["sent"] >= max(r["done"] for r in before)
+
+
+def test_without_the_key_each_client_sends_when_its_own_last_completed(
+        stub_port, monkeypatch):
+    clients = 4
+    recs = _closed(stub_port, monkeypatch, clients, 400, 0.8)
+    assert all(r["ok"] for r in recs) and len(recs) > 3 * clients
+    # The clients start STAGGER_S (here 0.1 s) apart: until then the first
+    # is alone, one request after the other ...
+    alone = [r for r in recs if r["sent"] < 0.09]
+    assert alone and alone[0]["sent"] >= 0.0
+    for a, b in zip(alone, alone[1:]):
+        assert b["sent"] >= a["done"]
+    # ... and once all have started nobody waits for anybody: a request is
+    # sent while the other clients are inside theirs.
+    others = [sum(o["sent"] < r["sent"] and (o["cut"] or r["sent"] < o["done"])
+                  for o in recs) for r in recs if r["sent"] > 0.35]
+    assert others and max(others) >= 2
+
+
+@pytest.mark.parametrize("wave", [False, True])
+def test_a_plan_that_runs_out_fails_the_run_and_ends_every_client(
+        stub_port, monkeypatch, wave):
+    recs = _closed(stub_port, monkeypatch, 4, 8, 5.0, wave=wave)
+    assert sum(r["ok"] for r in recs) == 8
+    bad = [r for r in recs if not r["ok"]]
+    assert bad and all("plan ran out" in r["error"] for r in bad)
+
+
+@pytest.mark.parametrize("lead_ms", [None, 60])
+def test_main_reads_the_wave_from_the_traffic_file(stub_port, tmp_path,
+                                                   capsys, lead_ms):
+    traffic = {"loop": "closed", "clients": 4, "wave": True, "block": 4,
+               "pool": 100,
+               "prompt_tokens": {"dist": "uniform", "min": 2, "max": 6},
+               "output_tokens": {"dist": "fixed", "value": 2}}
+    if lead_ms is not None:
+        traffic["lead_ms"] = lead_ms
+    (tmp_path / "t.json").write_text(json.dumps(traffic))
+    out = tmp_path / "records.jsonl"
+    assert loadgen.main([
+        "--traffic", str(tmp_path / "t.json"), "--seed", str(2**31 + 3),
+        "--port", str(stub_port), "--seconds", "0.5", "--vocab", "50",
+        "--t0", repr(time.monotonic() + 0.05), "--out", str(out),
+        "--drain", "5"]) == 0
+    assert json.loads(capsys.readouterr().out)["failed"] == 0
+    recs = [json.loads(line) for line in out.read_text().splitlines()]
+    waves = [recs[n:n + 4] for n in range(0, len(recs), 4)]
+    assert len(waves) >= 2
+    for before, after in zip(waves, waves[1:]):
+        assert min(r["sent"] for r in after) >= max(
+            r["done"] for r in before)
+    ahead = [min(r["sent"] for r in w[1:]) - w[0]["sent"] for w in waves]
+    if lead_ms is None:
+        assert max(ahead) < 0.05
+    else:
+        assert min(ahead) >= lead_ms / 1e3 - 0.005
+
+
+def test_a_wave_of_the_batch_cell_cannot_reach_the_p95_s_edge():
+    """What keeps gpt2-large.batch's `itl_p95_ms` on a decode tick, with no
+    tick's length in it (PERF.md, PR 35). Every wave is one block of the
+    plan, so its prompts are the same 1536 tokens. Its first request goes
+    `lead_ms` ahead, so the idle lane's first tick holds that prompt alone
+    and delays nobody: no row decodes yet. A later prefill tick takes the
+    lane's token budget less one token a decoding row, at least 256 - 31,
+    so the other 31 prompts take at most 7 ticks more, and six would not
+    hold them even with no row decoding but the first. Each such tick
+    delays at most the wave's other 31 rows by one sample."""
+    traffic = _traffic("batch")
+    with open(os.path.join(BENCH, "configs", "gpt2-large.json")) as f:
+        serving = json.load(f)["serving"]
+    clients, budget = traffic["clients"], serving["gen_prefill_chunk"]
+    assert traffic["wave"] is True and traffic["block"] == clients
+    assert traffic["lead_ms"] > 0
+    assert serving["gen_max_batch_size"] == clients
+    plan = loadgen.build_plan(traffic, 3, 51.0, 50257)
+    outputs = traffic["output_tokens"]["value"]
+    for n in range(0, len(plan), clients):
+        wave = plan[n:n + clients]
+        assert all(i["max_new_tokens"] == outputs for i in wave)
+        assert sum(len(i["prompt"]) for i in wave) == 1536
+        rest = sum(len(i["prompt"]) for i in wave[1:])
+        later_ticks = -(-rest // (budget - (clients - 1)))
+        assert later_ticks == 7 and rest > 5 * (budget - 1)
+        spanning = later_ticks * (clients - 1)
+        assert spanning / (clients * (outputs - 1)) < 0.027
+
+
 # -- records -> metrics -------------------------------------------------------
 
 def _rec(due, first, events, prompt=10, ok=True, sent=None, cut=False):
@@ -184,3 +364,27 @@ def test_end_to_end_names_units_and_missing_samples():
     assert out["itl_p95_ms"]["unit"] == "ms"
     assert "ttft_p50_ms" not in metrics.end_to_end(
         [_rec(0.0, None, [], ok=False)], 2.0, 1.0)
+
+
+def test_a_slipped_tick_moves_the_median_by_a_tick_and_the_mean_by_little():
+    """docqa's TTFTs are quantised to ticks of ~108 ms, eight ticks or so
+    (PERF.md, PR 35). One request in ten slipping a tick is what a run does
+    from nothing: the median of such a population jumps by the whole tick,
+    the mean by a tenth of one."""
+    tick, n = 108.0, 115
+
+    def window(slipped):
+        ticks = [8] * 58 + [9] * 57       # the median sits on an edge
+        for k in range(0, slipped):
+            ticks[10 * k + 5] += 1        # one in ten of them, spread out
+        return [_rec(float(i), float(i) + t * tick / 1e3,
+                     [[float(i) + t * tick / 1e3, 1]])
+                for i, t in enumerate(ticks)]
+
+    steady = metrics.end_to_end(window(0), 200.0, 1.0)
+    slipped = metrics.end_to_end(window(n // 10), 200.0, 1.0)
+    p50 = [m["ttft_p50_ms"]["value"] for m in (steady, slipped)]
+    mean = [m["ttft_mean_ms"]["value"] for m in (steady, slipped)]
+    assert p50[1] - p50[0] == pytest.approx(tick)          # 12.5 %
+    assert 0 < mean[1] / mean[0] - 1 < 0.02
+    assert mean[1] - mean[0] == pytest.approx(tick * (n // 10) / n)

@@ -186,10 +186,11 @@ def test_the_benchmark_lists_the_cell_and_its_six_metrics():
         "kernel.mla_attn_busy", "kernel.moe_experts_busy",
         "kernel.mla_attn_roofline", "kernel.moe_experts_roofline",
         "moe.expert_load_imbalance", "moe.rows_per_touched_expert"]
-    assert bench["per_layer"][-6:] == mine
+    at = bench["per_layer"].index(mine[0])
+    assert bench["per_layer"][at:at + 6] == mine
     assert {m["layer"] for m in mine} == {"kernels", "expert layer"}
     assert all(m["moves"] == "tokens_per_s" for m in mine)
-    for m in bench["end_to_end"] + bench["per_layer"][:-6]:
+    for m in bench["end_to_end"] + bench["per_layer"][:at]:
         assert CELL not in m.get("workloads", [])
     with open(os.path.join(BENCH, "traffic", "solve.json")) as f:
         traffic = json.load(f)
@@ -263,7 +264,7 @@ def test_a_decode_tick_s_experts_are_bound_by_their_weights():
 
 def test_the_rehearsal_lists_every_metric_of_the_new_cell():
     """run.py --trace 1 on the CPU at the small size, a cell list of its
-    own with the nine keyless per-layer metrics and the six new ones: the
+    own with the ten keyless per-layer metrics and the cell's own six: the
     span and counter metrics print, what only a device trace gives is left
     out and said so; the untraced run prints the three end-to-end ones."""
     cells = os.path.join(DATA, "BENCHMARK.moonlight.test.json")
@@ -274,7 +275,7 @@ def test_the_rehearsal_lists_every_metric_of_the_new_cell():
     want = [m["name"] for m in real["per_layer"]
             if CELL in m.get("workloads", [CELL])]
     assert [m["name"] for m in listed["per_layer"]] == want
-    assert len(want) == 15
+    assert len(want) == 16
     assert [m["name"] for m in listed["end_to_end"]] == [
         m["name"] for m in real["end_to_end"]
         if CELL in m.get("workloads", [CELL])] == [

@@ -44,11 +44,19 @@ def _listed(cells, kind, cell):
 
 
 def test_untraced_open_loop_run_prints_the_contract_s_last_line(cells):
-    line = _last_line(_run("--benchmark-file", CELLS, "--workload",
-                           "small.open", "--seed", str(2**31 + 17),
-                           "--seconds", "2", "--trace", "0"))
-    assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+    proc = _run("--benchmark-file", CELLS, "--workload", "small.open",
+                "--seed", str(2**31 + 17), "--seconds", "2", "--trace", "0")
+    line = _last_line(proc)
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
+    # What decided `correct`, each number beside its limit, comes last in
+    # the line and as the last lines of standard error.
+    assert set(line["compared"]) == {"worst_gap_in_logit_std", "exact_share",
+                                     "repeat_identical"}
+    gap = line["compared"]["worst_gap_in_logit_std"]
+    assert 0.0 <= gap["value"] <= gap["at_most"]
+    assert proc.stderr.strip().splitlines()[-1].startswith(
+        "benchmark: correct: repeat_identical ")
     assert line["correct"] is True
     assert line["attempted"] == 12 and line["failed"] == 0
     assert set(line["metrics"]) == _listed(cells, "end_to_end", "small.open")
@@ -82,12 +90,36 @@ def test_traced_closed_loop_run_reports_layers_and_no_device_number(cells):
     # gives is left out on a CPU, never filled in.
     assert {"gateway.route_ms", "lane.queue_wait_ms", "step.decode_ms",
             "step.prefill_ms", "sched.decode_rows_per_tick",
-            "sched.prefill_tick_share", "kv.blocks_peak_share"} == got
+            "sched.prefill_tick_share", "sched.itl_prefill_share",
+            "kv.blocks_peak_share"} == got
     assert not {"device.idle", "kernel.paged_attn_busy"} & got
     assert "busy_s" not in line["device"] and "breakdown" not in line
     assert line["device"]["platform"] == "cpu"
     # Every metric is also printed by name and unit on an earlier line.
     assert "step.decode_ms = " in proc.stdout
+
+
+def test_a_closed_loop_in_waves_runs_from_its_traffic_file_alone(cells):
+    """`"wave": true` in the traffic file and nothing else: the warm-up and
+    the window both send in waves of as many requests as the lane has
+    slots, and the traced run says what share of the gaps between tokens
+    lay across a prefill tick."""
+    line = _last_line(_run("--benchmark-file", CELLS, "--workload",
+                           "small.wave", "--seed", str(2**31 + 41),
+                           "--seconds", "2", "--trace", "1"))
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] >= 8 and line["attempted"] % 4 == 0
+    assert 0.0 < line["metrics"]["sched.itl_prefill_share"]["value"] < 100.0
+    assert "sched.prefill_tick_share" in line["metrics"]
+    runs = os.path.join(BENCH, "out", "runs")
+    kept = sorted(f for f in os.listdir(runs) if f.startswith("small.wave."))
+    with open(os.path.join(runs, kept[-1])) as f:
+        records = json.load(f)["records"]
+    assert [r["i"] for r in records] == list(range(len(records)))
+    waves = [records[n:n + 4] for n in range(0, len(records), 4)]
+    for before, after in zip(waves, waves[1:]):
+        assert min(r["sent"] for r in after) >= max(
+            r["done"] for r in before)
 
 
 def test_a_family_the_harness_has_no_word_for_runs_from_new_files_only(cells):
