@@ -318,6 +318,80 @@ def test_latent_mixed_step_copies_neither_the_pool_nor_a_bank(v5e_devices,
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
 
 
+@pytest.mark.parametrize("width", [1, 256])
+def test_windowed_mixed_step_copies_no_pool_and_no_bank(v5e_devices, width):
+    """The Laguna cell's mixed step at its serving shapes (shapes only),
+    both pools donated, compiled for one v5e: the full layers' call and the
+    window layers' (`swa_window_read`) and the grouped product are in it;
+    no `copy`, `slice`, `dynamic-slice` or `dynamic-update-slice` whose
+    result is a pool, a layer of one, or a layer's bank of held experts
+    (1.6 GB); temporaries of tens of MB: the step runs over the tick's
+    tokens (68 tiles of 8), and 11.15 GB of weights with 2.6 GB of pools
+    leave 2 GB of the chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.laguna import laguna_step_rows_ragged
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "laguna-s-2.1-5l.json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    assert width in (1, serving["gen_prefill_chunk"])
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg = spec.config
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    def pool(kind, blocks):
+        one = placed(jax.ShapeDtypeStruct(
+            (kind.n_layers, blocks, bs, kind.kv_lanes[0]), jnp.bfloat16))
+        return KVCache(one, one)
+
+    per_row = -(-(cfg.window + serving["gen_prefill_chunk"]) // bs) + 1
+    pools = (pool(cfg.kv_block_kinds[0], serving["gen_kv_blocks"]),
+             pool(cfg.kv_block_kinds[1], rows * per_row + 1))
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return laguna_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=functools.partial(ragged_paged_attention,
+                                      interpret=False),
+            sample_slot=jnp.zeros_like(pos0),
+            max_tokens=serving["gen_prefill_chunk"] + rows)
+
+    def host(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    table = host(rows, -(-cfg.max_seq // bs))
+    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+        params, pools, (table, table), host(rows, width), host(rows),
+        host(rows)).compile()
+    hlo = compiled.as_text()
+    assert "swa_window_read" in hlo and "ragged-dot" in hlo
+    assert "_paged_call" in hlo or "paged" in hlo
+    banks = jax.tree.leaves(params["layers"][1]["mlp"]["experts"])
+    sizes = {math.prod(x.shape) for x in banks}
+    for x in pools:
+        sizes |= {math.prod(x.k.shape), math.prod(x.k.shape[1:])}
+    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
+                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
+    moved = [(op, dims) for dims, op in movers.findall(hlo)
+             if math.prod(map(int, dims.split(","))) in sizes]
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+
+
 def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
     """Where JAX_COMPILATION_CACHE_DIR is set, no code names a cache
     directory (JAX reads the variable itself); unset, the directory is

@@ -470,7 +470,7 @@ def _spec(name: str, cfg: MoonlightConfig, seq_len: int) -> ModelSpec:
 
     return ModelSpec(name=name, apply=apply, init=init,
                      input_shape=(seq_len,), output_shape=(cfg.vocab,),
-                     config=cfg)
+                     config=cfg, ragged_step=moonlight_step_rows_ragged)
 
 
 def _cfg(**kw) -> MoonlightConfig:

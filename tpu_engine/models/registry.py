@@ -33,6 +33,13 @@ from typing import Callable, Dict, List, Optional, Tuple
 # `--tp` shards H_kv (here 1), the host tier and the chain wire format
 # (migration, handoff, prefix fetch) carry a K and a V of equal width,
 # speculative verify and the two-path prefill have no latent read.
+# "kv_windowed": a kv_paged chain whose sliding-window layers keep only
+# the blocks their window still sees (models.laguna): a row holds blocks of
+# two kinds, and the window kind's are given back as the row's position
+# passes them. Served by the mixed tick alone. A freed block can serve no
+# prefix hit, be demoted to no host tier and ride no chain, so prefix
+# sharing, the host tier, migration and handoff are ABSENT, with int8
+# (the window read takes no scales), `--tp` and speculative verify.
 FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
     "kv_paged": ("generate", "two_path", "mixed_step", "spec_decode",
                  "paged_kv", "prefix_sharing", "kv_quantize",
@@ -43,6 +50,7 @@ FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
     "stateless": ("oneshot_rows",),
     "kv_latent": ("generate", "mixed_step", "paged_kv", "prefix_sharing",
                   "oneshot_rows"),
+    "kv_windowed": ("generate", "mixed_step", "paged_kv", "oneshot_rows"),
 }
 
 # -- tensor-parallel partition rules ------------------------------------------
@@ -227,6 +235,15 @@ class ModelSpec:
     # weights). None: the steps read `params` itself — every family whose
     # step has no per-tick cast to save, or casts what it must itself.
     step_weights: Optional[Callable] = None
+    # The family's own step of the mixed tick, where it is not
+    # `models.transformer.transformer_step_rows_ragged`: (params, tokens,
+    # caches, tables, pos0, qlen, cfg, *, dtype, sample_slot, held,
+    # max_tokens) -> (logits, caches, rows (expert layers, experts): the
+    # rows each expert took), run over the tick's tokens
+    # (models.moonlight, models.laguna). `held` = (first, count): the
+    # routed experts this lane's weights hold (None: all of them).
+    ragged_step: Optional[Callable] = None
+    held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         if not self.state_family:
@@ -249,7 +266,8 @@ class ModelSpec:
             # rank heuristic.
             rule = getattr(self.config, "tp_partition_rule", None)
             if rule is None:
-                if self.state_family in ("kv_paged", "kv_latent"):
+                if self.state_family in ("kv_paged", "kv_latent",
+                                         "kv_windowed"):
                     rule = "transformer"
                 elif self.state_family == "state_slab":
                     # Defensive default for undeclared recurrent models:
@@ -313,6 +331,7 @@ def _ensure_builtin_models_imported():
 
     from tpu_engine.models import mlp, resnet  # noqa: F401
 
-    for optional in ("bert", "gpt2", "llama", "yolo", "ssd", "moonlight"):
+    for optional in ("bert", "gpt2", "llama", "yolo", "ssd", "moonlight",
+                     "laguna"):
         if importlib.util.find_spec(f"tpu_engine.models.{optional}") is not None:
             importlib.import_module(f"tpu_engine.models.{optional}")

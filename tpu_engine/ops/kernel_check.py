@@ -77,6 +77,29 @@ CELL_SHAPES = {
         n_blocks=2817,
         rows=((256, 1024), (1, 600), (1, 1023), (1, 1290), (1, 1567))
         + ((0, 0),) * 11),
+    # repo (benchmarks/configs/laguna-s-2.1-5l.json): G = 6 on full layers
+    # and G = 9 on window layers (`window` 512: the walk's lower bound),
+    # under a table of 16384 columns. Width 1: 32 decode rows of 0.3-8 k
+    # columns, heads packed. Width 8: a TILE of the step's token list is a
+    # row of the call; eight tiles of one chunk beside decode rows' tiles
+    # and dead ones.
+    "laguna-s-2.1-5l.repo/full/W1": dict(
+        geo=dict(n_heads=48, n_kv_heads=8, d_head=128), table_len=1024,
+        n_blocks=2049, rows=tuple((1, 300 + 250 * r) for r in range(32))),
+    "laguna-s-2.1-5l.repo/window/W1": dict(
+        geo=dict(n_heads=72, n_kv_heads=8, d_head=128), table_len=1024,
+        n_blocks=2049, window=512,
+        rows=tuple((1, 300 + 250 * r) for r in range(32))),
+    "laguna-s-2.1-5l.repo/full/W8": dict(
+        geo=dict(n_heads=48, n_kv_heads=8, d_head=128), table_len=1024,
+        n_blocks=2049,
+        rows=tuple((8, 4096 + 8 * t) for t in range(8))
+        + ((1, 700), (1, 5000), (5, 0), (0, 0))),
+    "laguna-s-2.1-5l.repo/window/W8": dict(
+        geo=dict(n_heads=72, n_kv_heads=8, d_head=128), table_len=1024,
+        n_blocks=2049, window=512,
+        rows=tuple((8, 4096 + 8 * t) for t in range(8))
+        + ((1, 700), (1, 5000), (5, 0), (0, 0))),
 }
 
 
@@ -189,19 +212,23 @@ def _latent_cases(model: str, interpret: bool):
 
 def cell_cases(interpret: bool = False):
     """The ragged read at every entry of `CELL_SHAPES`."""
-    kernel_fn, reference_fn = pa.READ_PATHS["ragged"]
     for name, shape in CELL_SHAPES.items():
+        # A window layer's call: the same read with a lower bound.
+        kernel_fn, reference_fn = (
+            functools.partial(fn, window=shape["window"])
+            if "window" in shape else fn for fn in pa.READ_PATHS["ragged"])
         q_lens, pos0 = zip(*shape["rows"])
         workload = functools.partial(
             pa.parity_workload, "ragged", q_lens, block_size=BLOCK_SIZE,
             n_blocks=shape["n_blocks"], table_len=shape["table_len"],
-            dtype=jnp.bfloat16, pos0=pos0, **shape["geo"])
+            dtype=jnp.bfloat16, pos0=pos0, window=shape.get("window"),
+            **shape["geo"])
         # The gather reference over the columns a row reaches, not the
         # table's width (2048 columns of 16 rows x 32 heads x 256 slots
         # are 17 GB of scores).
         reach = -(-max(q + p for q, p in shape["rows"]) // BLOCK_SIZE)
 
-        def check(out, operands, reach=reach,
+        def check(out, operands, reach=reach, reference_fn=reference_fn,
                   qlen=jnp.asarray(q_lens, jnp.int32)):
             near = operands[:4] + (operands[4][:, :reach],) + operands[5:]
             return pa.reference_error(reference_fn, out, near, qlen)

@@ -199,7 +199,8 @@ def routed_experts(x, valid, experts, weights, bank, *, first_group,
     `first_group + e` (a traced scalar: the bank of every layer, whole).
     `held` = (first, count): the experts this shard holds (default all);
     a pair whose expert lies outside is another shard's and forms no row
-    here. `max_tokens`: a static bound on how many slots can be valid
+    here, and only the held experts' groups, [first_group + first,
+    first_group + first + count), need exist in the bank. `max_tokens`: a static bound on how many slots can be valid
     (the scheduler's token budget plus its rows); it sizes the sorted
     pair list, so padding costs no gather either. Returns (y (N, d)
     float32, rows (n_experts,) int32: the rows each expert took)."""
@@ -215,7 +216,8 @@ def routed_experts(x, valid, experts, weights, bank, *, first_group,
     rows = jnp.zeros((n_experts + 1,), jnp.int32).at[eid].add(1)[:n_experts]
     groups = bank["gate_up"].shape[0]
     sizes = jax.lax.dynamic_update_slice(
-        jnp.zeros((groups,), jnp.int32), rows, (first_group,))
+        jnp.zeros((groups,), jnp.int32), rows[first:first + count],
+        (first_group + first,))
     xs = x[token].astype(dtype)
     gate_up = jax.lax.ragged_dot(xs, bank["gate_up"].astype(dtype), sizes,
                                  preferred_element_type=jnp.float32)

@@ -141,12 +141,14 @@ def _decode_reference(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
 
 
 def _ragged_reference(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
-                      pos0):
+                      pos0, window=None):
     kk, vv = _dense_rows(q, k_pool, v_pool, k_scale, v_scale, layer, tables)
     kpos = jnp.arange(kk.shape[1])
     qpos = pos0[:, None] + jnp.arange(q.shape[1])[None, :]     # (B, W)
-    valid = (kpos[None, None, :] <= qpos[:, :, None]).astype(jnp.int32)
-    return dot_product_attention(q, kk, vv, mask=valid)
+    valid = kpos[None, None, :] <= qpos[:, :, None]
+    if window is not None:
+        valid &= kpos[None, None, :] > qpos[:, :, None] - window
+    return dot_product_attention(q, kk, vv, mask=valid.astype(jnp.int32))
 
 
 def paged_attention_reference(q, k_pool, v_pool, layer, tables, pos_vec):
@@ -172,16 +174,19 @@ def paged_attention_reference(q, k_pool, v_pool, layer, tables, pos_vec):
 
 
 def ragged_paged_attention_reference(q, k_pool, v_pool, layer, tables, pos0,
-                                     qlen):
+                                     qlen, *, window=None):
     """XLA gather path, ragged queries. q: (B, W, H, D);
     k_pool/v_pool: (L, NB, bs, H_kv*D); layer: int32 scalar; tables:
     (B, nb) int32 block ids; pos0: (B,) logical position of each row's
     FIRST query slot; qlen: (B,) valid query slots (padding slots
     produce garbage the caller must ignore — masking them costs more
-    than ignoring). Returns (B, W, H, D)."""
+    than ignoring). `window` (a sliding-window layer): query slot i also
+    needs kpos > pos0 + i - window; table entries wholly behind every
+    slot's window may be the null block (the row gave those blocks
+    back). Returns (B, W, H, D)."""
     del qlen  # padding slots are ignored by contract, not masked
     return _ragged_reference(q, k_pool, v_pool, None, None, layer, tables,
-                             pos0)
+                             pos0, window)
 
 
 # -- quantized (int8 block pool) references ----------------------------------
@@ -237,7 +242,7 @@ def _tile_geometry(rows_a_row: int, n_kv_heads: int):
 
 def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
                   v_hbm, *rest, block_size: int, blocks: int, scale: float,
-                  group: int, pack: int, quant: bool):
+                  group: int, pack: int, quant: bool, window=None):
     """One query TILE a grid step (b, t): `rows` query rows of batch row
     b (row r = slot r // G, group head r % G) for ALL its KV heads.
     q_ref/o_ref (1, H_kv, rows, D); k_hbm/v_hbm: the whole pools, left in
@@ -257,6 +262,12 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
     moves a vector between lanes and sublanes; acc: (H_kv, M, D) f32.
     Causal masking within the new-token window: query row r keeps
     kpos <= pos0 + r // G.
+
+    `window` (static; a sliding-window layer) is the walk's OTHER end:
+    query row r also needs kpos > pos0 + r // G - window, so the walk
+    starts at the group that holds the first column the tile's FIRST row
+    still sees, and nothing is spent on the context behind it. Table
+    entries behind the window may be the null block.
 
     An int8 pool adds ks_ref/vs_ref (1, groups, H_kv', span) f32: the
     scales of the row's table, a group's tokens on the lanes (Mosaic
@@ -288,6 +299,11 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
         horizon = jnp.minimum(lengths_ref[b],
                               pos0 + (first + rows - 1) // group + 1)
         groups = (horizon + span - 1) // span
+        first_group = 0
+        if window is not None:
+            # The first column the tile's FIRST query row still sees.
+            lower = jnp.maximum(pos0 + first // group - (window - 1), 0)
+            first_group = lower // span
 
         def copies(g, slot):
             out = []
@@ -299,7 +315,7 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
                                                      (v_hbm, v_buf)))]
             return out
 
-        for copy in copies(0, 0):
+        for copy in copies(first_group, first_group % 2):
             copy.start()
         m_sc[...] = jnp.full(m_sc.shape, _NEG_INF, jnp.float32)
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
@@ -332,6 +348,8 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
             for copy in copies(g, slot):
                 copy.wait()
             keep = reach >= g * span
+            if window is not None:
+                keep &= reach < g * span + window
             # Columns past the horizon hold the null block's or a later
             # block's bytes: masked as scores, zeroed as values (0 * NaN).
             if quant:
@@ -339,8 +357,11 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
                     jnp.int32, (1, span), 1)) < horizon
                 ks, vs = ks_ref[0, g], jnp.where(seen, vs_ref[0, g], 0.0)
             else:
-                seen = (g * span + jax.lax.broadcasted_iota(
-                    jnp.int32, (span, 1), 0)) < horizon
+                column = g * span + jax.lax.broadcasted_iota(
+                    jnp.int32, (span, 1), 0)
+                seen = column < horizon
+                if window is not None:
+                    seen &= column >= lower      # given back: the null block
             for c in range(n_kv_heads // pack):
                 heads = range(c * pack, (c + 1) * pack)
                 s = None
@@ -375,7 +396,7 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
                         preferred_element_type=jnp.float32)
             return carry
 
-        jax.lax.fori_loop(0, groups, fold, 0)
+        jax.lax.fori_loop(first_group, groups, fold, 0)
         for h in range(n_kv_heads):
             own = pl.ds(h % pack * rows, rows)    # head h's rows of the M
             l = l_sc[h // pack, own, :]
@@ -383,9 +404,9 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
                            ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
-                lengths, *, interpret: bool):
+                lengths, *, interpret: bool, window=None):
     """The one pallas_call behind every read path. q: (B, W, H, D);
     k_pool/v_pool: (L, NB, bs, H_kv*D), the whole pool as it lives on
     the device — an operand left in HBM, never reshaped or sliced;
@@ -393,7 +414,9 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
     pool); layer: (1,) the layer read; tables: (B, nb); pos0: (B,)
     logical position of each row's first query slot; lengths: (B,) pos0
     + qlen. Returns (B, W, H, D) in q's dtype. The grid is the query
-    tiles, (B, ceil(W*G / rows)), whatever the table's width."""
+    tiles, (B, ceil(W*G / rows)), whatever the table's width. With
+    `window` the call is a sliding-window layer's and carries its own
+    name in a trace (`swa_window_read`)."""
     b, w, h, d = q.shape
     bs = k_pool.shape[2]
     h_kv = k_pool.shape[3] // d
@@ -440,7 +463,8 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
                                   lambda b, t, *_: (b, 0, 0, 0))] * 2
     kernel = functools.partial(
         _paged_kernel, block_size=bs, blocks=blocks,
-        scale=1.0 / math.sqrt(d), group=g, pack=pack, quant=quant)
+        scale=1.0 / math.sqrt(d), group=g, pack=pack, quant=quant,
+        window=window)
     m_rows = pack * rows
     out = pl.pallas_call(
         kernel,
@@ -463,13 +487,14 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        **({} if window is None else {"name": "swa_window_read"}),
     )(tables, pos0, lengths, layer, *operands)
     return (out[:, :, :r].reshape(b, h_kv, w, g, d)
             .transpose(0, 2, 1, 3, 4).reshape(b, w, h, d))
 
 
 def _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0, qlen,
-           interpret):
+           interpret, window=None):
     """Entry-point glue: `interpret=None` auto-selects (compiled on TPU,
     the Pallas interpreter elsewhere); host ints become int32 arrays."""
     if interpret is None:
@@ -479,7 +504,7 @@ def _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0, qlen,
                        jnp.asarray(layer, jnp.int32).reshape(1),
                        jnp.asarray(tables, jnp.int32), pos0,
                        pos0 + jnp.asarray(qlen, jnp.int32),
-                       interpret=bool(interpret))
+                       interpret=bool(interpret), window=window)
 
 
 def paged_attention(q, k_pool, v_pool, layer, tables, pos_vec, *,
@@ -491,11 +516,11 @@ def paged_attention(q, k_pool, v_pool, layer, tables, pos_vec, *,
 
 
 def ragged_paged_attention(q, k_pool, v_pool, layer, tables, pos0, qlen, *,
-                           interpret=None):
+                           window=None, interpret=None):
     """Pallas-kernel drop-in for `ragged_paged_attention_reference` (same
     signature/contract)."""
     return _paged(q, k_pool, v_pool, None, None, layer, tables, pos0, qlen,
-                  interpret)
+                  interpret, window)
 
 
 def quant_paged_attention(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
@@ -613,14 +638,17 @@ _PARITY_LAYERS, _PARITY_LAYER = 2, 1
 
 def parity_workload(kind: str, q_lens, *, n_heads: int, n_kv_heads: int,
                     d_head: int, block_size: int, n_blocks: int,
-                    table_len: int, dtype, seed: int = 0, pos0=None):
+                    table_len: int, dtype, seed: int = 0, pos0=None,
+                    window=None):
     """One random workload for the `READ_PATHS[kind]` pair, one row per
     entry of `q_lens`: (operands, qlen). The pool has two layers, both
     random, and the second is read. Rows get distinct
     shuffled tables and ragged positions so the skip/mask paths are
     exercised; with `pos0` (one column a row) the positions are those,
     and a row's table past its own pos0 + q_len columns is the null
-    block, as the scheduler leaves it. Traceable (`jax.eval_shape` gives
+    block, as the scheduler leaves it; with `window`, so is its table
+    behind the first column its first new token still sees (the blocks a
+    sliding-window layer gave back). Traceable (`jax.eval_shape` gives
     the operand shapes without generating them — ops.kernel_check
     AOT-compiles from those). Shared by the parity checks below and
     ops.kernel_check."""
@@ -658,6 +686,8 @@ def parity_workload(kind: str, q_lens, *, n_heads: int, n_kv_heads: int,
         else:
             pos0[r] = placed[r]
             tables[r, -(-(placed[r] + ql) // block_size):] = 0
+            if window is not None:
+                tables[r, :max(placed[r] - window + 1, 0) // block_size] = 0
     qlen = jnp.asarray(np.asarray(q_lens, np.int32))
     where = (jnp.int32(_PARITY_LAYER), jnp.asarray(tables),
              jnp.asarray(pos0))
@@ -686,6 +716,10 @@ def _parity(kind: str, q_lens, *, interpret, **shape) -> float:
     """Max |kernel - reference| over one `parity_workload`."""
     kernel_fn, reference_fn = READ_PATHS[kind]
     operands, qlen = parity_workload(kind, q_lens, **shape)
+    if shape.get("window") is not None:
+        kernel_fn, reference_fn = (
+            functools.partial(fn, window=shape["window"])
+            for fn in (kernel_fn, reference_fn))
     return reference_error(reference_fn,
                            kernel_fn(*operands, interpret=interpret),
                            operands, qlen)
@@ -728,6 +762,40 @@ def walk_parity_check(kind: str, case: str, *, interpret=None,
                    block_size=16, n_blocks=1 + len(q_lens) * table_len,
                    table_len=table_len, dtype=dtype, seed=seed, pos0=pos0,
                    interpret=interpret)
+
+
+# The walk's other end, one workload each: name -> (q_lens, pos0, window,
+# table_len) at block size 16 over 2 KV heads of 16 lanes. Run at G = 6
+# and G = 9 (12 and 18 query heads), the two group sizes of a model whose
+# window layers carry more query heads than its full ones; neither is a
+# power of two, so a score tile's rows do not end on a slot.
+WINDOW_CASES = {
+    # Width 1 (heads packed, groups of 128 columns): a row still inside
+    # its window, one on its edge, one past it, one whose walk starts five
+    # groups in; 40 is not a whole number of blocks.
+    "decode-rows-around-the-edge": ((1, 1, 1, 1), (5, 39, 40, 700), 40, 48),
+    # Width 256: a first chunk (the window opens inside it), a later chunk
+    # and a decode row in the same tick.
+    "chunk-across-the-edge": ((256, 1, 100), (0, 300, 500), 40, 48),
+    # A window wider than a group of the walk (256 columns): two or three
+    # groups are read, the first and the last partly.
+    "window-wider-than-a-group": ((64, 1), (600, 650), 300, 48),
+    # Tiles of eight slots as rows of the call, as a step over the tick's
+    # tokens hands them over: any pos0, a short last tile.
+    "tiles-of-eight-slots": ((8, 8, 1, 5), (0, 37, 520, 100), 40, 48),
+}
+
+
+def window_parity_check(case: str, group: int, *, interpret=None,
+                        dtype=jnp.float32, seed: int = 0) -> float:
+    """Max |kernel - reference| of the ragged read with a lower bound
+    over one of `WINDOW_CASES`, `group` query heads a KV head."""
+    q_lens, pos0, window, table_len = WINDOW_CASES[case]
+    return _parity("ragged", q_lens, n_heads=2 * group, n_kv_heads=2,
+                   d_head=16, block_size=16,
+                   n_blocks=1 + len(q_lens) * table_len,
+                   table_len=table_len, dtype=dtype, seed=seed, pos0=pos0,
+                   window=window, interpret=interpret)
 
 
 def parity_check(batch: int = 2, n_heads: int = 4, n_kv_heads: int = 2,

@@ -399,8 +399,19 @@ class ContinuousGenerator:
         # hold a latent and a rope key, stepped by its own ragged step.
         # Everything the pool does by block id — tables, radix sharing,
         # refcounts — is shared; what assumes K and V of H_kv*D lanes is
-        # fenced below.
-        self._latent = fam == "kv_latent"
+        # fenced below (`_fence_tick_only_family`).
+        # "kv_windowed" (models.laguna): a kv_paged chain in blocks of two
+        # kinds, one pool and one table a row each. Full-attention layers
+        # keep every block in `_pool`, as every other paged family;
+        # sliding-window layers keep theirs in `_wpool` and give the ones
+        # behind the window back inside the tick whose position passes
+        # them (`_slide_window_blocks`).
+        self._windowed = fam == "kv_windowed"
+        # A family whose step of the mixed tick is its own (and runs over
+        # the tick's tokens) declares it, with the experts the lane's
+        # weights hold (registry.ModelSpec).
+        self._ragged_step = getattr(model, "ragged_step", None)
+        self._held_experts = getattr(model, "held", None)
         # Unified stateless serving (DESIGN.md): score/infer/embed
         # models admit as SINGLE-TICK rows — no autoregressive state at
         # all, so every state-machinery branch below is skipped and the
@@ -563,10 +574,11 @@ class ContinuousGenerator:
                 "state_rows applies to the state_slab family; model "
                 f"'{model.name}' serves the "
                 f"{getattr(model, 'state_family', 'kv_paged')} family")
-        if self._latent:
-            self._fence_latent(model, mixed_step=mixed_step,
-                               kv_host_blocks=kv_host_blocks,
-                               kv_quantize=kv_quantize, spec_k=spec_k)
+        if fam in self._TICK_ONLY_WHY:
+            self._fence_tick_only_family(
+                model, fam, mixed_step=mixed_step,
+                kv_host_blocks=kv_host_blocks, kv_quantize=kv_quantize,
+                spec_k=spec_k, prefix_sharing=prefix_sharing)
         if int(kv_host_blocks) > 0 and not self._paged:
             raise ValueError("kv_host_blocks requires the paged KV cache "
                              "(set kv_block_size > 0)")
@@ -615,7 +627,10 @@ class ContinuousGenerator:
             if int(kv_host_blocks) > 0 and not prefix_sharing:
                 raise ValueError("kv_host_blocks requires prefix_sharing "
                                  "(the host tier holds radix entries)")
-            self._pool = BlockPool(self.cfg, nb, bs, self._dtype, device,
+            # A windowed family's `_pool` holds its full layers alone.
+            self._pool = BlockPool(self.cfg.kv_block_kinds[0]
+                                   if self._windowed else self.cfg,
+                                   nb, bs, self._dtype, device,
                                    host_blocks=int(kv_host_blocks),
                                    quantize=str(kv_quantize),
                                    mesh=self._tp_mesh)
@@ -814,7 +829,8 @@ class ContinuousGenerator:
                 "token_budget": self._mixed_budget,
                 "chunk_cap": self._chunk_cap,
             }
-            if self._latent and self.cfg.n_moe_layers:
+            if self._ragged_step is not None and getattr(
+                    self.cfg, "n_moe_layers", 0):
                 # What the expert layers routed, summed over ticks: the
                 # step returns each layer's per-expert row counts with
                 # the tick's other results. Padding slots form no pair.
@@ -822,6 +838,22 @@ class ContinuousGenerator:
                     (self.cfg.n_moe_layers, self.cfg.n_routed), np.int64)
                 self._stats["moe"] = {"assignments": 0,
                                       "experts_touched": 0}
+                if self._held_experts is not None:
+                    # A lane that holds a share of the experts: the
+                    # pairs that formed a row HERE, of all it routed.
+                    self._stats["moe"]["assignments_held"] = 0
+            if self._windowed:
+                # A row's window blocks: at most the window, a chunk and
+                # a block of tokens (`_slide_window_blocks`), so a pool
+                # of that bound a slot can never run out.
+                per_row = -(-(self.cfg.window + self._chunk_cap) // bs) + 1
+                self._wpool = BlockPool(
+                    self.cfg.kv_block_kinds[1], self.n_slots * per_row + 1,
+                    bs, self._dtype, device)
+                self._wtables = np.zeros_like(self._tables)
+                # The table entries [first, end) a row holds.
+                self._wspan = np.zeros((self.n_slots, 2), np.int64)
+                self._wfreed = 0
         # TTFT / inter-token-latency histograms — the two numbers mixed
         # stepping exists to improve, scrapeable at /metrics
         # (tpu_engine_ttft_seconds / tpu_engine_itl_seconds) on every
@@ -1081,37 +1113,53 @@ class ContinuousGenerator:
 
     # -- paged compiled stages -------------------------------------------------
 
-    def _fence_latent(self, model, *, mixed_step, kv_host_blocks,
-                      kv_quantize, spec_k) -> None:
-        """Start-up fences of the kv_latent family (registry
-        FAMILY_CAPABILITIES): what a latent pool cannot do yet is refused
-        by name, never served wrong. (`tp > 1` is refused above through
-        the model's unshardable TP rule.)"""
-        name = f"model '{model.name}' (kv_latent family)"
+    # Why a family served by the mixed tick alone lacks a capability.
+    _TICK_ONLY_WHY = {
+        "kv_latent": ("a latent block holds one latent and one rope key "
+                      "a token, not a K and a V a head", "latent read"),
+        "kv_windowed": ("a window layer's blocks are given back as the "
+                        "row's position passes them: a freed block can "
+                        "serve no prefix hit, go to no host tier and ride "
+                        "no chain, and the window read takes no int8 "
+                        "scales and no verify window",
+                        "window read over blocks of two kinds"),
+    }
+
+    def _fence_tick_only_family(self, model, fam, *, mixed_step,
+                                kv_host_blocks, kv_quantize, spec_k,
+                                prefix_sharing) -> None:
+        """Start-up fences of the kv_latent and kv_windowed families
+        (registry FAMILY_CAPABILITIES): what the family's pool cannot do
+        yet is refused by name, never served wrong. (`tp > 1` is refused
+        above through the model's unshardable TP rule.)"""
+        name = f"model '{model.name}' ({fam} family)"
+        why, read = self._TICK_ONLY_WHY[fam]
         if not (self._paged and mixed_step):
             raise ValueError(
                 f"{name} is served by the mixed tick over the block "
                 f"pool only: set kv_block_size > 0 and mixed_step (the "
                 f"two-path prefill and the dense per-slot cache have no "
-                f"latent read)")
+                f"{read})")
         for flag, value, cap in (
                 ("kv_quantize", kv_quantize, "kv_quantize"),
                 ("kv_host_blocks", int(kv_host_blocks), "kv_host_tier"),
-                ("spec_k", int(spec_k), "spec_decode")):
-            if value:
+                ("spec_k", int(spec_k), "spec_decode"),
+                ("prefix_sharing", prefix_sharing, "prefix_sharing")):
+            if value and not model.supports(cap):
                 raise ValueError(
                     f"{flag} needs the '{cap}' capability, which {name} "
-                    f"does not declare: a latent block holds one latent "
-                    f"and one rope key a token, not a K and a V a head")
+                    f"does not declare: {why}")
 
-    def _refuse_latent_chain(self, what: str) -> Optional[str]:
-        """The chain wire format carries a K and a V of H_kv*D lanes:
-        migration, handoff and prefix fetch refuse for a latent pool."""
-        if not self._latent:
+    def _refuse_chain(self, what: str) -> Optional[str]:
+        """The chain wire format carries every block of a row, a K and a
+        V of H_kv*D lanes each: migration, handoff and prefix fetch
+        refuse for a latent pool and for one that frees window blocks."""
+        fam = getattr(self.spec, "state_family", None)
+        if fam not in self._TICK_ONLY_WHY:
             return None
         return (f"{what} needs the 'migration' capability, which the "
-                f"kv_latent family does not declare (the chain wire "
-                f"format carries a K and a V a head)")
+                f"{fam} family does not declare (the chain wire format "
+                f"carries a K and a V a head for every block of the row)")
 
     def _pin_pool_out(self, caches, scales=None):
         """TRACED helper for the pool-donating executables: constrain
@@ -1355,16 +1403,11 @@ class ContinuousGenerator:
             if key not in self._decode_exe:
                 cfg, dtype = self.cfg, self._dtype
                 quant = self._quant
-                latent = self._latent
-                if latent:
-                    from tpu_engine.models.moonlight import (
-                        moonlight_step_rows_ragged,
-                    )
-
+                own_step, held = self._ragged_step, self._held_experts
+                if own_step is not None:
                     # No tick feeds more slots than the token budget
-                    # plus a token a row: the routed pairs' static size.
+                    # plus a token a row: the step's static size.
                     max_tokens = self._mixed_budget + self.n_slots
-                    attn_fn = None
                 else:
                     attn_fn = self._paged_attn_fn(ragged=True)
 
@@ -1374,13 +1417,13 @@ class ContinuousGenerator:
                               eos_vec, counts, pens, stops):
                     # sample_slot gathers the hidden state BEFORE the LM
                     # head: one (B, vocab) projection per tick, not W.
-                    if latent:
-                        logits, caches, moe_rows = \
-                            moonlight_step_rows_ragged(
-                                params, tokens, caches, tables, pos0,
-                                qlen, cfg, dtype=dtype,
-                                sample_slot=sample_slot,
-                                max_tokens=max_tokens)
+                    if own_step is not None:
+                        # `caches` and `tables`: the pool's pair and the
+                        # rows' table, or one of each a kind of block.
+                        logits, caches, moe_rows = own_step(
+                            params, tokens, caches, tables, pos0, qlen,
+                            cfg, dtype=dtype, sample_slot=sample_slot,
+                            held=held, max_tokens=max_tokens)
                     elif quant:
                         logits, caches, scales = \
                             transformer_step_rows_ragged(
@@ -1416,7 +1459,7 @@ class ContinuousGenerator:
                     out += (nxt, done)
                     if controls:
                         out += (counts,)
-                    if latent:
+                    if own_step is not None:
                         out += (moe_rows,)
                     return out
 
@@ -2006,7 +2049,7 @@ class ContinuousGenerator:
         if not (self._paged or self._slab):
             return {"ok": False,
                     "reason": "migration requires the paged KV cache"}
-        refused = self._refuse_latent_chain("row export")
+        refused = self._refuse_chain("row export")
         if refused:
             return {"ok": False, "reason": refused}
         if not self._running:
@@ -2043,7 +2086,7 @@ class ContinuousGenerator:
         if not (self._paged or self._slab):
             raise ValueError("migration import requires the paged KV "
                              "cache (kv_block_size > 0)")
-        refused = self._refuse_latent_chain("migration import")
+        refused = self._refuse_chain("migration import")
         if refused:
             raise ValueError(refused)
         if not isinstance(snapshot, dict):
@@ -2091,13 +2134,13 @@ class ContinuousGenerator:
         not a migration. Refusals return ``{"ok": False, "reason"}``
         and never raise (the fetching peer falls back to local
         prefill)."""
+        refused = self._refuse_chain("prefix export")
+        if refused:
+            return {"ok": False, "reason": refused}
         if not self._paged or not self._prefix_sharing:
             return {"ok": False,
                     "reason": "prefix export requires the paged KV "
                               "cache with prefix sharing on"}
-        refused = self._refuse_latent_chain("prefix export")
-        if refused:
-            return {"ok": False, "reason": refused}
         if not self._running:
             return {"ok": False, "reason": "scheduler stopped"}
         toks = [int(t) for t in tokens]
@@ -2479,7 +2522,7 @@ class ContinuousGenerator:
             # their baseline mutate under them.
             out["mixed"] = dict(self._stats["mixed"])
         if "moe" in self._stats:
-            # Gated additive block (kv_latent lanes with expert layers).
+            # Gated additive block (lanes whose step routes experts).
             # `experts_touched`: (layer, expert) pairs that took at least
             # one row, summed over ticks.
             out["moe"] = dict(self._stats["moe"],
@@ -2516,6 +2559,17 @@ class ContinuousGenerator:
             out["kv_pool"] = self._pool.stats()
             out["kv_pool"]["pending_admissions"] = \
                 len(self._pending)  # lint: lockfree-ok GIL-safe deque len
+            if self._windowed:
+                # Gated additive keys: blocks of each kind the rows hold
+                # now, and the window blocks given back so far.
+                window = self._wpool.stats()
+                out["kv_pool"].update(
+                    full_blocks_held=(out["kv_pool"]["blocks_total"]
+                                      - out["kv_pool"]["blocks_free"]),
+                    window_blocks_total=window["blocks_total"],
+                    window_blocks_held=(window["blocks_total"]
+                                        - window["blocks_free"]),
+                    window_blocks_freed=self._wfreed)
         if self._slab:
             # Gated additive block (the state_slab family's kv_pool
             # analog): a kv_paged lane's /stats and /health bytes never
@@ -3778,6 +3832,13 @@ class ContinuousGenerator:
                     self._spool.release_row(rid)
                 self._slab_rows[row] = -1
             return
+        if self._windowed:
+            first, end = self._wspan[row]
+            with self._wpool.lock:
+                self._wpool.release_many(
+                    self._wtables[row, first:end].tolist())
+            self._wtables[row, :] = 0
+            self._wspan[row] = 0
         if not self._paged or not self._row_blocks[row]:
             return
         with self._pool.lock:
@@ -4166,6 +4227,11 @@ class ContinuousGenerator:
             self._tables[:, :] = 0
             for r in range(self.n_slots):
                 self._row_blocks[r] = []
+            if self._windowed:
+                with self._wpool.lock:
+                    self._wpool.reset()
+                self._wtables[:, :] = 0
+                self._wspan[:, :] = 0
             if violations:
                 self._stats["recover_invariant_violations"] = (
                     self._stats.get("recover_invariant_violations", 0)
@@ -4372,17 +4438,59 @@ class ContinuousGenerator:
                       if pos0 is not None else 0)
         self._clock.dispatch(width, int(fed.sum()), ctx_tokens)
 
-    def _count_moe(self, rows) -> None:
+    def _slide_window_blocks(self, pos0, qlen) -> None:
+        """Before a tick's dispatch, for every row it feeds: give back the
+        window blocks wholly behind the first column the row's first new
+        token still sees, and take blocks through its last new token. A
+        row so holds at most the window, a chunk and a block of tokens,
+        the bound `_wpool` is sized by. Freed table entries become the
+        null block, which the window read never walks. The tick's span
+        says what the two kinds of layer read (`ctx_tokens_full`,
+        `ctx_tokens_window`: the rooflines' bytes) and what was freed."""
+        pool, window = self._wpool, self.cfg.window
+        bs, width = pool.block_size, self._wtables.shape[1]
+        freed = read = 0
+        with pool.lock:
+            for r in np.flatnonzero(qlen > 0):
+                p0, q = int(pos0[r]), int(qlen[r])
+                seen = max(p0 - window + 1, 0)
+                read += p0 + q - seen
+                lo, hi = seen // bs, min((p0 + q - 1) // bs + 1, width)
+                first, end = self._wspan[r]
+                if lo > first:
+                    behind = self._wtables[r, first:min(lo, end)]
+                    pool.release_many(behind.tolist())
+                    freed += len(behind)
+                    self._wtables[r, first:min(lo, end)] = 0
+                    first = lo
+                if hi > end:
+                    end = max(end, first)
+                    self._wtables[r, end:hi] = pool.alloc(hi - end)
+                    end = hi
+                self._wspan[r] = (first, end)
+        self._wfreed += freed
+        fed = qlen > 0
+        self._clock.note(ctx_tokens_full=int((pos0[fed] + qlen[fed]).sum()),
+                         ctx_tokens_window=read, window_blocks_freed=freed)
+
+    def _count_moe(self, rows, fed: int) -> None:
         """`rows` (L_moe, E): what each expert of each expert layer took
-        this tick, back with the tick's other results. Into stats()["moe"]
-        and onto the tick's span (before `TickClock.end`)."""
-        assignments, touched = int(rows.sum()), int((rows > 0).sum())
+        this tick, back with the tick's other results; `fed`: the tokens
+        the tick fed, each routed to `top_k` experts a layer (on a lane
+        that holds a share of the experts, more pairs than formed a row
+        here). Into stats()["moe"] and onto the tick's span (before
+        `TickClock.end`)."""
+        held, touched = int(rows.sum()), int((rows > 0).sum())
+        assignments = fed * self.cfg.top_k * self.cfg.n_moe_layers
         self._moe_rows += rows
         moe = self._stats["moe"]
         moe["assignments"] += assignments
         moe["experts_touched"] += touched
         self._clock.note(moe_assignments=assignments,
                          moe_experts_touched=touched)
+        if "assignments_held" in moe:
+            moe["assignments_held"] += held
+            self._clock.note(moe_assignments_held=held)
 
     def _tick_done(self, prefill_tokens: int, decode_rows: int, width: int,
                    spec: Optional[dict] = None) -> None:
@@ -4490,11 +4598,17 @@ class ContinuousGenerator:
                 active[r] = not self._done[r] and not self._held[r]
 
         # ONE dispatch, under the pool lock (it donates the pool buffers).
+        if self._windowed:
+            self._slide_window_blocks(pos0, qlen)
         with pool.lock:
-            pool_args = (pool.caches,)
+            pool_args, tables = (pool.caches,), jnp.asarray(self._tables)
             if self._quant:
                 pool_args += (pool.scales,)
-            common = (self._step_params, *pool_args, jnp.asarray(self._tables),
+            if self._windowed:
+                # One of each a kind of block, (full, window).
+                pool_args = ((pool.caches, self._wpool.caches),)
+                tables = (tables, jnp.asarray(self._wtables))
+            common = (self._step_params, *pool_args, tables,
                       jnp.asarray(tokens), jnp.asarray(pos0),
                       jnp.asarray(qlen), jnp.asarray(sample_slot),
                       jnp.asarray(fold_pos), jnp.asarray(active),
@@ -4509,14 +4623,17 @@ class ContinuousGenerator:
                     jnp.asarray(self._pens), jnp.asarray(self._stops))
             else:
                 out = self._mixed_step_exe(width, False)(*common)
-            pool.caches = out[0]
+            if self._windowed:
+                pool.caches, self._wpool.caches = out[0]
+            else:
+                pool.caches = out[0]
             if self._quant:
                 pool.scales = out[1]
                 out = out[2:]
             else:
                 out = out[1:]
             moe_rows = None
-            if self._latent:
+            if self._ragged_step is not None:
                 out, moe_rows = out[:-1], out[-1]
             if controls:
                 nxt, done, self._counts = out
@@ -4528,7 +4645,7 @@ class ContinuousGenerator:
         nxt = np.array(nxt)
         done_new = np.array(done)
         if moe_rows is not None and "moe" in self._stats:
-            self._count_moe(np.asarray(moe_rows))
+            self._count_moe(np.asarray(moe_rows), int(qlen.sum()))
         self._clock.apply()
         # Dispatch counted only past the host sync above — a device-step
         # failure surfaces asynchronously AT that sync (not at the

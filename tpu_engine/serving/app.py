@@ -350,6 +350,25 @@ def serve_combined(
                 device=devices[i % len(devices)],
             )
             workers.append(WorkerNode(lane_cfg, engine=engine))
+    # A fleet that moves live streams between lanes needs every
+    # generative lane's family to ride the chain wire format: refused
+    # here, by capability, not at the first drain.
+    for flag, cap in (("migrate_streams", "migration"),
+                      ("disagg", "handoff")):
+        if not getattr(gateway_config, flag, False):
+            continue
+        for w in workers:
+            spec = getattr(w.engine, "spec", None)
+            if (spec is not None and "generate" in spec.capabilities
+                    and not spec.supports(cap)):
+                for lane in workers:
+                    lane.stop()
+                raise RuntimeError(
+                    f"--{flag.replace('_', '-')} needs the '{cap}' "
+                    f"capability, which model '{spec.name}' "
+                    f"({spec.state_family} family) does not declare "
+                    f"(the chain wire format carries a K and a V a head "
+                    f"for every block of the row)")
     if warmup:
         # Pre-compile every batch bucket before accepting traffic — the
         # reference pays its graph compile at session load the same way

@@ -450,6 +450,19 @@ class WorkerNode:
                 "--role prefill|decode requires the continuous "
                 "scheduler with the paged KV cache "
                 "(--kv-block-size > 0)")
+        if (self.config.role != "both" and model_family
+                and "generate" in self.engine.spec.capabilities
+                and not self.engine.spec.supports("handoff")):
+            # A dedicated role exports or adopts a chain after prefill:
+            # a family whose pool cannot ride the chain wire format would
+            # start, and then refuse every handoff.
+            raise RuntimeError(
+                f"--role {self.config.role} needs the 'handoff' "
+                f"capability, which model "
+                f"'{getattr(self.engine.spec, 'name', self.config.model)}'"
+                f" ({model_family} family) does not declare (the chain "
+                f"wire format carries a K and a V a head for every block "
+                f"of the row)")
         if getattr(self.engine.spec, "config", None) is not None:
             try:
                 if self._speculative:
