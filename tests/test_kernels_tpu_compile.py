@@ -175,29 +175,17 @@ _POOL_MOVERS = re.compile(
 _CASTS = re.compile(r"= bf16\[([\d,]+)\]\S* convert\(")
 
 
-@pytest.mark.parametrize("width", [1, 256])
-@pytest.mark.parametrize("config", ["gpt2-large", "mistral-7b-v0.2-8l"])
-def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
-    """The mixed step at a benchmark configuration's serving shapes
-    (its rows, its pool, shapes only), pool donated, compiled for one
-    v5e: the optimized program holds no `copy`, `dynamic-slice` or
-    `dynamic-update-slice` whose result is a layer of the pool or the
-    whole pool — the pool is scattered into in place on the layer
-    loop's carry and read by the kernel where it lies — and its
-    temporaries stay below the pool's size. The parent of PR 26 held 17
-    such copies and 7.18 GB of temporaries at gpt2-large's shapes. The
-    step is handed the tree the lane hands it (`spec.step_weights`: the
-    benchmark keeps float32 weights, the step reads their bfloat16 copy
-    made once), so no kernel is cast inside it either: the parent of PR
-    33 cast every stacked kernel every tick, for Mistral 3.5 GB of
-    temporaries, more than the pool."""
+def _cell_tick_shapes(v5e_devices, config, width):
+    """(cfg, the master tree's shapes, `_mixed_tick`'s arguments) at a
+    benchmark configuration's serving shapes (its rows, its pool), shapes
+    only, placed on one v5e. The step is handed the tree the lane hands
+    it (`spec.step_weights`)."""
     from jax.sharding import SingleDeviceSharding
 
     from tpu_engine.models.registry import (
         _ensure_builtin_models_imported,
         create_model,
     )
-    from tpu_engine.ops.paged_attention import ragged_paged_attention
     from tpu_engine.runtime.kv_blocks import BlockPool
 
     with open(os.path.join(REPO, "benchmarks", "configs",
@@ -223,15 +211,38 @@ def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
     master = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
     params = jax.tree.map(placed, jax.eval_shape(
         lambda p: spec.step_weights(p, jnp.bfloat16), master))
-    tick = _mixed_tick(
-        cfg, functools.partial(ragged_paged_attention, interpret=False))
 
     def host(*shape):
         return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
 
-    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+    return cfg, master, (
         params, KVCache(pool, pool), host(rows, -(-cfg.max_seq // bs)),
-        host(rows, width), host(rows), host(rows)).compile()
+        host(rows, width), host(rows), host(rows))
+
+
+@pytest.mark.parametrize("width", [1, 256])
+@pytest.mark.parametrize("config", ["gpt2-large", "mistral-7b-v0.2-8l"])
+def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
+    """The mixed step at a benchmark configuration's serving shapes
+    (its rows, its pool, shapes only), pool donated, compiled for one
+    v5e: the optimized program holds no `copy`, `dynamic-slice` or
+    `dynamic-update-slice` whose result is a layer of the pool or the
+    whole pool — the pool is scattered into in place on the layer
+    loop's carry and read by the kernel where it lies — and its
+    temporaries stay below the pool's size. The parent of PR 26 held 17
+    such copies and 7.18 GB of temporaries at gpt2-large's shapes. The
+    step is handed the tree the lane hands it (`spec.step_weights`: the
+    benchmark keeps float32 weights, the step reads their bfloat16 copy
+    made once), so no kernel is cast inside it either: the parent of PR
+    33 cast every stacked kernel every tick, for Mistral 3.5 GB of
+    temporaries, more than the pool."""
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+
+    cfg, master, args = _cell_tick_shapes(v5e_devices, config, width)
+    pool = args[1].k
+    tick = _mixed_tick(
+        cfg, functools.partial(ragged_paged_attention, interpret=False))
+    compiled = jax.jit(tick, donate_argnums=(1,)).lower(*args).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     whole = math.prod(pool.shape)
@@ -245,6 +256,79 @@ def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
     assert not cast, cast
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2 * whole * pool.dtype.itemsize, temp
+
+
+_CALLED = re.compile(
+    r"(?:calls|to_apply|body|condition)=(%[\w.\-]+)"
+    r"|branch_computations=\{([^}]*)\}")
+
+
+def _computations(hlo):
+    """{name: text} of an HLO module's computations, the entry's under
+    "ENTRY"; and {name: names it calls}."""
+    bodies, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?(%[\w.\-]+) \(.*\{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            bodies[name] = ""
+        elif name is not None:
+            bodies[name] += line + "\n"
+    calls = {name: {c for one, many in _CALLED.findall(text)
+                    for c in ([one] if one else many.split(", "))}
+             for name, text in bodies.items()}
+    return bodies, calls
+
+
+def test_mixed_step_sorts_only_under_the_samplers_third_branch(v5e_devices):
+    """The width-1 mixed step of gpt2-large WITH its sampling tail
+    (`_sample` over the step's logits, `kept` = the live rows), compiled
+    for one v5e: the optimized program holds ONE conditional of three
+    branches and every `sort` lies under its third, so a tick in which no
+    row filters runs none. The parent's step sorted 32 x 50257 logits in
+    its entry computation every tick (2.2 ms of a 10.7 ms tick on the
+    chip; at Moonlight's 163840, 7.2 ms)."""
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+    from tpu_engine.runtime.generator import _sample
+
+    cfg, _master, args = _cell_tick_shapes(v5e_devices, "gpt2-large", 1)
+    tick = _mixed_tick(
+        cfg, functools.partial(ragged_paged_attention, interpret=False))
+    rows = args[-1]
+
+    def like(dtype):
+        return jax.ShapeDtypeStruct(rows.shape, dtype,
+                                    sharding=rows.sharding)
+
+    def step(params, caches, tables, tokens, pos0, qlen, seeds, fold_pos,
+             temps, topps, topks, minps, live):
+        logits, caches = tick(params, caches, tables, tokens, pos0, qlen)
+        return _sample(logits, seeds, fold_pos, temps, topps, topks, minps,
+                       kept=live), caches
+
+    hlo = jax.jit(step, donate_argnums=(1,)).lower(
+        *args, rows, rows, like(jnp.float32), like(jnp.float32), rows,
+        like(jnp.float32), like(jnp.bool_)).compile().as_text()
+    bodies, calls = _computations(hlo)
+    switches = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}",
+                          hlo)
+    assert len(switches) == 1, switches
+    branches = switches[0].split(", ")
+    assert len(branches) == 3
+
+    def under(name):
+        seen, todo = set(), [name]
+        while todo:
+            one = todo.pop()
+            if one not in seen:
+                seen.add(one)
+                todo += calls.get(one, ())
+        return seen
+
+    sorting = {name for name, text in bodies.items() if " sort(" in text}
+    assert sorting and sorting <= under(branches[2]), sorting
+    assert not sorting & (under(branches[0]) | under(branches[1]))
+    assert "ENTRY" not in sorting
 
 
 @pytest.mark.parametrize("width", [1, 256])

@@ -137,21 +137,121 @@ def token_counts(rows: "Sequence[Sequence[int]]", n_rows: int,
     return out
 
 
+def sampler_body(temperature, top_p, top_k, min_p, kept=None):
+    """Which of `_sample`'s three bodies a tick's rows ask for: 0 greedy
+    (no kept row has temperature > 0), 1 plain (some row samples, none
+    filters: top_p >= 1, top_k 0, min_p 0), 2 filtered. Array arithmetic
+    only, so `_sample` asks it of traced controls on the device and the
+    scheduler of the same controls as numpy arrays when it counts the
+    tick (`SAMPLER_BODIES` names the answer). `kept` (B,) marks the rows
+    whose sample is real; without one every row counts."""
+    samples = temperature > 0
+    if kept is not None:
+        samples = samples & kept
+    filters = samples & ((top_p < 1) | (top_k > 0) | (min_p > 0))
+    return samples.any().astype("int32") + filters.any().astype("int32")
+
+
+SAMPLER_BODIES = ("greedy", "plain", "filtered")
+
+
+def _draw(key_seed, pos, lg):
+    """One row's draw, the same for a filtered and an unfiltered row:
+    key fold_in(PRNGKey(seed), position) over the row's scaled (and
+    possibly masked) logits."""
+    key = jax.random.fold_in(jax.random.PRNGKey(key_seed), pos)
+    return jax.random.categorical(key, lg)
+
+
+def _plain_row(key_seed, pos, lg, t):
+    return _draw(key_seed, pos, lg / jnp.maximum(t, 1e-6))
+
+
+def _filtered_row(key_seed, pos, lg, t, p, k_limit, p_min):
+    lg = lg / jnp.maximum(t, 1e-6)
+    sorted_lg = jnp.sort(lg)[::-1]
+    # Nucleus filter: keep the top tokens whose cumulative softmax mass
+    # reaches p (always at least one). p >= 1 keeps everything, WHATEVER
+    # the float32 cumulative sum says: it can reach 1.0 before the last
+    # token, and the tail it would mask is what an unfiltered row beside
+    # no filtering row (the plain body) draws from.
+    cum = jnp.cumsum(jax.nn.softmax(sorted_lg))
+    k = jnp.where(p >= 1, lg.shape[-1],
+                  jnp.minimum(jnp.sum(cum < p) + 1, lg.shape[-1]))
+    # top_k caps the kept set (0 disables). NOTE: when both filters
+    # are active this is min-of-counts over the UNFILTERED distribution
+    # — HF instead renormalizes after top_k before applying top_p, so
+    # its kept set can be strictly smaller; don't expect draw-level HF
+    # parity with both filters on. Tokens TIED at the threshold logit
+    # are all kept (same boundary behavior as HF's `logits <
+    # topk[-1]` mask), so top_k=1 equals greedy only when the max
+    # logit is unique — ties are broken by seed, not argmax order.
+    k = jnp.where(k_limit > 0, jnp.minimum(k, k_limit), k)
+    thresh = sorted_lg[k - 1]
+    lg = jnp.where(lg >= thresh, lg, -jnp.inf)
+    # min_p last, matching HF's warper order (temperature -> top_k ->
+    # top_p -> min_p): the threshold is relative to the max logit —
+    # always a survivor of the filters above, and renormalization
+    # preserves logit differences, so "p_tok >= min_p * p_max over the
+    # renormalized kept set" is exactly this mask. Applying it first
+    # instead would shrink the nucleus (the -inf'd tail re-weights
+    # cum above) and keep a slightly different set than HF.
+    min_thresh = jnp.where(p_min > 0,
+                           jnp.max(lg) + jnp.log(jnp.maximum(p_min,
+                                                             1e-30)),
+                           -jnp.inf)
+    lg = jnp.where(lg >= min_thresh, lg, -jnp.inf)
+    return _draw(key_seed, pos, lg)
+
+
+def _greedy_body(greedy, *_):
+    return greedy
+
+
+def _plain_body(_greedy, seeds, positions, logits, temperature, *_):
+    return jax.vmap(_plain_row)(seeds, positions, logits,
+                                temperature).astype(jnp.int32)
+
+
+def _filtered_body(_greedy, *rows):
+    return jax.vmap(_filtered_row)(*rows).astype(jnp.int32)
+
+
+# Module-level functions over explicit operands, not closures: a call
+# outside `jit` (admission's first token) then finds the traced branches
+# again and compiles the conditional once, not at every call.
+_BODIES = (_greedy_body, _plain_body, _filtered_body)
+
+
 def _sample(logits, seeds, positions, temperature, top_p=None, top_k=None,
-            min_p=None):
+            min_p=None, kept=None):
     """Per-row sampling: logits (B, V); seeds/positions/temperature/top_p/
-    top_k/min_p (B,).
+    top_k/min_p (B,); kept (B,) bool, optional: the rows whose sample the
+    caller keeps (a caller without one keeps every row's).
 
     Greedy where temperature == 0, else categorical — optionally filtered
     to the nucleus (smallest token set with cumulative probability >=
-    top_p), the top_k highest-logit tokens (0 = disabled), and/or min_p
+    top_p; top_p >= 1 keeps the WHOLE vocabulary), the top_k
+    highest-logit tokens (0 = disabled), and/or min_p
     (keep tokens whose probability >= min_p x the max probability; 0 =
     disabled — in logit space that is simply lg >= max_lg + log(min_p),
     applied after temperature and after the nucleus/top_k filters,
     matching HF's warper order) — with key
     fold_in(PRNGKey(seed_r), position_r): deterministic per
     (seed, position) so co-batching and bucketing never change a request's
-    tokens."""
+    tokens.
+
+    The call does only what its kept rows ask for: ONE of three bodies
+    runs, chosen on the device by `sampler_body` from the controls (a
+    `lax.switch` on a scalar OUTSIDE the `vmap` over rows: under a `vmap`
+    it would be a select and every body would run). Greedy: the argmax and
+    nothing else. Plain: temperature and the draw, no sort. Filtered: the
+    whole-vocabulary sort, cumulative sum and masks. A row's token does
+    NOT depend on the body its call took: a greedy row reads the argmax in
+    all three, and an unfiltered sampling row's logits reach the one
+    `_draw` unmasked in the filtered body too. A row that is not kept may
+    get a cheaper body's token (its own controls did not choose): never
+    read it."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     if top_p is None:
         top_p = jnp.ones(logits.shape[:1], jnp.float32)
@@ -160,42 +260,10 @@ def _sample(logits, seeds, positions, temperature, top_p=None, top_k=None,
     if min_p is None:
         min_p = jnp.zeros(logits.shape[:1], jnp.float32)
 
-    def row(key_seed, pos, lg, t, p, k_limit, p_min):
-        key = jax.random.fold_in(jax.random.PRNGKey(key_seed), pos)
-        lg = lg / jnp.maximum(t, 1e-6)
-        sorted_lg = jnp.sort(lg)[::-1]
-        # Nucleus filter: keep the top tokens whose cumulative softmax mass
-        # reaches p (always at least one). p >= 1 keeps everything.
-        cum = jnp.cumsum(jax.nn.softmax(sorted_lg))
-        k = jnp.minimum(jnp.sum(cum < p) + 1, lg.shape[-1])
-        # top_k caps the kept set (0 disables). NOTE: when both filters
-        # are active this is min-of-counts over the UNFILTERED distribution
-        # — HF instead renormalizes after top_k before applying top_p, so
-        # its kept set can be strictly smaller; don't expect draw-level HF
-        # parity with both filters on. Tokens TIED at the threshold logit
-        # are all kept (same boundary behavior as HF's `logits <
-        # topk[-1]` mask), so top_k=1 equals greedy only when the max
-        # logit is unique — ties are broken by seed, not argmax order.
-        k = jnp.where(k_limit > 0, jnp.minimum(k, k_limit), k)
-        thresh = sorted_lg[k - 1]
-        lg = jnp.where(lg >= thresh, lg, -jnp.inf)
-        # min_p last, matching HF's warper order (temperature -> top_k ->
-        # top_p -> min_p): the threshold is relative to the max logit —
-        # always a survivor of the filters above, and renormalization
-        # preserves logit differences, so "p_tok >= min_p * p_max over the
-        # renormalized kept set" is exactly this mask. Applying it first
-        # instead would shrink the nucleus (the -inf'd tail re-weights
-        # cum above) and keep a slightly different set than HF.
-        min_thresh = jnp.where(p_min > 0,
-                               jnp.max(lg) + jnp.log(jnp.maximum(p_min,
-                                                                 1e-30)),
-                               -jnp.inf)
-        lg = jnp.where(lg >= min_thresh, lg, -jnp.inf)
-        return jax.random.categorical(key, lg)
-
-    sampled = jax.vmap(row)(seeds, positions, logits, temperature,
-                            top_p, top_k, min_p).astype(jnp.int32)
-    return jnp.where(temperature > 0, sampled, greedy)
+    drawn = jax.lax.switch(
+        sampler_body(temperature, top_p, top_k, min_p, kept), _BODIES,
+        greedy, seeds, positions, logits, temperature, top_p, top_k, min_p)
+    return jnp.where(temperature > 0, drawn, greedy)
 
 
 def _decode_step_sampled(params, cfg, dtype, tok, caches, pos, start, done,
@@ -215,7 +283,7 @@ def _decode_step_sampled(params, cfg, dtype, tok, caches, pos, start, done,
     # The sampled token sits at logical position pos+1-start in its own
     # sequence — fold that in so the stream is batch/bucket-independent.
     nxt = _sample(logits, seeds, pos + 1 - start, temps, topps, topks,
-                  minps)
+                  minps, kept=~done)
     nxt = jnp.where(done, eos, nxt)
     if controls:
         counts = counts.at[jnp.arange(nxt.shape[0]), nxt].add(

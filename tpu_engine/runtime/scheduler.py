@@ -78,9 +78,11 @@ from tpu_engine.models.transformer import (
 from tpu_engine.ops.attention import KVCache
 from tpu_engine.runtime.generator import (
     _DTYPES,
+    SAMPLER_BODIES,
     _sample,
     apply_repetition_penalty,
     right_pad_prompt,
+    sampler_body,
     start_host_copies,
     token_counts,
 )
@@ -826,6 +828,10 @@ class ContinuousGenerator:
             self._stats["mixed"] = {
                 "ticks": 0, "dispatches": 0, "prefill_tokens": 0,
                 "decode_tokens": 0, "coscheduled_ticks": 0,
+                # Ticks by the body of `_sample` their kept rows asked
+                # for (`generator.sampler_body`); they sum to `ticks`.
+                "sample_greedy_ticks": 0, "sample_plain_ticks": 0,
+                "sample_filtered_ticks": 0,
                 "token_budget": self._mixed_budget,
                 "chunk_cap": self._chunk_cap,
             }
@@ -878,6 +884,7 @@ class ContinuousGenerator:
         self._row_starved_ticks = [0] * self.n_slots
         self._row_starved_us = [0.0] * self.n_slots
         self._tick_starved: List[int] = []
+        self._tick_sampler = SAMPLER_BODIES[0]
         # Staged brownout degradations (set_brownout; driven by the
         # serving worker's overload control loop, DESIGN.md "Overload
         # control"). Plain attribute writes from the control thread,
@@ -1078,7 +1085,7 @@ class ContinuousGenerator:
                             logits = apply_repetition_penalty(
                                 logits, counts, pens)
                         nxt = _sample(logits, seeds, pos + 1 - start, temps,
-                                      topps, topks, minps)
+                                      topps, topks, minps, kept=~done)
                         nxt = jnp.where(done, eos_vec, nxt)
                         if controls:
                             counts = counts.at[rows, nxt].add(
@@ -1307,7 +1314,7 @@ class ContinuousGenerator:
                             logits = apply_repetition_penalty(
                                 logits, counts, pens)
                         nxt = _sample(logits, seeds, pos + 1, temps,
-                                      topps, topks, minps)
+                                      topps, topks, minps, kept=~done)
                         nxt = jnp.where(done, eos_vec, nxt)
                         if controls:
                             counts = counts.at[rows, nxt].add(
@@ -1439,9 +1446,12 @@ class ContinuousGenerator:
                     if controls:
                         logits = apply_repetition_penalty(logits, counts,
                                                           pens)
-                    nxt = _sample(logits, seeds, fold_pos, temps, topps,
-                                  topks, minps)
+                    # The sampler's body is chosen by the rows whose
+                    # sample is real: a released slot's controls stay
+                    # where admission put them.
                     live = active & ~done
+                    nxt = _sample(logits, seeds, fold_pos, temps, topps,
+                                  topks, minps, kept=live)
                     nxt = jnp.where(live, nxt, eos_vec)
                     if controls:
                         counts = counts.at[rows, nxt].add(
@@ -1579,7 +1589,7 @@ class ContinuousGenerator:
                                 if controls else lg)
                         fold = fold0 + j
                         det = _sample(lg_p, seeds, fold, temps, topps,
-                                      topks, minps)
+                                      topks, minps, kept=alive)
                         # The draft token this slot must reproduce for
                         # the chain to continue (decode rows: window slot
                         # j+1; prefill/undrafted rows never chain).
@@ -1772,7 +1782,7 @@ class ContinuousGenerator:
                             logits = apply_repetition_penalty(
                                 logits, counts, pens)
                         nxt = _sample(logits, seeds, pos + 1, temps,
-                                      topps, topks, minps)
+                                      topps, topks, minps, kept=~done)
                         nxt = jnp.where(done, eos_vec, nxt)
                         if controls:
                             counts = counts.at[rows, nxt].add(
@@ -1837,9 +1847,9 @@ class ContinuousGenerator:
                     if controls:
                         kept = apply_repetition_penalty(kept, counts,
                                                         pens)
-                    nxt = _sample(kept, seeds, fold_pos, temps, topps,
-                                  topks, minps)
                     live = active & ~done
+                    nxt = _sample(kept, seeds, fold_pos, temps, topps,
+                                  topks, minps, kept=live)
                     nxt = jnp.where(live, nxt, eos_vec)
                     if controls:
                         counts = counts.at[rows, nxt].add(
@@ -4420,13 +4430,22 @@ class ContinuousGenerator:
         self._row_starved_us[row] = 0.0
 
     def _tick_formed(self, width: int, prefill_rows: List[int], chunk,
-                     qlen, pos0=None) -> None:
+                     qlen, active, pos0=None) -> None:
         """The tick's batch is formed and its arguments are on their way:
         note which prefilling rows the token budget fed and which it
         starved, then mark the clock's `dispatch`. `ctx_tokens` is the
         context the attention kernel reads for the rows in the dispatch:
         `pos0 + qlen` (a decode row's pos + 1, a prefilling row's
-        w0 + chunk); a recurrent step (no `pos0`) attends none."""
+        w0 + chunk); a recurrent step (no `pos0`) attends none. `active`:
+        the rows whose sample is real; the body of `_sample` they ask for
+        (the step asks the same of the same controls on the device; a
+        speculative step's first slot, its later ones never a dearer)
+        goes on the tick's span as `sampler` and into the tick's
+        `sample_*_ticks` counter."""
+        self._tick_sampler = SAMPLER_BODIES[int(sampler_body(
+            self._temps, self._topps, self._topks, self._minps,
+            active & ~self._done))]
+        self._clock.note(sampler=self._tick_sampler)
         self._tick_starved = []
         for r in prefill_rows:
             if chunk[r] > 0:
@@ -4616,7 +4635,8 @@ class ContinuousGenerator:
                       jnp.asarray(self._temps), jnp.asarray(self._topps),
                       jnp.asarray(self._topks), jnp.asarray(self._minps),
                       jnp.asarray(eos_vec))
-            self._tick_formed(width, prefill_rows, chunk, qlen, pos0)
+            self._tick_formed(width, prefill_rows, chunk, qlen, active,
+                              pos0)
             if controls:
                 out = self._mixed_step_exe(width, True)(
                     *common, self._ensure_counts(),
@@ -4656,6 +4676,7 @@ class ContinuousGenerator:
 
         m = self._stats["mixed"]
         m["ticks"] += 1
+        m[f"sample_{self._tick_sampler}_ticks"] += 1
         m["prefill_tokens"] += prefill_tokens
         m["decode_tokens"] += n_decode
         if prefill_tokens and n_decode:
@@ -4834,7 +4855,8 @@ class ContinuousGenerator:
                       jnp.asarray(self._temps), jnp.asarray(self._topps),
                       jnp.asarray(self._topks), jnp.asarray(self._minps),
                       jnp.asarray(eos_vec))
-            self._tick_formed(width, prefill_rows, chunk, qlen, pos0)
+            self._tick_formed(width, prefill_rows, chunk, qlen, active,
+                              pos0)
             if controls:
                 out = self._spec_step_exe(width, True, stochastic)(
                     *common, self._ensure_counts(),
@@ -4873,6 +4895,7 @@ class ContinuousGenerator:
         if self._mixed:
             m = self._stats["mixed"]
             m["ticks"] += 1
+            m[f"sample_{self._tick_sampler}_ticks"] += 1
             m["prefill_tokens"] += prefill_tokens
             if prefill_tokens and n_decode:
                 m["coscheduled_ticks"] += 1
@@ -5090,7 +5113,7 @@ class ContinuousGenerator:
                       jnp.asarray(self._temps), jnp.asarray(self._topps),
                       jnp.asarray(self._topks), jnp.asarray(self._minps),
                       jnp.asarray(eos_vec))
-            self._tick_formed(width, prefill_rows, chunk, qlen)
+            self._tick_formed(width, prefill_rows, chunk, qlen, active)
             if controls:
                 out = self._slab_mixed_exe(width, True)(
                     *common, self._ensure_counts(),
@@ -5114,6 +5137,7 @@ class ContinuousGenerator:
 
         m = self._stats["mixed"]
         m["ticks"] += 1
+        m[f"sample_{self._tick_sampler}_ticks"] += 1
         m["prefill_tokens"] += prefill_tokens
         m["decode_tokens"] += n_decode
         if prefill_tokens and n_decode:

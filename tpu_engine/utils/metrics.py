@@ -368,6 +368,10 @@ def render_prometheus(healths: List[Dict], stats: Optional[Dict] = None,
     metric("tpu_engine_mixed_coscheduled_ticks_total", "counter",
            "Ticks that carried BOTH decode rows and prefill chunks",
            [(node(h), m.get("coscheduled_ticks")) for h, m in mx])
+    metric("tpu_engine_mixed_sample_ticks_total", "counter",
+           "Mixed ticks by the sampler body their kept rows asked for",
+           [({**node(h), "body": body}, m.get(f"sample_{body}_ticks"))
+            for h, m in mx for body in ("greedy", "plain", "filtered")])
     metric("tpu_engine_mixed_token_budget", "gauge",
            "Per-tick new-token budget (--mixed-token-budget)",
            [(node(h), m.get("token_budget")) for h, m in mx])

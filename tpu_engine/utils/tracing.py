@@ -522,7 +522,7 @@ class TickClock:
         self._compile_s0 = 0.0
         self._width = 0
         self._ctx_tokens = 0
-        self._notes: Dict[str, int] = {}
+        self._notes: Dict[str, object] = {}
         self._prev_wait_end: Optional[float] = None
         self._recent: Dict[int, deque] = {}
         self._last_slow_line = 0.0
@@ -584,9 +584,10 @@ class TickClock:
         self._mark("tick.apply")
 
     def note(self, **attrs) -> None:
-        """Counts the step brought back with the tick's results (a
-        routed model's `moe_assignments`, `moe_experts_touched`): they
-        ride the tick's span beside `ctx_tokens`."""
+        """What the scheduler knows of the tick beyond its phases: counts
+        the step brought back with its results (a routed model's
+        `moe_assignments`, `moe_experts_touched`), the `sampler` body its
+        rows asked for. They ride the tick's span beside `ctx_tokens`."""
         self._notes.update(attrs)
 
     def end(self, live: bool, node: str) -> Tuple[float, float, dict]:
