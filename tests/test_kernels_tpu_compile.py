@@ -476,6 +476,87 @@ def test_windowed_mixed_step_copies_no_pool_and_no_bank(v5e_devices, width):
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
 
 
+@pytest.mark.parametrize("width", [1, 256])
+def test_hybrid_mixed_step_copies_neither_the_pool_nor_the_states(v5e_devices,
+                                                                  width):
+    """The Olmo-Hybrid cell's mixed step at its serving shapes (shapes
+    only), the block pool and the state pool donated, compiled for one v5e:
+    the full layers' paged call is in it (at 30 KV heads of 128 lanes its
+    groups fit Mosaic's 16 MB: a 16-block group did not); no `copy`,
+    `slice` or `dynamic-slice` whose result is a pool, a state array or a
+    layer of one, and a `dynamic-update-slice` of that size only as a
+    chunk row's write of its conv tail into its own state row (in place:
+    the loop carries the array); both forms of the recurrence are Pallas
+    calls that change the state pool where it lies (`gdn_step`,
+    `gdn_chunk`); temporaries under 0.3 GB beside 13.4 GB of weights and
+    pools."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.olmo_hybrid import olmo_hybrid_step_rows_ragged
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops.gated_delta import gdn_chunk_row, gdn_step_rows
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "olmo-hybrid-7b-12l.json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    assert width in (1, serving["gen_prefill_chunk"])
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg = spec.config
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    (kind,) = cfg.kv_block_kinds
+    one = placed(jax.ShapeDtypeStruct(
+        (kind.n_layers, serving["gen_kv_blocks"], bs, kind.kv_lanes[0]),
+        jnp.bfloat16))
+    pools = (KVCache(one, one),
+             tuple(placed(jax.ShapeDtypeStruct(
+                 (cfg.n_linear_layers, rows + 1) + shape, jnp.float32))
+                 for shape in cfg.state_row_shapes))
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return olmo_hybrid_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=functools.partial(ragged_paged_attention,
+                                      interpret=False),
+            step_fn=functools.partial(gdn_step_rows, interpret=False),
+            chunk_fn=functools.partial(gdn_chunk_row, interpret=False),
+            sample_slot=jnp.zeros_like(pos0),
+            max_tokens=serving["gen_prefill_chunk"] + rows)
+
+    def host(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+        params, pools, (host(rows, -(-cfg.max_seq // bs)), host(rows)),
+        host(rows, width), host(rows), host(rows)).compile()
+    hlo = compiled.as_text()
+    assert "_paged_call" in hlo and "gdn_step" in hlo
+    assert ("gdn_chunk" in hlo) == (width > 1)
+    sizes = set()
+    for x in list(pools[0]) + list(pools[1]):
+        sizes |= {math.prod(x.shape), math.prod(x.shape[1:])}
+    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
+                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
+    moved = {op for dims, op in movers.findall(hlo)
+             if math.prod(map(int, dims.split(","))) in sizes}
+    assert moved <= ({"dynamic-update-slice"} if width > 1 else set()), moved
+    analysis = compiled.memory_analysis()
+    assert analysis.temp_size_in_bytes < 0.3e9
+    assert analysis.alias_size_in_bytes > 6.8e9      # both pools in place
+
+
 def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
     """Where JAX_COMPILATION_CACHE_DIR is set, no code names a cache
     directory (JAX reads the variable itself); unset, the directory is

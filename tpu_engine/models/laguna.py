@@ -69,6 +69,8 @@ from tpu_engine.models.transformer import (
     TransformerConfig,
     _mlp,
     _write_pool,
+    index_in_kind,
+    kv_kind_config,
 )
 from tpu_engine.ops import nn
 from tpu_engine.ops.attention import KVCache, dot_product_attention
@@ -137,23 +139,14 @@ class LagunaConfig(TransformerConfig):
 
     @property
     def kv_block_kinds(self) -> Tuple[TransformerConfig, TransformerConfig]:
-        """(full, window): what a block pool of each kind is sized by
-        (`runtime.kv_blocks.BlockPool` reads layers, KV heads and head
-        width): the kind's layers alone."""
-        return tuple(TransformerConfig(
-            n_layers=n, d_model=self.d_model, n_heads=self.n_heads,
-            n_kv_heads=self.n_kv_heads, head_dim=self.head_dim)
-            for n in (self.n_full_layers, self.n_window_layers))
+        """(full, window): what a block pool of each kind is sized by."""
+        return tuple(kv_kind_config(self, n)
+                     for n in (self.n_full_layers, self.n_window_layers))
 
     @property
     def pool_layer(self) -> Tuple[int, ...]:
         """Layer l's index in the pool of its kind."""
-        seen = {False: 0, True: 0}
-        out = []
-        for w in self.windowed:
-            out.append(seen[w])
-            seen[w] += 1
-        return tuple(out)
+        return index_in_kind(self.windowed)
 
 
 # -- rope -----------------------------------------------------------------------

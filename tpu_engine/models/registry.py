@@ -40,6 +40,14 @@ from typing import Callable, Dict, List, Optional, Tuple
 # prefix hit, be demoted to no host tier and ride no chain, so prefix
 # sharing, the host tier, migration and handoff are ABSENT, with int8
 # (the window read takes no scales), `--tp` and speculative verify.
+# "kv_and_state": blocks for some layers and a state row for the others
+# (models.olmo_hybrid): a row holds a kv_paged chain over the block pool,
+# which holds the full-attention layers alone, AND one row of a state pool
+# (the recurrent layers' fixed-size state and conv tail), admitted, parked
+# and released as one. Served by the mixed tick alone. A recurrent state
+# is not block-addressable, cannot be rolled back past a rejected draft
+# and rides no chain, so prefix sharing, the host tier, int8 payloads,
+# speculative verify, `--tp`, migration and handoff are ABSENT.
 FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
     "kv_paged": ("generate", "two_path", "mixed_step", "spec_decode",
                  "paged_kv", "prefix_sharing", "kv_quantize",
@@ -51,6 +59,7 @@ FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
     "kv_latent": ("generate", "mixed_step", "paged_kv", "prefix_sharing",
                   "oneshot_rows"),
     "kv_windowed": ("generate", "mixed_step", "paged_kv", "oneshot_rows"),
+    "kv_and_state": ("generate", "mixed_step", "paged_kv", "oneshot_rows"),
 }
 
 # -- tensor-parallel partition rules ------------------------------------------
@@ -267,7 +276,7 @@ class ModelSpec:
             rule = getattr(self.config, "tp_partition_rule", None)
             if rule is None:
                 if self.state_family in ("kv_paged", "kv_latent",
-                                         "kv_windowed"):
+                                         "kv_windowed", "kv_and_state"):
                     rule = "transformer"
                 elif self.state_family == "state_slab":
                     # Defensive default for undeclared recurrent models:
@@ -332,6 +341,6 @@ def _ensure_builtin_models_imported():
     from tpu_engine.models import mlp, resnet  # noqa: F401
 
     for optional in ("bert", "gpt2", "llama", "yolo", "ssd", "moonlight",
-                     "laguna"):
+                     "laguna", "olmo_hybrid"):
         if importlib.util.find_spec(f"tpu_engine.models.{optional}") is not None:
             importlib.import_module(f"tpu_engine.models.{optional}")

@@ -106,6 +106,27 @@ class TransformerConfig:
                          capacity_factor=self.moe_capacity_factor)
 
 
+def index_in_kind(kinds) -> Tuple[int, ...]:
+    """For a model whose layers are of several kinds (`kinds`: one hashable
+    value a layer), each kind keeping its rows' state in a pool of its own:
+    layer l's index among the layers of ITS kind, the layer of that pool
+    it reads and writes (`models.laguna`, `models.olmo_hybrid`)."""
+    seen, out = {}, []
+    for kind in kinds:
+        out.append(seen.get(kind, 0))
+        seen[kind] = out[-1] + 1
+    return tuple(out)
+
+
+def kv_kind_config(cfg: TransformerConfig, n_layers: int) -> TransformerConfig:
+    """What a block pool that holds `n_layers` of `cfg`'s layers is sized by
+    (`runtime.kv_blocks.BlockPool` reads layers, KV heads and head width):
+    one kind's layers alone."""
+    return TransformerConfig(
+        n_layers=n_layers, d_model=cfg.d_model, n_heads=cfg.n_heads,
+        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim)
+
+
 def _norm_init(cfg: TransformerConfig):
     return (nn.rmsnorm_init(cfg.d_model) if cfg.norm == "rmsnorm"
             else nn.layernorm_init(cfg.d_model))

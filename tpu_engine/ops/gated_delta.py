@@ -1,0 +1,361 @@
+"""The gated delta rule: a linear-attention layer whose fixed-size state is
+decayed, corrected by a rank-one term and written to, a token at a time.
+
+A head keeps S (d_v x d_k, float32). Token t brings a key k_t and a query
+q_t (d_k lanes), a value v_t (d_v lanes), a decay a_t = exp(g_t) in (0, 1]
+and a writing strength b_t (in (0, 2) where negative eigenvalues are
+allowed):
+
+    S_t = a_t S_{t-1} + b_t (v_t - a_t S_{t-1} k_t) k_t^T
+    o_t = S_t q_t
+
+Two forms of the one recurrence:
+
+- `gdn_step`: one token a row, every row of a batch at once. The DECODE
+  form: the state is read, changed and written once.
+- `gdn_chunk`: a row's run of tokens in sub-chunks of `SUB_CHUNK`. Inside a
+  sub-chunk the rank-one corrections couple the tokens through a unit
+  lower-triangular system (the WY form of the Gated DeltaNet paper): with
+  G_i = sum_{j<=i} g_j, A_ij = b_i exp(G_i - G_j) k_i.k_j for j < i and
+  T = (I + A)^-1,
+
+      U = T (b v) - T (b exp(G) k) S_0^T         the values really written
+      O = exp(G) q S_0^T + (q k^T * D) U         D_ij = exp(G_i - G_j), j <= i
+      S_C = exp(G_C) S_0 + U^T (exp(G_C - G) k)
+
+  so a sub-chunk is a handful of matrix products and the state is carried
+  once a sub-chunk. The PREFILL form; it starts from a GIVEN state and
+  hands the last one back. A token with b = 0 and g = 0 changes nothing:
+  that is how a run shorter than its padded length is masked.
+  `gdn_chunk_row` is the form as the served path takes it, on a state that
+  lies in a pool (below).
+
+`gdn_scan` is the recurrence as written, a `lax.scan` over tokens: what the
+two forms are tested against.
+
+`gdn_step_rows` is the step as the served path takes it: the rows' states
+lie in a POOL (layers, rows, H, d_v, d_k) and are changed where they lie.
+On a TPU it is a Pallas kernel named `gdn_step` in a trace: the grid is
+(rows, blocks of heads), a step's block of the pool is chosen by the layer
+and the row's pool row in SMEM (neither the layer nor the gather ever
+materializes) and aliased to the output, so a state is read once and
+written once. A head's update is multiplies and lane sums on a (d_v, d_k)
+tile: k, q and the decay come as rows, b v as a column (the heads of a
+block on the lanes, so a column is a lane slice), o leaves as a column.
+Elsewhere (and as the kernel's reference) it is `gdn_step` over a gather
+of the rows and a scatter back. `gdn_chunk_row` is a row's run of tokens
+from its state in the same pool: the kernel `gdn_chunk`, a head a grid
+step, solves each sub-chunk's triangular system by forward substitution
+(A handed over transposed, so its row is a lane slice) and carries the
+state through the sub-chunks' products in VMEM.
+
+Everything here is float32; the products take `PRECISION` (the MXU's
+float32 passes), since a state error is carried for the rest of the row.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.scipy.linalg import solve_triangular
+
+SUB_CHUNK = 64
+PRECISION = jax.lax.Precision.HIGHEST
+# State a grid step of the step kernel holds (in and out, double-buffered:
+# four of these in VMEM): 15 of Olmo-Hybrid-7B's 30 heads, 1.47 MB.
+_STEP_BLOCK_BYTES = 3 << 19
+
+
+def gdn_scan(q, k, v, g, beta, state):
+    """The recurrence, token by token. q, k: (T, H, d_k); v: (T, H, d_v);
+    g, beta: (T, H); state: (H, d_v, d_k). Returns (o (T, H, d_v), state)."""
+    def step(s, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        s = s * jnp.exp(g_t)[:, None, None]
+        u = b_t[:, None] * (v_t - (s * k_t[:, None, :]).sum(-1))
+        s = s + u[:, :, None] * k_t[:, None, :]
+        return s, (s * q_t[:, None, :]).sum(-1)
+
+    state, o = jax.lax.scan(step, state.astype(jnp.float32),
+                            tuple(x.astype(jnp.float32)
+                                  for x in (q, k, v, g, beta)))
+    return o, state
+
+
+def gdn_step(q, k, v, g, beta, state):
+    """One token a row. q, k: (B, H, d_k); v: (B, H, d_v); g, beta: (B, H);
+    state: (B, H, d_v, d_k) float32. Returns (o (B, H, d_v), state). Plain
+    multiplies and sums over the state's lanes: one pass over the state for
+    S k, one for the update and S q."""
+    s = state * jnp.exp(g)[..., None, None]
+    u = beta[..., None] * (v - (s * k[..., None, :]).sum(-1))
+    s = s + u[..., None] * k[..., None, :]
+    return (s * q[..., None, :]).sum(-1), s
+
+
+def gdn_step_rows_reference(q, k, v, g, beta, pool, layer, rows, live, fresh):
+    """`gdn_step_rows` by a gather of the rows' states and a scatter back."""
+    old = pool[layer, rows]
+    o, new = gdn_step(q, k, v, g, beta,
+                      jnp.where(fresh[:, None, None, None], 0.0, old))
+    return o, pool.at[layer, rows].set(
+        jnp.where(live[:, None, None, None], new, old))
+
+
+def _step_kernel(rows_ref, layer_ref, live_ref, fresh_ref, vec_ref, bv_ref,
+                 s_ref, s_out, o_ref, *, heads):
+    del rows_ref, layer_ref                      # the index maps read them
+    b = pl.program_id(0)
+    keep = (fresh_ref[b] == 0).astype(jnp.float32)
+
+    @pl.when(live_ref[b] != 0)
+    def _():
+        for i in range(heads):
+            s = s_ref[0, 0, i] * keep                        # (d_v, d_k)
+            k, q, a, kb = (vec_ref[0, 0, n, i:i + 1, :] for n in range(4))
+            u = bv_ref[0, 0, :, i:i + 1] - jnp.sum(s * kb, axis=1,
+                                                   keepdims=True)
+            s = s * a + u * k
+            s_out[0, 0, i] = s
+            o_ref[0, 0, :, i:i + 1] = jnp.sum(s * q, axis=1, keepdims=True)
+
+    @pl.when(live_ref[b] == 0)
+    def _():
+        # The null row, copied onto itself.
+        s_out[...] = s_ref[...]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _step_call(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
+               interpret: bool):
+    b, h, dk = k.shape
+    dv = v.shape[-1]
+    lanes = -(-dk // 128) * 128
+    heads = max(p for p in range(1, h + 1) if h % p == 0
+                and (p == 1 or p * dv * lanes * 4 <= _STEP_BLOCK_BYTES))
+    blocks = h // heads
+    a = jnp.exp(g)
+    # Rows of d_k lanes a head: k, q, the decay, and b a k (S k is wanted
+    # as b a S k); the heads of a block side by side.
+    vec = jnp.stack([k, q, jnp.broadcast_to(a[..., None], k.shape),
+                     (beta * a)[..., None] * k], axis=1)
+    vec = vec.reshape(b, 4, blocks, heads, dk).transpose(0, 2, 1, 3, 4)
+    # b v as columns: a block's heads on the lanes.
+    bv = (beta[..., None] * v).reshape(b, blocks, heads, dv)
+    bv = bv.transpose(0, 1, 3, 2)
+
+    def state(b, j, rows, layer, *_):
+        return (layer[0], rows[b], j, 0, 0)
+
+    def column(b, j, *_):
+        return (b, j, 0, 0)
+
+    pool, o = pl.pallas_call(
+        functools.partial(_step_kernel, heads=heads),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,            # rows, layer, live, fresh
+            grid=(b, blocks),
+            in_specs=[
+                pl.BlockSpec((1, 1, 4, heads, dk),
+                             lambda b, j, *_: (b, j, 0, 0, 0)),
+                pl.BlockSpec((1, 1, dv, heads), column),
+                pl.BlockSpec((1, 1, heads, dv, dk), state)],
+            out_specs=[pl.BlockSpec((1, 1, heads, dv, dk), state),
+                       pl.BlockSpec((1, 1, dv, heads), column)]),
+        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                   jax.ShapeDtypeStruct((b, blocks, dv, heads),
+                                        jnp.float32)],
+        input_output_aliases={6: 0},          # the pool, in place
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret, name="gdn_step",
+    )(jnp.where(live, rows, 0).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), live.astype(jnp.int32),
+      fresh.astype(jnp.int32), vec, bv, pool)
+    return o.transpose(0, 1, 3, 2).reshape(b, h, dv), pool
+
+
+def gdn_step_rows(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
+                  interpret=None):
+    """One token a row, the states changed where they lie. q, k: (B, H,
+    d_k); v: (B, H, d_v); g, beta: (B, H); pool: (L, R, H, d_v, d_k)
+    float32, donated; layer: the pool's layer; rows: (B,) each row's pool
+    row; live: (B,) rows that take the step (the others' states are left
+    as they are, their outputs garbage); fresh: (B,) rows whose state is
+    zero before the step. Returns (o (B, H, d_v), pool). `interpret=None`
+    picks the kernel on a TPU and the gather elsewhere; True runs the
+    kernel in the Pallas interpreter."""
+    if interpret is None and jax.default_backend() != "tpu":
+        return gdn_step_rows_reference(q, k, v, g, beta, pool, layer, rows,
+                                       live, fresh)
+    return _step_call(q, k, v, g, beta, pool, layer, rows, live, fresh,
+                      interpret=bool(interpret))
+
+
+def _heads_first(x, n):
+    """(T, H, ...) -> (H, n, C, ...)."""
+    x = jnp.moveaxis(x, 1, 0)
+    return x.reshape(x.shape[0], n, SUB_CHUNK, *x.shape[2:])
+
+
+def _mm(eq, a, b):
+    return jnp.einsum(eq, a, b, precision=PRECISION)
+
+
+def _chunk_terms(q, k, v, g, beta):
+    """What a run's sub-chunks need before the state is touched, each
+    (H, n, C, ...), n sub-chunks of C = `SUB_CHUNK` tokens: A (strictly
+    lower), the solve's two right-hand sides b v and b exp(G) k, q k^T * D,
+    exp(G) q (reads S_0), exp(G_C - G) k (feeds S_C), and exp(G_C)
+    (H, n)."""
+    t = g.shape[0]
+    if t % SUB_CHUNK:
+        raise ValueError(f"a run of {t} tokens is no multiple of "
+                         f"{SUB_CHUNK}")
+    q, k, v, g, beta = (_heads_first(x.astype(jnp.float32), t // SUB_CHUNK)
+                        for x in (q, k, v, g, beta))
+    cum = jnp.cumsum(g, axis=-1)                              # (H, n, C)
+    at = jnp.arange(SUB_CHUNK)
+    # exp(G_i - G_j) where j <= i, 0 above the diagonal: masked before
+    # the exponential, whose argument is positive there.
+    decay = jnp.exp(jnp.where(at[:, None] >= at[None, :],
+                              cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))
+    a = jnp.where(at[:, None] > at[None, :],
+                  beta[..., None] * decay * _mm("hnik,hnjk->hnij", k, k),
+                  0.0)
+    return (a, beta[..., None] * v, (beta * jnp.exp(cum))[..., None] * k,
+            _mm("hnik,hnjk->hnij", q, k) * decay,
+            q * jnp.exp(cum)[..., None],
+            k * jnp.exp(cum[..., -1:] - cum)[..., None],
+            jnp.exp(cum[..., -1]))
+
+
+def gdn_chunk(q, k, v, g, beta, state):
+    """A row's run of T tokens, T a multiple of `SUB_CHUNK`, from `state`.
+    Shapes as `gdn_scan`. Returns (o (T, H, d_v), state (H, d_v, d_k))."""
+    t, h = g.shape
+    a, bv, kb, qk, q_in, k_out, last = _chunk_terms(q, k, v, g, beta)
+    solved = solve_triangular(a + jnp.eye(SUB_CHUNK),
+                              jnp.concatenate([bv, kb], axis=-1),
+                              lower=True, unit_diagonal=True)
+    u_v, w = solved[..., :bv.shape[-1]], solved[..., bv.shape[-1]:]
+
+    def sub_chunk(s, x):
+        u_v, w, qk, q_in, k_out, last = x
+        u = u_v - _mm("hck,hvk->hcv", w, s)
+        o = _mm("hck,hvk->hcv", q_in, s) + _mm("hij,hjv->hiv", qk, u)
+        s = s * last[:, None, None] + _mm("hcv,hck->hvk", u, k_out)
+        return s, o
+
+    state, o = jax.lax.scan(
+        sub_chunk, state.astype(jnp.float32),
+        tuple(jnp.moveaxis(x, 1, 0)
+              for x in (u_v, w, qk, q_in, k_out, last)))
+    # (n, H, C, d_v) -> (T, H, d_v)
+    return jnp.moveaxis(o, 1, 2).reshape(t, h, -1), state
+
+
+def gdn_chunk_row_reference(q, k, v, g, beta, pool, layer, row, fresh):
+    """`gdn_chunk_row` by `gdn_chunk` on the row's state, written back."""
+    o, last = gdn_chunk(q, k, v, g, beta,
+                        jnp.where(fresh, 0.0, pool[layer, row]))
+    return o, pool.at[layer, row].set(last)
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=PRECISION,
+                               preferred_element_type=jnp.float32)
+
+
+def _chunk_kernel(row_ref, layer_ref, fresh_ref, at_ref, bv_ref, kb_ref,
+                  qk_ref, qin_ref, kout_ref, last_ref, s_ref, s_out, o_ref,
+                  u_v, w, *, n):
+    """A head of a row's run: its sub-chunks in turn. `at_ref` holds A
+    TRANSPOSED, so A's row i is a column, a lane slice."""
+    del row_ref, layer_ref                       # the index maps read them
+    s = s_ref[0, 0, 0] * (fresh_ref[0] == 0).astype(jnp.float32)
+    for c in range(n):
+        # (I + A) X = rhs by forward substitution: row i of X is rhs's less
+        # A's row i times the rows above it (the rows below are still 0).
+        u_v[...] = jnp.zeros_like(u_v)
+        w[...] = jnp.zeros_like(w)
+        for i in range(SUB_CHUNK):
+            above = at_ref[0, c, :, i:i + 1]                     # (C, 1)
+            u_v[i:i + 1, :] = bv_ref[0, c, i:i + 1, :] - jnp.sum(
+                above * u_v[...], axis=0, keepdims=True)
+            w[i:i + 1, :] = kb_ref[0, c, i:i + 1, :] - jnp.sum(
+                above * w[...], axis=0, keepdims=True)
+        u = u_v[...] - _dot(w[...], s, ((1,), (1,)))             # (C, d_v)
+        o_ref[0, c] = (_dot(qin_ref[0, c], s, ((1,), (1,)))
+                       + _dot(qk_ref[0, c], u, ((1,), (0,))))
+        s = s * last_ref[0, c] + _dot(u, kout_ref[0, c], ((0,), (0,)))
+    s_out[0, 0, 0] = s
+
+
+def _chunk_call(q, k, v, g, beta, pool, layer, row, fresh, *,
+                interpret: bool):
+    t, h, dk = k.shape
+    dv = v.shape[-1]
+    n = t // SUB_CHUNK
+    a, bv, kb, qk, q_in, k_out, last = _chunk_terms(q, k, v, g, beta)
+    last = jnp.broadcast_to(last[..., None, None], (h, n, 1, dk))
+
+    def head(j, *_):
+        return (j, 0, 0, 0)
+
+    def state(j, row, layer, *_):
+        return (layer[0], row[0], j, 0, 0)
+
+    def sub_chunks(*shape):
+        return pl.BlockSpec((1, n) + shape, head)
+
+    c = SUB_CHUNK
+    pool, o = pl.pallas_call(
+        functools.partial(_chunk_kernel, n=n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,                # row, layer, fresh
+            grid=(h,),
+            in_specs=[sub_chunks(c, c), sub_chunks(c, dv),
+                      sub_chunks(c, dk), sub_chunks(c, c),
+                      sub_chunks(c, dk), sub_chunks(c, dk),
+                      sub_chunks(1, dk),
+                      pl.BlockSpec((1, 1, 1, dv, dk), state)],
+            out_specs=[pl.BlockSpec((1, 1, 1, dv, dk), state),
+                       sub_chunks(c, dv)],
+            scratch_shapes=[pltpu.VMEM((c, dv), jnp.float32),
+                            pltpu.VMEM((c, dk), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                   jax.ShapeDtypeStruct((h, n, c, dv), jnp.float32)],
+        input_output_aliases={10: 0},             # the pool, in place
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="gdn_chunk",
+    )(*(jnp.asarray(x, jnp.int32).reshape(1) for x in (row, layer, fresh)),
+      jnp.swapaxes(a, -1, -2), bv, kb, qk, q_in, k_out, last, pool)
+    return jnp.moveaxis(o.reshape(h, t, dv), 0, 1), pool
+
+
+def gdn_chunk_row(q, k, v, g, beta, pool, layer, row, fresh, *,
+                  interpret=None):
+    """A row's run of T tokens (a multiple of `SUB_CHUNK`; shapes as
+    `gdn_scan`) from the state at `pool[layer, row]`, which is changed
+    where it lies (`fresh`: it counts as zero). pool: (L, R, H, d_v, d_k)
+    float32, donated. Returns (o (T, H, d_v), pool). On a TPU the
+    triangular solves and the pass over the sub-chunks are a Pallas kernel
+    named `gdn_chunk` in a trace, a head a grid step, its state block
+    chosen by the layer and the row in SMEM and aliased to the output; what
+    the sub-chunks need before the state is touched (`_chunk_terms`) is
+    batched XLA. `interpret=None` picks the kernel on a TPU and
+    `gdn_chunk` elsewhere; True runs the kernel in the Pallas
+    interpreter."""
+    if interpret is None and jax.default_backend() != "tpu":
+        return gdn_chunk_row_reference(q, k, v, g, beta, pool, layer, row,
+                                       fresh)
+    return _chunk_call(q, k, v, g, beta, pool, layer, row, fresh,
+                       interpret=bool(interpret))

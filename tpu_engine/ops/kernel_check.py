@@ -100,6 +100,21 @@ CELL_SHAPES = {
         n_blocks=2049, window=512,
         rows=tuple((8, 4096 + 8 * t) for t in range(8))
         + ((1, 700), (1, 5000), (5, 0), (0, 0))),
+    # digest (benchmarks/configs/olmo-hybrid-7b-12l.json): the full layers
+    # of a model whose other layers are recurrent, G = 1 at head size 128,
+    # 30 KV heads (3840 lanes a token: the walk's groups are 8 blocks).
+    # Width 1: 16 decode rows of 0.6-8.4 k columns, the 30 heads packed.
+    # Width 256: one row's chunk, two tiles, beside decode rows and a dead
+    # one: a ROW of the step is a row of the call (contexts to 2.3 k: the
+    # check's gather reference holds every row's scores at once).
+    "olmo-hybrid-7b-12l.digest/W1": dict(
+        geo=dict(n_heads=30, n_kv_heads=30, d_head=128), table_len=1024,
+        n_blocks=1537, rows=tuple((1, 600 + 520 * r) for r in range(16))),
+    "olmo-hybrid-7b-12l.digest/W256": dict(
+        geo=dict(n_heads=30, n_kv_heads=30, d_head=128), table_len=1024,
+        n_blocks=1537,
+        rows=((256, 2048), (1, 700), (1, 2300), (1, 1500), (0, 0))
+        + tuple((1, 300 + 150 * r) for r in range(11))),
 }
 
 

@@ -109,6 +109,12 @@ _ROW_TILE = 128
 # first group's products (measured, PERF.md section 6, PR 29).
 _BLOCKS_PER_GROUP = 16
 _BLOCKS_PER_GROUP_PACKED = 8
+# What a group's four VMEM buffers (K and V, two each) may take: a pool of
+# more lanes a token halves its group until they fit. 30 KV heads of 128
+# lanes (Olmo-Hybrid's full layers, 7.5 KB a token and tensor) walk 8
+# blocks a group, 3.9 MB; at 16 the call's 17.7 MB pass Mosaic's 16 MB.
+# Every pool up to 2048 lanes in bfloat16 keeps its 16.
+_KV_SCRATCH_BYTES = 4 << 20
 
 
 def _dense_rows(q, k_pool, v_pool, k_scale, v_scale, layer, tables):
@@ -433,6 +439,9 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
     if r_pad != r:
         # Zero rows past W*G: computed like padding slots, sliced off.
         qh = jnp.pad(qh, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
+    while (blocks > 1 and 4 * blocks * bs * k_pool.shape[3]
+           * k_pool.dtype.itemsize > _KV_SCRATCH_BYTES):
+        blocks //= 2
     blocks = min(blocks, tables.shape[1])
     # A whole number of groups: entries past the table's width point at
     # the null block (and lie past every horizon).

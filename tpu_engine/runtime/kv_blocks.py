@@ -1292,6 +1292,71 @@ class StateSlabPool:
             }
 
 
+class StateRowPool:
+    """The recurrent layers' rows of the ``kv_and_state`` family
+    (models.olmo_hybrid), where a stream ALSO holds a block chain: a row
+    is, a recurrent layer, one float32 array of each of `shapes` (the
+    state and the conv tail), kept as arrays of their own shape
+    ``(n_layers, n_slots + 1, *shape)`` so that the step reads and writes
+    them where they lie. `slab` is the tuple of them, donated through the
+    tick like the block pool's pair.
+
+    Scheduler slot ``s`` owns row ``s + 1`` for as long as it serves a
+    request, and row 0 is the null row a free slot's writes land in: a row
+    is held only by a slot, so there is no free list, nothing to run
+    short of and nothing to lock (the tick thread alone takes, gives and
+    dispatches). `rows` is the vector the step indexes by: a slot's row,
+    0 while it is free. A row is not cleared when it is given back: the
+    step starts a request's state from zero at position 0. No chain is
+    exported (the family declares no migration)."""
+
+    def __init__(self, n_layers: int, shapes, n_slots: int, device=None):
+        self.n_layers = int(n_layers)
+        self.shapes = tuple(tuple(int(n) for n in shape) for shape in shapes)
+        self.num_rows = int(n_slots) + 1
+        self._device = device
+        self.rows = np.zeros((int(n_slots),), np.int32)
+        self.rows_peak = 0
+        self.slab = self._init_device()
+
+    def _init_device(self):
+        slab = tuple(jnp.zeros((self.n_layers, self.num_rows) + shape,
+                               jnp.float32) for shape in self.shapes)
+        if self._device is not None:
+            slab = jax.device_put(slab, self._device)
+        return slab
+
+    @property
+    def rows_held(self) -> int:
+        return int(np.count_nonzero(self.rows))
+
+    def take(self, slot: int) -> None:
+        self.rows[slot] = slot + 1
+        self.rows_peak = max(self.rows_peak, self.rows_held)
+
+    def give(self, slot: int) -> None:
+        self.rows[slot] = 0
+
+    def reset(self) -> None:
+        """After a device failure the donated arrays may be invalid:
+        rebuilt, and no slot holds a row."""
+        self.slab = self._init_device()
+        self.rows[:] = 0
+
+    def bytes_per_row(self) -> int:
+        return 4 * self.n_layers * sum(int(np.prod(shape))
+                                       for shape in self.shapes)
+
+    def stats(self) -> dict:
+        held = self.rows_held
+        return {"rows_total": self.num_rows - 1,  # null row excluded
+                "rows_free": self.num_rows - 1 - held,
+                "rows_held": held,
+                "rows_peak": self.rows_peak,
+                "n_layers": self.n_layers,
+                "bytes_per_row": self.bytes_per_row()}
+
+
 # -- device-side block movement (jitted by the scheduler per bucket) ----------
 
 def gather_blocks(pool_k, pool_v, ids, *, kv_heads: int):

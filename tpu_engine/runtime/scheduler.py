@@ -89,6 +89,7 @@ from tpu_engine.runtime.generator import (
 from tpu_engine.runtime.kv_blocks import (
     BlockPool,
     PoolExhausted,
+    StateRowPool,
     StateSlabPool,
     gather_blocks,
     gather_blocks_quant,
@@ -409,6 +410,14 @@ class ContinuousGenerator:
         # behind the window back inside the tick whose position passes
         # them (`_slide_window_blocks`).
         self._windowed = fam == "kv_windowed"
+        # "kv_and_state" (models.olmo_hybrid): a kv_paged chain over a
+        # pool that holds the full-attention layers alone AND one row of
+        # a state pool (`_spool`: the recurrent layers' state and conv
+        # tail), one model, one row, two pools. A row takes both at
+        # admission (parked when blocks are short; a state row is its
+        # slot's own and cannot be) and gives both back together; the
+        # step reads and writes both in place.
+        self._hybrid = fam == "kv_and_state"
         # A family whose step of the mixed tick is its own (and runs over
         # the tick's tokens) declares it, with the experts the lane's
         # weights hold (registry.ModelSpec).
@@ -590,7 +599,7 @@ class ContinuousGenerator:
                              "(set kv_block_size > 0)")
         self._caches = None
         self._pool: Optional[BlockPool] = None
-        self._spool: Optional[StateSlabPool] = None
+        self._spool: Union[StateSlabPool, StateRowPool, None] = None
         if self._slab:
             # Fixed-size recurrent state rows: the whole per-stream
             # autoregressive state is one (n_layers, state_dim) f32 row
@@ -629,9 +638,11 @@ class ContinuousGenerator:
             if int(kv_host_blocks) > 0 and not prefix_sharing:
                 raise ValueError("kv_host_blocks requires prefix_sharing "
                                  "(the host tier holds radix entries)")
-            # A windowed family's `_pool` holds its full layers alone.
+            # A family with layers of several kinds: `_pool` holds its
+            # full-attention layers alone.
             self._pool = BlockPool(self.cfg.kv_block_kinds[0]
-                                   if self._windowed else self.cfg,
+                                   if self._windowed or self._hybrid
+                                   else self.cfg,
                                    nb, bs, self._dtype, device,
                                    host_blocks=int(kv_host_blocks),
                                    quantize=str(kv_quantize),
@@ -649,6 +660,13 @@ class ContinuousGenerator:
             self._pending: "collections.deque" = collections.deque()
             self._gather_exe = {}   # {n_blocks: compiled prefix gather}
             self._scatter_exe = {}  # {n_blocks: compiled block scatter}
+            if self._hybrid:
+                # The recurrent layers' rows, `n_slots + 1` as the slab
+                # family's are: slot s owns row s + 1, row 0 is the null
+                # row a free slot points at.
+                self._spool = StateRowPool(
+                    self.cfg.n_linear_layers, self.cfg.state_row_shapes,
+                    self.n_slots, device=device)
         elif not (self._slab or self._stateless):
             self._caches = init_caches(self.cfg, self.n_slots, self.max_seq,
                                        self._dtype)
@@ -1130,12 +1148,19 @@ class ContinuousGenerator:
                         "no chain, and the window read takes no int8 "
                         "scales and no verify window",
                         "window read over blocks of two kinds"),
+        "kv_and_state": ("a row's recurrent state is one fixed-size row, "
+                         "not block-addressable: it serves no prefix hit "
+                         "(snapshots at block boundaries are not kept), "
+                         "goes to no host tier, takes no int8 scales, "
+                         "cannot be rolled back past a rejected draft and "
+                         "rides no chain",
+                         "state row beside the block chain"),
     }
 
     def _fence_tick_only_family(self, model, fam, *, mixed_step,
                                 kv_host_blocks, kv_quantize, spec_k,
                                 prefix_sharing) -> None:
-        """Start-up fences of the kv_latent and kv_windowed families
+        """Start-up fences of the kv_latent, kv_windowed and kv_and_state families
         (registry FAMILY_CAPABILITIES): what the family's pool cannot do
         yet is refused by name, never served wrong. (`tp > 1` is refused
         above through the model's unshardable TP rule.)"""
@@ -2580,6 +2605,17 @@ class ContinuousGenerator:
                     window_blocks_held=(window["blocks_total"]
                                         - window["blocks_free"]),
                     window_blocks_freed=self._wfreed)
+            if self._hybrid:
+                # The state pool beside the block pool; and, in the block
+                # pool's own sample, the bytes of both kinds the rows
+                # hold now (one reading of the two, for their ratio).
+                out["state_pool"] = state = self._spool.stats()
+                held = (out["kv_pool"]["blocks_total"]
+                        - out["kv_pool"]["blocks_free"])
+                out["kv_pool"].update(
+                    kv_bytes_held=held * self._pool.bytes_per_block(),
+                    state_bytes_held=(state["rows_held"]
+                                      * state["bytes_per_row"]))
         if self._slab:
             # Gated additive block (the state_slab family's kv_pool
             # analog): a kv_paged lane's /stats and /health bytes never
@@ -3587,6 +3623,9 @@ class ContinuousGenerator:
             except PoolExhausted:
                 pool.release_many(fresh)
                 raise
+            if self._hybrid:
+                # The slot's own state row, taken with its blocks.
+                self._spool.take(row)
             pool.prefix_hit_tokens += p0
             pool.prefilled_tokens += Leff - p0
         self._tables[row, :] = 0
@@ -3849,6 +3888,8 @@ class ContinuousGenerator:
                     self._wtables[row, first:end].tolist())
             self._wtables[row, :] = 0
             self._wspan[row] = 0
+        if self._hybrid:
+            self._spool.give(row)
         if not self._paged or not self._row_blocks[row]:
             return
         with self._pool.lock:
@@ -4242,6 +4283,8 @@ class ContinuousGenerator:
                     self._wpool.reset()
                 self._wtables[:, :] = 0
                 self._wspan[:, :] = 0
+            if self._hybrid:
+                self._spool.reset()  # lint: lockfree-ok tick thread's alone
             if violations:
                 self._stats["recover_invariant_violations"] = (
                     self._stats.get("recover_invariant_violations", 0)
@@ -4492,6 +4535,20 @@ class ContinuousGenerator:
         self._clock.note(ctx_tokens_full=int((pos0[fed] + qlen[fed]).sum()),
                          ctx_tokens_window=read, window_blocks_freed=freed)
 
+    def _note_state_work(self, pos0, qlen) -> None:
+        """What a tick of a lane with both kinds of state asks of each, on
+        its span: the tokens that go through the chunked form of the
+        recurrence (and the rows they belong to) and the rows that take
+        one step of it, the tokens the full-attention layers read (as a
+        windowed lane's `ctx_tokens_full`), and the state rows held."""
+        fed = qlen > 0
+        self._clock.note(
+            gdn_chunk_tokens=int(qlen[qlen > 1].sum()),
+            gdn_chunk_rows=int((qlen > 1).sum()),
+            gdn_step_rows=int((qlen == 1).sum()),
+            ctx_tokens_full=int((pos0[fed] + qlen[fed]).sum()),
+            state_rows_held=self._spool.rows_held)
+
     def _count_moe(self, rows, fed: int) -> None:
         """`rows` (L_moe, E): what each expert of each expert layer took
         this tick, back with the tick's other results; `fed`: the tokens
@@ -4627,6 +4684,16 @@ class ContinuousGenerator:
                 # One of each a kind of block, (full, window).
                 pool_args = ((pool.caches, self._wpool.caches),)
                 tables = (tables, jnp.asarray(self._wtables))
+            if self._hybrid:
+                # The block pool and the state pool, the rows' table and
+                # their state rows; both pools are donated. The state
+                # arrays are this thread's alone (no admission write, no
+                # export: the family declares no chain), so the block
+                # pool's lock is the only one the dispatch is ordered by.
+                self._note_state_work(pos0, qlen)
+                pool_args = ((pool.caches,
+                              self._spool.slab),)  # lint: lockfree-ok tick thread's alone
+                tables = (tables, jnp.asarray(self._spool.rows))
             common = (self._step_params, *pool_args, tables,
                       jnp.asarray(tokens), jnp.asarray(pos0),
                       jnp.asarray(qlen), jnp.asarray(sample_slot),
@@ -4645,6 +4712,9 @@ class ContinuousGenerator:
                 out = self._mixed_step_exe(width, False)(*common)
             if self._windowed:
                 pool.caches, self._wpool.caches = out[0]
+            elif self._hybrid:
+                (pool.caches,
+                 self._spool.slab) = out[0]  # lint: lockfree-ok tick thread's alone
             else:
                 pool.caches = out[0]
             if self._quant:
