@@ -80,12 +80,14 @@ def pytest_collection_modifyitems(session, config, items):
     # file's, to the count, which PR 38's two metrics (they list every
     # cell) undo; and a PR that appends may edit none of these files.
     # Each reads BENCHMARK.json through its own `json` name: give it the
-    # lists as they stood when its rehearsal file was last written. A
-    # `benchmark` PR adds the two metrics to the rehearsal files, finds
-    # the cell by name and deletes this with the hook above (PERF.md
-    # section 7).
+    # lists as they stood when its rehearsal file was last written. PR
+    # 40's `sched.overlap_tick_share` lists all six cells, PR 39's too,
+    # so ..._olmo_hybrid.py joins them. A `benchmark` PR adds the three
+    # metrics to the rehearsal files, finds the cell by name and deletes
+    # this with the hook above (PERF.md section 7).
     for name in ("test_benchmark_reference_moonlight",
-                 "test_benchmark_reference_laguna"):
+                 "test_benchmark_reference_laguna",
+                 "test_benchmark_reference_olmo_hybrid"):
         module = sys.modules.get(name)
         if module is not None and not hasattr(module.json, "_cell"):
             module.json = _AsTheCellWasWritten(module.json, module.CELL)
@@ -97,7 +99,8 @@ class _AsTheCellWasWritten:
     per-layer metrics appended since its rehearsal file was written."""
 
     APPENDED_SINCE = ("step.sampler_sort_busy",
-                      "step.sampler_sort_tick_share")   # PR 38
+                      "step.sampler_sort_tick_share",   # PR 38
+                      "sched.overlap_tick_share")       # PR 40
 
     def __init__(self, json_module, cell):
         self._json, self._cell = json_module, cell

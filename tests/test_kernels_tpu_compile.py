@@ -120,6 +120,27 @@ def _mixed_tick(cfg, attn_fn):
     return tick
 
 
+def _behind_a_step(tick, rows):
+    """(`tick` as a lane's compiled step calls it since PR 40, the shapes
+    of what it takes besides): a row's first token and its end come from
+    the step before's own outputs, still on the device
+    (`scheduler.take_from_prev`), and the row's end is an output again.
+    (params, caches, tables, tokens, pos0, qlen, done, prev_nxt,
+    prev_done, from_prev) -> (logits, caches, done)."""
+    from tpu_engine.runtime.scheduler import take_from_prev
+
+    def step(params, caches, tables, tokens, pos0, qlen, done, prev_nxt,
+             prev_done, from_prev):
+        tokens, done = take_from_prev(tokens, done, prev_nxt, prev_done,
+                                      from_prev)
+        logits, caches = tick(params, caches, tables, tokens, pos0, qlen)[:2]
+        return logits, caches, done
+
+    flag = jax.ShapeDtypeStruct(rows.shape, jnp.bool_,
+                                sharding=rows.sharding)
+    return step, (flag, rows, flag, flag)
+
+
 def test_tp2_paged_tick_compiles_for_v5e(v5e_devices):
     """One --tp 2 mixed tick (transformer_step_rows_ragged over a
     head-sharded pool, params placed by the registry's TP rule) on two
@@ -242,7 +263,9 @@ def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
     pool = args[1].k
     tick = _mixed_tick(
         cfg, functools.partial(ragged_paged_attention, interpret=False))
-    compiled = jax.jit(tick, donate_argnums=(1,)).lower(*args).compile()
+    step, behind = _behind_a_step(tick, args[-1])
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        *args, *behind).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     whole = math.prod(pool.shape)
@@ -385,9 +408,10 @@ def test_latent_mixed_step_copies_neither_the_pool_nor_a_bank(v5e_devices,
     def host(*shape):
         return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
 
-    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+    step, behind = _behind_a_step(tick, host(rows))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, pool, host(rows, -(-cfg.max_seq // bs)), host(rows, width),
-        host(rows), host(rows)).compile()
+        host(rows), host(rows), *behind).compile()
     hlo = compiled.as_text()
     assert "mla_latent_read" in hlo and "ragged-dot" in hlo
     banks = jax.tree.leaves(params["moe"]["mlp"]["experts"])
@@ -458,9 +482,10 @@ def test_windowed_mixed_step_copies_no_pool_and_no_bank(v5e_devices, width):
         return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
 
     table = host(rows, -(-cfg.max_seq // bs))
-    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+    step, behind = _behind_a_step(tick, host(rows))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, pools, (table, table), host(rows, width), host(rows),
-        host(rows)).compile()
+        host(rows), *behind).compile()
     hlo = compiled.as_text()
     assert "swa_window_read" in hlo and "ragged-dot" in hlo
     assert "_paged_call" in hlo or "paged" in hlo
@@ -538,9 +563,10 @@ def test_hybrid_mixed_step_copies_neither_the_pool_nor_the_states(v5e_devices,
     def host(*shape):
         return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
 
-    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+    step, behind = _behind_a_step(tick, host(rows))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, pools, (host(rows, -(-cfg.max_seq // bs)), host(rows)),
-        host(rows, width), host(rows), host(rows)).compile()
+        host(rows, width), host(rows), host(rows), *behind).compile()
     hlo = compiled.as_text()
     assert "_paged_call" in hlo and "gdn_step" in hlo
     assert ("gdn_chunk" in hlo) == (width > 1)

@@ -358,6 +358,28 @@ def test_a_row_cut_by_its_deadline_leaks_no_block_of_either_kind(spec,
     assert pool["blocks_free"] == pool["blocks_total"]
 
 
+def test_the_tick_runs_one_ahead_with_both_kinds_of_block(spec, params):
+    """`_slide_window_blocks` works from positions, which the host knows a
+    tick early: window blocks are freed and taken when the next tick is
+    FORMED, while the tick before still runs. An EOS met mid-stream ends
+    the row one tick late on the device and never in the tokens; both
+    pools end whole (tests/tick_pipeline.py)."""
+    from tick_pipeline import check_late_ends
+
+    def whole(gen):
+        pool = gen.stats()["kv_pool"]
+        return (pool["window_blocks_held"] == pool["full_blocks_held"] == 0
+                and pool["blocks_free"] == pool["blocks_total"])
+
+    rng = np.random.default_rng(11)
+    prompts = [[int(t) for t in rng.integers(1, 256, size=n)]
+               for n in (70, 9, 33)]
+    counters = check_late_ends(
+        lambda: ContinuousGenerator(spec, params=params, **LANE),
+        prompts, whole, max_new=24)
+    assert counters["overlapped_ticks"] > counters["ticks"] // 2
+
+
 @pytest.mark.parametrize("kwargs, error, message", [
     ({"mixed_step": False}, ValueError,
      "served by the mixed tick over the block pool only"),

@@ -354,6 +354,31 @@ def test_what_the_latent_pool_cannot_do_is_refused_at_start_up(
         ContinuousGenerator(spec, params=params, **{**base, **kwargs})
 
 
+def test_the_tick_runs_one_ahead_and_late_ends_ride_as_done_rows(spec,
+                                                                 params):
+    """The pipeline's seam is in the step's shared wrapper, so this
+    family's step takes it unedited: an EOS met mid-stream ends the row one
+    tick late on the device and never in the tokens (tests/tick_pipeline.py:
+    the same lane kept in order is the oracle), the experts' counts come
+    back with the tick they belong to, the latent pool ends whole."""
+    from tick_pipeline import check_late_ends
+
+    def whole(gen):
+        pool = gen.stats()["kv_pool"]
+        return pool["blocks_free"] == pool["blocks_total"]
+
+    rng = np.random.default_rng(7)
+    prompts = [[int(t) for t in rng.integers(1, 256, size=n)]
+               for n in (40, 5, 23)]
+    counters = check_late_ends(
+        lambda: ContinuousGenerator(spec, params=params, n_slots=4,
+                                    dtype="float32", kv_block_size=BS,
+                                    mixed_step=True, prefill_chunk=16,
+                                    prefix_sharing=False),
+        prompts, whole)
+    assert counters["overlapped_ticks"] > counters["ticks"] // 2
+
+
 def test_the_chain_wire_format_is_refused_by_name(spec, params):
     gen = ContinuousGenerator(spec, params=params, n_slots=2,
                               dtype="float32", kv_block_size=BS,

@@ -437,6 +437,20 @@ def test_a_row_gives_back_its_state_row_and_its_blocks(spec, params, how):
         gen.stop()
 
 
+def test_the_tick_runs_one_ahead_with_a_state_row_a_slot(spec, params):
+    """A row's recurrent state is stepped where it lies by the tick in
+    flight while the next tick is formed; a row that meets its EOS is
+    stepped once more as a done row (its state row is its slot's own, and
+    the next request starts it from zero at position 0), and both pools
+    come back once (tests/tick_pipeline.py)."""
+    from tick_pipeline import check_late_ends
+
+    counters = check_late_ends(
+        lambda: ContinuousGenerator(spec, params=params, **LANE),
+        [_prompt(21, 40), _prompt(22, 7), _prompt(23, 25)], _drained)
+    assert counters["overlapped_ticks"] > counters["ticks"] // 2
+
+
 @pytest.mark.parametrize("short", [{"n_slots": 1}, {"kv_blocks": 9}])
 def test_a_request_waits_when_either_pool_is_short(spec, params, short):
     """One slot and so one state row (a state row is its slot's own), or

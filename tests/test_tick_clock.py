@@ -7,7 +7,11 @@ Contracts under test, on a CPU lane:
   and every tick function (paged, speculative, state slab) records them
   through the one tick helper;
 - `gap_us` is absent after an idle lane and present between back-to-back
-  ticks;
+  ticks: the host's time between two ticks on a lane that reads each
+  tick's results first, 0 (or what a probe saw) behind a tick in flight;
+- a lane that runs one tick ahead marks `form` and `dispatch` of tick N+1
+  and then `wait` and `apply` of tick N in one iteration, and each tick's
+  span carries its own four phases;
 - `queue_wait` + `slot_wait` + `prefill` cover submit -> first token with
   no hole, under one trace id;
 - a row the token budget starves says so on its `prefill` span;
@@ -111,18 +115,34 @@ def test_phases_add_up_to_the_tick_and_keep_the_old_fields(lane):
     assert [s["attrs"]["width"] for s in ticks[:4]] == [16, 16, 16, 1]
 
 
-def test_gap_only_between_back_to_back_ticks(lane):
+@pytest.mark.parametrize("order", ["ahead", "in_order"])
+def test_gap_only_between_back_to_back_ticks(lane, order):
     _wait_idle(lane)
-    seq0 = lane._clock.seq
-    _submit(lane, "gap", [5, 9, 3], 5).result(60)
+    if order == "in_order":
+        lane._may_run_ahead = lambda: False    # the drained case
+    try:
+        seq0 = lane._clock.seq
+        _submit(lane, "gap-" + order, [5, 9, 3], 5).result(60)
+        _wait_idle(lane)
+    finally:
+        lane.__dict__.pop("_may_run_ahead", None)
     ticks = _ticks(lane, seq0)
     assert len(ticks) >= 4
     assert "gap_us" not in ticks[0]["attrs"]   # the lane was idle before it
     for prev, s in zip(ticks, ticks[1:]):
-        # wait's end of the tick before -> this tick's dispatch: at least
-        # that tick's apply and this tick's form.
-        assert s["attrs"]["gap_us"] >= (prev["attrs"]["apply_us"]
-                                        + s["attrs"]["form_us"]) * 0.99
+        if order == "in_order":
+            # wait's end of the tick before -> this tick's dispatch: at
+            # least that tick's apply and this tick's form.
+            assert s["attrs"]["overlapped"] == 0
+            assert s["attrs"]["gap_us"] >= (prev["attrs"]["apply_us"]
+                                            + s["attrs"]["form_us"]) * 0.99
+        else:
+            # Enqueued behind the tick before: the device had it queued
+            # when that tick ended, unless a probe saw it end earlier
+            # (the first is made when the results of the tick before
+            # that one have been applied): no longer ago than that.
+            assert s["attrs"]["overlapped"] == 1
+            assert 0 <= s["attrs"]["gap_us"] <= (s["ts"] - prev["ts"]) * 1e6
 
 
 def test_request_stages_cover_submit_to_first_token(lane):
@@ -272,13 +292,22 @@ def test_profiler_capture_holds_the_phases_nested_and_in_order(lane,
     events = sorted(lines[0], key=lambda e: (e[1], -e[2]))
     ticks = [e for e in events if e[0] == "tick"]
     assert len(ticks) >= 6
+    four = [f"tick.{p}" for p in TICK_PHASES]
+    held = []
     for name, start, end, stats in ticks:
         inside = [e for e in events
                   if e[0] != "tick" and start <= e[1] and e[2] <= end]
-        assert [e[0] for e in inside] == [f"tick.{p}" for p in TICK_PHASES]
+        held.append([e[0] for e in inside])
         for a, b in zip(inside, inside[1:]):
             assert a[2] <= b[1]                    # in order, no overlap
-        assert {"seq", "width", "rows", "ctx_tokens"} <= set(stats)
+        if "tick.dispatch" in held[-1]:
+            assert {"seq", "width", "rows", "ctx_tokens"} <= set(stats)
+    # One tick ahead: the first iteration only enqueues, every later one
+    # forms and enqueues the next tick and then lands the one before, the
+    # last finds nothing to step (the row's last token is in flight) and
+    # only lands.
+    assert held[0] == four[:2] and held[-1] == [four[0]] + four[2:]
+    assert held[1:-1] == [four] * (len(ticks) - 2)
     # Between two ticks the loop's work is one `loop.admit`.
     for a, b in zip(ticks, ticks[1:]):
         between = [e[0] for e in events if a[2] <= e[1] and e[2] <= b[1]]
@@ -333,6 +362,63 @@ def test_a_slow_tick_names_its_phase_once_in_ten_seconds(capsys):
     assert attrs["wait_us"] > 0.9 * dur_us
     tick(0.05)                         # within ten seconds: no second line
     assert capsys.readouterr().err == ""
+    clock.idle()
+
+
+def test_a_tick_ahead_keeps_each_tick_s_own_four_phases():
+    """The marks of a lane that runs ahead, by hand: tick 1 enqueued alone
+    (`leave`), tick 2 formed and enqueued before tick 1 is landed, then
+    tick 2 landed alone. Each `end()` gives the OLDEST tick not ended, with
+    its own form and dispatch from the iteration before."""
+    clock = TickClock(CompileCounter())
+    clock.begin()                                  # tick 1
+    time.sleep(0.004)
+    clock.note(sampler="greedy")
+    clock.dispatch(width=16, rows=1, ctx_tokens=16)
+    clock.leave()
+    clock.admit()
+    clock.begin()                                  # tick 2, behind tick 1
+    clock.probe(False)
+    time.sleep(0.002)
+    clock.note(sampler="plain")
+    clock.dispatch(width=1, rows=1, ctx_tokens=17)
+    clock.wait()                                   # ... for tick 1
+    time.sleep(0.003)
+    clock.apply()
+    clock.note(moe_assignments=7)                  # came back with tick 1
+    _, dur1, one = clock.end(True, "n")
+    assert (one["seq"], one["overlapped"], one["ctx_tokens"]) == (1, 0, 16)
+    assert one["sampler"] == "greedy" and one["moe_assignments"] == 7
+    assert one["form_us"] >= 4000 and one["wait_us"] >= 3000
+    assert "gap_us" not in one                     # after an idle lane
+    assert dur1 == pytest.approx(sum(one[k] for k in PHASE_KEYS), abs=1)
+    clock.wait()                                   # tick 2 landed alone
+    clock.apply()
+    _, dur2, two = clock.end(False, "n")
+    assert (two["seq"], two["overlapped"], two["ctx_tokens"]) == (2, 1, 17)
+    assert two["sampler"] == "plain" and "moe_assignments" not in two
+    assert 2000 <= two["form_us"] < 4000           # its own form, not 1's
+    assert two["gap_us"] == 0.0                    # enqueued behind tick 1
+    assert dur2 == pytest.approx(sum(two[k] for k in PHASE_KEYS), abs=1)
+    # A probe that sees the tick in flight finished dates the device's
+    # idle time from then; a tick formed with nothing to step is dropped.
+    clock.begin()                                  # tick 3 after a dead lane
+    clock.dispatch(width=1, rows=1, ctx_tokens=3)
+    clock.leave()
+    clock.begin()                                  # tick 4
+    clock.probe(True)
+    time.sleep(0.002)
+    clock.dispatch(width=1, rows=1, ctx_tokens=4)
+    clock.wait()
+    clock.apply()
+    three = clock.end(True, "n")[2]
+    assert "gap_us" not in three and three["seq"] == 3
+    clock.begin()                                  # nothing to step: dropped
+    clock.wait()
+    clock.apply()
+    four = clock.end(False, "n")[2]
+    assert four["seq"] == 4 and four["gap_us"] >= 2000
+    assert clock.seq == 4
     clock.idle()
 
 

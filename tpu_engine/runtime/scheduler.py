@@ -171,6 +171,41 @@ class _Request:
     oneshot: Optional[tuple] = None
 
 
+class _FlightTick:
+    """A mixed tick that is enqueued and whose results the host has not
+    read yet: what `_land_tick` needs to apply them, as the tick was
+    formed. `nxt`, `done` (and a routed model's `moe_rows`) are the
+    step's outputs, still on the device; `reqs` the rows' requests when
+    it was formed (a row freed, or its slot given on, while the tick ran
+    takes nothing from it); `active` the rows whose sample is real,
+    `completing` those of them whose chunk ends a prompt, `decode` those
+    that advance a stream, `pos` the rows' positions with this tick's
+    advance and no later one's; `overlapped`: enqueued while the tick
+    before's results were unread."""
+
+    __slots__ = ("nxt", "done", "moe_rows", "reqs", "active", "completing",
+                 "decode", "pos", "starved", "sampler", "fed",
+                 "prefill_tokens", "n_decode", "width", "overlapped")
+
+    def __init__(self, **fields):
+        for name, value in fields.items():
+            setattr(self, name, value)
+
+    def sampled(self, r: int, req: "_Request") -> bool:
+        """This tick samples a token for `req` in row `r`."""
+        return bool(self.active[r]) and self.reqs[r] is req
+
+
+def take_from_prev(tokens, done, prev_nxt, prev_done, from_prev):
+    """Inside a compiled mixed step: the rows of `from_prev` take their
+    input token (column 0 of `tokens`) from `prev_nxt`, the token the
+    step before sampled for them and the host has not read yet, and ride
+    as done rows if `prev_done` says that token ended them."""
+    tokens = tokens.at[:, 0].set(
+        jnp.where(from_prev, prev_nxt, tokens[:, 0]))
+    return tokens, done | (from_prev & prev_done)
+
+
 class _StaleAdmission(RuntimeError):
     """A prefilled item's pool pins/gather predate a pool rebuild
     (device recovery): the single request fails, the scheduler keeps
@@ -850,6 +885,10 @@ class ContinuousGenerator:
                 # for (`generator.sampler_body`); they sum to `ticks`.
                 "sample_greedy_ticks": 0, "sample_plain_ticks": 0,
                 "sample_filtered_ticks": 0,
+                # Ticks enqueued while the tick before's results were
+                # not yet read, and row-ticks stepped past an end the
+                # host learned one tick late (`_land_tick`).
+                "overlapped_ticks": 0, "lagged_rows": 0,
                 "token_budget": self._mixed_budget,
                 "chunk_cap": self._chunk_cap,
             }
@@ -895,6 +934,8 @@ class ContinuousGenerator:
         # the process-wide compile counter it reads `compile_us` from.
         self._compiles = compile_counter()
         self._clock = TickClock(self._compiles)
+        # The mixed tick's pipeline, one tick deep (`_tick_mixed`).
+        self._reset_flight()
         # Per-row prefill accounting for the `prefill` span: ticks that
         # fed the row, and ticks (with their summed duration) in which
         # the row was prefilling and the token budget gave it nothing.
@@ -1425,8 +1466,12 @@ class ContinuousGenerator:
         logical position (the fold_in(seed, position) rule every path
         shares), `active` marks rows whose sample is REAL this tick
         (mid-prompt rows ride along without emitting or touching
-        counts). Exactly two widths compile per controls variant (1 and
-        the chunk cap)."""
+        counts). `prev_nxt` and `prev_done` are the step before's own
+        `nxt` and `done`, still on the device, and `from_prev` the rows
+        that take them: such a row's input token is the one that step
+        sampled, which the host has not read yet, and it rides as a done
+        row if that token ended it. They are data: exactly two widths
+        compile per controls variant (1 and the chunk cap)."""
         key = ("mixed", width, controls)
         exe = self._decode_exe.get(key)
         if exe is not None:
@@ -1445,8 +1490,11 @@ class ContinuousGenerator:
 
                 def step_core(params, caches, scales, tables, tokens,
                               pos0, qlen, sample_slot, fold_pos, active,
-                              done, seeds, temps, topps, topks, minps,
-                              eos_vec, counts, pens, stops):
+                              done, prev_nxt, prev_done, from_prev, seeds,
+                              temps, topps, topks, minps, eos_vec, counts,
+                              pens, stops):
+                    tokens, done = take_from_prev(
+                        tokens, done, prev_nxt, prev_done, from_prev)
                     # sample_slot gathers the hidden state BEFORE the LM
                     # head: one (B, vocab) projection per tick, not W.
                     if own_step is not None:
@@ -1501,27 +1549,31 @@ class ContinuousGenerator:
                 if quant:
                     def mixed_step(params, caches, scales, tables, tokens,
                                    pos0, qlen, sample_slot, fold_pos,
-                                   active, done, seeds, temps, topps,
-                                   topks, minps, eos_vec, counts=None,
-                                   pens=None, stops=None):
+                                   active, done, prev_nxt, prev_done,
+                                   from_prev, seeds, temps, topps, topks,
+                                   minps, eos_vec, counts=None, pens=None,
+                                   stops=None):
                         return step_core(params, caches, scales, tables,
                                          tokens, pos0, qlen, sample_slot,
-                                         fold_pos, active, done, seeds,
+                                         fold_pos, active, done, prev_nxt,
+                                         prev_done, from_prev, seeds,
                                          temps, topps, topks, minps,
                                          eos_vec, counts, pens, stops)
-                    donate = (1, 2, 17) if controls else (1, 2)
+                    donate = (1, 2, 20) if controls else (1, 2)
                 else:
                     def mixed_step(params, caches, tables, tokens, pos0,
                                    qlen, sample_slot, fold_pos, active,
-                                   done, seeds, temps, topps, topks,
-                                   minps, eos_vec, counts=None, pens=None,
+                                   done, prev_nxt, prev_done, from_prev,
+                                   seeds, temps, topps, topks, minps,
+                                   eos_vec, counts=None, pens=None,
                                    stops=None):
                         return step_core(params, caches, None, tables,
                                          tokens, pos0, qlen, sample_slot,
-                                         fold_pos, active, done, seeds,
+                                         fold_pos, active, done, prev_nxt,
+                                         prev_done, from_prev, seeds,
                                          temps, topps, topks, minps,
                                          eos_vec, counts, pens, stops)
-                    donate = (1, 16) if controls else (1,)
+                    donate = (1, 19) if controls else (1,)
                 self._decode_exe[key] = jax.jit(mixed_step,
                                                 donate_argnums=donate)
             return self._decode_exe[key]
@@ -2295,7 +2347,12 @@ class ContinuousGenerator:
         the top of every iteration (the tick boundary). Commands whose
         row has not finished prefill yet (wait_prefill, the
         disaggregated handoff shape) re-park until the next boundary,
-        bounded by their own deadline."""
+        bounded by their own deadline. A command ships or releases a
+        row as the last tick LEFT it, so the tick in flight is landed
+        before the first is served, and that is decided here, with the
+        commands in hand: `export_row` puts from another thread, and a
+        look at the queue before this call would miss the command that
+        arrives in between."""
         pending = self._export_waiting
         self._export_waiting = []
         while True:
@@ -2303,6 +2360,8 @@ class ContinuousGenerator:
                 pending.append(self._migrate_q.get_nowait())
             except queue.Empty:
                 break
+        if pending:
+            self._drain_tick()
         for tag, fut, opts in pending:
             if fut.done():
                 continue
@@ -2347,8 +2406,9 @@ class ContinuousGenerator:
                 "reason": "no live row with this tag; park pre-cancelled"}
 
     def _do_export(self, tag: str, opts: Optional[dict] = None) -> dict:
-        """Decode-thread half of export_row (the row is quiescent by
-        construction here). On success the row is GONE from this lane:
+        """Decode-thread half of export_row (the row is quiescent here:
+        `_serve_exports` lands the tick in flight before it calls this).
+        On success the row is GONE from this lane:
         stream flushed + ended with StreamMigratedAway, blocks released
         (radix-shared prefix blocks survive in the tree), slot freed.
         Returns None when a ``wait_until``-carrying command must re-park
@@ -4014,7 +4074,18 @@ class ContinuousGenerator:
             req.stream.put(vis[req.streamed:])
             req.streamed = len(vis)
 
-    def _maybe_complete(self, row: int) -> None:
+    def _row_ends(self, req: _Request, emitted_n: int, pos: int) -> bool:
+        """Whether a row with `emitted_n` tokens out and its next write
+        at column `pos` has had its last token, by what the host knows
+        without reading one: its budget, or the cache's end (the
+        backstop; `submit` clamps a budget to the cache, an imported
+        snapshot's is as the source lane set it)."""
+        return emitted_n >= req.max_new or pos >= self.max_seq - 1
+
+    def _maybe_complete(self, row: int, pos: Optional[int] = None) -> None:
+        """`pos`: the row's position as of the tick whose results are
+        being applied, where `_pos` already holds the advance of a tick
+        enqueued behind it (`_land_tick`)."""
         req = self._row_req[row]
         if req is None:
             return
@@ -4027,9 +4098,10 @@ class ContinuousGenerator:
             return
         emitted = self._row_emitted[row]
         hit_eos = req.eos_id >= 0 and req.eos_id in emitted
-        budget = len(emitted) >= req.max_new
-        out_of_cache = int(self._pos[row]) >= self.max_seq - 1
-        if hit_eos or budget or out_of_cache or self._done[row]:
+        if pos is None:
+            pos = int(self._pos[row])
+        if (hit_eos or self._row_ends(req, len(emitted), pos)
+                or self._done[row]):
             toks = self._visible_tokens(row, req)
             self._push_stream(row, req)
             if req.sink is not None and req.t_admit:
@@ -4324,6 +4396,9 @@ class ContinuousGenerator:
                 caches = jax.device_put(caches, self._device)
             self._caches = caches
         self._counts = None  # donated alongside — realloc lazily if needed
+        # A tick in flight (the failed one, or one enqueued behind it on
+        # buffers the failure took) is dropped with its rows, uncounted.
+        self._reset_flight()
 
     def _loop(self) -> None:
         try:
@@ -4337,6 +4412,7 @@ class ContinuousGenerator:
             # queued — a dropped future/sentinel would hang its blocking
             # caller or SSE reader.
             self._running = False
+            self._inflight = None  # its rows fail with the others below
             exc = RuntimeError("scheduler stopped")
             for r, req in enumerate(self._row_req):
                 if req is not None:
@@ -4399,6 +4475,12 @@ class ContinuousGenerator:
                 with pool.lock:
                     fresh = pool.alloc(need - have)
             except PoolExhausted:
+                if self._inflight is not None:
+                    # Its rows' ends may free blocks, and a row that
+                    # ends early ends with every token it was stepped
+                    # for: land the tick in flight, then look again.
+                    self._drain_tick()
+                    return self._ensure_capacity_paged()
                 self._stats["pool_starved"] = (
                     self._stats.get("pool_starved", 0) + 1)
                 self._done[r] = True
@@ -4440,7 +4522,8 @@ class ContinuousGenerator:
             self._stats["spec"]["tail_blocks_released"] += freed
 
     def _complete_prefill_row(self, r: int, req: "_Request",
-                              first_tok: int, done: bool) -> None:
+                              first_tok: int, done: bool,
+                              pos: Optional[int] = None) -> None:
         """Prompt consumed: the row becomes a decode row. Index the
         now-filled prompt blocks in the radix tree (mixed mode inserts
         at COMPLETION — a cancelled mid-prefill row must never leave
@@ -4464,8 +4547,56 @@ class ContinuousGenerator:
         self._row_emitted[r] = [first_tok]
         self._first_token_metrics(req, r)
         self._push_stream(r, req)
-        self._maybe_complete(r)
+        self._maybe_complete(r, pos)
         self._maybe_hold(r, req)
+
+    def _reset_flight(self) -> None:
+        """No tick is in flight, and the two per-row inputs a step takes
+        from the step before it (`_mixed_step_exe`) are zeros no row
+        reads, placed as the step's own outputs are: another placement
+        would be another signature of the same executable."""
+        self._inflight: Optional[_FlightTick] = None
+        prev = (np.zeros((self.n_slots,), np.int32),
+                np.zeros((self.n_slots,), bool))
+        if self._tp_mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            prev = jax.device_put(prev, NamedSharding(self._tp_mesh,
+                                                      PartitionSpec()))
+        elif self._device is not None:
+            prev = jax.device_put(prev, self._device)
+        else:
+            prev = (jnp.asarray(prev[0]), jnp.asarray(prev[1]))
+        self._prev_nxt, self._prev_done = prev
+
+    def _may_run_ahead(self) -> bool:
+        """Whether the next mixed tick may be enqueued before the last
+        one's results are read. Not while the lane must be quiescent
+        between ticks, by what the loop can see: an export or handoff
+        command re-parked for a row still in its prompt (it is served
+        at every boundary, and `_serve_exports` would land each tick at
+        once), a row is parked, or a handoff row's prompt is not
+        consumed yet (`_maybe_hold` parks it when that tick's results
+        are applied, and a parked row must not have been stepped on). A
+        command that arrives while a tick is in flight is
+        `_serve_exports`' to see."""
+        if self._export_waiting:
+            return False
+        return not any(
+            req is not None and (self._held[r] or (req.handoff
+                                                   and self._prefilling[r]))
+            for r, req in enumerate(self._row_req))
+
+    def _drain_tick(self) -> None:
+        """Outside the tick: land the tick in flight, if there is one. A
+        device failure surfaces at its wait, as inside the tick."""
+        if self._inflight is None:
+            return
+        try:
+            self._land_tick()
+        except Exception as exc:
+            self._clock.idle()  # the tick raised between marks
+            self._recover(exc)
 
     def _reset_prefill_accounting(self, row: int) -> None:
         self._row_chunks[row] = 0
@@ -4569,16 +4700,19 @@ class ContinuousGenerator:
             self._clock.note(moe_assignments_held=held)
 
     def _tick_done(self, prefill_tokens: int, decode_rows: int, width: int,
-                   spec: Optional[dict] = None) -> None:
+                   spec: Optional[dict] = None,
+                   starved: Optional[List[int]] = None) -> None:
         """End of a mixed or speculative tick: close the clock and record
         the tick's span(s). `mixed_step` keeps the fields its readers
         know (duration_us, width, prefill_tokens, decode_rows) and
         carries the clock's phases; a speculative tick also records
-        `spec_verify` (and `mixed_step` only on a mixed lane)."""
+        `spec_verify` (and `mixed_step` only on a mixed lane). `starved`:
+        the rows the tick's budget starved, where the tick that ends is
+        not the last one formed."""
         live = any(req is not None and not self._held[r]
                    for r, req in enumerate(self._row_req))
         start_ts, dur_us, phases = self._clock.end(live, self.trace_node)
-        for r in self._tick_starved:
+        for r in (self._tick_starved if starved is None else starved):
             self._row_starved_ticks[r] += 1
             self._row_starved_us[r] += dur_us
         if self.tracer is None:
@@ -4599,19 +4733,46 @@ class ContinuousGenerator:
 
     def _tick_mixed(self) -> None:
         """One mixed tick: form the ragged batch (decode rows x 1 token +
-        admitting rows x a budgeted prefill chunk), issue exactly ONE
-        compiled dispatch, and apply the results host-side. Budget rule:
-        decode rows are always included (1 token each); the remaining
-        budget splits over prefilling rows in row order — the first
-        prefilling row always gets at least one token, so admission can
-        never deadlock behind a saturated decode batch."""
+        admitting rows x a budgeted prefill chunk) and issue exactly ONE
+        compiled dispatch. Budget rule: decode rows are always included
+        (1 token each); the remaining budget splits over prefilling rows
+        in row order — the first prefilling row always gets at least one
+        token, so admission can never deadlock behind a saturated decode
+        batch.
+
+        A pipeline one tick deep: the tick is formed and enqueued BEFORE
+        the results of the tick in flight are read and applied
+        (`_land_tick`), so the host's work runs beside the device's.
+        What a tick's form needs of the tick before is advanced when
+        that tick is enqueued (`_pos`, `_row_w0`, `_prefilling`: they
+        depend on positions alone); its tokens stay on the device, and
+        a row whose sample is in flight takes column 0 from there
+        (`from_prev`). A row whose LAST token is in flight (by its
+        budget or the cache's end, both known here) is left out. An end
+        the host cannot know yet (EOS, a stop token) leaves the row one
+        tick too many in the batch: the step lets it ride as a done row
+        (token discarded, counts untouched, its write confined to the
+        column `pos`, whose block `_ensure_capacity_paged` holds for
+        it), `_land_tick` releases it and counts `lagged_rows`; its
+        emitted tokens are the synchronous order's, token for token.
+        Where the lane must be quiescent between ticks
+        (`_may_run_ahead`) the tick in flight is landed first and this
+        one before the call returns: the synchronous order is the
+        drained case of the same code."""
+        ahead_ok = self._may_run_ahead()
+        if not ahead_ok and self._inflight is not None:
+            self._land_tick()
+        prev = self._inflight
         pool = self._pool
         B = self.n_slots
         self._clock.begin()
+        if prev is not None:
+            self._clock.probe(prev.nxt.is_ready())
         eos_vec = np.full((B,), -1, np.int32)
         controls = False
         n_decode = 0
         prefill_rows: List[int] = []
+        ending = [False] * B
         for r, req in enumerate(self._row_req):
             if req is None:
                 continue
@@ -4623,8 +4784,22 @@ class ContinuousGenerator:
                 continue  # parked handoff rows: no budget, no decode slot
             if self._prefilling[r]:
                 prefill_rows.append(r)
+            elif (prev is not None and prev.sampled(r, req)
+                  and self._row_ends(req, len(self._row_emitted[r]) + 1,
+                                     int(self._pos[r]))):
+                # The tick in flight brings this row's last token, by
+                # the rule `_maybe_complete` will end it with there.
+                ending[r] = True
             else:
                 n_decode += 1
+        if not n_decode and not prefill_rows:
+            # Nothing to step: every row's last token is in flight, or
+            # the last row left with the tick landed above.
+            if prev is not None:
+                self._land_tick()
+            else:
+                self._clock.idle()
+            return
         budget_left = max(1, self._effective_mixed_budget() - n_decode)
         chunk = np.zeros((B,), np.int32)
         for r in prefill_rows:
@@ -4640,10 +4815,11 @@ class ContinuousGenerator:
         sample_slot = np.zeros((B,), np.int32)
         fold_pos = np.zeros((B,), np.int32)
         active = np.zeros((B,), bool)
-        completing = [False] * B
+        from_prev = np.zeros((B,), bool)
+        completing = np.zeros((B,), bool)
         prefill_tokens = 0
         for r, req in enumerate(self._row_req):
-            if req is None:
+            if req is None or ending[r]:
                 continue  # free rows: qlen 0, inactive, null-block writes
             if self._prefilling[r]:
                 w0 = self._row_w0[r]
@@ -4666,7 +4842,10 @@ class ContinuousGenerator:
             else:
                 pos0[r] = self._pos[r]
                 qlen[r] = 1
-                tokens[r, 0] = self._tok[r]
+                if prev is not None and prev.sampled(r, req):
+                    from_prev[r] = True  # its token is still on the device
+                else:
+                    tokens[r, 0] = self._tok[r]
                 fold_pos[r] = int(self._pos[r]) + 1
                 # Parked handoff rows ride inactive (like done rows):
                 # writes confined to the not-yet-valid column `pos`,
@@ -4674,16 +4853,21 @@ class ContinuousGenerator:
                 active[r] = not self._done[r] and not self._held[r]
 
         # ONE dispatch, under the pool lock (it donates the pool buffers).
+        # The rows' tables and controls go as copies: the loop changes
+        # them (admission, release) while the step may still read them.
+        def copied(a):
+            return jnp.asarray(a.copy())
+
         if self._windowed:
             self._slide_window_blocks(pos0, qlen)
         with pool.lock:
-            pool_args, tables = (pool.caches,), jnp.asarray(self._tables)
+            pool_args, tables = (pool.caches,), copied(self._tables)
             if self._quant:
                 pool_args += (pool.scales,)
             if self._windowed:
                 # One of each a kind of block, (full, window).
                 pool_args = ((pool.caches, self._wpool.caches),)
-                tables = (tables, jnp.asarray(self._wtables))
+                tables = (tables, copied(self._wtables))
             if self._hybrid:
                 # The block pool and the state pool, the rows' table and
                 # their state rows; both pools are donated. The state
@@ -4693,21 +4877,24 @@ class ContinuousGenerator:
                 self._note_state_work(pos0, qlen)
                 pool_args = ((pool.caches,
                               self._spool.slab),)  # lint: lockfree-ok tick thread's alone
-                tables = (tables, jnp.asarray(self._spool.rows))
+                tables = (tables, copied(self._spool.rows))
             common = (self._step_params, *pool_args, tables,
                       jnp.asarray(tokens), jnp.asarray(pos0),
                       jnp.asarray(qlen), jnp.asarray(sample_slot),
                       jnp.asarray(fold_pos), jnp.asarray(active),
-                      jnp.asarray(self._done), jnp.asarray(self._seeds),
-                      jnp.asarray(self._temps), jnp.asarray(self._topps),
-                      jnp.asarray(self._topks), jnp.asarray(self._minps),
+                      copied(self._done), self._prev_nxt, self._prev_done,
+                      jnp.asarray(from_prev), copied(self._seeds),
+                      copied(self._temps), copied(self._topps),
+                      copied(self._topks), copied(self._minps),
                       jnp.asarray(eos_vec))
             self._tick_formed(width, prefill_rows, chunk, qlen, active,
                               pos0)
+            if prev is not None:
+                self._clock.probe(prev.nxt.is_ready())
             if controls:
                 out = self._mixed_step_exe(width, True)(
                     *common, self._ensure_counts(),
-                    jnp.asarray(self._pens), jnp.asarray(self._stops))
+                    copied(self._pens), copied(self._stops))
             else:
                 out = self._mixed_step_exe(width, False)(*common)
             if self._windowed:
@@ -4729,58 +4916,98 @@ class ContinuousGenerator:
                 nxt, done, self._counts = out
             else:
                 nxt, done = out
-        self._clock.wait()
+        self._prev_nxt, self._prev_done = nxt, done
         start_host_copies(nxt, done,
                           *(() if moe_rows is None else (moe_rows,)))
-        nxt = np.array(nxt)
-        done_new = np.array(done)
-        if moe_rows is not None and "moe" in self._stats:
-            self._count_moe(np.asarray(moe_rows), int(qlen.sum()))
+        # What the next tick's form needs of this one depends on
+        # positions alone and moves now; the tokens move when they land.
+        decode = active & ~completing
+        for r in prefill_rows:
+            self._row_w0[r] += int(chunk[r])
+            if completing[r]:
+                self._prefilling[r] = False
+        for r in np.flatnonzero(decode):
+            self._pos[r] = min(int(self._pos[r]) + 1, self.max_seq - 1)
+        tick = _FlightTick(
+            nxt=nxt, done=done, moe_rows=moe_rows,
+            reqs=list(self._row_req), active=active, completing=completing,
+            decode=decode, pos=self._pos.copy(),
+            starved=self._tick_starved,
+            sampler=self._tick_sampler, fed=int(qlen.sum()),
+            prefill_tokens=prefill_tokens, n_decode=n_decode, width=width,
+            overlapped=int(prev is not None))
+        if prev is not None:
+            self._land_tick(behind=tick)
+        else:
+            self._inflight = tick
+            if ahead_ok:
+                self._clock.leave()  # the loop goes on beside the device
+            else:
+                self._land_tick()
+
+    def _land_tick(self, behind: Optional[_FlightTick] = None) -> None:
+        """Wait for the tick in flight and apply its results host-side:
+        each stepped row's token, end and stream. `behind`: the tick
+        enqueued after it, which a row that ends here by EOS or a stop
+        token rides as a done row (`lagged_rows`)."""
+        t, self._inflight = self._inflight, behind
+        self._clock.wait()
+        nxt = np.array(t.nxt)
+        done_new = np.array(t.done)
+        if t.moe_rows is not None and "moe" in self._stats:
+            self._count_moe(np.asarray(t.moe_rows), t.fed)
         self._clock.apply()
         # Dispatch counted only past the host sync above — a device-step
         # failure surfaces asynchronously AT that sync (not at the
         # enqueue), and a recovered failure must leave dispatches and
-        # ticks equal (the invariant scrapers and the bench assert).
-        # Still a separate statement/site from the tick counter below.
+        # ticks equal (the invariant scrapers and the bench assert),
+        # also with a second tick enqueued behind the failed one: both
+        # are dropped uncounted. Still a separate statement/site from
+        # the tick counter below.
         self._stats["mixed"]["dispatches"] += 1
 
         m = self._stats["mixed"]
         m["ticks"] += 1
-        m[f"sample_{self._tick_sampler}_ticks"] += 1
-        m["prefill_tokens"] += prefill_tokens
-        m["decode_tokens"] += n_decode
-        if prefill_tokens and n_decode:
+        m[f"sample_{t.sampler}_ticks"] += 1
+        m["overlapped_ticks"] += t.overlapped
+        m["prefill_tokens"] += t.prefill_tokens
+        m["decode_tokens"] += t.n_decode
+        if t.prefill_tokens and t.n_decode:
             m["coscheduled_ticks"] += 1
 
-        for r in list(range(B)):
+        for r in range(self.n_slots):
             req = self._row_req[r]
-            if req is None:
-                continue
-            if self._held[r]:
-                continue  # parked: nothing was dispatched for this row
-            if self._prefilling[r]:
-                self._row_w0[r] += int(chunk[r])
-                if not completing[r]:
-                    continue
+            if req is None or req is not t.reqs[r]:
+                continue  # freed, or its slot given on, while the tick ran
+            if t.completing[r]:
                 self._complete_prefill_row(r, req, int(nxt[r]),
-                                           bool(done_new[r]))
-                continue
-            tok_r = int(nxt[r])
-            self._tok[r] = tok_r
-            self._done[r] = bool(done_new[r])
-            if not self._done[r]:
-                self._pos[r] = min(int(self._pos[r]) + 1, self.max_seq - 1)
-            if req.max_new - len(self._row_emitted[r]) > 0:
-                self._row_emitted[r].append(tok_r)
-                now = time.perf_counter()
-                if self._row_last_emit[r] > 0:
-                    self.itl_hist.observe(
-                        max(0.0, now - self._row_last_emit[r]))
-                self._row_last_emit[r] = now
-            self._push_stream(r, req)
-            self._maybe_complete(r)
+                                           bool(done_new[r]),
+                                           pos=int(t.pos[r]))
+            elif t.decode[r]:
+                tok_r = int(nxt[r])
+                self._tok[r] = tok_r
+                self._done[r] = bool(done_new[r])
+                if req.max_new - len(self._row_emitted[r]) > 0:
+                    self._row_emitted[r].append(tok_r)
+                    now = time.perf_counter()
+                    if self._row_last_emit[r] > 0:
+                        self.itl_hist.observe(
+                            max(0.0, now - self._row_last_emit[r]))
+                    self._row_last_emit[r] = now
+                self._push_stream(r, req)
+                self._maybe_complete(r, pos=int(t.pos[r]))
+            else:
+                continue  # mid-prompt, starved or parked: nothing sampled
+            if (self._row_req[r] is None and behind is not None
+                    and behind.sampled(r, req)):
+                # Ended by what only the device knew: the tick behind
+                # steps it once more, as a done row.
+                m["lagged_rows"] += 1
 
-        self._tick_done(prefill_tokens, n_decode, width)
+        if behind is not None:
+            self._clock.probe(behind.nxt.is_ready())
+        self._tick_done(t.prefill_tokens, t.n_decode, t.width,
+                        starved=t.starved)
 
     def _tick_spec(self) -> None:
         """One SPECULATIVE ragged tick — the spec_k>0 replacement for
@@ -5266,8 +5493,9 @@ class ContinuousGenerator:
             # a newcomer).
             if self._paged or self._slab:
                 # Export commands run FIRST: between ticks the row is
-                # quiescent, and an export ahead of admissions can never
-                # observe a half-admitted batch.
+                # quiescent (`_serve_exports` lands a tick in flight
+                # before it serves one), and an export ahead of
+                # admissions can never observe a half-admitted batch.
                 self._serve_exports()
             if self._paged:
                 self._ensure_capacity_paged()
@@ -5403,6 +5631,7 @@ class ContinuousGenerator:
                     if self._row_req[r] is not None
                     and self._row_req[r].oneshot is None]
             if not live:
+                self._drain_tick()  # its rows left while it ran
                 self._clock.idle()
                 continue
             if (self._paged or self._slab) and all(self._held[r]

@@ -372,6 +372,12 @@ def render_prometheus(healths: List[Dict], stats: Optional[Dict] = None,
            "Mixed ticks by the sampler body their kept rows asked for",
            [({**node(h), "body": body}, m.get(f"sample_{body}_ticks"))
             for h, m in mx for body in ("greedy", "plain", "filtered")])
+    metric("tpu_engine_mixed_overlapped_ticks_total", "counter",
+           "Mixed ticks enqueued before the tick before's results were read",
+           [(node(h), m.get("overlapped_ticks")) for h, m in mx])
+    metric("tpu_engine_mixed_lagged_rows_total", "counter",
+           "Row-ticks stepped past an end the host learned one tick late",
+           [(node(h), m.get("lagged_rows")) for h, m in mx])
     metric("tpu_engine_mixed_token_budget", "gauge",
            "Per-tick new-token budget (--mixed-token-budget)",
            [(node(h), m.get("token_budget")) for h, m in mx])

@@ -494,22 +494,53 @@ def compile_counter() -> CompileCounter:
     return _compile_counter
 
 
+class _TickMarks:
+    """What the clock keeps of one tick from `begin` to `end`: under the
+    pipeline its form and dispatch are marked one loop iteration before
+    its wait and apply."""
+
+    __slots__ = ("seq", "us", "width", "ctx_tokens", "notes", "compile_s",
+                 "gap_us", "overlapped", "enqueued")
+
+    def __init__(self, seq: int):
+        self.seq = seq
+        self.us = dict.fromkeys(TICK_PHASES, 0.0)
+        self.width = self.ctx_tokens = 0
+        self.notes: Dict[str, object] = {}
+        self.compile_s = 0.0
+        self.gap_us: Optional[float] = None
+        self.overlapped = 0
+        self.enqueued = False
+
+
 class TickClock:
     """One lane's tick clock, marked by the decode thread only.
 
-    A tick is four contiguous phases: ``begin()`` opens `form`,
-    ``dispatch()``, ``wait()`` and ``apply()`` each close the phase
-    before and open their own, ``end()`` closes the tick and returns its
-    start, duration and the span attrs. The same marks open and close
+    A tick has four phases: ``begin()`` opens `form`, ``dispatch()``,
+    ``wait()`` and ``apply()`` each close the phase before and open
+    their own, ``end()`` closes the tick and returns its start, duration
+    and the span attrs. A lane that reads a tick's results before it
+    forms the next marks them back to back. A lane that runs one tick
+    ahead marks, in one loop iteration, `form` and `dispatch` of tick
+    N+1 and then `wait` and `apply` of tick N: the four phases still
+    tile the host's time between two `loop.admit`s, and each tick's span
+    carries its OWN four (``end()`` returns the oldest tick not yet
+    ended). ``leave()`` ends an iteration that enqueued a tick and had
+    none to wait for; ``wait()`` without ``begin()`` is an iteration
+    that only lands the tick in flight. The same marks open and close
     ``jax.profiler.TraceAnnotation``s (`tick` with children `tick.form`
     ... `tick.apply`, and `loop.admit` for the loop's work between and
     before ticks), which cost nothing measurable without a profiler
     session and otherwise land on the host plane of the device trace.
 
-    `gap_us`: from the end of the previous tick's `wait` to this tick's
-    `dispatch`, the time the device had nothing queued because of the
-    host. Only between back-to-back ticks: ``end(live=False)`` and
-    ``idle()`` break the chain."""
+    `gap_us`: the time the device had nothing queued because of the
+    host, up to this tick's `dispatch`. After a tick whose results were
+    read with nothing queued behind it: from that `wait`'s end. Behind a
+    tick still in flight: 0, unless a ``probe()`` saw that tick finished
+    already, then from the first such probe. Only between back-to-back
+    ticks: ``end(live=False)`` and ``idle()`` break the chain.
+    `overlapped`: 1 if the tick was enqueued while its predecessor's
+    results were not yet read."""
 
     def __init__(self, compiles: CompileCounter):
         from jax.profiler import TraceAnnotation
@@ -517,13 +548,14 @@ class TickClock:
         self._annotation = TraceAnnotation
         self._compiles = compiles
         self._open: List[object] = []
-        self._marks: List[float] = []
-        self._wall = 0.0
+        self._phase: Optional[str] = None
+        self._marks: Optional[_TickMarks] = None   # the open phase's tick
+        self._t0 = 0.0
         self._compile_s0 = 0.0
-        self._width = 0
-        self._ctx_tokens = 0
-        self._notes: Dict[str, object] = {}
-        self._prev_wait_end: Optional[float] = None
+        self._formed: Optional[_TickMarks] = None
+        self._flight: deque = deque()              # enqueued, not ended
+        self._chained = False
+        self._idle_since: Optional[float] = None
         self._recent: Dict[int, deque] = {}
         self._last_slow_line = 0.0
         self.seq = 0
@@ -537,10 +569,23 @@ class TickClock:
         while self._open:
             self._open.pop().__exit__(None, None, None)
 
-    def _mark(self, phase: str) -> None:
-        self._marks.append(time.perf_counter())
-        self._open.pop().__exit__(None, None, None)
-        self._push(phase)
+    def _enter(self, phase: Optional[str],
+               marks: Optional[_TickMarks]) -> float:
+        """Close the open phase into its tick's marks and open `phase`
+        of `marks` (None: the iteration's ticks are marked)."""
+        now = time.perf_counter()
+        if self._phase is not None:
+            self._marks.us[self._phase] += (now - self._t0) * 1e6
+            self._marks.compile_s += self._compiles.seconds - self._compile_s0
+            self._open.pop().__exit__(None, None, None)
+        elif phase is not None:
+            self._close()
+            self._push("tick")
+        self._phase, self._marks, self._t0 = phase, marks, now
+        self._compile_s0 = self._compiles.seconds
+        if phase is not None:
+            self._push("tick." + phase)
+        return now
 
     def admit(self) -> None:
         """Top of a scheduler loop iteration: the host's work before a
@@ -552,69 +597,107 @@ class TickClock:
 
     def idle(self) -> None:
         """The loop found nothing to dispatch, or the tick raised between
-        its marks: close what is open; the next tick follows an idle
-        lane, not a host gap."""
+        its marks: close what is open and forget the ticks not ended;
+        the next tick follows an idle lane, not a host gap."""
         self._close()
-        self._prev_wait_end = None
+        self._phase = self._marks = self._formed = None
+        self._flight.clear()
+        self._chained = False
+        self._idle_since = None
 
     def begin(self) -> None:
-        self._close()
         self.seq += 1
-        self._wall = time.time()
-        self._compile_s0 = self._compiles.seconds
-        self._marks = [time.perf_counter()]
-        self._notes = {}
-        self._push("tick")
-        self._push("tick.form")
+        self._formed = _TickMarks(self.seq)
+        self._enter("form", self._formed)
+
+    def probe(self, ready: bool) -> None:
+        """`ready`: the newest tick enqueued has finished on the device
+        (its result's ``is_ready()``), so the device has had nothing
+        queued since some moment before this one."""
+        if ready and self._idle_since is None:
+            self._idle_since = time.perf_counter()
 
     def dispatch(self, width: int, rows: int, ctx_tokens: int) -> None:
         """The batch is formed; the step executable is called next."""
-        self._width, self._ctx_tokens = int(width), int(ctx_tokens)
-        self._mark("tick.dispatch")
-        self._open[0].set_metadata(seq=self.seq, width=self._width,
+        marks = self._formed
+        marks.width, marks.ctx_tokens = int(width), int(ctx_tokens)
+        marks.enqueued = True
+        now = self._enter("dispatch", marks)
+        if self._chained or self._flight:
+            marks.gap_us = (0.0 if self._idle_since is None else
+                            round((now - self._idle_since) * 1e6, 1))
+        self._open[0].set_metadata(seq=marks.seq, width=marks.width,
                                    rows=int(rows),
-                                   ctx_tokens=self._ctx_tokens)
+                                   ctx_tokens=marks.ctx_tokens)
+
+    def _enqueued(self) -> None:
+        marks, self._formed = self._formed, None
+        if marks is None:
+            return
+        if not marks.enqueued:      # formed, and nothing was there to step
+            self.seq -= 1
+            return
+        marks.overlapped = int(bool(self._flight))
+        self._flight.append(marks)
+        self._idle_since = None     # the device has this tick queued
+
+    def leave(self) -> None:
+        """The step is enqueued and the iteration has no tick to wait
+        for: the loop goes on beside the device."""
+        self._enqueued()
+        self._enter(None, None)
+        self._close()
+        self._push("loop.admit")
 
     def wait(self) -> None:
-        """The step is enqueued; the host now blocks on its results."""
-        self._mark("tick.wait")
+        """The step is enqueued (if the iteration formed one); the host
+        now blocks on the results of the oldest tick in flight."""
+        self._enqueued()
+        self._enter("wait", self._flight[0])
 
     def apply(self) -> None:
         """The host has the results."""
-        self._mark("tick.apply")
+        now = self._enter("apply", self._flight[0])
+        if len(self._flight) == 1:
+            self._idle_since = now  # nothing is queued behind them
 
     def note(self, **attrs) -> None:
         """What the scheduler knows of the tick beyond its phases: counts
         the step brought back with its results (a routed model's
         `moe_assignments`, `moe_experts_touched`), the `sampler` body its
-        rows asked for. They ride the tick's span beside `ctx_tokens`."""
-        self._notes.update(attrs)
+        rows asked for. They ride the span of the tick whose phase is
+        open, beside `ctx_tokens`."""
+        self._marks.notes.update(attrs)
 
     def end(self, live: bool, node: str) -> Tuple[float, float, dict]:
-        """(start_ts, duration_us, attrs) of the tick just finished.
-        `live`: a row was still dispatchable when it ended."""
-        self._marks.append(time.perf_counter())
+        """(start_ts, duration_us, attrs) of the tick whose results were
+        just applied. `live`: a row was still dispatchable when it ended.
+        The duration is the sum of the tick's own four phases and the
+        span ends now: beside a tick in flight, the other tick's `form`
+        and `dispatch` lie between this one's `dispatch` and `wait`."""
+        now = self._enter(None, None)
         self._close()
         self._push("loop.admit")
-        t = self._marks
-        dur_us = (t[4] - t[0]) * 1e6
-        attrs = {f"{p}_us": round((b - a) * 1e6, 1)
-                 for p, a, b in zip(TICK_PHASES, t, t[1:])}
-        if self._prev_wait_end is not None:
-            attrs["gap_us"] = round((t[1] - self._prev_wait_end) * 1e6, 1)
-        self._prev_wait_end = t[3] if live else None
-        attrs["ctx_tokens"] = self._ctx_tokens
-        attrs.update(self._notes)
-        attrs["compile_us"] = int(
-            (self._compiles.seconds - self._compile_s0) * 1e6)
-        attrs["seq"] = self.seq
-        self._say_if_slow(dur_us, attrs, node, t[4])
-        return self._wall, dur_us, attrs
+        marks = self._flight.popleft()
+        attrs = {f"{p}_us": round(marks.us[p], 1) for p in TICK_PHASES}
+        dur_us = sum(marks.us.values())
+        if marks.gap_us is not None:
+            attrs["gap_us"] = marks.gap_us
+        self._chained = live or bool(self._flight)
+        if not self._chained:
+            self._idle_since = None
+        attrs["overlapped"] = marks.overlapped
+        attrs["ctx_tokens"] = marks.ctx_tokens
+        attrs.update(marks.notes)
+        attrs["compile_us"] = int(marks.compile_s * 1e6)
+        attrs["seq"] = marks.seq
+        self._say_if_slow(dur_us, attrs, node, now, marks.width, marks.seq)
+        return time.time() - dur_us / 1e6, dur_us, attrs
 
     def _say_if_slow(self, dur_us: float, attrs: dict, node: str,
-                     now: float) -> None:
+                     now: float, width: int, seq: int) -> None:
         recent = self._recent.setdefault(
-            self._width, deque(maxlen=SLOW_TICK_HISTORY))
+            width, deque(maxlen=SLOW_TICK_HISTORY))
         if (len(recent) >= SLOW_TICK_MIN_HISTORY
                 and now - self._last_slow_line >= SLOW_TICK_LINE_EVERY_S):
             median_us = statistics.median(recent)
@@ -623,8 +706,8 @@ class TickClock:
                 said = " ".join(f"{k}={attrs[k]}" for k in
                                 (*(f"{p}_us" for p in TICK_PHASES), "gap_us",
                                  "compile_us") if k in attrs)
-                print(f"slow tick: node={node} seq={self.seq} "
-                      f"width={self._width} duration_us={dur_us:.0f} "
+                print(f"slow tick: node={node} seq={seq} "
+                      f"width={width} duration_us={dur_us:.0f} "
                       f"median_us={median_us:.0f} {said}",
                       file=sys.stderr, flush=True)
         recent.append(dur_us)
