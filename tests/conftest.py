@@ -82,9 +82,10 @@ def pytest_collection_modifyitems(session, config, items):
     # Each reads BENCHMARK.json through its own `json` name: give it the
     # lists as they stood when its rehearsal file was last written. PR
     # 40's `sched.overlap_tick_share` lists all six cells, PR 39's too,
-    # so ..._olmo_hybrid.py joins them. A `benchmark` PR adds the three
-    # metrics to the rehearsal files, finds the cell by name and deletes
-    # this with the hook above (PERF.md section 7).
+    # so ..._olmo_hybrid.py joins them; eight of PR 42's nine list all
+    # six. A `benchmark` PR adds the metrics to the rehearsal files,
+    # finds the cell by name and deletes this with the hook above
+    # (PERF.md section 7).
     for name in ("test_benchmark_reference_moonlight",
                  "test_benchmark_reference_laguna",
                  "test_benchmark_reference_olmo_hybrid"):
@@ -100,7 +101,11 @@ class _AsTheCellWasWritten:
 
     APPENDED_SINCE = ("step.sampler_sort_busy",
                       "step.sampler_sort_tick_share",   # PR 38
-                      "sched.overlap_tick_share")       # PR 40
+                      "sched.overlap_tick_share",       # PR 40
+                      "sched.loop_ms", "sched.host_offcpu_ms",
+                      "front.stream_cpu_ms_per_tick", "lane.stream_wake_ms",
+                      "front.stream_deliver_ms", "device.idle_loop",
+                      "device.idle_stream", "step.gc_ms_per_s")  # PR 42
 
     def __init__(self, json_module, cell):
         self._json, self._cell = json_module, cell

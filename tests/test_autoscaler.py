@@ -241,6 +241,22 @@ def test_scale_down_rides_live_stream_migration(gen_fleet):
             [PROMPT], max_new_tokens=16)[0]
         toks, final = [], [None]
         armed = threading.Event()
+        # The export must find the row mid-stream, however slow this
+        # thread is to be woken: the lane holds once, after the third
+        # streamed token, until the export command waits in its queue
+        # (13 more tokens of this model take a few milliseconds).
+        gen = next(w for w in gen_fleet if w.node_id == lane).generator
+        push, held = gen._push_stream, []
+
+        def push_then_hold(row, req):
+            push(row, req)
+            if req.streamed >= 3 and not held:
+                held.append(row)
+                limit = time.monotonic() + 60
+                while gen._migrate_q.empty() and time.monotonic() < limit:
+                    time.sleep(0.001)
+
+        gen._push_stream = push_then_hold
 
         def consume():
             for frame in gw.route_generate_stream(
@@ -272,6 +288,7 @@ def test_scale_down_rides_live_stream_migration(gen_fleet):
         gw.stop()
         # Re-register both lanes for other tests sharing the fixture.
         for w in gen_fleet:
+            w.generator.__dict__.pop("_push_stream", None)
             w.undrain()
 
 

@@ -105,7 +105,21 @@ from tpu_engine.utils.sampling import (
     expand_stopping_params,
     truncate_at_stops,
 )
-from tpu_engine.utils.tracing import TickClock, compile_counter
+from tpu_engine.utils.tracing import (
+    TickClock,
+    compile_counter,
+    gc_counter,
+)
+
+
+class StreamDelta(list):
+    """A streamed request's fresh tokens as they go into its queue: the
+    list its reader always got, and `t_put`, when it was put
+    (``time.perf_counter``; `_push_stream` sets it), so that the reader
+    can say how long the tokens lay there (the lane's `generate_stream`
+    span, `wake_us_*`)."""
+
+    __slots__ = ("t_put",)
 
 
 @dataclass
@@ -933,7 +947,8 @@ class ContinuousGenerator:
         # the `mixed_step` span, profiler annotations, host gap), and
         # the process-wide compile counter it reads `compile_us` from.
         self._compiles = compile_counter()
-        self._clock = TickClock(self._compiles)
+        self._gcs = gc_counter()
+        self._clock = TickClock(self._compiles, self._gcs)
         # The mixed tick's pipeline, one tick deep (`_tick_mixed`).
         self._reset_flight()
         # Per-row prefill accounting for the `prefill` span: ticks that
@@ -2610,6 +2625,7 @@ class ContinuousGenerator:
                    last_tick_age_s=round(age, 3),
                    prefix_cache=self._prefix_cache.stats(),
                    compile=self._compiles.snapshot(),
+                   gc=self._gcs.snapshot(),
                    weights=dict(self._weights))
         if self._mixed:
             # Snapshot, not the live nested dict — callers diff stats()
@@ -4071,7 +4087,9 @@ class ContinuousGenerator:
             return
         vis = self._visible_tokens(row, req)
         if len(vis) > req.streamed:
-            req.stream.put(vis[req.streamed:])
+            fresh = StreamDelta(vis[req.streamed:])
+            fresh.t_put = time.perf_counter()
+            req.stream.put(fresh)
             req.streamed = len(vis)
 
     def _row_ends(self, req: _Request, emitted_n: int, pos: int) -> bool:
@@ -5471,9 +5489,14 @@ class ContinuousGenerator:
         self._tick_done(prefill_tokens, n_decode, width)
 
     def _loop_body(self) -> None:
+        # The lanes whose ticks are marked say which of the loop's
+        # statements run (`TickClock.loop_part`); the others mark nothing.
+        marked = self._mixed or self._spec
+        part = self._clock.loop_part if marked else lambda name: None
         while self._running:
-            if self._mixed or self._spec:  # the lanes whose ticks are marked
+            if marked:
                 self._clock.admit()
+            part("exports")
             now = time.monotonic()
             if self._flight_capacity:
                 # One bounded record per tick; the wall delta since the
@@ -5497,11 +5520,13 @@ class ContinuousGenerator:
                 # before it serves one), and an export ahead of
                 # admissions can never observe a half-admitted batch.
                 self._serve_exports()
+            part("capacity")
             if self._paged:
                 self._ensure_capacity_paged()
             # Admit as many prefilled requests as there are free rows —
             # deferred (pool-pressure) admissions first, in arrival
             # order; block briefly when completely idle.
+            part("admit")
             free = self._free_rows()
             admitted_any = False
             while free:
@@ -5612,6 +5637,7 @@ class ContinuousGenerator:
                     self._fail_request(item[0], exc)
                     self._recover(exc)
                     break
+            part("expire")
             self._cancel_expired_rows()
             if self._paged or self._slab:
                 # Handoff holds past their park window resume decoding

@@ -57,7 +57,12 @@ from tpu_engine.utils.deadline import (
 from tpu_engine.utils.sampling import clamp_top_k as _clamp_top_k
 from tpu_engine.utils.sampling import validate_min_p as _validate_min_p
 from tpu_engine.utils.sampling import expand_stopping_params
-from tpu_engine.utils.tracing import SpanRecorder, TraceContext, TraceSink
+from tpu_engine.utils.tracing import (
+    SpanRecorder,
+    StreamClock,
+    TraceContext,
+    TraceSink,
+)
 
 
 @dataclass
@@ -2120,6 +2125,7 @@ class WorkerNode:
         def events():
             sent = 0  # tokens relayed to the client so far (resume offset)
             ttft_us = None  # receipt by the lane -> first token event out
+            way = StreamClock()  # the token events' way out, summed
             completed = False
             try:
                 while True:
@@ -2127,7 +2133,7 @@ class WorkerNode:
                         item = q.get(timeout=600)
                     except queue.Empty:
                         self._segment_span(request_id, tctx, parent, t0,
-                                           t_start_wall, "stalled")
+                                           t_start_wall, "stalled", way)
                         yield sse_event(self._stream_error(
                             RuntimeError("generation stalled (no tokens "
                                          "for 600s)"),
@@ -2136,9 +2142,13 @@ class WorkerNode:
                     if item is None:
                         break
                     sent += len(item)
+                    t_woke = way.woke(item)
                     if ttft_us is None:
-                        ttft_us = int((time.perf_counter() - t_admit) * 1e6)
-                    yield sse_event({"tokens": item})
+                        ttft_us = int((t_woke - t_admit) * 1e6)
+                    try:
+                        yield sse_event({"tokens": item})
+                    finally:
+                        way.delivered()
                 elapsed_us = int((time.perf_counter() - t0) * 1e6)
                 try:
                     tokens = fut.result(timeout=10)
@@ -2146,7 +2156,7 @@ class WorkerNode:
                     self._segment_span(
                         request_id, tctx, parent, t0, t_start_wall,
                         "exported" if getattr(exc, "migrated", False)
-                        else "error")
+                        else "error", way)
                     yield sse_event(self._stream_error(
                         exc, request_id, tctx.trace_id, sent))
                     return
@@ -2158,7 +2168,7 @@ class WorkerNode:
                                else None),
                     start_ts=t_start_wall,
                     attrs=(None if ttft_us is None
-                           else {"ttft_us": ttft_us}))
+                           else {"ttft_us": ttft_us, **way.attrs()}))
                 completed = True
                 yield sse_event({"done": True, "request_id": request_id,
                                  "tokens": tokens, "node_id": self.node_id,
@@ -2173,20 +2183,22 @@ class WorkerNode:
         return events()
 
     def _segment_span(self, request_id, tctx, parent, t0, t_start_wall,
-                      outcome: str) -> None:
+                      outcome: str, way: StreamClock) -> None:
         """Root span for a stream SEGMENT that did not complete on this
         lane (exported row, lane fault, stall). The stage spans already
         recorded under ``tctx.span_id`` must not dangle: a mobile
         stream's stitched tree needs every serving lane's segment root,
         and even a single lane's /trace/export should never ship
         orphans (the completion path records the same span with no
-        ``segment`` attr)."""
+        ``segment`` attr). `way`: what the segment's token events took
+        on their way out, as on a completed stream's span."""
         self.tracer.record(
             request_id, "generate_stream", self.node_id,
             (time.perf_counter() - t0) * 1e6,
             trace_id=tctx.trace_id, span_id=tctx.span_id,
             parent_id=(parent.span_id if parent is not None else None),
-            start_ts=t_start_wall, attrs={"segment": outcome})
+            start_ts=t_start_wall,
+            attrs={"segment": outcome, **way.attrs()})
 
     @staticmethod
     def _stream_error(exc: BaseException, request_id: str, trace_id: str,

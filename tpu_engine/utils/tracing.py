@@ -33,15 +33,22 @@ request. This module now carries a real tracing subsystem:
   (XLA device traces viewable in TensorBoard / Perfetto), driven by
   ``POST /admin/profile`` on the combined server.
 - `TickClock` — the scheduler's one tick clock: four contiguous phases
-  (form, dispatch, wait, apply) as attrs of the tick span AND as
-  ``jax.profiler.TraceAnnotation``s on the device trace's clock, the
-  host gap between ticks, and the slow-tick stderr line.
+  (form, dispatch, wait, apply) and the loop between two ticks as attrs
+  of the tick span AND as ``jax.profiler.TraceAnnotation``s on the
+  device trace's clock, the scheduler thread's time off the CPU in the
+  phases that never block by design, the host gap between ticks, and
+  the slow-tick stderr line.
 - `CompileCounter` / `compile_counter()` — XLA compilations and their
   seconds, process-wide, from ``jax.monitoring``.
+- `GcCounter` / `gc_counter()` — the interpreter's garbage collections
+  and their pause seconds, process-wide, from ``gc.callbacks``.
+- `StreamClock` — a streamed request's token events on the handler's
+  thread: queue wake-up and delivery, summed onto its request span.
 """
 
 from __future__ import annotations
 
+import gc
 import hashlib
 import math
 import re
@@ -67,6 +74,18 @@ _TRACEPARENT_RE = re.compile(
 # A scheduler tick's four contiguous phases (`TickClock`): `<phase>_us`
 # attrs on the tick span, `tick.<phase>` profiler annotations.
 TICK_PHASES = ("form", "dispatch", "wait", "apply")
+# The phases in which the scheduler thread makes no call that blocks by
+# design (the loop between two ticks is the third): wall time less the
+# thread's CPU time there is time without the interpreter lock or without
+# a core, `<phase>_offcpu_us`. `dispatch` and `wait` block by design.
+OFFCPU_PHASES = ("form", "apply")
+# ... read on one loop iteration in this many: the thread's CPU clock is a
+# system call, 0.4 us on a plain kernel and 45 us on the v5e hosts', where
+# five reads a tick cost 4 % of a 5.5 ms decode period (PERF.md, PR 42).
+CPU_CLOCK_EVERY = 8
+# The loop's statements between two ticks, in order (`TickClock.loop_part`):
+# `loop_<part>_us` attrs, `loop.admit.<part>` annotations under `loop.admit`.
+LOOP_PARTS = ("exports", "capacity", "admit", "expire")
 
 
 def new_span_id() -> str:
@@ -448,6 +467,11 @@ SLOW_TICK_FACTOR = 5.0       # a tick this many medians long says where
 SLOW_TICK_HISTORY = 20       # ... the median of the last ticks of its width
 SLOW_TICK_MIN_HISTORY = 5
 SLOW_TICK_LINE_EVERY_S = 10.0
+# ... with, of its span's attrs: the phases, then on the CPU or off it,
+# in a collection or a compilation or neither.
+SLOW_TICK_SAYS = (*(f"{p}_us" for p in TICK_PHASES), "gap_us", "loop_us",
+                  *(f"{p}_offcpu_us" for p in (*OFFCPU_PHASES, "loop")),
+                  "compile_us", "gc_us")
 
 
 class CompileCounter:
@@ -494,23 +518,71 @@ def compile_counter() -> CompileCounter:
     return _compile_counter
 
 
+class GcCounter:
+    """The interpreter's garbage collections and the seconds they paused
+    it, process-wide: a ``gc.callbacks`` entry, which runs only when a
+    collection does, on the thread that set it off and with the
+    interpreter lock held: every Python thread waits the pause out,
+    whoever started it, so a tick is charged a neighbour's too. `gen2`
+    counts the full collections (the long ones)."""
+
+    def __init__(self, wall=time.perf_counter):
+        self._wall = wall
+        self._t0: Optional[float] = None
+        self.count = 0
+        self.gen2 = 0
+        self.seconds = 0.0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = self._wall()
+        elif self._t0 is not None:
+            self.seconds += self._wall() - self._t0
+            self._t0 = None
+            self.count += 1
+            self.gen2 += info.get("generation") == 2
+
+    def snapshot(self) -> dict:
+        return {"count": self.count, "seconds": round(self.seconds, 6),
+                "gen2": self.gen2}
+
+
+_gc_counter: Optional[GcCounter] = None
+_gc_counter_lock = threading.Lock()
+
+
+def gc_counter() -> GcCounter:
+    """The process's one `GcCounter`; the first call registers it."""
+    global _gc_counter
+    with _gc_counter_lock:
+        if _gc_counter is None:
+            _gc_counter = GcCounter()
+            gc.callbacks.append(_gc_counter)
+    return _gc_counter
+
+
 class _TickMarks:
     """What the clock keeps of one tick from `begin` to `end`: under the
     pipeline its form and dispatch are marked one loop iteration before
     its wait and apply."""
 
-    __slots__ = ("seq", "us", "width", "ctx_tokens", "notes", "compile_s",
-                 "gap_us", "overlapped", "enqueued")
+    __slots__ = ("seq", "us", "offcpu_us", "width", "ctx_tokens", "notes",
+                 "compile_s", "gc_s", "gap_us", "overlapped", "enqueued",
+                 "t_begin", "loop")
 
     def __init__(self, seq: int):
         self.seq = seq
         self.us = dict.fromkeys(TICK_PHASES, 0.0)
+        self.offcpu_us: Dict[str, float] = {}   # the phases it was read in
         self.width = self.ctx_tokens = 0
         self.notes: Dict[str, object] = {}
-        self.compile_s = 0.0
+        self.compile_s = self.gc_s = 0.0
         self.gap_us: Optional[float] = None
         self.overlapped = 0
         self.enqueued = False
+        self.t_begin = 0.0
+        # `loop_us`, its parts and `period_us`, where it follows a tick.
+        self.loop: Dict[str, float] = {}
 
 
 class TickClock:
@@ -530,8 +602,37 @@ class TickClock:
     that only lands the tick in flight. The same marks open and close
     ``jax.profiler.TraceAnnotation``s (`tick` with children `tick.form`
     ... `tick.apply`, and `loop.admit` for the loop's work between and
-    before ticks), which cost nothing measurable without a profiler
-    session and otherwise land on the host plane of the device trace.
+    before ticks, with children `loop.admit.exports` ...
+    `loop.admit.expire` where the loop says ``loop_part()``), which
+    cost nothing measurable without a profiler session and otherwise
+    land on the host plane of the device trace.
+
+    The loop is the fifth measured phase. `loop_us`: the `loop.admit`
+    time since the ``begin()`` before, up to this tick's ``begin()``,
+    on the span of the tick that `begin()` opens; `loop_<part>_us`: what
+    of it lay behind each ``loop_part()`` mark (the rest is the tail of
+    the tick before: its span's record, the returns); `period_us`: from
+    the ``begin()`` of the tick enqueued before to this tick's. All
+    only where the tick follows another (as `gap_us`), so one iteration
+    of a lane that runs ahead reads: `period_us` of tick N+2 =
+    `form_us + dispatch_us` of N+1, `wait_us + apply_us` of N and
+    `loop_us` of N+2.
+
+    `form_offcpu_us`, `apply_offcpu_us`, `loop_offcpu_us`: the phase's
+    wall time less the decode thread's own CPU time in it
+    (``time.thread_time_ns``), never below 0. In these phases the thread
+    makes no call that blocks by design, so what is left is time it
+    waited for the interpreter lock, or for a core. The CPU clock is
+    read on one loop iteration in `cpu_every` (`CPU_CLOCK_EVERY`: a
+    read is a system call), from one ``end()`` to the next, so a tick
+    carries the attrs of the phases that fell into such an iteration
+    (its loop and form in one, its apply in the next) or none. Where
+    that clock ticks coarsely (10 ms on the v5e hosts: a phase of 4 ms
+    reads 0 or 10 ms of CPU), the CPU time beyond a stretch's wall time
+    is owed to the same phase's next stretches: one tick's attr says
+    little there, their MEAN over a window is right. `gc_us`: the
+    collector's pauses (`GcCounter`) inside the tick's phases and its
+    loop, whichever thread set them off.
 
     `gap_us`: the time the device had nothing queued because of the
     host, up to this tick's `dispatch`. After a tick whose results were
@@ -542,20 +643,35 @@ class TickClock:
     `overlapped`: 1 if the tick was enqueued while its predecessor's
     results were not yet read."""
 
-    def __init__(self, compiles: CompileCounter):
+    def __init__(self, compiles: CompileCounter,
+                 gcs: Optional[GcCounter] = None,
+                 wall=time.perf_counter, cpu_ns=time.thread_time_ns,
+                 cpu_every: int = CPU_CLOCK_EVERY):
         from jax.profiler import TraceAnnotation
 
         self._annotation = TraceAnnotation
         self._compiles = compiles
+        self._gcs = gcs if gcs is not None else GcCounter()
+        self._wall, self._cpu_ns = wall, cpu_ns
+        self._cpu_every = max(1, int(cpu_every))
+        self._iterations = 0
+        self._cpu_on = True        # this iteration reads the CPU clock
         self._open: List[object] = []
         self._phase: Optional[str] = None
         self._marks: Optional[_TickMarks] = None   # the open phase's tick
         self._t0 = 0.0
-        self._compile_s0 = 0.0
+        self._cpu0: Optional[int] = None
+        self._compile_s0 = self._gc_s0 = 0.0
+        self._cpu_owed = dict.fromkeys((*OFFCPU_PHASES, "loop"), 0.0)
         self._formed: Optional[_TickMarks] = None
         self._flight: deque = deque()              # enqueued, not ended
         self._chained = False
         self._idle_since: Optional[float] = None
+        self._begin_prev: Optional[float] = None   # of the tick enqueued last
+        self._in_loop = False      # a `loop.admit` after a tick is open
+        self._part: Optional[str] = None
+        self._part_t0 = 0.0
+        self._reset_loop()
         self._recent: Dict[int, deque] = {}
         self._last_slow_line = 0.0
         self.seq = 0
@@ -569,20 +685,75 @@ class TickClock:
         while self._open:
             self._open.pop().__exit__(None, None, None)
 
+    def _reset_loop(self) -> None:
+        self._loop_us = self._loop_gc_s = 0.0
+        # None once a stretch of the loop went by with the CPU clock unread.
+        self._loop_offcpu_us: Optional[float] = 0.0
+        self._loop_parts = dict.fromkeys(LOOP_PARTS, 0.0)
+
+    def _open_loop(self) -> float:
+        """After a tick's marks: the loop's time runs from here, and
+        with it the next loop iteration, which reads the CPU clock or
+        does not."""
+        now = self._enter(None, None)
+        self._close()
+        self._push("loop.admit")
+        self._in_loop = True
+        self._iterations += 1
+        self._cpu_on = self._iterations % self._cpu_every == 0
+        if self._cpu_on and self._cpu0 is None:
+            self._cpu0 = self._cpu_ns()
+        return now
+
+    def _end_part(self, now: float) -> None:
+        if self._part is not None:
+            if self._in_loop:
+                self._loop_parts[self._part] += (now - self._part_t0) * 1e6
+            self._part = None
+
+    def _offcpu_us(self, phase: str, wall_us: float, cpu: int) -> float:
+        """Wall less CPU time of a stretch of `phase` that ends now,
+        never below 0; CPU time beyond the wall time (a coarse CPU
+        clock charges a whole tick of its own to the stretch it falls
+        in) is carried to the phase's next stretches."""
+        off = wall_us - (cpu - self._cpu0) / 1e3 - self._cpu_owed[phase]
+        self._cpu_owed[phase] = max(0.0, -off)
+        return max(0.0, off)
+
     def _enter(self, phase: Optional[str],
                marks: Optional[_TickMarks]) -> float:
-        """Close the open phase into its tick's marks and open `phase`
-        of `marks` (None: the iteration's ticks are marked)."""
-        now = time.perf_counter()
+        """Close the open phase into its tick's marks (the loop's
+        stretch into the loop's sums) and open `phase` of `marks`
+        (None: the iteration's ticks are marked)."""
+        now = self._wall()
+        cpu = self._cpu_ns() if self._cpu_on else None
+        us = (now - self._t0) * 1e6
         if self._phase is not None:
-            self._marks.us[self._phase] += (now - self._t0) * 1e6
-            self._marks.compile_s += self._compiles.seconds - self._compile_s0
+            done = self._marks
+            done.us[self._phase] += us
+            if cpu is not None and self._phase in OFFCPU_PHASES:
+                done.offcpu_us[self._phase] = (
+                    done.offcpu_us.get(self._phase, 0.0)
+                    + self._offcpu_us(self._phase, us, cpu))
+            done.compile_s += self._compiles.seconds - self._compile_s0
+            done.gc_s += self._gcs.seconds - self._gc_s0
             self._open.pop().__exit__(None, None, None)
         elif phase is not None:
+            self._end_part(now)
+            if self._in_loop:
+                self._loop_us += us
+                if cpu is None:
+                    self._loop_offcpu_us = None
+                elif self._loop_offcpu_us is not None:
+                    self._loop_offcpu_us += self._offcpu_us("loop", us, cpu)
+                self._loop_gc_s += self._gcs.seconds - self._gc_s0
+                self._in_loop = False
             self._close()
             self._push("tick")
-        self._phase, self._marks, self._t0 = phase, marks, now
-        self._compile_s0 = self._compiles.seconds
+        self._phase, self._marks = phase, marks
+        self._t0, self._cpu0 = now, cpu
+        self._compile_s0, self._gc_s0 = (self._compiles.seconds,
+                                         self._gcs.seconds)
         if phase is not None:
             self._push("tick." + phase)
         return now
@@ -595,27 +766,53 @@ class TickClock:
         if not self._open:
             self._push("loop.admit")
 
+    def loop_part(self, name: str) -> None:
+        """The loop's statements from here to the next mark (or to the
+        tick's ``begin()``) are its part `name` of `LOOP_PARTS`: a
+        `loop.admit.<name>` annotation under `loop.admit`, and
+        `loop_<name>_us` on the span of the tick that follows."""
+        if self._phase is not None:
+            return
+        now = self._wall()
+        if self._part is not None:
+            self._open.pop().__exit__(None, None, None)
+            self._end_part(now)
+        self._part, self._part_t0 = name, now
+        self._push("loop.admit." + name)
+
     def idle(self) -> None:
         """The loop found nothing to dispatch, or the tick raised between
         its marks: close what is open and forget the ticks not ended;
         the next tick follows an idle lane, not a host gap."""
         self._close()
-        self._phase = self._marks = self._formed = None
+        self._phase = self._marks = self._formed = self._part = None
         self._flight.clear()
-        self._chained = False
-        self._idle_since = None
+        self._chained = self._in_loop = False
+        self._idle_since = self._begin_prev = None
+        self._reset_loop()
 
     def begin(self) -> None:
         self.seq += 1
-        self._formed = _TickMarks(self.seq)
-        self._enter("form", self._formed)
+        marks = self._formed = _TickMarks(self.seq)
+        marks.t_begin = now = self._enter("form", marks)
+        if self._chained or self._flight:
+            marks.loop = {"loop_us": round(self._loop_us, 1)}
+            if self._loop_offcpu_us is not None:
+                marks.loop["loop_offcpu_us"] = round(self._loop_offcpu_us, 1)
+            for part, us in self._loop_parts.items():
+                marks.loop[f"loop_{part}_us"] = round(us, 1)
+            if self._begin_prev is not None:
+                marks.loop["period_us"] = round(
+                    (now - self._begin_prev) * 1e6, 1)
+            marks.gc_s = self._loop_gc_s
+        self._reset_loop()
 
     def probe(self, ready: bool) -> None:
         """`ready`: the newest tick enqueued has finished on the device
         (its result's ``is_ready()``), so the device has had nothing
         queued since some moment before this one."""
         if ready and self._idle_since is None:
-            self._idle_since = time.perf_counter()
+            self._idle_since = self._wall()
 
     def dispatch(self, width: int, rows: int, ctx_tokens: int) -> None:
         """The batch is formed; the step executable is called next."""
@@ -639,15 +836,14 @@ class TickClock:
             return
         marks.overlapped = int(bool(self._flight))
         self._flight.append(marks)
+        self._begin_prev = marks.t_begin
         self._idle_since = None     # the device has this tick queued
 
     def leave(self) -> None:
         """The step is enqueued and the iteration has no tick to wait
         for: the loop goes on beside the device."""
         self._enqueued()
-        self._enter(None, None)
-        self._close()
-        self._push("loop.admit")
+        self._open_loop()
 
     def wait(self) -> None:
         """The step is enqueued (if the iteration formed one); the host
@@ -675,12 +871,13 @@ class TickClock:
         The duration is the sum of the tick's own four phases and the
         span ends now: beside a tick in flight, the other tick's `form`
         and `dispatch` lie between this one's `dispatch` and `wait`."""
-        now = self._enter(None, None)
-        self._close()
-        self._push("loop.admit")
+        now = self._open_loop()
         marks = self._flight.popleft()
         attrs = {f"{p}_us": round(marks.us[p], 1) for p in TICK_PHASES}
         dur_us = sum(marks.us.values())
+        for p, us in marks.offcpu_us.items():
+            attrs[f"{p}_offcpu_us"] = round(us, 1)
+        attrs.update(marks.loop)
         if marks.gap_us is not None:
             attrs["gap_us"] = marks.gap_us
         self._chained = live or bool(self._flight)
@@ -690,6 +887,7 @@ class TickClock:
         attrs["ctx_tokens"] = marks.ctx_tokens
         attrs.update(marks.notes)
         attrs["compile_us"] = int(marks.compile_s * 1e6)
+        attrs["gc_us"] = int(marks.gc_s * 1e6)
         attrs["seq"] = marks.seq
         self._say_if_slow(dur_us, attrs, node, now, marks.width, marks.seq)
         return time.time() - dur_us / 1e6, dur_us, attrs
@@ -703,11 +901,79 @@ class TickClock:
             median_us = statistics.median(recent)
             if dur_us > SLOW_TICK_FACTOR * median_us:
                 self._last_slow_line = now
-                said = " ".join(f"{k}={attrs[k]}" for k in
-                                (*(f"{p}_us" for p in TICK_PHASES), "gap_us",
-                                 "compile_us") if k in attrs)
+                said = " ".join(f"{k}={attrs[k]}" for k in SLOW_TICK_SAYS
+                                if k in attrs)
                 print(f"slow tick: node={node} seq={seq} "
                       f"width={width} duration_us={dur_us:.0f} "
                       f"median_us={median_us:.0f} {said}",
                       file=sys.stderr, flush=True)
         recent.append(dur_us)
+
+
+class StreamClock:
+    """One streamed request's token events on their way out, marked by
+    the handler's thread and summed (2,700 events a second on a full
+    lane would flood any ring): the sums ride the `generate_stream` span
+    the stream records once.
+
+    Per event, ``woke(item)`` when the stream queue's `get` has returned
+    it and ``delivered()`` when the generator is resumed after its
+    `yield`: `wake` = the scheduler's put (`item.t_put`) -> `woke`, how
+    long the tokens lay in the queue before this thread ran; `deliver`
+    = `woke` -> `delivered`, everything downstream of the lane (the
+    gateway's relay and journal, the chunk framing, the socket writes,
+    the flush). High `wake`: the lane starves its handlers; high
+    `deliver` less its CPU: a slow reader, a full socket, or the wait
+    for the interpreter lock. `deliver_cpu_us_sum`: this thread's CPU
+    time from its first event's `woke` to ``attrs()``, read twice a
+    STREAM and not twice an event (the thread's CPU clock is a system
+    call, `CPU_CLOCK_EVERY`): between two deliveries the thread is
+    blocked in the queue's `get`, so the difference is the deliveries'
+    CPU time and a few microseconds an event of waking up. Between
+    `woke` and `delivered` a ``TraceAnnotation("stream.deliver")`` is
+    open on the handler's line of the profiler's host plane, on the
+    device planes' clock."""
+
+    __slots__ = ("events", "wake_us_sum", "wake_us_max", "deliver_us_sum",
+                 "_wall", "_cpu_ns", "_annotation", "_open", "_t0", "_cpu0")
+
+    def __init__(self, wall=time.perf_counter, cpu_ns=time.thread_time_ns):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._wall, self._cpu_ns = wall, cpu_ns
+        self.events = 0
+        self.wake_us_sum = self.wake_us_max = self.deliver_us_sum = 0.0
+        self._open = None
+        self._t0, self._cpu0 = 0.0, 0
+
+    def woke(self, item) -> float:
+        """The stream queue's `get` returned `item`; the time it did."""
+        now = self._wall()
+        wake_us = max(0.0, now - getattr(item, "t_put", now)) * 1e6
+        if not self.events:
+            self._cpu0 = self._cpu_ns()
+        self.events += 1
+        self.wake_us_sum += wake_us
+        if wake_us > self.wake_us_max:
+            self.wake_us_max = wake_us
+        self._open = self._annotation("stream.deliver")
+        self._open.__enter__()
+        self._t0 = now
+        return now
+
+    def delivered(self) -> None:
+        now = self._wall()
+        self._open.__exit__(None, None, None)
+        self.deliver_us_sum += (now - self._t0) * 1e6
+
+    def attrs(self) -> dict:
+        """The span attrs of a stream that had a token event."""
+        if not self.events:
+            return {}
+        return {"events": self.events,
+                "wake_us_sum": round(self.wake_us_sum, 1),
+                "wake_us_max": round(self.wake_us_max, 1),
+                "deliver_us_sum": round(self.deliver_us_sum, 1),
+                "deliver_cpu_us_sum": round(
+                    (self._cpu_ns() - self._cpu0) / 1e3, 1)}
