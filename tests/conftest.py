@@ -83,7 +83,7 @@ def pytest_collection_modifyitems(session, config, items):
     # lists as they stood when its rehearsal file was last written. PR
     # 40's `sched.overlap_tick_share` lists all six cells, PR 39's too,
     # so ..._olmo_hybrid.py joins them; eight of PR 42's nine list all
-    # six. A `benchmark` PR adds the metrics to the rehearsal files,
+    # six, as does PR 43's `front.stream_writer_share`. A `benchmark` PR adds the metrics to the rehearsal files,
     # finds the cell by name and deletes this with the hook above
     # (PERF.md section 7).
     for name in ("test_benchmark_reference_moonlight",
@@ -105,7 +105,8 @@ class _AsTheCellWasWritten:
                       "sched.loop_ms", "sched.host_offcpu_ms",
                       "front.stream_cpu_ms_per_tick", "lane.stream_wake_ms",
                       "front.stream_deliver_ms", "device.idle_loop",
-                      "device.idle_stream", "step.gc_ms_per_s")  # PR 42
+                      "device.idle_stream", "step.gc_ms_per_s",  # PR 42
+                      "front.stream_writer_share")      # PR 43
 
     def __init__(self, json_module, cell):
         self._json, self._cell = json_module, cell

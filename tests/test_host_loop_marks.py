@@ -594,3 +594,104 @@ def test_a_stream_delta_is_a_list_on_the_wire_and_knows_its_put():
                            "deliver_us_sum": pytest.approx(1000.0, abs=1),
                            "deliver_cpu_us_sum": pytest.approx(500.0)}
     assert reads[0] == 2       # a stream, not an event: it is a system call
+
+
+def test_a_writer_s_pass_marks_a_stream_clock_without_reading_the_cpu():
+    """PR 43: the front's stream writer takes an event up (`woke(item,
+    driven=True)`), closes it when the pass's sends returned
+    (`delivered(t)`) and adds the event's share of its own thread's CPU
+    time; the generator's `delivered()` on its next resume then finds
+    nothing open. A stream handed back to its handler goes on as before,
+    and its thread's CPU time is added to the writer's share."""
+    delta = StreamDelta([7])
+    delta.t_put = 100.0
+    now, cpu, reads = [100.002], [5_000_000], [0]
+
+    def cpu_ns():
+        reads[0] += 1
+        return cpu[0]
+
+    way = StreamClock(wall=lambda: now[0], cpu_ns=cpu_ns)
+    assert way.woke(delta, driven=True) == pytest.approx(100.002)
+    assert way.delivered(100.0025) is True      # the sends' own clock
+    assert way.delivered() is False             # the generator, resumed
+    way.add_cpu(40.0)
+    assert reads[0] == 0
+    assert way.attrs() == {"events": 1,
+                           "wake_us_sum": pytest.approx(2000.0, abs=1),
+                           "wake_us_max": pytest.approx(2000.0, abs=1),
+                           "deliver_us_sum": pytest.approx(500.0, abs=1),
+                           "deliver_cpu_us_sum": pytest.approx(40.0)}
+    now[0] = 100.010
+    way.woke([8])                               # handed back: the handler's
+    now[0] += 0.001
+    cpu[0] += 300_000
+    assert way.delivered() is True
+    assert way.attrs()["events"] == 2
+    assert way.attrs()["deliver_us_sum"] == pytest.approx(1500.0, abs=1)
+    assert way.attrs()["deliver_cpu_us_sum"] == pytest.approx(340.0)
+
+
+def test_the_scheduler_wakes_a_driven_stream_s_writer_once_a_tick(lane):
+    """An outbox a writer is attached to hands `put` the writer's wake;
+    the loop calls it after a tick's last put, not once a row: two rows'
+    tokens of one tick are marked ready before the one wake."""
+    from tpu_engine.utils.streams import StreamOutbox
+
+    log = []
+    boxes = [StreamOutbox(), StreamOutbox()]
+
+    def wake():
+        log.append("wake")
+
+    for n, box in enumerate(boxes):
+        box.attach(lambda n=n: log.append(n) or wake)
+    futs = [lane.submit([5, 9, 3 + n], max_new_tokens=6, stream=box)
+            for n, box in enumerate(boxes)]
+    for fut in futs:
+        assert len(fut.result(timeout=120)) == 6
+    limit = time.monotonic() + 30
+    while log.count("wake") < 2 and time.monotonic() < limit:
+        time.sleep(0.01)
+    # Every mark is followed by a wake before the lane goes idle, and
+    # somewhere both rows' marks precede one wake.
+    assert log[-1] == "wake" and {0, 1} <= set(log)
+    runs = "".join("w" if x == "wake" else str(x) for x in log).split("w")
+    assert any({"0", "1"} <= set(run) for run in runs), log
+    for box in boxes:
+        items = []
+        while box.has_next() and not (items and items[-1] is None):
+            items.append(box.get(timeout=1))
+        assert items[-1] is None
+        assert sum(len(i) for i in items[:-1]) == 6
+
+
+def test_the_probe_before_the_dispatch_is_read_by_that_dispatch(lane,
+                                                               monkeypatch):
+    """`gap_us` is fixed where the clock marks the dispatch, from what the
+    probes saw until then. The scheduler asks whether the tick in flight
+    has finished at the form's start and again when the batch is formed:
+    the second probe must come BEFORE the dispatch mark, or a tick that
+    finished during the form reads as still running (gap 0 on a lane
+    whose host is the slower: PR 43 found the two the wrong way round,
+    hidden as long as the stream handlers' convoy kept the loop long)."""
+    calls = []
+    clock = lane._clock
+    probe, dispatch, begin = clock.probe, clock.dispatch, clock.begin
+    monkeypatch.setattr(clock, "begin",
+                        lambda: (calls.append("begin"), begin())[1])
+    monkeypatch.setattr(clock, "probe",
+                        lambda ready: (calls.append("probe"), probe(ready))[1])
+    monkeypatch.setattr(
+        clock, "dispatch",
+        lambda *a: (calls.append("dispatch"), dispatch(*a))[1])
+    assert len(lane.submit([4, 8, 15, 16], max_new_tokens=12)
+               .result(timeout=120)) == 12
+    _wait_idle(lane)
+    ticks = " ".join(calls).split("begin")
+    behind_a_tick = [t.split() for t in ticks if "probe" in t and
+                     "dispatch" in t]
+    assert len(behind_a_tick) >= 8
+    for tick in behind_a_tick:
+        at = tick.index("dispatch")
+        assert tick[:at].count("probe") == 2, tick

@@ -113,6 +113,11 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.tpu_front_reply2.argtypes = [ctypes.c_void_p, ctypes.c_int,
                                      ctypes.c_char_p, c_size,
                                      ctypes.c_char_p]
+    lib.tpu_send_each.restype = ctypes.c_double
+    lib.tpu_send_each.argtypes = [
+        ctypes.c_int, ctypes.POINTER(ctypes.c_int),
+        ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(c_size),
+        ctypes.POINTER(ctypes.c_long)]
     lib.tpu_json_encode_f32.restype = c_size
     lib.tpu_json_encode_f32.argtypes = [
         ctypes.c_void_p, c_size, ctypes.POINTER(ctypes.c_void_p)]
@@ -216,6 +221,26 @@ def json_encode_f32(arr) -> Optional[bytes]:
     if not out:
         return None  # allocation failure: let the Python path serve
     return _take_bytes(lib, out, length)
+
+
+def send_each(fds, bufs):
+    """One ``send`` a buffer, each to its socket's descriptor, in order
+    and without waiting, in ONE call that holds no interpreter lock
+    (``tpu_send_each``): ``(sent, t_done)``, where ``sent[i]`` is the
+    bytes socket i took (0: it would have blocked) or ``-errno``, and
+    `t_done` the ``time.perf_counter`` at which the last send returned.
+    Buffers of one socket must be adjacent: after one that was not taken
+    whole the socket's later ones are not offered. None when the native
+    core is absent: callers send from Python."""
+    lib = _try_load()
+    if lib is None:
+        return None
+    n = len(fds)
+    sent = (ctypes.c_long * n)()
+    t_done = lib.tpu_send_each(
+        n, (ctypes.c_int * n)(*fds), (ctypes.c_char_p * n)(*bufs),
+        (ctypes.c_size_t * n)(*map(len, bufs)), sent)
+    return list(sent), t_done
 
 
 class NativeLRUCache:

@@ -9,7 +9,11 @@
 #include <cstdlib>
 #include <cstring>
 
+#include <cerrno>
+#include <ctime>
+
 #include <locale.h>  // newlocale/uselocale (POSIX.1-2008)
+#include <sys/socket.h>
 
 #include "core.h"
 #include "http_front.h"
@@ -82,6 +86,45 @@ std::size_t tpu_json_encode_f32(const float* data, std::size_t n,
   uselocale(prior);
   *out = buf;
   return w;
+}
+
+// ----- one pass of sends ------------------------------------------------------
+
+// Hands bufs[i][0, lens[i]) to socket fds[i], i in order, each in ONE
+// send that never waits (MSG_DONTWAIT): the front's stream writer
+// (tpu_engine/serving/http.py) sends a scheduler tick's token events, one
+// a socket, in one call, and ctypes has released the interpreter lock for
+// all of it: between two sends nobody has to win the lock back from the
+// scheduler's thread. sent[i] is the number of bytes the socket took (0 if
+// it would have blocked, fewer than lens[i] if its buffer filled), or
+// -errno where the send failed (a reader that went away). Entries of one
+// socket are adjacent, and once one of them was not taken whole the
+// socket's later entries are not offered (sent 0): its bytes stay in
+// order. Returns CLOCK_MONOTONIC seconds (Python's time.perf_counter)
+// as the last send returned.
+double tpu_send_each(int n, const int* fds, const char* const* bufs,
+                     const std::size_t* lens, long* sent) {
+  for (int i = 0; i < n; ++i) {
+    if (i > 0 && fds[i] == fds[i - 1] &&
+        sent[i - 1] != static_cast<long>(lens[i - 1])) {
+      sent[i] = 0;
+      continue;
+    }
+    ssize_t took;
+    do {
+      took = ::send(fds[i], bufs[i], lens[i], MSG_DONTWAIT | MSG_NOSIGNAL);
+    } while (took < 0 && errno == EINTR);
+    if (took >= 0) {
+      sent[i] = static_cast<long>(took);
+    } else if (errno == EAGAIN || errno == EWOULDBLOCK) {
+      sent[i] = 0;
+    } else {
+      sent[i] = -static_cast<long>(errno);
+    }
+  }
+  timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return static_cast<double>(ts.tv_sec) + ts.tv_nsec * 1e-9;
 }
 
 // ----- LRU cache ------------------------------------------------------------

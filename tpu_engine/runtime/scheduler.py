@@ -105,6 +105,7 @@ from tpu_engine.utils.sampling import (
     expand_stopping_params,
     truncate_at_stops,
 )
+from tpu_engine.utils.streams import StreamCounts
 from tpu_engine.utils.tracing import (
     TickClock,
     compile_counter,
@@ -949,6 +950,12 @@ class ContinuousGenerator:
         self._compiles = compile_counter()
         self._gcs = gc_counter()
         self._clock = TickClock(self._compiles, self._gcs)
+        # Streams: who handed this lane's token events to their sockets
+        # (`stats()["stream"]`; an outbox carries it to the front), and
+        # the wakes of the fronts' writers that the puts since the last
+        # `_wake_streams` marked a stream ready with.
+        self.stream_counts = StreamCounts()
+        self._stream_wakes: set = set()
         # The mixed tick's pipeline, one tick deep (`_tick_mixed`).
         self._reset_flight()
         # Per-row prefill accounting for the `prefill` span: ticks that
@@ -2626,6 +2633,7 @@ class ContinuousGenerator:
                    prefix_cache=self._prefix_cache.stats(),
                    compile=self._compiles.snapshot(),
                    gc=self._gcs.snapshot(),
+                   stream=self.stream_counts.snapshot(),
                    weights=dict(self._weights))
         if self._mixed:
             # Snapshot, not the live nested dict — callers diff stats()
@@ -2959,7 +2967,9 @@ class ContinuousGenerator:
         if not req.future.done():
             req.future.set_exception(exc)
         if req.stream is not None:
-            req.stream.put(None)
+            wake = req.stream.put(None)
+            if wake is not None:
+                wake()  # any thread fails a request: no tick to wait for
 
     def _prefill_loop(self) -> None:
         """Prefill thread: drains submissions, runs each prompt's forward
@@ -4089,8 +4099,27 @@ class ContinuousGenerator:
         if len(vis) > req.streamed:
             fresh = StreamDelta(vis[req.streamed:])
             fresh.t_put = time.perf_counter()
-            req.stream.put(fresh)
+            self._stream_put(req, fresh)
             req.streamed = len(vis)
+
+    def _stream_put(self, req: _Request, item) -> None:
+        """Put `item` into the request's stream. A queue with a reader
+        blocked in `get` has told it; an outbox a front's writer drives
+        (`utils/streams.py`) hands back that writer's wake, which the
+        loop calls once it has put all of a tick's tokens
+        (`_wake_streams`), not once a row."""
+        wake = req.stream.put(item)
+        if wake is not None:
+            self._stream_wakes.add(wake)
+
+    def _wake_streams(self) -> None:
+        """Tell the writers that the puts since the last call marked a
+        stream ready with: once, at the end of a tick's `apply`, so that
+        one thread takes the tick's events up in one pass."""
+        if self._stream_wakes:
+            wakes, self._stream_wakes = self._stream_wakes, set()
+            for wake in wakes:
+                wake()
 
     def _row_ends(self, req: _Request, emitted_n: int, pos: int) -> bool:
         """Whether a row with `emitted_n` tokens out and its next write
@@ -4131,7 +4160,7 @@ class ContinuousGenerator:
                                tokens=len(toks))
             req.future.set_result(toks)
             if req.stream is not None:
-                req.stream.put(None)  # end of stream
+                self._stream_put(req, None)  # end of stream
             self._row_req[row] = None
             self._row_emitted[row] = []
             self._done[row] = True
@@ -4304,7 +4333,7 @@ class ContinuousGenerator:
                     batch_size=len(group))
             req.future.set_result((out, per_us))
             if req.stream is not None:
-                req.stream.put(None)
+                self._stream_put(req, None)
             if r is not None:
                 self._free_oneshot_row(r)
             st["completed"] += 1
@@ -4423,6 +4452,7 @@ class ContinuousGenerator:
             self._loop_body()
         finally:
             self._clock.idle()  # closes the loop's open annotation
+            self._wake_streams()
             # Exit (stop() sentinel, _running flip, or the loop body itself
             # raising): mark the scheduler dead FIRST so submit() fails fast
             # and the prefill thread's bounded put stops retrying, then fail
@@ -4905,10 +4935,12 @@ class ContinuousGenerator:
                       copied(self._temps), copied(self._topps),
                       copied(self._topks), copied(self._minps),
                       jnp.asarray(eos_vec))
+            if prev is not None:
+                # Before `_tick_formed` marks the dispatch, which reads
+                # what the probes saw.
+                self._clock.probe(prev.nxt.is_ready())
             self._tick_formed(width, prefill_rows, chunk, qlen, active,
                               pos0)
-            if prev is not None:
-                self._clock.probe(prev.nxt.is_ready())
             if controls:
                 out = self._mixed_step_exe(width, True)(
                     *common, self._ensure_counts(),
@@ -5022,6 +5054,7 @@ class ContinuousGenerator:
                 # steps it once more, as a done row.
                 m["lagged_rows"] += 1
 
+        self._wake_streams()  # one wake a tick, after the last row's put
         if behind is not None:
             self._clock.probe(behind.nxt.is_ready())
         self._tick_done(t.prefill_tokens, t.n_decode, t.width,
@@ -5496,6 +5529,9 @@ class ContinuousGenerator:
         while self._running:
             if marked:
                 self._clock.admit()
+            # The ticks that do not land through `_land_tick`, a row's
+            # first token at admission, an expired or failed row.
+            self._wake_streams()
             part("exports")
             now = time.monotonic()
             if self._flight_capacity:

@@ -90,6 +90,7 @@ from tpu_engine.serving.slo import (
     SloTracker,
     completion_hists,
 )
+from tpu_engine.utils.streams import relay
 from tpu_engine.utils.tracing import (
     SpanRecorder,
     TraceContext,
@@ -1138,7 +1139,9 @@ class Gateway:
             except Exception:
                 self._stream_fault_penalty(lane)
                 raise
-        return watched()
+        # A frame out for a frame in: a front's writer may drive it
+        # wherever it may drive `it`.
+        return relay(watched(), it)
 
     def _stream_fault_penalty(self, lane: Optional[str]) -> None:
         breaker = self.breaker_for(lane) if lane else None
@@ -2580,7 +2583,7 @@ class Gateway:
             finally:
                 with self._lock:
                     self._inflight -= 1
-        return watched()
+        return relay(watched(), it)
 
     def _overload_pressure(self) -> float:
         """Measured congestion in [0, inf): the in-flight gauge's fill

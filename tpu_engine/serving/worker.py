@@ -57,6 +57,12 @@ from tpu_engine.utils.deadline import (
 from tpu_engine.utils.sampling import clamp_top_k as _clamp_top_k
 from tpu_engine.utils.sampling import validate_min_p as _validate_min_p
 from tpu_engine.utils.sampling import expand_stopping_params
+from tpu_engine.utils.streams import (
+    STREAM_STALL_S,
+    UNREGISTERED,
+    EventStream,
+    StreamOutbox,
+)
 from tpu_engine.utils.tracing import (
     SpanRecorder,
     StreamClock,
@@ -2033,7 +2039,7 @@ class WorkerNode:
             self._maybe_slow()
             with self._counter_lock:
                 self._total_requests += 1
-            q: "queue.Queue" = queue.Queue()
+            q = self._stream_outbox()
             t0 = time.perf_counter()
             # Disaggregated handoff (gateway-stamped): park the row
             # after prefill for the export-after-prefill command; the
@@ -2103,7 +2109,7 @@ class WorkerNode:
             self._maybe_slow()
             with self._counter_lock:
                 self._total_requests += 1
-            q: "queue.Queue" = queue.Queue()
+            q = self._stream_outbox()
             t0 = time.perf_counter()
             # ValueError (malformed snapshot) raises HERE -> wire 400
             # before the 200 SSE stream commits.
@@ -2118,37 +2124,53 @@ class WorkerNode:
         return self._continuous_stream_events(
             q, fut, request_id, tctx, parent, t0, t_start_wall, t_admit)
 
+    def _stream_outbox(self) -> StreamOutbox:
+        """The queue a streamed request's tokens leave the lane by, with
+        the stream's clock and the lane's counts for whoever drives its
+        events out."""
+        return StreamOutbox(
+            StreamClock(), getattr(self.generator, "stream_counts", None))
+
     def _continuous_stream_events(self, q, fut, request_id, tctx, parent,
                                   t0, t_start_wall, t_admit):
         """The continuous-scheduler SSE event iterator, shared by fresh
-        submissions and migration imports. Owns the admission release."""
+        submissions and migration imports. Owns the admission release.
+        One `next` takes one item from `q` (the first `next` past the
+        end of the stream takes none), and it says so (`EventStream`):
+        a front's stream writer may then drive it, calling `next` only
+        when an item waits; anyone else iterates it as a generator."""
+        way, counts = q.clock, q.counts  # the token events' way out, summed
+
         def events():
             sent = 0  # tokens relayed to the client so far (resume offset)
             ttft_us = None  # receipt by the lane -> first token event out
-            way = StreamClock()  # the token events' way out, summed
             completed = False
             try:
                 while True:
                     try:
-                        item = q.get(timeout=600)
+                        item = q.get(timeout=STREAM_STALL_S)
                     except queue.Empty:
                         self._segment_span(request_id, tctx, parent, t0,
                                            t_start_wall, "stalled", way)
                         yield sse_event(self._stream_error(
                             RuntimeError("generation stalled (no tokens "
-                                         "for 600s)"),
+                                         f"for {STREAM_STALL_S:.0f}s)"),
                             request_id, tctx.trace_id, sent))
                         return
                     if item is None:
                         break
                     sent += len(item)
-                    t_woke = way.woke(item)
+                    t_woke = way.woke(item, q.driven)
                     if ttft_us is None:
                         ttft_us = int((t_woke - t_admit) * 1e6)
                     try:
                         yield sse_event({"tokens": item})
                     finally:
-                        way.delivered()
+                        # A writer's pass closes (and counts) what it
+                        # sends; what is still open here went out on
+                        # the thread that iterates.
+                        if way.delivered() and not q.driven:
+                            counts.handler_event(q.handback or UNREGISTERED)
                 elapsed_us = int((time.perf_counter() - t0) * 1e6)
                 try:
                     tokens = fut.result(timeout=10)
@@ -2180,7 +2202,7 @@ class WorkerNode:
                 # see the latency it exists to react to.
                 if completed and self._aimd is not None:
                     self._aimd.observe(time.perf_counter() - t_admit)
-        return events()
+        return EventStream(events(), q)
 
     def _segment_span(self, request_id, tctx, parent, t0, t_start_wall,
                       outcome: str, way: StreamClock) -> None:

@@ -42,8 +42,9 @@ request. This module now carries a real tracing subsystem:
   seconds, process-wide, from ``jax.monitoring``.
 - `GcCounter` / `gc_counter()` — the interpreter's garbage collections
   and their pause seconds, process-wide, from ``gc.callbacks``.
-- `StreamClock` — a streamed request's token events on the handler's
-  thread: queue wake-up and delivery, summed onto its request span.
+- `StreamClock` — a streamed request's token events on their way out,
+  by the front's stream writer or the handler's thread: wake-up and
+  delivery, summed onto its request span.
 """
 
 from __future__ import annotations
@@ -912,30 +913,38 @@ class TickClock:
 
 class StreamClock:
     """One streamed request's token events on their way out, marked by
-    the handler's thread and summed (2,700 events a second on a full
+    whoever drives them and summed (2,700 events a second on a full
     lane would flood any ring): the sums ride the `generate_stream` span
     the stream records once.
 
-    Per event, ``woke(item)`` when the stream queue's `get` has returned
-    it and ``delivered()`` when the generator is resumed after its
-    `yield`: `wake` = the scheduler's put (`item.t_put`) -> `woke`, how
-    long the tokens lay in the queue before this thread ran; `deliver`
-    = `woke` -> `delivered`, everything downstream of the lane (the
-    gateway's relay and journal, the chunk framing, the socket writes,
-    the flush). High `wake`: the lane starves its handlers; high
-    `deliver` less its CPU: a slow reader, a full socket, or the wait
-    for the interpreter lock. `deliver_cpu_us_sum`: this thread's CPU
-    time from its first event's `woke` to ``attrs()``, read twice a
-    STREAM and not twice an event (the thread's CPU clock is a system
-    call, `CPU_CLOCK_EVERY`): between two deliveries the thread is
-    blocked in the queue's `get`, so the difference is the deliveries'
-    CPU time and a few microseconds an event of waking up. Between
-    `woke` and `delivered` a ``TraceAnnotation("stream.deliver")`` is
-    open on the handler's line of the profiler's host plane, on the
-    device planes' clock."""
+    Per event, ``woke(item)`` when the stream's outbox has handed it to
+    the event iterator and ``delivered()`` when its bytes were handed to
+    the socket: `wake` = the scheduler's put (`item.t_put`) -> `woke`,
+    how long the tokens lay there before a thread took them up;
+    `deliver` = `woke` -> `delivered`, everything downstream of the lane
+    (the gateway's relay and journal, the chunk framing, the send). High
+    `wake`: the lane starves whoever delivers; high `deliver` less its
+    CPU: a slow reader, a full socket, or the wait for the interpreter
+    lock.
+
+    Two drivers mark it. The front's stream writer (``serving/http.py``)
+    takes a tick's events up in one pass: `woke(item, driven=True)` as
+    its `next` reaches each, one `delivered(now)` a stream when the
+    pass's bytes are out, `add_cpu` for the event's share of the
+    writer thread's CPU time, and ONE ``TraceAnnotation("stream.
+    deliver")`` a pass on the writer's line of the profiler's host
+    plane. A handler thread that iterates the stream itself marks as
+    PR 42 had it: `delivered()` when the generator is resumed after its
+    `yield`, the annotation an event on the handler's line, and the
+    thread's CPU time from its first event's `woke` to ``attrs()``, read
+    twice a STREAM and not twice an event (the thread's CPU clock is a
+    system call, `CPU_CLOCK_EVERY`): between two deliveries the thread
+    is blocked in the outbox's `get`, so the difference is the
+    deliveries' CPU time and a few microseconds an event of waking up."""
 
     __slots__ = ("events", "wake_us_sum", "wake_us_max", "deliver_us_sum",
-                 "_wall", "_cpu_ns", "_annotation", "_open", "_t0", "_cpu0")
+                 "_wall", "_cpu_ns", "_annotation", "_open", "_t0", "_cpu0",
+                 "_cpu_us")
 
     def __init__(self, wall=time.perf_counter, cpu_ns=time.thread_time_ns):
         from jax.profiler import TraceAnnotation
@@ -945,35 +954,55 @@ class StreamClock:
         self.events = 0
         self.wake_us_sum = self.wake_us_max = self.deliver_us_sum = 0.0
         self._open = None
-        self._t0, self._cpu0 = 0.0, 0
+        self._t0, self._cpu0, self._cpu_us = None, None, 0.0
 
-    def woke(self, item) -> float:
-        """The stream queue's `get` returned `item`; the time it did."""
+    def woke(self, item, driven: bool = False) -> float:
+        """The outbox's `get` returned `item`; the time it did.
+        `driven`: in a writer's pass, which holds the annotation and
+        apportions its thread's CPU time itself."""
         now = self._wall()
         wake_us = max(0.0, now - getattr(item, "t_put", now)) * 1e6
-        if not self.events:
-            self._cpu0 = self._cpu_ns()
         self.events += 1
         self.wake_us_sum += wake_us
         if wake_us > self.wake_us_max:
             self.wake_us_max = wake_us
-        self._open = self._annotation("stream.deliver")
-        self._open.__enter__()
+        if not driven:
+            if self._cpu0 is None:
+                self._cpu0 = self._cpu_ns()
+            self._open = self._annotation("stream.deliver")
+            self._open.__enter__()
         self._t0 = now
         return now
 
-    def delivered(self) -> None:
-        now = self._wall()
-        self._open.__exit__(None, None, None)
-        self.deliver_us_sum += (now - self._t0) * 1e6
+    def delivered(self, now: Optional[float] = None) -> bool:
+        """The open event's bytes were handed to the socket (at `now`,
+        if the caller read the clock). False if no event was open: its
+        other driver had closed it."""
+        if self._t0 is None:
+            return False
+        if now is None:
+            now = self._wall()
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+        self.deliver_us_sum += max(0.0, now - self._t0) * 1e6
+        self._t0 = None
+        return True
+
+    def add_cpu(self, cpu_us: float) -> None:
+        """A writer's share of its thread's CPU time for this stream's
+        events."""
+        self._cpu_us += cpu_us
 
     def attrs(self) -> dict:
         """The span attrs of a stream that had a token event."""
         if not self.events:
             return {}
+        cpu_us = self._cpu_us
+        if self._cpu0 is not None:
+            cpu_us += (self._cpu_ns() - self._cpu0) / 1e3
         return {"events": self.events,
                 "wake_us_sum": round(self.wake_us_sum, 1),
                 "wake_us_max": round(self.wake_us_max, 1),
                 "deliver_us_sum": round(self.deliver_us_sum, 1),
-                "deliver_cpu_us_sum": round(
-                    (self._cpu_ns() - self._cpu0) / 1e3, 1)}
+                "deliver_cpu_us_sum": round(cpu_us, 1)}
