@@ -65,7 +65,11 @@ def test_the_latent_read_compiles_for_v5e_at_both_widths(v5e_devices):
         for case in kernel_check.kernel_cases(model, interpret=False):
             kernel_check.compile_for_topology(case, v5e_devices[0])
             names.append(case.name)
-    assert names == ["moonlight/latent/W1", "moonlight/latent/W256"]
+    # 16 heads, and 32 in a model whose other layers are recurrent: both
+    # forms of its channel-gated recurrence compile beside the read.
+    assert names == ["moonlight/latent/W1", "moonlight/latent/W256",
+                     "kimi_linear/latent/W1", "kimi_linear/latent/W256",
+                     "kimi_linear/kda_step/B128", "kimi_linear/kda_chunk/T256"]
 
 
 def _pallas_grids(jaxpr):
@@ -581,6 +585,92 @@ def test_hybrid_mixed_step_copies_neither_the_pool_nor_the_states(v5e_devices,
     analysis = compiled.memory_analysis()
     assert analysis.temp_size_in_bytes < 0.3e9
     assert analysis.alias_size_in_bytes > 6.8e9      # both pools in place
+
+
+@pytest.mark.parametrize("width", [1, 256])
+def test_latent_and_state_mixed_step_copies_no_pool_state_or_bank(v5e_devices,
+                                                                  width):
+    """The Kimi-Linear cell's mixed step at its serving shapes (shapes
+    only: 128 rows, a latent pool of 90,113 blocks, a state pool of 129
+    rows, 128 of 256 experts held), both pools donated, compiled for one
+    v5e: the MLA layer's read is the latent kernel at 32 heads
+    (`mla_latent_read`), both forms of the channel-gated recurrence are
+    Pallas calls that change the state pool where it lies (`kda_step`,
+    `kda_chunk`: at 128 key lanes a state is whole lane tiles) and the
+    scalar gate's names are not in it; no `copy`, `slice` or
+    `dynamic-slice` whose result is a pool, a state array, an expert bank
+    or a layer of one, and a `dynamic-update-slice` of that size only as a
+    chunk row's write of its conv tail into its own state row; the
+    temporaries stay under 1 GB beside 11.6 GB of weights and pools."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.kimi_linear import kimi_linear_step_rows_ragged
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops.gated_delta import gdn_chunk_row, gdn_step_rows
+    from tpu_engine.ops.latent_attention import latent_attention
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "kimi-linear-48b-a3b-5l.json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    assert width in (1, serving["gen_prefill_chunk"])
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg = spec.config
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    (kind,) = cfg.kv_block_kinds
+    assert (kind.n_layers, kind.kv_lanes) == (1, (128, 512))
+    pools = (KVCache(*(placed(jax.ShapeDtypeStruct(
+                 (kind.n_layers, serving["gen_kv_blocks"], bs, lanes),
+                 jnp.bfloat16)) for lanes in kind.kv_lanes)),
+             tuple(placed(jax.ShapeDtypeStruct(
+                 (cfg.n_linear_layers, rows + 1) + shape, jnp.float32))
+                 for shape in cfg.state_row_shapes))
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return kimi_linear_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=functools.partial(latent_attention, interpret=False),
+            step_fn=functools.partial(gdn_step_rows, interpret=False),
+            chunk_fn=functools.partial(gdn_chunk_row, interpret=False),
+            sample_slot=jnp.zeros_like(pos0), held=spec.held,
+            max_tokens=serving["gen_prefill_chunk"] + rows)
+
+    def host(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    step, behind = _behind_a_step(tick, host(rows))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, (host(rows, -(-cfg.max_seq // bs)), host(rows)),
+        host(rows, width), host(rows), host(rows), *behind).compile()
+    hlo = compiled.as_text()
+    assert "mla_latent_read" in hlo and "kda_step" in hlo
+    assert ("kda_chunk" in hlo) == (width > 1)
+    assert '"gdn_step"' not in hlo and '"gdn_chunk"' not in hlo
+    # A bank whole; a pool or a state array whole or a layer of it (ONE
+    # expert's 2304 x 2048 is as many numbers as 128 rows' conv tails).
+    banks = [bp["mlp"]["experts"] for bp in params["layers"][1:]]
+    sizes = {math.prod(x.shape) for x in jax.tree.leaves(banks)}
+    for x in list(pools[0]) + list(pools[1]):
+        sizes |= {math.prod(x.shape), math.prod(x.shape[1:])}
+    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
+                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
+    moved = {op for dims, op in movers.findall(hlo)
+             if math.prod(map(int, dims.split(","))) in sizes}
+    assert moved <= ({"dynamic-update-slice"} if width > 1 else set()), moved
+    analysis = compiled.memory_analysis()
+    assert analysis.temp_size_in_bytes < 1.0e9
+    assert analysis.alias_size_in_bytes > 2.9e9      # both pools in place
 
 
 def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):

@@ -374,8 +374,8 @@ def test_a_tick_ahead_keeps_each_tick_s_own_four_phases():
     its own form and dispatch from the iteration before."""
     clock = TickClock(CompileCounter())
     clock.begin()                                  # tick 1
-    time.sleep(0.004)
-    clock.note(sampler="greedy")
+    time.sleep(0.02)       # ten times tick 2's: a loaded machine's sleep
+    clock.note(sampler="greedy")                   # overshoots by ms
     clock.dispatch(width=16, rows=1, ctx_tokens=16)
     clock.leave()
     clock.admit()
@@ -391,7 +391,7 @@ def test_a_tick_ahead_keeps_each_tick_s_own_four_phases():
     _, dur1, one = clock.end(True, "n")
     assert (one["seq"], one["overlapped"], one["ctx_tokens"]) == (1, 0, 16)
     assert one["sampler"] == "greedy" and one["moe_assignments"] == 7
-    assert one["form_us"] >= 4000 and one["wait_us"] >= 3000
+    assert one["form_us"] >= 20000 and one["wait_us"] >= 3000
     assert "gap_us" not in one                     # after an idle lane
     assert dur1 == pytest.approx(sum(one[k] for k in PHASE_KEYS), abs=1)
     clock.wait()                                   # tick 2 landed alone
@@ -399,7 +399,7 @@ def test_a_tick_ahead_keeps_each_tick_s_own_four_phases():
     _, dur2, two = clock.end(False, "n")
     assert (two["seq"], two["overlapped"], two["ctx_tokens"]) == (2, 1, 17)
     assert two["sampler"] == "plain" and "moe_assignments" not in two
-    assert 2000 <= two["form_us"] < 4000           # its own form, not 1's
+    assert 2000 <= two["form_us"] < 20000          # its own form, not 1's
     assert two["gap_us"] == 0.0                    # enqueued behind tick 1
     assert dur2 == pytest.approx(sum(two[k] for k in PHASE_KEYS), abs=1)
     # A probe that sees the tick in flight finished dates the device's

@@ -202,7 +202,10 @@ def moonlight_init(key, cfg: MoonlightConfig):
 def _attn_inputs(ap, x, positions, cfg: MoonlightConfig, dtype):
     """x: (B, S, d) normalised. Returns q_nope (B, S, H, nope), q_pe
     (B, S, H, rope) rotated, c (B, S, C) normalised, k_pe (B, S, rope)
-    rotated — c and k_pe as the cache holds them, in `dtype`."""
+    rotated — c and k_pe as the cache holds them, in `dtype`.
+    `positions=None`: a model that rotates nothing (`models.kimi_linear`,
+    `mla_use_nope`); the 64 lanes are then plain key lanes the heads
+    share."""
     b, s, _ = x.shape
     q = nn.dense(ap["wq"], x, dtype=dtype).astype(dtype)
     q = q.reshape(b, s, cfg.n_heads, cfg.d_head)
@@ -211,9 +214,10 @@ def _attn_inputs(ap, x, positions, cfg: MoonlightConfig, dtype):
     c = nn.rmsnorm(ap["kv_norm"], kv_a[..., :cfg.kv_lora_rank],
                    eps=cfg.kv_norm_eps).astype(dtype)
     k_pe = kv_a[..., cfg.kv_lora_rank:].astype(dtype)[:, :, None, :]
-    q_pe = rope(q_pe, positions, cfg.rope_theta)
-    k_pe = rope(k_pe, positions, cfg.rope_theta)[:, :, 0]
-    return q_nope, q_pe, c, k_pe
+    if positions is not None:
+        q_pe = rope(q_pe, positions, cfg.rope_theta)
+        k_pe = rope(k_pe, positions, cfg.rope_theta)
+    return q_nope, q_pe, c, k_pe[:, :, 0]
 
 
 def _kv_b_halves(ap, cfg: MoonlightConfig, dtype):

@@ -87,11 +87,14 @@ class OlmoHybridConfig(TransformerConfig):
     neg_eigval: bool = True
     param_dtype: str = "bfloat16"
 
-    # The registry derives family and TP rule from these two.
+    # The registry derives family and TP rule from these two; the
+    # scheduler names a tick's recurrent work by the third (the kernels'
+    # names in a trace).
     serving_state_family = "kv_and_state"
     tp_partition_rule = ("unshardable: a row's recurrent state and conv "
                          "tail are one state row a layer, which no shard "
                          "map over heads carries yet")
+    recurrence = "gdn"
 
     def __post_init__(self):
         if len(self.linear) != self.n_layers:
@@ -207,7 +210,7 @@ def _lin_inputs(lp, x, cfg: OlmoHybridConfig, dtype):
     return mixed, z, g, beta * (2.0 if cfg.neg_eigval else 1.0)
 
 
-def _conv_heads(lp, ext, cfg: OlmoHybridConfig):
+def _conv_heads(lp, ext, cfg):
     """ext: (..., T + width - 1, lanes): a run's inputs behind its conv
     tail. The causal depthwise conv, SiLU, then q and k (..., T, H, d_k),
     L2-normalised a head and q scaled, and v (..., T, H, d_v)."""
@@ -292,23 +295,30 @@ def olmo_hybrid_apply(params, tokens, cfg: OlmoHybridConfig, *,
 # -- the served step: the mixed tick over the block pool and the state pool -------
 
 def _linear_rows(lp, x, state, at: int, start, rows, pos0, qlen, width: int,
-                 cfg: OlmoHybridConfig, dtype, step_fn, chunk_fn):
+                 cfg, dtype, step_fn, chunk_fn, inputs=None, output=None):
     """A linear layer over the tick's token list. x: (M, d), row b's new
     tokens at [start[b], start[b] + qlen[b]); state: the state pool's
-    (S (L_lin, R, H, d_v, d_k), conv tails (L_lin, R, width - 1, lanes)),
-    row b's at `rows[b]` of layer `at`. A row with ONE new token goes
-    through `gdn_step_rows`, all such rows at once; a row with more through
+    (S (L_lin, R, H, d_v, d_k), conv tails (L_lin, R, width - 1, lanes) or
+    as many numbers a row in another shape), row b's at `rows[b]` of layer
+    `at`. A row with ONE new token goes through `gdn_step_rows`, all such rows at once; a row with more through
     `gdn_chunk_row`, a row at a time, from the state its last tick left
     (zero where the row starts at position 0). Returns (the mixer's
-    output (M, d), state)."""
+    output (M, d), state).
+
+    `inputs`, `output`: this family's `_lin_inputs` and `_lin_output`, or
+    another's of their signatures whose `cfg` has the same `lin_*` and
+    `conv_width` fields (`models.kimi_linear`: a gate a key channel, g
+    (M, H, d_k), which `step_fn` and `chunk_fn` tell by its rank)."""
     m = x.shape[0]
     s_pool, c_pool = state
-    mixed, z, g, beta = _lin_inputs(lp, x, cfg, dtype)
+    mixed, z, g, beta = (inputs or _lin_inputs)(lp, x, cfg, dtype)
     fresh = pos0 == 0
 
     # Rows that decode (or prefill a single token): one step each.
     first = jnp.minimum(start, m - 1)
-    tail_old = c_pool[at, rows]
+    # A pool may keep a row's tail in another shape of as many numbers.
+    tail_shape = (cfg.conv_width - 1, mixed.shape[-1])
+    tail_old = c_pool[at, rows].reshape((-1,) + tail_shape)
     ext = jnp.concatenate(
         [jnp.where(fresh[:, None, None], 0.0, tail_old),
          mixed[first][:, None]], axis=1)
@@ -317,7 +327,8 @@ def _linear_rows(lp, x, state, at: int, start, rows, pos0, qlen, width: int,
     o, s_pool = step_fn(q[:, 0], k[:, 0], v[:, 0], g[first], beta[first],
                         s_pool, at, rows, steps, fresh)
     c_pool = c_pool.at[at, rows].set(
-        jnp.where(steps[:, None, None], ext[:, 1:], tail_old))
+        jnp.where(steps[:, None, None], ext[:, 1:], tail_old)
+        .reshape((-1,) + c_pool.shape[2:]))
     # One padded run behind the list: a run's slice never clamps, and a
     # row that took no step writes there.
     run = _pad_run(width)
@@ -325,8 +336,8 @@ def _linear_rows(lp, x, state, at: int, start, rows, pos0, qlen, width: int,
     o_all = o_all.at[jnp.where(steps, first, m)].set(o)
 
     if width > 1:
-        behind = ((0, run), (0, 0))
-        mixed, g, beta = (jnp.pad(y, behind) for y in (mixed, g, beta))
+        mixed, g, beta = (jnp.pad(y, ((0, run),) + ((0, 0),) * (y.ndim - 1))
+                          for y in (mixed, g, beta))
         chunks = qlen > 1
         order = jnp.argsort(~chunks, stable=True)
 
@@ -340,22 +351,28 @@ def _linear_rows(lp, x, state, at: int, start, rows, pos0, qlen, width: int,
                 return jax.lax.dynamic_slice_in_dim(y, off, run)
 
             ext = jnp.concatenate(
-                [jnp.where(fresh[b], 0.0, c_pool[at, r]), run_of(mixed)])
+                [jnp.where(fresh[b], 0.0, c_pool[at, r].reshape(tail_shape)),
+                 run_of(mixed)])
             q, k, v = _conv_heads(lp, ext, cfg)
             # Past the row's last new token nothing decays and nothing
             # is written.
+            g_run = run_of(g)
             o, s_pool = chunk_fn(
-                q, k, v, jnp.where(valid, run_of(g), 0.0),
+                q, k, v,
+                jnp.where(valid.reshape((run,) + (1,) * (g_run.ndim - 1)),
+                          g_run, 0.0),
                 jnp.where(valid, run_of(beta), 0.0), s_pool, at, r,
                 fresh[b])
             tail = jax.lax.dynamic_slice_in_dim(ext, n, cfg.conv_width - 1)
             o = jnp.where(valid[:, :, None], o, run_of(o_all))
-            return (s_pool, c_pool.at[at, r].set(tail),
+            return (s_pool,
+                    c_pool.at[at, r].set(tail.reshape(c_pool.shape[2:])),
                     jax.lax.dynamic_update_slice_in_dim(o_all, o, off, 0))
 
         s_pool, c_pool, o_all = jax.lax.fori_loop(
             0, chunks.sum(), one_row, (s_pool, c_pool, o_all))
-    return _lin_output(lp, o_all[:m], z, cfg, dtype), (s_pool, c_pool)
+    return ((output or _lin_output)(lp, o_all[:m], z, cfg, dtype),
+            (s_pool, c_pool))
 
 
 def olmo_hybrid_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,

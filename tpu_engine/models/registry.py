@@ -40,14 +40,19 @@ from typing import Callable, Dict, List, Optional, Tuple
 # prefix hit, be demoted to no host tier and ride no chain, so prefix
 # sharing, the host tier, migration and handoff are ABSENT, with int8
 # (the window read takes no scales), `--tp` and speculative verify.
-# "kv_and_state": blocks for some layers and a state row for the others
-# (models.olmo_hybrid): a row holds a kv_paged chain over the block pool,
-# which holds the full-attention layers alone, AND one row of a state pool
-# (the recurrent layers' fixed-size state and conv tail), admitted, parked
-# and released as one. Served by the mixed tick alone. A recurrent state
-# is not block-addressable, cannot be rolled back past a rejected draft
-# and rides no chain, so prefix sharing, the host tier, int8 payloads,
-# speculative verify, `--tp`, migration and handoff are ABSENT.
+# "kv_and_state": blocks for some layers and a state row for the others:
+# a row holds a chain over the block pool, which holds the attention layers
+# alone, AND one row of a state pool (the recurrent layers' fixed-size
+# state and conv tail), admitted, parked and released as one. WHAT a block
+# holds is the model's to state (`cfg.kv_lanes`, through
+# `cfg.kv_block_kinds`) and no part of the family: K and V a head
+# (models.olmo_hybrid) or a latent and its shared key lanes
+# (models.kimi_linear), under the same admission, parking and release.
+# Served by the mixed tick alone. A recurrent state is not
+# block-addressable, cannot be rolled back past a rejected draft and rides
+# no chain, so prefix sharing, the host tier, int8 payloads, speculative
+# verify, `--tp`, migration and handoff are ABSENT, whatever the blocks
+# hold.
 FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
     "kv_paged": ("generate", "two_path", "mixed_step", "spec_decode",
                  "paged_kv", "prefix_sharing", "kv_quantize",
@@ -341,6 +346,6 @@ def _ensure_builtin_models_imported():
     from tpu_engine.models import mlp, resnet  # noqa: F401
 
     for optional in ("bert", "gpt2", "llama", "yolo", "ssd", "moonlight",
-                     "laguna", "olmo_hybrid"):
+                     "laguna", "olmo_hybrid", "kimi_linear"):
         if importlib.util.find_spec(f"tpu_engine.models.{optional}") is not None:
             importlib.import_module(f"tpu_engine.models.{optional}")

@@ -118,13 +118,25 @@ def index_in_kind(kinds) -> Tuple[int, ...]:
     return tuple(out)
 
 
+@dataclasses.dataclass(frozen=True)
+class KVKindConfig(TransformerConfig):
+    """One kind's layers of a model, as a block pool is sized by them."""
+    lanes: Tuple[int, int] = (0, 0)
+
+    @property
+    def kv_lanes(self) -> Tuple[int, int]:
+        return self.lanes
+
+
 def kv_kind_config(cfg: TransformerConfig, n_layers: int) -> TransformerConfig:
     """What a block pool that holds `n_layers` of `cfg`'s layers is sized by
-    (`runtime.kv_blocks.BlockPool` reads layers, KV heads and head width):
-    one kind's layers alone."""
-    return TransformerConfig(
+    (`runtime.kv_blocks.BlockPool` reads layers, KV heads, head width and
+    the lanes a token takes, which are what `cfg` STATES: K and V a head,
+    or a latent and its shared key lanes): one kind's layers alone."""
+    return KVKindConfig(
         n_layers=n_layers, d_model=cfg.d_model, n_heads=cfg.n_heads,
-        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim)
+        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+        lanes=tuple(cfg.kv_lanes))
 
 
 def _norm_init(cfg: TransformerConfig):

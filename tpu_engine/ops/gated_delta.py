@@ -49,6 +49,27 @@ step, solves each sub-chunk's triangular system by forward substitution
 (A handed over transposed, so its row is a lane slice) and carries the
 state through the sub-chunks' products in VMEM.
 
+**A gate a key CHANNEL** (Kimi Delta Attention, arXiv:2510.26692). Every
+form takes `g` a head, (..., H), or a key channel, (..., H, d_k): the decay
+is then Diag(a_t) on the state's d_k lanes,
+
+    S_t = S_{t-1} Diag(a_t) + b_t (v_t - S_{t-1} Diag(a_t) k_t) k_t^T
+
+and the two coincide where a channel gate is the same in every channel.
+The step is the same multiplies with the decay row no longer constant. In
+the chunked form the decays no longer factor out of k_i.k_j: with
+Gamma_i = exp(G_i) a vector, A_ij = b_i sum_c k_ic k_jc exp(G_ic - G_jc).
+The textbook split k_i Gamma_i . k_j / Gamma_j overflows (a channel whose
+gate is -5 a token underflows Gamma within 18 tokens), so
+`_kda_chunk_terms` takes every difference BEFORE the exponential: inside a
+diagonal block of `KDA_BLOCK` tokens as it stands, and between a block and
+the earlier ones of its sub-chunk through the block's start, whose two
+factors exp(G_i - G_start) and exp(G_start - G_j) are both at most 1. What
+meets the state (exp(G) q, b exp(G) k, exp(G_C - G) k, exp(G_C)) never
+needed a quotient. The pass over the sub-chunks is then the scalar gate's,
+with exp(G_C) a lane vector: the same kernel bodies, named `kda_step` and
+`kda_chunk` in a trace where the gate is a channel's.
+
 Everything here is float32; the products take `PRECISION` (the MXU's
 float32 passes), since a state error is carried for the rest of the row.
 """
@@ -64,18 +85,27 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.scipy.linalg import solve_triangular
 
 SUB_CHUNK = 64
+# A channel gate's diagonal blocks inside a sub-chunk (module docstring).
+KDA_BLOCK = 16
 PRECISION = jax.lax.Precision.HIGHEST
 # State a grid step of the step kernel holds (in and out, double-buffered:
 # four of these in VMEM): 15 of Olmo-Hybrid-7B's 30 heads, 1.47 MB.
 _STEP_BLOCK_BYTES = 3 << 19
 
 
+def _over_state(g, k):
+    """A gate as it meets a state's (..., d_v, d_k): a head's number, or
+    (where `g` has `k`'s rank) a key channel's vector on the lanes."""
+    return g[..., None, :] if g.ndim == k.ndim else g[..., None, None]
+
+
 def gdn_scan(q, k, v, g, beta, state):
     """The recurrence, token by token. q, k: (T, H, d_k); v: (T, H, d_v);
-    g, beta: (T, H); state: (H, d_v, d_k). Returns (o (T, H, d_v), state)."""
+    g: (T, H) or (T, H, d_k); beta: (T, H); state: (H, d_v, d_k). Returns
+    (o (T, H, d_v), state)."""
     def step(s, x):
         q_t, k_t, v_t, g_t, b_t = x
-        s = s * jnp.exp(g_t)[:, None, None]
+        s = s * jnp.exp(_over_state(g_t, k_t))
         u = b_t[:, None] * (v_t - (s * k_t[:, None, :]).sum(-1))
         s = s + u[:, :, None] * k_t[:, None, :]
         return s, (s * q_t[:, None, :]).sum(-1)
@@ -87,11 +117,11 @@ def gdn_scan(q, k, v, g, beta, state):
 
 
 def gdn_step(q, k, v, g, beta, state):
-    """One token a row. q, k: (B, H, d_k); v: (B, H, d_v); g, beta: (B, H);
-    state: (B, H, d_v, d_k) float32. Returns (o (B, H, d_v), state). Plain
-    multiplies and sums over the state's lanes: one pass over the state for
-    S k, one for the update and S q."""
-    s = state * jnp.exp(g)[..., None, None]
+    """One token a row. q, k: (B, H, d_k); v: (B, H, d_v); g: (B, H) or
+    (B, H, d_k); beta: (B, H); state: (B, H, d_v, d_k) float32. Returns
+    (o (B, H, d_v), state). Plain multiplies and sums over the state's
+    lanes: one pass over the state for S k, one for the update and S q."""
+    s = state * jnp.exp(_over_state(g, k))
     u = beta[..., None] * (v - (s * k[..., None, :]).sum(-1))
     s = s + u[..., None] * k[..., None, :]
     return (s * q[..., None, :]).sum(-1), s
@@ -139,10 +169,14 @@ def _step_call(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
                 and (p == 1 or p * dv * lanes * 4 <= _STEP_BLOCK_BYTES))
     blocks = h // heads
     a = jnp.exp(g)
+    channel = g.ndim == k.ndim
     # Rows of d_k lanes a head: k, q, the decay, and b a k (S k is wanted
     # as b a S k); the heads of a block side by side.
-    vec = jnp.stack([k, q, jnp.broadcast_to(a[..., None], k.shape),
-                     (beta * a)[..., None] * k], axis=1)
+    if channel:
+        vec = jnp.stack([k, q, a, beta[..., None] * a * k], axis=1)
+    else:
+        vec = jnp.stack([k, q, jnp.broadcast_to(a[..., None], k.shape),
+                         (beta * a)[..., None] * k], axis=1)
     vec = vec.reshape(b, 4, blocks, heads, dk).transpose(0, 2, 1, 3, 4)
     # b v as columns: a block's heads on the lanes.
     bv = (beta[..., None] * v).reshape(b, blocks, heads, dv)
@@ -172,7 +206,7 @@ def _step_call(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
         input_output_aliases={6: 0},          # the pool, in place
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret, name="gdn_step",
+        interpret=interpret, name="kda_step" if channel else "gdn_step",
     )(jnp.where(live, rows, 0).astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), live.astype(jnp.int32),
       fresh.astype(jnp.int32), vec, bv, pool)
@@ -182,13 +216,14 @@ def _step_call(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
 def gdn_step_rows(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
                   interpret=None):
     """One token a row, the states changed where they lie. q, k: (B, H,
-    d_k); v: (B, H, d_v); g, beta: (B, H); pool: (L, R, H, d_v, d_k)
+    d_k); v: (B, H, d_v); g: (B, H) or (B, H, d_k); beta: (B, H); pool: (L, R, H, d_v, d_k)
     float32, donated; layer: the pool's layer; rows: (B,) each row's pool
     row; live: (B,) rows that take the step (the others' states are left
     as they are, their outputs garbage); fresh: (B,) rows whose state is
-    zero before the step. Returns (o (B, H, d_v), pool). `interpret=None`
-    picks the kernel on a TPU and the gather elsewhere; True runs the
-    kernel in the Pallas interpreter."""
+    zero before the step. Returns (o (B, H, d_v), pool). The kernel is
+    named `kda_step` in a trace where the gate is a channel's.
+    `interpret=None` picks the kernel on a TPU and the gather elsewhere;
+    True runs the kernel in the Pallas interpreter."""
     if interpret is None and jax.default_backend() != "tpu":
         return gdn_step_rows_reference(q, k, v, g, beta, pool, layer, rows,
                                        live, fresh)
@@ -235,11 +270,74 @@ def _chunk_terms(q, k, v, g, beta):
             jnp.exp(cum[..., -1]))
 
 
+def _kda_chunk_terms(q, k, v, g, beta):
+    """`_chunk_terms` for a gate a key channel, g (T, H, d_k); exp(G_C)
+    comes as (H, n, d_k). No quotient by exp(G) is taken (module
+    docstring): a diagonal block of `KDA_BLOCK` tokens sums k_ic k_jc
+    exp(G_ic - G_jc) over the channels as it stands; block I reaches an
+    earlier token j of its sub-chunk through its own start,
+    exp(G_i - G_start(I)) exp(G_start(I) - G_j), a matrix product."""
+    t, h = g.shape[:2]
+    if t % SUB_CHUNK:
+        raise ValueError(f"a run of {t} tokens is no multiple of "
+                         f"{SUB_CHUNK}")
+    n, c, m = t // SUB_CHUNK, SUB_CHUNK, SUB_CHUNK // KDA_BLOCK
+    q, k, v, g, beta = (_heads_first(x.astype(jnp.float32), n)
+                        for x in (q, k, v, g, beta))
+    cum = jnp.cumsum(g, axis=2)                               # (H, n, C, d_k)
+
+    def blocks(x):
+        return x.reshape(h, n, m, KDA_BLOCK, -1)
+
+    q_b, k_b, cum_b = blocks(q), blocks(k), blocks(cum)
+    at = jnp.arange(KDA_BLOCK)
+    decay = jnp.exp(jnp.where(
+        (at[:, None] >= at[None, :])[:, :, None],
+        cum_b[..., :, None, :] - cum_b[..., None, :, :], -jnp.inf))
+
+    def on_diagonal(x_b):
+        """(H, n, m, B, B) -> (H, n, C, C), the blocks down the diagonal."""
+        inside = (x_b[..., :, None, :] * k_b[..., None, :, :] * decay).sum(-1)
+        return (inside[..., :, :, None, :]
+                * jnp.eye(m)[:, None, :, None]).reshape(h, n, c, c)
+
+    # G at each block's start (the last token of the block before it) and
+    # the two factors through it; a token at or past the start gets 0.
+    start = jnp.concatenate([jnp.zeros_like(cum_b[:, :, :1, -1]),
+                             cum_b[:, :, :-1, -1]], axis=2)   # (H, n, m, d_k)
+    down = jnp.exp(cum_b - start[..., None, :])
+    before = (jnp.arange(c)[None, :]
+              < (jnp.arange(m) * KDA_BLOCK)[:, None])[:, :, None]
+    up = k[:, :, None] * jnp.exp(jnp.where(
+        before, start[..., None, :] - cum[:, :, None], -jnp.inf))
+
+    def below_diagonal(x_b):
+        return _mm("hnmik,hnmjk->hnmij", x_b * down, up).reshape(h, n, c, c)
+
+    rows = jnp.arange(c)
+    a = jnp.where(rows[:, None] > rows[None, :],
+                  beta[..., None] * (on_diagonal(k_b) + below_diagonal(k_b)),
+                  0.0)
+    return (a, beta[..., None] * v, beta[..., None] * jnp.exp(cum) * k,
+            on_diagonal(q_b) + below_diagonal(q_b),
+            q * jnp.exp(cum), k * jnp.exp(cum[:, :, -1:] - cum),
+            jnp.exp(cum[:, :, -1]))
+
+
+def _terms(q, k, v, g, beta):
+    """A run's chunk terms by the gate's rank; exp(G_C) as it scales a
+    state's lanes, (H, n, 1 or d_k)."""
+    if g.ndim == k.ndim:
+        return _kda_chunk_terms(q, k, v, g, beta)
+    terms = _chunk_terms(q, k, v, g, beta)
+    return terms[:-1] + (terms[-1][..., None],)
+
+
 def gdn_chunk(q, k, v, g, beta, state):
     """A row's run of T tokens, T a multiple of `SUB_CHUNK`, from `state`.
     Shapes as `gdn_scan`. Returns (o (T, H, d_v), state (H, d_v, d_k))."""
-    t, h = g.shape
-    a, bv, kb, qk, q_in, k_out, last = _chunk_terms(q, k, v, g, beta)
+    t, h = g.shape[:2]
+    a, bv, kb, qk, q_in, k_out, last = _terms(q, k, v, g, beta)
     solved = solve_triangular(a + jnp.eye(SUB_CHUNK),
                               jnp.concatenate([bv, kb], axis=-1),
                               lower=True, unit_diagonal=True)
@@ -249,7 +347,7 @@ def gdn_chunk(q, k, v, g, beta, state):
         u_v, w, qk, q_in, k_out, last = x
         u = u_v - _mm("hck,hvk->hcv", w, s)
         o = _mm("hck,hvk->hcv", q_in, s) + _mm("hij,hjv->hiv", qk, u)
-        s = s * last[:, None, None] + _mm("hcv,hck->hvk", u, k_out)
+        s = s * last[:, None, :] + _mm("hcv,hck->hvk", u, k_out)
         return s, o
 
     state, o = jax.lax.scan(
@@ -303,8 +401,8 @@ def _chunk_call(q, k, v, g, beta, pool, layer, row, fresh, *,
     t, h, dk = k.shape
     dv = v.shape[-1]
     n = t // SUB_CHUNK
-    a, bv, kb, qk, q_in, k_out, last = _chunk_terms(q, k, v, g, beta)
-    last = jnp.broadcast_to(last[..., None, None], (h, n, 1, dk))
+    a, bv, kb, qk, q_in, k_out, last = _terms(q, k, v, g, beta)
+    last = jnp.broadcast_to(last[:, :, None, :], (h, n, 1, dk))
 
     def head(j, *_):
         return (j, 0, 0, 0)
@@ -335,7 +433,8 @@ def _chunk_call(q, k, v, g, beta, pool, layer, row, fresh, *,
         input_output_aliases={10: 0},             # the pool, in place
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=interpret, name="gdn_chunk",
+        interpret=interpret,
+        name="kda_chunk" if g.ndim == k.ndim else "gdn_chunk",
     )(*(jnp.asarray(x, jnp.int32).reshape(1) for x in (row, layer, fresh)),
       jnp.swapaxes(a, -1, -2), bv, kb, qk, q_in, k_out, last, pool)
     return jnp.moveaxis(o.reshape(h, t, dv), 0, 1), pool
@@ -351,9 +450,9 @@ def gdn_chunk_row(q, k, v, g, beta, pool, layer, row, fresh, *,
     named `gdn_chunk` in a trace, a head a grid step, its state block
     chosen by the layer and the row in SMEM and aliased to the output; what
     the sub-chunks need before the state is touched (`_chunk_terms`) is
-    batched XLA. `interpret=None` picks the kernel on a TPU and
-    `gdn_chunk` elsewhere; True runs the kernel in the Pallas
-    interpreter."""
+    batched XLA. Where the gate is a channel's the kernel is named
+    `kda_chunk`. `interpret=None` picks the kernel on a TPU and `gdn_chunk`
+    elsewhere; True runs the kernel in the Pallas interpreter."""
     if interpret is None and jax.default_backend() != "tpu":
         return gdn_chunk_row_reference(q, k, v, g, beta, pool, layer, row,
                                        fresh)

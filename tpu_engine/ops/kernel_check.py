@@ -4,7 +4,9 @@ One list of cases (`kernel_cases`) covers the flash kernel (forward at
 every prompt bucket, backward) and the paged read paths (decode, ragged
 at q_len 1 and at the prefill-chunk width, both int8 variants) at the
 head geometry of a registered model; for a latent-attention model
-(`LATENT_MODELS`) it is the latent read at both widths instead.
+(`LATENT_MODELS`) it is the latent read at both widths instead, and for
+one whose other layers are recurrent (`RECURRENT_MODELS`) both forms of
+its recurrence as well (`kda_step`, `kda_chunk`), against the scan.
 `cell_cases` adds the ragged read at the shapes the benchmark's cells
 serve it at (`CELL_SHAPES`). Two consumers:
 
@@ -42,8 +44,15 @@ from tpu_engine.ops.flash import flash_attention
 # serving model chip_smoke.py launches; llama is the grouped case
 # (32 query heads over 4 KV heads).
 MODELS = ("gpt2", "llama")
-# The kv_latent family: one kernel, the absorbed read over the latent pool.
-LATENT_MODELS = ("moonlight",)
+# A latent pool: one kernel, the absorbed read (16 heads, and 32 in a model
+# that rotates nothing and whose other layers are recurrent).
+LATENT_MODELS = ("moonlight", "kimi_linear")
+# A state row beside the blocks, the gate a key channel's: the step over a
+# lane's rows and the chunked form over a row's run, where the states lie.
+RECURRENT_MODELS = ("kimi_linear",)
+# Max |kernel - scan| accepted for the recurrence: float32 throughout, the
+# MXU's float32 passes.
+F32_TOLERANCE = 1e-3
 # Serving shapes: the smoke's launch (--kv-block-size 16, 8 decode slots,
 # max_seq 1024 -> 64-block tables over the auto-sized 513-block pool,
 # --gen-prefill-chunk 256) and the scheduler's prompt buckets.
@@ -52,6 +61,7 @@ ROWS = 8
 TABLE_LEN = 64
 N_BLOCKS = ROWS * TABLE_LEN + 1
 CHUNK = 256
+ROWS_RECURRENT = 128     # a lane whose rows' states are small: its slots
 FLASH_BUCKETS = (16, 32, 64, 128, 256, 512, 1024)
 FLASH_BACKWARD_SEQ = 256
 # Max |kernel - reference| accepted for bf16 operands (and for the int8
@@ -225,6 +235,70 @@ def _latent_cases(model: str, interpret: bool):
                          workload, check)
 
 
+def _recurrence_cases(model: str, interpret: bool):
+    """`kda_step` over 128 rows of a 129-row pool (three of them on the null
+    row, one from a zero state) and `kda_chunk` over a run of 256 tokens, at
+    `model`'s heads and lanes, the decays over the draw's range by head,
+    channel and token; each against `gdn_scan` from the same states."""
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops import gated_delta as gd
+
+    _ensure_builtin_models_imported()
+    cfg = create_model(model).config
+    h, dk, dv = cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim
+
+    def operands(t, rows):
+        ks = jax.random.split(jax.random.PRNGKey(t), 6)
+
+        def unit(x):
+            return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+        q = unit(jax.random.normal(ks[0], (t, h, dk))) / dk ** 0.5
+        k = unit(jax.random.normal(ks[1], (t, h, dk)))
+        v = jax.random.normal(ks[2], (t, h, dv))
+        g = jnp.log(jax.random.uniform(ks[3], (t, h, dk), minval=0.55,
+                                       maxval=1.0))
+        beta = jax.random.uniform(ks[4], (t, h))
+        pool = jax.random.normal(ks[5], (2, rows, h, dv, dk))
+        return q, k, v, g, beta, pool
+
+    def step_operands():
+        q, k, v, g, beta, pool = operands(ROWS_RECURRENT, ROWS_RECURRENT + 1)
+        at = jnp.arange(ROWS_RECURRENT)
+        live = at % 50 != 7
+        return (q, k, v, g, beta, pool, jnp.int32(1),
+                jnp.where(live, at + 1, 0), live, at == 3)
+
+    def step_check(out, operands):
+        (o, pool), live = out, operands[8]
+        o_want, want = gd.gdn_step_rows_reference(*operands)
+        return float(jnp.maximum(
+            jnp.abs(jnp.where(live[:, None, None], o - o_want, 0.0)).max(),
+            jnp.abs(pool - want).max()))
+
+    def chunk_operands():
+        return operands(CHUNK, 3) + (jnp.int32(1), jnp.int32(2),
+                                     jnp.bool_(False))
+
+    def chunk_check(out, operands):
+        (o, pool), (q, k, v, g, beta, old, layer, row, _) = out, operands
+        with jax.default_matmul_precision("highest"):
+            o_want, last = gd.gdn_scan(q, k, v, g, beta, old[layer, row])
+        return float(jnp.maximum(
+            jnp.abs(o - o_want).max(),
+            jnp.abs(pool - old.at[layer, row].set(last)).max()))
+
+    yield KernelCase(f"{model}/kda_step/B{ROWS_RECURRENT}",
+                     functools.partial(gd.gdn_step_rows, interpret=interpret),
+                     step_operands, step_check)
+    yield KernelCase(f"{model}/kda_chunk/T{CHUNK}",
+                     functools.partial(gd.gdn_chunk_row, interpret=interpret),
+                     chunk_operands, chunk_check)
+
+
 def cell_cases(interpret: bool = False):
     """The ragged read at every entry of `CELL_SHAPES`."""
     for name, shape in CELL_SHAPES.items():
@@ -257,6 +331,8 @@ def kernel_cases(model: str, interpret: bool = False):
     """Every Pallas kernel site at `model`'s registry geometry."""
     if model in LATENT_MODELS:
         yield from _latent_cases(model, interpret)
+        if model in RECURRENT_MODELS:
+            yield from _recurrence_cases(model, interpret)
         return
     geo = _geometry(model)
     yield from _flash_cases(model, geo, interpret)
@@ -299,7 +375,8 @@ def main() -> int:
             err = case.check(jax.block_until_ready(
                 jax.jit(case.kernel)(*operands)), operands)
             worst = max(worst, err)
-            if not err <= BF16_TOLERANCE:   # NaN fails too
+            limit = F32_TOLERANCE if "/kda_" in case.name else BF16_TOLERANCE
+            if not err <= limit:            # NaN fails too
                 failed.append(case.name)
         print(json.dumps({"kernel": case.name, "interpret": False,
                           "max_abs_err": err,

@@ -369,8 +369,10 @@ class BlockPool:
     model): ``block_size`` tokens' keys in ``caches.k`` and values in
     ``caches.v``, all KV heads, layout below. Quantized: the same in
     int8 plus a scale a (slot, KV head). Latent (the kv_latent family,
-    models.moonlight): ``caches.k`` holds each token's rotated shared
-    rope key, zero-padded to one lane tile, and ``caches.v`` its
+    models.moonlight, and the MLA layers of a kv_and_state model,
+    models.kimi_linear): ``caches.k`` holds each token's shared key
+    lanes (the rotated rope key, or plain lanes where nothing is
+    rotated), zero-padded to one lane tile, and ``caches.v`` its
     normalised latent — ``cfg.kv_lanes`` = (128, kv_lora_rank); free
     list, refcounts, radix tree, tables and copy-on-write treat the pair
     as they treat K and V, while the host tier, int8 and the chain wire
@@ -1293,10 +1295,11 @@ class StateSlabPool:
 
 
 class StateRowPool:
-    """The recurrent layers' rows of the ``kv_and_state`` family
-    (models.olmo_hybrid), where a stream ALSO holds a block chain: a row
-    is, a recurrent layer, one float32 array of each of `shapes` (the
-    state and the conv tail), kept as arrays of their own shape
+    """The recurrent layers' rows of the ``kv_and_state`` family, where a
+    stream ALSO holds a block chain, of K and V a head
+    (models.olmo_hybrid) or of latents (models.kimi_linear): a row is, a
+    recurrent layer, one float32 array of each of `shapes` (the state and
+    the conv tail), kept as arrays of their own shape
     ``(n_layers, n_slots + 1, *shape)`` so that the step reads and writes
     them where they lie. `slab` is the tuple of them, donated through the
     tick like the block pool's pair.

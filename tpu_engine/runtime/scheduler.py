@@ -460,13 +460,15 @@ class ContinuousGenerator:
         # behind the window back inside the tick whose position passes
         # them (`_slide_window_blocks`).
         self._windowed = fam == "kv_windowed"
-        # "kv_and_state" (models.olmo_hybrid): a kv_paged chain over a
-        # pool that holds the full-attention layers alone AND one row of
-        # a state pool (`_spool`: the recurrent layers' state and conv
-        # tail), one model, one row, two pools. A row takes both at
-        # admission (parked when blocks are short; a state row is its
-        # slot's own and cannot be) and gives both back together; the
-        # step reads and writes both in place.
+        # "kv_and_state" (models.olmo_hybrid, models.kimi_linear): a chain
+        # over a pool that holds the attention layers alone AND one row
+        # of a state pool (`_spool`: the recurrent layers' state and conv
+        # tail), one model, one row, two pools. What a block holds (K and
+        # V a head, or a latent) is the model's `kv_block_kinds[0]` and
+        # changes nothing here. A row takes both at admission (parked
+        # when blocks are short; a state row is its slot's own and cannot
+        # be) and gives both back together; the step reads and writes
+        # both in place.
         self._hybrid = fam == "kv_and_state"
         # A family whose step of the mixed tick is its own (and runs over
         # the tick's tokens) declares it, with the experts the lane's
@@ -689,7 +691,7 @@ class ContinuousGenerator:
                 raise ValueError("kv_host_blocks requires prefix_sharing "
                                  "(the host tier holds radix entries)")
             # A family with layers of several kinds: `_pool` holds its
-            # full-attention layers alone.
+            # full-attention layers alone, at the lanes the model states.
             self._pool = BlockPool(self.cfg.kv_block_kinds[0]
                                    if self._windowed or self._hybrid
                                    else self.cfg,
@@ -1212,21 +1214,23 @@ class ContinuousGenerator:
                         "scales and no verify window",
                         "window read over blocks of two kinds"),
         "kv_and_state": ("a row's recurrent state is one fixed-size row, "
-                         "not block-addressable: it serves no prefix hit "
-                         "(snapshots at block boundaries are not kept), "
-                         "goes to no host tier, takes no int8 scales, "
-                         "cannot be rolled back past a rejected draft and "
-                         "rides no chain",
+                         "not block-addressable, whatever its blocks hold "
+                         "(K and V a head, or a latent): it serves no "
+                         "prefix hit (snapshots at block boundaries are "
+                         "not kept), goes to no host tier, takes no int8 "
+                         "scales, cannot be rolled back past a rejected "
+                         "draft and rides no chain",
                          "state row beside the block chain"),
     }
 
     def _fence_tick_only_family(self, model, fam, *, mixed_step,
                                 kv_host_blocks, kv_quantize, spec_k,
                                 prefix_sharing) -> None:
-        """Start-up fences of the kv_latent, kv_windowed and kv_and_state families
-        (registry FAMILY_CAPABILITIES): what the family's pool cannot do
-        yet is refused by name, never served wrong. (`tp > 1` is refused
-        above through the model's unshardable TP rule.)"""
+        """Start-up fences of the kv_latent, kv_windowed and kv_and_state
+        families (registry FAMILY_CAPABILITIES): what the family's pool or
+        pools cannot do yet is refused by name, never served wrong.
+        (`tp > 1` is refused above through the model's unshardable TP
+        rule.)"""
         name = f"model '{model.name}' ({fam} family)"
         why, read = self._TICK_ONLY_WHY[fam]
         if not (self._paged and mixed_step):
@@ -1247,14 +1251,16 @@ class ContinuousGenerator:
 
     def _refuse_chain(self, what: str) -> Optional[str]:
         """The chain wire format carries every block of a row, a K and a
-        V of H_kv*D lanes each: migration, handoff and prefix fetch
-        refuse for a latent pool and for one that frees window blocks."""
+        V of H_kv*D lanes each, and nothing else of the row: migration,
+        handoff and prefix fetch refuse for a latent pool, for one that
+        frees window blocks and for a row that also owns a state row."""
         fam = getattr(self.spec, "state_family", None)
         if fam not in self._TICK_ONLY_WHY:
             return None
         return (f"{what} needs the 'migration' capability, which the "
                 f"{fam} family does not declare (the chain wire format "
-                f"carries a K and a V a head for every block of the row)")
+                f"carries a K and a V a head for every block of the row, "
+                f"and no state row)")
 
     def _pin_pool_out(self, caches, scales=None):
         """TRACED helper for the pool-donating executables: constrain
@@ -2692,14 +2698,17 @@ class ContinuousGenerator:
             if self._hybrid:
                 # The state pool beside the block pool; and, in the block
                 # pool's own sample, the bytes of both kinds the rows
-                # hold now (one reading of the two, for their ratio).
+                # hold now (one reading of the two, for their ratio) and
+                # the lanes a token takes in the pool's two tensors (K
+                # and V a head: equal; a latent pool's differ).
                 out["state_pool"] = state = self._spool.stats()
                 held = (out["kv_pool"]["blocks_total"]
                         - out["kv_pool"]["blocks_free"])
                 out["kv_pool"].update(
                     kv_bytes_held=held * self._pool.bytes_per_block(),
                     state_bytes_held=(state["rows_held"]
-                                      * state["bytes_per_row"]))
+                                      * state["bytes_per_row"]),
+                    block_lanes=list(self._pool.cfg.kv_lanes))
         if self._slab:
             # Gated additive block (the state_slab family's kv_pool
             # analog): a kv_paged lane's /stats and /health bytes never
@@ -4718,15 +4727,21 @@ class ContinuousGenerator:
         """What a tick of a lane with both kinds of state asks of each, on
         its span: the tokens that go through the chunked form of the
         recurrence (and the rows they belong to) and the rows that take
-        one step of it, the tokens the full-attention layers read (as a
-        windowed lane's `ctx_tokens_full`), and the state rows held."""
+        one step of it, under the kernels' names in a trace (the model's
+        `recurrence`: `gdn_*` or `kda_*`); the tokens the
+        attention layers read (`ctx_tokens_full` as a windowed lane's, or
+        `ctx_tokens_latent` where the pool's two tensors differ in width:
+        a latent pool); and the state rows held."""
         fed = qlen > 0
-        self._clock.note(
-            gdn_chunk_tokens=int(qlen[qlen > 1].sum()),
-            gdn_chunk_rows=int((qlen > 1).sum()),
-            gdn_step_rows=int((qlen == 1).sum()),
-            ctx_tokens_full=int((pos0[fed] + qlen[fed]).sum()),
-            state_rows_held=self._spool.rows_held)
+        kernel = self.cfg.recurrence
+        k_lanes, v_lanes = self._pool.cfg.kv_lanes
+        read = "ctx_tokens_full" if k_lanes == v_lanes else "ctx_tokens_latent"
+        self._clock.note(**{
+            f"{kernel}_chunk_tokens": int(qlen[qlen > 1].sum()),
+            f"{kernel}_chunk_rows": int((qlen > 1).sum()),
+            f"{kernel}_step_rows": int((qlen == 1).sum()),
+            read: int((pos0[fed] + qlen[fed]).sum()),
+            "state_rows_held": self._spool.rows_held})
 
     def _count_moe(self, rows, fed: int) -> None:
         """`rows` (L_moe, E): what each expert of each expert layer took
