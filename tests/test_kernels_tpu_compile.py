@@ -111,6 +111,28 @@ def test_paged_grid_is_the_query_tiles_not_the_table(v5e_devices, cell):
         dataclasses.replace(case, operands=lambda: half), v5e_devices[0])
 
 
+@pytest.mark.parametrize("name, grids", [
+    ("olmo-hybrid-7b-12l.digest/classes/W256", [(16, 1), (19, 1)]),
+    ("laguna-s-2.1-5l.repo/full/classes/W256", [(8, 1), (13, 3)]),
+])
+def test_the_two_classes_of_tile_are_two_calls_with_grids_of_their_own(
+        v5e_devices, name, grids):
+    """A tick that carries a chunk, its rows read by the class of their
+    runs (`kernel_check.CLASS_SHAPES`), compiles for a v5e as TWO paged
+    calls: the short rows a row a tile at width 1 (the heads packed: one
+    grid step a row), the tall tiles a row of their own call each (one
+    tile of the grid at G = 1, three at G = 6: 64 slots x 6 heads), rows +
+    ceil(max_tokens / height) of them; no operand of rows x 256 slots."""
+    (case,) = [c for c in kernel_check.class_cases(interpret=False)
+               if c.name == name]
+    kernel_check.compile_for_topology(case, v5e_devices[0])
+    jaxpr = jax.make_jaxpr(case.kernel)(*jax.eval_shape(case.operands))
+    assert _pallas_grids(jaxpr.jaxpr) == grids
+    rows = len(kernel_check.CLASS_SHAPES[name]["rows"])
+    assert not [v.aval.shape for eqn in jaxpr.jaxpr.eqns for v in eqn.outvars
+                if v.aval.shape[:2] == (rows, kernel_check.CHUNK)]
+
+
 def _mixed_tick(cfg, attn_fn):
     """The mixed step as the scheduler traces it, sampling left out:
     (params, caches, tables, tokens, pos0, qlen) -> (logits, caches)."""
@@ -438,8 +460,10 @@ def test_windowed_mixed_step_copies_no_pool_and_no_bank(v5e_devices, width):
     no `copy`, `slice`, `dynamic-slice` or `dynamic-update-slice` whose
     result is a pool, a layer of one, or a layer's bank of held experts
     (1.6 GB); temporaries of tens of MB: the step runs over the tick's
-    tokens (68 tiles of 8), and 11.15 GB of weights with 2.6 GB of pools
-    leave 2 GB of the chip."""
+    tokens (68 tiles of 8; since PR 45 the full layers' tall tiles beside
+    them, 37 x 64 slots x 48 heads, 29 MB a copy: 85 MB in all, and
+    `device.hbm_peak_gb` read 14.355 for 14.353 on the chip), and 11.15
+    GB of weights with 2.6 GB of pools leave 2 GB of the chip."""
     from jax.sharding import SingleDeviceSharding
 
     from tpu_engine.models.laguna import laguna_step_rows_ragged
@@ -502,7 +526,7 @@ def test_windowed_mixed_step_copies_no_pool_and_no_bank(v5e_devices, width):
     moved = [(op, dims) for dims, op in movers.findall(hlo)
              if math.prod(map(int, dims.split(","))) in sizes]
     assert not moved, moved
-    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+    assert compiled.memory_analysis().temp_size_in_bytes < 100e6
 
 
 @pytest.mark.parametrize("width", [1, 256])

@@ -205,6 +205,46 @@ def test_chunked_prefill_then_decode_equals_the_reference_on_logits(
     assert pos == [52, 30]
 
 
+def test_the_chunk_tick_s_full_layers_hold_no_operand_of_rows_x_width(
+        spec, params):
+    """The step of two rows in 256 slots, traced: a full layer (G = 6)
+    reads the rows with one new token as (2, 1, H, D) and the longer runs
+    as tall tiles of 64 slots, (2 + ceil(100 / 64), 64, H, D), a tile a
+    row of the call; a window layer keeps the list's tiles of 8 slots,
+    and no layer makes rows x width query slots. A step a slot wide makes
+    one call a layer."""
+    cfg = spec.config
+    asked = []
+
+    def attn_fn(q, *rest, window=None):
+        asked.append((window is not None, q.shape))
+        return pa.ragged_paged_attention_reference(q, *rest, window=window)
+
+    def step(width):
+        table = jnp.zeros((2, 32), jnp.int32)
+        return jax.make_jaxpr(
+            lambda tokens, caches, pos0, qlen: laguna_step_rows_ragged(
+                params, tokens, caches, (table, table), pos0, qlen, cfg,
+                dtype=jnp.float32, max_tokens=100, attn_fn=attn_fn,
+                sample_slot=jnp.zeros(2, jnp.int32)))(
+            jnp.zeros((2, width), jnp.int32), _pools(cfg, 9),
+            jnp.zeros(2, jnp.int32), jnp.ones(2, jnp.int32))
+
+    jaxpr = step(256)
+    full = [shape for windowed, shape in asked if not windowed]
+    heads = (cfg.n_heads, cfg.d_head)
+    assert full == [(2, 1) + heads, (2 + 2, 64) + heads] * cfg.n_full_layers
+    assert [shape for windowed, shape in asked if windowed] == [
+        (2 + -(-100 // 8), 8, 18, cfg.d_head)] * cfg.n_window_layers
+    shapes = [v.aval.shape for eqn in jaxpr.jaxpr.eqns for v in eqn.outvars]
+    assert shapes and not [x for x in shapes
+                           if len(x) == 4 and x[:2] == (2, 256)]
+    del asked[:]
+    step(1)
+    assert [shape for _, shape in asked] == [
+        (2, 1, h, cfg.d_head) for h in cfg.heads_per_layer]
+
+
 def test_two_shares_of_the_experts_add_up_to_the_uncut_layer(reference):
     """A whole expert layer: the shares held=(0, 8) and (8, 8), each with
     its half of the banks, the shared expert counted once, against the
@@ -333,6 +373,12 @@ def test_the_mixed_tick_serves_it_frees_window_blocks_and_counts(spec,
     assert all(0 < s["ctx_tokens_window"] <= s["ctx_tokens_full"]
                for s in spans)
     assert any(s["ctx_tokens_window"] < s["ctx_tokens_full"] for s in spans)
+    # A tile of one class or the other a row the tick fed (a chunk is at
+    # most 16 tokens here, one tall tile), and every fed token in one.
+    assert all(0 < s["attn_tiles_short"] + s["attn_tiles_tall"] <= 4
+               and s["attn_tiles_short"] + 16 * s["attn_tiles_tall"]
+               >= s["decode_rows"] + s["prefill_tokens"] for s in spans)
+    assert any(s["attn_tiles_short"] and s["attn_tiles_tall"] for s in spans)
 
 
 def test_a_row_cut_by_its_deadline_leaks_no_block_of_either_kind(spec,

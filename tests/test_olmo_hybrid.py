@@ -287,6 +287,44 @@ def test_chunked_prefill_then_decode_equals_the_reference_on_logits(
     assert all(float(jnp.abs(x[:, 0]).max()) == 0.0 for x in caches[1])
 
 
+def test_the_chunk_tick_s_full_layers_hold_no_operand_of_rows_x_width(
+        spec, params):
+    """The step of three rows in 256 slots, traced: each full layer reads
+    the rows with one new token as (3, 1, H, D) and the longer runs as
+    tall tiles of 128 slots, (3 + ceil(200 / 128), 128, H, D), a tile a
+    row of the call; nothing of rows x width query slots is made for a
+    full layer (the head's gather of the rows' hidden states is left
+    alone: here the step samples a slot a row). A step a slot wide makes
+    one call a layer."""
+    cfg = spec.config
+    asked = []
+
+    def attn_fn(q, *rest):
+        asked.append(q.shape)
+        return pa.ragged_paged_attention_reference(q, *rest)
+
+    def step(width):
+        tables = (jnp.zeros((3, 32), jnp.int32), jnp.zeros(3, jnp.int32))
+        return jax.make_jaxpr(
+            lambda tokens, caches, pos0, qlen: olmo_hybrid_step_rows_ragged(
+                params, tokens, caches, tables, pos0, qlen, cfg,
+                dtype=jnp.float32, max_tokens=200, attn_fn=attn_fn,
+                sample_slot=jnp.zeros(3, jnp.int32)))(
+            jnp.zeros((3, width), jnp.int32), _pools(cfg, rows=4, blocks=17),
+            jnp.zeros(3, jnp.int32), jnp.ones(3, jnp.int32))
+
+    jaxpr = step(256)
+    heads = (cfg.n_heads, cfg.d_head)
+    assert asked == [(3, 1) + heads,
+                     (3 + 2, 128) + heads] * cfg.n_full_layers
+    shapes = [v.aval.shape for eqn in jaxpr.jaxpr.eqns for v in eqn.outvars]
+    assert shapes and not [x for x in shapes
+                           if len(x) == 4 and x[:2] == (3, 256)]
+    del asked[:]
+    step(1)
+    assert asked == [(3, 1) + heads] * cfg.n_full_layers
+
+
 # -- the paged kernel at this model's heads ---------------------------------------
 
 @pytest.mark.parametrize("width", [1, 256])
@@ -352,6 +390,9 @@ def test_the_mixed_tick_serves_it_from_both_pools_and_counts(spec, params,
                                    + mixed["decode_tokens"])
     assert any(s["gdn_chunk_tokens"] and s["gdn_step_rows"] for s in spans)
     assert all(s["ctx_tokens_full"] == s["ctx_tokens"] for s in spans)
+    # A chunk of at most 16 tokens is one tall tile; a step, a short one.
+    assert all(s["attn_tiles_short"] == s["gdn_step_rows"]
+               and s["attn_tiles_tall"] == s["gdn_chunk_rows"] for s in spans)
     assert max(s["state_rows_held"] for s in spans) == 3
 
 

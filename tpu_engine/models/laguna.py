@@ -373,9 +373,17 @@ def laguna_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     (B, nb) by logical column // bs. A window layer's table may hold the
     null block wherever the row's first new token no longer sees (the
     blocks it gave back): the read is `ops.paged_attention`'s ragged read
-    with `window`, a TILE a row of the call, so a tile walks only the
-    columns its own slots see. Every token's K and V are scattered into
-    its row's blocks BEFORE the read (write-before-attend).
+    with `window`, a TILE of the list a row of the call, so a tile walks
+    only the columns its own slots see. A FULL layer walks a row's whole
+    context, so its tiles follow the row's run
+    (`ops.latent_attention.class_plan`,
+    `ops.paged_attention.ragged_read_by_class`): a row with one new token
+    is a row of a width-1 call, the 8 KV heads packed, and a longer run is
+    cut in tall tiles of up to 64 slots (8 list tiles; at G = 6, 384 query
+    rows a KV head, three tiles of the call's grid), each a row of a second
+    call, so a 256-token chunk walks its context 12 times and not once a
+    list tile, 32 times. Every token's K and V are scattered into its
+    row's blocks BEFORE the read (write-before-attend).
 
     ``held`` = (first, count): the experts `params` holds (default
     `cfg.held`). Returns (logits, caches, rows) as the Moonlight step:
@@ -403,6 +411,11 @@ def laguna_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     # invalid -> the null block
     blk = [jnp.where(valid, t[row, cols // bs], 0) for t in tables]
     tile_tables = [t[plan.row] for t in tables]
+    # The full layers' read: the list flat, row b's run from its first tile.
+    classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
+                            max_tokens)
+    flat = (plan.start * per_tile, jnp.repeat(plan.row, per_tile),
+            slot.reshape(-1))
     h = nn.embedding(params["tok_embed"], tokens[row, slot]).astype(dtype)
 
     def attend(layer, ap, x, pools):
@@ -410,8 +423,13 @@ def laguna_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
         at = cfg.pool_layer[layer]
         q, k, v, gate = _attn_inputs(ap, x, logical, layer, cfg, dtype)
         pool = _write_pool(pools[kind], at, blk[kind], off, k, v)
-        o = attn_fn(q, *pool, at, tile_tables[kind], tile_pos0, tile_qlen,
-                    **({"window": cfg.window} if kind else {}))
+        if kind:
+            o = attn_fn(q, *pool, at, tile_tables[kind], tile_pos0,
+                        tile_qlen, window=cfg.window)
+        else:
+            o = pa.ragged_read_by_class(
+                attn_fn, q.reshape((-1,) + q.shape[2:]), pool, at,
+                tables[0], pos0, classes, *flat).reshape(q.shape)
         return o, gate, pools[:kind] + (pool,) + pools[kind + 1:]
 
     h, pools, rows = _run_layers(

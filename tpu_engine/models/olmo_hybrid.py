@@ -393,7 +393,12 @@ def olmo_hybrid_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     them); tables: (the rows' block table (B, nb); the rows' state row
     (B,), the null row 0 for a free slot). A full layer scatters every
     token's K and V into its row's blocks BEFORE the read
-    (write-before-attend) and reads a ROW a row of the paged call.
+    (write-before-attend) and reads each row by the class of its run
+    (`ops.latent_attention.class_plan`,
+    `ops.paged_attention.ragged_read_by_class`): a row with one new token
+    as a row of a width-1 call, the 30 heads packed, and a longer run in
+    tall tiles of up to 128 slots, each a row of a second call; a step a
+    slot wide makes the first call alone.
     `step_fn`, `chunk_fn`: `ops.gated_delta`'s `gdn_step_rows` and
     `gdn_chunk_row` or stand-ins of their signatures (a test compiling the
     kernels for a chip that is not there).
@@ -414,8 +419,8 @@ def olmo_hybrid_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     bs = pool.k.shape[2]
     cols = jnp.minimum(pos0[row] + slot, table.shape[1] * bs - 1)
     blk = jnp.where(valid, table[row, cols // bs], 0)  # invalid -> null block
-    # Row b's new tokens in the list, for the read that takes a row.
-    listed = jnp.minimum(plan.start[:, None] + jnp.arange(w)[None, :], m - 1)
+    classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
+                            max_tokens)
     h = nn.embedding(params["tok_embed"], tokens[row, slot]).astype(dtype)
 
     def mixer(layer, bp, x, carry):
@@ -428,8 +433,9 @@ def olmo_hybrid_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
             return y, (pool, state)
         q, k, v = _attn_inputs(bp["attn"], x, cfg, dtype)
         pool = _write_pool(pool, at, blk, cols % bs, k, v)
-        o = attn_fn(q[listed], *pool, at, table, pos0, qlen)
-        o = o[row, slot].astype(dtype).reshape(m, -1)
+        o = pa.ragged_read_by_class(attn_fn, q, pool, at, table, pos0,
+                                    classes, plan.start, row, slot)
+        o = o.astype(dtype).reshape(m, -1)
         return nn.dense(bp["attn"]["wo"], o, dtype=dtype), (pool, state)
 
     h, (pool, state) = _run_layers(params, h, (tuple(pool), tuple(state)),
@@ -438,6 +444,9 @@ def olmo_hybrid_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
         h = h[jnp.minimum(plan.start + jnp.minimum(sample_slot, w - 1),
                           m - 1)]                                # (B, d)
     else:
+        # Row b's new tokens in the list.
+        listed = jnp.minimum(plan.start[:, None] + jnp.arange(w)[None, :],
+                             m - 1)
         h = jnp.where((jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
                       h[listed], 0)
     return (_head(params, h, cfg, dtype), (KVCache(*pool), state),

@@ -8,7 +8,9 @@ head geometry of a registered model; for a latent-attention model
 one whose other layers are recurrent (`RECURRENT_MODELS`) both forms of
 its recurrence as well (`kda_step`, `kda_chunk`), against the scan.
 `cell_cases` adds the ragged read at the shapes the benchmark's cells
-serve it at (`CELL_SHAPES`). Two consumers:
+serve it at (`CELL_SHAPES`), `class_cases` the two calls a tick that
+carries a chunk makes of its rows, by the class of their runs
+(`CLASS_SHAPES`). Two consumers:
 
 - `python -m tpu_engine.ops.kernel_check` — chip_smoke.py's kernel
   phase: on the attached TPU, compile every case with `interpret=False`
@@ -125,6 +127,27 @@ CELL_SHAPES = {
         n_blocks=1537,
         rows=((256, 2048), (1, 700), (1, 2300), (1, 1500), (0, 0))
         + tuple((1, 300 + 150 * r) for r in range(11))),
+}
+
+
+# The two calls of a tick that carries a chunk, by the class of a row's run
+# (`ops.latent_attention.class_plan`, `pa.ragged_read_by_class`), at the
+# two geometries that make them: rows = slots, `max_tokens` the lane's
+# (chunk + slots), a decode row of 301 columns beside a 242-slot chunk at
+# column 3000 (two tall tiles of 128 slots at G = 1, four of 64 at G = 6),
+# decode rows to 2.3 k columns and a free slot. The short call is the
+# cell's width-1 program; the tall call has a TILE a row.
+CLASS_SHAPES = {
+    "olmo-hybrid-7b-12l.digest/classes/W256": dict(
+        geo=dict(n_heads=30, n_kv_heads=30, d_head=128), table_len=1024,
+        n_blocks=1537, max_tokens=272,
+        rows=((1, 300), (242, 3000), (1, 700), (1, 2300), (0, 0))
+        + tuple((1, 300 + 150 * r) for r in range(11))),
+    "laguna-s-2.1-5l.repo/full/classes/W256": dict(
+        geo=dict(n_heads=48, n_kv_heads=8, d_head=128), table_len=1024,
+        n_blocks=2049, max_tokens=264,
+        rows=((1, 300), (242, 3000), (1, 700), (1, 2300), (0, 0))
+        + tuple((1, 300 + 150 * r) for r in range(3))),
 }
 
 
@@ -327,6 +350,28 @@ def cell_cases(interpret: bool = False):
                          lambda workload=workload: workload()[0], check)
 
 
+def class_cases(interpret: bool = False):
+    """The short and the tall call over one token list, at every entry of
+    `CLASS_SHAPES`, against the gather reference on the whole batch."""
+    for name, shape in CLASS_SHAPES.items():
+        q_lens, pos0 = zip(*shape["rows"])
+        workload = functools.partial(
+            pa.class_workload, q_lens, pos0, width=CHUNK,
+            max_tokens=shape["max_tokens"], block_size=BLOCK_SIZE,
+            n_blocks=shape["n_blocks"], table_len=shape["table_len"],
+            dtype=jnp.bfloat16, **shape["geo"])
+        reach = -(-max(q + p for q, p in shape["rows"]) // BLOCK_SIZE)
+
+        def check(out, operands, reach=reach):
+            return pa.class_read_error(
+                out, operands[:4] + (operands[4][:, :reach],) + operands[5:])
+
+        yield KernelCase(
+            name, functools.partial(pa.class_read, width=CHUNK,
+                                    max_tokens=shape["max_tokens"],
+                                    interpret=interpret), workload, check)
+
+
 def kernel_cases(model: str, interpret: bool = False):
     """Every Pallas kernel site at `model`'s registry geometry."""
     if model in LATENT_MODELS:
@@ -365,7 +410,7 @@ def main() -> int:
     worst, failed = 0.0, []
     for case in itertools.chain(
             *(kernel_cases(model) for model in MODELS + LATENT_MODELS),
-            cell_cases()):
+            cell_cases(), class_cases()):
         t0 = time.monotonic()
         operands = case.operands()
         if case.check is None:

@@ -35,6 +35,21 @@ step (models.moonlight) therefore keeps only the tiles that hold one.
 static list is dead (all slots invalid). Everything token-wise in the step
 runs over (n_tiles, S) and the read takes q as (n_tiles, S, H, .).
 
+**Two classes of tile, chosen by a row's q_len** (`class_plan`), for a read
+whose geometry follows the query's height (`ops.paged_attention`: K and V
+of every head, where one query row a head packs all heads into one score
+tile and 128 query rows a head are a product of their own). A SHORT row
+has one new token (a decode row, or a prompt's last token alone): one tile
+a row, read at width 1. Every longer run is TALL: cut in tiles of
+`tall_slots` slots (128 query rows a KV head or a few times that), each a
+row of a second call with its own first column, q_len and table row, so a
+tick that carries a chunk reads its decode rows as a decode-only tick does
+and the chunk's run in tiles of its own. The
+latent read above keeps ONE class (`tile_plan`: `models.moonlight`,
+`models.kimi_linear`, and the token lists of `models.laguna` and
+`models.olmo_hybrid`); the two-class plan is what those last two hand
+`ops.paged_attention.ragged_read_by_class` for their full layers.
+
 `latent_attention_reference` is the XLA gather path (the CPU serving path
 and the correctness anchor); `latent_attention` is the Pallas TPU kernel
 `_mla_latent_kernel`: one grid step a tile, each walking its own context
@@ -48,6 +63,7 @@ follows `ops.paged_attention` (`TPU_ENGINE_PAGED`).
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -75,7 +91,10 @@ def pad_rope_lanes(x):
 
 
 class TilePlan(NamedTuple):
-    """Which (row, tile-of-row) each query tile is (module docstring)."""
+    """Which (row, tile-of-row) each query tile is (module docstring): ONE
+    class of tile, every row's run cut at the same height. What the latent
+    read takes, and what a step lays its token list out by. `TileClasses`
+    holds one of these for the tall class."""
     row: jax.Array       # (n_tiles,) int32
     tile: jax.Array      # (n_tiles,) int32
     start: jax.Array     # (B,) int32: a row's first tile in the list
@@ -120,6 +139,56 @@ def tile_slots(plan: TilePlan, qlen, per_tile: int):
     slot = plan.tile[:, None] * per_tile + jnp.arange(per_tile)[None, :]
     live = jnp.arange(plan.row.shape[0])[:, None] < plan.n_live
     return slot, live & (slot < qlen[plan.row][:, None])
+
+
+class TileClasses(NamedTuple):
+    """A tick's rows by the class that reads them (module docstring).
+    `short`: the rows with ONE new token, read a row a tile at width 1.
+    `tall`: the tiles of every longer run, `tall_slots` slots each, in row
+    order (a `TilePlan` over the runs of the rows that are not short), or
+    None where the step is a slot wide: no run is longer than one token
+    and one call reads every row. `slot`, `valid`: each tall tile slot's
+    index in its row's new tokens and whether it holds one."""
+    short: jax.Array             # (B,) bool
+    tall: "TilePlan | None"
+    slot: "jax.Array | None"     # (n_tall, T) int32, `tile_slots` of `tall`
+    valid: "jax.Array | None"    # (n_tall, T) bool
+
+
+def tall_slots(width: int, group: int) -> int:
+    """Slots a tall tile in a step of `width` slots a row, `group` query
+    heads a KV head: the fewest whose query rows (slot x group head) are
+    whole tiles of `_ROW_TILE` rows, the paged kernel's too. 128 at one
+    head a KV head (one tile of the call's grid a tall tile), 64 at six
+    (three): a run walks its context ceil(q_len * G / 128) times, the
+    fewest any height gives, and a higher tile only pads the operand."""
+    return min(width, _ROW_TILE // math.gcd(_ROW_TILE, group))
+
+
+def class_plan(qlen, width: int, group: int, max_tokens=None) -> TileClasses:
+    """The two classes of a tick whose rows hold `qlen` new tokens, in a
+    step of `width` slots a row and at most `max_tokens` valid slots, for
+    a read of `group` query heads a KV head: the class is chosen by
+    `qlen`, a value the step observes, and by nothing else. The tall list
+    is `tiles_bound` long (a tile a row and ceil(max_tokens / tall_slots)
+    more)."""
+    short = qlen == 1
+    if width == 1:
+        return TileClasses(short, None, None, None)
+    height = tall_slots(width, group)
+    runs = jnp.where(short, 0, qlen)
+    tall = tile_plan(runs, height,
+                     tiles_bound(qlen.shape[0], width, height, max_tokens))
+    return TileClasses(short, tall, *tile_slots(tall, runs, height))
+
+
+def class_counts(qlen, width: int, group: int):
+    """(short, tall): the live tiles of each class in a tick, on the host
+    from the rows' `qlen` (a numpy vector): what `class_plan` makes of the
+    same values on the device."""
+    runs = qlen[qlen > 1]
+    return (int((qlen == 1).sum()),
+            int((-(-runs // tall_slots(width, group))).sum()))
 
 
 def latent_attention_reference(q_lat, q_pe, pe_pool, c_pool, layer, tables,

@@ -42,7 +42,10 @@ out (B, H_kv, W*G, D) with G = n_heads/kv_heads (row r = slot r//G,
 head r%G), tiled `_ROW_TILE` rows at a time, and the grid is
 (B, ceil(W*G / rows)) whatever the table's width: a tile of a row with
 no new token there (q_len 0, or a decode row's tiles past its first)
-writes zeros and costs one empty step. A live tile walks ITS OWN
+writes zeros and costs one empty step. (A decode row's FIRST tile in a
+call W slots wide is as tall as a chunk's, one live query row in 128 a
+head: a step that knows its rows' runs makes two calls instead, see "Two
+classes of tile" below.) A live tile walks ITS OWN
 context: K and V stay whole in HBM, and a loop over
 ceil(horizon / (blocks * bs)) groups — `horizon` is what the tile's
 last slot sees — DMAs each group's physical blocks, all KV heads, a
@@ -65,6 +68,21 @@ query row a head, so its heads are packed into one score tile (row i =
 head i: each head's product with its own key lanes, queries zero in the
 other heads' rows, added up) against groups of 8 blocks. VMEM holds
 O(H_kv * _ROW_TILE * D) whatever W*G is.
+
+**Two classes of tile.** The geometry is a CALL's, chosen by its width,
+so a tick that carries a chunk would read its decode rows through the
+chunk's tall tiles. `ragged_read_by_class` takes the tick's token list
+and the plan `ops.latent_attention.class_plan` makes of the rows' q_len
+and calls the read twice: the SHORT rows (one new token) as
+(B, 1, H, D), a row a tile, the packed geometry a decode-only tick
+runs; every longer run in TALL tiles of 128 query rows a KV head or a
+few times that (`tall_slots`), a TILE a row of the call with its own
+first column, q_len and table row. Who calls which: the steps that run
+over the tick's tokens (`models.olmo_hybrid`, and `models.laguna` for
+its full layers; its window layers' tiles walk only their window and
+stay a list tile a row) call `ragged_read_by_class`; the uniform step
+(`models.transformer`, every slot of every row) calls a read path
+below once, a ROW a row of the call.
 
 An int8 pool's scales, (L, NB, bs, H_kv), cannot be fetched by the walk
 (Mosaic refuses a DMA whose minor dimension is H_kv): the call gathers
@@ -549,6 +567,37 @@ def quant_ragged_paged_attention(q, k_pool, v_pool, k_scale, v_scale, layer,
                   qlen, interpret)
 
 
+def ragged_read_by_class(attn_fn, q, pool, layer, tables, pos0, classes,
+                         base, row, slot):
+    """The ragged read of a tick's token LIST, each row by the class of
+    its run (`ops.latent_attention.class_plan`). q: (M, H, D), row b's new
+    token s at list index base[b] + s, and list entry m is slot `slot[m]`
+    of row `row[m]`; pool: the (k, v) pair; tables: (B, nb); pos0: (B,).
+    The SHORT rows' one token goes through `attn_fn` (a ragged read path
+    above) as (B, 1, H, D), a ROW a row of the call, which is a
+    decode-only tick's call: `_tile_geometry` packs the heads. The TALL
+    tiles go through it as (n_tall, T, H, D), a TILE a row of the call
+    with its own first column, q_len and table row. No (B, W, ...) operand
+    is made. Returns (M, H, D); an entry that holds no token is garbage
+    by contract."""
+    m = q.shape[0]
+    tall = classes.tall
+    short = classes.short.astype(jnp.int32)
+    o = attn_fn(q[jnp.minimum(base, m - 1)][:, None], *pool, layer, tables,
+                pos0, short)[:, 0]                               # (B, H, D)
+    if tall is None:
+        return o[row]
+    height = classes.slot.shape[1]
+    at = jnp.minimum(base[tall.row][:, None] + classes.slot, m - 1)
+    o_tall = attn_fn(q[at], *pool, layer, tables[tall.row],
+                     pos0[tall.row] + tall.tile * height,
+                     classes.valid.sum(-1).astype(jnp.int32))
+    tile = jnp.minimum(tall.start[row] + slot // height,
+                       tall.row.shape[0] - 1)
+    return jnp.where(classes.short[row][:, None, None], o[row],
+                     o_tall[tile, slot % height])
+
+
 # The four read paths: name -> (kernel entry point, XLA reference). The
 # selectors, the start-up banner and the parity checks all read this.
 READ_PATHS = {
@@ -805,6 +854,90 @@ def window_parity_check(case: str, group: int, *, interpret=None,
                    n_blocks=1 + len(q_lens) * table_len,
                    table_len=table_len, dtype=dtype, seed=seed, pos0=pos0,
                    window=window, interpret=interpret)
+
+
+def class_read(q, k_pool, v_pool, layer, tables, pos0, qlen, *, width: int,
+               max_tokens=None, attn_fn=None, interpret=None):
+    """`ragged_read_by_class` over a token list laid out a slot a tile, as
+    `models.olmo_hybrid` lays its tick out: q (M, H, D), the rows' new
+    tokens side by side in row order (`class_workload`). Returns
+    (M, H, D). What the parity checks and `ops.kernel_check` run."""
+    from tpu_engine.ops import latent_attention as la
+
+    if attn_fn is None:
+        attn_fn = functools.partial(ragged_paged_attention,
+                                    interpret=interpret)
+    plan = la.tile_plan(qlen, 1, q.shape[0])
+    group = q.shape[1] * q.shape[2] // k_pool.shape[3]
+    return ragged_read_by_class(
+        attn_fn, q, (k_pool, v_pool), layer, tables, pos0,
+        la.class_plan(qlen, width, group, max_tokens), plan.start, plan.row,
+        plan.tile)
+
+
+def class_workload(q_lens, pos0, *, width: int, max_tokens=None, **shape):
+    """`parity_workload("ragged", ...)` with its queries as the tick's
+    token list: (q (M, H, D), pools, layer, tables, pos0, qlen), M the
+    list's static length for a step of `width` slots a row."""
+    from tpu_engine.ops import latent_attention as la
+
+    (q, *rest), qlen = parity_workload("ragged", q_lens, pos0=pos0, **shape)
+    plan = la.tile_plan(
+        qlen, 1, la.tiles_bound(len(q_lens), width, 1, max_tokens))
+    return (q[plan.row, jnp.minimum(plan.tile, q.shape[1] - 1)], *rest)
+
+
+def class_read_error(out, operands) -> float:
+    """Max |out - reference| over the list entries that hold a token: the
+    gather reference on the WHOLE batch, a row a row, in f32
+    (`reference_error`)."""
+    from tpu_engine.ops import latent_attention as la
+
+    q, *rest, qlen = operands
+    plan = la.tile_plan(qlen, 1, q.shape[0])
+    width = max(int(qlen.max()), 1)
+    listed = jnp.minimum(plan.start[:, None] + jnp.arange(width)[None, :],
+                         q.shape[0] - 1)
+    return reference_error(ragged_paged_attention_reference, out[listed],
+                           (q[listed], *rest, qlen), qlen)
+
+
+# What the two-class plan can get wrong, one tick each: name -> (q_lens,
+# pos0, max_tokens) in a step of 256 slots a row at block size 16 under a
+# table of 24 blocks. Run at one and at four query heads a KV head (tall
+# tiles of 128 and of 32 slots).
+CLASS_CASES = {
+    "every-row-short": ((1, 1, 1, 1), (37, 0, 301, 128), None),
+    "a-run-of-1-beside-short-rows": ((1, 1, 0, 1), (5, 300, 0, 77), None),
+    "a-run-of-127-beside-short-rows": ((1, 127, 1), (40, 3, 200), None),
+    "a-run-of-128-beside-short-rows": ((1, 128, 1), (40, 100, 200), None),
+    "a-run-of-129-beside-short-rows": ((129, 1, 1), (64, 300, 9), None),
+    "a-run-of-256-beside-short-rows": ((1, 256, 1), (40, 128, 383), None),
+    # A row with no new token between live rows of both classes.
+    "a-dead-row-between-live-rows": ((1, 0, 40, 0, 1), (37, 9, 20, 50, 3),
+                                     None),
+    # The tail of one prompt and the head of the next in one tick.
+    "two-tall-runs-in-one-tick": ((1, 70, 1, 150), (90, 260, 17, 0), None),
+    # The list is full: every one of `max_tokens` slots holds a token.
+    "max-tokens-reached-exactly": ((1, 200, 1, 58), (12, 100, 300, 0), 260),
+    # Runs of two and three tokens: too long for the short class, a tall
+    # tile with a few live slots each.
+    "runs-just-past-short": ((2, 1, 3, 1), (0, 33, 126, 255), None),
+}
+
+
+def class_parity_check(case: str, group: int, *, interpret=None,
+                       dtype=jnp.float32, seed: int = 0) -> float:
+    """Max |short + tall reads - reference| over one of `CLASS_CASES`,
+    `group` query heads a KV head."""
+    q_lens, pos0, max_tokens = CLASS_CASES[case]
+    operands = class_workload(
+        q_lens, pos0, width=256, max_tokens=max_tokens, n_heads=2 * group,
+        n_kv_heads=2, d_head=16, block_size=16,
+        n_blocks=1 + len(q_lens) * 24, table_len=24, dtype=dtype, seed=seed)
+    return class_read_error(
+        class_read(*operands, width=256, max_tokens=max_tokens,
+                   interpret=interpret), operands)
 
 
 def parity_check(batch: int = 2, n_heads: int = 4, n_kv_heads: int = 2,

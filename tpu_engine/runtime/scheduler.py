@@ -76,6 +76,7 @@ from tpu_engine.models.transformer import (
     transformer_step_rows_ragged,
 )
 from tpu_engine.ops.attention import KVCache
+from tpu_engine.ops.latent_attention import class_counts
 from tpu_engine.runtime.generator import (
     _DTYPES,
     SAMPLER_BODIES,
@@ -4696,7 +4697,9 @@ class ContinuousGenerator:
         the bound `_wpool` is sized by. Freed table entries become the
         null block, which the window read never walks. The tick's span
         says what the two kinds of layer read (`ctx_tokens_full`,
-        `ctx_tokens_window`: the rooflines' bytes) and what was freed."""
+        `ctx_tokens_window`: the rooflines' bytes), in how many tiles of
+        each class the full layers read it (`_attn_tiles`) and what was
+        freed."""
         pool, window = self._wpool, self.cfg.window
         bs, width = pool.block_size, self._wtables.shape[1]
         freed = read = 0
@@ -4721,7 +4724,17 @@ class ContinuousGenerator:
         self._wfreed += freed
         fed = qlen > 0
         self._clock.note(ctx_tokens_full=int((pos0[fed] + qlen[fed]).sum()),
-                         ctx_tokens_window=read, window_blocks_freed=freed)
+                         ctx_tokens_window=read, window_blocks_freed=freed,
+                         **self._attn_tiles(qlen))
+
+    def _attn_tiles(self, qlen) -> dict:
+        """The live query tiles of a tick's full-attention read, by the
+        class its step reads a row's run in
+        (`ops.latent_attention.class_plan`): a row with one new token is
+        one short tile, a longer run ceil(q_len / height) tall ones."""
+        short, tall = class_counts(qlen, self._chunk_cap,
+                                   self.cfg.n_heads // self.cfg.kv_heads)
+        return {"attn_tiles_short": short, "attn_tiles_tall": tall}
 
     def _note_state_work(self, pos0, qlen) -> None:
         """What a tick of a lane with both kinds of state asks of each, on
@@ -4729,14 +4742,17 @@ class ContinuousGenerator:
         recurrence (and the rows they belong to) and the rows that take
         one step of it, under the kernels' names in a trace (the model's
         `recurrence`: `gdn_*` or `kda_*`); the tokens the
-        attention layers read (`ctx_tokens_full` as a windowed lane's, or
+        attention layers read (`ctx_tokens_full` as a windowed lane's,
+        with the tiles of each class that read them, or
         `ctx_tokens_latent` where the pool's two tensors differ in width:
-        a latent pool); and the state rows held."""
+        a latent pool, whose read has one class); and the state rows
+        held."""
         fed = qlen > 0
         kernel = self.cfg.recurrence
         k_lanes, v_lanes = self._pool.cfg.kv_lanes
         read = "ctx_tokens_full" if k_lanes == v_lanes else "ctx_tokens_latent"
         self._clock.note(**{
+            **(self._attn_tiles(qlen) if k_lanes == v_lanes else {}),
             f"{kernel}_chunk_tokens": int(qlen[qlen > 1].sum()),
             f"{kernel}_chunk_rows": int((qlen > 1).sum()),
             f"{kernel}_step_rows": int((qlen == 1).sum()),
