@@ -72,6 +72,22 @@ def test_the_latent_read_compiles_for_v5e_at_both_widths(v5e_devices):
                      "kimi_linear/kda_step/B128", "kimi_linear/kda_chunk/T256"]
 
 
+def test_the_state_space_recurrence_compiles_for_v5e_in_both_forms(
+        v5e_devices):
+    """`ssd_step` over 64 rows of a pool of 4.2 MB states (the 16 heads of
+    a group a block: 2 MB in, 2 MB out, double-buffered) and `ssd_chunk`
+    over a run of 256 tokens, at Falcon-H1's heads, groups and lanes."""
+    names = []
+    for case in kernel_check.kernel_cases("falcon_h1", interpret=False):
+        kernel_check.compile_for_topology(case, v5e_devices[0])
+        names.append(case.name)
+    assert names == ["falcon_h1/ssd_step/B64", "falcon_h1/ssd_chunk/T256"]
+    (step,) = [c for c in kernel_check.kernel_cases("falcon_h1")
+               if "ssd_step" in c.name]
+    jaxpr = jax.make_jaxpr(step.kernel)(*jax.eval_shape(step.operands))
+    assert _pallas_grids(jaxpr.jaxpr) == [(64, 2)]
+
+
 def _pallas_grids(jaxpr):
     """The grid of every `pallas_call` in a jaxpr, nested calls included."""
     grids = []
@@ -114,6 +130,10 @@ def test_paged_grid_is_the_query_tiles_not_the_table(v5e_devices, cell):
 @pytest.mark.parametrize("name, grids", [
     ("olmo-hybrid-7b-12l.digest/classes/W256", [(16, 1), (19, 1)]),
     ("laguna-s-2.1-5l.repo/full/classes/W256", [(8, 1), (13, 3)]),
+    # G = 5: a decode row's 4 x 5 query rows are one packed tile, a tall
+    # tile of 128 slots 640 query rows = five tiles of the grid; 16 rows and
+    # ceil(272 / 128) more tall tiles.
+    ("falcon-h1-34b-6l.converse/classes/W256", [(16, 1), (19, 5)]),
 ])
 def test_the_two_classes_of_tile_are_two_calls_with_grids_of_their_own(
         v5e_devices, name, grids):
@@ -695,6 +715,88 @@ def test_latent_and_state_mixed_step_copies_no_pool_state_or_bank(v5e_devices,
     analysis = compiled.memory_analysis()
     assert analysis.temp_size_in_bytes < 1.0e9
     assert analysis.alias_size_in_bytes > 2.9e9      # both pools in place
+
+
+@pytest.mark.parametrize("width", [1, 256])
+def test_two_mixers_a_layer_mixed_step_copies_neither_pool(v5e_devices, width):
+    """The Falcon-H1 cell's mixed step at its serving shapes (shapes only:
+    64 rows, six layers that each read a K/V chain at G = 5 AND step or
+    chunk a 4.2 MB state, the 261,120-row head), both pools donated,
+    compiled for one v5e: the paged calls at five query heads a KV head and
+    both forms of the Mamba-2 recurrence are Pallas calls in it (`ssd_step`
+    with the 16 heads of a group a block, `ssd_chunk`); no `copy`, `slice`
+    or `dynamic-slice` whose result is a pool, a state array or a layer of
+    one, a `dynamic-update-slice` of that size only as a chunk row's write
+    of its conv tail into its own state row; both pools in place."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.falcon_h1 import falcon_h1_step_rows_ragged
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+    from tpu_engine.ops.ssd import ssd_chunk_row, ssd_step_rows
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "falcon-h1-34b-6l.json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    assert width in (1, serving["gen_prefill_chunk"])
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg = spec.config
+    assert cfg.n_heads // cfg.kv_heads == 5
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    (kind,) = cfg.kv_block_kinds
+    one = placed(jax.ShapeDtypeStruct(
+        (kind.n_layers, serving["gen_kv_blocks"], bs, kind.kv_lanes[0]),
+        jnp.bfloat16))
+    pools = (KVCache(one, one),
+             tuple(placed(jax.ShapeDtypeStruct(
+                 (cfg.n_linear_layers, rows + 1) + shape, jnp.float32))
+                 for shape in cfg.state_row_shapes))
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return falcon_h1_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=functools.partial(ragged_paged_attention,
+                                      interpret=False),
+            step_fn=functools.partial(ssd_step_rows, interpret=False),
+            chunk_fn=functools.partial(ssd_chunk_row, interpret=False),
+            sample_slot=jnp.zeros_like(pos0),
+            max_tokens=serving["gen_prefill_chunk"] + rows)
+
+    def host(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    step, behind = _behind_a_step(tick, host(rows))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, (host(rows, -(-cfg.max_seq // bs)), host(rows)),
+        host(rows, width), host(rows), host(rows), *behind).compile()
+    hlo = compiled.as_text()
+    assert "_paged_call" in hlo and "ssd_step" in hlo
+    assert ("ssd_chunk" in hlo) == (width > 1)
+    sizes = set()
+    for x in list(pools[0]) + list(pools[1]):
+        sizes |= {math.prod(x.shape), math.prod(x.shape[1:])}
+    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
+                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
+    moved = {op for dims, op in movers.findall(hlo)
+             if math.prod(map(int, dims.split(","))) in sizes}
+    assert moved <= ({"dynamic-update-slice"} if width > 1 else set()), moved
+    analysis = compiled.memory_analysis()
+    print("falcon_h1 step width", width, "temp bytes",
+          analysis.temp_size_in_bytes, "alias", analysis.alias_size_in_bytes)
+    assert analysis.temp_size_in_bytes < 0.6e9
+    assert analysis.alias_size_in_bytes > 2.8e9      # both pools in place
 
 
 def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):

@@ -129,7 +129,7 @@ def test_gap_only_between_back_to_back_ticks(lane, order):
     ticks = _ticks(lane, seq0)
     assert len(ticks) >= 4
     assert "gap_us" not in ticks[0]["attrs"]   # the lane was idle before it
-    for k, (prev, s) in enumerate(zip(ticks, ticks[1:])):
+    for prev, s in zip(ticks, ticks[1:]):
         if order == "in_order":
             # wait's end of the tick before -> this tick's dispatch: at
             # least that tick's apply and this tick's form.
@@ -138,13 +138,17 @@ def test_gap_only_between_back_to_back_ticks(lane, order):
                                             + s["attrs"]["form_us"]) * 0.99
         else:
             # Enqueued behind the tick before: the device had it queued
-            # when that tick ended, unless a probe saw it end earlier
-            # (the first is made when the results of the tick before
-            # that one have been applied, just before that tick's span
-            # is recorded): no longer ago than that.
-            assert s["attrs"]["overlapped"] == 1
-            since = ticks[k - 1]["ts"] if k else prev["start_ts"]
-            assert 0 <= s["attrs"]["gap_us"] <= (s["ts"] - since) * 1e6 + 500
+            # when that tick ended, unless a probe saw it end earlier.
+            # Held on ONE clock, the scheduler thread's (ROADMAP C0: a
+            # span's `time.time()` record stamp comes after the probe by
+            # however long the thread was held, so it bounds nothing): the
+            # idle mark is set after the tick before was enqueued, so not
+            # before its begin, and the gap ends at this tick's dispatch:
+            # gap <= begin-to-begin + this tick's form.
+            a = s["attrs"]
+            assert a["overlapped"] == 1
+            assert a["seq"] == prev["attrs"]["seq"] + 1
+            assert 0 <= a["gap_us"] <= a["period_us"] + a["form_us"] + 1
 
 
 def test_request_stages_cover_submit_to_first_token(lane):

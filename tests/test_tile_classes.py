@@ -20,6 +20,12 @@ from tpu_engine.ops import paged_attention as pa
 WIDTH = 256
 CASES = sorted(pa.CLASS_CASES)
 GROUPS = [1, 4]
+# Five query heads a KV head (Falcon-H1: 20 over 4), the first group size
+# coprime with the 128-row tile: a tall tile of 128 slots is 640 query rows,
+# five tiles of the grid. Three of the cases, not the whole product.
+ODD_GROUP = 5
+ODD_GROUP_CASES = ["a-run-of-129-beside-short-rows",
+                   "two-tall-runs-in-one-tick", "max-tokens-reached-exactly"]
 
 
 def test_a_tall_tile_is_whole_tiles_of_the_kernel_s_query_rows():
@@ -30,7 +36,9 @@ def test_a_tall_tile_is_whole_tiles_of_the_kernel_s_query_rows():
     assert [la.tall_slots(WIDTH, g) for g in (1, 4, 6, 9)] == [128, 32, 64,
                                                                128]
     assert la.tall_slots(16, 1) == 16 and la.tall_slots(1, 6) == 1
-    for g in (1, 4, 6):
+    assert la.tall_slots(WIDTH, ODD_GROUP) == 128
+    assert pa._tile_geometry(ODD_GROUP, 4)[:2] == (5, 4)   # 4 x 5 rows packed
+    for g in (1, 4, 5, 6):
         assert la.tall_slots(WIDTH, g) * g % pa._ROW_TILE == 0
 
 
@@ -75,6 +83,15 @@ def test_short_and_tall_reads_equal_the_reference_on_the_whole_batch(
     out = pa.class_read(*operands, width=WIDTH, max_tokens=max_tokens,
                         attn_fn=pa.ragged_paged_attention_reference)
     assert pa.class_read_error(out, operands) < 2e-5
+
+
+@pytest.mark.parametrize("case", ODD_GROUP_CASES)
+def test_an_odd_group_s_tiles_cover_and_read_as_the_reference(case):
+    """G = 5: the tall plan covers every slot of every longer run once in
+    tiles of 128 slots, and short + tall reads through the Pallas
+    interpreter equal the gather reference."""
+    test_the_tiles_cover_every_valid_slot_once_and_none_twice(case, ODD_GROUP)
+    assert pa.class_parity_check(case, ODD_GROUP, interpret=True) < 2e-5
 
 
 def _calls(fn, *operands):

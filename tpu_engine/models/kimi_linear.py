@@ -134,7 +134,7 @@ class KimiLinearConfig(TransformerConfig):
         if len(self.linear) != self.n_layers:
             raise ValueError("linear needs one entry a layer")
         if not self.n_full_layers or not self.n_linear_layers:
-            raise ValueError("the family has layers of both kinds")
+            raise ValueError("this model has layers of both kinds")
         first, count = self.held
         if not (0 <= first and count > 0
                 and first + count <= self.n_routed):

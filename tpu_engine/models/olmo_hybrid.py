@@ -97,10 +97,13 @@ class OlmoHybridConfig(TransformerConfig):
     recurrence = "gdn"
 
     def __post_init__(self):
+        """THIS model's layers are each of one kind, and it has both (the
+        `kv_and_state` family asks only that a row own a chain and a state
+        row: `models.falcon_h1` keeps both in every layer)."""
         if len(self.linear) != self.n_layers:
             raise ValueError("linear needs one entry a layer")
         if not self.n_full_layers or not self.n_linear_layers:
-            raise ValueError("the family has layers of both kinds")
+            raise ValueError("this model has layers of both kinds")
 
     @property
     def n_linear_layers(self) -> int:
@@ -295,8 +298,9 @@ def olmo_hybrid_apply(params, tokens, cfg: OlmoHybridConfig, *,
 # -- the served step: the mixed tick over the block pool and the state pool -------
 
 def _linear_rows(lp, x, state, at: int, start, rows, pos0, qlen, width: int,
-                 cfg, dtype, step_fn, chunk_fn, inputs=None, output=None):
-    """A linear layer over the tick's token list. x: (M, d), row b's new
+                 cfg, dtype, step_fn, chunk_fn, inputs=None, output=None,
+                 conv=None):
+    """A recurrent mixer over the tick's token list. x: (M, d), row b's new
     tokens at [start[b], start[b] + qlen[b]); state: the state pool's
     (S (L_lin, R, H, d_v, d_k), conv tails (L_lin, R, width - 1, lanes) or
     as many numbers a row in another shape), row b's at `rows[b]` of layer
@@ -308,7 +312,12 @@ def _linear_rows(lp, x, state, at: int, start, rows, pos0, qlen, width: int,
     `inputs`, `output`: this family's `_lin_inputs` and `_lin_output`, or
     another's of their signatures whose `cfg` has the same `lin_*` and
     `conv_width` fields (`models.kimi_linear`: a gate a key channel, g
-    (M, H, d_k), which `step_fn` and `chunk_fn` tell by its rank)."""
+    (M, H, d_k), which `step_fn` and `chunk_fn` tell by its rank).
+    `conv`: this family's `_conv_heads` or another's of its signature,
+    whose three results are what `step_fn` and `chunk_fn` take first and
+    whose `cfg` need only state `conv_width` (`models.falcon_h1`: a conv
+    with a bias over x, B and C, which come out as v, k and q)."""
+    conv = conv or _conv_heads
     m = x.shape[0]
     s_pool, c_pool = state
     mixed, z, g, beta = (inputs or _lin_inputs)(lp, x, cfg, dtype)
@@ -322,7 +331,7 @@ def _linear_rows(lp, x, state, at: int, start, rows, pos0, qlen, width: int,
     ext = jnp.concatenate(
         [jnp.where(fresh[:, None, None], 0.0, tail_old),
          mixed[first][:, None]], axis=1)
-    q, k, v = _conv_heads(lp, ext, cfg)
+    q, k, v = conv(lp, ext, cfg)
     steps = qlen == 1
     o, s_pool = step_fn(q[:, 0], k[:, 0], v[:, 0], g[first], beta[first],
                         s_pool, at, rows, steps, fresh)
@@ -353,7 +362,7 @@ def _linear_rows(lp, x, state, at: int, start, rows, pos0, qlen, width: int,
             ext = jnp.concatenate(
                 [jnp.where(fresh[b], 0.0, c_pool[at, r].reshape(tail_shape)),
                  run_of(mixed)])
-            q, k, v = _conv_heads(lp, ext, cfg)
+            q, k, v = conv(lp, ext, cfg)
             # Past the row's last new token nothing decays and nothing
             # is written.
             g_run = run_of(g)

@@ -40,10 +40,12 @@ from typing import Callable, Dict, List, Optional, Tuple
 # prefix hit, be demoted to no host tier and ride no chain, so prefix
 # sharing, the host tier, migration and handoff are ABSENT, with int8
 # (the window read takes no scales), `--tp` and speculative verify.
-# "kv_and_state": blocks for some layers and a state row for the others:
-# a row holds a chain over the block pool, which holds the attention layers
-# alone, AND one row of a state pool (the recurrent layers' fixed-size
-# state and conv tail), admitted, parked and released as one. WHAT a block
+# "kv_and_state": a row holds a chain over the block pool, which holds the
+# layers that attend, AND one row of a state pool (the recurrent mixers'
+# fixed-size state and conv tail), admitted, parked and released as one:
+# blocks for some layers and a state row for the others
+# (models.olmo_hybrid, models.kimi_linear), or both in EVERY layer, read
+# from the same normed rows and summed (models.falcon_h1). WHAT a block
 # holds is the model's to state (`cfg.kv_lanes`, through
 # `cfg.kv_block_kinds`) and no part of the family: K and V a head
 # (models.olmo_hybrid) or a latent and its shared key lanes
@@ -346,6 +348,6 @@ def _ensure_builtin_models_imported():
     from tpu_engine.models import mlp, resnet  # noqa: F401
 
     for optional in ("bert", "gpt2", "llama", "yolo", "ssd", "moonlight",
-                     "laguna", "olmo_hybrid", "kimi_linear"):
+                     "laguna", "olmo_hybrid", "kimi_linear", "falcon_h1"):
         if importlib.util.find_spec(f"tpu_engine.models.{optional}") is not None:
             importlib.import_module(f"tpu_engine.models.{optional}")

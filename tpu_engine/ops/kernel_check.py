@@ -5,8 +5,9 @@ every prompt bucket, backward) and the paged read paths (decode, ragged
 at q_len 1 and at the prefill-chunk width, both int8 variants) at the
 head geometry of a registered model; for a latent-attention model
 (`LATENT_MODELS`) it is the latent read at both widths instead, and for
-one whose other layers are recurrent (`RECURRENT_MODELS`) both forms of
-its recurrence as well (`kda_step`, `kda_chunk`), against the scan.
+one whose rows also own a recurrent state (`RECURRENT_MODELS`) both forms
+of its recurrence (`kda_step`, `kda_chunk`; `ssd_step`, `ssd_chunk`),
+against the scan.
 `cell_cases` adds the ragged read at the shapes the benchmark's cells
 serve it at (`CELL_SHAPES`), `class_cases` the two calls a tick that
 carries a chunk makes of its rows, by the class of their runs
@@ -49,9 +50,11 @@ MODELS = ("gpt2", "llama")
 # A latent pool: one kernel, the absorbed read (16 heads, and 32 in a model
 # that rotates nothing and whose other layers are recurrent).
 LATENT_MODELS = ("moonlight", "kimi_linear")
-# A state row beside the blocks, the gate a key channel's: the step over a
-# lane's rows and the chunked form over a row's run, where the states lie.
-RECURRENT_MODELS = ("kimi_linear",)
+# A state row beside the blocks: the step over a lane's rows and the
+# chunked form over a row's run, where the states lie. kimi_linear: the
+# delta rule with a gate a key channel's; falcon_h1: Mamba-2 (its paged read
+# at five query heads a KV head is a case of `CLASS_SHAPES`).
+RECURRENT_MODELS = ("kimi_linear", "falcon_h1")
 # Max |kernel - scan| accepted for the recurrence: float32 throughout, the
 # MXU's float32 passes.
 F32_TOLERANCE = 1e-3
@@ -64,6 +67,7 @@ TABLE_LEN = 64
 N_BLOCKS = ROWS * TABLE_LEN + 1
 CHUNK = 256
 ROWS_RECURRENT = 128     # a lane whose rows' states are small: its slots
+ROWS_SSD = 64            # and one whose rows' states are 4.2 MB a layer
 FLASH_BUCKETS = (16, 32, 64, 128, 256, 512, 1024)
 FLASH_BACKWARD_SEQ = 256
 # Max |kernel - reference| accepted for bf16 operands (and for the int8
@@ -148,6 +152,18 @@ CLASS_SHAPES = {
         n_blocks=2049, max_tokens=264,
         rows=((1, 300), (242, 3000), (1, 700), (1, 2300), (0, 0))
         + tuple((1, 300 + 150 * r) for r in range(3))),
+    # converse (benchmarks/configs/falcon-h1-34b-6l.json): G = 5, the first
+    # group size coprime with the 128-row tile: a decode row packs 4 KV
+    # heads x 5 query rows, a tall tile of 128 slots is five grid tiles.
+    # Under the cell's table of 2048 columns and its pool: a 242-slot chunk
+    # at column 700 beside decode rows of 0.3-1.5 k columns and a free slot
+    # (16 of the lane's 64 rows: the check's gather reference holds every
+    # row's scores at once, and the geometry is a row's, not the batch's).
+    "falcon-h1-34b-6l.converse/classes/W256": dict(
+        geo=dict(n_heads=20, n_kv_heads=4, d_head=128), table_len=128,
+        n_blocks=6145, max_tokens=272,
+        rows=((1, 300), (242, 700), (1, 1500), (0, 0))
+        + tuple((1, 32 + 120 * r) for r in range(12))),
 }
 
 
@@ -165,14 +181,18 @@ class KernelCase:
     check: Optional[Callable]
 
 
-def _geometry(model: str) -> dict:
+def _config(model: str):
     from tpu_engine.models.registry import (
         _ensure_builtin_models_imported,
         create_model,
     )
 
     _ensure_builtin_models_imported()
-    cfg = create_model(model).config
+    return create_model(model).config
+
+
+def _geometry(model: str) -> dict:
+    cfg = _config(model)
     return {"n_heads": cfg.n_heads, "n_kv_heads": cfg.kv_heads,
             "d_head": cfg.d_head}
 
@@ -322,6 +342,61 @@ def _recurrence_cases(model: str, interpret: bool):
                      chunk_operands, chunk_check)
 
 
+def _ssd_cases(model: str, interpret: bool):
+    """`ssd_step` over 64 rows of a 65-row pool (two of them on the null
+    row, one from a zero state) and `ssd_chunk` over a run of 256 tokens, at
+    `model`'s heads, groups and lanes, the decays over the draw's range by
+    head and token; each against `ssd_recurrent` from the same states."""
+    from tpu_engine.ops import ssd
+
+    cfg = _config(model)
+    h, p, g, n = cfg.lin_heads, cfg.ssm_head_dim, cfg.n_groups, cfg.d_state
+
+    def operands(t, rows):
+        ks = jax.random.split(jax.random.PRNGKey(t), 6)
+        x = jax.random.normal(ks[0], (t, h, p))
+        dt = jax.random.uniform(ks[1], (t, h), minval=0.1, maxval=2.5)
+        a = -jax.random.uniform(ks[2], (h,), minval=0.02, maxval=0.25)
+        b = jax.random.normal(ks[3], (t, g, n)) / n ** 0.5
+        c = jax.random.normal(ks[4], (t, g, n))
+        return x, dt, a, b, c, jax.random.normal(ks[5], (2, rows, h, p, n))
+
+    def step_operands():
+        at = jnp.arange(ROWS_SSD)
+        live = at % 40 != 7
+        return operands(ROWS_SSD, ROWS_SSD + 1) + (
+            jnp.int32(1), jnp.where(live, at + 1, 0), live, at == 3)
+
+    def step_check(out, operands):
+        (y, pool), live = out, operands[8]
+        y_want, want = ssd.ssd_step_rows_reference(*operands)
+        return float(jnp.maximum(
+            jnp.abs(jnp.where(live[:, None, None], y - y_want, 0.0)).max(),
+            jnp.abs(pool - want).max()))
+
+    def chunk_operands():
+        return operands(CHUNK, 3) + (jnp.int32(1), jnp.int32(2),
+                                     jnp.bool_(False))
+
+    def chunk_check(out, operands):
+        (y, pool), (x, dt, a, b, c, old, layer, row, _) = out, operands
+        y_want, last = ssd.ssd_recurrent(
+            x[None], dt[None], a, b[None], c[None],
+            initial_state=old[layer, row][None])
+        return float(jnp.maximum(
+            jnp.abs(y - y_want[0]).max(),
+            jnp.abs(pool - old.at[layer, row].set(last[0])).max()))
+
+    yield KernelCase(f"{model}/ssd_step/B{ROWS_SSD}",
+                     functools.partial(ssd.ssd_step_rows,
+                                       interpret=interpret),
+                     step_operands, step_check)
+    yield KernelCase(f"{model}/ssd_chunk/T{CHUNK}",
+                     functools.partial(ssd.ssd_chunk_row,
+                                       interpret=interpret),
+                     chunk_operands, chunk_check)
+
+
 def cell_cases(interpret: bool = False):
     """The ragged read at every entry of `CELL_SHAPES`."""
     for name, shape in CELL_SHAPES.items():
@@ -374,10 +449,13 @@ def class_cases(interpret: bool = False):
 
 def kernel_cases(model: str, interpret: bool = False):
     """Every Pallas kernel site at `model`'s registry geometry."""
-    if model in LATENT_MODELS:
-        yield from _latent_cases(model, interpret)
+    if model in LATENT_MODELS + RECURRENT_MODELS:
+        if model in LATENT_MODELS:
+            yield from _latent_cases(model, interpret)
         if model in RECURRENT_MODELS:
-            yield from _recurrence_cases(model, interpret)
+            # By the kernels' names in a trace (the model's `recurrence`).
+            cases = {"kda": _recurrence_cases, "ssd": _ssd_cases}
+            yield from cases[_config(model).recurrence](model, interpret)
         return
     geo = _geometry(model)
     yield from _flash_cases(model, geo, interpret)
@@ -409,7 +487,8 @@ def main() -> int:
     enable_compilation_cache()
     worst, failed = 0.0, []
     for case in itertools.chain(
-            *(kernel_cases(model) for model in MODELS + LATENT_MODELS),
+            *(kernel_cases(model) for model in dict.fromkeys(
+                MODELS + LATENT_MODELS + RECURRENT_MODELS)),
             cell_cases(), class_cases()):
         t0 = time.monotonic()
         operands = case.operands()
@@ -420,7 +499,8 @@ def main() -> int:
             err = case.check(jax.block_until_ready(
                 jax.jit(case.kernel)(*operands)), operands)
             worst = max(worst, err)
-            limit = F32_TOLERANCE if "/kda_" in case.name else BF16_TOLERANCE
+            limit = (F32_TOLERANCE if "/kda_" in case.name
+                     or "/ssd_" in case.name else BF16_TOLERANCE)
             if not err <= limit:            # NaN fails too
                 failed.append(case.name)
         print(json.dumps({"kernel": case.name, "interpret": False,

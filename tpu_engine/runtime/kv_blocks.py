@@ -1295,11 +1295,13 @@ class StateSlabPool:
 
 
 class StateRowPool:
-    """The recurrent layers' rows of the ``kv_and_state`` family, where a
+    """The recurrent mixers' rows of the ``kv_and_state`` family, where a
     stream ALSO holds a block chain, of K and V a head
-    (models.olmo_hybrid) or of latents (models.kimi_linear): a row is, a
-    recurrent layer, one float32 array of each of `shapes` (the state and
-    the conv tail), kept as arrays of their own shape
+    (models.olmo_hybrid, models.falcon_h1) or of latents
+    (models.kimi_linear): a row is, for each of the `n_layers` layers that
+    have a recurrent mixer (some of the model's, or every one where a
+    layer has both mixers), one float32 array of each of `shapes` (the
+    state and the conv tail), kept as arrays of their own shape
     ``(n_layers, n_slots + 1, *shape)`` so that the step reads and writes
     them where they lie. `slab` is the tuple of them, donated through the
     tick like the block pool's pair.

@@ -461,10 +461,12 @@ class ContinuousGenerator:
         # behind the window back inside the tick whose position passes
         # them (`_slide_window_blocks`).
         self._windowed = fam == "kv_windowed"
-        # "kv_and_state" (models.olmo_hybrid, models.kimi_linear): a chain
-        # over a pool that holds the attention layers alone AND one row
-        # of a state pool (`_spool`: the recurrent layers' state and conv
-        # tail), one model, one row, two pools. What a block holds (K and
+        # "kv_and_state" (models.olmo_hybrid, models.kimi_linear,
+        # models.falcon_h1): a chain over a pool that holds the layers
+        # that attend AND one row of a state pool (`_spool`: the
+        # recurrent mixers' state and conv tail), one model, one row,
+        # two pools; a layer is in one of them or, where it has both
+        # mixers, in both. What a block holds (K and
         # V a head, or a latent) is the model's `kv_block_kinds[0]` and
         # changes nothing here. A row takes both at admission (parked
         # when blocks are short; a state row is its slot's own and cannot
@@ -1216,7 +1218,9 @@ class ContinuousGenerator:
                         "window read over blocks of two kinds"),
         "kv_and_state": ("a row's recurrent state is one fixed-size row, "
                          "not block-addressable, whatever its blocks hold "
-                         "(K and V a head, or a latent): it serves no "
+                         "(K and V a head, or a latent) and whichever "
+                         "layers keep it (some, or every one beside its "
+                         "blocks): it serves no "
                          "prefix hit (snapshots at block boundaries are "
                          "not kept), goes to no host tier, takes no int8 "
                          "scales, cannot be rolled back past a rejected "
@@ -4741,8 +4745,8 @@ class ContinuousGenerator:
         its span: the tokens that go through the chunked form of the
         recurrence (and the rows they belong to) and the rows that take
         one step of it, under the kernels' names in a trace (the model's
-        `recurrence`: `gdn_*` or `kda_*`); the tokens the
-        attention layers read (`ctx_tokens_full` as a windowed lane's,
+        `recurrence`: `gdn_*`, `kda_*` or `ssd_*`); the tokens the
+        layers that attend read (`ctx_tokens_full` as a windowed lane's,
         with the tiles of each class that read them, or
         `ctx_tokens_latent` where the pool's two tensors differ in width:
         a latent pool, whose read has one class); and the state rows
