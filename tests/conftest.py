@@ -83,7 +83,8 @@ def pytest_collection_modifyitems(session, config, items):
     # lists as they stood when its rehearsal file was last written. PR
     # 40's `sched.overlap_tick_share` lists all six cells, PR 39's too,
     # so ..._olmo_hybrid.py joins them; eight of PR 42's nine list all
-    # six, as does PR 43's `front.stream_writer_share`. A `benchmark` PR adds the metrics to the rehearsal files,
+    # six, as do PR 43's `front.stream_writer_share` and PR 47's two
+    # (`sched.form_ms`, `sched.form_transfers_per_tick`). A `benchmark` PR adds the metrics to the rehearsal files,
     # finds the cell by name and deletes this with the hook above
     # (PERF.md section 7).
     for name in ("test_benchmark_reference_moonlight",
@@ -106,7 +107,9 @@ class _AsTheCellWasWritten:
                       "front.stream_cpu_ms_per_tick", "lane.stream_wake_ms",
                       "front.stream_deliver_ms", "device.idle_loop",
                       "device.idle_stream", "step.gc_ms_per_s",  # PR 42
-                      "front.stream_writer_share")      # PR 43
+                      "front.stream_writer_share",      # PR 43
+                      "sched.form_ms",
+                      "sched.form_transfers_per_tick")  # PR 47
 
     def __init__(self, json_module, cell):
         self._json, self._cell = json_module, cell

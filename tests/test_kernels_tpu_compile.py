@@ -193,7 +193,10 @@ def test_tp2_paged_tick_compiles_for_v5e(v5e_devices):
     v5e devices. GSPMD refuses to partition a Mosaic kernel ("wrap the
     call in a shard_map"), so this compiles only because the read path
     runs per head shard (ops.paged_attention.shard_over_heads) — the
-    wrapper the scheduler applies to every tp > 1 lane."""
+    wrapper the scheduler applies to every tp > 1 lane. The tick's
+    per-row inputs arrive as the lane hands them: ONE control block
+    (`scheduler.TickBlock`), replicated over the mesh and taken apart
+    inside the program."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from tpu_engine.models.registry import (
@@ -206,6 +209,7 @@ def test_tp2_paged_tick_compiles_for_v5e(v5e_devices):
         shard_over_heads,
     )
     from tpu_engine.parallel.mesh import tp_mesh
+    from tpu_engine.runtime.scheduler import TickBlock
 
     _ensure_builtin_models_imported()
     spec = create_model("gpt2", n_layers=2)   # published widths, depth cut
@@ -222,14 +226,17 @@ def test_tp2_paged_tick_compiles_for_v5e(v5e_devices):
     pool = jax.ShapeDtypeStruct(
         (cfg.n_layers, nb, bs, cfg.kv_heads * cfg.d_head), jnp.bfloat16,
         sharding=NamedSharding(mesh, P(None, None, None, "model")))
-    rep = NamedSharding(mesh, P())
+    layout = TickBlock(16, [cfg.max_seq // bs])
 
-    def host(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+    def step(params, caches, block):
+        sent = layout.unpack(block)
+        return tick(params, caches, sent["tables"][0], sent["tokens"],
+                    sent["pos0"], sent["qlen"])
 
-    compiled = jax.jit(tick).lower(
-        params, KVCache(pool, pool), host((rows, cfg.max_seq // bs)),
-        host((rows, 16)), host((rows,)), host((rows,))).compile()
+    block = jax.ShapeDtypeStruct((rows, layout.cols), jnp.int32,
+                                 sharding=NamedSharding(mesh, P()))
+    compiled = jax.jit(step).lower(params, KVCache(pool, pool),
+                                   block).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

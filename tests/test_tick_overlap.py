@@ -150,6 +150,21 @@ def _ended_by(kind, order):
             assert k is not None, toks
             requests.append(dict(kw, stop_tokens=[toks[k]]))
         return requests, 3
+    if kind == "every_control_in_one_tick":
+        # What the control block carries, all in the same ticks: a row
+        # that samples through the filters, a penalised row, and a row a
+        # stop token ends one tick late (a done row of the tick behind);
+        # on the lane that runs ahead every decode row is a `from_prev`
+        # row.
+        toks = _greedy(order, prompts[2], 14)
+        k = first_fresh(toks)
+        assert k is not None, toks
+        return [dict(prompt=prompts[0], max_new_tokens=12, temperature=0.7,
+                     top_p=0.95, min_p=1e-6, seed=3),
+                dict(prompt=prompts[1], max_new_tokens=12,
+                     repetition_penalty=1.4),
+                dict(prompt=prompts[2], max_new_tokens=14,
+                     stop_tokens=[toks[k]])], 1
     if kind == "one_token":
         return [dict(prompt=p, max_new_tokens=1) for p in prompts], 0
     if kind == "cache_end":
@@ -162,7 +177,8 @@ def _ended_by(kind, order):
 
 @pytest.mark.parametrize("kind", [
     "eos", "stop", "eos_at_the_budget", "eos_first_token", "penalty",
-    "sampled", "sampled_penalty_stop", "one_token", "cache_end"])
+    "sampled", "sampled_penalty_stop", "every_control_in_one_tick",
+    "one_token", "cache_end"])
 def test_the_stream_is_the_in_order_lane_s_token_for_token(kind, ahead,
                                                            order, dense):
     requests, late = _ended_by(kind, order)
@@ -178,6 +194,10 @@ def test_the_stream_is_the_in_order_lane_s_token_for_token(kind, ahead,
     assert after["lagged_rows"] - before["lagged_rows"] == late
     assert after["overlapped_ticks"] > before["overlapped_ticks"]
     assert after["dispatches"] == after["ticks"]
+    # One control block a tick (at most three arrays: ISSUE 47).
+    ticks = after["ticks"] - before["ticks"]
+    assert ticks <= (after["form_transfers"]
+                     - before["form_transfers"]) <= 3 * ticks
     assert _pool_whole(ahead), ahead.stats()["kv_pool"]
     assert ahead.faults == []
     assert mixed_counters(order)["overlapped_ticks"] == 0
@@ -645,6 +665,10 @@ def test_a_failure_at_the_wait_drops_both_ticks_and_recovers(spec, params):
         wait_idle(gen)
         after = mixed_counters(gen)
         assert after["dispatches"] == after["ticks"] > before["ticks"]
+        # The two dropped ticks were formed, and their blocks sent.
+        formed = after["ticks"] - before["ticks"] + 2
+        assert formed <= (after["form_transfers"]
+                          - before["form_transfers"]) <= 3 * formed
         assert gen.stats().get("recover_invariant_violations", 0) == 0
         assert _pool_whole(gen)
     finally:

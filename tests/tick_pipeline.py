@@ -47,15 +47,17 @@ def held_blocks_guard(gen, bs, faults):
     promises of a row it feeds, late ends included: the row holds the
     block of every column the step writes for it (`pos0 .. pos0 + qlen -
     1`), and no column lies past `max_seq - 1`. Plain block-pool lanes
-    (tables at argument 2)."""
+    (the tick's control block at argument 2)."""
     real = gen._mixed_step_exe
 
     def guarded(width, controls):
         exe = real(width, controls)
 
         def call(*args, **kwargs):
-            tables = np.asarray(args[2])
-            pos0, qlen = np.asarray(args[4]), np.asarray(args[5])
+            sent = gen._tick_block(width, controls).unpack(
+                np.asarray(args[2]))
+            tables, = sent["tables"]
+            pos0, qlen = sent["pos0"], sent["qlen"]
             for r in np.flatnonzero(qlen > 0):
                 last = int(pos0[r] + qlen[r] - 1)
                 if last > gen.max_seq - 1:

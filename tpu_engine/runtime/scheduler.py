@@ -222,6 +222,95 @@ def take_from_prev(tokens, done, prev_nxt, prev_done, from_prev):
     return tokens, done | (from_prev & prev_done)
 
 
+class TickBlock:
+    """The layout of a mixed tick's control block: everything the host
+    hands the compiled step per row, as ONE int32 array `(B, cols)` made
+    fresh every tick, a slot a row. One host→device transfer a tick is a
+    quarter of a millisecond on a v5e host whatever its size, and the
+    fifteen small arrays a tick used to make were three quarters of a
+    short decode tick's period. The columns, in order:
+
+    - one each of `pos0`, `qlen`, `sample_slot`, `fold_pos`, `seeds`,
+      `topks`, `eos_vec` (int32 as they are), `active`, `done`,
+      `from_prev` (bool as 0 / 1), `temps`, `topps`, `minps` (float32,
+      their bits), then `state_rows` on a lane whose rows own a state
+      row, then `pens` (float32 bits) and `stops` (`MAX_STOP_TOKENS`
+      columns) in the `controls` variant;
+    - `tokens`: `width` columns;
+    - `tables`: a row's block table, and a second kind's after it on a
+      windowed lane (`table_widths`).
+
+    `pack` fills a block from numpy arrays, `unpack` takes one apart
+    inside the compiled step by static slices: a field comes back with
+    the type, shape and bits it went in with, so a step sees the
+    arguments it saw when each was an array of its own. The layout
+    follows what the lane is and the tick's `width` and `controls`, all
+    static to the step's program."""
+
+    INT = ("pos0", "qlen", "sample_slot", "fold_pos", "seeds", "topks",
+           "eos_vec")
+    BOOL = ("active", "done", "from_prev")
+    FLOAT = ("temps", "topps", "minps")
+
+    def __init__(self, width: int, table_widths: Sequence[int],
+                 state_rows: bool = False, controls: bool = False):
+        # name -> (first column, columns or None for a (B,) field, kind)
+        self.fields: dict = {}
+        self.cols = 0
+
+        def add(name, kind="int", columns=None):
+            self.fields[name] = (self.cols, columns, kind)
+            self.cols += 1 if columns is None else int(columns)
+
+        for kind, names in (("int", self.INT), ("bool", self.BOOL),
+                            ("float", self.FLOAT)):
+            for name in names:
+                add(name, kind)
+        if state_rows:
+            add("state_rows")
+        if controls:
+            add("pens", "float")
+            add("stops", columns=MAX_STOP_TOKENS)
+        add("tokens", columns=width)
+        self.n_tables = len(table_widths)
+        for k, n in enumerate(table_widths):
+            add(f"tables{k}", columns=n)
+
+    def pack(self, tables, **fields) -> np.ndarray:
+        """A fresh `(B, cols)` block of `fields` (numpy arrays by the
+        layout's names) and `tables` (a kind of block each)."""
+        fields.update({f"tables{k}": t for k, t in enumerate(tables)})
+        if fields.keys() != self.fields.keys():
+            raise ValueError("a tick's fields are not its block's: "
+                             f"{sorted(fields.keys() ^ self.fields.keys())}")
+        block = np.empty((len(fields["pos0"]), self.cols), np.int32)
+        for name, (at, columns, kind) in self.fields.items():
+            value = fields[name]
+            if kind == "float":
+                value = value.view(np.int32)
+            if columns is None:
+                block[:, at] = value
+            else:
+                block[:, at:at + columns] = value
+        return block
+
+    def unpack(self, block) -> dict:
+        """Inside the compiled step: every field of `block` by name, as
+        it went in; `tables` a tuple, a kind of block each."""
+        out = {}
+        for name, (at, columns, kind) in self.fields.items():
+            value = (block[:, at] if columns is None
+                     else block[:, at:at + columns])
+            if kind == "bool":
+                value = value != 0
+            elif kind == "float":
+                value = jax.lax.bitcast_convert_type(value, jnp.float32)
+            out[name] = value
+        out["tables"] = tuple(out.pop(f"tables{k}")
+                              for k in range(self.n_tables))
+        return out
+
+
 class _StaleAdmission(RuntimeError):
     """A prefilled item's pool pins/gather predate a pool rebuild
     (device recovery): the single request fails, the scheduler keeps
@@ -898,6 +987,7 @@ class ContinuousGenerator:
                 [None] * self.n_slots
             self._row_L = [0] * self.n_slots
             self._row_w0 = [0] * self.n_slots
+            self._tick_blocks: dict = {}
             self._stats["mixed"] = {
                 "ticks": 0, "dispatches": 0, "prefill_tokens": 0,
                 "decode_tokens": 0, "coscheduled_ticks": 0,
@@ -909,6 +999,9 @@ class ContinuousGenerator:
                 # not yet read, and row-ticks stepped past an end the
                 # host learned one tick late (`_land_tick`).
                 "overlapped_ticks": 0, "lagged_rows": 0,
+                # Host→device arrays `_tick_mixed` made while it formed
+                # its ticks, counted where each is made.
+                "form_transfers": 0,
                 "token_budget": self._mixed_budget,
                 "chunk_cap": self._chunk_cap,
             }
@@ -1490,10 +1583,24 @@ class ContinuousGenerator:
                     decode_chunk, donate_argnums=donate)
             return self._decode_exe[("paged", controls)]
 
+    def _tick_block(self, width: int, controls: bool) -> TickBlock:
+        """This lane's control block layout at a tick's `width`, with or
+        without the `controls` fields."""
+        layout = self._tick_blocks.get((width, controls))
+        if layout is None:
+            tables = [self._tables] + ([self._wtables] if self._windowed
+                                       else [])
+            layout = self._tick_blocks[(width, controls)] = TickBlock(
+                width, [t.shape[1] for t in tables],
+                state_rows=self._hybrid, controls=controls)
+        return layout
+
     def _mixed_step_exe(self, width: int, controls: bool):
         """Compiled mixed step: ONE ragged dispatch serving decode rows
         (q_len 1) and prefill-chunk rows (q_len up to `width`) together —
-        forward, KV pool writes, and sampling fused. Per-row host inputs:
+        forward, KV pool writes, and sampling fused. The host's per-row
+        inputs arrive as one control block (`TickBlock`, which states
+        the layout) and are taken apart here; of them
         `sample_slot` picks the logits slot to sample (decode: 0;
         completing prefill: L-1-pos0), `fold_pos` is the sampled token's
         logical position (the fold_in(seed, position) rule every path
@@ -1521,13 +1628,24 @@ class ContinuousGenerator:
                 else:
                     attn_fn = self._paged_attn_fn(ragged=True)
 
-                def step_core(params, caches, scales, tables, tokens,
-                              pos0, qlen, sample_slot, fold_pos, active,
-                              done, prev_nxt, prev_done, from_prev, seeds,
-                              temps, topps, topks, minps, eos_vec, counts,
-                              pens, stops):
+                layout = self._tick_block(width, controls)
+                hybrid = self._hybrid
+
+                def step_core(params, caches, scales, block, prev_nxt,
+                              prev_done, counts):
+                    f = layout.unpack(block)
+                    # The rows' table; one of each a kind of block on a
+                    # windowed lane, (table, state rows) on a hybrid one.
+                    tables = f["tables"]
+                    if len(tables) == 1:
+                        tables, = tables
+                    if hybrid:
+                        tables = (tables, f["state_rows"])
+                    pos0, qlen, sample_slot, eos_vec = (
+                        f["pos0"], f["qlen"], f["sample_slot"], f["eos_vec"])
                     tokens, done = take_from_prev(
-                        tokens, done, prev_nxt, prev_done, from_prev)
+                        f["tokens"], f["done"], prev_nxt, prev_done,
+                        f["from_prev"])
                     # sample_slot gathers the hidden state BEFORE the LM
                     # head: one (B, vocab) projection per tick, not W.
                     if own_step is not None:
@@ -1551,13 +1669,14 @@ class ContinuousGenerator:
                     rows = jnp.arange(tokens.shape[0])
                     if controls:
                         logits = apply_repetition_penalty(logits, counts,
-                                                          pens)
+                                                          f["pens"])
                     # The sampler's body is chosen by the rows whose
                     # sample is real: a released slot's controls stay
                     # where admission put them.
-                    live = active & ~done
-                    nxt = _sample(logits, seeds, fold_pos, temps, topps,
-                                  topks, minps, kept=live)
+                    live = f["active"] & ~done
+                    nxt = _sample(logits, f["seeds"], f["fold_pos"],
+                                  f["temps"], f["topps"], f["topks"],
+                                  f["minps"], kept=live)
                     nxt = jnp.where(live, nxt, eos_vec)
                     if controls:
                         counts = counts.at[rows, nxt].add(
@@ -1565,7 +1684,7 @@ class ContinuousGenerator:
                     done = done | (live & (nxt == eos_vec))
                     if controls:
                         done = done | (live & jnp.any(
-                            nxt[:, None] == stops, axis=1))
+                            nxt[:, None] == f["stops"], axis=1))
                     if quant:
                         caches, scales = self._pin_pool_out(caches,
                                                             scales)
@@ -1580,33 +1699,17 @@ class ContinuousGenerator:
                     return out
 
                 if quant:
-                    def mixed_step(params, caches, scales, tables, tokens,
-                                   pos0, qlen, sample_slot, fold_pos,
-                                   active, done, prev_nxt, prev_done,
-                                   from_prev, seeds, temps, topps, topks,
-                                   minps, eos_vec, counts=None, pens=None,
-                                   stops=None):
-                        return step_core(params, caches, scales, tables,
-                                         tokens, pos0, qlen, sample_slot,
-                                         fold_pos, active, done, prev_nxt,
-                                         prev_done, from_prev, seeds,
-                                         temps, topps, topks, minps,
-                                         eos_vec, counts, pens, stops)
-                    donate = (1, 2, 20) if controls else (1, 2)
+                    def mixed_step(params, caches, scales, block, prev_nxt,
+                                   prev_done, counts=None):
+                        return step_core(params, caches, scales, block,
+                                         prev_nxt, prev_done, counts)
+                    donate = (1, 2, 6) if controls else (1, 2)
                 else:
-                    def mixed_step(params, caches, tables, tokens, pos0,
-                                   qlen, sample_slot, fold_pos, active,
-                                   done, prev_nxt, prev_done, from_prev,
-                                   seeds, temps, topps, topks, minps,
-                                   eos_vec, counts=None, pens=None,
-                                   stops=None):
-                        return step_core(params, caches, None, tables,
-                                         tokens, pos0, qlen, sample_slot,
-                                         fold_pos, active, done, prev_nxt,
-                                         prev_done, from_prev, seeds,
-                                         temps, topps, topks, minps,
-                                         eos_vec, counts, pens, stops)
-                    donate = (1, 19) if controls else (1,)
+                    def mixed_step(params, caches, block, prev_nxt,
+                                   prev_done, counts=None):
+                        return step_core(params, caches, None, block,
+                                         prev_nxt, prev_done, counts)
+                    donate = (1, 5) if controls else (1,)
                 self._decode_exe[key] = jax.jit(mixed_step,
                                                 donate_argnums=donate)
             return self._decode_exe[key]
@@ -4821,7 +4924,10 @@ class ContinuousGenerator:
         (1 token each); the remaining budget splits over prefilling rows
         in row order — the first prefilling row always gets at least one
         token, so admission can never deadlock behind a saturated decode
-        batch.
+        batch. What the step takes per row (positions, lengths, tokens,
+        sampling controls, the block tables) goes to the device as ONE
+        array made fresh here, the control block: `TickBlock` states its
+        layout, `form_transfers` counts the arrays sent.
 
         A pipeline one tick deep: the tick is formed and enqueued BEFORE
         the results of the tick in flight are read and applied
@@ -4936,21 +5042,20 @@ class ContinuousGenerator:
                 active[r] = not self._done[r] and not self._held[r]
 
         # ONE dispatch, under the pool lock (it donates the pool buffers).
-        # The rows' tables and controls go as copies: the loop changes
-        # them (admission, release) while the step may still read them.
-        def copied(a):
-            return jnp.asarray(a.copy())
-
+        # The rows' tables and controls go in a block made fresh here
+        # (`TickBlock`): the loop changes them (admission, release) while
+        # the step may still read it.
         if self._windowed:
             self._slide_window_blocks(pos0, qlen)
         with pool.lock:
-            pool_args, tables = (pool.caches,), copied(self._tables)
+            pool_args, tables = (pool.caches,), (self._tables,)
             if self._quant:
                 pool_args += (pool.scales,)
             if self._windowed:
                 # One of each a kind of block, (full, window).
                 pool_args = ((pool.caches, self._wpool.caches),)
-                tables = (tables, copied(self._wtables))
+                tables += (self._wtables,)
+            extra = {}
             if self._hybrid:
                 # The block pool and the state pool, the rows' table and
                 # their state rows; both pools are donated. The state
@@ -4960,16 +5065,18 @@ class ContinuousGenerator:
                 self._note_state_work(pos0, qlen)
                 pool_args = ((pool.caches,
                               self._spool.slab),)  # lint: lockfree-ok tick thread's alone
-                tables = (tables, copied(self._spool.rows))
-            common = (self._step_params, *pool_args, tables,
-                      jnp.asarray(tokens), jnp.asarray(pos0),
-                      jnp.asarray(qlen), jnp.asarray(sample_slot),
-                      jnp.asarray(fold_pos), jnp.asarray(active),
-                      copied(self._done), self._prev_nxt, self._prev_done,
-                      jnp.asarray(from_prev), copied(self._seeds),
-                      copied(self._temps), copied(self._topps),
-                      copied(self._topks), copied(self._minps),
-                      jnp.asarray(eos_vec))
+                extra["state_rows"] = self._spool.rows
+            if controls:
+                extra.update(pens=self._pens, stops=self._stops)
+            block = self._tick_block(width, controls).pack(
+                tables, tokens=tokens, pos0=pos0, qlen=qlen,
+                sample_slot=sample_slot, fold_pos=fold_pos, active=active,
+                done=self._done, from_prev=from_prev, seeds=self._seeds,
+                temps=self._temps, topps=self._topps, topks=self._topks,
+                minps=self._minps, eos_vec=eos_vec, **extra)
+            self._stats["mixed"]["form_transfers"] += 1
+            common = (self._step_params, *pool_args, jnp.asarray(block),
+                      self._prev_nxt, self._prev_done)
             if prev is not None:
                 # Before `_tick_formed` marks the dispatch, which reads
                 # what the probes saw.
@@ -4977,11 +5084,8 @@ class ContinuousGenerator:
             self._tick_formed(width, prefill_rows, chunk, qlen, active,
                               pos0)
             if controls:
-                out = self._mixed_step_exe(width, True)(
-                    *common, self._ensure_counts(),
-                    copied(self._pens), copied(self._stops))
-            else:
-                out = self._mixed_step_exe(width, False)(*common)
+                common += (self._ensure_counts(),)
+            out = self._mixed_step_exe(width, controls)(*common)
             if self._windowed:
                 pool.caches, self._wpool.caches = out[0]
             elif self._hybrid:

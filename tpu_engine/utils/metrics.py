@@ -378,6 +378,9 @@ def render_prometheus(healths: List[Dict], stats: Optional[Dict] = None,
     metric("tpu_engine_mixed_lagged_rows_total", "counter",
            "Row-ticks stepped past an end the host learned one tick late",
            [(node(h), m.get("lagged_rows")) for h, m in mx])
+    metric("tpu_engine_mixed_form_transfers_total", "counter",
+           "Host-to-device arrays made while mixed ticks were formed",
+           [(node(h), m.get("form_transfers")) for h, m in mx])
     metric("tpu_engine_mixed_token_budget", "gauge",
            "Per-tick new-token budget (--mixed-token-budget)",
            [(node(h), m.get("token_budget")) for h, m in mx])
