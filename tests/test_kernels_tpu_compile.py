@@ -88,15 +88,21 @@ def test_the_state_space_recurrence_compiles_for_v5e_in_both_forms(
     assert _pallas_grids(jaxpr.jaxpr) == [(64, 2)]
 
 
-def _pallas_grids(jaxpr):
-    """The grid of every `pallas_call` in a jaxpr, nested calls included."""
-    grids = []
+def _pallas_calls(jaxpr):
+    """The parameters of every `pallas_call` in a jaxpr, nested calls
+    included."""
+    calls = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            grids.append(tuple(eqn.params["grid_mapping"].grid))
+            calls.append(eqn.params)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            grids += _pallas_grids(sub)
-    return grids
+            calls += _pallas_calls(sub)
+    return calls
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every `pallas_call` in a jaxpr, nested calls included."""
+    return [tuple(call["grid_mapping"].grid) for call in _pallas_calls(jaxpr)]
 
 
 @pytest.mark.parametrize("cell", sorted(kernel_check.CELL_SHAPES))
@@ -125,6 +131,43 @@ def test_paged_grid_is_the_query_tiles_not_the_table(v5e_devices, cell):
         assert grids == [(rows, tiles)], grids
     kernel_check.compile_for_topology(
         dataclasses.replace(case, operands=lambda: half), v5e_devices[0])
+
+
+@pytest.mark.parametrize("cell", sorted(kernel_check.CELL_SHAPES))
+def test_the_paged_walk_is_one_pipeline_over_a_sequential_grid(cell):
+    """Since PR 48 a step starts the next step's first copies, so the
+    grid's steps run in order (both axes "arbitrary"; a "parallel" axis
+    could be split between cores or reordered) and what one step leaves
+    the next is an SMEM scratch beside ONE DMA semaphore a tensor and
+    buffer (a buffer's copies are waited for by their bytes). That Mosaic
+    takes all of it at this shape is
+    `test_paged_grid_is_the_query_tiles_not_the_table`."""
+    (case,) = [c for c in kernel_check.cell_cases(interpret=False)
+               if c.name == cell]
+    (call,) = _pallas_calls(jax.make_jaxpr(case.kernel)(
+        *jax.eval_shape(case.operands)).jaxpr)
+    semantics = call["compiler_params"]["mosaic_tpu"].dimension_semantics
+    assert tuple(str(s).lower().rsplit(".", 1)[-1] for s in semantics) == (
+        "arbitrary", "arbitrary")
+    scratch = [str(aval) for aval in call["grid_mapping"].scratch_avals]
+    assert sum("smem" in s.lower() and "int32[2]" in s.replace(" ", "")
+               for s in scratch) == 1, scratch
+    assert sum("sem" in s.lower() and "[2,2]" in s.replace(" ", "")
+               for s in scratch) == 1, scratch
+
+
+def test_the_walk_s_own_cases_compile_for_v5e(v5e_devices):
+    """`kernel_check.walk_cases`: what chip_smoke.py's kernel phase runs
+    of the pipeline (dead steps between live ones, horizons on a block's
+    edge, lower bounds groups apart), every one through Mosaic."""
+    from tpu_engine.ops import paged_attention as pa
+
+    names = []
+    for case in kernel_check.walk_cases(interpret=False):
+        kernel_check.compile_for_topology(case, v5e_devices[0])
+        names.append(case.name)
+    assert names == [f"walk/{n}" for n in pa.WALK_CASES] + [
+        f"walk/window/{n}" for n in pa.WINDOW_CASES]
 
 
 @pytest.mark.parametrize("name, grids", [

@@ -11,7 +11,8 @@ against the scan.
 `cell_cases` adds the ragged read at the shapes the benchmark's cells
 serve it at (`CELL_SHAPES`), `class_cases` the two calls a tick that
 carries a chunk makes of its rows, by the class of their runs
-(`CLASS_SHAPES`). Two consumers:
+(`CLASS_SHAPES`), `walk_cases` the small workloads the paged walk's
+pipeline can get wrong. Two consumers:
 
 - `python -m tpu_engine.ops.kernel_check` — chip_smoke.py's kernel
   phase: on the attached TPU, compile every case with `interpret=False`
@@ -447,6 +448,47 @@ def class_cases(interpret: bool = False):
                                     interpret=interpret), workload, check)
 
 
+# The cases the paged walk can get wrong (`pa.WALK_CASES`, `pa.WINDOW_CASES`:
+# dead steps between live ones, a horizon on a block's edge, walks that
+# start groups apart side by side), compiled: 2 KV heads of 64 lanes, a
+# block the 128 lanes Mosaic's DMA needs, at the tables' own group sizes
+# (four query heads a KV head, six under a window).
+WALK_GEOMETRY = dict(n_kv_heads=2, d_head=64)
+
+
+def walk_cases(interpret: bool = False):
+    """The ragged read over every entry of `pa.WALK_CASES` and of
+    `pa.WINDOW_CASES`: the pipeline over a call's steps as the chip runs
+    it, where the interpreters on the CPU only model its DMAs."""
+    cases = [(f"walk/{name}", q_lens, pos0, None, table_len, 4)
+             for name, (q_lens, pos0, table_len) in pa.WALK_CASES.items()]
+    cases += [(f"walk/window/{name}", q_lens, pos0, window, table_len, 6)
+              for name, (q_lens, pos0, window, table_len)
+              in pa.WINDOW_CASES.items()]
+    for name, q_lens, pos0, window, table_len, group in cases:
+        kernel_fn, reference_fn = (
+            functools.partial(fn, window=window)
+            for fn in pa.READ_PATHS["ragged"])
+        workload = functools.partial(
+            pa.parity_workload, "ragged", q_lens, block_size=BLOCK_SIZE,
+            n_blocks=1 + len(q_lens) * table_len, table_len=table_len,
+            dtype=jnp.bfloat16, pos0=pos0, window=window,
+            n_heads=group * WALK_GEOMETRY["n_kv_heads"], **WALK_GEOMETRY)
+
+        # Nineteen small shapes: the workload and the gather reference one
+        # compiled program each, not an XLA compile an eager operation.
+        gap = jax.jit(functools.partial(pa.reference_gap, reference_fn))
+
+        def check(out, operands, gap=gap,
+                  qlen=jnp.asarray(q_lens, jnp.int32)):
+            return float(gap(out, operands, qlen))
+
+        yield KernelCase(name,
+                         functools.partial(kernel_fn, interpret=interpret),
+                         jax.jit(lambda workload=workload: workload()[0]),
+                         check)
+
+
 def kernel_cases(model: str, interpret: bool = False):
     """Every Pallas kernel site at `model`'s registry geometry."""
     if model in LATENT_MODELS + RECURRENT_MODELS:
@@ -489,7 +531,7 @@ def main() -> int:
     for case in itertools.chain(
             *(kernel_cases(model) for model in dict.fromkeys(
                 MODELS + LATENT_MODELS + RECURRENT_MODELS)),
-            cell_cases(), class_cases()):
+            cell_cases(), class_cases(), walk_cases()):
         t0 = time.monotonic()
         operands = case.operands()
         if case.check is None:

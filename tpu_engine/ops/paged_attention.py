@@ -54,12 +54,35 @@ the (bs, H_kv, D) block of the chain wire format, chosen by the layer
 index and the block table in SMEM (neither the layer nor the gather
 ever materializes), into one of two VMEM buffers while the other is
 folded into running flash accumulators (f32 max / denominator /
-weighted sum in VMEM scratch). No step, DMA or table read is spent on a
-column past a tile's horizon, so a short row under a long table costs
-its own blocks and nothing else — the ragged-batch win the TPU
-paged-attention kernel exists for (PAPERS.md "Ragged Paged Attention").
-Columns past the horizon inside the last group are masked as scores and
-zeroed as values.
+weighted sum in VMEM scratch). A group's copies are those of its blocks
+that hold a column the tile sees: none is started for a block wholly
+past the horizon (or behind a window's lower bound), so a row of 48
+columns under a group of 128 fetches 3 blocks, not 8, and a short row
+under a long table costs its own blocks and nothing else — the
+ragged-batch win the TPU paged-attention kernel exists for (PAPERS.md
+"Ragged Paged Attention"). The one block the horizon falls in is fetched
+whole; its columns past the horizon, and the buffer rows no copy filled,
+are selected away as scores and as values.
+
+**The walk is one DMA pipeline over the whole call** (PR 48). The grid's
+steps run in order (both axes "arbitrary": one TensorCore a v5e chip, and
+a step now depends on the one before it), and the fold of a tile's LAST
+group starts the copies of the FIRST group of the grid's next step,
+(b, t + 1) else (b + 1, 0), into the buffer it is not folding, when that
+step is live: by the next step's own horizon, lower bound and table row,
+the expressions that step uses for itself. Two words of SMEM scratch
+carry "started, and into which buffer" across the step's edge. So a live
+tile behind a live tile begins with its first group in flight, and only
+the call's first tile or one behind a dead step starts its own and waits
+with nothing to fold. No copy is ever started that the very next step
+does not wait for, and the call's last live tile starts none. A buffer's
+copies signal ONE semaphore a tensor, which counts bytes: a whole group is
+straight-line code and one wait a tensor; a group cut by the horizon goes
+by the bits of its block count (4 + 2 + 1), so no block costs a branch of
+its own (on the chip sixteen predicated copies a group cost more than the
+bytes they saved, PERF.md section 6, PR 48). `walk_counts` applies the
+same rules on the host: a tick's span says how many of its tiles were
+warm and how many tokens its DMAs fetched.
 
 Tile geometry comes from the shapes the call sees (`_tile_geometry`),
 one walk for both: a chunk's tile is 128 query rows a KV head against
@@ -124,7 +147,8 @@ _ROW_TILE = 128
 # 640 KB buffers a tensor at gpt2-large's 1280 lanes, 512 KB at Mistral's
 # 1024). A decode row's (heads packed, `_tile_geometry`): 8, so a row of a
 # few hundred tokens already overlaps its second group's DMAs with the
-# first group's products (measured, PERF.md section 6, PR 29).
+# first group's products (measured, PERF.md section 6, PR 29); since PR 48
+# its first group's too, with the fold of the row before it.
 _BLOCKS_PER_GROUP = 16
 _BLOCKS_PER_GROUP_PACKED = 8
 # What a group's four VMEM buffers (K and V, two each) may take: a pool of
@@ -271,11 +295,15 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
     b (row r = slot r // G, group head r % G) for ALL its KV heads.
     q_ref/o_ref (1, H_kv, rows, D); k_hbm/v_hbm: the whole pools, left in
     HBM. A tile with no valid slot writes zeros; a live one walks ITS
-    OWN context, `blocks` physical blocks a group: the blocks' DMAs
-    (picked by `layer_ref` and the block table) fill one of two VMEM
-    buffers (blocks, bs, H_kv*D) while the other is folded into the
-    flash accumulators, so nothing is spent on a table column past what
-    the tile's last slot sees.
+    OWN context, `blocks` physical blocks a group: the DMAs of a group's
+    blocks that hold a column the tile sees (picked by `layer_ref` and
+    the block table) fill one of two VMEM buffers (blocks, bs, H_kv*D)
+    while the other is folded into the flash accumulators. The walk is
+    one pipeline over the CALL: the fold of a tile's last group starts
+    the first group of the grid's next step where that tile is live
+    (`warm_sc`: (2,) in SMEM, whether this step's first group was
+    started for it, and into which buffer), so only a tile after a dead
+    step, or the call's first, waits for a fetch with nothing to fold.
 
     `pack` KV heads share a score tile of M = pack * rows rows (row
     i = head i // rows of the chunk, query row i % rows): head h's
@@ -285,11 +313,13 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
     kept. Statistics (m/l: (H_kv/pack, M, 1)) stay columns, so no step
     moves a vector between lanes and sublanes; acc: (H_kv, M, D) f32.
     Causal masking within the new-token window: query row r keeps
-    kpos <= pos0 + r // G.
+    kpos <= pos0 + r // G; a padding row (a slot past q_len) reads as
+    the tile's last valid one, so no row keeps a column past the
+    horizon, whose block was never fetched.
 
     `window` (static; a sliding-window layer) is the walk's OTHER end:
     query row r also needs kpos > pos0 + r // G - window, so the walk
-    starts at the group that holds the first column the tile's FIRST row
+    starts at the block that holds the first column the tile's FIRST row
     still sees, and nothing is spent on the context behind it. Table
     entries behind the window may be the null block.
 
@@ -301,16 +331,33 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
     the reference's product, rounded once at the int8 write."""
     if quant:
         ks_ref, vs_ref, *rest = rest
-    o_ref, k_buf, v_buf, sems, *scratch = rest
+    o_ref, k_buf, v_buf, sems, warm_sc, *scratch = rest
     qx_sc = scratch.pop(0) if pack > 1 else None
     m_sc, l_sc, acc_sc = scratch
-    b = pl.program_id(0)
+    b, t = pl.program_id(0), pl.program_id(1)
+    n_b, n_t = pl.num_programs(0), pl.num_programs(1)
     n_kv_heads, rows, d_head = q_ref.shape[1:]
     m_rows = pack * rows
     span = blocks * block_size
-    pos0 = pos0_ref[b]
-    first = pl.program_id(1) * rows            # the tile's first query row
-    live = first < (lengths_ref[b] - pos0) * group
+
+    def tile(b, t):
+        """(live, horizon, lower, first group) of grid step (b, t).
+        `horizon`: the columns the tile's LAST query row sees, capped at
+        pos0 + q_len; `lower`: the first column its FIRST row still sees."""
+        pos0, first = pos0_ref[b], t * rows
+        horizon = jnp.minimum(lengths_ref[b],
+                              pos0 + (first + rows - 1) // group + 1)
+        lower = 0 if window is None else jnp.maximum(
+            pos0 + first // group - (window - 1), 0)
+        return (first < (lengths_ref[b] - pos0) * group, horizon, lower,
+                lower // span)
+
+    live, horizon, lower, first_group = tile(b, t)
+    # Was this step's first group started by the step before, and where?
+    # (The scratch holds nothing at the call's first step.)
+    warm = jnp.logical_and(jnp.logical_or(b > 0, t > 0), warm_sc[0] == 1)
+    first_slot = jnp.where(warm, warm_sc[1], 0)
+    warm_sc[0] = 0
 
     @pl.when(jnp.logical_not(live))
     def _dead():
@@ -319,28 +366,60 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
     @pl.when(live)
     def _tile():
         layer = layer_ref[0]
-        # Columns the tile's LAST query row sees, capped at pos0 + q_len.
-        horizon = jnp.minimum(lengths_ref[b],
-                              pos0 + (first + rows - 1) // group + 1)
+        pos0, first = pos0_ref[b], t * rows
         groups = (horizon + span - 1) // span
-        first_group = 0
-        if window is not None:
-            # The first column the tile's FIRST query row still sees.
-            lower = jnp.maximum(pos0 + first // group - (window - 1), 0)
-            first_group = lower // span
+        # The grid's next step, (b, t + 1) else (b + 1, 0), as it will see
+        # itself: the one tile this one may fetch for.
+        wrap = t + 1 == n_t
+        b_next = jnp.minimum(jnp.where(wrap, b + 1, b), n_b - 1)
+        live_next, horizon_next, lower_next, first_group_next = tile(
+            b_next, jnp.where(wrap, 0, t + 1))
+        live_next = jnp.logical_and(        # the call's last step has none
+            live_next, jnp.logical_not(jnp.logical_and(wrap, b + 1 == n_b)))
 
-        def copies(g, slot):
-            out = []
-            for i in range(blocks):
-                blk = tables_ref[b, g * blocks + i]
-                out += [pltpu.make_async_copy(
-                    pool.at[layer, blk], buf.at[slot, i], sems.at[n, slot, i])
-                    for n, (pool, buf) in enumerate(((k_hbm, k_buf),
-                                                     (v_hbm, v_buf)))]
-            return out
+        def group_copies(b, g, slot, horizon, lower, do, wait=False):
+            """Start, or wait for, the K and V copies of the blocks of row
+            b's group g that hold a column in [lower, horizon), into
+            buffer `slot`; none unless `do`. They are one run [lo, lo + n)
+            of the group's blocks, and a start and its wait name the same
+            run. A buffer's copies signal ONE semaphore a tensor, which
+            counts bytes: a whole group (every group of a long walk but
+            its ends) is straight-line code and one wait a tensor, a part
+            of one goes by the bits of n, a power of two of blocks at a
+            time, so no block costs a branch of its own."""
+            j0 = g * blocks
+            lo = 0 if window is None else jnp.maximum(
+                lower // block_size - j0, 0)
+            n = jnp.where(do, jnp.minimum(
+                (horizon + block_size - 1) // block_size - j0, blocks) - lo,
+                0)
 
-        for copy in copies(first_group, first_group % 2):
-            copy.start()
+            def run(first, count):
+                for pool, buf, sem in ((k_hbm, k_buf, sems.at[0, slot]),
+                                       (v_hbm, v_buf, sems.at[1, slot])):
+                    if wait:
+                        dst = buf.at[slot, pl.ds(0, count)]
+                        pltpu.make_async_copy(dst, dst, sem).wait()
+                        continue
+                    for i in range(count):
+                        pltpu.make_async_copy(
+                            pool.at[layer, tables_ref[b, j0 + first + i]],
+                            buf.at[slot, first + i], sem).start()
+
+            @pl.when(n == blocks)
+            def _():
+                run(0, blocks)
+
+            @pl.when(jnp.logical_and(n > 0, n < blocks))
+            def _():
+                first, size = lo, 1 << (blocks - 1).bit_length() >> 1
+                while size:
+                    pl.when(n & size != 0)(
+                        functools.partial(run, first, size))
+                    first, size = first + (n & size), size // 2
+
+        group_copies(b, first_group, first_slot, horizon, lower,
+                  jnp.logical_not(warm))
         m_sc[...] = jnp.full(m_sc.shape, _NEG_INF, jnp.float32)
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
@@ -349,10 +428,12 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
             for h in range(n_kv_heads):
                 qx_sc[h, pl.ds(h % pack * rows, rows), :] = q_ref[0, h]
         # qpos - (column inside a group): a column of group g is kept
-        # where this reaches g * span.
+        # where this reaches g * span. qpos stops at the horizon's last
+        # column (a valid row's is below it already).
         shape = (m_rows, span)
-        reach = pos0 + (first + jax.lax.broadcasted_iota(
-            jnp.int32, shape, 0) % rows) // group \
+        reach = jnp.minimum(
+            pos0 + (first + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 0) % rows) // group, horizon - 1) \
             - jax.lax.broadcasted_iota(jnp.int32, shape, 1)
 
         def head(buf, slot, h):                 # -> (span, D)
@@ -361,21 +442,29 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
                 x = x.astype(jnp.float32)
             return x.reshape(span, d_head)
 
-        def fold(g, carry):
-            slot = g % 2
+        def fold(g, slot):
+            # What the pipeline fetches while group g is folded: this
+            # tile's next group, or after its last one the next step's
+            # first, into the buffer this iteration does not fold.
+            own = g + 1 < groups
+            group_copies(jnp.where(own, b, b_next),
+                      jnp.where(own, g + 1, first_group_next), 1 - slot,
+                      jnp.where(own, horizon, horizon_next),
+                      jnp.where(own, lower, lower_next),
+                      jnp.logical_or(own, live_next))
 
-            @pl.when(g + 1 < groups)
-            def _prefetch():
-                for copy in copies(g + 1, 1 - slot):
-                    copy.start()
+            @pl.when(jnp.logical_and(jnp.logical_not(own), live_next))
+            def _next_step_is_warm():
+                warm_sc[0] = 1
+                warm_sc[1] = 1 - slot
 
-            for copy in copies(g, slot):
-                copy.wait()
+            group_copies(b, g, slot, horizon, lower, True, wait=True)
             keep = reach >= g * span
             if window is not None:
                 keep &= reach < g * span + window
-            # Columns past the horizon hold the null block's or a later
-            # block's bytes: masked as scores, zeroed as values (0 * NaN).
+            # A block past the horizon (or behind `lower`) was not
+            # fetched: its buffer rows hold an older group's bytes or
+            # nothing yet. Selected away as scores, and as values.
             if quant:
                 seen = (g * span + jax.lax.broadcasted_iota(
                     jnp.int32, (1, span), 1)) < horizon
@@ -418,14 +507,48 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
                     acc_sc[h] = acc_sc[h] * corr + jax.lax.dot_general(
                         pv, v, dimension_numbers=(((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)
-            return carry
+            return 1 - slot
 
-        jax.lax.fori_loop(first_group, groups, fold, 0)
+        jax.lax.fori_loop(first_group, groups, fold, first_slot)
         for h in range(n_kv_heads):
             own = pl.ds(h % pack * rows, rows)    # head h's rows of the M
             l = l_sc[h // pack, own, :]
             o_ref[0, h] = (acc_sc[h, own, :] / jnp.where(l == 0.0, 1.0, l)
                            ).astype(o_ref.dtype)
+
+
+def walk_tiles(pos0, qlen, *, width: int, group: int, kv_heads: int,
+               block_size: int, window=None):
+    """The kernel's own rules on the host (numpy), a tile of ONE
+    `_paged_call` an entry, (B, T) in the grid's order: `live`, where the
+    tile holds a valid slot, and the table entries [`lo`, `hi`) its DMAs
+    fetch: the whole blocks of `block_size` columns that hold a column in
+    [lower, horizon), whatever a group's size. Rows at `pos0` with `qlen`
+    new tokens each, in a call `width` slots wide with `group` query heads
+    a KV head."""
+    import numpy as np
+
+    pos0, qlen = np.asarray(pos0), np.asarray(qlen)
+    rows = _tile_geometry(width * group, kv_heads)[0]
+    first = np.arange(-(-width * group // rows))[None, :] * rows     # (1, T)
+    live = first < (qlen * group)[:, None]
+    horizon = np.minimum((pos0 + qlen)[:, None],
+                         pos0[:, None] + (first + rows - 1) // group + 1)
+    lower = np.zeros_like(horizon) if window is None else np.maximum(
+        pos0[:, None] + first // group - (window - 1), 0)
+    return live, lower // block_size, -(-horizon // block_size)
+
+
+def walk_counts(pos0, qlen, *, block_size: int, **call):
+    """(live tiles, warm tiles, tokens fetched) of one `_paged_call`
+    (`walk_tiles`' arguments). A tile is WARM where the grid's step right
+    before it, (b, t - 1) else the last tile of row b - 1, is live too and
+    so started its first group. What the span of a tick says of its read
+    (`runtime.scheduler`), and what the tests hold the kernel's DMAs to."""
+    live, lo, hi = walk_tiles(pos0, qlen, block_size=block_size, **call)
+    order = live.ravel()
+    return (int(order.sum()), int((order[1:] & order[:-1]).sum()),
+            int((hi - lo)[live].sum()) * block_size)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window"))
@@ -503,7 +626,8 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
             scratch_shapes=[
                 pltpu.VMEM((2, blocks, bs, h_kv * d), k_pool.dtype),
                 pltpu.VMEM((2, blocks, bs, h_kv * d), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2, blocks)),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
             ] + [pltpu.VMEM((h_kv, m_rows, d), q.dtype)] * (pack > 1) + [
                 pltpu.VMEM((h_kv // pack, m_rows, 1), jnp.float32),
                 pltpu.VMEM((h_kv // pack, m_rows, 1), jnp.float32),
@@ -512,7 +636,7 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
         ),
         out_shape=jax.ShapeDtypeStruct((b, h_kv, r_pad, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         **({} if window is None else {"name": "swa_window_read"}),
     )(tables, pos0, lengths, layer, *operands)
@@ -523,7 +647,9 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
 def _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0, qlen,
            interpret, window=None):
     """Entry-point glue: `interpret=None` auto-selects (compiled on TPU,
-    the Pallas interpreter elsewhere); host ints become int32 arrays."""
+    the Pallas interpreter elsewhere; a `pltpu.InterpretParams` asks for
+    the TPU interpreter, which runs the DMAs and semaphores as such); host
+    ints become int32 arrays."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     pos0 = jnp.asarray(pos0, jnp.int32)
@@ -531,7 +657,7 @@ def _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0, qlen,
                        jnp.asarray(layer, jnp.int32).reshape(1),
                        jnp.asarray(tables, jnp.int32), pos0,
                        pos0 + jnp.asarray(qlen, jnp.int32),
-                       interpret=bool(interpret), window=window)
+                       interpret=interpret, window=window)
 
 
 def paged_attention(q, k_pool, v_pool, layer, tables, pos_vec, *,
@@ -754,12 +880,13 @@ def parity_workload(kind: str, q_lens, *, n_heads: int, n_kv_heads: int,
     return (q, *pool, *where), qlen
 
 
-def reference_error(reference_fn, out, operands, qlen) -> float:
+def reference_gap(reference_fn, out, operands, qlen):
     """Max |out - reference| over the VALID query slots (slot <
-    qlen[row]; padding slots are garbage by contract). The reference
-    runs on f32 copies of the operands at the highest matmul precision,
-    so on a TPU the distance measures the kernel, not the reference's
-    own bf16 passes (a no-op for the f32 CPU parity tests)."""
+    qlen[row]; padding slots are garbage by contract), a traceable
+    scalar. The reference runs on f32 copies of the operands at the
+    highest matmul precision, so on a TPU the distance measures the
+    kernel, not the reference's own bf16 passes (a no-op for the f32 CPU
+    parity tests)."""
     f32 = tuple(x.astype(jnp.float32)
                 if jnp.issubdtype(x.dtype, jnp.floating) else x
                 for x in operands)
@@ -767,7 +894,12 @@ def reference_error(reference_fn, out, operands, qlen) -> float:
         ref = reference_fn(*f32)
     diff = jnp.abs(out.astype(jnp.float32) - ref)
     valid = jnp.arange(out.shape[1])[None, :] < qlen[:, None]
-    return float(jnp.max(jnp.where(valid[:, :, None, None], diff, 0.0)))
+    return jnp.max(jnp.where(valid[:, :, None, None], diff, 0.0))
+
+
+def reference_error(reference_fn, out, operands, qlen) -> float:
+    """`reference_gap` as a host float."""
+    return float(reference_gap(reference_fn, out, operands, qlen))
 
 
 def _parity(kind: str, q_lens, *, interpret, **shape) -> float:
@@ -808,6 +940,26 @@ WALK_CASES = {
     # A 64-slot chunk at G = 4 is two tiles of 32 slots; the first one's
     # horizon (272) already crosses the group boundary at 256.
     "chunk-tiles-straddle-a-group": ((64, 1), (240, 255), 20),
+    # What the pipeline over the call's steps can get wrong. Width 1: a
+    # live row fetches for the live row after it and for no dead one; a
+    # row of one group leaves its neighbour the other buffer, a row of two
+    # groups (201, 131 columns) the first again.
+    "neighbours-dead-and-live-width-1": ((1, 0, 1, 1, 0, 1),
+                                         (37, 9, 200, 130, 50, 3), 16),
+    # The call's last step is live and warm: it starts no copy at all.
+    "live-last-row-width-1": ((1, 1, 1), (10, 300, 140), 20),
+    # Rows of exactly 1, 128 and 129 columns: one block, a whole group (the
+    # straight-line fetch), one column into the second group.
+    "rows-of-1-128-129-columns": ((1, 1, 1, 1), (0, 127, 128, 0), 16),
+    # A horizon on a block's edge (16, 144 columns) and one past it (17,
+    # 145): the last block fetched holds 16 columns, or one.
+    "horizon-on-a-block-edge-width-1": ((1, 1, 1, 1), (15, 16, 143, 144),
+                                        16),
+    "horizon-on-a-block-edge": ((40, 40), (8, 9), 8),
+    # Tiles of 32 slots: a tall tile, then the same row's dead tile (no
+    # copy started for it, none for the row behind it: that row's first
+    # tile is cold, its second warm), then a decode row's one live tile.
+    "tall-tile-dead-tile-live-row": ((20, 64, 1), (300, 40, 500), 36),
 }
 
 
@@ -841,6 +993,13 @@ WINDOW_CASES = {
     # Tiles of eight slots as rows of the call, as a step over the tick's
     # tokens hands them over: any pos0, a short last tile.
     "tiles-of-eight-slots": ((8, 8, 1, 5), (0, 37, 520, 100), 40, 48),
+    # A tile fetches its neighbour's first group by the NEIGHBOUR's lower
+    # bound: walks that start five, zero, four and two groups in, side by
+    # side (width 1), and the same between tiles of eight slots.
+    "first-groups-differ-between-neighbours": (
+        (1, 1, 1, 1), (700, 5, 650, 300), 40, 48),
+    "first-groups-differ-between-tiles-of-eight": (
+        (8, 8, 8), (700, 0, 333), 40, 48),
 }
 
 
@@ -923,6 +1082,12 @@ CLASS_CASES = {
     # Runs of two and three tokens: too long for the short class, a tall
     # tile with a few live slots each.
     "runs-just-past-short": ((2, 1, 3, 1), (0, 33, 126, 255), None),
+    # Both calls hold dead steps between live ones: the short call's first
+    # two rows (a tall run, a free slot), the tall call's every tile past
+    # the run's own (two of 128 slots at one head a KV head, five of 32 at
+    # four).
+    "a-tall-run-a-dead-row-then-short-rows": ((130, 0, 1, 1),
+                                              (20, 0, 300, 77), None),
 }
 
 

@@ -77,6 +77,7 @@ from tpu_engine.models.transformer import (
 )
 from tpu_engine.ops.attention import KVCache
 from tpu_engine.ops.latent_attention import class_counts
+from tpu_engine.ops.paged_attention import walk_counts
 from tpu_engine.runtime.generator import (
     _DTYPES,
     SAMPLER_BODIES,
@@ -4775,7 +4776,12 @@ class ContinuousGenerator:
         starved, then mark the clock's `dispatch`. `ctx_tokens` is the
         context the attention kernel reads for the rows in the dispatch:
         `pos0 + qlen` (a decode row's pos + 1, a prefilling row's
-        w0 + chunk); a recurrent step (no `pos0`) attends none. `active`:
+        w0 + chunk); a recurrent step (no `pos0`) attends none. The
+        uniform step reads every row by ONE call a layer, a row of the
+        lane a row of the call: how that call's walk goes
+        (`ops.paged_attention.walk_counts`: its live tiles, those whose
+        first group the step before started, the tokens its DMAs fetch)
+        is on the span beside it. `active`:
         the rows whose sample is real; the body of `_sample` they ask for
         (the step asks the same of the same controls on the device; a
         speculative step's first slot, its later ones never a dearer)
@@ -4794,6 +4800,14 @@ class ContinuousGenerator:
         fed = qlen > 0
         ctx_tokens = (int((pos0[fed] + qlen[fed]).sum())
                       if pos0 is not None else 0)
+        if pos0 is not None and self._ragged_step is None:
+            live, warm, fetched = walk_counts(
+                pos0, qlen, width=width,
+                group=self.cfg.n_heads // self.cfg.kv_heads,
+                kv_heads=self.cfg.kv_heads,
+                block_size=self._pool.block_size)
+            self._clock.note(walk_live_tiles=live, walk_warm_tiles=warm,
+                             walk_tokens_fetched=fetched)
         self._clock.dispatch(width, int(fed.sum()), ctx_tokens)
 
     def _slide_window_blocks(self, pos0, qlen) -> None:
