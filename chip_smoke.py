@@ -18,7 +18,7 @@ that):
            compiled (interpret=False) at gpt2 and llama geometry and
            compared with its XLA reference.
   serve    python -m tpu_engine.serving.cli serve --model gpt2 --lanes 1
-           --kv-block-size 16 --mixed-step --gen-prefill-chunk 256
+           --kv-block-size 16 --gen-prefill-chunk 256
            --warmup, then over HTTP through the gateway: greedy and seeded
            /generate (repeats identical), /generate/stream (equals the
            blocking result), a 640-token prompt (chunked prefill), eight
@@ -52,14 +52,16 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
-# Everything, compilation included, must end inside the driver's 1200 s.
-DEADLINE = time.monotonic() + 1140
+# Everything, compilation included, must end inside the 1500 s the chip
+# call is given (measured, PR 49: device 18 s, kernels 990, serve 188,
+# cache 32).
+DEADLINE = time.monotonic() + 1440
 
-SERVE_FLAGS = ["--model", "gpt2", "--kv-block-size", "16", "--mixed-step",
+SERVE_FLAGS = ["--model", "gpt2", "--kv-block-size", "16",
                "--gen-prefill-chunk", "256", "--warmup"]
 VOCAB = 50257            # gpt2's registry vocabulary
 SLOTS = 8                # WorkerConfig.gen_max_batch_size
-ATTENTION_PATHS = ("flash", "paged", "ragged", "quant_paged", "quant_ragged")
+ATTENTION_PATHS = ("flash", "ragged", "quant_ragged")
 
 _DEVICE_CHILD = r"""
 import importlib.metadata as md, json, sys
@@ -91,7 +93,7 @@ def say(**fields):
 
 def time_left(cap):
     left = DEADLINE - time.monotonic()
-    check(left > 0, "out of time: the run must end inside 1200 s")
+    check(left > 0, "out of time: the run must end inside 1500 s")
     return min(cap, left)
 
 
@@ -460,10 +462,11 @@ def main():
         check(device["platform"] == "tpu", f"device is {device}")
 
     with phase("kernels"):
-        # 700 s on a v5e with the benchmark cells' own shapes (PR 46: the
-        # gather references of the cell and class cases are most of it).
+        # 990 s on a v5e with the benchmark cells' own shapes and the walk's
+        # cases (PRs 46, 48: the gather references of the cell and class
+        # cases are most of it).
         run_child("kernels",
-                  [sys.executable, "-m", "tpu_engine.ops.kernel_check"], 1000)
+                  [sys.executable, "-m", "tpu_engine.ops.kernel_check"], 1200)
 
     with phase("serve"):
         cold_ready, drive_s, _ = serve_phase("serve", 1, 1)

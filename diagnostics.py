@@ -78,7 +78,7 @@ def main() -> int:
                          "CPU, validates Mosaic on a TPU host)")
     ap.add_argument("--mixed-parity", action="store_true",
                     help="step 8: RAGGED paged-attention kernel (the "
-                         "--mixed-step read path) vs the XLA gather "
+                         "ragged tick's read path) vs the XLA gather "
                          "reference at mixed q_lens {1, 7, 16, 17} — "
                          "decode rows and prefill chunks in one batch")
     ap.add_argument("--spec-parity", action="store_true",
@@ -264,24 +264,26 @@ def main() -> int:
         step(6, "gateway end-to-end infer", False, f"({exc})")
 
     # 7 (--kernel-parity): paged-attention Pallas kernel vs XLA reference
-    # — the decode read path behind --kv-block-size serving; run on a TPU
+    # — a decode-only tick's read (every row one token, heads packed)
+    # behind --kv-block-size serving; run on a TPU
     # host this validates the Mosaic compile, elsewhere the interpreter.
     if args.kernel_parity:
         try:
             import jax.numpy as jnp
 
-            from tpu_engine.ops.paged_attention import parity_check
+            from tpu_engine.ops.paged_attention import ragged_parity_check
 
-            diff = max(parity_check(),
-                       parity_check(n_heads=8, n_kv_heads=2, d_head=16))
-            bf16 = parity_check(dtype=jnp.bfloat16)
+            diff = max(ragged_parity_check(q_lens=(1, 1)),
+                       ragged_parity_check(q_lens=(1, 1), n_heads=8,
+                                           n_kv_heads=2, d_head=16))
+            bf16 = ragged_parity_check(q_lens=(1, 1), dtype=jnp.bfloat16)
             step(7, "paged-attention kernel parity",
                  diff < 2e-5 and bf16 < 2e-2,
                  f"(max|Δ| f32 {diff:.2e}, bf16 {bf16:.2e})")
         except Exception as exc:
             step(7, "paged-attention kernel parity", False, f"({exc})")
 
-    # 8 (--mixed-parity): the ragged kernel behind --mixed-step serving —
+    # 8 (--mixed-parity): the ragged kernel behind --kv-block-size serving —
     # one batch mixing decode rows (q_len 1) and prefill chunks (q_len up
     # to block_size+1, crossing a block boundary) against the XLA gather
     # reference. On a TPU host this validates the Mosaic compile.
@@ -343,14 +345,13 @@ def main() -> int:
              + int(args.spec_parity) + 1)
         try:
             from tpu_engine.ops.paged_attention import (
-                quant_parity_check,
                 quant_ragged_parity_check,
             )
 
-            decode = max(quant_parity_check(),
-                         quant_parity_check(n_heads=8, n_kv_heads=2,
-                                            d_head=64, block_size=16,
-                                            n_blocks=33, table_len=8))
+            decode = max(quant_ragged_parity_check(q_lens=(1, 1)),
+                         quant_ragged_parity_check(
+                             q_lens=(1, 1), n_heads=8, n_kv_heads=2,
+                             d_head=64, table_len=8))
             ragged = quant_ragged_parity_check(q_lens=(1, 7, 16, 17))
             step(n, "quantized (int8) kernel parity",
                  decode < 2e-4 and ragged < 2e-4,
@@ -427,7 +428,7 @@ def main() -> int:
                     gen = ContinuousGenerator(
                         tp_spec, params=tp_params, dtype="float32",
                         n_slots=4, kv_block_size=16, prefill_chunk=16,
-                        mixed_step=True, mixed_token_budget=32, tp=tp)
+                        mixed_token_budget=32, tp=tp)
                     try:
                         out = gen.generate(tp_prompts, max_new_tokens=10)
                         return out, gen.stats()

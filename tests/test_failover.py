@@ -732,16 +732,16 @@ def test_recover_emits_per_row_retryable_events(shared_worker):
     it = iter(worker_stream)
     frames.append(next(it))               # at least one token is out
     # Arm a one-shot device failure on the next decode dispatch.
-    real = gen._decode_paged
+    real = gen._mixed_step_exe
 
-    def failing(controls):
-        gen._decode_paged = real
+    def failing(width, controls):
+        gen._mixed_step_exe = real
 
         def exe(*a, **k):
             raise RuntimeError("injected device failure")
         return exe
 
-    gen._decode_paged = failing
+    gen._mixed_step_exe = failing
     events = [_parse_sse(frames[0])] + [_parse_sse(f) for f in it]
     final = events[-1]
     assert final["done"] and final["retryable"] is True
@@ -774,16 +774,16 @@ def test_gateway_resumes_past_recover_event(shared_worker):
             def gen_frames():
                 it = iter(inner)
                 yield next(it)            # first token is out
-                real = gen._decode_paged
+                real = gen._mixed_step_exe
 
-                def failing(controls):
-                    gen._decode_paged = real
+                def failing(width, controls):
+                    gen._mixed_step_exe = real
 
                     def exe(*a, **k):
                         raise RuntimeError("injected device failure")
                     return exe
 
-                gen._decode_paged = failing
+                gen._mixed_step_exe = failing
                 yield from it
             return gen_frames()
 

@@ -32,7 +32,7 @@ from tpu_engine.runtime.scheduler import ContinuousGenerator
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BS = 16
-LANE = dict(n_slots=4, dtype="float32", kv_block_size=BS, mixed_step=True,
+LANE = dict(n_slots=4, dtype="float32", kv_block_size=BS,
             prefill_chunk=16, prefix_sharing=False)
 MULTIPLIERS = ("embedding_multiplier", "attention_in_multiplier",
                "key_multiplier", "attention_out_multiplier",
@@ -510,4 +510,4 @@ def test_what_the_family_refuses_at_start_up_stays_refused(spec, params):
                                 **{**LANE, flag: value})
     with pytest.raises(ValueError, match="mixed tick over the block pool"):
         ContinuousGenerator(spec, params=params,
-                            **{**LANE, "mixed_step": False})
+                            **{**LANE, "kv_block_size": 0})

@@ -372,8 +372,9 @@ def test_splice_identity_seeded_sampling(owner, control, fetcher):
     assert _pfetch(fetcher)["spliced"] == 1
 
 
-def test_splice_identity_mixed_step(owner):
-    kw = dict(GEN_KW, gen_mixed_step=True)
+def test_splice_identity_small_token_budget(owner):
+    """The 16 tokens past the splice prefill over two ticks of eight."""
+    kw = dict(GEN_KW, gen_mixed_token_budget=8)
     mx_owner = WorkerNode(WorkerConfig(node_id="mx0", **kw))
     mx_owner.apply_weights(owner.engine.params)
     mx_fetch = WorkerNode(WorkerConfig(node_id="mx1", **kw))

@@ -374,7 +374,7 @@ def lane(spec, params):
     gen = ContinuousGenerator(spec, params=params, dtype="float32",
                               n_slots=4, step_chunk=4, max_seq=128,
                               kv_block_size=16, prefill_chunk=16,
-                              mixed_step=True, mixed_token_budget=16,
+                              mixed_token_budget=16,
                               prefix_sharing=False)
     gen.tracer = SpanRecorder(8192)
     gen.trace_node = "lane"
@@ -477,7 +477,7 @@ def _worker(spec, params, node_id, **config):
     return WorkerNode(WorkerConfig(
         node_id=node_id, model="gpt2-small-test", dtype="float32",
         gen_scheduler="continuous", gen_max_batch_size=2,
-        gen_kv_block_size=16, gen_prefill_chunk=16, gen_mixed_step=True,
+        gen_kv_block_size=16, gen_prefill_chunk=16,
         gen_mixed_token_budget=16, **config), engine=engine)
 
 

@@ -51,12 +51,11 @@ def test_every_kernel_site_compiles_for_v5e(v5e_devices, model):
         kernel_check.compile_for_topology(case, v5e_devices[0])
         names.append(case.name.split("/", 1)[1])
     # The list itself is part of the gate: flash forward at every prompt
-    # bucket, flash backward, and all four paged read paths.
+    # bucket, flash backward, and both paged read paths.
     assert [n for n in names if n.startswith("flash_fwd")] == [
         f"flash_fwd/S{s}" for s in kernel_check.FLASH_BUCKETS]
     assert {n.split("/")[0] for n in names} == {
-        "flash_fwd", "flash_bwd", "paged_decode", "ragged", "quant_decode",
-        "quant_ragged"}
+        "flash_fwd", "flash_bwd", "ragged", "quant_ragged"}
 
 
 def test_the_latent_read_compiles_for_v5e_at_both_widths(v5e_devices):
@@ -309,7 +308,6 @@ def _cell_tick_shapes(v5e_devices, config, width):
                            config + ".json")) as f:
         bench = json.load(f)
     serving = bench["serving"]
-    assert serving["gen_mixed_step"]
     assert width in (1, serving["gen_prefill_chunk"])
     _ensure_builtin_models_imported()
     spec = create_model(bench["factory"], **bench["kwargs"])
@@ -922,7 +920,7 @@ def test_failed_warmup_is_a_failed_start(monkeypatch):
 
     monkeypatch.setattr(paged_attention, "default_ragged_attention",
                         lambda: rejected)
-    cfg = WorkerConfig(gen_kv_block_size=16, gen_mixed_step=True,
+    cfg = WorkerConfig(gen_kv_block_size=16,
                        gen_prefill_chunk=16, batch_buckets=(1,),
                        max_batch_size=1)
     with pytest.raises(RuntimeError, match="Mosaic failed to compile"):

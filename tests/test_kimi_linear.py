@@ -32,7 +32,7 @@ from tpu_engine.runtime.scheduler import ContinuousGenerator
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BS = 16
-LANE = dict(n_slots=4, dtype="float32", kv_block_size=BS, mixed_step=True,
+LANE = dict(n_slots=4, dtype="float32", kv_block_size=BS,
             prefill_chunk=16, prefix_sharing=False)
 
 
@@ -637,8 +637,8 @@ def test_one_admission_and_release_path_serves_both_kinds_of_block():
 
 
 @pytest.mark.parametrize("kwargs, error, message", [
-    ({"mixed_step": False}, ValueError,
-     "served by the mixed tick over the block pool only"),
+    ({"kv_block_size": 0, "kv_blocks": 64}, ValueError,
+     r"set kv_block_size > 0 \(the dense per-slot cache has no"),
     ({"kv_block_size": 0}, ValueError,
      "served by the mixed tick over the block pool only"),
     ({"prefix_sharing": True}, ValueError,
@@ -680,7 +680,7 @@ def test_the_chain_wire_format_is_refused_by_name(spec, params):
 
 _GEN_KW = dict(model="kimi_linear_small", dtype="float32", batch_buckets=(1,),
                gen_max_batch_size=2, gen_kv_block_size=BS,
-               gen_mixed_step=True, gen_prefill_chunk=16,
+               gen_prefill_chunk=16,
                gen_prefix_sharing=False)
 
 

@@ -280,7 +280,7 @@ def test_swap_in_mixed_mode(spec, params, control_stream):
     g = ContinuousGenerator(spec, params=params, dtype="float32",
                             n_slots=2, step_chunk=4, max_seq=128,
                             kv_block_size=16, kv_blocks=12,
-                            kv_host_blocks=8, mixed_step=True,
+                            kv_host_blocks=8,
                             prefill_chunk=16)
     try:
         assert g.generate([prompt], max_new_tokens=8)[0] == want

@@ -8,8 +8,8 @@ Contracts under test:
   (no cumulative requantization drift anywhere in the lifecycle);
 - `quantize_kv` granularity: one scale per (layer, slot, kv-head)
   vector, round-trip error bounded by half an int8 lsb per vector;
-- quantized greedy streams are DETERMINISTIC run-to-run (every
-  scheduler mode: two-path, mixed, mixed+spec) and agree closely with
+- quantized greedy streams are DETERMINISTIC run-to-run (plain ticks
+  and speculative ones) and agree closely with
   the bf16 pool's streams at serving shapes — but are not required to
   be byte-identical to bf16 (MIGRATION.md);
 - kernel-vs-reference parity in int8 mode (fused-dequant Pallas kernel
@@ -160,13 +160,14 @@ def test_insert_readopt_frees_scale_slot(spec):
 # -- kernel parity ------------------------------------------------------------
 
 def test_quant_kernel_parity_decode(monkeypatch):
-    from tpu_engine.ops.paged_attention import quant_parity_check
+    """A decode-only tick's call: the int8 read one slot wide."""
+    from tpu_engine.ops.paged_attention import quant_ragged_parity_check
 
     monkeypatch.setenv("TPU_ENGINE_PAGED", "1")  # force the Pallas kernel
-    assert quant_parity_check() < 2e-4
-    assert quant_parity_check(n_heads=8, n_kv_heads=2, d_head=64,
-                              block_size=16, n_blocks=33,
-                              table_len=8) < 2e-4
+    assert quant_ragged_parity_check(q_lens=(1, 1)) < 2e-4
+    assert quant_ragged_parity_check(q_lens=(1, 1), n_heads=8,
+                                     n_kv_heads=2, d_head=64,
+                                     table_len=8) < 2e-4
 
 
 def test_quant_kernel_parity_ragged(monkeypatch):
@@ -180,8 +181,7 @@ def test_quant_kernel_parity_ragged(monkeypatch):
 
 
 @pytest.mark.parametrize("kind,case", [
-    ("quant_paged", "ends-on-group-boundary-width-1"),
-    ("quant_paged", "table-four-times-wider-width-1"),
+    ("quant_ragged", "table-four-times-wider-width-1"),
     ("quant_ragged", "dead-row-between-live-rows"),
     ("quant_ragged", "decode-row-in-wide-tick"),
     ("quant_ragged", "ends-on-group-boundary"),
@@ -210,10 +210,9 @@ def _gen(spec, params, quantize, **kw):
 
 
 @pytest.mark.parametrize("mode_kw", [
-    {},                                     # two-path paged
-    {"mixed_step": True},                   # mixed stepping
-    {"mixed_step": True, "spec_k": 2},      # mixed + speculation
-], ids=["two-path", "mixed", "mixed-spec"])
+    {},                     # a prompt one chunk
+    {"spec_k": 2},          # + speculation
+], ids=["mixed", "mixed-spec"])
 def test_quant_streams_deterministic_and_agree_with_bf16(
         spec, params, mode_kw):
     g = _gen(spec, params, "int8", **mode_kw)
@@ -230,11 +229,10 @@ def test_quant_streams_deterministic_and_agree_with_bf16(
         ref.stop()
     # int8 KV rounding may eventually fork a greedy stream (a fork is
     # permanent: every later token differs), but at serving shapes the
-    # agreement stays high and first tokens (prefill logits are computed
-    # before any quantized read in two-path mode; one chunk deep
-    # elsewhere) essentially always match. The bound is re-derived under
+    # agreement stays high and first tokens (one chunk deep in
+    # quantized reads) essentially always match. The bound is re-derived under
     # the installed JAX 0.9.0, whose random init and CPU matmuls differ
-    # from the 0.4.37 the old 0.75 was pinned on: all three modes measure
+    # from the 0.4.37 the old 0.75 was pinned on: both modes measure
     # 0.734 (two of the four streams identical end to end, the two
     # shortest prompts fork after two tokens).
     per_tok = [sum(x == y for x, y in zip(a, b)) / max(1, len(a))

@@ -32,7 +32,7 @@ from tpu_engine.runtime.scheduler import ContinuousGenerator
 BS = 16
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LANE = {"n_slots": 4, "dtype": "float32", "kv_block_size": BS,
-        "mixed_step": True, "prefill_chunk": 16, "prefix_sharing": False}
+        "prefill_chunk": 16, "prefix_sharing": False}
 
 
 @pytest.fixture(scope="module")
@@ -427,8 +427,8 @@ def test_the_tick_runs_one_ahead_with_both_kinds_of_block(spec, params):
 
 
 @pytest.mark.parametrize("kwargs, error, message", [
-    ({"mixed_step": False}, ValueError,
-     "served by the mixed tick over the block pool only"),
+    ({"kv_block_size": 0, "kv_blocks": 64}, ValueError,
+     r"set kv_block_size > 0 \(the dense per-slot cache has no"),
     ({"kv_block_size": 0}, ValueError,
      "served by the mixed tick over the block pool only"),
     ({"prefix_sharing": True}, ValueError,
@@ -477,7 +477,7 @@ def test_the_scheduler_imports_no_model_s_step_by_name():
 
 _GEN_KW = dict(model="laguna-small-test", dtype="float32", batch_buckets=(1,),
                gen_max_batch_size=2, gen_kv_block_size=BS,
-               gen_mixed_step=True, gen_prefill_chunk=16,
+               gen_prefill_chunk=16,
                gen_prefix_sharing=False)
 
 

@@ -1,9 +1,10 @@
-"""Mixed prefill+decode stepping (runtime.scheduler mixed_step=True):
+"""Mixed prefill+decode stepping (runtime.scheduler, every lane with a
+pool or a slab):
 one ragged dispatch per tick serving decode rows and prefill chunks
 together.
 
 Contracts under test:
-- seeded output streams are identical mixed vs dense vs two-path paged —
+- seeded output streams are identical to the dense cache's —
   greedy AND temperature sampling, short and chunk-crossing prompts,
   radix-shared prefixes, controls (penalty/stop lists).
 - token budget: a long prompt's admission cannot stall live decode rows
@@ -12,7 +13,7 @@ Contracts under test:
   reference at q_len 1 / 7 / block_size / block_size+1 in one batch.
 - deadline-cancelled rows mid-prefill return every block.
 - one dispatch per tick, counted at separate sites, stays equal.
-- serving integration: --mixed-step wiring, tpu_engine_mixed_* and
+- serving integration: worker wiring, tpu_engine_mixed_* and
   TTFT/ITL histograms at /metrics, mixed_step spans in the trace ring.
 
 Kept lean per the tier-1 budget: the dense oracle is a module fixture,
@@ -60,7 +61,7 @@ def mixed(spec, params):
     s = ContinuousGenerator(spec, params=params, dtype="float32",
                             n_slots=4, step_chunk=4, max_seq=128,
                             kv_block_size=16, prefill_chunk=16,
-                            mixed_step=True, mixed_token_budget=16)
+                            mixed_token_budget=16)
     yield s
     s.stop()
 
@@ -71,12 +72,29 @@ def test_mixed_requires_paged(spec, params):
                             n_slots=2, mixed_step=True)
 
 
+@pytest.mark.parametrize("model, lane", [
+    ("gpt2-small-test", dict(kv_block_size=16)),
+    ("gpt2-small-test", dict(kv_block_size=16, mixed_step=False)),
+    ("ssd-small-test", dict()),
+], ids=["pool", "pool-argument-false", "slab"])
+def test_a_lane_with_a_pool_steps_by_the_ragged_tick(model, lane):
+    """What the lane holds decides, the `mixed_step` argument nothing: a
+    block pool and a state slab step by the ragged tick (one dispatch a
+    tick, no chunk of the dense loop), asked or not."""
+    spec = create_model(model)
+    gen = ContinuousGenerator(spec, params=spec.init(jax.random.PRNGKey(0)),
+                              dtype="float32", n_slots=2, step_chunk=4,
+                              prefill_chunk=16, **lane)
+    try:
+        assert len(gen.generate([[5, 9, 3]], max_new_tokens=6)[0]) == 6
+        st = gen.stats()
+        assert st["mixed"]["ticks"] == st["mixed"]["dispatches"] >= 6
+        assert st["mixed"]["decode_tokens"] == 5 and st["chunks"] == 0
+    finally:
+        gen.stop()
+
+
 def test_greedy_matches_dense_and_paged(dense, mixed):
-    # Identity vs the two-path PAGED scheduler is transitive:
-    # tests/test_paged_kv.py pins paged == dense on this exact prompt
-    # (same model/params/seed), so mixed == dense here closes the
-    # three-way claim without compiling a third scheduler instance
-    # (tier-1 budget).
     prompt = [5, 9, 3]
     d = dense.generate([prompt], max_new_tokens=6)[0]
     assert mixed.generate([prompt], max_new_tokens=6)[0] == d
@@ -230,7 +248,6 @@ def test_worker_mixed_serving_and_observability(spec, params):
                                 gen_max_batch_size=4,
                                 gen_kv_block_size=16,
                                 gen_prefill_chunk=16,
-                                gen_mixed_step=True,
                                 gen_mixed_token_budget=16),
                    engine=engine)
     try:

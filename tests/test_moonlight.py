@@ -66,7 +66,7 @@ def test_family_capabilities_and_stated_widths(spec):
     for missing in ("kv_quantize", "kv_host_tier", "migration", "handoff",
                     "tensor_parallel", "spec_decode", "two_path"):
         assert not spec.supports(missing)
-    assert spec.supports("mixed_step") and spec.supports("prefix_sharing")
+    assert spec.supports("generate") and spec.supports("prefix_sharing")
     assert spec.tp_rule.startswith("unshardable")
     # A head's width is what the model states, not d_model / n_heads.
     assert cfg.d_model // cfg.n_heads == 16 and cfg.d_head == 16 + 8
@@ -297,7 +297,7 @@ def test_the_mixed_tick_serves_it_and_counts_its_experts(spec, params):
     tracer = SpanRecorder(capacity=4096)
     gen = ContinuousGenerator(spec, params=params, n_slots=4,
                               dtype="float32", kv_block_size=BS,
-                              mixed_step=True, prefill_chunk=16)
+                              prefill_chunk=16)
     gen.tracer, gen.trace_node = tracer, "lane"
     try:
         rng = np.random.default_rng(0)
@@ -334,8 +334,8 @@ def test_the_mixed_tick_serves_it_and_counts_its_experts(spec, params):
 
 
 @pytest.mark.parametrize("kwargs, error, message", [
-    ({"mixed_step": False}, ValueError,
-     "served by the mixed tick over the block pool only"),
+    ({"kv_block_size": 0, "kv_blocks": 64}, ValueError,
+     r"set kv_block_size > 0 \(the dense per-slot cache has no"),
     ({"kv_block_size": 0}, ValueError,
      "served by the mixed tick over the block pool only"),
     ({"kv_quantize": "int8"}, ValueError,
@@ -349,7 +349,7 @@ def test_the_mixed_tick_serves_it_and_counts_its_experts(spec, params):
 def test_what_the_latent_pool_cannot_do_is_refused_at_start_up(
         spec, params, kwargs, error, message):
     base = {"n_slots": 2, "dtype": "float32", "kv_block_size": BS,
-            "mixed_step": True, "prefill_chunk": 16}
+            "prefill_chunk": 16}
     with pytest.raises(error, match=message):
         ContinuousGenerator(spec, params=params, **{**base, **kwargs})
 
@@ -373,7 +373,7 @@ def test_the_tick_runs_one_ahead_and_late_ends_ride_as_done_rows(spec,
     counters = check_late_ends(
         lambda: ContinuousGenerator(spec, params=params, n_slots=4,
                                     dtype="float32", kv_block_size=BS,
-                                    mixed_step=True, prefill_chunk=16,
+                                    prefill_chunk=16,
                                     prefix_sharing=False),
         prompts, whole)
     assert counters["overlapped_ticks"] > counters["ticks"] // 2
@@ -382,7 +382,7 @@ def test_the_tick_runs_one_ahead_and_late_ends_ride_as_done_rows(spec,
 def test_the_chain_wire_format_is_refused_by_name(spec, params):
     gen = ContinuousGenerator(spec, params=params, n_slots=2,
                               dtype="float32", kv_block_size=BS,
-                              mixed_step=True, prefill_chunk=16)
+                              prefill_chunk=16)
     try:
         refusal = "needs the 'migration' capability"
         assert refusal in gen.export_row("nobody")["reason"]

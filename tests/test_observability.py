@@ -370,10 +370,10 @@ def test_twice_moved_stream_stitches_with_zero_orphans(fleet):
         # recover dump's 10 s rate-limit window; clear the stamp so the
         # anomaly dump below is observable.
         gen._flight_last_dump_ts = 0.0
-        real = gen._decode_paged
+        real = gen._mixed_step_exe
 
-        def failing(controls):
-            gen._decode_paged = real
+        def failing(width, controls):
+            gen._mixed_step_exe = real
             armed_gen[0] = None
 
             def exe(*a, **k):
@@ -381,7 +381,7 @@ def test_twice_moved_stream_stitches_with_zero_orphans(fleet):
             return exe
 
         armed_gen[0], armed_real[0] = gen, real
-        gen._decode_paged = failing
+        gen._mixed_step_exe = failing
         t.join(timeout=180)
         assert final[0] is not None, "stream never terminated"
         assert "error" not in final[0], final[0]
@@ -436,5 +436,5 @@ def test_twice_moved_stream_stitches_with_zero_orphans(fleet):
         assert _wait(lambda: all(pool_leak_free(w) for w in fleet), 30)
     finally:
         if armed_gen[0] is not None:       # fault never fired: disarm
-            armed_gen[0]._decode_paged = armed_real[0]
+            armed_gen[0]._mixed_step_exe = armed_real[0]
         gw.stop()

@@ -31,7 +31,7 @@ from tpu_engine.runtime.scheduler import ContinuousGenerator
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BS = 16
-LANE = dict(n_slots=4, dtype="float32", kv_block_size=BS, mixed_step=True,
+LANE = dict(n_slots=4, dtype="float32", kv_block_size=BS,
             prefill_chunk=16, prefix_sharing=False)
 
 
@@ -517,8 +517,8 @@ def test_a_request_waits_when_either_pool_is_short(spec, params, short):
 
 
 @pytest.mark.parametrize("kwargs, error, message", [
-    ({"mixed_step": False}, ValueError,
-     "served by the mixed tick over the block pool only"),
+    ({"kv_block_size": 0, "kv_blocks": 64}, ValueError,
+     r"set kv_block_size > 0 \(the dense per-slot cache has no"),
     ({"kv_block_size": 0}, ValueError,
      "served by the mixed tick over the block pool only"),
     ({"prefix_sharing": True}, ValueError,
@@ -570,7 +570,7 @@ def test_the_scheduler_imports_no_step_of_this_model_by_name():
 
 _GEN_KW = dict(model="olmo_hybrid_small", dtype="float32", batch_buckets=(1,),
                gen_max_batch_size=2, gen_kv_block_size=BS,
-               gen_mixed_step=True, gen_prefill_chunk=16,
+               gen_prefill_chunk=16,
                gen_prefix_sharing=False)
 
 

@@ -451,7 +451,7 @@ def test_fleet_stream_under_the_knee_is_identical_with_control_on():
         lanes = [WorkerNode(WorkerConfig(
             node_id=f"ov_lane_{i}", model="gpt2-small-test",
             gen_max_batch_size=2, gen_prefix_cache_mb=0,
-            gen_kv_block_size=16, gen_mixed_step=True,
+            gen_kv_block_size=16,
             gen_mixed_token_budget=16, gen_prefill_chunk=16,
             max_queue_depth=2 if control else 0,
             priority_admission=control, brownout=control,
@@ -496,7 +496,7 @@ def bo_sched():
     s = ContinuousGenerator(spec, params=spec.init(jax.random.PRNGKey(0)),
                             dtype="float32", n_slots=2, max_seq=128,
                             kv_block_size=16, prefill_chunk=16,
-                            mixed_step=True, mixed_token_budget=16,
+                            mixed_token_budget=16,
                             spec_k=2)
     yield s
     s.stop()

@@ -263,9 +263,13 @@ def test_shared_prefix_concurrent_rows_share_blocks(dense, spec, params):
                             n_slots=4, step_chunk=4, max_seq=128,
                             kv_block_size=16)
     try:
-        # Admit the prefix owner first so its blocks are indexed...
+        # Admit the prefix owner first so its blocks are indexed (by
+        # the tick that ends its prompt)...
         first = s.submit(prompts[0], max_new_tokens=12)
-        time.sleep(0.2)
+        deadline = time.monotonic() + 60
+        while (not s.stats()["kv_pool"]["radix_nodes"]
+               and time.monotonic() < deadline):
+            time.sleep(0.02)
         rest = [s.submit(p, max_new_tokens=12) for p in prompts[1:]]
         outs = [first.result(60)] + [f.result(60) for f in rest]
         assert outs == dense.generate(prompts, max_new_tokens=12)
@@ -325,7 +329,7 @@ def test_equal_kv_bytes_hold_more_short_rows_than_the_dense_layout(
     s = ContinuousGenerator(spec, params=params, dtype="float32",
                             n_slots=5, max_seq=128, kv_block_size=16,
                             kv_blocks=2 * (128 // 16) + 1,
-                            prefix_sharing=False, mixed_step=True,
+                            prefix_sharing=False,
                             prefill_chunk=16)
     try:
         prompts = [[i + 1, i + 2, i + 3] for i in range(8)]
@@ -400,12 +404,15 @@ def test_stop_under_load_releases_everything(spec, params):
 # -- kernel parity ------------------------------------------------------------
 
 def test_paged_kernel_matches_reference():
-    from tpu_engine.ops.paged_attention import parity_check
+    """A decode-only tick's call: every row one token, the ragged read
+    one slot wide (the heads packed into one score tile)."""
+    from tpu_engine.ops.paged_attention import ragged_parity_check
 
-    assert parity_check() < 2e-5
-    assert parity_check(n_heads=8, n_kv_heads=2, d_head=16,
-                        block_size=8, n_blocks=17, table_len=6) < 2e-5
-    assert parity_check(dtype=jnp.bfloat16) < 2e-2
+    assert ragged_parity_check(q_lens=(1, 1)) < 2e-5
+    assert ragged_parity_check(q_lens=(1, 1), n_heads=8, n_kv_heads=2,
+                               d_head=16, block_size=8, n_blocks=17,
+                               table_len=6) < 2e-5
+    assert ragged_parity_check(q_lens=(1, 1), dtype=jnp.bfloat16) < 2e-2
 
 
 # Head shapes of the one pool layout (L, NB, bs, H_kv*D): the small test
@@ -419,15 +426,16 @@ HEAD_SHAPES = {
 
 
 @pytest.mark.parametrize("heads", sorted(HEAD_SHAPES))
-@pytest.mark.parametrize("kind", ["paged", "ragged", "quant_paged",
-                                  "quant_ragged"])
-def test_every_read_path_at_every_head_shape(kind, heads):
+@pytest.mark.parametrize("q_lens", [(1, 1, 1), (1, 5, 9)],
+                         ids=["width-1", "width-9"])
+@pytest.mark.parametrize("kind", ["ragged", "quant_ragged"])
+def test_every_read_path_at_every_head_shape(kind, q_lens, heads):
     """Kernel (interpreter) against its XLA reference through the
     (pool, layer) signature, reading the SECOND layer of a two-layer
-    pool: a path that ignored the layer index would miss."""
+    pool: a path that ignored the layer index would miss. Width 1 is a
+    decode-only tick's call (one query row a head, the heads packed)."""
     from tpu_engine.ops import paged_attention as pa
 
-    q_lens = (1, 1, 1) if "ragged" not in kind else (1, 5, 9)
     err = pa._parity(kind, q_lens, block_size=8, n_blocks=13, table_len=3,
                      dtype=jnp.float32, seed=3, interpret=True,
                      **HEAD_SHAPES[heads])
@@ -437,13 +445,16 @@ def test_every_read_path_at_every_head_shape(kind, heads):
 @pytest.mark.parametrize("case", ["ends-on-group-boundary-width-1",
                                   "table-four-times-wider-width-1"])
 def test_decode_kernel_walks_each_rows_own_context(case):
-    """The decode read (the ragged read at q_len 1) over the workloads
-    the tile walk can get wrong (`ops.paged_attention.WALK_CASES`):
-    contexts ending on, one past and far before a group boundary, and a
-    table whose tail is the null block."""
+    """A decode-only tick's read (the ragged read one slot wide) over
+    the workloads the tile walk can get wrong
+    (`ops.paged_attention.WALK_CASES`), from a bfloat16 pool as the
+    cells serve it (test_mixed_step.py and test_paged_walk.py run the
+    float32 ones): contexts ending on, one past and far before a group
+    boundary, and a table whose tail is the null block."""
     from tpu_engine.ops import paged_attention as pa
 
-    assert pa.walk_parity_check("paged", case, interpret=True) < 2e-5
+    assert pa.walk_parity_check("ragged", case, interpret=True,
+                                dtype=jnp.bfloat16) < 2e-2
 
 
 @pytest.mark.parametrize("width", [1, 40])
@@ -471,10 +482,7 @@ def test_step_writes_layer_l_into_layer_l_only(spec, params, step, quant):
     other byte of every layer is bit-equal before and after — the layer
     loop carries the whole pool and must not smear a layer's write over
     its neighbours."""
-    from tpu_engine.models.transformer import (
-        transformer_decode_rows_paged,
-        transformer_step_rows_ragged,
-    )
+    from tpu_engine.models.transformer import transformer_step_rows_ragged
 
     cfg = spec.config
     assert cfg.n_layers > 1
@@ -491,9 +499,11 @@ def test_step_writes_layer_l_into_layer_l_only(spec, params, step, quant):
     tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     pos0 = jnp.asarray([17, 3], jnp.int32)
     if step == "decode":
-        out = transformer_decode_rows_paged(
-            params, jnp.asarray([5, 9]), caches, tables, pos0, cfg,
-            dtype=jnp.float32, scales=scales)
+        # A decode-only tick: the ragged step one slot wide.
+        out = transformer_step_rows_ragged(
+            params, jnp.asarray([[5], [9]]), caches, tables, pos0,
+            jnp.asarray([1, 1], jnp.int32), cfg, dtype=jnp.float32,
+            scales=scales)
         written = {(2, 1), (3, 3)}
     else:
         # Row 0 consumes 3 tokens (columns 17..19 of block 2), row 1 one

@@ -44,10 +44,13 @@ def test_the_pipeline_s_cases_equal_the_reference(kind, case):
 
 @pytest.mark.parametrize("case", sorted(
     c for c, width in PIPELINE_CASES.items() if width == 1))
-@pytest.mark.parametrize("kind", ["paged", "quant_paged"])
-def test_the_decode_read_is_the_same_pipeline(kind, case):
-    limit = 2e-4 if kind.startswith("quant") else 2e-5
-    assert pa.walk_parity_check(kind, case, interpret=True) < limit
+@pytest.mark.parametrize("kind", ["ragged", "quant_ragged"])
+def test_a_decode_only_tick_s_read_from_bfloat16_queries(kind, case):
+    """The call every cell's decode ticks make: one slot wide (the heads
+    packed into one score tile), the queries bfloat16 as a served lane's,
+    the pool bfloat16 or int8 beside float32 scales."""
+    assert pa.walk_parity_check(kind, case, interpret=True,
+                                dtype=jnp.bfloat16) < 3e-2
 
 
 def _workload(q_lens, pos0, table_len, window=None, group=4):

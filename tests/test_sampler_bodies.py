@@ -294,7 +294,7 @@ def lane(spec, params):
     gen = ContinuousGenerator(spec, params=params, dtype="float32",
                               n_slots=4, step_chunk=4, max_seq=128,
                               kv_block_size=16, prefill_chunk=16,
-                              mixed_step=True, mixed_token_budget=16)
+                              mixed_token_budget=16)
     gen.tracer = SpanRecorder(8192)
     gen.trace_node = "lane"
     yield gen
@@ -371,8 +371,7 @@ PROMPTS = [[5, 9, 3], [7, 7, 7, 7, 7, 7], [1, 2, 3, 4]]
 def _tokens(spec, params, **lane_kwargs):
     gen = ContinuousGenerator(spec, params=params, dtype="float32",
                               n_slots=4, step_chunk=4, max_seq=128,
-                              kv_block_size=16, prefill_chunk=16,
-                              **lane_kwargs)
+                              prefill_chunk=16, **lane_kwargs)
     try:
         return gen.generate(PROMPTS, **REQUESTS)
     finally:
@@ -380,12 +379,12 @@ def _tokens(spec, params, **lane_kwargs):
 
 
 @pytest.mark.parametrize("lane_kwargs", [
-    {}, {"mixed_step": True, "mixed_token_budget": 16, "spec_k": 2}],
+    {}, {"kv_block_size": 16, "mixed_token_budget": 16, "spec_k": 2}],
     ids=["decode_chunk_scan", "speculative_step"])
 def test_another_caller_emits_the_parents_tokens(spec, params, monkeypatch,
                                                  lane_kwargs):
     """A greedy, an unfiltered and a filtering request through a lane
-    whose steps call `_sample` inside a scan (the two-path paged decode
+    whose steps call `_sample` inside a scan (the dense cache's decode
     chunk) and inside the speculative verify loop, against the same lane
     built over the parent's `_sample`."""
     got = _tokens(spec, params, **lane_kwargs)

@@ -294,7 +294,7 @@ def test_mixed_spec_identity_and_coscheduling(spec, params, plain):
     ms = ContinuousGenerator(spec, params=params, dtype="float32",
                              n_slots=4, step_chunk=4, max_seq=128,
                              kv_block_size=16, prefill_chunk=16,
-                             mixed_step=True, mixed_token_budget=16,
+                             mixed_token_budget=16,
                              spec_k=3)
     try:
         for prompt, mn in (([5, 9, 3], 10),

@@ -8,11 +8,11 @@ Contracts under test:
   bounded), at the ops level and through the whole model;
 - PARTITION INVARIANCE of the serving recurrence: consuming a prompt in
   windows of any width produces bit-identical state — the property that
-  makes two-path prefill chunks, mixed-step budgeted chunks, and
-  crash-replay (prompt ⧺ emitted) resumes agree;
-- stream identity across scheduling modes: greedy SSD streams are
-  byte-identical between two-path and mixed stepping, across repeats,
-  and across a replay-style resume; seeded sampling is deterministic;
+  makes budgeted chunks of any width and crash-replay
+  (prompt ⧺ emitted) resumes agree;
+- stream identity: greedy SSD streams are byte-identical across token
+  budgets, across repeats, and across a replay-style resume; seeded
+  sampling is deterministic;
 - StateSlabPool discipline: null row, refcounts, PoolExhausted,
   deferred admissions under row exhaustion, zero-leak accounting on
   every row-free path (completion, deadline cancel, stop);
@@ -158,7 +158,7 @@ def test_family_declarations():
     assert create_model("gpt2-small-test").state_family == "kv_paged"
     assert create_model("mlp").state_family == "stateless"
     ssd = create_model("ssd-small-test")
-    assert ssd.supports("mixed_step") and ssd.supports("migration")
+    assert ssd.supports("generate") and ssd.supports("migration")
     assert not ssd.supports("spec_decode")
     assert not ssd.supports("paged_kv")
 
@@ -269,7 +269,7 @@ def test_slab_chain_refusals_before_allocation():
 
 # -- scheduler e2e -----------------------------------------------------------
 
-def test_two_path_greedy_and_seeded_streams(spec, params):
+def test_greedy_and_seeded_streams(spec, params):
     gen = _gen(spec, params)
     try:
         a = gen.generate([[5, 9, 3], [7, 2]], max_new_tokens=12)
@@ -288,25 +288,26 @@ def test_two_path_greedy_and_seeded_streams(spec, params):
         gen.stop()
 
 
-def test_two_path_vs_mixed_byte_identical(spec, params):
-    """The acceptance criterion: greedy SSD streams byte-identical
-    across the two-path and mixed stepping disciplines (plus a seeded
-    stream — the fold_in(seed, position) rule is family-portable)."""
+def test_streams_byte_identical_across_token_budgets(spec, params):
+    """Greedy SSD streams byte-identical however the token budget cuts
+    a prompt into chunks: eight tokens a tick (the lane's chunk) or six
+    over all rows (plus a seeded stream — the fold_in(seed, position)
+    rule is family-portable)."""
     prompts = [[5, 9, 3, 17, 44, 2, 8, 11, 23], [7, 2], [1] * 12]
     gen = _gen(spec, params)
     try:
-        two_path = gen.generate(prompts, max_new_tokens=14)
-        seeded_tp = gen.generate([prompts[0]], max_new_tokens=10,
-                                 temperature=0.8, seed=9)
+        whole = gen.generate(prompts, max_new_tokens=14)
+        seeded_whole = gen.generate([prompts[0]], max_new_tokens=10,
+                                    temperature=0.8, seed=9)
     finally:
         gen.stop()
-    genm = _gen(spec, params, mixed_step=True, mixed_token_budget=6)
+    genm = _gen(spec, params, mixed_token_budget=6)
     try:
         mixed = genm.generate(prompts, max_new_tokens=14)
         seeded_mx = genm.generate([prompts[0]], max_new_tokens=10,
                                   temperature=0.8, seed=9)
-        assert mixed == two_path
-        assert seeded_mx == seeded_tp
+        assert mixed == whole
+        assert seeded_mx == seeded_whole
         m = genm.stats()["mixed"]
         assert m["ticks"] == m["dispatches"]  # one dispatch per tick
         st = genm.stats()["state_pool"]
@@ -455,6 +456,38 @@ def test_scheduler_migration_splice_identity(spec, params):
         b.stop()
 
 
+def test_export_mid_prompt_is_refused_as_mid_prefill(spec, params):
+    """A slab row's state is whole only at its prompt's end: an export
+    command that meets the row between two of its prompt's chunks is
+    refused by name (a replay re-prefills what an import would ship),
+    counted, and the row goes on undisturbed."""
+    from concurrent.futures import Future
+
+    gen = _gen(spec, params, mixed_token_budget=8)
+    try:
+        prompt = list(range(1, 25))          # three chunks of eight
+        want = gen.generate([prompt], max_new_tokens=6)[0]
+        real = gen._slab_mixed_exe
+        fut, sent = Future(), []
+
+        def commanding(width, controls):
+            if width > 1 and not sent:       # the first chunk's tick
+                sent.append(True)
+                gen._migrate_q.put(("mover", fut, {}))
+            return real(width, controls)
+
+        gen._slab_mixed_exe = commanding
+        moving = gen.submit(prompt, max_new_tokens=6, tag="mover")
+        assert fut.result(timeout=60) == {"ok": False,
+                                          "reason": "row is mid-prefill"}
+        assert moving.result(timeout=60) == want
+        assert gen.stats()["migration"]["export_refused"] == 1
+        st = gen.stats()["state_pool"]
+        assert st["rows_free"] == st["rows_total"]
+    finally:
+        gen.stop()
+
+
 def test_import_refusals_resolve_retryable(spec, params):
     from tpu_engine.runtime.scheduler import ImportRefused
 
@@ -584,16 +617,16 @@ def test_crash_recover_keeps_serving(spec, params):
     gen = _gen(spec, params)
     try:
         before = gen.generate([[5, 9, 3]], max_new_tokens=8)[0]
-        real = gen._slab_decode
+        real = gen._slab_mixed_exe
 
-        def failing(controls):
-            gen._slab_decode = real
+        def failing(width, controls):
+            gen._slab_mixed_exe = real
 
             def exe(*a, **k):
                 raise RuntimeError("injected device failure")
             return exe
 
-        gen._slab_decode = failing
+        gen._slab_mixed_exe = failing
         fut = gen.submit([5, 9, 3], max_new_tokens=30)
         with pytest.raises(RuntimeError, match="device-step failure"):
             fut.result(timeout=60)

@@ -34,7 +34,6 @@ from tpu_engine.models.registry import (
 )
 from tpu_engine.models.transformer import (
     step_weights,
-    transformer_decode_rows_paged,
     transformer_step_rows_ragged,
 )
 from tpu_engine.ops.quant import quantize_params
@@ -77,14 +76,23 @@ def _lane(name, params=None, dtype="bfloat16", **kw):
                                dtype=dtype, **kw)
 
 
-def _step_inputs(cfg, width):
+def _step_inputs(cfg, width, quantize=""):
     """Two rows mid-stream over a pool of recognisable bytes: row 0
     consumes `width` tokens from column 17, row 1 one token at column 3
-    (its other slots are padding)."""
-    pool = BlockPool(cfg, 6, 16, jnp.bfloat16)
+    (its other slots are padding). An int8 pool's scales ride as the
+    caches' second half."""
+    pool = BlockPool(cfg, 6, 16, jnp.bfloat16, quantize=quantize)
     rng = np.random.default_rng(0)
-    caches = jax.tree.map(
-        lambda x: jnp.asarray(rng.normal(size=x.shape), x.dtype), pool.caches)
+
+    def noise(x):
+        if x.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-100, 100, x.shape), x.dtype)
+        return jnp.asarray(rng.normal(size=x.shape), x.dtype)
+
+    caches = jax.tree.map(noise, pool.caches)
+    if quantize:
+        caches = (caches, jax.tree.map(lambda x: jnp.abs(noise(x)) + 0.1,
+                                       pool.scales))
     tokens = jnp.asarray(rng.integers(1, cfg.vocab, (2, width)), jnp.int32)
     tables = jnp.asarray([[1, 2, 3], [4, 5, 0]], jnp.int32)
     pos0 = jnp.asarray([17, 3], jnp.int32)
@@ -92,17 +100,21 @@ def _step_inputs(cfg, width):
     return tokens, caches, tables, pos0, qlen
 
 
-@pytest.mark.parametrize("step", ["ragged-1", f"ragged-{CHUNK}", "decode"])
+@pytest.mark.parametrize("step", ["ragged-1", f"ragged-{CHUNK}", "int8-1"])
 @pytest.mark.parametrize("name", DENSE)
 def test_step_tree_gives_bit_equal_logits_and_pool(name, step):
     spec, params = _model(name)
     cfg = spec.config
     tree = step_weights(params, jnp.bfloat16)
     assert tree is not params
-    if step == "decode":
-        tokens, caches, tables, pos0, _ = _step_inputs(cfg, 1)
-        run = jax.jit(lambda p: transformer_decode_rows_paged(
-            p, tokens[:, 0], caches, tables, pos0, cfg))
+    if step == "int8-1":
+        # A decode-only tick over an int8 pool: the new token quantizes
+        # at its write and the scales come back with the pool.
+        tokens, (caches, scales), tables, pos0, qlen = _step_inputs(
+            cfg, 1, quantize="int8")
+        run = jax.jit(lambda p: transformer_step_rows_ragged(
+            p, tokens, caches, tables, pos0, qlen, cfg,
+            sample_slot=qlen - 1, scales=scales))
     else:
         tokens, caches, tables, pos0, qlen = _step_inputs(
             cfg, int(step.split("-")[1]))

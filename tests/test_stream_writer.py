@@ -57,7 +57,7 @@ def _serve(**gateway):
         worker_config=WorkerConfig(
             model="gpt2-small-test", dtype="float32",
             gen_scheduler="continuous", gen_max_batch_size=N_SLOTS,
-            gen_kv_block_size=16, gen_prefill_chunk=16, gen_mixed_step=True,
+            gen_kv_block_size=16, gen_prefill_chunk=16,
             gen_mixed_token_budget=64),
         gateway_config=GatewayConfig(port=0, **gateway), warmup=False,
         native_front=False)

@@ -131,7 +131,7 @@ def test_a_field_the_layout_does_not_name_is_refused():
 # -- the count, on lanes of each kind ------------------------------------------
 
 LANE = dict(dtype="float32", n_slots=4, kv_block_size=16, prefill_chunk=16,
-            mixed_step=True, prefix_sharing=False)
+            prefix_sharing=False)
 LANES = {
     "plain": ("gpt2-small-test", {}),
     "controls": ("gpt2-small-test", dict(repetition_penalty=1.3,

@@ -66,7 +66,7 @@ def lane(spec, params):
     gen = ContinuousGenerator(spec, params=params, dtype="float32",
                               n_slots=4, step_chunk=4, max_seq=128,
                               kv_block_size=16, prefill_chunk=16,
-                              mixed_step=True, mixed_token_budget=16,
+                              mixed_token_budget=16,
                               prefix_sharing=False)
     gen.tracer = SpanRecorder(8192)
     gen.trace_node = "lane"
@@ -206,7 +206,7 @@ def test_compile_counter_moves_only_for_a_new_program(spec, params, lane):
     other = ContinuousGenerator(spec, params=params, dtype="float32",
                                 n_slots=4, step_chunk=4, max_seq=128,
                                 kv_block_size=16, prefill_chunk=32,
-                                mixed_step=True, mixed_token_budget=32,
+                                mixed_token_budget=32,
                                 prefix_sharing=False)
     other.tracer = SpanRecorder(256)
     try:
@@ -237,7 +237,7 @@ def test_every_tick_function_uses_the_one_clock(kind, spec, params):
         gen = ContinuousGenerator(spec, params=params, dtype="float32",
                                   n_slots=2, step_chunk=4, max_seq=128,
                                   kv_block_size=16, prefill_chunk=16,
-                                  mixed_step=True, mixed_token_budget=16,
+                                  mixed_token_budget=16,
                                   spec_k=2)
         prompt = [3, 3, 3, 3, 3, 3]
     else:
@@ -245,7 +245,7 @@ def test_every_tick_function_uses_the_one_clock(kind, spec, params):
         gen = ContinuousGenerator(
             slab_spec, params=slab_spec.init(jax.random.PRNGKey(0)),
             dtype="float32", n_slots=2, state_rows=4, prefill_chunk=8,
-            mixed_step=True, mixed_token_budget=16)
+            mixed_token_budget=16)
         prompt = list(range(1, 12))
     gen.tracer = SpanRecorder(512)
     try:
@@ -438,7 +438,7 @@ def _worker(spec, params, node_id, **config):
     return WorkerNode(WorkerConfig(
         node_id=node_id, model="gpt2-small-test", dtype="float32",
         gen_scheduler="continuous", gen_max_batch_size=2,
-        gen_kv_block_size=16, gen_prefill_chunk=16, gen_mixed_step=True,
+        gen_kv_block_size=16, gen_prefill_chunk=16,
         gen_mixed_token_budget=16, **config), engine=engine)
 
 

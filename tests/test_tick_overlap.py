@@ -50,7 +50,7 @@ _ensure_builtin_models_imported()
 BS = 16
 MAX_SEQ = 96
 LANE = dict(dtype="float32", n_slots=4, max_seq=MAX_SEQ, kv_block_size=BS,
-            prefill_chunk=16, mixed_step=True, mixed_token_budget=16,
+            prefill_chunk=16, mixed_token_budget=16,
             prefix_sharing=False)
 
 
@@ -529,6 +529,48 @@ def test_an_export_command_lands_the_tick_in_flight_first(command, spec,
         o.stop()
 
 
+def test_an_int8_import_lands_behind_a_tick_in_flight(spec, params):
+    """An int8 pool's chain (payload and scales, verbatim) adopted by a
+    lane whose tick is in flight for a neighbour: the import waits for no
+    drain of its own, the resumed stream and the neighbour's are the
+    in-order int8 lane's, and every scale slot goes back with its block."""
+    lane = {**LANE, "kv_quantize": "int8"}
+    a = ContinuousGenerator(spec, params=params, **lane)
+    b = ContinuousGenerator(spec, params=params, **lane)
+    o = in_order(ContinuousGenerator(spec, params=params, **lane))
+    try:
+        request = dict(prompt=_prompt(93, 25), max_new_tokens=30)
+        other = dict(prompt=_prompt(94, 9), max_new_tokens=40)
+        want, want_other = serve(o, [request])[0], serve(o, [other])[0]
+        snap, _, _ = _export_mid_stream(
+            a, request, lambda a: a.export_row("mover", timeout_s=30))
+        assert snap["ok"], snap
+        assert snap["chain"]["quantized"]
+        stream = queue.Queue()
+        beside = b.submit(**other, stream=stream)
+        got = []
+        while len(got) < 4:                  # the neighbour is decoding
+            got += stream.get(timeout=60)
+        flights = []
+        real = b._admit_import
+
+        def watching(item, row):
+            flights.append(b._inflight)
+            return real(item, row)
+
+        b._admit_import = watching
+        assert b.submit_import(snap).result(timeout=120) == want
+        assert beside.result(timeout=120) == want_other
+        assert len(flights) == 1 and flights[0] is not None
+        wait_idle(b)
+        assert _pool_whole(a) and _pool_whole(b)
+        assert b.stats()["migration"]["imported_rows"] == 1
+    finally:
+        a.stop()
+        b.stop()
+        o.stop()
+
+
 def test_a_budget_past_the_cache_ends_at_the_cache_as_in_order(spec, params):
     """An imported snapshot's budget is the source lane's, not clamped to
     this lane's cache: the row ends at `max_seq - 1`, and with the token
@@ -561,6 +603,46 @@ def test_a_budget_past_the_cache_ends_at_the_cache_as_in_order(spec, params):
     finally:
         a.stop()
         b.stop()
+        o.stop()
+
+
+def test_a_parked_row_rides_beside_a_prefilling_row(spec, params):
+    """A handoff row, parked, in the ticks that carry a neighbour's prompt
+    chunks: it is fed no token (its `pos` and pending token stand still
+    while the neighbour's prompt goes through three ticks), spends none of
+    the budget, and both streams are the in-order lane's once the park
+    runs out."""
+    gen = ContinuousGenerator(spec, params=params, **LANE)
+    o = in_order(ContinuousGenerator(spec, params=params, **LANE))
+    try:
+        request = dict(prompt=_prompt(97, 10), max_new_tokens=10)
+        long = dict(prompt=_prompt(98, 48), max_new_tokens=6)
+        want, want_long = serve(o, [request])[0], serve(o, [long])[0]
+        parked = gen.submit(**request, tag="park", handoff=True,
+                            handoff_park_s=1.5)
+        limit = time.monotonic() + 60
+        while not any(gen._held) and time.monotonic() < limit:
+            time.sleep(0.0005)
+        row = gen._held.index(True)
+        at = (int(gen._pos[row]), int(gen._tok[row]),
+              list(gen._row_emitted[row]))
+        assert at[2] == want[:1]
+        before = mixed_counters(gen)
+        beside = gen.submit(**long)
+        assert beside.result(timeout=120) == want_long
+        after = mixed_counters(gen)
+        # 48 prompt tokens at a chunk of 16: three ticks carried them
+        # beside the parked row, and none of them stepped it.
+        assert after["prefill_tokens"] - before["prefill_tokens"] == 48
+        assert after["coscheduled_ticks"] == before["coscheduled_ticks"]
+        if gen._held[row]:
+            assert at == (int(gen._pos[row]), int(gen._tok[row]),
+                          list(gen._row_emitted[row]))
+        assert parked.result(timeout=120) == want    # the park ran out
+        wait_idle(gen)
+        assert _pool_whole(gen)
+    finally:
+        gen.stop()
         o.stop()
 
 
@@ -607,7 +689,7 @@ def test_a_speculative_and_a_slab_lane_never_run_ahead(kind, spec, params):
         slab = create_model("ssd-small-test")
         gen = ContinuousGenerator(
             slab, params=slab.init(jax.random.PRNGKey(0)), dtype="float32",
-            n_slots=2, state_rows=4, prefill_chunk=8, mixed_step=True,
+            n_slots=2, state_rows=4, prefill_chunk=8,
             mixed_token_budget=16)
         prompt = list(range(1, 12))
     gen.tracer = SpanRecorder(512)

@@ -3,7 +3,7 @@ paged scheduler plus the topology-aware gateway ring.
 
 Contracts under test:
 - STREAM IDENTITY: greedy AND seeded streams at tp ∈ {1, 2, 4} are
-  byte-identical across the two-path, mixed, and speculative paged
+  byte-identical across the plain and speculative paged
   schedulers on the CPU mesh (the logits agree to ~1e-6 — the same
   empirical basis as the mixed-vs-dense identity the engine already
   rests on), with radix prefix hits and the int8 quantized pool
@@ -150,11 +150,11 @@ def test_a_block_budget_one_chip_refuses_serves_the_same_row_at_tp2(
     with pytest.raises(ValueError, match="cannot hold even one max_seq row"):
         make_gen(spec, params, kv_blocks=per_chip)
     long_prompt = [(i * 7) % 90 + 1 for i in range(40)]
-    want = run_streams(make_gen(spec, params, mixed_step=True,
+    want = run_streams(make_gen(spec, params,
                                 mixed_token_budget=32), [long_prompt],
                        max_new=20)
     gen = make_gen(spec, params, tp=2, kv_blocks=2 * per_chip,
-                   mixed_step=True, mixed_token_budget=32)
+                   mixed_token_budget=32)
     try:
         pool = gen.stats()["kv_pool"]
         assert pool["tp"] == 2 and pool["blocks_total"] == 2 * per_chip - 1
@@ -190,9 +190,9 @@ def test_mixed_tp2_streams_identical_single_dispatch(spec, params):
     """The tier-1 smoke: mixed stepping at tp=2 — greedy AND seeded
     streams byte-identical to the tp=1 arm, exactly one compiled ragged
     dispatch per tick, pool sharding stable, zero leaks."""
-    base = run_streams(make_gen(spec, params, mixed_step=True,
+    base = run_streams(make_gen(spec, params,
                                 mixed_token_budget=32), PROMPTS)
-    gen = make_gen(spec, params, tp=2, mixed_step=True,
+    gen = make_gen(spec, params, tp=2,
                    mixed_token_budget=32)
     sharding_before = gen._pool.caches.k.sharding
     try:
@@ -214,14 +214,14 @@ def test_mixed_tp2_streams_identical_single_dispatch(spec, params):
     finally:
         gen.stop()
     seeded_base = run_streams(
-        make_gen(spec, params, mixed_step=True, mixed_token_budget=32),
+        make_gen(spec, params, mixed_token_budget=32),
         PROMPTS, temperature=0.9, seed=[7, 8, 9, 10])
     assert seeded == seeded_base
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("tp", [2, 4])
-def test_two_path_tp_streams_identical(spec, params, tp):
+def test_default_budget_tp_streams_identical(spec, params, tp):
     base = run_streams(make_gen(spec, params), PROMPTS)
     gen = make_gen(spec, params, tp=tp)
     try:
@@ -235,16 +235,15 @@ def test_two_path_tp_streams_identical(spec, params, tp):
 
 @pytest.mark.slow
 def test_mixed_tp4_streams_identical(spec, params):
-    base = run_streams(make_gen(spec, params, mixed_step=True,
+    base = run_streams(make_gen(spec, params,
                                 mixed_token_budget=32), PROMPTS)
-    assert run_streams(make_gen(spec, params, tp=4, mixed_step=True,
+    assert run_streams(make_gen(spec, params, tp=4,
                                 mixed_token_budget=32), PROMPTS) == base
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("mixed", [False, True])
-def test_spec_tp2_streams_identical(spec, params, mixed):
-    kw = dict(spec_k=2, mixed_step=mixed, mixed_token_budget=32)
+def test_spec_tp2_streams_identical(spec, params):
+    kw = dict(spec_k=2, mixed_token_budget=32)
     base = run_streams(make_gen(spec, params, **kw), PROMPTS)
     gen = make_gen(spec, params, tp=2, **kw)
     try:
@@ -262,9 +261,9 @@ def test_radix_hit_tp2_identical(spec, params):
     """Shared prefixes still share under a sharded pool: the second
     stream's first block comes from the radix tree (prefix_hit_tokens
     > 0) and both streams match the tp=1 arm byte-for-byte."""
-    base = run_streams(make_gen(spec, params, mixed_step=True), SHARED,
+    base = run_streams(make_gen(spec, params), SHARED,
                        max_new=8)
-    gen = make_gen(spec, params, tp=2, mixed_step=True)
+    gen = make_gen(spec, params, tp=2)
     try:
         # Serialize so the second admission sees the first's blocks.
         out = [gen.generate([p], max_new_tokens=8)[0] for p in SHARED]
@@ -281,7 +280,7 @@ def test_quantized_pool_tp2_deterministic(spec, params):
     """int8 pool under TP: scale arrays shard alongside the payloads,
     streams are deterministic run-to-run and (on this backend) equal to
     the tp=1 quantized arm; zero leaks."""
-    kw = dict(mixed_step=True, kv_quantize="int8")
+    kw = dict(kv_quantize="int8")
     base = run_streams(make_gen(spec, params, **kw), PROMPTS)
     gen = make_gen(spec, params, tp=2, **kw)
     try:
@@ -332,11 +331,11 @@ def test_migration_between_equal_tp_lanes_byte_identical(spec, params):
     """Export a live tp=2 row mid-stream, import it on another tp=2
     lane: the spliced stream equals an uninterrupted run; the same
     snapshot refuses on a tp=1 lane with the geometry named."""
-    control = run_streams(make_gen(spec, params, mixed_step=True),
+    control = run_streams(make_gen(spec, params),
                           [PROMPTS[0]], max_new=16)[0]
-    src = make_gen(spec, params, tp=2, mixed_step=True)
-    dst = make_gen(spec, params, tp=2, mixed_step=True)
-    one = make_gen(spec, params, mixed_step=True)
+    src = make_gen(spec, params, tp=2)
+    dst = make_gen(spec, params, tp=2)
+    one = make_gen(spec, params)
     try:
         # Park-after-prefill makes the export deterministic: the row
         # holds (first token emitted, chain complete) until the
@@ -498,7 +497,7 @@ def test_worker_tp_e2e_health_and_generate(spec, params):
 
     def lane(nid, tp, offset=0):
         cfg = WorkerConfig(node_id=nid, model="gpt2-small-test",
-                           gen_kv_block_size=16, gen_mixed_step=True,
+                           gen_kv_block_size=16,
                            tp=tp, tp_device_offset=offset)
         return WorkerNode(cfg, engine=InferenceEngine(
             spec, params=params, dtype="float32"))

@@ -135,7 +135,7 @@ def test_kv_blocks_fenced_on_stateless_model():
 
 
 def test_mixed_step_fenced_on_stateless_model():
-    with pytest.raises(RuntimeError, match="mixed-step"):
+    with pytest.raises(RuntimeError, match="mixed stepping"):
         make_mlp("f4", gen_mixed_step=True)
 
 

@@ -239,7 +239,7 @@ ENGINE_REGISTRY = Registry(
         # documented idiom, so only WRITES must hold the compile lock.
         GuardedEntry(
             attrs=("_prefill_exe", "_insert_exe", "_decode_exe",
-                   "_window_exe", "_gather_exe", "_scatter_exe"),
+                   "_window_exe"),
             lock="ContinuousGenerator._exe_lock",
             classes=("ContinuousGenerator",),
             mode="w"),
@@ -320,7 +320,6 @@ ENGINE_REGISTRY = Registry(
         "tpu_engine.runtime.scheduler:ContinuousGenerator._prefill_loop",
         "tpu_engine.runtime.scheduler:ContinuousGenerator._tick_mixed",
         "tpu_engine.runtime.scheduler:ContinuousGenerator._tick_spec",
-        "tpu_engine.runtime.scheduler:ContinuousGenerator._tick_slab",
         "tpu_engine.runtime.scheduler:ContinuousGenerator."
         "_tick_slab_mixed",
         # Unified stateless serving (PR 20): one-shot rows dispatch from

@@ -30,7 +30,7 @@ failure paths, not just the happy path.
 
 ``--mixed`` runs a STANDALONE mixed-stepping fault scenario instead: it
 spawns its own combined server (gpt2-small-test decode lane with
-``--kv-block-size 16 --mixed-step`` and a tiny token budget so prefills
+``--kv-block-size 16`` and a tiny token budget so prefills
 span many ticks), fires /generate requests whose deadlines expire
 mid-prefill-chunk, and asserts via ``/stats`` + ``/trace/export`` that
 every cancelled row returned its blocks to the pool, none reappears in a
@@ -356,7 +356,7 @@ def launch_mixed_server(attempts: int = 3):
         cmd = [sys.executable, "-m", "tpu_engine.serving.cli", "serve",
                "--model", "gpt2-small-test", "--lanes", "1",
                "--port", str(port), "--kv-block-size", "16",
-               "--mixed-step", "--mixed-token-budget", "2",
+               "--mixed-token-budget", "2",
                "--gen-prefill-chunk", "16"]
         proc = subprocess.Popen(cmd, cwd=repo, env=env,
                                 stdout=sys.stderr, stderr=sys.stderr)
@@ -2272,7 +2272,7 @@ def launch_overload_server(attempts: int = 3):
                "--model", "gpt2-small-test", "--lanes", "3",
                "--port", str(port),
                "--kv-block-size", "16", "--kv-blocks", "24",
-               "--mixed-step", "--mixed-token-budget", "16",
+               "--mixed-token-budget", "16",
                "--spec-k", "2",
                "--max-queue-depth", "4",
                "--default-deadline-ms", "30000",
@@ -3638,7 +3638,7 @@ def main() -> int:
                          "free-port bind race")
     ap.add_argument("--mixed", action="store_true",
                     help="standalone mixed-stepping scenario: spawns its "
-                         "own --mixed-step server and asserts cancelled "
+                         "own paged server and asserts cancelled "
                          "mid-prefill rows return their blocks (see "
                          "module docstring); ignores the other flags")
     ap.add_argument("--spec", action="store_true",

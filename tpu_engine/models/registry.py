@@ -32,7 +32,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 # at start-up (runtime.scheduler): int8 KV scales are per KV head,
 # `--tp` shards H_kv (here 1), the host tier and the chain wire format
 # (migration, handoff, prefix fetch) carry a K and a V of equal width,
-# speculative verify and the two-path prefill have no latent read.
+# speculative verify and the dense per-slot cache have no latent read.
 # "kv_windowed": a kv_paged chain whose sliding-window layers keep only
 # the blocks their window still sees (models.laguna): a row holds blocks of
 # two kinds, and the window kind's are given back as the row's position
@@ -56,17 +56,19 @@ from typing import Callable, Dict, List, Optional, Tuple
 # verify, `--tp`, migration and handoff are ABSENT, whatever the blocks
 # hold.
 FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
-    "kv_paged": ("generate", "two_path", "mixed_step", "spec_decode",
+    # "two_path": the dense per-slot cache (`kv_block_size` 0) and its
+    # prefill-thread / chunk-loop stepping. Every other lane that
+    # generates holds a pool or a slab and steps by the ragged tick.
+    "kv_paged": ("generate", "two_path", "spec_decode",
                  "paged_kv", "prefix_sharing", "kv_quantize",
                  "kv_host_tier", "migration", "handoff",
                  "tensor_parallel", "oneshot_rows"),
-    "state_slab": ("generate", "two_path", "mixed_step", "migration",
-                   "handoff", "oneshot_rows"),
+    "state_slab": ("generate", "migration", "handoff", "oneshot_rows"),
     "stateless": ("oneshot_rows",),
-    "kv_latent": ("generate", "mixed_step", "paged_kv", "prefix_sharing",
+    "kv_latent": ("generate", "paged_kv", "prefix_sharing",
                   "oneshot_rows"),
-    "kv_windowed": ("generate", "mixed_step", "paged_kv", "oneshot_rows"),
-    "kv_and_state": ("generate", "mixed_step", "paged_kv", "oneshot_rows"),
+    "kv_windowed": ("generate", "paged_kv", "oneshot_rows"),
+    "kv_and_state": ("generate", "paged_kv", "oneshot_rows"),
 }
 
 # -- tensor-parallel partition rules ------------------------------------------

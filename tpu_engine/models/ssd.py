@@ -22,10 +22,10 @@ Block = gated SSD mixer (Mamba-2 shape):
 
 Serving uses the O(1) recurrence for BOTH prefill and decode
 (`ssd_step_rows` scanned over prompt windows): the recurrence is
-partition-invariant, so any chunking of the prompt — two-path windows,
-mixed-step budgeted chunks, a crash-replay (prompt ⧺ emitted) resume —
+partition-invariant, so any chunking of the prompt — budgeted chunks of
+any width, a crash-replay (prompt ⧺ emitted) resume —
 produces bit-identical state, which is what makes greedy streams
-byte-identical across scheduling modes (tested). The chunked
+byte-identical across token budgets (tested). The chunked
 matmul-form prefill (`ssd_prefill_chunked`, ops.ssd.ssd_chunked) is the
 on-chip throughput path, held to the recurrence by
 ``ops.ssd.ssd_parity_check``.
@@ -218,8 +218,7 @@ def ssd_step_rows_masked(params, tok, states: SSDState, valid,
 def ssd_window_scan(params, tokens, states: SSDState, qlen, sample_slot,
                     cfg: SSDConfig):
     """Consume up to W prompt tokens per row from the rows' current
-    states — the budgeted-prefill-chunk form shared (bit-identically) by
-    the two-path prefill thread (B=1 windows) and the mixed tick's
+    states — the budgeted-prefill-chunk form of the mixed tick's
     ragged rows. tokens (B, W); row r advances through its first
     ``qlen[r]`` slots (the rest are padding); the returned logits are
     each row's slot ``sample_slot[r]`` output (garbage for rows whose

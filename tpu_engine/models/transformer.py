@@ -567,90 +567,15 @@ def _scan_layers_paged(block_fn, params, h, caches: KVCache,
     return h, KVCache(*cache_kv)
 
 
-def _block_decode_rows_paged(bp, h, cache_kv, layer, tables, pos_vec,
-                             cfg: TransformerConfig, *, dtype, attn_fn):
-    """One decode step against the PAGED pool: cache_kv arrays are the
-    whole (L, NB, bs, H_kv*D) block pools shared by every row and layer
-    (`runtime.kv_blocks.BlockPool` states the layout); ``tables``
-    (B, nb) maps row b's logical column c to pool block
-    ``tables[b, c // bs]``, offset ``c % bs``. Paged rows are 0-aligned
-    (token i at logical column i — the alignment radix sharing needs),
-    so pos_vec IS the logical position. The new token's K/V is scattered
-    into its block of layer ``layer`` BEFORE the attention read
-    (write-before-attend, like every other decode path).
-
-    QUANTIZED pool (cache_kv = (ck, cv, ks, vs)): see `_write_pool`;
-    ``attn_fn`` must be a quantized read path
-    (ops.paged_attention.default_quant_paged_attention)."""
-    bs = cache_kv[0].shape[2]
-    b = h.shape[0]
-    x = _norm(bp["ln1"], h, cfg)
-    q, k, v = _project_qkv(bp, x, cfg, dtype=dtype,
-                           positions=pos_vec[:, None])
-    rows = jnp.arange(b)
-    blk = tables[rows, pos_vec // bs]
-    off = pos_vec % bs
-    cache_kv = _write_pool(cache_kv, layer, blk, off, k[:, 0], v[:, 0])
-    a = attn_fn(q, *cache_kv, layer, tables, pos_vec)  # grouped, unexpanded
-    a = a.astype(dtype)
-    h = h + nn.dense(bp["attn"]["wo"], a.reshape(b, 1, -1), dtype=dtype)
-    h = h + _mlp(bp["mlp"], _norm(bp["ln2"], h, cfg), dtype, cfg)
-    return h.astype(dtype), cache_kv
-
-
-def transformer_decode_rows_paged(params, token_t, caches: KVCache, tables,
-                                  pos_vec, cfg: TransformerConfig, *,
-                                  dtype=jnp.bfloat16, attn_fn=None,
-                                  scales: Optional[KVCache] = None):
-    """`transformer_decode_rows` over a block pool instead of per-row
-    cache stripes. caches: (L, NB, bs, H_kv*D) pool pair, updated in
-    place through the layer loop (`_scan_layers_paged`); tables:
-    (B, nb) int32 per-row block tables (0 = the reserved null block —
-    masked by pos); pos_vec: (B,) logical write positions (0-aligned
-    rows: no start_vec). ``attn_fn`` defaults to
-    `ops.paged_attention.default_paged_attention()` — the Pallas kernel
-    on TPU, the XLA gather reference elsewhere. Returns
-    (logits (B, vocab), caches).
-
-    ``scales`` (a KVCache pair of (L, NB, bs, H_kv) f32 arrays) switches
-    to the QUANTIZED pool: payloads are int8, the new token quantizes at
-    its write, and the return grows to (logits, caches, scales).
-    ``attn_fn`` then defaults to the quantized read path."""
-    if attn_fn is None:
-        from tpu_engine.ops.paged_attention import (
-            default_paged_attention,
-            default_quant_paged_attention,
-        )
-
-        attn_fn = (default_quant_paged_attention() if scales is not None
-                   else default_paged_attention())
-    if cfg.sliding_window is not None:
-        # Band masking is not plumbed through the paged read path yet;
-        # failing loudly beats silently attending the full context.
-        raise NotImplementedError(
-            "sliding_window models are not supported by the paged KV "
-            "cache (use the dense scheduler)")
-    h = nn.embedding(params["tok_embed"], token_t[:, None])
-    if cfg.pos == "learned":
-        logical = jnp.clip(pos_vec, 0,
-                           params["pos_embed"]["table"].shape[0] - 1)
-        h = h + params["pos_embed"]["table"][logical][:, None, :]
-    h = h.astype(dtype)
-
-    def block(bp, h, cache_kv, layer):
-        return _block_decode_rows_paged(
-            bp, h, cache_kv, layer, tables, pos_vec, cfg, dtype=dtype,
-            attn_fn=attn_fn)
-
-    h, *pool = _scan_layers_paged(block, params, h, caches, scales)
-    h = _norm(params["ln_f"], h, cfg)
-    logits = nn.dense(params["head"], h, dtype=dtype).astype(jnp.float32)
-    return (logits[:, 0], *pool)
-
-
 def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
                             cfg: TransformerConfig, *, dtype, attn_fn):
-    """One ragged mixed step against the PAGED pool: row b consumes
+    """One ragged mixed step against the PAGED pool: cache_kv arrays are
+    the whole (L, NB, bs, H_kv*D) block pools shared by every row and
+    layer (`runtime.kv_blocks.BlockPool` states the layout); ``tables``
+    (B, nb) maps row b's logical column c to pool block
+    ``tables[b, c // bs]``, offset ``c % bs``. Paged rows are 0-aligned
+    (token i at logical column i — the alignment radix sharing needs).
+    Row b consumes
     qlen[b] new tokens at logical columns [pos0[b], pos0[b]+qlen[b])
     (decode rows: qlen 1; admitting rows: a prefill chunk). All W slots'
     K/V scatter into the rows' pool blocks of layer ``layer`` BEFORE the
@@ -683,8 +608,8 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
                                  dtype=jnp.bfloat16, attn_fn=None,
                                  sample_slot=None, sample_width: int = 1,
                                  scales: Optional[KVCache] = None):
-    """The mixed prefill+decode primitive (runtime.scheduler
-    --mixed-step): one ragged batch where each row consumes qlen[b] >= 0
+    """The mixed prefill+decode primitive (runtime.scheduler's ragged
+    tick): one ragged batch where each row consumes qlen[b] >= 0
     new tokens, writing their KV straight into the row's pool blocks in
     the SAME dispatch. tokens: (B, W) int32 right-aligned at slot 0;
     caches: (L, NB, bs, H_kv*D) pool pair, updated in place through the

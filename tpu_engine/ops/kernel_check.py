@@ -1,8 +1,8 @@
 """Every Pallas kernel site at the geometry of the models the registry serves.
 
 One list of cases (`kernel_cases`) covers the flash kernel (forward at
-every prompt bucket, backward) and the paged read paths (decode, ragged
-at q_len 1 and at the prefill-chunk width, both int8 variants) at the
+every prompt bucket, backward) and the paged read paths (ragged at
+q_len 1 and at the prefill-chunk width, and the int8 variant of each) at the
 head geometry of a registered model; for a latent-attention model
 (`LATENT_MODELS`) it is the latent read at both widths instead, and for
 one whose rows also own a recurrent state (`RECURRENT_MODELS`) both forms
@@ -232,10 +232,8 @@ def _paged_cases(model: str, geo: dict, interpret: bool):
     # full and partial prefill chunks, one a block past a boundary.
     mixed = (1, CHUNK, 130, BLOCK_SIZE + 1, 1, 1, 77, CHUNK)
     for kind, label, q_lens in (
-            ("paged", "paged_decode", (1,) * ROWS),
             ("ragged", "ragged/W1", (1,) * ROWS),
             ("ragged", f"ragged/W{CHUNK}", mixed),
-            ("quant_paged", "quant_decode", (1,) * ROWS),
             ("quant_ragged", "quant_ragged/W1", (1,) * ROWS),
             ("quant_ragged", f"quant_ragged/W{CHUNK}", mixed)):
         kernel_fn, reference_fn = pa.READ_PATHS[kind]

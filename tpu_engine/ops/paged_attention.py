@@ -4,16 +4,17 @@ The paged KV cache (runtime.kv_blocks) stores every row's keys/values in
 fixed-size blocks of a shared pool instead of one dense per-row stripe;
 a per-row **block table** maps logical column `c` to pool block
 `table[c // bs]`, offset `c % bs`. This module is the attention read
-side of that layout. Four read paths share one contract each with an
+side of that layout. Two read paths share one contract each with an
 XLA reference:
 
-- decode (`paged_attention`): one query token per row;
-- ragged (`ragged_paged_attention`): q_len >= 1 per row — the mixed
-  scheduler (--mixed-step) serves decode rows (one token) and admitting
-  rows (a prefill chunk) in ONE dispatch, with causal masking inside
-  each row's new-token window (query slot i attends kpos <= pos0 + i);
-- the two int8-pool variants (`quant_*`, --kv-quantize int8), which
-  apply the per-slot scales inside the read.
+- ragged (`ragged_paged_attention`): q_len >= 0 per row — the ragged
+  tick serves decode rows (one token) and admitting rows (a prefill
+  chunk) in ONE dispatch, with causal masking inside each row's
+  new-token window (query slot i attends kpos <= pos0 + i); a decode
+  row is a ragged row of q_len 1, and a call one slot wide is the
+  packed one-query geometry below;
+- its int8-pool variant (`quant_ragged_paged_attention`, --kv-quantize
+  int8), which applies the per-slot scales inside the read.
 
 Every read path takes the WHOLE pool, (L, NB, bs, H_kv*D) as
 `runtime.kv_blocks.BlockPool` holds it (head h in lanes
@@ -34,9 +35,8 @@ gathered view puts every logical column at the same index the dense
 scheduler would, so reductions see identical operand layouts and seeded
 token streams match the dense path.
 
-The kernel side is ONE Pallas TPU kernel (`_paged_kernel`) behind all
-four entry points — decode is the ragged read at q_len 1, and the pool
-dtype is a static flag. **The grid is the query tiles, not the table.**
+The kernel side is ONE Pallas TPU kernel (`_paged_kernel`) behind both
+entry points — the pool dtype is a static flag. **The grid is the query tiles, not the table.**
 Query slots stack with the group heads on the sublane axis: q is laid
 out (B, H_kv, W*G, D) with G = n_heads/kv_heads (row r = slot r//G,
 head r%G), tiled `_ROW_TILE` rows at a time, and the grid is
@@ -180,14 +180,6 @@ def _dense_rows(q, k_pool, v_pool, k_scale, v_scale, layer, tables):
     return kk, vv
 
 
-def _decode_reference(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
-                      pos_vec):
-    kk, vv = _dense_rows(q, k_pool, v_pool, k_scale, v_scale, layer, tables)
-    kpos = jnp.arange(kk.shape[1])[None, :]
-    valid = (kpos <= pos_vec[:, None]).astype(jnp.int32)
-    return dot_product_attention(q, kk, vv, mask=valid)
-
-
 def _ragged_reference(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
                       pos0, window=None):
     kk, vv = _dense_rows(q, k_pool, v_pool, k_scale, v_scale, layer, tables)
@@ -199,20 +191,9 @@ def _ragged_reference(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
     return dot_product_attention(q, kk, vv, mask=valid.astype(jnp.int32))
 
 
-def paged_attention_reference(q, k_pool, v_pool, layer, tables, pos_vec):
-    """XLA gather path. q: (B, 1, H, D); k_pool/v_pool:
-    (L, NB, bs, H_kv*D), the whole pool; layer: int32 scalar, the layer
-    read; tables: (B, nb) int32 block ids (0 = the reserved null block —
-    its columns must be masked by `pos_vec`); pos_vec: (B,) last valid
-    logical column per row (columns kpos <= pos are attended). Returns
-    (B, 1, H, D)."""
-    return _decode_reference(q, k_pool, v_pool, None, None, layer, tables,
-                             pos_vec)
-
-
 # -- ragged (mixed prefill+decode) reference ---------------------------------
 #
-# The mixed scheduler (runtime.scheduler, --mixed-step) folds admission
+# The ragged tick (runtime.scheduler) folds admission
 # prefill into the decode dispatch: one ragged batch where decode rows
 # contribute ONE new token and admitting rows contribute a prefill chunk
 # of up to W tokens (PAPERS.md "Ragged Paged Attention"). Row b's query
@@ -224,8 +205,10 @@ def paged_attention_reference(q, k_pool, v_pool, layer, tables, pos_vec):
 def ragged_paged_attention_reference(q, k_pool, v_pool, layer, tables, pos0,
                                      qlen, *, window=None):
     """XLA gather path, ragged queries. q: (B, W, H, D);
-    k_pool/v_pool: (L, NB, bs, H_kv*D); layer: int32 scalar; tables:
-    (B, nb) int32 block ids; pos0: (B,) logical position of each row's
+    k_pool/v_pool: (L, NB, bs, H_kv*D), the whole pool; layer: int32
+    scalar, the layer read; tables: (B, nb) int32 block ids (0 = the
+    reserved null block: its columns must lie past what the row's
+    slots see); pos0: (B,) logical position of each row's
     FIRST query slot; qlen: (B,) valid query slots (padding slots
     produce garbage the caller must ignore — masking them costs more
     than ignoring). `window` (a sliding-window layer): query slot i also
@@ -249,27 +232,20 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, layer, tables, pos0,
 # int8 write at block-fill time.
 
 
-def quant_paged_attention_reference(q, k_pool, v_pool, k_scale, v_scale,
-                                    layer, tables, pos_vec):
-    """`paged_attention_reference` over the int8 pool. k_pool/v_pool:
-    (L, NB, bs, H_kv*D) int8; k_scale/v_scale: (L, NB, bs, H_kv) f32.
-    The gathered view dequantizes to f32 (exact: int8 * f32 scale), then
-    the identical dense attention math runs."""
-    return _decode_reference(q, k_pool, v_pool, k_scale, v_scale, layer,
-                             tables, pos_vec)
-
-
 def quant_ragged_paged_attention_reference(q, k_pool, v_pool, k_scale,
                                            v_scale, layer, tables, pos0,
                                            qlen):
     """`ragged_paged_attention_reference` over the int8 pool (same
-    contract; padding slots produce garbage the caller ignores)."""
+    contract; padding slots produce garbage the caller ignores).
+    k_pool/v_pool: (L, NB, bs, H_kv*D) int8; k_scale/v_scale:
+    (L, NB, bs, H_kv) f32. The gathered view dequantizes to f32 (exact:
+    int8 * f32 scale), then the identical dense attention math runs."""
     del qlen
     return _ragged_reference(q, k_pool, v_pool, k_scale, v_scale, layer,
                              tables, pos0)
 
 
-# -- the kernel (all four read paths) -----------------------------------------
+# -- the kernel (both read paths) ---------------------------------------------
 
 
 def _tile_geometry(rows_a_row: int, n_kv_heads: int):
@@ -660,14 +636,6 @@ def _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0, qlen,
                        interpret=interpret, window=window)
 
 
-def paged_attention(q, k_pool, v_pool, layer, tables, pos_vec, *,
-                    interpret=None):
-    """Pallas-kernel drop-in for `paged_attention_reference` (same
-    signature/contract): the ragged read at q_len 1."""
-    return _paged(q, k_pool, v_pool, None, None, layer, tables, pos_vec, 1,
-                  interpret)
-
-
 def ragged_paged_attention(q, k_pool, v_pool, layer, tables, pos0, qlen, *,
                            window=None, interpret=None):
     """Pallas-kernel drop-in for `ragged_paged_attention_reference` (same
@@ -676,19 +644,11 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, tables, pos0, qlen, *,
                   interpret, window)
 
 
-def quant_paged_attention(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
-                          pos_vec, *, interpret=None):
-    """Pallas-kernel drop-in for `quant_paged_attention_reference` (same
-    signature/contract): the block DMAs are int8, about half the bf16
-    bytes, and the scales are applied in VMEM."""
-    return _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
-                  pos_vec, 1, interpret)
-
-
 def quant_ragged_paged_attention(q, k_pool, v_pool, k_scale, v_scale, layer,
                                  tables, pos0, qlen, *, interpret=None):
     """Pallas-kernel drop-in for `quant_ragged_paged_attention_reference`
-    (same signature/contract)."""
+    (same signature/contract): the block DMAs are int8, about half the
+    bf16 bytes, and the scales are applied in VMEM."""
     return _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
                   qlen, interpret)
 
@@ -724,12 +684,10 @@ def ragged_read_by_class(attn_fn, q, pool, layer, tables, pos0, classes,
                      o_tall[tile, slot % height])
 
 
-# The four read paths: name -> (kernel entry point, XLA reference). The
+# The two read paths: name -> (kernel entry point, XLA reference). The
 # selectors, the start-up banner and the parity checks all read this.
 READ_PATHS = {
-    "paged": (paged_attention, paged_attention_reference),
     "ragged": (ragged_paged_attention, ragged_paged_attention_reference),
-    "quant_paged": (quant_paged_attention, quant_paged_attention_reference),
     "quant_ragged": (quant_ragged_paged_attention,
                      quant_ragged_paged_attention_reference),
 }
@@ -764,7 +722,7 @@ _PAGED_CACHE = {}
 
 
 def _select_impl(kind: str):
-    """One `TPU_ENGINE_PAGED` selection rule for all four read paths —
+    """One `TPU_ENGINE_PAGED` selection rule for both read paths —
     "1" forces the Pallas kernel (interpreter off-TPU — slow, for parity
     tests), "0" forces the XLA gather reference, unset/"auto" picks the
     kernel on TPU only."""
@@ -784,26 +742,15 @@ def _select_impl(kind: str):
     return fn
 
 
-def default_paged_attention():
+def default_ragged_attention():
     """Serving-path paged-attention selection, one rule with
     `models.transformer.default_attention` (see `_select_impl`)."""
-    return _select_impl("paged")
-
-
-def default_ragged_attention():
-    """Ragged-variant selection — the same env knob and rule as
-    `default_paged_attention` governs both read paths."""
     return _select_impl("ragged")
 
 
-def default_quant_paged_attention():
-    """Quantized decode-path selection (int8 pool, --kv-quantize) — the
-    same `TPU_ENGINE_PAGED` knob and rule as the bf16 paths."""
-    return _select_impl("quant_paged")
-
-
 def default_quant_ragged_attention():
-    """Quantized ragged-path selection — one rule for all four paths."""
+    """Quantized-path selection (int8 pool, --kv-quantize) — the same
+    `TPU_ENGINE_PAGED` knob and rule as the bf16 path."""
     return _select_impl("quant_ragged")
 
 
@@ -838,7 +785,7 @@ def parity_workload(kind: str, q_lens, *, n_heads: int, n_kv_heads: int,
     ops.kernel_check."""
     import numpy as np
 
-    decode, quant = "ragged" not in kind, kind.startswith("quant")
+    quant = kind.startswith("quant")
     rng = np.random.default_rng(seed)
     batch = len(q_lens)
     w = max(q_lens)
@@ -873,11 +820,8 @@ def parity_workload(kind: str, q_lens, *, n_heads: int, n_kv_heads: int,
             if window is not None:
                 tables[r, :max(placed[r] - window + 1, 0) // block_size] = 0
     qlen = jnp.asarray(np.asarray(q_lens, np.int32))
-    where = (jnp.int32(_PARITY_LAYER), jnp.asarray(tables),
-             jnp.asarray(pos0))
-    if not decode:
-        where += (qlen,)
-    return (q, *pool, *where), qlen
+    return (q, *pool, jnp.int32(_PARITY_LAYER), jnp.asarray(tables),
+            jnp.asarray(pos0), qlen), qlen
 
 
 def reference_gap(reference_fn, out, operands, qlen):
@@ -966,7 +910,7 @@ WALK_CASES = {
 def walk_parity_check(kind: str, case: str, *, interpret=None,
                       dtype=jnp.float32, seed: int = 0) -> float:
     """Max |kernel - reference| of `READ_PATHS[kind]` over one of
-    `WALK_CASES` (a decode path takes the width-1 cases only)."""
+    `WALK_CASES`."""
     q_lens, pos0, table_len = WALK_CASES[case]
     return _parity(kind, q_lens, n_heads=8, n_kv_heads=2, d_head=16,
                    block_size=16, n_blocks=1 + len(q_lens) * table_len,
@@ -1105,45 +1049,17 @@ def class_parity_check(case: str, group: int, *, interpret=None,
                    interpret=interpret), operands)
 
 
-def parity_check(batch: int = 2, n_heads: int = 4, n_kv_heads: int = 2,
-                 d_head: int = 8, block_size: int = 16, n_blocks: int = 9,
-                 table_len: int = 4, dtype=jnp.float32, seed: int = 0,
-                 interpret=None) -> float:
-    """Decode-path parity (`_parity` at q_len 1 per row) — shared by
-    tests/test_paged_kv.py, diagnostics.py --kernel-parity and
-    chip_smoke.py's kernel phase."""
-    return _parity("paged", (1,) * batch, n_heads=n_heads,
-                   n_kv_heads=n_kv_heads, d_head=d_head,
-                   block_size=block_size, n_blocks=n_blocks,
-                   table_len=table_len, dtype=dtype, seed=seed,
-                   interpret=interpret)
-
-
 def ragged_parity_check(q_lens=(1, 7, 16, 17), n_heads: int = 4,
                         n_kv_heads: int = 2, d_head: int = 8,
                         block_size: int = 16, n_blocks: int = 33,
                         table_len: int = 6, dtype=jnp.float32,
                         seed: int = 0, interpret=None) -> float:
     """Ragged-path parity: mixed decode (q_len 1) rows and prefill-chunk
-    rows in the same batch, the --mixed-step shape. Shared by
-    tests/test_mixed_step.py, diagnostics.py --mixed-parity and
-    chip_smoke.py's kernel phase."""
+    rows in the same batch, the ragged tick's shape; `q_lens` all 1 is
+    a decode-only tick's packed one-query call. Shared by
+    tests/test_mixed_step.py, diagnostics.py --kernel-parity /
+    --mixed-parity and chip_smoke.py's kernel phase."""
     return _parity("ragged", tuple(q_lens), n_heads=n_heads,
-                   n_kv_heads=n_kv_heads, d_head=d_head,
-                   block_size=block_size, n_blocks=n_blocks,
-                   table_len=table_len, dtype=dtype, seed=seed,
-                   interpret=interpret)
-
-
-def quant_parity_check(batch: int = 2, n_heads: int = 4, n_kv_heads: int = 2,
-                       d_head: int = 8, block_size: int = 16,
-                       n_blocks: int = 9, table_len: int = 4,
-                       dtype=jnp.float32, seed: int = 0,
-                       interpret=None) -> float:
-    """`parity_check` for the QUANTIZED decode path (int8 pool). Shared
-    by tests/test_kv_quant.py, diagnostics.py --quant-parity and
-    chip_smoke.py's kernel phase."""
-    return _parity("quant_paged", (1,) * batch, n_heads=n_heads,
                    n_kv_heads=n_kv_heads, d_head=d_head,
                    block_size=block_size, n_blocks=n_blocks,
                    table_len=table_len, dtype=dtype, seed=seed,
@@ -1156,8 +1072,9 @@ def quant_ragged_parity_check(q_lens=(1, 7, 16, 17), n_heads: int = 4,
                               table_len: int = 6, dtype=jnp.float32,
                               seed: int = 0, interpret=None) -> float:
     """`ragged_parity_check` for the QUANTIZED ragged path (mixed decode
-    + prefill-chunk rows over the int8 pool, the --kv-quantize
-    --mixed-step serving shape)."""
+    + prefill-chunk rows over the int8 pool, the --kv-quantize serving
+    shape). Shared by tests/test_kv_quant.py, diagnostics.py
+    --quant-parity and chip_smoke.py's kernel phase."""
     return _parity("quant_ragged", tuple(q_lens), n_heads=n_heads,
                    n_kv_heads=n_kv_heads, d_head=d_head,
                    block_size=block_size, n_blocks=n_blocks,

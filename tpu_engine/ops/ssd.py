@@ -16,7 +16,7 @@ with two dual computational forms:
   the paper names), and it is partition-invariant: processing a sequence
   in windows of any size through repeated steps produces bit-identical
   states, which is what makes the serving scheduler's budgeted prefill
-  chunks, crash-replay resumes, and two-path-vs-mixed stepping
+  chunks and crash-replay resumes
   byte-identical (runtime.scheduler, DESIGN.md "Recurrent state
   serving").
 - **Chunked matmul form** (`ssd_chunked`): the sequence splits into

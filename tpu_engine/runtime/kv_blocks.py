@@ -1362,23 +1362,15 @@ class StateRowPool:
                 "bytes_per_row": self.bytes_per_row()}
 
 
-# -- device-side block movement (jitted by the scheduler per bucket) ----------
-
-def gather_blocks(pool_k, pool_v, ids, *, kv_heads: int):
-    """(L, NB, bs, H*D) pools + (nb,) block ids -> one row-cache KVCache
-    (L, 1, nb*bs, H, D), H = ``kv_heads``: logical column j*bs+o reads
-    pool[ids[j], o]. Padding entries point at the null block; their
-    columns carry garbage the position mask must exclude."""
-    L, _, bs, _ = pool_k.shape
-    row = (L, 1, ids.shape[0] * bs, kv_heads, -1)
-    return KVCache(pool_k[:, ids].reshape(row), pool_v[:, ids].reshape(row))
-
+# -- device-side block movement -------------------------------------------------
+#
+# A whole row cache written into pool blocks. No served path calls these
+# since the ragged tick writes a row's tokens where they lie (PR 49); the
+# pool's tests fill blocks with known bytes through them.
 
 def scatter_blocks(caches, row_k, row_v, ids):
-    """Write a prefilled (L, 1, nb*bs, H, D) row cache into pool blocks
-    ``ids`` (the admission half of paging). Entries mapped to 0 dump
-    into the null block — the scheduler points radix-matched prefix
-    blocks there so shared blocks are never rewritten. Donate `caches`."""
+    """Write a (L, 1, nb*bs, H, D) row cache into pool blocks ``ids``.
+    Entries mapped to 0 dump into the null block. Donate `caches`."""
     L, _, bs, hd = caches.k.shape
     blocks = (L, ids.shape[0], bs, hd)
     rk = row_k.reshape(blocks).astype(caches.k.dtype)
@@ -1386,27 +1378,11 @@ def scatter_blocks(caches, row_k, row_v, ids):
     return KVCache(caches.k.at[:, ids].set(rk), caches.v.at[:, ids].set(rv))
 
 
-def gather_blocks_quant(pool_k, pool_v, k_scale, v_scale, ids, *, dtype):
-    """`gather_blocks` for the int8 pool: dequantize the gathered blocks
-    (payload * per-slot scale) into a `dtype` row-cache view the prefill
-    windows can consume. The pool bytes themselves are untouched — only
-    this row's dense view is full-precision."""
-    from tpu_engine.ops.quant import dequantize_kv
-
-    L, _, bs, h = k_scale.shape
-    row = (L, 1, ids.shape[0] * bs, h)
-    return KVCache(*(
-        dequantize_kv(pool[:, ids].reshape(row + (-1,)),
-                      scale[:, ids].reshape(row), dtype)
-        for pool, scale in ((pool_k, k_scale), (pool_v, v_scale))))
-
-
 def scatter_blocks_quant(caches, scales, row_k, row_v, ids):
-    """`scatter_blocks` for the int8 pool: quantize the prefilled row
-    cache ONCE — one symmetric int8 vector + f32 scale per (layer, slot,
-    kv-head) — and write payload and scales together. This is the single
-    place a two-path admission's prompt KV is ever quantized; every later
-    movement copies these bytes verbatim. Donate `caches` AND `scales`."""
+    """`scatter_blocks` for the int8 pool: quantize the row cache ONCE —
+    one symmetric int8 vector + f32 scale per (layer, slot, kv-head) —
+    and write payload and scales together. Donate `caches` AND
+    `scales`."""
     from tpu_engine.ops.quant import quantize_kv
 
     L, _, bs, h = scales.k.shape

@@ -8,30 +8,31 @@ starts, so one long request convoys everything behind it. This scheduler
 closes the gap: a FIXED-shape decode batch runs forever, and requests join
 and leave between chunks —
 
-- The batch is `n_slots` rows over one preallocated KV cache
-  (L, n_slots, max_seq, H, D). All shapes static: the decode chunk and the
-  per-bucket prefill/insert executables each compile exactly once.
-- **Admission** (two-path modes): a new request prefills alone on a
-  (1, prompt-bucket) executable — on the PREFILL THREAD, so admission
-  compute never stalls the decode loop's host side — then its KV slice
-  is written into a free row (`dynamic_update_slice` on the row axis)
-  with per-row `pos`/`start`.
-- **Decode** runs `transformer_decode_rows` — every row carries its own
-  cache position, so rows admitted at different times decode side by side.
-  Finished rows (EOS or budget) free their slot between chunks; idle rows
-  burn lanes of an already-launched batch, not wall-clock.
-- **Mixed stepping** (`mixed_step=True`, paged layout only) replaces the
-  two-path discipline: the prefill thread becomes pure batch formation
-  (bucket pick + radix lookup), and each tick issues ONE ragged dispatch
-  (`transformer_step_rows_ragged`) serving decode rows (1 token each)
-  and admitting rows' budgeted prefill chunks together — admission work
-  rides the decode dispatch instead of contending with it on the device
-  queue (PERF.md "Mixed stepping": 3.7× lower ITL p99 under
-  long-prompt interference, identical streams).
+How a lane is stepped follows from what it holds, and no caller chooses:
+
+- **The dense per-slot cache** (`kv_block_size` 0): `n_slots` rows over one
+  preallocated KV cache (L, n_slots, max_seq, H, D), stepped by TWO PATHS.
+  All shapes static: the decode chunk and the per-bucket prefill/insert
+  executables each compile exactly once. *Admission*: a new request
+  prefills alone on a (1, prompt-bucket) executable — on the PREFILL
+  THREAD, so admission compute never stalls the decode loop's host side —
+  then its KV slice is written into a free row (`dynamic_update_slice` on
+  the row axis) with per-row `pos`/`start`. *Decode* runs `step_chunk`
+  steps of `transformer_decode_rows` a dispatch — every row carries its
+  own cache position, so rows admitted at different times decode side by
+  side. Finished rows (EOS or budget) free their slot between chunks; idle
+  rows burn lanes of an already-launched batch, not wall-clock.
+- **A block pool or a state slab** (`kv_block_size` > 0, or the
+  state_slab family) steps by the RAGGED TICK: the prefill thread is pure
+  batch formation (bucket pick + radix lookup), and each tick issues ONE
+  ragged dispatch (`transformer_step_rows_ragged`, or the family's own
+  step) serving decode rows (1 token each) and admitting rows' budgeted
+  prefill chunks together — admission work rides the decode dispatch
+  instead of contending with it on the device queue.
 - Sampling is the generator's per-row fold_in(seed, position) scheme, so a
   seeded request emits identical tokens whether it was admitted into an
-  empty, full, or draining batch — and whichever stepping discipline or
-  cache layout served it (tested).
+  empty, full, or draining batch — and whichever stepping or cache layout
+  served it (tested).
 
 `submit()` returns a Future; a daemon thread runs the admit→decode→emit
 loop. `generate()` is a blocking convenience with the same signature as
@@ -41,7 +42,6 @@ Generator.generate.
 from __future__ import annotations
 
 import collections
-import functools
 import json
 import os
 import queue
@@ -58,11 +58,8 @@ import numpy as np
 from tpu_engine.models.registry import ModelSpec, create_model, _ensure_builtin_models_imported
 from tpu_engine.models.ssd import (
     SSDConfig,
-    SSDState,
     flatten_states,
-    ssd_init_states,
     ssd_state_dim,
-    ssd_step_rows_masked,
     ssd_window_scan,
     unflatten_states,
 )
@@ -70,7 +67,6 @@ from tpu_engine.models.transformer import (
     TransformerConfig,
     init_caches,
     transformer_decode_rows,
-    transformer_decode_rows_paged,
     transformer_decode_window,
     transformer_prefill,
     transformer_step_rows_ragged,
@@ -93,10 +89,6 @@ from tpu_engine.runtime.kv_blocks import (
     PoolExhausted,
     StateRowPool,
     StateSlabPool,
-    gather_blocks,
-    gather_blocks_quant,
-    scatter_blocks,
-    scatter_blocks_quant,
 )
 from tpu_engine.utils.deadline import Deadline, DeadlineExceeded
 from tpu_engine.utils.metrics import LatencyHistogram
@@ -467,20 +459,21 @@ class ContinuousGenerator:
         lookup stops at the resident prefix and the tail recomputes
         (counted ``swap_in_deferred``).
 
-        `mixed_step` (paged mode only) merges the prefill and decode
-        paths into a single token-budgeted mixed step: each tick forms
-        ONE ragged batch of (decode rows x 1 token) + (admitting rows x
-        a prefill chunk) and issues exactly one compiled dispatch
+        A lane with a pool (or the state_slab family's slab) steps by
+        the token-budgeted ragged tick: each tick forms ONE ragged
+        batch of (decode rows x 1 token) + (admitting rows x a prefill
+        chunk) and issues exactly one compiled dispatch
         (transformer_step_rows_ragged) — admission work rides the
         decode dispatch instead of queueing beside it, so a long prompt
-        can no longer head-of-line-block in-flight rows' tokens. The
-        prefill thread becomes pure batch formation (bucket pick +
-        radix lookup; no device work). `mixed_token_budget` caps new
-        tokens per tick (decode rows count 1 each; the remainder is
-        split over admitting rows' chunks, and also caps the compiled
-        chunk width) so per-tick latency stays bounded; 0 = auto
-        (prefill_chunk). Seeded streams are byte-identical to the dense
-        and two-path paged schedulers (tested).
+        cannot head-of-line-block in-flight rows' tokens. The prefill
+        thread is pure batch formation (bucket pick + radix lookup; no
+        device work). `mixed_token_budget` caps new tokens per tick
+        (decode rows count 1 each; the remainder is split over
+        admitting rows' chunks, and also caps the compiled chunk width)
+        so per-tick latency stays bounded; 0 = auto (prefill_chunk).
+        Seeded streams are byte-identical to the dense cache's
+        (tested). `mixed_step` is accepted and chooses nothing (see
+        where `self._mixed` is set).
 
         `tp` > 1 (paged kv_paged family only) serves the model
         TENSOR-PARALLEL over a 1-axis ``model`` mesh of that many
@@ -498,7 +491,7 @@ class ContinuousGenerator:
         mamba2 conv tail/slab) refuse loudly; `device` is mutually
         exclusive with `tp`.
 
-        `spec_k` > 0 (paged layouts only — two-path AND mixed) turns on
+        `spec_k` > 0 (a block pool only) turns on
         CONTINUOUS SPECULATIVE DECODING: each tick a host-side drafter
         proposes up to spec_k tokens per decode row (`spec_draft`
         "ngram" = the deterministic prompt-lookup drafter, no second
@@ -732,9 +725,9 @@ class ContinuousGenerator:
                 f"{getattr(model, 'state_family', 'kv_paged')} family")
         if fam in self._TICK_ONLY_WHY:
             self._fence_tick_only_family(
-                model, fam, mixed_step=mixed_step,
-                kv_host_blocks=kv_host_blocks, kv_quantize=kv_quantize,
-                spec_k=spec_k, prefix_sharing=prefix_sharing)
+                model, fam, kv_host_blocks=kv_host_blocks,
+                kv_quantize=kv_quantize, spec_k=spec_k,
+                prefix_sharing=prefix_sharing)
         if int(kv_host_blocks) > 0 and not self._paged:
             raise ValueError("kv_host_blocks requires the paged KV cache "
                              "(set kv_block_size > 0)")
@@ -803,8 +796,6 @@ class ContinuousGenerator:
             self._prefix_sharing = bool(prefix_sharing)
             # Admissions deferred on pool pressure, retried as rows free.
             self._pending: "collections.deque" = collections.deque()
-            self._gather_exe = {}   # {n_blocks: compiled prefix gather}
-            self._scatter_exe = {}  # {n_blocks: compiled block scatter}
             if self._hybrid:
                 # The recurrent layers' rows, `n_slots + 1` as the slab
                 # family's are: slot s owns row s + 1, row 0 is the null
@@ -918,11 +909,17 @@ class ContinuousGenerator:
         # granularity instead of stalling behind a long prompt (0 = off).
         self._prefill_chunk = int(prefill_chunk)
         self._window_exe = None
-        # Mixed stepping (paged only): ONE ragged dispatch per tick.
-        self._mixed = bool(mixed_step)
-        if self._mixed and not (self._paged or self._slab):
+        # How a lane is stepped follows from what it holds: a block pool
+        # or a state slab steps by the ragged tick (ONE dispatch per
+        # tick), the dense per-slot cache by the two-path chunk loop. The
+        # `mixed_step` argument chooses nothing: benchmarks/ and
+        # tests/benchmarks/ still pass it (ROADMAP: the benchmark item
+        # that drops it), so it stays accepted, and only `True` on a lane
+        # that has nothing to step raggedly is refused.
+        if mixed_step and not (self._paged or self._slab):
             raise ValueError("mixed_step requires the paged KV cache "
                              "(set kv_block_size > 0)")
+        self._mixed = self._paged or self._slab
         # Continuous speculative decoding (paged layouts only): drafts
         # verified inside the per-tick ragged dispatch.
         self._spec_k = int(spec_k)
@@ -957,18 +954,10 @@ class ContinuousGenerator:
                 "row_ticks": 0,
                 "draft_dispatches": 0, "tail_blocks_released": 0,
             }
-        # Decode rows advance one token per tick in mixed mode (spec off)
-        # and up to spec_k+1 in spec mode, so block growth and admission
-        # headroom reserve exactly that horizon, not a step_chunk-sized
-        # one.
-        if self._spec:
-            self._decode_horizon = self._spec_k + 1
-        else:
-            self._decode_horizon = 1 if self._mixed else self._step_chunk
-        # The drafter needs each row's token history (prompt + emitted);
-        # mixed mode already keeps the prompt for its in-tick prefill.
-        if self._spec and not self._mixed:
-            self._row_prompt_toks = [None] * self.n_slots
+        # A pool's decode rows advance one token per tick (spec off) and
+        # up to spec_k+1 in spec mode, so block growth and admission
+        # headroom reserve exactly that horizon.
+        self._decode_horizon = self._spec_k + 1
         if self._mixed:
             budget = int(mixed_token_budget) or (self._prefill_chunk
                                                  if self._prefill_chunk > 0
@@ -1322,8 +1311,8 @@ class ContinuousGenerator:
                          "state row beside the block chain"),
     }
 
-    def _fence_tick_only_family(self, model, fam, *, mixed_step,
-                                kv_host_blocks, kv_quantize, spec_k,
+    def _fence_tick_only_family(self, model, fam, *, kv_host_blocks,
+                                kv_quantize, spec_k,
                                 prefix_sharing) -> None:
         """Start-up fences of the kv_latent, kv_windowed and kv_and_state
         families (registry FAMILY_CAPABILITIES): what the family's pool or
@@ -1332,12 +1321,11 @@ class ContinuousGenerator:
         rule.)"""
         name = f"model '{model.name}' ({fam} family)"
         why, read = self._TICK_ONLY_WHY[fam]
-        if not (self._paged and mixed_step):
+        if not self._paged:
             raise ValueError(
                 f"{name} is served by the mixed tick over the block "
-                f"pool only: set kv_block_size > 0 and mixed_step (the "
-                f"two-path prefill and the dense per-slot cache have no "
-                f"{read})")
+                f"pool only: set kv_block_size > 0 (the dense per-slot "
+                f"cache has no {read})")
         for flag, value, cap in (
                 ("kv_quantize", kv_quantize, "kv_quantize"),
                 ("kv_host_blocks", int(kv_host_blocks), "kv_host_tier"),
@@ -1380,209 +1368,19 @@ class ContinuousGenerator:
                          wsc(scales.v, self._kv_pin))
         return caches, scales
 
-    def _paged_attn_fn(self, ragged: bool):
-        """The paged attention read path this lane's step executables
-        trace: decode or ragged, the int8 or the full-precision variant
-        by the pool, and under tp > 1 run once per head shard — a Mosaic
-        kernel cannot be partitioned by GSPMD, and heads are independent
-        (no collective inside)."""
+    def _paged_attn_fn(self):
+        """The ragged paged attention read this lane's step executables
+        trace: the int8 or the full-precision variant by the pool, and
+        under tp > 1 run once per head shard — a Mosaic kernel cannot be
+        partitioned by GSPMD, and heads are independent (no collective
+        inside)."""
         from tpu_engine.ops import paged_attention as pa
 
-        if ragged:
-            fn = (pa.default_quant_ragged_attention() if self._quant
-                  else pa.default_ragged_attention())
-        else:
-            fn = (pa.default_quant_paged_attention() if self._quant
-                  else pa.default_paged_attention())
+        fn = (pa.default_quant_ragged_attention() if self._quant
+              else pa.default_ragged_attention())
         if self._tp_mesh is not None:
             fn = pa.shard_over_heads(fn, self._tp_mesh)
         return fn
-
-    def _gather(self, nb: int):
-        """Prefix gather for one bucket width: (pool, nb block ids) ->
-        the row's (L, 1, nb*bs, H, D) cache view. Read-only on the pool
-        — dispatched by the prefill thread under the pool lock so it
-        orders before the decode thread's donating chunk. Quantized
-        pools dequantize the gathered view (int8 * scale) into the
-        compute dtype; the pool bytes themselves are untouched."""
-        exe = self._gather_exe.get(nb)
-        if exe is None:
-            with self._exe_lock:
-                if self._quant:
-                    fn = functools.partial(gather_blocks_quant,
-                                           dtype=self._dtype)
-                else:
-                    fn = functools.partial(gather_blocks,
-                                           kv_heads=self.cfg.kv_heads)
-                if self._kv_pin is not None:
-                    # TP: the gathered (L, 1, S, H_kv, D) row cache
-                    # keeps the pool's head sharding, so the prefill
-                    # windows that consume it compile SPMD over the
-                    # same mesh.
-                    from jax.sharding import NamedSharding, PartitionSpec
-
-                    base = fn
-                    row_pin = NamedSharding(
-                        self._tp_mesh,
-                        PartitionSpec(None, None, None,
-                                      self._kv_pin.spec[3], None))
-
-                    def fn(*args, _base=base):
-                        return jax.tree.map(
-                            lambda x: jax.lax.with_sharding_constraint(
-                                x, row_pin), _base(*args))
-                exe = self._gather_exe.setdefault(nb, jax.jit(fn))
-        return exe
-
-    def _scatter(self, nb: int):
-        """Admission scatter for one bucket width: write a prefilled row
-        cache into its allocated pool blocks (null-block entries absorb
-        radix-matched positions). Donates the pool — decode-thread only,
-        under the pool lock. Quantized pools quantize HERE, exactly once
-        per written slot, and donate the scale arrays alongside."""
-        exe = self._scatter_exe.get(nb)
-        if exe is None:
-            with self._exe_lock:
-                if self._quant:
-                    fn = scatter_blocks_quant
-                    if self._kv_pin is not None:
-                        def fn(caches, scales, row_k, row_v, ids):
-                            out_c, out_s = scatter_blocks_quant(
-                                caches, scales, row_k, row_v, ids)
-                            return self._pin_pool_out(out_c, out_s)
-                    exe = self._scatter_exe.setdefault(
-                        nb, jax.jit(fn, donate_argnums=(0, 1)))
-                else:
-                    fn = scatter_blocks
-                    if self._kv_pin is not None:
-                        def fn(caches, row_k, row_v, ids):
-                            return self._pin_pool_out(scatter_blocks(
-                                caches, row_k, row_v, ids))
-                    exe = self._scatter_exe.setdefault(
-                        nb, jax.jit(fn, donate_argnums=(0,)))
-        return exe
-
-    def _decode_paged(self, controls: bool):
-        """Compiled decode chunk over the block pool — `_decode` with the
-        per-row cache stripe swapped for (pool, block tables). Paged rows
-        are 0-aligned (no start vector): pos IS the logical position, so
-        the sampling fold positions and rotary phases match the dense
-        path token for token (seeded streams are identical — tested)."""
-        exe = self._decode_exe.get(("paged", controls))
-        if exe is not None:
-            return exe
-        with self._exe_lock:
-            if ("paged", controls) not in self._decode_exe:
-                cfg, dtype, chunk = self.cfg, self._dtype, self._step_chunk
-                quant = self._quant
-                attn_fn = self._paged_attn_fn(ragged=False)
-                max_col = self.max_seq - 1
-
-                def chunk_scan(params, caches, scales, tables, tok, pos,
-                               done, seeds, temps, topps, topks, minps,
-                               eos_vec, counts, pens, stops):
-                    rows = jnp.arange(tok.shape[0])
-
-                    def body(carry, _):
-                        scales = counts = None
-                        if quant and controls:
-                            caches, scales, tok, pos, done, counts = carry
-                        elif quant:
-                            caches, scales, tok, pos, done = carry
-                        elif controls:
-                            caches, tok, pos, done, counts = carry
-                        else:
-                            caches, tok, pos, done = carry
-                        if quant:
-                            logits, caches, scales = \
-                                transformer_decode_rows_paged(
-                                    params, tok, caches, tables, pos, cfg,
-                                    dtype=dtype, attn_fn=attn_fn,
-                                    scales=scales)
-                        else:
-                            logits, caches = transformer_decode_rows_paged(
-                                params, tok, caches, tables, pos, cfg,
-                                dtype=dtype, attn_fn=attn_fn)
-                        if controls:
-                            logits = apply_repetition_penalty(
-                                logits, counts, pens)
-                        nxt = _sample(logits, seeds, pos + 1, temps,
-                                      topps, topks, minps, kept=~done)
-                        nxt = jnp.where(done, eos_vec, nxt)
-                        if controls:
-                            counts = counts.at[rows, nxt].add(
-                                (~done).astype(jnp.int32))
-                        done = done | (nxt == eos_vec)
-                        if controls:
-                            done = done | jnp.any(nxt[:, None] == stops,
-                                                  axis=1)
-                        pos = jnp.where(done, pos,
-                                        jnp.minimum(pos + 1, max_col))
-                        state = (caches,) + ((scales,) if quant else ())
-                        state += (nxt, pos, done)
-                        if controls:
-                            state += (counts,)
-                        return state, nxt
-
-                    state = (caches,) + ((scales,) if quant else ())
-                    state += (tok, pos, done)
-                    if controls:
-                        state += (counts,)
-                    state, toks = jax.lax.scan(body, state, None,
-                                               length=chunk)
-                    # TP: pin the donated pool (and scales) outputs to
-                    # the pool sharding (no-op when tp == 1).
-                    if quant:
-                        pc, ps = self._pin_pool_out(state[0], state[1])
-                        state = (pc, ps) + state[2:]
-                    else:
-                        state = (self._pin_pool_out(state[0]),) \
-                            + state[1:]
-                    return state + (toks.T,)
-
-                # Donation-friendly positional signatures: the quantized
-                # variant threads (and donates) the scale arrays right
-                # after the payload pool; counts donates when controls.
-                if quant and controls:
-                    def decode_chunk(params, caches, scales, tables, tok,
-                                     pos, done, seeds, temps, topps,
-                                     topks, minps, eos_vec, counts, pens,
-                                     stops):
-                        return chunk_scan(params, caches, scales, tables,
-                                          tok, pos, done, seeds, temps,
-                                          topps, topks, minps, eos_vec,
-                                          counts, pens, stops)
-                    donate = (1, 2, 13)
-                elif quant:
-                    def decode_chunk(params, caches, scales, tables, tok,
-                                     pos, done, seeds, temps, topps,
-                                     topks, minps, eos_vec):
-                        return chunk_scan(params, caches, scales, tables,
-                                          tok, pos, done, seeds, temps,
-                                          topps, topks, minps, eos_vec,
-                                          None, None, None)
-                    donate = (1, 2)
-                elif controls:
-                    def decode_chunk(params, caches, tables, tok, pos,
-                                     done, seeds, temps, topps, topks,
-                                     minps, eos_vec, counts, pens, stops):
-                        return chunk_scan(params, caches, None, tables,
-                                          tok, pos, done, seeds, temps,
-                                          topps, topks, minps, eos_vec,
-                                          counts, pens, stops)
-                    donate = (1, 12)
-                else:
-                    def decode_chunk(params, caches, tables, tok, pos,
-                                     done, seeds, temps, topps, topks,
-                                     minps, eos_vec):
-                        return chunk_scan(params, caches, None, tables,
-                                          tok, pos, done, seeds, temps,
-                                          topps, topks, minps, eos_vec,
-                                          None, None, None)
-                    donate = (1,)
-                self._decode_exe[("paged", controls)] = jax.jit(
-                    decode_chunk, donate_argnums=donate)
-            return self._decode_exe[("paged", controls)]
 
     def _tick_block(self, width: int, controls: bool) -> TickBlock:
         """This lane's control block layout at a tick's `width`, with or
@@ -1627,7 +1425,7 @@ class ContinuousGenerator:
                     # plus a token a row: the step's static size.
                     max_tokens = self._mixed_budget + self.n_slots
                 else:
-                    attn_fn = self._paged_attn_fn(ragged=True)
+                    attn_fn = self._paged_attn_fn()
 
                 layout = self._tick_block(width, controls)
                 hybrid = self._hybrid
@@ -1762,7 +1560,7 @@ class ContinuousGenerator:
 
                 cfg, dtype = self.cfg, self._dtype
                 quant = self._quant
-                attn_fn = self._paged_attn_fn(ragged=True)
+                attn_fn = self._paged_attn_fn()
                 S = self._spec_k + 1
 
                 def spec_core(params, caches, scales, tables, tokens,
@@ -1892,57 +1690,16 @@ class ContinuousGenerator:
     # -- state-slab compiled stages (the state_slab family's step fns) ---------
     #
     # The SSD family's autoregressive step is models.ssd.ssd_step_rows —
-    # an O(1) recurrence per row instead of a KV-cache read. Every stage
-    # below threads (and donates) the slab pool exactly like the paged
-    # stages thread the block pool, and the decode/mixed bodies reuse
+    # an O(1) recurrence per row instead of a KV-cache read. The stages
+    # below thread (and donate) the slab pool exactly like the paged
+    # stages thread the block pool, and the mixed body reuses
     # the SAME sampling/penalty/stop logic (fold_in(seed, position)), so
     # streams are family-portable in every property the scheduler
     # promises: seeded determinism, deadline cancel, crash replay,
     # migration splice, brownout.
 
-    def _slab_prefill_window(self, width: int):
-        """One prompt window on the PREFILL thread (batch 1): consume up
-        to `width` tokens from the request's carried state via the
-        masked recurrence scan. Partition-invariant: any window split
-        yields the same per-token steps, which is what makes two-path,
-        mixed, and replay-resume prompt states agree."""
-        key = ("slab_window", width)
-        exe = self._decode_exe.get(key)
-        if exe is not None:
-            return exe
-        with self._exe_lock:
-            if key not in self._decode_exe:
-                cfg = self.cfg
-
-                def window(params, tokens, conv, ssm, n_valid):
-                    logits, states = ssd_window_scan(
-                        params, tokens, SSDState(conv, ssm),
-                        n_valid, n_valid - 1, cfg)
-                    return logits[0], states.conv, states.ssm
-
-                self._decode_exe[key] = jax.jit(window,
-                                                donate_argnums=(2, 3))
-            return self._decode_exe[key]
-
-    def _slab_write(self):
-        """Admission write: one row's prompt state (computed on the
-        prefill thread) lands in its allocated slab row. Donates the
-        slab — decode-thread only, under the pool lock."""
-        key = ("slab_write",)
-        exe = self._decode_exe.get(key)
-        if exe is not None:
-            return exe
-        with self._exe_lock:
-            if key not in self._decode_exe:
-                def write(slab, conv, ssm, rid):
-                    flat = flatten_states(SSDState(conv, ssm))[:, 0]
-                    return slab.at[:, rid].set(flat)
-
-                self._decode_exe[key] = jax.jit(write, donate_argnums=(0,))
-            return self._decode_exe[key]
-
     def _slab_zero(self):
-        """Zero a freshly-allocated slab row (mixed-mode admission: the
+        """Zero a freshly-allocated slab row (admission: the
         prompt's state accumulates IN the slab across ticks, so the row
         must not inherit a previous occupant's bytes)."""
         key = ("slab_zero",)
@@ -1955,74 +1712,6 @@ class ContinuousGenerator:
                     return slab.at[:, rid].set(0.0)
 
                 self._decode_exe[key] = jax.jit(zero, donate_argnums=(0,))
-            return self._decode_exe[key]
-
-    def _slab_decode(self, controls: bool):
-        """Compiled decode chunk over the slab pool — `_decode_paged`
-        with (pool, block tables) swapped for (slab, row ids) and the
-        attention read swapped for the O(1) recurrence. Rows are
-        0-aligned like paged rows (pos IS the logical position), so the
-        sampling folds match the other families token for token. Done
-        (and parked-handoff) rows ride the batch with their state
-        FROZEN — the slab family's equivalent of the paged path's
-        frozen-column writes."""
-        key = ("slab", controls)
-        exe = self._decode_exe.get(key)
-        if exe is not None:
-            return exe
-        with self._exe_lock:
-            if key not in self._decode_exe:
-                cfg, chunk = self.cfg, self._step_chunk
-                max_col = self.max_seq - 1
-
-                def decode_chunk(params, slab, row_ids, tok, pos, done,
-                                 seeds, temps, topps, topks, minps,
-                                 eos_vec, counts=None, pens=None,
-                                 stops=None):
-                    rows = jnp.arange(tok.shape[0])
-                    states = unflatten_states(slab[:, row_ids], cfg)
-
-                    def body(carry, _):
-                        if controls:
-                            states, tok, pos, done, counts = carry
-                        else:
-                            states, tok, pos, done = carry
-                            counts = None
-                        # The ONE shared masked-step primitive: done
-                        # rows ride the batch with state frozen.
-                        logits, states = ssd_step_rows_masked(
-                            params, tok, states, ~done, cfg)
-                        if controls:
-                            logits = apply_repetition_penalty(
-                                logits, counts, pens)
-                        nxt = _sample(logits, seeds, pos + 1, temps,
-                                      topps, topks, minps, kept=~done)
-                        nxt = jnp.where(done, eos_vec, nxt)
-                        if controls:
-                            counts = counts.at[rows, nxt].add(
-                                (~done).astype(jnp.int32))
-                        done = done | (nxt == eos_vec)
-                        if controls:
-                            done = done | jnp.any(nxt[:, None] == stops,
-                                                  axis=1)
-                        pos = jnp.where(done, pos,
-                                        jnp.minimum(pos + 1, max_col))
-                        if controls:
-                            return (states, nxt, pos, done, counts), nxt
-                        return (states, nxt, pos, done), nxt
-
-                    state = (states, tok, pos, done)
-                    if controls:
-                        state += (counts,)
-                    state, toks = jax.lax.scan(body, state, None,
-                                               length=chunk)
-                    states = state[0]
-                    slab = slab.at[:, row_ids].set(flatten_states(states))
-                    return (slab,) + state[1:] + (toks.T,)
-
-                self._decode_exe[key] = jax.jit(
-                    decode_chunk,
-                    donate_argnums=(1, 12) if controls else (1,))
             return self._decode_exe[key]
 
     def _slab_mixed_exe(self, width: int, controls: bool):
@@ -2052,9 +1741,8 @@ class ContinuousGenerator:
                                stops=None):
                     rows = jnp.arange(tokens.shape[0])
                     states = unflatten_states(slab[:, row_ids], cfg)
-                    # The ONE shared window primitive (the same scan the
-                    # two-path prefill windows run): a frozen row is
-                    # simply a row with zero valid steps.
+                    # The ONE window primitive: a frozen row is simply a
+                    # row with zero valid steps.
                     kept, states = ssd_window_scan(
                         params, tokens, states,
                         jnp.where(step_ok, qlen, 0), sample_slot, cfg)
@@ -2560,7 +2248,7 @@ class ContinuousGenerator:
                 return None  # not admitted yet (queued or prefilling)
             return {"ok": False, "reason": "no live row with this tag"}
         req = self._row_req[row]
-        if self._mixed and self._prefilling[row]:
+        if self._prefilling[row]:
             if waiting:
                 return None  # prefill chunks still running
             # Nothing emitted yet — a replay resume re-prefills exactly
@@ -2906,9 +2594,8 @@ class ContinuousGenerator:
                "ready": self._ready.qsize()}
         for k, v in cur.items():
             rec[k] = v - prev.get(k, 0)
-        if self._paged or self._slab:
-            rec["parked"] = len(self._pending)
         if self._mixed:
+            rec["parked"] = len(self._pending)
             rec["prefilling"] = int(sum(1 for p in self._prefilling if p))
         if self._paged:
             ps = self._pool.stats()
@@ -3068,11 +2755,11 @@ class ContinuousGenerator:
         self._fail_request(req, DeadlineExceeded(message))
 
     def _count_admission_dispatch(self, n: int = 1) -> None:
-        """Device dispatches issued by the ADMISSION side of the two-path
-        scheduler (prefill forwards/windows, prefix gathers, row
-        scatters) — the dispatches mixed stepping folds into the decode
-        tick. chunks + admission_dispatches is the two-path lane's
-        dispatch count, beside the mixed lane's `dispatches`. Lock: the
+        """Device dispatches issued by the ADMISSION side: the dense
+        cache's prefill forwards/windows and row inserts, and an import's
+        chain write — the dispatches the ragged tick folds into the
+        decode tick. chunks + admission_dispatches is the dense lane's
+        dispatch count, beside a pool lane's `dispatches`. Lock: the
         prefill and decode threads both increment."""
         with self._stats_lock:
             self._stats["admission_dispatches"] = (
@@ -3324,121 +3011,15 @@ class ContinuousGenerator:
                            peer=str(hint.get("lane") or ""))
         return matched
 
-    def _run_prefill_paged(self, req: _Request):
-        """Paged admission prefill: 0-aligned (RIGHT-padded) row cache,
-        radix longest-prefix match, prefill resumed mid-prompt past the
-        matched blocks. Runs on the prefill thread; the only shared-state
-        touches are the radix lookup and the prefix gather, both under
-        the pool lock (the lock also orders the gather's dispatch before
-        any decode chunk's pool donation)."""
-        pool = self._pool
-        bs = pool.block_size
-        pb = next((b for b in self._prompt_buckets if b >= len(req.prompt)),
-                  self._prompt_buckets[-1])
-        prompt = req.prompt[-pb:]
-        L = len(prompt)
-        Leff = max(L, 1)  # empty prompts sample from the zero-token column
-        tokens = right_pad_prompt(prompt, pb)
-
-        matched: List[int] = []
-        swapped = 0
-        t0 = time.perf_counter()
-        with pool.lock:
-            gen = pool.generation
-            if self._prefix_sharing:
-                si0 = pool.swap_ins
-                matched = pool.radix.lookup(          # pins for this row
-                    prompt, promote_reserve=self._swap_reserve())
-                swapped = pool.swap_ins - si0
-        m_tok = len(matched) * bs
-        self._record_swap_in(req, swapped, t0)
-        if self.prefix_fetch is not None and req.prefix_hint is not None:
-            # Fleet prefix tier: a gateway hint on a (partial) miss
-            # pulls the peer's deeper chain BEFORE the gather — spliced
-            # blocks ride the row cache like local radix hits. m_tok
-            # keeps the LOCAL match for the radix_lookup span; the
-            # prefix_fetch span accounts for the splice.
-            matched = self._fetch_prefix_splice(req, prompt, matched,
-                                                pool, gen, pb)
-        m_tok_all = len(matched) * bs
-        try:
-            if matched:
-                # The gather IS the row cache init on a hit: matched
-                # columns carry the shared prefix, the rest null-block
-                # garbage the windows overwrite / the position mask hides.
-                ids = np.zeros((pb // bs,), np.int32)
-                ids[:len(matched)] = matched
-                with pool.lock:  # dispatch-order fence vs pool donation
-                    if self._quant:
-                        # Dequantized view of the shared prefix for the
-                        # resumed prefill windows; the pool bytes stay
-                        # int8 — no requantization ever happens.
-                        row_caches = self._gather(pb // bs)(
-                            pool.caches.k, pool.caches.v,
-                            pool.scales.k, pool.scales.v,
-                            jnp.asarray(ids))
-                    else:
-                        row_caches = self._gather(pb // bs)(
-                            pool.caches.k, pool.caches.v, jnp.asarray(ids))
-                self._count_admission_dispatch()
-            else:
-                row_caches = init_caches(self.cfg, 1, pb, self._dtype)
-                if self._device is not None:
-                    row_caches = jax.device_put(row_caches, self._device)
-            if req.sink is not None:
-                dur_us = (time.perf_counter() - t0) * 1e6
-                req.sink.stage("radix_lookup", dur_us,
-                               start_ts=time.time() - dur_us / 1e6,
-                               matched_tokens=m_tok)
-            # Resume prefill at the BLOCK boundary at/below the match —
-            # the matched tokens' compute is skipped entirely (the whole
-            # point of sharing), and window starts stay block-aligned so
-            # the compiled-width set is bounded (multiples of block_size
-            # up to the prefill chunk, materialized lazily). Always runs
-            # at least the window holding position L-1, whose logits seed
-            # the first sample — an exact whole-prompt match recomputes
-            # that one block so sampling params stay OUT of the radix
-            # key (logits are never cached, seeds stay per-request).
-            w = self._prefill_chunk
-            if not 0 < w < pb:
-                w = pb
-            win_exe = self._window()
-            p0 = (min(m_tok_all, Leff - 1) // bs) * bs
-            logits = None
-            w0 = p0
-            while w0 <= Leff - 1:
-                width = min(w, pb - w0)
-                head = "all" if w0 <= Leff - 1 < w0 + width else "none"
-                wlog, row_caches = win_exe(
-                    self._step_params, jnp.asarray(tokens[:, w0:w0 + width]),
-                    row_caches, jnp.asarray([w0], jnp.int32),
-                    jnp.asarray([0], jnp.int32), head)
-                self._count_admission_dispatch()
-                if head == "all":
-                    logits = wlog[0, Leff - 1 - w0]
-                w0 += width
-            with pool.lock:
-                pool.prefix_hit_tokens += p0
-                pool.prefilled_tokens += Leff - p0
-            first_tok, row_counts = self._first_token(req, logits, prompt, L)
-        except BaseException:
-            if matched:
-                with pool.lock:
-                    if pool.generation == gen:  # void after a pool reset
-                        pool.release_many(matched)
-            raise
-        return (req, row_caches, first_tok, pb, L, row_counts, matched,
-                prompt, gen)
-
     def _run_prefill_mixed(self, req: _Request):
         """Mixed-mode batch formation (the prefill thread's whole job
         here): pick the bucket, take the radix pins, precompute the
         penalty counts — NO device work. The prompt's forward pass runs
         inside the decode thread's ragged ticks instead. Returns the
-        same 9-tuple shape as `_run_prefill_paged` (row_caches and
-        first_tok slots None — both materialize in-dispatch), so every
-        downstream path (deadline drop, pool-pressure parking, shutdown
-        drain, `_discard_item`) works unchanged."""
+        9-tuple every pool item is (row_caches and first_tok slots None
+        — both materialize in-dispatch), so every downstream path
+        (deadline drop, pool-pressure parking, shutdown drain,
+        `_discard_item`) handles one shape."""
         pool = self._pool
         pb = next((b for b in self._prompt_buckets if b >= len(req.prompt)),
                   self._prompt_buckets[-1])
@@ -3534,44 +3115,6 @@ class ContinuousGenerator:
         return (req, None, None, n_chain * bs, len(prompt), row_counts,
                 matched, prompt, gen)
 
-    def _run_prefill_slab(self, req: _Request):
-        """state_slab admission prefill (prefill thread): consume the
-        prompt through the O(1) recurrence in fixed-width masked
-        windows, carrying the state between window dispatches — the
-        budgeted prefill chunks of the two-path discipline, with decode
-        chunks interleaving between windows exactly like the
-        transformer families. Touches NO shared state (a fresh stream's
-        state starts from zeros — nothing to read from the slab pool),
-        so there is no radix lookup, no gather, no pool lock on this
-        thread: recurrent prefixes are not block-addressable."""
-        spool = self._spool
-        prompt = list(req.prompt)
-        L = len(prompt)
-        Leff = max(L, 1)  # empty prompts consume one pad-token step
-        with spool.lock:
-            gen = spool.generation
-        W = self._prefill_chunk if self._prefill_chunk > 0 else 64
-        W = max(1, min(W, self.max_seq))
-        win_exe = self._slab_prefill_window(W)
-        states = ssd_init_states(self.cfg, 1)
-        conv, ssm = states.conv, states.ssm
-        logits = None
-        for w0 in range(0, Leff, W):
-            n_valid = min(W, Leff - w0)
-            # A buffer of its own per window: on the CPU backend
-            # jnp.asarray aliases an aligned numpy array, and the
-            # previous window's dispatch may not have read it yet.
-            tokens = np.zeros((1, W), np.int32)
-            if L:
-                tokens[0, :n_valid] = prompt[w0:w0 + n_valid]
-            logits, conv, ssm = win_exe(
-                self._step_params, jnp.asarray(tokens), conv, ssm,
-                jnp.asarray([n_valid], jnp.int32))
-            self._count_admission_dispatch()
-        first_tok, row_counts = self._first_token(req, logits, prompt, L)
-        return (req, SSDState(conv, ssm), first_tok, L, L, row_counts,
-                [], prompt, gen)
-
     def _run_prefill_mixed_slab(self, req: _Request):
         """Mixed-mode batch formation for the state_slab family: NO
         device work and no lookups at all (no radix to walk) — the
@@ -3632,15 +3175,11 @@ class ContinuousGenerator:
         if self._slab:
             if req.migrate is not None:
                 return self._run_prefill_import_slab(req)
-            if self._mixed:
-                return self._run_prefill_mixed_slab(req)
-            return self._run_prefill_slab(req)
+            return self._run_prefill_mixed_slab(req)
         if self._paged:
             if req.migrate is not None:
                 return self._run_prefill_import(req)
-            if self._mixed:
-                return self._run_prefill_mixed(req)
-            return self._run_prefill_paged(req)
+            return self._run_prefill_mixed(req)
         pb = next((b for b in self._prompt_buckets if b >= len(req.prompt)),
                   self._prompt_buckets[-1])
         prompt = req.prompt[-pb:]
@@ -3710,89 +3249,14 @@ class ContinuousGenerator:
         first_tok, row_counts = self._first_token(req, logits, prompt, L)
         return req, row_caches, first_tok, pb, L, row_counts
 
-    def _admit_paged(self, item, row: int) -> None:
-        """Decode-thread half of paged admission: allocate the bucket's
-        fresh blocks (radix-matched prefix blocks are already pinned and
-        simply enter the table), scatter the prefilled row cache into
-        them, and index the prompt's full blocks in the radix tree.
-        Raises PoolExhausted (nothing consumed) when even eviction can't
-        cover the allocation — the caller defers the admission."""
-        (req, row_caches, first_tok, pb, L, row_counts, matched, prompt,
-         gen) = item
-        pool = self._pool
-        bs = pool.block_size
-        nb_bucket = pb // bs
-        m = len(matched)
-        t0 = time.perf_counter()
-        req.t_admit = t0
-        first_col = min(L, self.max_seq - 1)  # first decode write column
-        with pool.lock:
-            if gen != pool.generation:
-                # The pool was rebuilt (device recovery) while this item
-                # sat prefilled: its gathered KV and pins are void.
-                raise _StaleAdmission(
-                    "kv pool was rebuilt during this request's admission")
-            # Cover the bucket AND the first decode chunk's columns so
-            # the chunk never writes through an unallocated table entry.
-            cols = min(first_col + self._decode_horizon + 1, self.max_seq)
-            need = max(nb_bucket, (cols - 1) // bs + 1)
-            fresh = pool.alloc(need - m)  # PoolExhausted -> defer
-            ids = np.zeros((nb_bucket,), np.int32)
-            ids[m:] = fresh[:nb_bucket - m]  # matched slots -> null block
-            table = list(matched) + fresh
-            # Tail block the row will append into must be private — full
-            # shared blocks make this structurally true; COW is the
-            # mechanical backstop (kv_blocks.ensure_writable). A deferral
-            # raised past this point must hand the fresh blocks back, or
-            # every retry would leak an allocation.
-            try:
-                wid, copied = pool.ensure_writable(table[first_col // bs])
-            except PoolExhausted:
-                pool.release_many(fresh)
-                raise
-            if copied:
-                table[first_col // bs] = wid
-            if self._quant:
-                # The ONE place this row's prompt KV quantizes (fresh
-                # blocks only — matched slots scatter into the null
-                # block, so shared int8 bytes are never rewritten).
-                pool.caches, pool.scales = self._scatter(nb_bucket)(
-                    pool.caches, pool.scales, row_caches.k, row_caches.v,
-                    jnp.asarray(ids))
-            else:
-                pool.caches = self._scatter(nb_bucket)(
-                    pool.caches, row_caches.k, row_caches.v,
-                    jnp.asarray(ids))
-            if self._prefix_sharing:
-                pool.radix.insert(prompt, table)
-        self._count_admission_dispatch()
-        self._tables[row, :] = 0
-        self._tables[row, :len(table)] = table
-        self._row_blocks[row] = table
-        if req.sink is not None:
-            dur_us = (time.perf_counter() - t0) * 1e6
-            req.sink.stage("kv_alloc", dur_us,
-                           start_ts=time.time() - dur_us / 1e6,
-                           blocks=len(table), shared_blocks=m)
-        if row_counts is not None:
-            # Counts splice is an eager scatter here (the KV went through
-            # the pool scatter above; no fused insert executable needed).
-            self._counts = self._ensure_counts().at[row].set(
-                jnp.asarray(row_counts[0]))
-        if self._spec:
-            # The drafter's lookup corpus: prompt + emitted-so-far.
-            self._row_prompt_toks[row] = prompt
-        self._init_row(req, row, first_tok, pos=first_col, start=0)
-        self._maybe_hold(row, req)
-
     def _admit_mixed(self, item, row: int) -> None:
         """Mixed-mode admission (decode thread): allocate the bucket's
         blocks up front (radix-matched prefix blocks enter the table
         pinned), make the two write targets private, and mark the row
         PREFILLING — the prompt forward runs chunk-by-chunk inside the
         subsequent ragged ticks, writing KV straight into these blocks.
-        Raises PoolExhausted (nothing consumed) to defer under pool
-        pressure, exactly like `_admit_paged`."""
+        Raises PoolExhausted (nothing consumed) when even eviction can't
+        cover the allocation — the caller defers the admission."""
         (req, _rc, _ft, pb, L, row_counts, matched, prompt, gen) = item
         pool = self._pool
         bs = pool.block_size
@@ -3804,7 +3268,7 @@ class ContinuousGenerator:
         # Resume at the block boundary at/below the radix match; the last
         # prompt block always recomputes so logits for the first sample
         # come from this row's own forward (sampling params stay OUT of
-        # the radix key, same rule as the two-path scheduler).
+        # the radix key).
         p0 = (min(m * bs, Leff - 1) // bs) * bs
         with pool.lock:
             if gen != pool.generation:
@@ -3926,13 +3390,11 @@ class ContinuousGenerator:
         self._tok[row] = int(snap["tok"])
         self._done[row] = False
         self._row_emitted[row] = emitted
-        if self._mixed:
-            self._prefilling[row] = False
-            self._row_prompt[row] = None
-            self._row_L[row] = L
-            self._row_w0[row] = 0
-        if self._mixed or self._spec:
-            self._row_prompt_toks[row] = prompt
+        self._prefilling[row] = False
+        self._row_prompt[row] = None
+        self._row_L[row] = L
+        self._row_w0[row] = 0
+        self._row_prompt_toks[row] = prompt
         # No TTFT sample (the first token happened on the source lane);
         # ITL resumes from now — the migration gap shows up client-side.
         self._row_last_emit[row] = time.perf_counter()
@@ -3944,40 +3406,6 @@ class ContinuousGenerator:
             mig["imported_chain_tokens"] += (n_chain - m) * bs
         self._push_stream(row, req)
         self._maybe_complete(row)
-
-    def _admit_slab(self, item, row: int) -> None:
-        """Decode-thread half of state_slab admission: allocate ONE slab
-        row (the stream's whole autoregressive state budget, now and
-        forever) and write the prefill thread's computed state into it.
-        Raises PoolExhausted (nothing consumed) when no row is free —
-        the caller defers the admission exactly like paged block
-        pressure."""
-        (req, states, first_tok, _pb, L, row_counts, _m, prompt,
-         gen) = item
-        spool = self._spool
-        t0 = time.perf_counter()
-        req.t_admit = t0
-        first_col = min(L, self.max_seq - 1)
-        with spool.lock:
-            if gen != spool.generation:
-                raise _StaleAdmission(
-                    "state slab pool was rebuilt during this request's "
-                    "admission")
-            rid = spool.alloc_row()  # PoolExhausted -> defer
-            spool.slab = self._slab_write()(
-                spool.slab, states.conv, states.ssm, jnp.int32(rid))
-        self._slab_rows[row] = rid
-        self._count_admission_dispatch()
-        if req.sink is not None:
-            dur_us = (time.perf_counter() - t0) * 1e6
-            req.sink.stage("state_alloc", dur_us,
-                           start_ts=time.time() - dur_us / 1e6,
-                           state_row=rid)
-        if row_counts is not None:
-            self._counts = self._ensure_counts().at[row].set(
-                jnp.asarray(row_counts[0]))
-        self._init_row(req, row, first_tok, pos=first_col, start=0)
-        self._maybe_hold(row, req)
 
     def _admit_slab_mixed(self, item, row: int) -> None:
         """Mixed-mode state_slab admission (decode thread): allocate the
@@ -4054,13 +3482,11 @@ class ContinuousGenerator:
         self._tok[row] = int(snap["tok"])
         self._done[row] = False
         self._row_emitted[row] = emitted
-        if self._mixed:
-            self._prefilling[row] = False
-            self._row_prompt[row] = None
-            self._row_L[row] = L
-            self._row_w0[row] = 0
-        if self._mixed or self._spec:
-            self._row_prompt_toks[row] = prompt
+        self._prefilling[row] = False
+        self._row_prompt[row] = None
+        self._row_L[row] = L
+        self._row_w0[row] = 0
+        self._row_prompt_toks[row] = prompt
         # No TTFT sample (the first token happened on the source lane);
         # ITL resumes from now — the migration gap shows up client-side.
         self._row_last_emit[row] = time.perf_counter()
@@ -4132,24 +3558,11 @@ class ContinuousGenerator:
         self.ttft_hist.observe(max(0.0, now - req.t_submit))
         self._row_last_emit[row] = now
 
-    def _init_row(self, req: _Request, row: int, first_tok: int, *,
-                  pos: int, start: int) -> None:
-        """Host-side row state shared by both two-path admission modes."""
-        self._set_row_params(req, row, pos=pos, start=start)
-        self._tok[row] = first_tok
-        self._row_emitted[row] = [first_tok]
-        self._done[row] = ((req.eos_id >= 0 and first_tok == req.eos_id)
-                           or first_tok in req.stop_tokens)
-        self._stats["admitted"] += 1
-        self._first_token_metrics(req, row)
-        self._push_stream(row, req)  # first token flushes at admission
-        self._maybe_complete(row)
-
     def _admit(self, item, row: int) -> None:
-        """Decode-thread half of admission: splice the prefilled KV block
-        into the shared cache and initialise the row's host-side state.
-        Family-dispatched: state_slab rows write their computed state
-        into one slab row instead of scattering KV into pool blocks."""
+        """Decode-thread half of admission, by what the lane holds: a
+        slab row or a block chain is taken and the row set to prefill
+        inside the ticks; the dense cache splices the prefilled KV block
+        in and initialises the row's host-side state."""
         if item[0].oneshot is not None:
             # One-shot rows first — family-independent (no blocks, no
             # slab row, no cache splice), so a generative lane carrying
@@ -4159,20 +3572,14 @@ class ContinuousGenerator:
         if self._slab:
             if item[0].migrate is not None:
                 self._admit_import_slab(item, row)
-                return
-            if self._mixed:
-                self._admit_slab_mixed(item, row)
             else:
-                self._admit_slab(item, row)
+                self._admit_slab_mixed(item, row)
             return
         if self._paged:
             if item[0].migrate is not None:
                 self._admit_import(item, row)
-                return
-            if self._mixed:
-                self._admit_mixed(item, row)
             else:
-                self._admit_paged(item, row)
+                self._admit_mixed(item, row)
             return
         req, row_caches, first_tok, pb, L, row_counts = item
         req.t_admit = time.perf_counter()
@@ -4184,7 +3591,15 @@ class ContinuousGenerator:
             self._caches = self._insert(False)(
                 self._caches, row_caches.k, row_caches.v, row)
         self._count_admission_dispatch()
-        self._init_row(req, row, first_tok, pos=pb, start=pb - L)
+        self._set_row_params(req, row, pos=pb, start=pb - L)
+        self._tok[row] = first_tok
+        self._row_emitted[row] = [first_tok]
+        self._done[row] = ((req.eos_id >= 0 and first_tok == req.eos_id)
+                           or first_tok in req.stop_tokens)
+        self._stats["admitted"] += 1
+        self._first_token_metrics(req, row)
+        self._push_stream(row, req)  # first token flushes at admission
+        self._maybe_complete(row)
 
     def _clear_mixed_row(self, row: int) -> None:
         """Drop a row's mixed-mode prefill / speculative state
@@ -4199,7 +3614,6 @@ class ContinuousGenerator:
             self._row_prompt[row] = None
             self._row_L[row] = 0
             self._row_w0[row] = 0
-        if self._mixed or self._spec:
             self._row_prompt_toks[row] = None
 
     def _visible_tokens(self, row: int, req: _Request) -> List[int]:
@@ -4587,7 +4001,7 @@ class ContinuousGenerator:
                     self._row_emitted[r] = []
                 self._release_row_blocks(r)
                 self._clear_mixed_row(r)
-            if self._paged or self._slab:
+            if self._mixed:
                 while self._pending:
                     item = self._pending.popleft()
                     self._discard_item(item)
@@ -4615,8 +4029,8 @@ class ContinuousGenerator:
                                     "reason": "scheduler stopped"})
 
     def _ensure_capacity_paged(self) -> None:
-        """Pre-chunk block growth: every live row must own blocks through
-        the columns the next chunk can write (a write through an
+        """Pre-tick block growth: every live row must own blocks through
+        the columns the next tick can write (a write through an
         unallocated table entry would land in the null block and the row
         would attend garbage). A row the pool cannot grow — even after
         radix eviction — completes early with the tokens it has (counted
@@ -4629,7 +4043,7 @@ class ContinuousGenerator:
                 continue  # done rows rewrite their own (allocated) column
             if self._held[r]:
                 continue  # parked handoff rows decode nothing this tick
-            if self._mixed and self._prefilling[r]:
+            if self._prefilling[r]:
                 continue  # bucket + first-decode blocks reserved at admit
             last_col = min(int(self._pos[r]) + self._row_horizon(r, req),
                            self.max_seq - 1)
@@ -4906,7 +4320,7 @@ class ContinuousGenerator:
         the tick's span(s). `mixed_step` keeps the fields its readers
         know (duration_us, width, prefill_tokens, decode_rows) and
         carries the clock's phases; a speculative tick also records
-        `spec_verify` (and `mixed_step` only on a mixed lane). `starved`:
+        `spec_verify`. `starved`:
         the rows the tick's budget starved, where the tick that ends is
         not the last one formed."""
         live = any(req is not None and not self._held[r]
@@ -4923,13 +4337,12 @@ class ContinuousGenerator:
                 start_ts=start_ts,
                 attrs={"decode_rows": int(decode_rows), **spec,
                        "width": int(width)})
-        if spec is None or self._mixed:
-            self.tracer.record(
-                "tick", "mixed_step", self.trace_node, dur_us,
-                start_ts=start_ts,
-                attrs={"prefill_tokens": int(prefill_tokens),
-                       "decode_rows": int(decode_rows),
-                       "width": int(width), **phases})
+        self.tracer.record(
+            "tick", "mixed_step", self.trace_node, dur_us,
+            start_ts=start_ts,
+            attrs={"prefill_tokens": int(prefill_tokens),
+                   "decode_rows": int(decode_rows),
+                   "width": int(width), **phases})
 
     def _tick_mixed(self) -> None:
         """One mixed tick: form the ragged batch (decode rows x 1 token +
@@ -5037,7 +4450,7 @@ class ContinuousGenerator:
                         # This chunk reaches the prompt's last token: the
                         # dispatch samples the request's FIRST token from
                         # slot Leff-1-w0 at logical position L (the exact
-                        # _first_token rule of the two-path modes).
+                        # `_first_token` rule of the dense path).
                         completing[r] = True
                         active[r] = True
                         sample_slot[r] = Leff - 1 - w0
@@ -5215,10 +4628,9 @@ class ContinuousGenerator:
 
     def _tick_spec(self) -> None:
         """One SPECULATIVE ragged tick — the spec_k>0 replacement for
-        both the paged decode chunk (two-path mode) and `_tick_mixed`
-        (mixed mode). Host side: ask the drafter for up to spec_k
+        `_tick_mixed`. Host side: ask the drafter for up to spec_k
         deterministic proposals per eligible decode row, form ONE ragged
-        batch (decode rows: q_len = proposals+1 verify windows; mixed
+        batch (decode rows: q_len = proposals+1 verify windows;
         admitting rows: their budgeted prefill chunk), issue exactly one
         compiled dispatch, and advance each row by its accepted prefix
         plus the corrected/bonus token. Rejected tails leave stale KV
@@ -5241,21 +4653,20 @@ class ContinuousGenerator:
                 controls = True
             if self._held[r]:
                 continue  # parked handoff rows: no budget, no proposals
-            if self._mixed and self._prefilling[r]:
+            if self._prefilling[r]:
                 prefill_rows.append(r)
             else:
                 n_decode += 1
         chunk = np.zeros((B,), np.int32)
-        if self._mixed:
-            # Mixed budget rule unchanged: decode rows count 1 each (the
-            # verify window RE-DERIVES tokens, it does not widen the
-            # budgeted stream), remainder over admitting rows.
-            budget_left = max(1, self._effective_mixed_budget() - n_decode)
-            for r in prefill_rows:
-                remaining = max(self._row_L[r], 1) - self._row_w0[r]
-                c = min(remaining, self._chunk_cap, budget_left)
-                chunk[r] = max(0, c)
-                budget_left -= chunk[r]
+        # Mixed budget rule unchanged: decode rows count 1 each (the
+        # verify window RE-DERIVES tokens, it does not widen the
+        # budgeted stream), remainder over admitting rows.
+        budget_left = max(1, self._effective_mixed_budget() - n_decode)
+        for r in prefill_rows:
+            remaining = max(self._row_L[r], 1) - self._row_w0[r]
+            c = min(remaining, self._chunk_cap, budget_left)
+            chunk[r] = max(0, c)
+            budget_left -= chunk[r]
 
         # Drafting (host-side, before batch formation). The cap keeps a
         # window inside both the row's token budget (never propose past
@@ -5265,7 +4676,7 @@ class ContinuousGenerator:
         for r, req in enumerate(self._row_req):
             if (req is None or self._done[r] or self._held[r]
                     or self._bo_spec_off
-                    or (self._mixed and self._prefilling[r])):
+                    or self._prefilling[r]):
                 # Brownout spec suspension: no proposals — every row
                 # rides q_len 1 through the same compiled dispatch
                 # (greedy streams byte-identical, drafter work skipped).
@@ -5293,10 +4704,10 @@ class ContinuousGenerator:
                 proposed += len(drafts[r])
 
         # Exactly two compiled ragged widths per controls variant:
-        # S (decode-only ticks) and max(chunk cap, S) (mixed ticks that
-        # carry a prefill chunk).
+        # S (decode-only ticks) and max(chunk cap, S) (ticks that carry
+        # a prefill chunk).
         width = S
-        if self._mixed and prefill_rows and chunk.max() > 0:
+        if prefill_rows and chunk.max() > 0:
             width = max(self._chunk_cap, S)
         tokens = np.zeros((B, width), np.int32)
         pos0 = np.zeros((B,), np.int32)
@@ -5311,7 +4722,7 @@ class ContinuousGenerator:
         for r, req in enumerate(self._row_req):
             if req is None:
                 continue
-            if self._mixed and self._prefilling[r]:
+            if self._prefilling[r]:
                 w0 = self._row_w0[r]
                 c = int(chunk[r])
                 Leff = max(self._row_L[r], 1)
@@ -5387,19 +4798,17 @@ class ContinuousGenerator:
         # one-dispatch-per-tick invariant stays independently assertable.
         sp = self._stats["spec"]
         sp["dispatches"] += 1
-        if self._mixed:
-            self._stats["mixed"]["dispatches"] += 1
+        m = self._stats["mixed"]
+        m["dispatches"] += 1
 
         sp["ticks"] += 1
         sp["proposed_tokens"] += proposed
         sp["draft_dispatches"] = getattr(self._drafter, "dispatches", 0)
-        if self._mixed:
-            m = self._stats["mixed"]
-            m["ticks"] += 1
-            m[f"sample_{self._tick_sampler}_ticks"] += 1
-            m["prefill_tokens"] += prefill_tokens
-            if prefill_tokens and n_decode:
-                m["coscheduled_ticks"] += 1
+        m["ticks"] += 1
+        m[f"sample_{self._tick_sampler}_ticks"] += 1
+        m["prefill_tokens"] += prefill_tokens
+        if prefill_tokens and n_decode:
+            m["coscheduled_ticks"] += 1
 
         accepted = 0
         decode_emitted = 0
@@ -5409,7 +4818,7 @@ class ContinuousGenerator:
                 continue
             if self._held[r]:
                 continue  # parked: nothing was dispatched for this row
-            if self._mixed and self._prefilling[r]:
+            if self._prefilling[r]:
                 self._row_w0[r] += int(chunk[r])
                 if not completing[r]:
                     continue
@@ -5444,92 +4853,11 @@ class ContinuousGenerator:
                 self._trim_row_tail(r, req)
         sp["accepted_tokens"] += accepted
         sp["emitted_tokens"] += decode_emitted
-        if self._mixed:
-            self._stats["mixed"]["decode_tokens"] += decode_emitted
+        m["decode_tokens"] += decode_emitted
 
         self._tick_done(prefill_tokens, n_decode, width,
                         spec={"proposed": int(proposed),
                               "accepted": int(accepted)})
-
-    def _tick_slab(self) -> None:
-        """One two-path decode chunk for the state_slab family — the
-        paged chunk with (pool, block tables) swapped for (slab, row
-        ids) and the attention read swapped for the O(1) recurrence.
-        Held (parked handoff) rows ride the fixed batch masked done
-        with their STATE frozen in-dispatch (the family's analog of the
-        paged path's frozen-column writes) and host state restored
-        after. Exceptions propagate to the loop's _recover."""
-        spool = self._spool
-        eos_vec = np.full((self.n_slots,), -1, np.int32)
-        controls = False
-        live = []
-        for r, req in enumerate(self._row_req):
-            if req is None:
-                continue
-            live.append(r)
-            if req.eos_id >= 0:
-                eos_vec[r] = req.eos_id
-            if req.rep_penalty != 1.0 or req.stop_tokens:
-                controls = True
-        held_rows = [r for r in live if self._held[r]]
-        done_in = self._done
-        saved = []
-        if held_rows:
-            done_in = self._done.copy()
-            done_in[held_rows] = True
-            saved = [(r, int(self._tok[r]), int(self._pos[r]))
-                     for r in held_rows]
-        row_ids = np.asarray([rid if rid >= 0 else 0
-                              for rid in self._slab_rows], np.int32)
-        # Slab-donating dispatch under the pool lock (exports and
-        # admission writes order against it).
-        with spool.lock:
-            common = (self._step_params, spool.slab, jnp.asarray(row_ids),
-                      jnp.asarray(self._tok), jnp.asarray(self._pos),
-                      jnp.asarray(done_in), jnp.asarray(self._seeds),
-                      jnp.asarray(self._temps), jnp.asarray(self._topps),
-                      jnp.asarray(self._topks), jnp.asarray(self._minps),
-                      jnp.asarray(eos_vec))
-            if controls:
-                out = self._slab_decode(True)(
-                    *common, self._ensure_counts(),
-                    jnp.asarray(self._pens), jnp.asarray(self._stops))
-            else:
-                out = self._slab_decode(False)(*common)
-            spool.slab = out[0]
-            out = out[1:]
-            if controls:
-                tok, pos, done, self._counts, toks = out
-            else:
-                tok, pos, done, toks = out
-        start_host_copies(tok, pos, done, toks)
-        self._tok = np.array(tok)
-        self._pos = np.array(pos)
-        self._done = np.array(done)
-        toks_host = np.asarray(toks)
-        for r, tok_r, pos_r in saved:
-            # Parked rows rode the dispatch masked done: restore their
-            # true pending state (they are NOT done; their slab row was
-            # never written — the state freeze is in-dispatch).
-            self._tok[r] = tok_r
-            self._pos[r] = pos_r
-            self._done[r] = False
-        self._stats["chunks"] += 1
-
-        for r, req in enumerate(self._row_req):
-            if req is None or self._held[r]:
-                continue
-            need = req.max_new - len(self._row_emitted[r])
-            if need > 0:
-                self._row_emitted[r].extend(
-                    int(t) for t in toks_host[r, :need])
-                now = time.perf_counter()
-                if self._row_last_emit[r] > 0:
-                    self.itl_hist.observe(
-                        max(0.0, now - self._row_last_emit[r]))
-                self._row_last_emit[r] = now
-            self._push_stream(r, req)
-            self._maybe_complete(r)
 
     def _tick_slab_mixed(self) -> None:
         """One mixed tick for the state_slab family: the SAME batch
@@ -5676,8 +5004,9 @@ class ContinuousGenerator:
 
     def _loop_body(self) -> None:
         # The lanes whose ticks are marked say which of the loop's
-        # statements run (`TickClock.loop_part`); the others mark nothing.
-        marked = self._mixed or self._spec
+        # statements run (`TickClock.loop_part`); the dense cache's chunk
+        # loop marks nothing.
+        marked = self._mixed
         part = self._clock.loop_part if marked else lambda name: None
         while self._running:
             if marked:
@@ -5703,7 +5032,7 @@ class ContinuousGenerator:
             # Live rows' block growth outranks new admissions for pool
             # space (an admitted row must never be starved mid-stream by
             # a newcomer).
-            if self._paged or self._slab:
+            if self._mixed:
                 # Export commands run FIRST: between ticks the row is
                 # quiescent (`_serve_exports` lands a tick in flight
                 # before it serves one), and an export ahead of
@@ -5719,8 +5048,7 @@ class ContinuousGenerator:
             free = self._free_rows()
             admitted_any = False
             while free:
-                from_pending = bool((self._paged or self._slab)
-                                    and self._pending)
+                from_pending = bool(self._mixed and self._pending)
                 if from_pending:
                     item = self._pending[0]
                 else:
@@ -5797,11 +5125,9 @@ class ContinuousGenerator:
                         # holding pins makes its prefix unevictable,
                         # and two mutually-pinned parked items with no
                         # live rows would starve each other forever.
-                        # Dropping them is fully correct — two-path
-                        # items already hold the gathered prefix KV in
-                        # their row cache, and mixed items simply
-                        # re-prefill from position 0 at the retry
-                        # (either way the request just shares nothing).
+                        # Dropping them is fully correct: the item
+                        # re-prefills from position 0 at the retry (the
+                        # request just shares nothing).
                         self._discard_item(item)
                         item = item[:6] + ([], item[7], item[8])
                         self._pending.append(item)
@@ -5828,7 +5154,7 @@ class ContinuousGenerator:
                     break
             part("expire")
             self._cancel_expired_rows()
-            if self._paged or self._slab:
+            if self._mixed:
                 # Handoff holds past their park window resume decoding
                 # (the colocated fallback — the export never came).
                 self._unpark_expired()
@@ -5849,8 +5175,7 @@ class ContinuousGenerator:
                 self._drain_tick()  # its rows left while it ran
                 self._clock.idle()
                 continue
-            if (self._paged or self._slab) and all(self._held[r]
-                                                   for r in live):
+            if self._mixed and all(self._held[r] for r in live):
                 # Only parked handoff rows: no dispatchable work this
                 # tick — idle briefly instead of spinning while the
                 # export command (or the park bound) arrives.
@@ -5858,12 +5183,12 @@ class ContinuousGenerator:
                 time.sleep(0.002)
                 continue
 
-            if self._mixed or self._spec:
-                # ONE ragged dispatch serves this tick's decode rows and
-                # prefill chunks together (admission folded into the
-                # decode dispatch — no second device path to contend).
-                # Speculation upgrades decode rows to verify windows in
-                # the SAME single dispatch.
+            if self._mixed:
+                # A lane with a pool or a slab: ONE ragged dispatch serves
+                # this tick's decode rows and prefill chunks together
+                # (admission folded into the decode dispatch — no second
+                # device path to contend). Speculation upgrades decode
+                # rows to verify windows in the SAME single dispatch.
                 try:
                     if self._spec:
                         self._tick_spec()
@@ -5876,21 +5201,12 @@ class ContinuousGenerator:
                     self._recover(exc)
                 continue
 
-            if self._slab:
-                # Two-path decode chunk through the family's step
-                # function (the state_slab analog of the paged/dense
-                # chunk below).
-                try:
-                    self._tick_slab()
-                except Exception as exc:
-                    self._recover(exc)
-                continue
-
             try:
-                # One decode chunk over the fixed batch. -1 marks rows with
-                # EOS disabled (and free rows): sampled tokens are in
-                # [0, vocab) so `nxt == -1` never fires; done rows emit -1
-                # (discarded), and the embedding lookup of -1 clips
+                # The dense per-slot cache (`kv_block_size` 0): one decode
+                # chunk of `step_chunk` steps over the fixed batch. -1 marks
+                # rows with EOS disabled (and free rows): sampled tokens are
+                # in [0, vocab) so `nxt == -1` never fires; done rows emit
+                # -1 (discarded), and the embedding lookup of -1 clips
                 # harmlessly under jit.
                 eos_vec = np.full((self.n_slots,), -1, np.int32)
                 controls = False
@@ -5900,55 +5216,7 @@ class ContinuousGenerator:
                     if req is not None and (req.rep_penalty != 1.0
                                             or req.stop_tokens):
                         controls = True
-                # Handoff holds ride the chunk as DONE rows (pos frozen,
-                # sampled tokens discarded, writes confined to the
-                # not-yet-valid column `pos`) and restore their host
-                # state after — a parked row spends no budget and emits
-                # nothing while it waits for export.
-                held_rows = ([r for r in live if self._held[r]]
-                             if self._paged else [])
-                done_in = self._done
-                if held_rows:
-                    done_in = self._done.copy()
-                    done_in[held_rows] = True
-                    saved = [(r, int(self._tok[r]), int(self._pos[r]))
-                             for r in held_rows]
-                if self._paged:
-                    # Pool-donating dispatch under the pool lock so the
-                    # prefill thread's prefix gathers order before it.
-                    with self._pool.lock:
-                        pool_args = (self._pool.caches,)
-                        if self._quant:
-                            pool_args += (self._pool.scales,)
-                        common = (self._step_params, *pool_args,
-                                  jnp.asarray(self._tables),
-                                  jnp.asarray(self._tok),
-                                  jnp.asarray(self._pos),
-                                  jnp.asarray(done_in),
-                                  jnp.asarray(self._seeds),
-                                  jnp.asarray(self._temps),
-                                  jnp.asarray(self._topps),
-                                  jnp.asarray(self._topks),
-                                  jnp.asarray(self._minps),
-                                  jnp.asarray(eos_vec))
-                        if controls:
-                            out = self._decode_paged(True)(
-                                *common, self._ensure_counts(),
-                                jnp.asarray(self._pens),
-                                jnp.asarray(self._stops))
-                        else:
-                            out = self._decode_paged(False)(*common)
-                        self._pool.caches = out[0]
-                        if self._quant:
-                            self._pool.scales = out[1]
-                            out = out[2:]
-                        else:
-                            out = out[1:]
-                        if controls:
-                            tok, pos, done, self._counts, toks = out
-                        else:
-                            tok, pos, done, toks = out
-                elif controls:
+                if controls:
                     (self._caches, tok, pos, done, self._counts,
                      toks) = self._decode(True)(
                         self._step_params, self._caches,
@@ -5976,19 +5244,13 @@ class ContinuousGenerator:
                 self._pos = np.array(pos)
                 self._done = np.array(done)
                 toks_host = np.asarray(toks)
-                for r, tok_r, pos_r in (saved if held_rows else ()):
-                    # Parked rows rode the dispatch masked done: restore
-                    # their true pending state (they are NOT done).
-                    self._tok[r] = tok_r
-                    self._pos[r] = pos_r
-                    self._done[r] = False
             except Exception as exc:
                 self._recover(exc)
                 continue
             self._stats["chunks"] += 1
 
             for r, req in enumerate(self._row_req):
-                if req is None or self._held[r]:
+                if req is None:
                     continue
                 need = req.max_new - len(self._row_emitted[r])
                 if need > 0:

@@ -921,14 +921,6 @@ def main(argv=None) -> int:
                             default=None,
                             help="per-fetch peer budget in seconds "
                                  "(default 5)")
-        parser.add_argument("--mixed-step", action="store_true",
-                            help="mixed prefill+decode stepping (needs "
-                                 "--kv-block-size): every scheduler tick "
-                                 "issues ONE ragged dispatch serving decode "
-                                 "rows (1 token each) and admitting rows' "
-                                 "prefill chunks together — long prompts "
-                                 "stop spiking in-flight rows' inter-token "
-                                 "latency (tests/test_mixed_step.py)")
         parser.add_argument("--mixed-token-budget", type=int, default=0,
                             help="new tokens per mixed tick (decode rows "
                                  "count 1 each; the rest splits over "
@@ -937,8 +929,8 @@ def main(argv=None) -> int:
                                  "(--gen-prefill-chunk)")
         parser.add_argument("--spec-k", type=int, default=0,
                             help="continuous speculative decoding (needs "
-                                 "--kv-block-size; composes with "
-                                 "--mixed-step): a drafter proposes up to "
+                                 "--kv-block-size): a drafter proposes up "
+                                 "to "
                                  "this many tokens per decode row per tick "
                                  "and the tick's ONE ragged dispatch "
                                  "verifies every window — rows advance "
@@ -1110,7 +1102,6 @@ def main(argv=None) -> int:
                                      gen_kv_quantize=args.kv_quantize,
                                      gen_prefix_sharing=(
                                          args.prefix_sharing == "on"),
-                                     gen_mixed_step=args.mixed_step,
                                      gen_mixed_token_budget=(
                                          args.mixed_token_budget),
                                      gen_continuous_spec_k=args.spec_k,

@@ -416,9 +416,10 @@ class WorkerNode:
                     "--kv-quantize apply to the kv_paged family")
             if self.config.gen_mixed_step:
                 raise RuntimeError(
-                    "--mixed-step merges prefill and decode dispatches; "
-                    "stateless-family models have neither (one-shot "
-                    "rows already ride one grouped dispatch per tick)")
+                    "mixed stepping merges prefill and decode "
+                    "dispatches; stateless-family models have neither "
+                    "(one-shot rows already ride one grouped dispatch "
+                    "per tick)")
         # Tensor-parallel serving fences (the registry declares the
         # partition rule; the worker refuses misconfigurations LOUDLY —
         # an operator who asked for a sharded lane must never get a

@@ -122,19 +122,17 @@ class WorkerConfig:
     # degrades to local prefill (``inflight_capped``), not a convoy of
     # blocked prefill threads.
     gen_prefix_fetch_inflight: int = 2
-    # Mixed prefill+decode stepping (paged mode only): each scheduler
-    # tick forms ONE ragged batch of (decode rows x 1 token) +
-    # (admitting rows x a prefill chunk) and issues exactly one device
-    # dispatch — admission rides the decode dispatch instead of
-    # contending with it, so long prompts stop spiking in-flight rows'
-    # inter-token latency. Off = the two-path scheduler above.
+    # Chooses nothing: a lane with gen_kv_block_size > 0 (or a state
+    # slab) always steps by the ragged tick, the dense cache by two
+    # paths. Kept because the `serving` blocks under benchmarks/ name it
+    # (ROADMAP: the benchmark item that drops it); True without a pool
+    # or on a stateless model is refused at start-up.
     gen_mixed_step: bool = False
-    # Per-tick new-token budget for mixed stepping (decode rows count 1
+    # Per-tick new-token budget of the ragged tick (decode rows count 1
     # each; the rest splits over admitting rows' prefill chunks and caps
     # the compiled chunk width). 0 = auto (gen_prefill_chunk).
     gen_mixed_token_budget: int = 0
-    # Continuous speculative decoding (paged mode only, two-path or
-    # mixed): each tick a drafter proposes up to this many tokens per
+    # Continuous speculative decoding (paged mode only): each tick a drafter proposes up to this many tokens per
     # decode row and the tick's ONE ragged dispatch verifies every
     # window, advancing rows 1..k+1 tokens per dispatch. Greedy streams
     # byte-identical to plain decode for any draft; 0 = off (--spec-k).

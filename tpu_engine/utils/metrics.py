@@ -348,7 +348,7 @@ def render_prometheus(healths: List[Dict], stats: Optional[Dict] = None,
            [({**node(h), "dtype": w.get("step_dtype")}, w.get("step_bytes"))
             for h, w in wt])
 
-    # Mixed prefill+decode stepping (continuous scheduler --mixed-step):
+    # Mixed prefill+decode stepping (a lane with a pool or a slab):
     # one ragged dispatch per tick — ticks and dispatches are counted at
     # different sites precisely so scrapers can assert they stay equal.
     mx = [(h, g.get("mixed")) for h, g in gen
