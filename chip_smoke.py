@@ -52,10 +52,11 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
-# Everything, compilation included, must end inside the 1500 s the chip
+# Everything, compilation included, must end inside the 2700 s the chip
 # call is given (measured, PR 49: device 18 s, kernels 990, serve 188,
-# cache 32).
-DEADLINE = time.monotonic() + 1440
+# cache 32; PR 50: the kernel phase alone 896 s with the machine's compile
+# cache warm and OVER 1400 s cold, its four new cases 125-160 s of it).
+DEADLINE = time.monotonic() + 2640
 
 SERVE_FLAGS = ["--model", "gpt2", "--kv-block-size", "16",
                "--gen-prefill-chunk", "256", "--warmup"]
@@ -93,7 +94,7 @@ def say(**fields):
 
 def time_left(cap):
     left = DEADLINE - time.monotonic()
-    check(left > 0, "out of time: the run must end inside 1500 s")
+    check(left > 0, "out of time: the run must end inside 2700 s")
     return min(cap, left)
 
 
@@ -462,11 +463,12 @@ def main():
         check(device["platform"] == "tpu", f"device is {device}")
 
     with phase("kernels"):
-        # 990 s on a v5e with the benchmark cells' own shapes and the walk's
-        # cases (PRs 46, 48: the gather references of the cell and class
-        # cases are most of it).
+        # ~900 s on a v5e with a warm compile cache and over 1400 s cold,
+        # with the benchmark cells' own shapes and the walk's cases (PRs
+        # 46, 48, 50: the gather references of the cell and class cases
+        # are most of it).
         run_child("kernels",
-                  [sys.executable, "-m", "tpu_engine.ops.kernel_check"], 1200)
+                  [sys.executable, "-m", "tpu_engine.ops.kernel_check"], 2100)
 
     with phase("serve"):
         cold_ready, drive_s, _ = serve_phase("serve", 1, 1)

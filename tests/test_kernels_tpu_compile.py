@@ -87,6 +87,36 @@ def test_the_state_space_recurrence_compiles_for_v5e_in_both_forms(
     assert _pallas_grids(jaxpr.jaxpr) == [(64, 2)]
 
 
+def test_the_recurrence_compiles_for_v5e_at_128_heads_of_64_by_128(
+        v5e_devices):
+    """`ssd_step` and `ssd_chunk` cut the other way (Nemotron-H: 128 heads
+    of (64, 128) in 8 groups): a block of the step is a group's 16 heads,
+    512 KB, its dt x block (1, 1, 64, 16); the chunk kernel a head a grid
+    step, 128 steps a row."""
+    names = []
+    for case in kernel_check.kernel_cases("nemotron_h", interpret=False):
+        kernel_check.compile_for_topology(case, v5e_devices[0])
+        names.append(case.name)
+    assert names == ["nemotron_h/ssd_step/B64", "nemotron_h/ssd_chunk/T256"]
+    step, chunk = kernel_check.kernel_cases("nemotron_h")
+    for case, grid in ((step, (64, 8)), (chunk, (128,))):
+        jaxpr = jax.make_jaxpr(case.kernel)(*jax.eval_shape(case.operands))
+        assert _pallas_grids(jaxpr.jaxpr) == [grid]
+
+
+def test_the_two_matrix_grouped_product_compiles_for_v5e(v5e_devices):
+    """`ops.moe.routed_experts` over a bank of ungated two-matrix experts in
+    a 1024-lane latent, 128 held of 512, 7,040 pairs a tick
+    (`kernel_check.GROUPED_SHAPES`): two `ragged_dot`s, no gate's split."""
+    (case,) = kernel_check.grouped_cases()
+    kernel_check.compile_for_topology(case, v5e_devices[0])
+    jaxpr = jax.make_jaxpr(case.kernel)(*jax.eval_shape(case.operands))
+    products = [e for e in jaxpr.jaxpr.eqns
+                if e.primitive.name == "ragged_dot_general"]
+    assert [tuple(e.outvars[0].aval.shape) for e in products] == [
+        (7040, 2688), (7040, 1024)]
+
+
 def _pallas_calls(jaxpr):
     """The parameters of every `pallas_call` in a jaxpr, nested calls
     included."""
@@ -176,6 +206,11 @@ def test_the_walk_s_own_cases_compile_for_v5e(v5e_devices):
     # tile of 128 slots 640 query rows = five tiles of the grid; 16 rows and
     # ceil(272 / 128) more tall tiles.
     ("falcon-h1-34b-6l.converse/classes/W256", [(16, 1), (19, 5)]),
+    # G = 16 over 2 KV heads: a decode row's 2 x 16 query rows are one
+    # packed tile; a tall tile is 8 slots = 128 query rows = one tile of
+    # the grid; 16 rows and ceil(320 / 8) more tall tiles.
+    ("nemotron-3-super-120b-a12b-11l.agents/classes/W256",
+     [(16, 1), (56, 1)]),
 ])
 def test_the_two_classes_of_tile_are_two_calls_with_grids_of_their_own(
         v5e_devices, name, grids):
@@ -845,6 +880,94 @@ def test_two_mixers_a_layer_mixed_step_copies_neither_pool(v5e_devices, width):
           analysis.temp_size_in_bytes, "alias", analysis.alias_size_in_bytes)
     assert analysis.temp_size_in_bytes < 0.6e9
     assert analysis.alias_size_in_bytes > 2.8e9      # both pools in place
+
+
+@pytest.mark.parametrize("width", [1, 256])
+def test_one_mixer_a_layer_mixed_step_copies_no_pool_state_or_bank(
+        v5e_devices, width):
+    """The Nemotron-H cell's mixed step at its serving shapes (shapes only:
+    64 rows, eleven layers of three shapes: five step or chunk a 4.2 MB
+    state of 128 heads of (64, 128), one reads a K/V chain at G = 16, five
+    route 22 of 512 experts over a bank of 128 two-matrix experts in a
+    1024-lane latent), both pools donated, compiled for one v5e: the paged
+    calls and both forms of the recurrence are Pallas calls in it; no
+    `copy`, `slice` or `dynamic-slice` whose result is a pool, a state
+    array, an expert bank or a layer of one, a `dynamic-update-slice` of
+    that size only as a chunk row's write of its conv tail into its own
+    state row; both pools in place."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.nemotron_h import nemotron_h_step_rows_ragged
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+    from tpu_engine.ops.ssd import ssd_chunk_row, ssd_step_rows
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "nemotron-3-super-120b-a12b-11l.json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    assert width in (1, serving["gen_prefill_chunk"])
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg = spec.config
+    assert cfg.n_heads // cfg.kv_heads == 16
+    assert (cfg.n_linear_layers, cfg.n_moe_layers, cfg.n_full_layers) == (
+        5, 5, 1)
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    (kind,) = cfg.kv_block_kinds
+    one = placed(jax.ShapeDtypeStruct(
+        (kind.n_layers, serving["gen_kv_blocks"], bs, kind.kv_lanes[0]),
+        jnp.bfloat16))
+    pools = (KVCache(one, one),
+             tuple(placed(jax.ShapeDtypeStruct(
+                 (cfg.n_linear_layers, rows + 1) + shape, jnp.float32))
+                 for shape in cfg.state_row_shapes))
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return nemotron_h_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=functools.partial(ragged_paged_attention,
+                                      interpret=False),
+            step_fn=functools.partial(ssd_step_rows, interpret=False),
+            chunk_fn=functools.partial(ssd_chunk_row, interpret=False),
+            sample_slot=jnp.zeros_like(pos0), held=spec.held,
+            max_tokens=serving["gen_prefill_chunk"] + rows)
+
+    def host(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    step, behind = _behind_a_step(tick, host(rows))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, (host(rows, -(-cfg.max_seq // bs)), host(rows)),
+        host(rows, width), host(rows), host(rows), *behind).compile()
+    hlo = compiled.as_text()
+    assert "_paged_call" in hlo and "ssd_step" in hlo
+    assert ("ssd_chunk" in hlo) == (width > 1)
+    banks = [bp["mlp"]["experts"] for bp in params["layers"] if "mlp" in bp]
+    assert len(banks) == 5
+    sizes = {math.prod(x.shape) for x in jax.tree.leaves(banks)}
+    for x in list(pools[0]) + list(pools[1]):
+        sizes |= {math.prod(x.shape), math.prod(x.shape[1:])}
+    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
+                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
+    moved = {op for dims, op in movers.findall(hlo)
+             if math.prod(map(int, dims.split(","))) in sizes}
+    assert moved <= ({"dynamic-update-slice"} if width > 1 else set()), moved
+    analysis = compiled.memory_analysis()
+    print("nemotron_h step width", width, "temp bytes",
+          analysis.temp_size_in_bytes, "alias", analysis.alias_size_in_bytes)
+    assert analysis.temp_size_in_bytes < 1.0e9
+    assert analysis.alias_size_in_bytes > 1.9e9      # both pools in place
 
 
 def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):

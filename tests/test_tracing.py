@@ -249,7 +249,10 @@ def test_hedged_route_trace_tree_and_export(lanes):
     rid = next(f"hedge_{i}" for i in range(200)
                if gw._ring.get_node(f"hedge_{i}") == "tr_w1")
     slow, fast = w1, w2
-    slow.inject_latency(0.4)
+    # Long enough that the hedge lane wins though its first request
+    # compiles the model under six loaded test workers (0.4 s lost once:
+    # PR 50's whole run, nothing wrong in the program).
+    slow.inject_latency(2.0)
     client = _client_ctx()
     try:
         resp = gw.route_request({
@@ -259,7 +262,7 @@ def test_hedged_route_trace_tree_and_export(lanes):
         slow.heal()
     assert resp["node_id"] == fast.node_id  # hedge lane answered
     # The primary attempt span records when its dispatch completes
-    # (~0.4 s after the hedge already won) — wait for both attempts and
+    # (~2 s after the hedge already won) — wait for both attempts and
     # the dispatch-thread observer spans before asserting on the tree.
     deadline = time.monotonic() + 5.0
     while time.monotonic() < deadline:

@@ -111,8 +111,32 @@ from tpu_engine.ops.ssd import (
 )
 
 
+class Mamba2Shapes:
+    """What a Mamba-2 mixer's sizes make of a config that states
+    `lin_heads`, `ssm_head_dim`, `d_state`, `n_groups` and `conv_width`
+    (this family, `models.nemotron_h`)."""
+
+    @property
+    def d_ssm(self) -> int:
+        return self.lin_heads * self.ssm_head_dim
+
+    @property
+    def conv_lanes(self) -> int:
+        """x, B and C side by side: what the conv runs over."""
+        return self.d_ssm + 2 * self.n_groups * self.d_state
+
+    @property
+    def state_row_shapes(self) -> Tuple[Tuple[int, ...], ...]:
+        """A row's state, a layer: S and the conv tail (float32), the tail
+        as 8 sublanes of whole lane tiles where it can be
+        (`models.kimi_linear` says what (width - 1, lanes) costs a tick)."""
+        tail = (self.conv_width - 1) * self.conv_lanes
+        return ((self.lin_heads, self.ssm_head_dim, self.d_state),
+                (8, tail // 8) if tail % 8 == 0 else (tail,))
+
+
 @dataclasses.dataclass(frozen=True)
-class FalconH1Config(TransformerConfig):
+class FalconH1Config(Mamba2Shapes, TransformerConfig):
     """The base fields this family fixes: rmsnorm, rope, swiglu."""
     lin_heads: int = 32                     # H: the recurrence's heads
     ssm_head_dim: int = 128                 # P
@@ -147,15 +171,6 @@ class FalconH1Config(TransformerConfig):
             raise ValueError("ssm_multipliers has five entries (z, x, B, C, "
                              "dt) and mlp_multipliers two (gate, down)")
 
-    @property
-    def d_ssm(self) -> int:
-        return self.lin_heads * self.ssm_head_dim
-
-    @property
-    def conv_lanes(self) -> int:
-        """x, B and C side by side: what the conv runs over."""
-        return self.d_ssm + 2 * self.n_groups * self.d_state
-
     # Every layer is of both kinds: each pool holds all of them.
     @property
     def n_linear_layers(self) -> int:
@@ -173,15 +188,6 @@ class FalconH1Config(TransformerConfig):
     def pool_layer(self) -> Tuple[int, ...]:
         """Layer l's index in either pool."""
         return tuple(range(self.n_layers))
-
-    @property
-    def state_row_shapes(self) -> Tuple[Tuple[int, ...], ...]:
-        """A row's state, a layer: S and the conv tail (float32), the tail
-        as 8 sublanes of whole lane tiles where it can be
-        (`models.kimi_linear` says what (width - 1, lanes) costs a tick)."""
-        tail = (self.conv_width - 1) * self.conv_lanes
-        return ((self.lin_heads, self.ssm_head_dim, self.d_state),
-                (8, tail // 8) if tail % 8 == 0 else (tail,))
 
     @property
     def mup_segments(self) -> Tuple[Tuple[int, float], ...]:
@@ -313,7 +319,7 @@ def _ssm_inputs(sp, u, cfg: FalconH1Config, dtype):
     return mixed, z, dt, dt
 
 
-def _ssm_conv(sp, ext, cfg: FalconH1Config):
+def _ssm_conv(sp, ext, cfg):
     """`models.olmo_hybrid._conv_heads` for this family. ext: (..., T +
     width - 1, lanes): a run's inputs behind its conv tail. The causal
     depthwise conv with its bias, SiLU, then C and B (..., T, g, N) and x
@@ -333,14 +339,17 @@ def _ssm_conv(sp, ext, cfg: FalconH1Config):
             x.reshape(x.shape[:-1] + (cfg.lin_heads, cfg.ssm_head_dim)))
 
 
-def _ssm_output(sp, o, z, cfg: FalconH1Config, dtype):
+def _gated_norm(sp, o, z, cfg):
     """o: (..., H, P) the heads' reads with the D x skip; z: (..., d_ssm)
-    the gate's input. Gated, then normalised a GROUP, to W_out."""
+    the gate's input. Gated, then normalised a GROUP: what W_out takes."""
     y = o.reshape(z.shape) * jax.nn.silu(z)
     y = y.reshape(z.shape[:-1] + (cfg.n_groups, -1))
     y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + cfg.ln_eps)
-    y = y.reshape(z.shape) * sp["norm"]["scale"]
-    y = nn.dense(sp["w_out"], y, dtype=dtype)
+    return y.reshape(z.shape) * sp["norm"]["scale"]
+
+
+def _ssm_output(sp, o, z, cfg: FalconH1Config, dtype):
+    y = nn.dense(sp["w_out"], _gated_norm(sp, o, z, cfg), dtype=dtype)
     return y * jnp.asarray(cfg.ssm_out_multiplier, y.dtype)
 
 
@@ -355,6 +364,18 @@ def _with_skip(sp, fn):
         return o + sp["D"][:, None] * x, pool
 
     return call
+
+
+def _ssm_whole_row(sp, u, cfg, dtype, inputs, output):
+    """The mixer over one whole sequence from an empty state, by the
+    chunked form. u: (S, d) normalised; `inputs`, `output`: a family's
+    `_ssm_inputs` and `_ssm_output`."""
+    mixed, z, dt, _ = inputs(sp, u, cfg, dtype)
+    c, b, x = _ssm_conv(
+        sp, jnp.pad(mixed, ((cfg.conv_width - 1, 0), (0, 0))), cfg)
+    y, _ = ssd_chunked(x[None], dt[None], -jnp.exp(sp["A_log"]), b[None],
+                       c[None], chunk=SUB_CHUNK)
+    return output(sp, y[0] + sp["D"][:, None] * x, z, cfg, dtype)
 
 
 def _ffn(mp, v, cfg: FalconH1Config, dtype):
@@ -404,15 +425,9 @@ def falcon_h1_apply(params, tokens, cfg: FalconH1Config, *,
     def one_row(bp, u):
         q, k, v = _attn_inputs(bp["attn"], u, positions, cfg, dtype)
         o = dot_product_attention(q[None], k[None], v[None], causal=True)[0]
-        sp = bp["ssm"]
-        mixed, z, dt, _ = _ssm_inputs(sp, u, cfg, dtype)
-        c, bb, x = _ssm_conv(
-            sp, jnp.pad(mixed, ((cfg.conv_width - 1, 0), (0, 0))), cfg)
-        y, _ = ssd_chunked(x[None], dt[None], -jnp.exp(sp["A_log"]),
-                           bb[None], c[None], chunk=SUB_CHUNK)
-        y = y[0] + sp["D"][:, None] * x
         return (_attn_output(bp["attn"], o, cfg, dtype),
-                _ssm_output(sp, y, z, cfg, dtype))
+                _ssm_whole_row(bp["ssm"], u, cfg, dtype, _ssm_inputs,
+                               _ssm_output))
 
     def mixers(layer, bp, u, carry):
         y_att, y_ssm = jax.vmap(lambda row: one_row(bp, row))(u)

@@ -44,8 +44,10 @@ from typing import Callable, Dict, List, Optional, Tuple
 # layers that attend, AND one row of a state pool (the recurrent mixers'
 # fixed-size state and conv tail), admitted, parked and released as one:
 # blocks for some layers and a state row for the others
-# (models.olmo_hybrid, models.kimi_linear), or both in EVERY layer, read
-# from the same normed rows and summed (models.falcon_h1). WHAT a block
+# (models.olmo_hybrid, models.kimi_linear), with layers that own neither
+# between them (models.nemotron_h: a layer is one mixer, and an expert
+# layer keeps no state), or both in EVERY layer, read from the same normed
+# rows and summed (models.falcon_h1). WHAT a block
 # holds is the model's to state (`cfg.kv_lanes`, through
 # `cfg.kv_block_kinds`) and no part of the family: K and V a head
 # (models.olmo_hybrid) or a latent and its shared key lanes
@@ -350,6 +352,7 @@ def _ensure_builtin_models_imported():
     from tpu_engine.models import mlp, resnet  # noqa: F401
 
     for optional in ("bert", "gpt2", "llama", "yolo", "ssd", "moonlight",
-                     "laguna", "olmo_hybrid", "kimi_linear", "falcon_h1"):
+                     "laguna", "olmo_hybrid", "kimi_linear", "falcon_h1",
+                     "nemotron_h"):
         if importlib.util.find_spec(f"tpu_engine.models.{optional}") is not None:
             importlib.import_module(f"tpu_engine.models.{optional}")

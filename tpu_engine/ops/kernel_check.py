@@ -7,7 +7,8 @@ head geometry of a registered model; for a latent-attention model
 (`LATENT_MODELS`) it is the latent read at both widths instead, and for
 one whose rows also own a recurrent state (`RECURRENT_MODELS`) both forms
 of its recurrence (`kda_step`, `kda_chunk`; `ssd_step`, `ssd_chunk`),
-against the scan.
+against the scan; `grouped_cases` the grouped product over a bank of
+two-matrix experts in a latent.
 `cell_cases` adds the ragged read at the shapes the benchmark's cells
 serve it at (`CELL_SHAPES`), `class_cases` the two calls a tick that
 carries a chunk makes of its rows, by the class of their runs
@@ -54,8 +55,11 @@ LATENT_MODELS = ("moonlight", "kimi_linear")
 # A state row beside the blocks: the step over a lane's rows and the
 # chunked form over a row's run, where the states lie. kimi_linear: the
 # delta rule with a gate a key channel's; falcon_h1: Mamba-2 (its paged read
-# at five query heads a KV head is a case of `CLASS_SHAPES`).
-RECURRENT_MODELS = ("kimi_linear", "falcon_h1")
+# at five query heads a KV head is a case of `CLASS_SHAPES`); nemotron_h:
+# Mamba-2 cut the other way, 128 heads of (64, 128) in 8 groups (its read at
+# sixteen query heads a KV head a case of `CLASS_SHAPES`, its experts'
+# two-matrix grouped product `grouped_cases`).
+RECURRENT_MODELS = ("kimi_linear", "falcon_h1", "nemotron_h")
 # Max |kernel - scan| accepted for the recurrence: float32 throughout, the
 # MXU's float32 passes.
 F32_TOLERANCE = 1e-3
@@ -165,6 +169,29 @@ CLASS_SHAPES = {
         n_blocks=6145, max_tokens=272,
         rows=((1, 300), (242, 700), (1, 1500), (0, 0))
         + tuple((1, 32 + 120 * r) for r in range(12))),
+    # agents (benchmarks/configs/nemotron-3-super-120b-a12b-11l.json): G =
+    # 16 over 2 KV heads, the largest group: a decode row packs 2 x 16 query
+    # rows in one score tile, a tall tile is 8 slots = one grid tile of 128
+    # query rows, so the 242-slot chunk is 31 tiles that each walk the row's
+    # 3 k columns. Under the cell's table of 9,216 columns and its pool (16
+    # of the lane's 64 rows, contexts to 3.2 k: the check's gather reference
+    # holds every row's scores at once).
+    "nemotron-3-super-120b-a12b-11l.agents/classes/W256": dict(
+        geo=dict(n_heads=32, n_kv_heads=2, d_head=128), table_len=576,
+        n_blocks=35841, max_tokens=320,
+        rows=((1, 300), (242, 3000), (1, 2900), (0, 0))
+        + tuple((1, 130 + 250 * r) for r in range(12))),
+}
+
+# The grouped product over a layer's bank of TWO-matrix experts in a latent
+# (`ops.moe.routed_experts`, the bank {"up", "down"}), as the agents cell's
+# tick calls it: 320 token slots of which 300 are valid, 22 of 512 experts a
+# token = 7,040 pairs of which the quarter routed to the 128 held experts
+# form rows, 1024 latent lanes, 2688 hidden.
+GROUPED_SHAPES = {
+    "nemotron_h/grouped/latent1024x2688": dict(
+        slots=320, valid=300, top_k=22, n_experts=512, held=(0, 128),
+        lanes=1024, hidden=2688),
 }
 
 
@@ -396,6 +423,63 @@ def _ssd_cases(model: str, interpret: bool):
                      chunk_operands, chunk_check)
 
 
+def grouped_cases():
+    """The served expert layer's grouped product over a bank of ungated
+    two-matrix experts (relu^2 between them) at every entry of
+    `GROUPED_SHAPES`, against every held expert applied to every token under
+    the router's mask in float32. No `interpret`: the product is XLA's own
+    kernel (`jax.lax.ragged_dot`)."""
+    from tpu_engine.ops import moe
+
+    for name, shape in GROUPED_SHAPES.items():
+        n, k, e = shape["slots"], shape["top_k"], shape["n_experts"]
+        held, lanes, hidden = shape["held"], shape["lanes"], shape["hidden"]
+
+        def operands(n=n, k=k, e=e, held=held, lanes=lanes, hidden=hidden,
+                     live=shape["valid"]):
+            ks = jax.random.split(jax.random.PRNGKey(n), 4)
+            x = jax.random.normal(ks[0], (n, lanes), jnp.bfloat16)
+            scores = jax.random.uniform(ks[1], (n, e))
+            chosen, experts = jax.lax.top_k(scores, k)
+            weights = chosen / chosen.sum(-1, keepdims=True) * 5.0
+            bank = {"up": jax.random.normal(
+                        ks[2], (held[1], lanes, hidden), jnp.bfloat16)
+                    * jnp.bfloat16(lanes ** -0.5),
+                    "down": jax.random.normal(
+                        ks[3], (held[1], hidden, lanes), jnp.bfloat16)
+                    * jnp.bfloat16((1.5 * hidden) ** -0.5)}
+            return (x, jnp.arange(n) < live, experts.astype(jnp.int32),
+                    weights, bank)
+
+        kernel = functools.partial(
+            moe.routed_experts, first_group=-held[0], n_experts=e, held=held,
+            max_tokens=n, activation=moe.relu2)
+
+        def check(out, operands, e=e, held=held):
+            (y, rows), (x, valid, experts, weights, bank) = out, operands
+            gates = jnp.zeros((x.shape[0], e)).at[
+                jnp.arange(x.shape[0])[:, None], experts].set(weights)
+            gates = jnp.where(valid[:, None], gates, 0.0)
+            x = x.astype(jnp.float32)
+
+            def one(want, i):
+                up, down = (jax.lax.dynamic_index_in_dim(
+                    bank[m], i, keepdims=False).astype(jnp.float32)
+                    for m in ("up", "down"))
+                mine = jax.lax.dynamic_index_in_dim(gates, held[0] + i, 1)
+                return want + mine * (moe.relu2(x @ up) @ down), None
+
+            with jax.default_matmul_precision("highest"):
+                want, _ = jax.lax.scan(one, jnp.zeros_like(x),
+                                       jnp.arange(held[1]))
+            taken = (gates > 0).sum(0)[held[0]:held[0] + held[1]]
+            if not bool((rows[held[0]:held[0] + held[1]] == taken).all()):
+                return float("nan")
+            return float(jnp.abs(y - want).max())
+
+        yield KernelCase(name, kernel, operands, check)
+
+
 def cell_cases(interpret: bool = False):
     """The ragged read at every entry of `CELL_SHAPES`."""
     for name, shape in CELL_SHAPES.items():
@@ -529,7 +613,7 @@ def main() -> int:
     for case in itertools.chain(
             *(kernel_cases(model) for model in dict.fromkeys(
                 MODELS + LATENT_MODELS + RECURRENT_MODELS)),
-            cell_cases(), class_cases(), walk_cases()):
+            cell_cases(), class_cases(), walk_cases(), grouped_cases()):
         t0 = time.monotonic()
         operands = case.operands()
         if case.check is None:

@@ -31,7 +31,8 @@ sorted by expert (a stable sort, so a token's own pairs keep their
 order whatever its tick-mates), one grouped matrix product over the
 experts held (`jax.lax.ragged_dot`: on a TPU XLA lowers it to a Mosaic
 grouped matmul that visits only the row tiles and expert banks in
-use), SwiGLU experts, weighted scatter-add back. Padding slots form no
+use), SwiGLU experts or ungated ones of two matrices (the bank's keys
+say which), weighted scatter-add back. Padding slots form no
 pair, reach no expert and count in no load. The bank is handed over
 WHOLE, all layers' experts on one leading axis, with the layer's
 offset into it: a slice of a layer's bank would be copied for the
@@ -188,14 +189,23 @@ def sigmoid_topk_route(x, router, top_k: int, scale: float):
     return experts.astype(jnp.int32), weights
 
 
+def relu2(x):
+    """relu(x)^2: the nonlinearity of an ungated two-matrix expert."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def routed_experts(x, valid, experts, weights, bank, *, first_group,
                    n_experts: int, held=None, max_tokens=None,
-                   dtype=jnp.bfloat16):
+                   dtype=jnp.bfloat16, activation=None):
     """Apply each valid token's chosen experts and sum them, weighted.
 
-    x: (N, d); valid: (N,) bool, padding slots False; experts, weights:
-    (N, k) from the router; bank: {"gate_up" (G, d, 2f), "down" (G, f, d)}
-    with G >= n_experts groups, this layer's expert e at group
+    x: (N, d), d the width the experts read (the model's, or a latent's
+    the caller projected to: `y` comes back as wide); valid: (N,) bool,
+    padding slots False; experts, weights: (N, k) from the router. The
+    bank states its expert by its keys: {"gate_up" (G, d, 2f), "down"
+    (G, f, d)} a SwiGLU, or {"up" (G, d, f), "down" (G, f, d)} an ungated
+    expert of two matrices whose nonlinearity is `activation` (f32 -> f32).
+    G >= n_experts groups, this layer's expert e at group
     `first_group + e` (a traced scalar: the bank of every layer, whole).
     `held` = (first, count): the experts this shard holds (default all);
     a pair whose expert lies outside is another shard's and forms no row
@@ -214,15 +224,20 @@ def routed_experts(x, valid, experts, weights, bank, *, first_group,
     eid_sorted = eid[order]
     token = order // k
     rows = jnp.zeros((n_experts + 1,), jnp.int32).at[eid].add(1)[:n_experts]
-    groups = bank["gate_up"].shape[0]
+    gated = "gate_up" in bank
+    first_matrix = bank["gate_up" if gated else "up"]
     sizes = jax.lax.dynamic_update_slice(
-        jnp.zeros((groups,), jnp.int32), rows[first:first + count],
-        (first_group + first,))
+        jnp.zeros((first_matrix.shape[0],), jnp.int32),
+        rows[first:first + count], (first_group + first,))
     xs = x[token].astype(dtype)
-    gate_up = jax.lax.ragged_dot(xs, bank["gate_up"].astype(dtype), sizes,
-                                 preferred_element_type=jnp.float32)
-    gate, up = jnp.split(gate_up, 2, axis=-1)
-    hidden = (jax.nn.silu(gate) * up).astype(dtype)
+    hidden = jax.lax.ragged_dot(xs, first_matrix.astype(dtype), sizes,
+                                preferred_element_type=jnp.float32)
+    if gated:
+        gate, up = jnp.split(hidden, 2, axis=-1)
+        hidden = jax.nn.silu(gate) * up
+    else:
+        hidden = activation(hidden)
+    hidden = hidden.astype(dtype)
     out = jax.lax.ragged_dot(hidden, bank["down"].astype(dtype), sizes,
                              preferred_element_type=jnp.float32)
     # Rows past the last group hold whatever the kernel left there.
