@@ -104,17 +104,52 @@ def test_the_recurrence_compiles_for_v5e_at_128_heads_of_64_by_128(
         assert _pallas_grids(jaxpr.jaxpr) == [grid]
 
 
-def test_the_two_matrix_grouped_product_compiles_for_v5e(v5e_devices):
-    """`ops.moe.routed_experts` over a bank of ungated two-matrix experts in
-    a 1024-lane latent, 128 held of 512, 7,040 pairs a tick
-    (`kernel_check.GROUPED_SHAPES`): two `ragged_dot`s, no gate's split."""
-    (case,) = kernel_check.grouped_cases()
-    kernel_check.compile_for_topology(case, v5e_devices[0])
-    jaxpr = jax.make_jaxpr(case.kernel)(*jax.eval_shape(case.operands))
-    products = [e for e in jaxpr.jaxpr.eqns
-                if e.primitive.name == "ragged_dot_general"]
-    assert [tuple(e.outvars[0].aval.shape) for e in products] == [
-        (7040, 2688), (7040, 1024)]
+@pytest.mark.parametrize("name", list(kernel_check.GROUPED_SHAPES))
+def test_the_grouped_product_states_its_tiles_for_v5e(v5e_devices, name):
+    """`ops.moe.routed_experts` over the four banks the cells hold, each at
+    its decode-only and its chunk-tick list length
+    (`kernel_check.GROUPED_SHAPES`), compiled for one v5e: both products
+    are still XLA's grouped kernel (`ragged-dot-none` behind one
+    `ragged-dot-metadata`), each carries the tiles `grouped_tiling` states
+    for its shapes, the pair list is a whole number of row tiles, and
+    nothing of a bank's size is copied or sliced on the way in."""
+    from tpu_engine.ops import moe
+
+    shape = kernel_check.GROUPED_SHAPES[name]
+    (case,) = [c for c in kernel_check.grouped_cases() if c.name == name]
+    hlo = kernel_check.compile_for_topology(case, v5e_devices[0]).as_text()
+    lanes, hidden = shape["lanes"], shape["hidden"]
+    pairs = shape["slots"] * shape["top_k"]
+    tile = moe.row_tile(pairs, 2)
+    pairs = -(-pairs // tile) * tile
+    want = [(pairs, lanes, hidden * (1 + shape["gated"])),
+            (pairs, hidden, lanes)]
+    stated = {}
+    for line in hlo.splitlines():
+        found = re.match(r"\s*%ragged-dot-none[.\d]* = f32\[([\d,]+)\]"
+                         r".* custom-call\(", line)
+        if found:
+            (tiles,) = re.findall(r'ragged_dot_tiling="([\d,]+)"', line)
+            stated[tuple(map(int, found[1].split(",")))] = tuple(
+                map(int, tiles.split(",")))
+        else:
+            assert "ragged_dot_tiling" not in line, line[:200]
+    assert len(re.findall(r"%ragged-dot-metadata[.\d]* = ", hlo)) == 1
+    assert stated == {(m, n): moe.grouped_tiling(m, k, n, 2)
+                      for m, k, n in want}
+    bank = jax.eval_shape(case.operands)[-1]
+    sizes = {math.prod(x.shape) for x in jax.tree.leaves(bank)}
+    assert not _moved(hlo, sizes)
+
+
+def _moved(hlo, sizes):
+    """The (op, dims) of every `copy`, `slice`, `dynamic-slice` and
+    `dynamic-update-slice` of a compiled module whose result has one of
+    `sizes` elements."""
+    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
+                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
+    return [(op, dims) for dims, op in movers.findall(hlo)
+            if math.prod(map(int, dims.split(","))) in sizes]
 
 
 def _pallas_calls(jaxpr):
@@ -547,11 +582,7 @@ def test_latent_mixed_step_copies_neither_the_pool_nor_a_bank(v5e_devices,
     sizes = set()
     for x in list(pool) + banks:
         sizes |= {math.prod(x.shape), math.prod(x.shape[1:])}
-    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
-                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
-    moved = [(op, dims) for dims, op in movers.findall(hlo)
-             if math.prod(map(int, dims.split(","))) in sizes]
-    assert not moved, moved
+    assert not _moved(hlo, sizes)
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
 
 
@@ -624,11 +655,7 @@ def test_windowed_mixed_step_copies_no_pool_and_no_bank(v5e_devices, width):
     sizes = {math.prod(x.shape) for x in banks}
     for x in pools:
         sizes |= {math.prod(x.k.shape), math.prod(x.k.shape[1:])}
-    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
-                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
-    moved = [(op, dims) for dims, op in movers.findall(hlo)
-             if math.prod(map(int, dims.split(","))) in sizes]
-    assert not moved, moved
+    assert not _moved(hlo, sizes)
     assert compiled.memory_analysis().temp_size_in_bytes < 100e6
 
 

@@ -222,6 +222,98 @@ def test_routed_experts_equal_every_expert_applied_and_masked(held,
     assert np.array_equal(np.asarray(rows), np.bincount(taken, minlength=e))
 
 
+# The grouped products of the benchmark's four expert cells, (pairs, K, N) in
+# bfloat16 with the contraction the rule keeps whole there, and shapes no
+# cell has: a short list, wide banks, float32.
+_TILED_SHAPES = [
+    (256, 2048, 2816, 2, 2048), (1792, 2048, 2816, 2, 2048),
+    (1792, 1408, 2048, 2, 1408), (1024, 2304, 2048, 2, 2304),
+    (3072, 1024, 2304, 2, 1024), (7040, 1024, 2688, 2, 1024),
+    (7040, 2688, 1024, 2, 2688), (2944, 3072, 2048, 2, 3072),
+    (384, 1024, 3072, 2, 1024), (48, 128, 256, 2, 128),
+    (112, 4096, 14336, 2, 4096), (4096, 14336, 4096, 2, 2048),
+    (512, 8192, 8192, 4, 4096), (104, 4096, 14336, 4, 4096),
+    (1792, 2048, 2816, 4, 2048)]
+
+
+@pytest.mark.parametrize("m, k, n, itemsize, tk_wanted", _TILED_SHAPES)
+def test_grouped_tiling_states_tiles_the_kernel_can_take(m, k, n, itemsize,
+                                                         tk_wanted):
+    tm, tk, tn = moe.grouped_tiling(m, k, n, itemsize)
+    assert tm == moe.row_tile(m, itemsize) <= 128 and m % tm == 0
+    assert tm % (32 // itemsize) == 0
+    assert k % tk == 0 and tk % 128 == 0 and tk == tk_wanted
+    assert n % tn == 0 and tn % 128 == 0
+    blocks = 2 * (tk * tn * itemsize + tm * tk * itemsize + tm * tn * 4)
+    blocks += tm * tn * 4 * (tk < k)
+    assert blocks == moe._block_bytes(tm, tk, tn, k, itemsize)
+    assert blocks <= moe._VMEM_BLOCK_BYTES == 14 * 2 ** 20
+    # No wider output tile fits beside this contraction tile.
+    wider = [t for t in range(tn + 128, n + 1, 128) if n % t == 0]
+    assert all(moe._block_bytes(tm, tk, t, k, itemsize)
+               > moe._VMEM_BLOCK_BYTES for t in wider)
+    assert tk * tn * itemsize >= 2 ** 20 or (tk, tn) == (k, n)
+
+
+@pytest.mark.parametrize("m, k, n, itemsize", [
+    (1792, 2048, 2800, 2),      # N no multiple of 128
+    (1792, 1400, 2048, 2),      # K no multiple of 128
+    (48, 32, 32, 4),            # the test models' widths
+    (1728, 2048, 2816, 2),      # a list that is no whole number of tiles
+    (100, 2048, 2816, 2)])
+def test_grouped_tiling_states_none_where_no_tiling_is_legal(m, k, n,
+                                                            itemsize):
+    assert moe.grouped_tiling(m, k, n, itemsize) is None
+
+
+@pytest.mark.parametrize("n, max_tokens, pairs", [
+    (25, None, 56),             # 50 slots' pairs and 6 that pad the list
+    (25, 17, 40),               # 34 pairs rounded up inside the slots' 50
+    (70, None, 256)])           # two row tiles of 128 for 140 pairs
+def test_the_pair_list_is_whole_row_tiles_and_its_padding_forms_no_row(
+        n, max_tokens, pairs):
+    x, router, bank, e, k, f = _moe_case(n=n)
+    valid = np.arange(n) % 3 != 0
+    if max_tokens:
+        valid &= np.arange(n) < 3 * max_tokens // 2     # <= max_tokens valid
+    experts, weights = moe.sigmoid_topk_route(x, router, k, 2.5)
+    y, rows = jax.jit(lambda *a: moe.routed_experts(
+        *a, first_group=jnp.int32(e), n_experts=e, max_tokens=max_tokens,
+        dtype=jnp.float32))(x, jnp.asarray(valid), experts, weights, bank)
+    tilings = moe.traced_tilings()
+    assert tilings[f"{pairs}x32x32"] == tilings[f"{pairs}x16x32"] == "xla"
+    assert pairs % moe.row_tile(pairs, 4) == 0
+    want = _dense_experts(x, valid, np.asarray(experts), np.asarray(weights),
+                          bank, e, f)
+    assert np.abs(np.asarray(y) - want).max() < 1e-5
+    assert not np.asarray(y)[~valid].any()
+    taken = np.asarray(experts)[valid].reshape(-1)
+    assert np.array_equal(np.asarray(rows), np.bincount(taken, minlength=e))
+    assert int(rows.sum()) == valid.sum() * k
+
+
+def test_stated_tiles_change_no_bit_off_the_tpu(monkeypatch):
+    """At widths the rule states tiles for, the attribute rides the op and
+    the CPU's product is the one XLA's own pick gives."""
+    x, router, bank, e, k, f = _moe_case(n=40, d=128, f=128)
+    valid = jnp.arange(40) % 5 != 0
+    experts, weights = moe.sigmoid_topk_route(x, router, k, 2.5)
+
+    def run():
+        return jax.jit(lambda *a: moe.routed_experts(
+            *a, first_group=jnp.int32(0), n_experts=e, dtype=jnp.float32))(
+            x, valid, experts, weights, bank)
+
+    stated = run()
+    assert moe.traced_tilings()["80x128x256"] == "80,128,256"
+    assert moe.traced_tilings()["80x128x128"] == "80,128,128"
+    monkeypatch.setattr(moe, "grouped_tiling", lambda *shape: None)
+    plain = run()
+    assert moe.traced_tilings()["80x128x256"] == "xla"
+    for a, b in zip(stated, plain):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_the_selection_bias_chooses_and_does_not_weigh():
     x, router, _, e, k, _ = _moe_case()
     experts, weights = moe.sigmoid_topk_route(x, router, k, 2.446)
@@ -331,6 +423,18 @@ def test_the_mixed_tick_serves_it_and_counts_its_experts(spec, params):
         == counted["experts_touched"]
     assert all("ctx_tokens" in s["attrs"] for s in spans)
     assert "kv_pool" in stats
+    # Both products of every list length the lane traced, by their shapes
+    # (the record is the process's: other tests' shapes lie beside them).
+    widths = {(cfg.d_model, 2 * cfg.d_ff_expert),
+              (cfg.d_ff_expert, cfg.d_model)}
+    mine = {}
+    for shape, tiles in counted["tilings"].items():
+        m, k, n = map(int, shape.split("x"))
+        if (k, n) in widths:
+            mine.setdefault(m, set()).add((k, n))
+            assert m % moe.row_tile(m, 4) == 0
+            assert tiles == "xla" and moe.grouped_tiling(m, k, n, 4) is None
+    assert mine and all(both == widths for both in mine.values())
 
 
 @pytest.mark.parametrize("kwargs, error, message", [

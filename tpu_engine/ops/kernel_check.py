@@ -7,8 +7,8 @@ head geometry of a registered model; for a latent-attention model
 (`LATENT_MODELS`) it is the latent read at both widths instead, and for
 one whose rows also own a recurrent state (`RECURRENT_MODELS`) both forms
 of its recurrence (`kda_step`, `kda_chunk`; `ssd_step`, `ssd_chunk`),
-against the scan; `grouped_cases` the grouped product over a bank of
-two-matrix experts in a latent.
+against the scan; `grouped_cases` the routed experts' grouped product
+over the four banks the benchmark's cells hold (`GROUPED_SHAPES`).
 `cell_cases` adds the ragged read at the shapes the benchmark's cells
 serve it at (`CELL_SHAPES`), `class_cases` the two calls a tick that
 carries a chunk makes of its rows, by the class of their runs
@@ -183,15 +183,38 @@ CLASS_SHAPES = {
         + tuple((1, 130 + 250 * r) for r in range(12))),
 }
 
-# The grouped product over a layer's bank of TWO-matrix experts in a latent
-# (`ops.moe.routed_experts`, the bank {"up", "down"}), as the agents cell's
-# tick calls it: 320 token slots of which 300 are valid, 22 of 512 experts a
-# token = 7,040 pairs of which the quarter routed to the 128 held experts
-# form rows, 1024 latent lanes, 2688 hidden.
+# The grouped product of the served expert layers (`ops.moe.routed_experts`)
+# at the four banks the benchmark's cells hold, each at its lane's two list
+# lengths: a decode-only tick (the slots alone) and a tick that carries a
+# chunk of 256 prompt tokens. `slots` x `top_k` pairs, of which the share
+# routed to the `held` experts form rows. `gated`: a SwiGLU bank {"gate_up"
+# (G, lanes, 2 hidden), "down"}; else two matrices {"up", "down"} with
+# relu^2 between them (the agents cell's, in a 1024-lane latent).
 GROUPED_SHAPES = {
-    "nemotron_h/grouped/latent1024x2688": dict(
+    "moonlight/grouped/2048x1408/decode": dict(
+        slots=32, valid=32, top_k=6, n_experts=64, held=(0, 64),
+        lanes=2048, hidden=1408, gated=True),
+    "moonlight/grouped/2048x1408/chunk": dict(
+        slots=288, valid=280, top_k=6, n_experts=64, held=(0, 64),
+        lanes=2048, hidden=1408, gated=True),
+    "laguna/grouped/3072x1024/decode": dict(
+        slots=32, valid=32, top_k=10, n_experts=256, held=(0, 128),
+        lanes=3072, hidden=1024, gated=True),
+    "laguna/grouped/3072x1024/chunk": dict(
+        slots=288, valid=280, top_k=10, n_experts=256, held=(0, 128),
+        lanes=3072, hidden=1024, gated=True),
+    "kimi_linear/grouped/2304x1024/decode": dict(
+        slots=128, valid=128, top_k=8, n_experts=256, held=(0, 128),
+        lanes=2304, hidden=1024, gated=True),
+    "kimi_linear/grouped/2304x1024/chunk": dict(
+        slots=384, valid=370, top_k=8, n_experts=256, held=(0, 128),
+        lanes=2304, hidden=1024, gated=True),
+    "nemotron_h/grouped/latent1024x2688/decode": dict(
+        slots=64, valid=64, top_k=22, n_experts=512, held=(0, 128),
+        lanes=1024, hidden=2688, gated=False),
+    "nemotron_h/grouped/latent1024x2688/chunk": dict(
         slots=320, valid=300, top_k=22, n_experts=512, held=(0, 128),
-        lanes=1024, hidden=2688),
+        lanes=1024, hidden=2688, gated=False),
 }
 
 
@@ -424,27 +447,29 @@ def _ssd_cases(model: str, interpret: bool):
 
 
 def grouped_cases():
-    """The served expert layer's grouped product over a bank of ungated
-    two-matrix experts (relu^2 between them) at every entry of
+    """The served expert layer's grouped product at every entry of
     `GROUPED_SHAPES`, against every held expert applied to every token under
     the router's mask in float32. No `interpret`: the product is XLA's own
-    kernel (`jax.lax.ragged_dot`)."""
+    kernel (`jax.lax.ragged_dot`) under the tiles `ops.moe.grouped_tiling`
+    states."""
     from tpu_engine.ops import moe
 
     for name, shape in GROUPED_SHAPES.items():
         n, k, e = shape["slots"], shape["top_k"], shape["n_experts"]
         held, lanes, hidden = shape["held"], shape["lanes"], shape["hidden"]
+        gated = shape["gated"]
+        first = "gate_up" if gated else "up"
 
         def operands(n=n, k=k, e=e, held=held, lanes=lanes, hidden=hidden,
-                     live=shape["valid"]):
+                     live=shape["valid"], gated=gated, first=first):
             ks = jax.random.split(jax.random.PRNGKey(n), 4)
             x = jax.random.normal(ks[0], (n, lanes), jnp.bfloat16)
             scores = jax.random.uniform(ks[1], (n, e))
             chosen, experts = jax.lax.top_k(scores, k)
             weights = chosen / chosen.sum(-1, keepdims=True) * 5.0
-            bank = {"up": jax.random.normal(
-                        ks[2], (held[1], lanes, hidden), jnp.bfloat16)
-                    * jnp.bfloat16(lanes ** -0.5),
+            bank = {first: jax.random.normal(
+                        ks[2], (held[1], lanes, hidden * (1 + gated)),
+                        jnp.bfloat16) * jnp.bfloat16(lanes ** -0.5),
                     "down": jax.random.normal(
                         ks[3], (held[1], hidden, lanes), jnp.bfloat16)
                     * jnp.bfloat16((1.5 * hidden) ** -0.5)}
@@ -453,9 +478,9 @@ def grouped_cases():
 
         kernel = functools.partial(
             moe.routed_experts, first_group=-held[0], n_experts=e, held=held,
-            max_tokens=n, activation=moe.relu2)
+            max_tokens=n, activation=None if gated else moe.relu2)
 
-        def check(out, operands, e=e, held=held):
+        def check(out, operands, e=e, held=held, gated=gated, first=first):
             (y, rows), (x, valid, experts, weights, bank) = out, operands
             gates = jnp.zeros((x.shape[0], e)).at[
                 jnp.arange(x.shape[0])[:, None], experts].set(weights)
@@ -465,9 +490,15 @@ def grouped_cases():
             def one(want, i):
                 up, down = (jax.lax.dynamic_index_in_dim(
                     bank[m], i, keepdims=False).astype(jnp.float32)
-                    for m in ("up", "down"))
+                    for m in (first, "down"))
                 mine = jax.lax.dynamic_index_in_dim(gates, held[0] + i, 1)
-                return want + mine * (moe.relu2(x @ up) @ down), None
+                hid = x @ up
+                if gated:
+                    gate, lin = jnp.split(hid, 2, axis=-1)
+                    hid = jax.nn.silu(gate) * lin
+                else:
+                    hid = moe.relu2(hid)
+                return want + mine * (hid @ down), None
 
             with jax.default_matmul_precision("highest"):
                 want, _ = jax.lax.scan(one, jnp.zeros_like(x),
@@ -586,18 +617,19 @@ def kernel_cases(model: str, interpret: bool = False):
     yield from _paged_cases(model, geo, interpret)
 
 
-def compile_for_topology(case: KernelCase, device) -> None:
+def compile_for_topology(case: KernelCase, device):
     """AOT-compile `case` for `device` of a
     `jax.experimental.topologies.get_topology_desc(platform="tpu", ...)`
     description: lowers through the Pallas TPU rules and runs Mosaic
-    with no chip attached. Raises what the compiler raises."""
+    with no chip attached. Raises what the compiler raises; returns the
+    compiled program."""
     from jax.sharding import SingleDeviceSharding
 
     sharding = SingleDeviceSharding(device)
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
         jax.eval_shape(case.operands))
-    jax.jit(case.kernel).lower(*shapes).compile()
+    return jax.jit(case.kernel).lower(*shapes).compile()
 
 
 def main() -> int:

@@ -30,21 +30,31 @@ scaled (`sigmoid_topk_route`), the tick's VALID (token, expert) pairs
 sorted by expert (a stable sort, so a token's own pairs keep their
 order whatever its tick-mates), one grouped matrix product over the
 experts held (`jax.lax.ragged_dot`: on a TPU XLA lowers it to a Mosaic
-grouped matmul that visits only the row tiles and expert banks in
-use), SwiGLU experts or ungated ones of two matrices (the bank's keys
-say which), weighted scatter-add back. Padding slots form no
-pair, reach no expert and count in no load. The bank is handed over
-WHOLE, all layers' experts on one leading axis, with the layer's
-offset into it: a slice of a layer's bank would be copied for the
-kernel's operand, 1.2 GB a layer and tick at Moonlight's widths.
+grouped matmul whose steps are, for each output tile, the (row tile,
+expert) pairs that MEET, each over its contraction tiles: a visit
+streams the expert's tk x tn weight pieces against one tile of rows, so
+an expert whose rows straddle two row tiles is streamed twice, and rows
+past the last expert are never visited), SwiGLU experts or ungated ones
+of two matrices (the bank's keys say which), weighted scatter-add back.
+The tiles are stated HERE from the operands' shapes (`grouped_tiling`,
+the op's `ragged_dot_tiling` attribute) in place of XLA's pick, the
+largest power of two up to 512 that divides each dimension: that pick
+streams a bank of 2816, 1408, 2304 or 2688 lanes in pieces of 128-256 KB
+and multiplies a 512-row tile for the few rows an expert took. Padding
+slots form no pair, reach no expert and count in no load. The bank is
+handed over WHOLE, all layers' experts on one leading axis, with the
+layer's offset into it: a slice of a layer's bank would be copied for
+the kernel's operand, 1.2 GB a layer and tick at Moonlight's widths.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from tpu_engine.ops import nn
 
@@ -194,6 +204,86 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+# -- the grouped product's tiles ------------------------------------------------
+
+_LANES = 128
+# Rows a tile. Measured on a v5e at the cells' shapes (PERF.md section 6,
+# PR 51): 64, 128 and 192 rows give the same call within 2 %, 256 is 3-8 %
+# slower (a visit's product, 2 * tm * tk * tn FLOPs at 197 TF/s, passes its
+# weight piece's DMA at 819 GB/s from ~240 rows), and 128 leaves the VMEM for
+# the whole contraction of every bank served today.
+_ROW_TILE = 128
+# A weight piece (tk x tn) of at least this much keeps a step's fixed cost
+# small beside its DMA.
+_PIECE_BYTES = 2 ** 20
+# What the Mosaic grouped matmul may hold in its scoped VMEM (16 MiB on a
+# v5e): compiled for a v5e with no chip attached, blocks counted as
+# `_block_bytes` counts them pass up to 14.8 MiB and fail from 15.2.
+_VMEM_BLOCK_BYTES = 14 * 2 ** 20
+
+# "<m>x<K>x<N>" -> "tm,tk,tn" ("xla": none stated): the tiling each grouped
+# product was traced with, once a shape (`traced_tilings`).
+_TRACED = {}
+
+
+def row_tile(m: int, itemsize: int) -> int:
+    """The row tile of a pair list of m rows: `_ROW_TILE`, or the whole of
+    a shorter list rounded up to the dtype's sublane packing."""
+    packing = 32 // itemsize
+    return min(_ROW_TILE, -(-m // packing) * packing)
+
+
+def _block_bytes(tm: int, tk: int, tn: int, k: int, itemsize: int) -> int:
+    """The kernel's VMEM blocks: weights, x and the float32 output double
+    buffered, and the accumulator a split contraction adds."""
+    return (2 * tk * tn * itemsize + 2 * tm * tk * itemsize
+            + 2 * tm * tn * 4 + (tm * tn * 4 if tk < k else 0))
+
+
+def _lane_divisors(d: int):
+    """The multiples of 128 that divide d, widest first."""
+    return [t for t in range(d, 0, -_LANES) if d % t == 0]
+
+
+def grouped_tiling(m: int, k: int, n: int, itemsize: int):
+    """The (row, contraction, output) tiles of a grouped product x (m, k) @
+    bank (G, k, n) -> float32, from the shapes alone, or None where none is
+    legal (XLA then picks, from the dimensions' power-of-two divisors). tk
+    and tn are multiples of 128 that divide k and n; tm divides m
+    (`row_tile`: the caller rounds its list up to it). Of the tiles whose
+    blocks fit (`_VMEM_BLOCK_BYTES`): a weight piece of `_PIECE_BYTES` or
+    more, then the whole contraction (no accumulator pass, x fetched once a
+    row tile), then the larger piece."""
+    tm = row_tile(m, itemsize)
+    if m % tm or k % _LANES or n % _LANES:
+        return None
+    fits = [(tk, tn) for tk in _lane_divisors(k) for tn in _lane_divisors(n)
+            if _block_bytes(tm, tk, tn, k, itemsize) <= _VMEM_BLOCK_BYTES]
+    tk, tn = max(fits, key=lambda t: (          # 128 x 128 always fits
+        t[0] * t[1] * itemsize >= _PIECE_BYTES, t[0] == k, t[0] * t[1]))
+    return tm, tk, tn
+
+
+def traced_tilings() -> dict:
+    """{"<m>x<K>x<N>": "tm,tk,tn" or "xla"} of every grouped product this
+    process has traced."""
+    return dict(_TRACED)
+
+
+def grouped_dot(x, bank, sizes):
+    """`jax.lax.ragged_dot` (float32 out) with the tiling `grouped_tiling`
+    states for its shapes as the op's `ragged_dot_tiling` attribute, which
+    XLA's TPU lowering follows; no attribute where it states none."""
+    (m, k), n = x.shape, bank.shape[-1]
+    tiling = grouped_tiling(m, k, n, x.dtype.itemsize)
+    stated = "xla" if tiling is None else ",".join(map(str, tiling))
+    _TRACED[f"{m}x{k}x{n}"] = stated
+    with (contextlib.nullcontext() if tiling is None
+          else set_xla_metadata(ragged_dot_tiling=stated)):
+        return jax.lax.ragged_dot(x, bank, sizes,
+                                  preferred_element_type=jnp.float32)
+
+
 def routed_experts(x, valid, experts, weights, bank, *, first_group,
                    n_experts: int, held=None, max_tokens=None,
                    dtype=jnp.bfloat16, activation=None):
@@ -212,16 +302,24 @@ def routed_experts(x, valid, experts, weights, bank, *, first_group,
     here, and only the held experts' groups, [first_group + first,
     first_group + first + count), need exist in the bank. `max_tokens`: a static bound on how many slots can be valid
     (the scheduler's token budget plus its rows); it sizes the sorted
-    pair list, so padding costs no gather either. Returns (y (N, d)
-    float32, rows (n_experts,) int32: the rows each expert took)."""
+    pair list, so padding costs no gather either; the list is a whole
+    number of row tiles (`row_tile`), the pairs that round it up dead ones.
+    Returns (y (N, d) float32, rows (n_experts,) int32: the rows each
+    expert took)."""
     n, k = experts.shape
     first, count = held or (0, n_experts)
     mine = (valid[:, None] & (experts >= first) & (experts < first + count))
     # Pairs that form no row sort behind every expert.
     eid = jnp.where(mine, experts, n_experts).reshape(-1)
     pairs = min(n, max_tokens or n) * k
+    tile = row_tile(pairs, jnp.dtype(dtype).itemsize)
+    pairs = -(-pairs // tile) * tile
+    # Past the slots the list is padded with pairs that form no row.
+    eid = jnp.pad(eid, (0, max(0, pairs - n * k)), constant_values=n_experts)
     order = jnp.argsort(eid, stable=True)[:pairs]
     eid_sorted = eid[order]
+    # A padding pair reads the last slot's row and weight; `live` masks it.
+    order = jnp.minimum(order, n * k - 1)
     token = order // k
     rows = jnp.zeros((n_experts + 1,), jnp.int32).at[eid].add(1)[:n_experts]
     gated = "gate_up" in bank
@@ -230,16 +328,14 @@ def routed_experts(x, valid, experts, weights, bank, *, first_group,
         jnp.zeros((first_matrix.shape[0],), jnp.int32),
         rows[first:first + count], (first_group + first,))
     xs = x[token].astype(dtype)
-    hidden = jax.lax.ragged_dot(xs, first_matrix.astype(dtype), sizes,
-                                preferred_element_type=jnp.float32)
+    hidden = grouped_dot(xs, first_matrix.astype(dtype), sizes)
     if gated:
         gate, up = jnp.split(hidden, 2, axis=-1)
         hidden = jax.nn.silu(gate) * up
     else:
         hidden = activation(hidden)
     hidden = hidden.astype(dtype)
-    out = jax.lax.ragged_dot(hidden, bank["down"].astype(dtype), sizes,
-                             preferred_element_type=jnp.float32)
+    out = grouped_dot(hidden, bank["down"].astype(dtype), sizes)
     # Rows past the last group hold whatever the kernel left there.
     live = (eid_sorted < n_experts)[:, None]
     out = jnp.where(live, out * weights.reshape(-1)[order][:, None], 0.0)

@@ -73,6 +73,7 @@ from tpu_engine.models.transformer import (
 )
 from tpu_engine.ops.attention import KVCache
 from tpu_engine.ops.latent_attention import class_counts
+from tpu_engine.ops.moe import traced_tilings
 from tpu_engine.ops.paged_attention import walk_counts
 from tpu_engine.runtime.generator import (
     _DTYPES,
@@ -2447,8 +2448,11 @@ class ContinuousGenerator:
             # Gated additive block (lanes whose step routes experts).
             # `experts_touched`: (layer, expert) pairs that took at least
             # one row, summed over ticks.
+            # `tilings`: the tiles each grouped product was traced with,
+            # "<pairs>x<K>x<N>" -> "tm,tk,tn" ("xla": none stated).
             out["moe"] = dict(self._stats["moe"],
-                              rows_by_expert=self._moe_rows.tolist())
+                              rows_by_expert=self._moe_rows.tolist(),
+                              tilings=traced_tilings())
         if self._spec:
             spec = dict(self._stats["spec"])
             spec["accept_ratio"] = (

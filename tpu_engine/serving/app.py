@@ -939,6 +939,13 @@ def _print_runtime_banner(workers, front: str) -> None:
     for path, impl in impls.items():
         print(f"  attention {path}: {impl}"
               + (f" interpret={interpret}" if impl == "pallas" else ""))
+    for w in workers:
+        # The tiles each grouped expert product was traced with so far
+        # (warm-up's shapes): "xla" where the rule stated none.
+        gen = getattr(w, "generator", None)
+        stats = gen.stats() if gen is not None else {}
+        for shape, tiles in stats.get("moe", {}).get("tilings", {}).items():
+            print(f"  lane {w.node_id} expert tiles {shape}: {tiles}")
     print(f"  front: {front}")
     # The entry point exports the directory it placed (utils.checkpoint).
     print(f"  compile cache: "
