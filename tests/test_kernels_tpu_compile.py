@@ -265,15 +265,18 @@ def test_the_two_classes_of_tile_are_two_calls_with_grids_of_their_own(
                 if v.aval.shape[:2] == (rows, kernel_check.CHUNK)]
 
 
-def _mixed_tick(cfg, attn_fn):
+def _mixed_tick(cfg, attn_fn, max_tokens=None):
     """The mixed step as the scheduler traces it, sampling left out:
-    (params, caches, tables, tokens, pos0, qlen) -> (logits, caches)."""
+    (params, caches, tables, tokens, pos0, qlen) -> (logits, caches).
+    `max_tokens`: the lane's bound on a tick's tokens, its token budget
+    plus a token a row (`_mixed_step_exe` states it since PR 52)."""
     from tpu_engine.models.transformer import transformer_step_rows_ragged
 
     def tick(params, caches, tables, tokens, pos0, qlen):
         return transformer_step_rows_ragged(
             params, tokens, caches, tables, pos0, qlen, cfg,
-            attn_fn=attn_fn, sample_slot=jnp.zeros_like(pos0))
+            attn_fn=attn_fn, sample_slot=jnp.zeros_like(pos0),
+            max_tokens=max_tokens)
 
     return tick
 
@@ -327,14 +330,18 @@ def test_tp2_paged_tick_compiles_for_v5e(v5e_devices):
     spec = create_model("gpt2", n_layers=2)   # published widths, depth cut
     cfg = spec.config
     mesh = tp_mesh(2, v5e_devices)
+    rows, nb, bs = 8, 513, 16
+    # The bound a lane of 8 rows and a budget of 16 tokens states: the
+    # pool write gathers the tick's 24 listed tokens out of 8 x 16 slots,
+    # over K and V sharded by head.
     tick = _mixed_tick(cfg, shard_over_heads(
-        functools.partial(ragged_paged_attention, interpret=False), mesh))
+        functools.partial(ragged_paged_attention, interpret=False), mesh),
+        max_tokens=16 + rows)
 
     param_shapes = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
     params = jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
         param_shapes, tp_shardings(spec, param_shapes, mesh))
-    rows, nb, bs = 8, 513, 16
     pool = jax.ShapeDtypeStruct(
         (cfg.n_layers, nb, bs, cfg.kv_heads * cfg.d_head), jnp.bfloat16,
         sharding=NamedSharding(mesh, P(None, None, None, "model")))
@@ -420,18 +427,29 @@ def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
     benchmark keeps float32 weights, the step reads their bfloat16 copy
     made once), so no kernel is cast inside it either: the parent of PR
     33 cast every stacked kernel every tick, for Mistral 3.5 GB of
-    temporaries, more than the pool."""
+    temporaries, more than the pool. Since PR 52 the lane states its
+    bound on a tick's tokens (a budget of 256 plus a token a row) and a
+    chunk tick's pool write takes that list: K and V are gathered at 288
+    (272) listed tokens, whole rows of lanes, and the program holds no
+    scatter over the pool flattened to (L x NB x bs, lanes), the form XLA
+    gave the write of all rows x 256 slots."""
     from tpu_engine.ops.paged_attention import ragged_paged_attention
 
     cfg, master, args = _cell_tick_shapes(v5e_devices, config, width)
     pool = args[1].k
+    bound = 256 + args[-1].shape[0]
     tick = _mixed_tick(
-        cfg, functools.partial(ragged_paged_attention, interpret=False))
+        cfg, functools.partial(ragged_paged_attention, interpret=False),
+        max_tokens=bound)
     step, behind = _behind_a_step(tick, args[-1])
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         *args, *behind).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
+    lanes = f"{cfg.kv_heads * cfg.d_head}|{cfg.kv_heads},{cfg.d_head}"
+    listed = re.search(rf"= bf16\[{bound},({lanes})\]\S* gather\(", hlo)
+    assert (listed is not None) == (width > 1)
+    assert f"[{math.prod(pool.shape[:3])},{pool.shape[3]}]" not in hlo
     whole = math.prod(pool.shape)
     moved = [(op, dims) for dims, op in _POOL_MOVERS.findall(hlo)
              if math.prod(map(int, dims.split(",")))

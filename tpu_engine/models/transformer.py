@@ -24,7 +24,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import re
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -567,8 +567,50 @@ def _scan_layers_paged(block_fn, params, h, caches: KVCache,
     return h, KVCache(*cache_kv)
 
 
+def pool_write_slots(batch: int, width: int,
+                     max_tokens: Optional[int] = None) -> int:
+    """The indices ONE layer's pool write of the uniform step scatters
+    (`transformer_step_rows_ragged`): the stated bound on a call's valid
+    slots where it is under the step's slots, else every slot — a step a
+    slot wide has a slot a row and nothing to leave out."""
+    slots = batch * width
+    if max_tokens is None or width == 1:
+        return slots
+    return min(max_tokens, slots)
+
+
+class _TickTokens(NamedTuple):
+    """A tick's valid (row, slot) pairs, compacted in row order into a
+    list of the caller's static bound (`_tick_tokens`), and where each
+    one's K/V goes in the pool. Entries past the tick's live count go to
+    the null block."""
+    at: jax.Array    # (max_tokens,) int32 row * W + slot
+    blk: jax.Array   # (max_tokens,) int32 pool block, 0 past the live count
+    off: jax.Array   # (max_tokens,) int32 offset in the block
+
+
+def _tick_tokens(tables, pos0, qlen, width: int, max_tokens: int,
+                 block_size: int) -> _TickTokens:
+    """The list the uniform step's pool write scatters when its caller
+    states how many valid slots a tick can hold: a cumulative sum over
+    `qlen` and a `searchsorted` (`ops.latent_attention.tile_plan` at one
+    slot a tile, the six family steps' token list). A tick that holds
+    more than `max_tokens` valid slots would lose the rest, so the
+    caller that states the bound holds its ticks to it
+    (`runtime.scheduler` `_tick_formed`)."""
+    from tpu_engine.ops import latent_attention as la
+
+    plan = la.tile_plan(qlen, 1, max_tokens)
+    _, valid = la.tile_slots(plan, qlen, 1)
+    row, slot = plan.row, plan.tile
+    cols = jnp.minimum(pos0[row] + slot, tables.shape[1] * block_size - 1)
+    blk = jnp.where(valid[:, 0], tables[row, cols // block_size], 0)
+    return _TickTokens(row * width + slot, blk, cols % block_size)
+
+
 def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
-                            cfg: TransformerConfig, *, dtype, attn_fn):
+                            cfg: TransformerConfig, *, dtype, attn_fn,
+                            listed: Optional[_TickTokens] = None):
     """One ragged mixed step against the PAGED pool: cache_kv arrays are
     the whole (L, NB, bs, H_kv*D) block pools shared by every row and
     layer (`runtime.kv_blocks.BlockPool` states the layout); ``tables``
@@ -577,25 +619,39 @@ def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
     (token i at logical column i — the alignment radix sharing needs).
     Row b consumes
     qlen[b] new tokens at logical columns [pos0[b], pos0[b]+qlen[b])
-    (decode rows: qlen 1; admitting rows: a prefill chunk). All W slots'
-    K/V scatter into the rows' pool blocks of layer ``layer`` BEFORE the
-    attention read (write-before-attend; a quantized pool quantizes at
-    THIS write, `_write_pool`); padding slots (i >= qlen) scatter into
-    the null block and their outputs are garbage the scheduler
-    ignores."""
+    (decode rows: qlen 1; admitting rows: a prefill chunk). The new
+    tokens' K/V scatter into the rows' pool blocks of layer ``layer``
+    BEFORE the attention read (write-before-attend; a quantized pool
+    quantizes at THIS write, `_write_pool`). What the scatter takes:
+    with ``listed`` the tick's valid slots alone, gathered out of the
+    (B, W) products into the list's order — an index a token, and XLA's
+    scatter on a TPU pays by the index; without it all B x W slots, the
+    padding ones (i >= qlen) sent to the null block. The values and the
+    places are the same either way, the null block's contents apart;
+    q, the read and the dense products keep their (B, W) shapes, and
+    the padding slots' outputs are garbage the scheduler ignores."""
     bs = cache_kv[0].shape[2]
     b, w = h.shape[:2]
     x = _norm(bp["ln1"], h, cfg)
     offs = jnp.arange(w)[None, :]
     logical = pos0[:, None] + offs                           # (B, W)
     q, k, v = _project_qkv(bp, x, cfg, dtype=dtype, positions=logical)
-    rows = jnp.arange(b)[:, None]
-    max_col = tables.shape[1] * bs - 1
-    cols = jnp.minimum(logical, max_col)  # padding may run off the table
-    blk = tables[rows, cols // bs]
-    blk = jnp.where(offs < qlen[:, None], blk, 0)  # padding -> null block
-    off = cols % bs
-    cache_kv = _write_pool(cache_kv, layer, blk, off, k, v)
+    if listed is not None:
+        blk, off = listed.blk, listed.off
+        # Whole rows of lanes out of the (B x W, H_kv * D) form the
+        # projection left: gathered by head, XLA first re-lays all B x W
+        # slots out with (H_kv, D) minor, a copy a tensor a layer.
+        k_new, v_new = (x.reshape(b * w, -1)[listed.at].reshape(
+            (-1,) + x.shape[2:]) for x in (k, v))
+    else:
+        rows = jnp.arange(b)[:, None]
+        max_col = tables.shape[1] * bs - 1
+        cols = jnp.minimum(logical, max_col)  # padding may run off the table
+        blk = tables[rows, cols // bs]
+        blk = jnp.where(offs < qlen[:, None], blk, 0)  # padding -> null block
+        off = cols % bs
+        k_new, v_new = k, v
+    cache_kv = _write_pool(cache_kv, layer, blk, off, k_new, v_new)
     a = attn_fn(q, *cache_kv, layer, tables, pos0, qlen)  # grouped
     a = a.astype(dtype)
     h = h + nn.dense(bp["attn"]["wo"], a.reshape(b, w, -1), dtype=dtype)
@@ -607,7 +663,8 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
                                  pos0, qlen, cfg: TransformerConfig, *,
                                  dtype=jnp.bfloat16, attn_fn=None,
                                  sample_slot=None, sample_width: int = 1,
-                                 scales: Optional[KVCache] = None):
+                                 scales: Optional[KVCache] = None,
+                                 max_tokens: Optional[int] = None):
     """The mixed prefill+decode primitive (runtime.scheduler's ragged
     tick): one ragged batch where each row consumes qlen[b] >= 0
     new tokens, writing their KV straight into the row's pool blocks in
@@ -638,7 +695,16 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
     ``scales`` (KVCache of (L, NB, bs, H_kv) f32) switches to the
     QUANTIZED int8 pool — new-token KV quantizes at its in-dispatch
     write, the default ``attn_fn`` becomes the quantized ragged read
-    path, and the caches return grows to (..., caches, scales)."""
+    path, and the caches return grows to (..., caches, scales).
+
+    ``max_tokens``: a static bound on the valid slots of one call,
+    sum(qlen) <= max_tokens (the scheduler's token budget plus a token a
+    row). Stated, and at a width above 1, each layer's pool write
+    scatters that many indices — the tick's tokens, listed once a step
+    (`_tick_tokens`) — where it otherwise scatters all B x W slots,
+    most of them padding sent to the null block. Logits and every block
+    but the null one are the same either way; tokens past a bound the
+    caller broke would be lost, so the caller checks it."""
     if attn_fn is None:
         from tpu_engine.ops.paged_attention import (
             default_quant_ragged_attention,
@@ -658,11 +724,17 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
                            params["pos_embed"]["table"].shape[0] - 1)
         h = h + params["pos_embed"]["table"][logical]
     h = h.astype(dtype)
+    # One list a step, outside the layer loop, where it is shorter than
+    # the step's slots.
+    n_listed = pool_write_slots(b, w, max_tokens)
+    listed = (_tick_tokens(tables, pos0, qlen, w, n_listed,
+                           caches.k.shape[2])
+              if n_listed < b * w else None)
 
     def block(bp, h, cache_kv, layer):
         return _block_step_rows_ragged(
             bp, h, cache_kv, layer, tables, pos0, qlen, cfg, dtype=dtype,
-            attn_fn=attn_fn)
+            attn_fn=attn_fn, listed=listed)
 
     h, *pool = _scan_layers_paged(block, params, h, caches, scales)
     if sample_slot is not None:

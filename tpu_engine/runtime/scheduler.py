@@ -66,6 +66,7 @@ from tpu_engine.models.ssd import (
 from tpu_engine.models.transformer import (
     TransformerConfig,
     init_caches,
+    pool_write_slots,
     transformer_decode_rows,
     transformer_decode_window,
     transformer_prefill,
@@ -964,6 +965,10 @@ class ContinuousGenerator:
                                                  if self._prefill_chunk > 0
                                                  else 256)
             self._mixed_budget = max(1, budget)
+            # No tick of `_tick_mixed` feeds more slots than the token
+            # budget plus a token a row: the static size of its step's
+            # token list (`_mixed_step_exe`, held to in `_tick_formed`).
+            self._tick_max_tokens = self._mixed_budget + self.n_slots
             # Per-row chunk cap == compiled ragged width. Exactly two
             # compiled widths exist per controls variant (1 and the cap):
             # a narrower final chunk pads with null-block slots instead of
@@ -1421,11 +1426,8 @@ class ContinuousGenerator:
                 cfg, dtype = self.cfg, self._dtype
                 quant = self._quant
                 own_step, held = self._ragged_step, self._held_experts
-                if own_step is not None:
-                    # No tick feeds more slots than the token budget
-                    # plus a token a row: the step's static size.
-                    max_tokens = self._mixed_budget + self.n_slots
-                else:
+                max_tokens = self._tick_max_tokens
+                if own_step is None:
                     attn_fn = self._paged_attn_fn()
 
                 layout = self._tick_block(width, controls)
@@ -1460,12 +1462,13 @@ class ContinuousGenerator:
                             transformer_step_rows_ragged(
                                 params, tokens, caches, tables, pos0,
                                 qlen, cfg, dtype=dtype, attn_fn=attn_fn,
-                                sample_slot=sample_slot, scales=scales)
+                                sample_slot=sample_slot, scales=scales,
+                                max_tokens=max_tokens)
                     else:
                         logits, caches = transformer_step_rows_ragged(
                             params, tokens, caches, tables, pos0, qlen,
                             cfg, dtype=dtype, attn_fn=attn_fn,
-                            sample_slot=sample_slot)
+                            sample_slot=sample_slot, max_tokens=max_tokens)
                     rows = jnp.arange(tokens.shape[0])
                     if controls:
                         logits = apply_repetition_penalty(logits, counts,
@@ -4188,7 +4191,8 @@ class ContinuousGenerator:
         self._row_starved_us[row] = 0.0
 
     def _tick_formed(self, width: int, prefill_rows: List[int], chunk,
-                     qlen, active, pos0=None) -> None:
+                     qlen, active, pos0=None,
+                     max_tokens: Optional[int] = None) -> None:
         """The tick's batch is formed and its arguments are on their way:
         note which prefilling rows the token budget fed and which it
         starved, then mark the clock's `dispatch`. `ctx_tokens` is the
@@ -4199,12 +4203,25 @@ class ContinuousGenerator:
         lane a row of the call: how that call's walk goes
         (`ops.paged_attention.walk_counts`: its live tiles, those whose
         first group the step before started, the tokens its DMAs fetch)
-        is on the span beside it. `active`:
+        is on the span beside it, and so is what ONE layer's pool write
+        scatters (`write_slots`, `models.transformer.pool_write_slots`:
+        the step's token list where the tick's caller states its bound,
+        `max_tokens`, else every slot of the step) beside the tokens the
+        tick holds (`write_tokens`). A tick over a stated bound would lose
+        the K/V of the tokens past it: it raises here, before the
+        dispatch, and the loop counts it with the device's failures
+        (`_recover`). `active`:
         the rows whose sample is real; the body of `_sample` they ask for
         (the step asks the same of the same controls on the device; a
         speculative step's first slot, its later ones never a dearer)
         goes on the tick's span as `sampler` and into the tick's
         `sample_*_ticks` counter."""
+        n_tokens = int(qlen.sum())
+        if max_tokens is not None and n_tokens > max_tokens:
+            raise RuntimeError(
+                f"a tick of {n_tokens} tokens is over the step's bound of "
+                f"{max_tokens} (token budget {self._mixed_budget} + "
+                f"{self.n_slots} rows)")
         self._tick_sampler = SAMPLER_BODIES[int(sampler_body(
             self._temps, self._topps, self._topks, self._minps,
             active & ~self._done))]
@@ -4224,8 +4241,11 @@ class ContinuousGenerator:
                 group=self.cfg.n_heads // self.cfg.kv_heads,
                 kv_heads=self.cfg.kv_heads,
                 block_size=self._pool.block_size)
-            self._clock.note(walk_live_tiles=live, walk_warm_tiles=warm,
-                             walk_tokens_fetched=fetched)
+            self._clock.note(
+                walk_live_tiles=live, walk_warm_tiles=warm,
+                walk_tokens_fetched=fetched, write_tokens=n_tokens,
+                write_slots=pool_write_slots(qlen.shape[0], width,
+                                             max_tokens))
         self._clock.dispatch(width, int(fed.sum()), ctx_tokens)
 
     def _slide_window_blocks(self, pos0, qlen) -> None:
@@ -4513,7 +4533,7 @@ class ContinuousGenerator:
                 # what the probes saw.
                 self._clock.probe(prev.nxt.is_ready())
             self._tick_formed(width, prefill_rows, chunk, qlen, active,
-                              pos0)
+                              pos0, self._tick_max_tokens)
             if controls:
                 common += (self._ensure_counts(),)
             out = self._mixed_step_exe(width, controls)(*common)
