@@ -25,6 +25,13 @@ that):
            concurrent streams (every slot live), /score, /infer twice
            (second cached), /health, /stats, /metrics; then the lane's
            counters, the idle pool and the server log are checked.
+  blocks   one lane of `sdar-small-test` (a model that decodes by blocks
+           of 4 tokens under a block-causal mask): a prompt with a tail,
+           three blocks through the tick, twice the same tokens, both tick
+           orders the same, every block counted and the pool idle after.
+           The lane's read is the XLA gather here (`TPU_ENGINE_PAGED=0`:
+           the test model's 32 lanes a token are below what Mosaic's DMA
+           takes; the compiled block-mask kernel is in the kernel phase).
   cache    the same launch again must reach ready without adding an entry
            to the compile cache.
   lanes    with >= 4 devices: --lanes 0 gives four lanes on four distinct
@@ -63,6 +70,41 @@ SERVE_FLAGS = ["--model", "gpt2", "--kv-block-size", "16",
 VOCAB = 50257            # gpt2's registry vocabulary
 SLOTS = 8                # WorkerConfig.gen_max_batch_size
 ATTENTION_PATHS = ("flash", "ragged", "quant_ragged")
+
+_BLOCKS_CHILD = r"""
+import json
+import jax
+from tpu_engine.models.registry import (_ensure_builtin_models_imported,
+                                        create_model)
+from tpu_engine.runtime.scheduler import ContinuousGenerator
+
+assert jax.default_backend() == "tpu", jax.default_backend()
+_ensure_builtin_models_imported()
+spec = create_model("sdar-small-test")
+params = spec.init(jax.random.PRNGKey(0))
+prompt, out = [7, 11, 13, 17, 19, 23, 29, 31, 37], {}
+for order in ("ahead", "drained"):
+    gen = ContinuousGenerator(spec, params=params, n_slots=4,
+                              dtype="float32", kv_block_size=16,
+                              prefill_chunk=16, prefix_sharing=False)
+    if order == "drained":
+        gen._may_run_ahead = lambda: False
+    try:
+        first = gen.submit(prompt, max_new_tokens=11).result(300)
+        again = gen.submit(prompt, max_new_tokens=11).result(300)
+        stats = gen.stats()
+    finally:
+        gen.stop()
+    assert first == again and len(first) == 11, (first, again)
+    mixed, pool = stats["mixed"], stats["kv_pool"]
+    # 11 tokens behind a tail of 1: blocks of 3 + 4 + 4, twice.
+    assert mixed["blocks_finished"] == 6, mixed
+    assert mixed["ticks"] == mixed["dispatches"], mixed
+    assert pool["blocks_free"] == pool["blocks_total"], pool
+    out[order] = first
+assert out["ahead"] == out["drained"], out
+print(json.dumps({"blocks": "ok", "tokens": out["ahead"]}))
+"""
 
 _DEVICE_CHILD = r"""
 import importlib.metadata as md, json, sys
@@ -123,12 +165,13 @@ def cache_entries():
         return set()
 
 
-def run_child(name, argv, cap):
+def run_child(name, argv, cap, env=None):
     """Run one child to its end; returns its stdout. Its stderr goes to a
-    log under chiprun_out/."""
+    log under chiprun_out/. `env`: what the child's environment adds."""
     log_path = os.path.join(OUT_DIR, f"{name}.err.log")
     with open(log_path, "w") as err:
-        proc = subprocess.Popen(argv, cwd=HERE, env=child_env(),
+        proc = subprocess.Popen(argv, cwd=HERE,
+                                env=dict(child_env(), **(env or {})),
                                 stdout=subprocess.PIPE, stderr=err, text=True)
         try:
             out, _ = proc.communicate(timeout=time_left(cap))
@@ -487,6 +530,13 @@ def main():
     say(phase="cache", cache_dir=cache_dir(), entries=len(before),
         added_by_relaunch=0, cold_time_to_ready_s=cold_ready,
         cached_time_to_ready_s=server.ready_s)
+
+    with phase("blocks"):
+        out = run_child("blocks", [sys.executable, "-c", _BLOCKS_CHILD], 300,
+                        env={"TPU_ENGINE_PAGED": "0"})
+        check(json.loads(out.strip().splitlines()[-1])["blocks"] == "ok",
+              "the block-decoding lane's smoke did not end ok")
+    say(phase="blocks", seconds=times["blocks"])
 
     if device["count"] >= 4:
         with phase("lanes"):

@@ -1015,6 +1015,129 @@ def test_one_mixer_a_layer_mixed_step_copies_no_pool_state_or_bank(
     assert analysis.alias_size_in_bytes > 1.9e9      # both pools in place
 
 
+@pytest.mark.parametrize("name,q_lens,width,grid", [
+    # A tick of runs alone: 64 rows x 4 slots x 8 heads = 32 query rows a
+    # KV head, the 4 heads packed into one 128-row score tile a row.
+    ("runs", (4,) * 64, 4, (64, 1)),
+    # A tall tile of a prompt chunk: 16 slots x 8 heads = one 128-row tile.
+    ("tall", (16,) * 32, 16, (32, 1)),
+])
+def test_the_block_mask_read_compiles_for_v5e_in_both_classes(
+        v5e_devices, name, q_lens, width, grid):
+    """The paged kernel under the block-causal mask (`mask_block` 4) at the
+    SDAR cell's shapes (G = 8: 32 query heads over 4 KV heads of 128 lanes,
+    blocks of 16, a table of 256 entries) compiles for a v5e in both
+    classes of tile, under its own name, with the query tiles its grid."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.ops import paged_attention as pa
+
+    del name
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+    shapes = jax.eval_shape(lambda: pa.parity_workload(
+        "ragged", q_lens, n_heads=32, n_kv_heads=4, d_head=128,
+        block_size=16, n_blocks=9217, table_len=256,
+        dtype=jnp.bfloat16)[0])
+    assert shapes[0].shape == (len(q_lens), width, 32, 128)
+    placed = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+              for x in shapes]
+
+    def read(*operands):
+        return pa.ragged_paged_attention(*operands, mask_block=4,
+                                         interpret=False)
+
+    hlo = jax.jit(read).lower(*placed).compile().as_text()
+    assert "block_mask_read" in hlo
+    assert _pallas_grids(jax.make_jaxpr(read)(*shapes).jaxpr) == [grid]
+
+
+@pytest.mark.parametrize("width", [4, 256])
+def test_block_decode_mixed_step_copies_no_pool_and_no_bank(v5e_devices,
+                                                           width):
+    """The SDAR cell's mixed step at its serving shapes (shapes only: 64
+    rows, seven layers of GQA 32 / 4 under the block mask and 128 whole
+    experts top 8 of (2048, 768); a run of 4 tokens a generating row, a
+    chunk of 256), with the L-position head, its confidences and the
+    reveal, the pool donated, compiled for one v5e: the block-mask reads
+    are Pallas calls in it (one call a layer without a chunk, two with);
+    no `copy`, `slice` or `dynamic-slice` whose result is the pool, an
+    expert bank or a layer of one; the pool in place; the grouped products
+    carry the tiles `ops.moe.grouped_tiling` states."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.models.sdar import sdar_step_rows_ragged
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+    from tpu_engine.runtime.generator import reveal_block, sample_block
+    from tpu_engine.runtime.scheduler import take_block_from_prev
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "sdar-30b-a3b-chat-7l.json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg, block = spec.config, spec.block_decode
+    run = block.block_length
+    assert width in (run, serving["gen_prefill_chunk"])
+    assert cfg.n_heads // cfg.kv_heads == 8 and cfg.n_moe_layers == 7
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    bound = run + max(serving["gen_mixed_token_budget"], rows * run)
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    one = placed(jax.ShapeDtypeStruct(
+        (cfg.n_layers, serving["gen_kv_blocks"], bs, cfg.kv_lanes[0]),
+        jnp.bfloat16))
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+
+    def host(*shape, dtype=jnp.int32):
+        return placed(jax.ShapeDtypeStruct(shape, dtype))
+
+    def step(params, caches, tables, tokens, pos0, qlen, done, live, count,
+             seeds, temps, prev_blk, prev_done, from_prev):
+        tokens, blk, done = take_block_from_prev(
+            tokens, done, prev_blk, prev_done, from_prev, run, block.mask_id)
+        logits, caches, moe_rows = sdar_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=functools.partial(ragged_paged_attention,
+                                      interpret=False),
+            sample_slot=jnp.broadcast_to(jnp.arange(run)[None], blk.shape),
+            max_tokens=bound)
+        x0, conf = sample_block(logits, seeds, pos0, temps, temps,
+                                seeds, temps, live)
+        return (caches, reveal_block(blk, x0, conf, count, block.reveal,
+                                     block.threshold), done, moe_rows)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, KVCache(one, one), host(rows, -(-cfg.max_seq // bs)),
+        host(rows, width), host(rows), host(rows),
+        host(rows, dtype=jnp.bool_), host(rows, dtype=jnp.bool_), host(rows),
+        host(rows), host(rows, dtype=jnp.float32), host(rows, run),
+        host(rows, dtype=jnp.bool_), host(rows, dtype=jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    assert "block_mask_read" in hlo and "ragged_dot_tiling" in hlo
+    banks = [bp["mlp"]["experts"] for bp in params["layers"]]
+    sizes = {math.prod(x.shape) for x in jax.tree.leaves(banks)}
+    sizes |= {math.prod(one.shape), math.prod(one.shape[1:])}
+    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
+                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
+    moved = {op for dims, op in movers.findall(hlo)
+             if math.prod(map(int, dims.split(","))) in sizes}
+    assert not moved, moved
+    analysis = compiled.memory_analysis()
+    print("sdar step width", width, "temp bytes",
+          analysis.temp_size_in_bytes, "alias", analysis.alias_size_in_bytes)
+    assert analysis.temp_size_in_bytes < 1.0e9
+    assert analysis.alias_size_in_bytes > 2.0e9      # the pool in place
+
+
 def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
     """Where JAX_COMPILATION_CACHE_DIR is set, no code names a cache
     directory (JAX reads the variable itself); unset, the directory is

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 # Serving-capability flags per state family (VirtualFlow framing: the
 # registry, not the serving machinery, declares what a model family can
@@ -57,6 +57,14 @@ from typing import Callable, Dict, List, Optional, Tuple
 # no chain, so prefix sharing, the host tier, int8 payloads, speculative
 # verify, `--tp`, migration and handoff are ABSENT, whatever the blocks
 # hold.
+# "kv_block_decode": a kv_paged chain whose generating rows step a RUN of
+# `block_length` tokens a tick under a block-causal mask and reveal them
+# over several passes (models.sdar; `ModelSpec.block_decode`). Served by
+# the mixed tick alone. A row's last block is rewritten by every pass until
+# its commit, and what a pass yields is no single token a row, so
+# speculative verify, int8 payloads (a rewritten slot would be requantized
+# a pass), the host tier, prefix sharing, `--tp`, migration and handoff
+# (a chain carries no block in denoising) are ABSENT.
 FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
     # "two_path": the dense per-slot cache (`kv_block_size` 0) and its
     # prefill-thread / chunk-loop stepping. Every other lane that
@@ -71,6 +79,7 @@ FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
                   "oneshot_rows"),
     "kv_windowed": ("generate", "paged_kv", "oneshot_rows"),
     "kv_and_state": ("generate", "paged_kv", "oneshot_rows"),
+    "kv_block_decode": ("generate", "paged_kv", "oneshot_rows"),
 }
 
 # -- tensor-parallel partition rules ------------------------------------------
@@ -229,6 +238,21 @@ def tp_shardings(spec, params, mesh, axis: str = "model"):
     return TP_RULES[rule](params, mesh, axis)
 
 
+class BlockDecode(NamedTuple):
+    """What a model that generates by denoising blocks declares
+    (`ModelSpec.block_decode`): a generating row's step is a run of
+    `block_length` tokens; a position still masked reads `mask_id` at the
+    embedding; a denoise pass reveals `tokens_per_pass` of them by the
+    rule `reveal` ("sequential", "low_confidence_static" or
+    "low_confidence_dynamic", the last with its confidence
+    `threshold`)."""
+    block_length: int
+    mask_id: int
+    tokens_per_pass: int
+    reveal: str
+    threshold: float
+
+
 @dataclasses.dataclass
 class ModelSpec:
     name: str
@@ -264,6 +288,11 @@ class ModelSpec:
     # routed experts this lane's weights hold (None: all of them).
     ragged_step: Optional[Callable] = None
     held: Optional[Tuple[int, int]] = None
+    # A model whose generating rows denoise a block of tokens over
+    # several ticks instead of sampling one a tick (models.sdar): the
+    # scheduler reads this declaration, never the model. None: one token
+    # a row a tick.
+    block_decode: Optional[BlockDecode] = None
 
     def __post_init__(self):
         if not self.state_family:
@@ -287,7 +316,8 @@ class ModelSpec:
             rule = getattr(self.config, "tp_partition_rule", None)
             if rule is None:
                 if self.state_family in ("kv_paged", "kv_latent",
-                                         "kv_windowed", "kv_and_state"):
+                                         "kv_windowed", "kv_and_state",
+                                         "kv_block_decode"):
                     rule = "transformer"
                 elif self.state_family == "state_slab":
                     # Defensive default for undeclared recurrent models:
@@ -353,6 +383,6 @@ def _ensure_builtin_models_imported():
 
     for optional in ("bert", "gpt2", "llama", "yolo", "ssd", "moonlight",
                      "laguna", "olmo_hybrid", "kimi_linear", "falcon_h1",
-                     "nemotron_h"):
+                     "nemotron_h", "sdar"):
         if importlib.util.find_spec(f"tpu_engine.models.{optional}") is not None:
             importlib.import_module(f"tpu_engine.models.{optional}")

@@ -602,6 +602,33 @@ def walk_cases(interpret: bool = False):
                          check)
 
 
+def block_mask_cases(interpret: bool = False):
+    """The ragged read under the block-causal mask (`mask_block`) over every
+    entry of `pa.BLOCK_MASK_CASES`, compiled: eight query heads a KV head
+    over `WALK_GEOMETRY`'s 2 KV heads of 64 lanes, runs of 4 (the heads
+    packed) and tall tiles of 16 slots."""
+    for name, (q_lens, pos0, table_len) in pa.BLOCK_MASK_CASES.items():
+        block = 1 if name.startswith("blocks-of-one") else 4
+        kernel_fn, reference_fn = (
+            functools.partial(fn, mask_block=block)
+            for fn in pa.READ_PATHS["ragged"])
+        workload = functools.partial(
+            pa.parity_workload, "ragged", q_lens, block_size=BLOCK_SIZE,
+            n_blocks=1 + len(q_lens) * table_len, table_len=table_len,
+            dtype=jnp.bfloat16, pos0=pos0,
+            n_heads=8 * WALK_GEOMETRY["n_kv_heads"], **WALK_GEOMETRY)
+        gap = jax.jit(functools.partial(pa.reference_gap, reference_fn))
+
+        def check(out, operands, gap=gap,
+                  qlen=jnp.asarray(q_lens, jnp.int32)):
+            return float(gap(out, operands, qlen))
+
+        yield KernelCase(f"walk/block-mask/{name}",
+                         functools.partial(kernel_fn, interpret=interpret),
+                         jax.jit(lambda workload=workload: workload()[0]),
+                         check)
+
+
 def kernel_cases(model: str, interpret: bool = False):
     """Every Pallas kernel site at `model`'s registry geometry."""
     if model in LATENT_MODELS + RECURRENT_MODELS:
@@ -645,7 +672,8 @@ def main() -> int:
     for case in itertools.chain(
             *(kernel_cases(model) for model in dict.fromkeys(
                 MODELS + LATENT_MODELS + RECURRENT_MODELS)),
-            cell_cases(), class_cases(), walk_cases(), grouped_cases()):
+            cell_cases(), class_cases(), walk_cases(), block_mask_cases(),
+            grouped_cases()):
         t0 = time.monotonic()
         operands = case.operands()
         if case.check is None:

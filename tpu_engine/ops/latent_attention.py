@@ -148,11 +148,17 @@ class TileClasses(NamedTuple):
     order (a `TilePlan` over the runs of the rows that are not short), or
     None where the step is a slot wide: no run is longer than one token
     and one call reads every row. `slot`, `valid`: each tall tile slot's
-    index in its row's new tokens and whether it holds one."""
+    index in its row's new tokens and whether it holds one. `runs`,
+    `run_slots`: where the short class is a run of up to `run_slots` new
+    tokens (a block-decoding model's block, read a row a tile at that
+    width), the short rows' q_len, zero elsewhere; None where it is one
+    token."""
     short: jax.Array             # (B,) bool
     tall: "TilePlan | None"
     slot: "jax.Array | None"     # (n_tall, T) int32, `tile_slots` of `tall`
     valid: "jax.Array | None"    # (n_tall, T) bool
+    runs: "jax.Array | None" = None      # (B,) int32
+    run_slots: int = 1
 
 
 def tall_slots(width: int, group: int) -> int:
@@ -165,29 +171,36 @@ def tall_slots(width: int, group: int) -> int:
     return min(width, _ROW_TILE // math.gcd(_ROW_TILE, group))
 
 
-def class_plan(qlen, width: int, group: int, max_tokens=None) -> TileClasses:
+def class_plan(qlen, width: int, group: int, max_tokens=None,
+               run_slots: int = 1) -> TileClasses:
     """The two classes of a tick whose rows hold `qlen` new tokens, in a
     step of `width` slots a row and at most `max_tokens` valid slots, for
     a read of `group` query heads a KV head: the class is chosen by
     `qlen`, a value the step observes, and by nothing else. The tall list
     is `tiles_bound` long (a tile a row and ceil(max_tokens / tall_slots)
-    more)."""
-    short = qlen == 1
-    if width == 1:
-        return TileClasses(short, None, None, None)
+    more). `run_slots` S > 1: the short class is every run of up to S
+    tokens (`TileClasses.runs`)."""
+    if run_slots > 1:
+        short = (qlen > 0) & (qlen <= run_slots)
+        short_runs = (jnp.where(short, qlen, 0).astype(jnp.int32), run_slots)
+    else:
+        short, short_runs = qlen == 1, ()
+    if width == run_slots:
+        return TileClasses(short, None, None, None, *short_runs)
     height = tall_slots(width, group)
     runs = jnp.where(short, 0, qlen)
     tall = tile_plan(runs, height,
                      tiles_bound(qlen.shape[0], width, height, max_tokens))
-    return TileClasses(short, tall, *tile_slots(tall, runs, height))
+    return TileClasses(short, tall, *tile_slots(tall, runs, height),
+                       *short_runs)
 
 
-def class_counts(qlen, width: int, group: int):
+def class_counts(qlen, width: int, group: int, run_slots: int = 1):
     """(short, tall): the live tiles of each class in a tick, on the host
     from the rows' `qlen` (a numpy vector): what `class_plan` makes of the
     same values on the device."""
-    runs = qlen[qlen > 1]
-    return (int((qlen == 1).sum()),
+    runs = qlen[qlen > run_slots]
+    return (int(((qlen > 0) & (qlen <= run_slots)).sum()),
             int((-(-runs // tall_slots(width, group))).sum()))
 
 

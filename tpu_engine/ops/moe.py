@@ -26,7 +26,8 @@ expert axis changes placement, not math (tests assert equality).
 
 **The served path drops nothing** (`routed_experts`, models.moonlight):
 sigmoid scores with a selection bias, top-k weights normalised and
-scaled (`sigmoid_topk_route`), the tick's VALID (token, expert) pairs
+scaled (`sigmoid_topk_route`), or a soft-max over every expert taken
+before the choice (`softmax_topk_route`, models.sdar), the tick's VALID (token, expert) pairs
 sorted by expert (a stable sort, so a token's own pairs keep their
 order whatever its tick-mates), one grouped matrix product over the
 experts held (`jax.lax.ragged_dot`: on a TPU XLA lowers it to a Mosaic
@@ -196,6 +197,21 @@ def sigmoid_topk_route(x, router, top_k: int, scale: float):
     _, experts = jax.lax.top_k(scores + router["bias"], top_k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scale
+    return experts.astype(jnp.int32), weights
+
+
+def softmax_topk_route(x, router, top_k: int):
+    """Qwen3-MoE routing. x: (N, d); router: {"kernel" (d, E)} float32.
+    The probabilities are a soft-max over ALL E experts in float32, taken
+    BEFORE the choice; the top `top_k` are chosen and their probabilities
+    normalised to sum one (`norm_topk_prob`). No selection bias, no
+    scaling factor. Returns (experts (N, k) int32, weights (N, k)
+    float32)."""
+    probs = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router["kernel"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    chosen, experts = jax.lax.top_k(probs, top_k)
+    weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
     return experts.astype(jnp.int32), weights
 
 

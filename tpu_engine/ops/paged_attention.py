@@ -181,10 +181,15 @@ def _dense_rows(q, k_pool, v_pool, k_scale, v_scale, layer, tables):
 
 
 def _ragged_reference(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
-                      pos0, window=None):
+                      pos0, window=None, mask_block: int = 1, qlen=None):
     kk, vv = _dense_rows(q, k_pool, v_pool, k_scale, v_scale, layer, tables)
     kpos = jnp.arange(kk.shape[1])
     qpos = pos0[:, None] + jnp.arange(q.shape[1])[None, :]     # (B, W)
+    if mask_block > 1:
+        # Block-causal: a query sees its whole block of `mask_block`
+        # positions, as far as the row's new tokens reach.
+        qpos = qpos // mask_block * mask_block + (mask_block - 1)
+        qpos = jnp.minimum(qpos, jnp.maximum(pos0 + qlen - 1, pos0)[:, None])
     valid = kpos[None, None, :] <= qpos[:, :, None]
     if window is not None:
         valid &= kpos[None, None, :] > qpos[:, :, None] - window
@@ -203,7 +208,8 @@ def _ragged_reference(q, k_pool, v_pool, k_scale, v_scale, layer, tables,
 
 
 def ragged_paged_attention_reference(q, k_pool, v_pool, layer, tables, pos0,
-                                     qlen, *, window=None):
+                                     qlen, *, window=None,
+                                     mask_block: int = 1):
     """XLA gather path, ragged queries. q: (B, W, H, D);
     k_pool/v_pool: (L, NB, bs, H_kv*D), the whole pool; layer: int32
     scalar, the layer read; tables: (B, nb) int32 block ids (0 = the
@@ -214,10 +220,13 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, layer, tables, pos0,
     than ignoring). `window` (a sliding-window layer): query slot i also
     needs kpos > pos0 + i - window; table entries wholly behind every
     slot's window may be the null block (the row gave those blocks
-    back). Returns (B, W, H, D)."""
-    del qlen  # padding slots are ignored by contract, not masked
+    back). `mask_block` L > 1 (a block-decoding model): query slot i at
+    position p = pos0 + i sees kpos < (p // L + 1) * L, its whole block
+    and every earlier one, and no column at or past pos0 + qlen (1 is the
+    causal mask). Returns (B, W, H, D)."""
+    # padding slots are ignored by contract, not masked
     return _ragged_reference(q, k_pool, v_pool, None, None, layer, tables,
-                             pos0, window)
+                             pos0, window, mask_block, qlen)
 
 
 # -- quantized (int8 block pool) references ----------------------------------
@@ -266,7 +275,8 @@ def _tile_geometry(rows_a_row: int, n_kv_heads: int):
 
 def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
                   v_hbm, *rest, block_size: int, blocks: int, scale: float,
-                  group: int, pack: int, quant: bool, window=None):
+                  group: int, pack: int, quant: bool, window=None,
+                  mask_block: int = 1):
     """One query TILE a grid step (b, t): `rows` query rows of batch row
     b (row r = slot r // G, group head r % G) for ALL its KV heads.
     q_ref/o_ref (1, H_kv, rows, D); k_hbm/v_hbm: the whole pools, left in
@@ -291,7 +301,11 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
     Causal masking within the new-token window: query row r keeps
     kpos <= pos0 + r // G; a padding row (a slot past q_len) reads as
     the tile's last valid one, so no row keeps a column past the
-    horizon, whose block was never fetched.
+    horizon, whose block was never fetched. `mask_block` L > 1 (static; a
+    block-decoding model's block-causal mask): query row r at position
+    p = pos0 + r // G keeps kpos < (p // L + 1) * L, and the tile's
+    horizon is the end of its last row's block, capped at pos0 + q_len
+    as before (L = 1 is the causal mask, the same program as without).
 
     `window` (static; a sliding-window layer) is the walk's OTHER end:
     query row r also needs kpos > pos0 + r // G - window, so the walk
@@ -321,8 +335,10 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
         `horizon`: the columns the tile's LAST query row sees, capped at
         pos0 + q_len; `lower`: the first column its FIRST row still sees."""
         pos0, first = pos0_ref[b], t * rows
-        horizon = jnp.minimum(lengths_ref[b],
-                              pos0 + (first + rows - 1) // group + 1)
+        sees = pos0 + (first + rows - 1) // group + 1
+        if mask_block > 1:
+            sees = (sees + mask_block - 1) // mask_block * mask_block
+        horizon = jnp.minimum(lengths_ref[b], sees)
         lower = 0 if window is None else jnp.maximum(
             pos0 + first // group - (window - 1), 0)
         return (first < (lengths_ref[b] - pos0) * group, horizon, lower,
@@ -407,9 +423,11 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
         # where this reaches g * span. qpos stops at the horizon's last
         # column (a valid row's is below it already).
         shape = (m_rows, span)
-        reach = jnp.minimum(
-            pos0 + (first + jax.lax.broadcasted_iota(
-                jnp.int32, shape, 0) % rows) // group, horizon - 1) \
+        qpos = pos0 + (first + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0) % rows) // group
+        if mask_block > 1:      # the last column of the query's block
+            qpos = qpos // mask_block * mask_block + (mask_block - 1)
+        reach = jnp.minimum(qpos, horizon - 1) \
             - jax.lax.broadcasted_iota(jnp.int32, shape, 1)
 
         def head(buf, slot, h):                 # -> (span, D)
@@ -494,7 +512,7 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
 
 
 def walk_tiles(pos0, qlen, *, width: int, group: int, kv_heads: int,
-               block_size: int, window=None):
+               block_size: int, window=None, mask_block: int = 1):
     """The kernel's own rules on the host (numpy), a tile of ONE
     `_paged_call` an entry, (B, T) in the grid's order: `live`, where the
     tile holds a valid slot, and the table entries [`lo`, `hi`) its DMAs
@@ -508,8 +526,9 @@ def walk_tiles(pos0, qlen, *, width: int, group: int, kv_heads: int,
     rows = _tile_geometry(width * group, kv_heads)[0]
     first = np.arange(-(-width * group // rows))[None, :] * rows     # (1, T)
     live = first < (qlen * group)[:, None]
-    horizon = np.minimum((pos0 + qlen)[:, None],
-                         pos0[:, None] + (first + rows - 1) // group + 1)
+    sees = pos0[:, None] + (first + rows - 1) // group + 1
+    sees = -(-sees // mask_block) * mask_block     # its last row's block end
+    horizon = np.minimum((pos0 + qlen)[:, None], sees)
     lower = np.zeros_like(horizon) if window is None else np.maximum(
         pos0[:, None] + first // group - (window - 1), 0)
     return live, lower // block_size, -(-horizon // block_size)
@@ -527,9 +546,11 @@ def walk_counts(pos0, qlen, *, block_size: int, **call):
             int((hi - lo)[live].sum()) * block_size)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "window", "mask_block"))
 def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
-                lengths, *, interpret: bool, window=None):
+                lengths, *, interpret: bool, window=None,
+                mask_block: int = 1):
     """The one pallas_call behind every read path. q: (B, W, H, D);
     k_pool/v_pool: (L, NB, bs, H_kv*D), the whole pool as it lives on
     the device — an operand left in HBM, never reshaped or sliced;
@@ -539,7 +560,10 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
     + qlen. Returns (B, W, H, D) in q's dtype. The grid is the query
     tiles, (B, ceil(W*G / rows)), whatever the table's width. With
     `window` the call is a sliding-window layer's and carries its own
-    name in a trace (`swa_window_read`)."""
+    name in a trace (`swa_window_read`), with `mask_block` > 1 a
+    block-decoding model's (`block_mask_read`)."""
+    if mask_block > 1 and window is not None:
+        raise ValueError("a block-causal read takes no window")
     b, w, h, d = q.shape
     bs = k_pool.shape[2]
     h_kv = k_pool.shape[3] // d
@@ -590,7 +614,8 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
     kernel = functools.partial(
         _paged_kernel, block_size=bs, blocks=blocks,
         scale=1.0 / math.sqrt(d), group=g, pack=pack, quant=quant,
-        window=window)
+        window=window, **({"mask_block": mask_block} if mask_block > 1
+                          else {}))
     m_rows = pack * rows
     out = pl.pallas_call(
         kernel,
@@ -614,14 +639,15 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        **({} if window is None else {"name": "swa_window_read"}),
+        **({"name": "swa_window_read"} if window is not None
+           else {"name": "block_mask_read"} if mask_block > 1 else {}),
     )(tables, pos0, lengths, layer, *operands)
     return (out[:, :, :r].reshape(b, h_kv, w, g, d)
             .transpose(0, 2, 1, 3, 4).reshape(b, w, h, d))
 
 
 def _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0, qlen,
-           interpret, window=None):
+           interpret, window=None, mask_block: int = 1):
     """Entry-point glue: `interpret=None` auto-selects (compiled on TPU,
     the Pallas interpreter elsewhere; a `pltpu.InterpretParams` asks for
     the TPU interpreter, which runs the DMAs and semaphores as such); host
@@ -633,15 +659,16 @@ def _paged(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0, qlen,
                        jnp.asarray(layer, jnp.int32).reshape(1),
                        jnp.asarray(tables, jnp.int32), pos0,
                        pos0 + jnp.asarray(qlen, jnp.int32),
-                       interpret=interpret, window=window)
+                       interpret=interpret, window=window,
+                       mask_block=mask_block)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, layer, tables, pos0, qlen, *,
-                           window=None, interpret=None):
+                           window=None, mask_block: int = 1, interpret=None):
     """Pallas-kernel drop-in for `ragged_paged_attention_reference` (same
     signature/contract)."""
     return _paged(q, k_pool, v_pool, None, None, layer, tables, pos0, qlen,
-                  interpret, window)
+                  interpret, window, mask_block)
 
 
 def quant_ragged_paged_attention(q, k_pool, v_pool, k_scale, v_scale, layer,
@@ -654,33 +681,49 @@ def quant_ragged_paged_attention(q, k_pool, v_pool, k_scale, v_scale, layer,
 
 
 def ragged_read_by_class(attn_fn, q, pool, layer, tables, pos0, classes,
-                         base, row, slot):
+                         base, row, slot, **read):
     """The ragged read of a tick's token LIST, each row by the class of
     its run (`ops.latent_attention.class_plan`). q: (M, H, D), row b's new
     token s at list index base[b] + s, and list entry m is slot `slot[m]`
     of row `row[m]`; pool: the (k, v) pair; tables: (B, nb); pos0: (B,).
     The SHORT rows' one token goes through `attn_fn` (a ragged read path
     above) as (B, 1, H, D), a ROW a row of the call, which is a
-    decode-only tick's call: `_tile_geometry` packs the heads. The TALL
+    decode-only tick's call: `_tile_geometry` packs the heads. Where the
+    plan's short class is a RUN of up to S slots (`classes.runs`: a
+    block-decoding model's block), the call is (B, S, H, D), S x G query
+    rows a KV head and row, packed the same way. The TALL
     tiles go through it as (n_tall, T, H, D), a TILE a row of the call
     with its own first column, q_len and table row. No (B, W, ...) operand
-    is made. Returns (M, H, D); an entry that holds no token is garbage
-    by contract."""
+    is made. `read`: what every call of `attn_fn` takes besides
+    (`mask_block`). Returns (M, H, D); an entry that holds no token is
+    garbage by contract."""
     m = q.shape[0]
     tall = classes.tall
-    short = classes.short.astype(jnp.int32)
-    o = attn_fn(q[jnp.minimum(base, m - 1)][:, None], *pool, layer, tables,
-                pos0, short)[:, 0]                               # (B, H, D)
+    if classes.runs is None:
+        short = classes.short.astype(jnp.int32)
+        o = attn_fn(q[jnp.minimum(base, m - 1)][:, None], *pool, layer,
+                    tables, pos0, short, **read)[:, 0]           # (B, H, D)
+
+        def listed():
+            return o[row]
+    else:
+        s = classes.run_slots
+        at = jnp.minimum(base[:, None] + jnp.arange(s)[None, :], m - 1)
+        o = attn_fn(q[at], *pool, layer, tables, pos0, classes.runs,
+                    **read)                                   # (B, S, H, D)
+
+        def listed():
+            return o[row, jnp.minimum(slot, s - 1)]
     if tall is None:
-        return o[row]
+        return listed()
     height = classes.slot.shape[1]
     at = jnp.minimum(base[tall.row][:, None] + classes.slot, m - 1)
     o_tall = attn_fn(q[at], *pool, layer, tables[tall.row],
                      pos0[tall.row] + tall.tile * height,
-                     classes.valid.sum(-1).astype(jnp.int32))
+                     classes.valid.sum(-1).astype(jnp.int32), **read)
     tile = jnp.minimum(tall.start[row] + slot // height,
                        tall.row.shape[0] - 1)
-    return jnp.where(classes.short[row][:, None, None], o[row],
+    return jnp.where(classes.short[row][:, None, None], listed(),
                      o_tall[tile, slot % height])
 
 
@@ -846,10 +889,15 @@ def reference_error(reference_fn, out, operands, qlen) -> float:
     return float(reference_gap(reference_fn, out, operands, qlen))
 
 
-def _parity(kind: str, q_lens, *, interpret, **shape) -> float:
+def _parity(kind: str, q_lens, *, interpret, mask_block: int = 1,
+            **shape) -> float:
     """Max |kernel - reference| over one `parity_workload`."""
     kernel_fn, reference_fn = READ_PATHS[kind]
     operands, qlen = parity_workload(kind, q_lens, **shape)
+    if mask_block > 1:
+        kernel_fn, reference_fn = (
+            functools.partial(fn, mask_block=mask_block)
+            for fn in (kernel_fn, reference_fn))
     if shape.get("window") is not None:
         kernel_fn, reference_fn = (
             functools.partial(fn, window=shape["window"])
@@ -960,11 +1008,14 @@ def window_parity_check(case: str, group: int, *, interpret=None,
 
 
 def class_read(q, k_pool, v_pool, layer, tables, pos0, qlen, *, width: int,
-               max_tokens=None, attn_fn=None, interpret=None):
+               max_tokens=None, attn_fn=None, interpret=None,
+               mask_block: int = 1):
     """`ragged_read_by_class` over a token list laid out a slot a tile, as
     `models.olmo_hybrid` lays its tick out: q (M, H, D), the rows' new
-    tokens side by side in row order (`class_workload`). Returns
-    (M, H, D). What the parity checks and `ops.kernel_check` run."""
+    tokens side by side in row order (`class_workload`). With `mask_block`
+    L > 1 the read is a block-decoding model's: the block mask, and runs of
+    up to L tokens the short class. Returns (M, H, D). What the parity
+    checks and `ops.kernel_check` run."""
     from tpu_engine.ops import latent_attention as la
 
     if attn_fn is None:
@@ -972,10 +1023,11 @@ def class_read(q, k_pool, v_pool, layer, tables, pos0, qlen, *, width: int,
                                     interpret=interpret)
     plan = la.tile_plan(qlen, 1, q.shape[0])
     group = q.shape[1] * q.shape[2] // k_pool.shape[3]
+    read = {"mask_block": mask_block} if mask_block > 1 else {}
     return ragged_read_by_class(
         attn_fn, q, (k_pool, v_pool), layer, tables, pos0,
-        la.class_plan(qlen, width, group, max_tokens), plan.start, plan.row,
-        plan.tile)
+        la.class_plan(qlen, width, group, max_tokens, run_slots=mask_block),
+        plan.start, plan.row, plan.tile, **read)
 
 
 def class_workload(q_lens, pos0, *, width: int, max_tokens=None, **shape):
@@ -990,7 +1042,7 @@ def class_workload(q_lens, pos0, *, width: int, max_tokens=None, **shape):
     return (q[plan.row, jnp.minimum(plan.tile, q.shape[1] - 1)], *rest)
 
 
-def class_read_error(out, operands) -> float:
+def class_read_error(out, operands, mask_block: int = 1) -> float:
     """Max |out - reference| over the list entries that hold a token: the
     gather reference on the WHOLE batch, a row a row, in f32
     (`reference_error`)."""
@@ -1001,8 +1053,10 @@ def class_read_error(out, operands) -> float:
     width = max(int(qlen.max()), 1)
     listed = jnp.minimum(plan.start[:, None] + jnp.arange(width)[None, :],
                          q.shape[0] - 1)
-    return reference_error(ragged_paged_attention_reference, out[listed],
-                           (q[listed], *rest, qlen), qlen)
+    return reference_error(
+        functools.partial(ragged_paged_attention_reference,
+                          mask_block=mask_block),
+        out[listed], (q[listed], *rest, qlen), qlen)
 
 
 # What the two-class plan can get wrong, one tick each: name -> (q_lens,
@@ -1047,6 +1101,66 @@ def class_parity_check(case: str, group: int, *, interpret=None,
     return class_read_error(
         class_read(*operands, width=256, max_tokens=max_tokens,
                    interpret=interpret), operands)
+
+
+# What the block-causal mask can get wrong, one workload each: name ->
+# (q_lens, pos0, table_len) at block size 16 and blocks of 4 positions,
+# sixteen query heads over 2 KV heads of 16 lanes (G = 8, the SDAR cell's).
+# A call 4 slots wide packs both heads' 32 query rows into one score tile
+# and walks 8-block groups; a wider one tiles 16 slots (128 query rows).
+BLOCK_MASK_CASES = {
+    # Runs of 4 at block starts: a first block, mid-context, on a group's
+    # edge (the horizon 128 and 132 columns), a dead row between them.
+    "runs-of-4": ((4, 4, 0, 4, 4), (0, 36, 9, 124, 128), 16),
+    # Chunks in tall tiles: 64 slots are four tiles; one starts a prompt,
+    # one continues it behind 240 columns; a run of 4 in the same wide call.
+    "chunks-in-tall-tiles": ((64, 16, 4), (0, 240, 500), 36),
+    # A chunk whose last block is cut by q_len (the row's tokens end
+    # mid-block: no query sees a column at or past pos0 + q_len) and runs
+    # that start mid-block: the mask is of POSITIONS, not of slots.
+    "not-aligned-to-blocks": ((6, 3, 21), (0, 37, 130), 16),
+    # The causal mask's own case under the same geometry: blocks of 1.
+    "blocks-of-one-are-causal": ((4, 17, 1), (36, 3, 200), 16),
+}
+
+
+def block_mask_parity_check(case: str, *, interpret=None,
+                            dtype=jnp.float32, seed: int = 0) -> float:
+    """Max |kernel - reference| of the ragged read under the block-causal
+    mask over one of `BLOCK_MASK_CASES`."""
+    q_lens, pos0, table_len = BLOCK_MASK_CASES[case]
+    return _parity("ragged", q_lens, n_heads=16, n_kv_heads=2, d_head=16,
+                   block_size=16, n_blocks=1 + len(q_lens) * table_len,
+                   table_len=table_len, dtype=dtype, seed=seed, pos0=pos0,
+                   mask_block=1 if case.startswith("blocks-of-one") else 4,
+                   interpret=interpret)
+
+
+# The run class beside the tall class, one tick each: name -> (q_lens, pos0,
+# max_tokens) in a step of 64 slots a row at block size 16 under a table of
+# 24 blocks, every q_len and pos0 a multiple of 4 as a block-decoding lane
+# forms them.
+BLOCK_CLASS_CASES = {
+    "every-row-a-run": ((4, 4, 4, 4), (36, 0, 300, 128), None),
+    "runs-beside-a-chunk": ((4, 64, 4, 0, 4), (40, 128, 200, 0, 8), None),
+    "a-chunk-of-one-block-is-a-run": ((4, 48, 4), (0, 16, 252), None),
+    "the-list-is-full": ((4, 60, 4, 16), (12, 100, 300, 0), 84),
+}
+
+
+def block_class_parity_check(case: str, *, interpret=None,
+                             dtype=jnp.float32, seed: int = 0) -> float:
+    """Max |run + tall reads - reference| under the block mask over one of
+    `BLOCK_CLASS_CASES`, eight query heads a KV head."""
+    q_lens, pos0, max_tokens = BLOCK_CLASS_CASES[case]
+    operands = class_workload(
+        q_lens, pos0, width=64, max_tokens=max_tokens, n_heads=16,
+        n_kv_heads=2, d_head=16, block_size=16,
+        n_blocks=1 + len(q_lens) * 24, table_len=24, dtype=dtype, seed=seed)
+    return class_read_error(
+        class_read(*operands, width=64, max_tokens=max_tokens,
+                   interpret=interpret, mask_block=4), operands,
+        mask_block=4)
 
 
 def ragged_parity_check(q_lens=(1, 7, 16, 17), n_heads: int = 4,

@@ -266,6 +266,56 @@ def _sample(logits, seeds, positions, temperature, top_p=None, top_k=None,
     return jnp.where(temperature > 0, drawn, greedy)
 
 
+def sample_block(logits, seeds, pos0, temperature, top_p, top_k, min_p,
+                 kept):
+    """`_sample` at the L positions of a row's block. logits: (B * L, V)
+    float32, a row's L positions side by side, each the distribution of the
+    token AT pos0 + i; the per-row controls (B,), each position's key
+    fold_in(seed, its own position), the rule every path shares. Returns
+    (x0 (B, L) int32, confidence (B, L) float32: soft-max(logits)[x0] in
+    float32)."""
+    b = seeds.shape[0]
+    run = logits.shape[0] // b
+
+    def rows(x):
+        return jnp.repeat(x, run)
+
+    positions = (pos0[:, None] + jnp.arange(run)[None, :]).reshape(-1)
+    x0 = _sample(logits, rows(seeds), positions, rows(temperature),
+                 rows(top_p), rows(top_k), rows(min_p), kept=rows(kept))
+    chosen = jnp.take_along_axis(logits, x0[:, None], axis=-1)[:, 0]
+    conf = jnp.exp(chosen - jax.nn.logsumexp(logits, axis=-1))
+    return x0.reshape(b, run), conf.reshape(b, run)
+
+
+def reveal_block(block, x0, conf, count, rule: str, threshold: float):
+    """One denoise pass's reveal. block: (B, L) int32, -1 where a position
+    is still masked (maskedness is state, never a token's value); x0,
+    conf: (B, L) the pass's proposal and its confidence; count: (B,) how
+    many positions the pass reveals at least (0: none, the row's block
+    comes back as it went in). `rule`: "sequential" the `count` leftmost
+    masked positions; "low_confidence_static" the `count` masked positions
+    of largest confidence (ties to the left); "low_confidence_dynamic"
+    every masked position whose confidence passes `threshold` where those
+    are at least `count`, else the static choice. Returns the block with
+    the chosen positions set to x0."""
+    masked = block < 0
+    at = jnp.arange(block.shape[1])
+    if rule == "sequential":
+        ahead = masked[:, None, :] & (at[None, :] < at[:, None])[None]
+    else:
+        # j goes before i: more confident, or as confident and to its left.
+        ci, cj = conf[:, :, None], conf[:, None, :]
+        ahead = masked[:, None, :] & (
+            (cj > ci) | ((cj == ci) & (at[None, :] < at[:, None])[None]))
+    take = masked & (ahead.sum(-1) < count[:, None])
+    if rule == "low_confidence_dynamic":
+        sure = masked & (conf > threshold)
+        take = jnp.where((sure.sum(-1) >= count)[:, None]
+                         & (count > 0)[:, None], sure, take)
+    return jnp.where(take, x0, block)
+
+
 def _decode_step_sampled(params, cfg, dtype, tok, caches, pos, start, done,
                          seeds, temps, topps, topks, minps, eos, controls,
                          counts, pens, stops):

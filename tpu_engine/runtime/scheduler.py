@@ -81,6 +81,8 @@ from tpu_engine.runtime.generator import (
     SAMPLER_BODIES,
     _sample,
     apply_repetition_penalty,
+    reveal_block,
+    sample_block,
     right_pad_prompt,
     sampler_body,
     start_host_copies,
@@ -192,11 +194,15 @@ class _FlightTick:
     `completing` those of them whose chunk ends a prompt, `decode` those
     that advance a stream, `pos` the rows' positions with this tick's
     advance and no later one's; `overlapped`: enqueued while the tick
-    before's results were unread."""
+    before's results were unread. On a block-decoding lane `nxt` is the
+    rows' blocks (B, L), `active` (and `decode`) the rows in a denoise
+    pass, `commit` those in a commit pass, `tail` how many prompt tokens
+    head each row's block, and `pos` a row's block start."""
 
     __slots__ = ("nxt", "done", "moe_rows", "reqs", "active", "completing",
                  "decode", "pos", "starved", "sampler", "fed",
-                 "prefill_tokens", "n_decode", "width", "overlapped")
+                 "prefill_tokens", "n_decode", "width", "overlapped",
+                 "commit", "tail")
 
     def __init__(self, **fields):
         for name, value in fields.items():
@@ -205,6 +211,12 @@ class _FlightTick:
     def sampled(self, r: int, req: "_Request") -> bool:
         """This tick samples a token for `req` in row `r`."""
         return bool(self.active[r]) and self.reqs[r] is req
+
+    def stepped(self, r: int, req: "_Request") -> bool:
+        """This tick steps `req` in row `r` as a generating row: it
+        samples for it, or runs its block's commit pass."""
+        return self.reqs[r] is req and bool(
+            self.active[r] or (self.commit is not None and self.commit[r]))
 
 
 def take_from_prev(tokens, done, prev_nxt, prev_done, from_prev):
@@ -215,6 +227,20 @@ def take_from_prev(tokens, done, prev_nxt, prev_done, from_prev):
     tokens = tokens.at[:, 0].set(
         jnp.where(from_prev, prev_nxt, tokens[:, 0]))
     return tokens, done | (from_prev & prev_done)
+
+
+def take_block_from_prev(tokens, done, prev_blk, prev_done, from_prev,
+                         run: int, mask_id: int):
+    """`take_from_prev` where a generating row's step is its block of
+    `run` tokens: the rows of `from_prev` take columns [0, run) of
+    `tokens` from `prev_blk`, the block the step before left them (-1
+    where a position is still masked). Returns (tokens with `mask_id`
+    where masked, what the embedding reads; the rows' blocks (B, run) as
+    they stand before this pass; done)."""
+    blk = jnp.where(from_prev[:, None], prev_blk, tokens[:, :run])
+    tokens = tokens.at[:, :run].set(blk)
+    return (jnp.where(tokens < 0, mask_id, tokens), blk,
+            done | (from_prev & prev_done))
 
 
 class TickBlock:
@@ -229,8 +255,9 @@ class TickBlock:
       `topks`, `eos_vec` (int32 as they are), `active`, `done`,
       `from_prev` (bool as 0 / 1), `temps`, `topps`, `minps` (float32,
       their bits), then `state_rows` on a lane whose rows own a state
-      row, then `pens` (float32 bits) and `stops` (`MAX_STOP_TOKENS`
-      columns) in the `controls` variant;
+      row, `reveal` on a block-decoding lane (how many positions a row's
+      denoise pass reveals), then `pens` (float32 bits) and `stops`
+      (`MAX_STOP_TOKENS` columns) in the `controls` variant;
     - `tokens`: `width` columns;
     - `tables`: a row's block table, and a second kind's after it on a
       windowed lane (`table_widths`).
@@ -248,7 +275,8 @@ class TickBlock:
     FLOAT = ("temps", "topps", "minps")
 
     def __init__(self, width: int, table_widths: Sequence[int],
-                 state_rows: bool = False, controls: bool = False):
+                 state_rows: bool = False, controls: bool = False,
+                 reveal: bool = False):
         # name -> (first column, columns or None for a (B,) field, kind)
         self.fields: dict = {}
         self.cols = 0
@@ -263,6 +291,8 @@ class TickBlock:
                 add(name, kind)
         if state_rows:
             add("state_rows")
+        if reveal:
+            add("reveal")
         if controls:
             add("pens", "float")
             add("stops", columns=MAX_STOP_TOKENS)
@@ -563,6 +593,12 @@ class ContinuousGenerator:
         # weights hold (registry.ModelSpec).
         self._ragged_step = getattr(model, "ragged_step", None)
         self._held_experts = getattr(model, "held", None)
+        # A model whose generating rows denoise a block of tokens over
+        # several ticks declares it (registry.BlockDecode; None: a row
+        # samples one token a tick). `_run`: the tokens a generating row
+        # feeds a tick.
+        self._block = getattr(model, "block_decode", None)
+        self._run = self._block.block_length if self._block else 1
         # Unified stateless serving (DESIGN.md): score/infer/embed
         # models admit as SINGLE-TICK rows — no autoregressive state at
         # all, so every state-machinery branch below is skipped and the
@@ -960,6 +996,14 @@ class ContinuousGenerator:
         # up to spec_k+1 in spec mode, so block growth and admission
         # headroom reserve exactly that horizon.
         self._decode_horizon = self._spec_k + 1
+        if self._block is not None:
+            # A generating row writes its whole block every pass.
+            self._decode_horizon = self._run
+            if int(kv_block_size) % self._run:
+                raise ValueError(
+                    f"kv_block_size={kv_block_size} must hold whole blocks "
+                    f"of {self._run} tokens (model '{model.name}' decodes "
+                    f"by blocks; the cache's end is a pool block's)")
         if self._mixed:
             budget = int(mixed_token_budget) or (self._prefill_chunk
                                                  if self._prefill_chunk > 0
@@ -969,6 +1013,12 @@ class ContinuousGenerator:
             # budget plus a token a row: the static size of its step's
             # token list (`_mixed_step_exe`, held to in `_tick_formed`).
             self._tick_max_tokens = self._mixed_budget + self.n_slots
+            if self._block is not None:
+                # A generating row counts as its run in the budget, and
+                # the first prefilling row is owed one block whatever the
+                # runs left of it.
+                self._tick_max_tokens = self._run + max(
+                    self._mixed_budget, self.n_slots * self._run)
             # Per-row chunk cap == compiled ragged width. Exactly two
             # compiled widths exist per controls variant (1 and the cap):
             # a narrower final chunk pads with null-block slots instead of
@@ -976,6 +1026,15 @@ class ContinuousGenerator:
             self._chunk_cap = max(1, min(
                 self._prefill_chunk if self._prefill_chunk > 0 else budget,
                 budget))
+            if self._block is not None:
+                # Chunks are whole blocks: their starts stay multiples of
+                # the block length.
+                self._chunk_cap = self._chunk_cap // self._run * self._run
+                if self._chunk_cap < self._run:
+                    raise ValueError(
+                        f"the prefill chunk and the token budget must hold "
+                        f"a block of {self._run} tokens (model "
+                        f"'{model.name}' decodes by blocks)")
             self._prefilling = [False] * self.n_slots
             self._row_prompt: List[Optional[np.ndarray]] = \
                 [None] * self.n_slots
@@ -1001,6 +1060,26 @@ class ContinuousGenerator:
                 "token_budget": self._mixed_budget,
                 "chunk_cap": self._chunk_cap,
             }
+            if self._block is not None:
+                # A block-decoding lane's rows by the pass they ran
+                # (counted as their tick lands), the blocks whose last
+                # denoise pass landed, and the run a row feeds a tick.
+                # `decode_tokens` counts output tokens as their block is
+                # finished.
+                self._stats["mixed"].update(
+                    denoise_passes=0, commit_passes=0, blocks_finished=0,
+                    block_decode={
+                        **self._block._asdict(),
+                        "runs_ahead": self._block.reveal
+                        != "low_confidence_dynamic"})
+                # The current block of each row as the host last knew it
+                # (-1: still masked), how many of its positions are masked
+                # once the ticks enqueued so far have run, and how many
+                # prompt tokens head it (the first block alone).
+                self._blk_known = np.full((self.n_slots, self._run), -1,
+                                          np.int32)
+                self._blk_masked = np.zeros((self.n_slots,), np.int32)
+                self._blk_tail = np.zeros((self.n_slots,), np.int32)
             if self._ragged_step is not None and getattr(
                     self.cfg, "n_moe_layers", 0):
                 # What the expert layers routed, summed over ticks: the
@@ -1315,6 +1394,15 @@ class ContinuousGenerator:
                          "scales, cannot be rolled back past a rejected "
                          "draft and rides no chain",
                          "state row beside the block chain"),
+        "kv_block_decode": ("a generating row's last block is rewritten "
+                            "by every denoise pass until its commit and a "
+                            "tick yields no single token a row: there is "
+                            "no verify window over a run, an int8 slot "
+                            "would be requantized a pass, a block in "
+                            "denoising rides no chain and goes to no host "
+                            "tier, and prefix reuse at block-aligned "
+                            "boundaries is not wired",
+                            "block-causal read of a run"),
     }
 
     def _fence_tick_only_family(self, model, fam, *, kv_host_blocks,
@@ -1397,7 +1485,8 @@ class ContinuousGenerator:
                                        else [])
             layout = self._tick_blocks[(width, controls)] = TickBlock(
                 width, [t.shape[1] for t in tables],
-                state_rows=self._hybrid, controls=controls)
+                state_rows=self._hybrid, controls=controls,
+                reveal=self._block is not None)
         return layout
 
     def _mixed_step_exe(self, width: int, controls: bool):
@@ -1432,6 +1521,11 @@ class ContinuousGenerator:
 
                 layout = self._tick_block(width, controls)
                 hybrid = self._hybrid
+                blockwise = self._block
+                if blockwise is not None and (controls or own_step is None):
+                    raise RuntimeError(
+                        "a block-decoding lane steps by its model's own "
+                        "ragged step, without the controls variant")
 
                 def step_core(params, caches, scales, block, prev_nxt,
                               prev_done, counts):
@@ -1445,9 +1539,21 @@ class ContinuousGenerator:
                         tables = (tables, f["state_rows"])
                     pos0, qlen, sample_slot, eos_vec = (
                         f["pos0"], f["qlen"], f["sample_slot"], f["eos_vec"])
-                    tokens, done = take_from_prev(
-                        f["tokens"], f["done"], prev_nxt, prev_done,
-                        f["from_prev"])
+                    if blockwise is not None:
+                        # A generating row's step is its block, carried
+                        # on the device from pass to pass; the head reads
+                        # the run's slots of every row.
+                        tokens, blk, done = take_block_from_prev(
+                            f["tokens"], f["done"], prev_nxt, prev_done,
+                            f["from_prev"], blockwise.block_length,
+                            blockwise.mask_id)
+                        sample_slot = jnp.broadcast_to(
+                            jnp.arange(blockwise.block_length)[None, :],
+                            blk.shape)
+                    else:
+                        tokens, done = take_from_prev(
+                            f["tokens"], f["done"], prev_nxt, prev_done,
+                            f["from_prev"])
                     # sample_slot gathers the hidden state BEFORE the LM
                     # head: one (B, vocab) projection per tick, not W.
                     if own_step is not None:
@@ -1477,6 +1583,21 @@ class ContinuousGenerator:
                     # sample is real: a released slot's controls stay
                     # where admission put them.
                     live = f["active"] & ~done
+                    if blockwise is not None:
+                        # A denoise pass: a proposal and its confidence at
+                        # each of the run's positions, of which the rule
+                        # reveals some; a commit pass, a chunk and a free
+                        # row leave the block as it came. The host reads
+                        # the block's end (EOS, a stop token) off the
+                        # block itself.
+                        x0, conf = sample_block(
+                            logits, f["seeds"], pos0, f["temps"],
+                            f["topps"], f["topks"], f["minps"], live)
+                        nxt = reveal_block(
+                            blk, x0, conf, jnp.where(live, f["reveal"], 0),
+                            blockwise.reveal, blockwise.threshold)
+                        return (self._pin_pool_out(caches), nxt, done,
+                                moe_rows)
                     nxt = _sample(logits, f["seeds"], f["fold_pos"],
                                   f["temps"], f["topps"], f["topks"],
                                   f["minps"], kept=live)
@@ -1833,6 +1954,13 @@ class ContinuousGenerator:
                                              if stop_tokens else None)
         if not 0.0 <= float(min_p) <= 1.0:
             raise ValueError(f"min_p must be in [0, 1], got {min_p}")
+        if self._block is not None and pens[0] != 1.0:
+            raise ValueError(
+                f"repetition_penalty is not served by model "
+                f"'{self.spec.name}': it decodes by blocks of "
+                f"{self._run} tokens and the penalty's counts are not "
+                f"carried to a block's positions (stop tokens are: a "
+                f"block that reveals one ends the row)")
         # Deterministic capacity clamp: the out_of_cache backstop
         # (_maybe_complete) fires only after a whole decode chunk, so a
         # row stopping THERE ends with a chunk-alignment-dependent ±1
@@ -1853,7 +1981,8 @@ class ContinuousGenerator:
                        prefix_hint=dict(prefix_hint)
                        if isinstance(prefix_hint, dict) else None,
                        handoff=bool(handoff) and (self._paged
-                                                  or self._slab),
+                                                  or self._slab)
+                       and self._block is None,
                        # Clamped: a parked row pins a slot + KV chain,
                        # so the window must stay bounded no matter what
                        # the caller passed.
@@ -1922,6 +2051,12 @@ class ContinuousGenerator:
                 "submit_score requires a score_provider: construct "
                 "the scheduler with score_provider=<callable returning "
                 "a scoring Generator>")
+        if self._block is not None:
+            raise ValueError(
+                f"/score is not served by model '{self.spec.name}': it "
+                f"decodes by blocks, and a teacher-forced causal read "
+                f"gives no log-probability of a token under its "
+                f"block-causal mask")
         if not self._running:
             raise RuntimeError("scheduler stopped")
         req = _Request([], 0, -1, 0.0, 0, 1.0, 0,
@@ -3324,6 +3459,8 @@ class ContinuousGenerator:
         self._row_emitted[row] = []
         self._done[row] = False
         self._stats["admitted"] += 1
+        if self._block is not None:
+            self._open_first_block(row, req, prompt, L)
 
     def _admit_import(self, item, row: int) -> None:
         """Decode-thread half of a migration import: allocate blocks for
@@ -3666,7 +3803,23 @@ class ContinuousGenerator:
         without reading one: its budget, or the cache's end (the
         backstop; `submit` clamps a budget to the cache, an imported
         snapshot's is as the source lane set it)."""
+        if self._block is not None:
+            # `pos`: where the row's next block would start.
+            return (emitted_n >= req.max_new
+                    or pos + self._run > self.max_seq)
         return emitted_n >= req.max_new or pos >= self.max_seq - 1
+
+    def _last_sample_in_flight(self, r: int, req: _Request) -> bool:
+        """The tick in flight, which samples for row r, brings the row's
+        last token by the rule `_maybe_complete` will end it with there.
+        On a block-decoding lane: it is the last denoise pass of the
+        row's last block (its commit would store K and V nobody reads)."""
+        emitted, pos = len(self._row_emitted[r]), int(self._pos[r])
+        if self._block is None:
+            return self._row_ends(req, emitted + 1, pos)
+        return self._blk_masked[r] == 0 and self._row_ends(
+            req, emitted + self._run - int(self._blk_tail[r]),
+            pos + self._run)
 
     def _maybe_complete(self, row: int, pos: Optional[int] = None) -> None:
         """`pos`: the row's position as of the tick whose results are
@@ -4116,6 +4269,21 @@ class ContinuousGenerator:
         at COMPLETION — a cancelled mid-prefill row must never leave
         half-written blocks indexed), stamp the prefill span, and emit
         the first token. Shared by _tick_mixed and _tick_spec."""
+        self._prompt_consumed(r, req)
+        self._tok[r] = first_tok
+        self._done[r] = done
+        self._row_emitted[r] = [first_tok]
+        self._first_token_metrics(req, r)
+        self._push_stream(r, req)
+        self._maybe_complete(r, pos)
+        self._maybe_hold(r, req)
+
+    def _prompt_consumed(self, r: int, req: "_Request") -> None:
+        """Row r's prompt is in the pool: index its blocks in the radix
+        tree and stamp the prefill span. What `_complete_prefill_row`
+        begins with; all of it on a block-decoding lane, whose last chunk
+        samples nothing (the row's first tokens come from its first
+        block)."""
         self._prefilling[r] = False
         if self._prefix_sharing:
             with self._pool.lock:
@@ -4129,13 +4297,57 @@ class ContinuousGenerator:
                              starved_ticks=self._row_starved_ticks[r],
                              starved_us=int(self._row_starved_us[r]))
             req.t_admit = now  # decode span start
-        self._tok[r] = first_tok
-        self._done[r] = done
-        self._row_emitted[r] = [first_tok]
-        self._first_token_metrics(req, r)
+
+    def _open_first_block(self, row: int, req: "_Request", prompt,
+                          L: int) -> None:
+        """Admission on a block-decoding lane: the blocks wholly inside
+        the prompt are prefilled (`_row_L`), its tail of L mod run tokens
+        opens the first generated block, masked behind it. A prompt
+        shorter than a block has no prefill at all."""
+        run = self._run
+        head = L // run * run
+        tail = L - head
+        self._row_L[row] = head
+        self._pos[row] = head
+        self._blk_known[row] = -1
+        self._blk_known[row, :tail] = prompt[head:L]
+        self._blk_tail[row] = tail
+        self._blk_masked[row] = run - tail
+        if head == 0:
+            self._prompt_consumed(row, req)
+            self._maybe_complete(row)      # a budget of no token at all
+
+    def _land_block_row(self, t: _FlightTick, r: int, req: "_Request",
+                        block) -> int:
+        """Row r's denoise pass has landed with `block` (L,), -1 where
+        still masked. Its last one puts the block's tokens (those behind
+        the prompt's tail) out as ONE event, whatever the reveal rule,
+        and may end the row. Returns the tokens put out."""
+        if int(t.pos[r]) == int(self._pos[r]):
+            # Still the row's current block (no commit enqueued behind):
+            # what a pass formed with nothing in flight starts from.
+            self._blk_known[r] = block
+            if self._inflight is None:      # no pass of it enqueued behind
+                self._blk_masked[r] = int((block < 0).sum())
+        if (block < 0).any():
+            return 0
+        fresh = [int(tok) for tok in block[int(t.tail[r]):]]
+        out = fresh[:max(0, req.max_new - len(self._row_emitted[r]))]
+        first = not self._row_emitted[r]
+        self._row_emitted[r].extend(out)
+        now = time.perf_counter()
+        if first:
+            self._first_token_metrics(req, r)
+        elif out:
+            gap = max(0.0, now - self._row_last_emit[r]) / len(out)
+            for _ in out:
+                self.itl_hist.observe(gap)
+        self._row_last_emit[r] = now
+        self._done[r] = any(tok == req.eos_id or tok in req.stop_tokens
+                            for tok in fresh)
         self._push_stream(r, req)
-        self._maybe_complete(r, pos)
-        self._maybe_hold(r, req)
+        self._maybe_complete(r, pos=int(t.pos[r]) + self._run)
+        return len(out)
 
     def _reset_flight(self) -> None:
         """No tick is in flight, and the two per-row inputs a step takes
@@ -4143,7 +4355,8 @@ class ContinuousGenerator:
         reads, placed as the step's own outputs are: another placement
         would be another signature of the same executable."""
         self._inflight: Optional[_FlightTick] = None
-        prev = (np.zeros((self.n_slots,), np.int32),
+        prev = (np.zeros((self.n_slots,) + ((self._run,) if self._block
+                                            else ()), np.int32),
                 np.zeros((self.n_slots,), bool))
         if self._tp_mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -4168,6 +4381,12 @@ class ContinuousGenerator:
         command that arrives while a tick is in flight is
         `_serve_exports`' to see."""
         if self._export_waiting:
+            return False
+        if (self._block is not None
+                and self._block.reveal == "low_confidence_dynamic"):
+            # The dynamic rule ends a block at a pass the host cannot
+            # foresee: the next tick's passes are known once this one's
+            # blocks are read.
             return False
         return not any(
             req is not None and (self._held[r] or (req.handoff
@@ -4286,13 +4505,15 @@ class ContinuousGenerator:
                          ctx_tokens_window=read, window_blocks_freed=freed,
                          **self._attn_tiles(qlen))
 
-    def _attn_tiles(self, qlen) -> dict:
+    def _attn_tiles(self, qlen, run_slots: int = 1) -> dict:
         """The live query tiles of a tick's full-attention read, by the
         class its step reads a row's run in
-        (`ops.latent_attention.class_plan`): a row with one new token is
-        one short tile, a longer run ceil(q_len / height) tall ones."""
+        (`ops.latent_attention.class_plan`): a row with one new token (on
+        a block-decoding lane: a run of up to `run_slots`) is one short
+        tile, a longer run ceil(q_len / height) tall ones."""
         short, tall = class_counts(qlen, self._chunk_cap,
-                                   self.cfg.n_heads // self.cfg.kv_heads)
+                                   self.cfg.n_heads // self.cfg.kv_heads,
+                                   run_slots)
         return {"attn_tiles_short": short, "attn_tiles_tall": tall}
 
     def _note_state_work(self, pos0, qlen) -> None:
@@ -4317,6 +4538,24 @@ class ContinuousGenerator:
             f"{kernel}_step_rows": int((qlen == 1).sum()),
             read: int((pos0[fed] + qlen[fed]).sum()),
             "state_rows_held": self._spool.rows_held})
+
+    def _note_block_work(self, pos0, qlen, active, commit) -> None:
+        """What a tick of a block-decoding lane holds, on its span: the
+        run a generating row feeds (`run_width`; the span's `width` stays
+        a prompt chunk's), the rows in a denoise pass and in a commit
+        pass, the tokens the block-causal read walks, the pairs it keeps
+        and the tiles of each class that read them."""
+        fed = qlen > 0
+        run, blocks = self._run, qlen[fed].astype(np.int64) // self._run
+        self._clock.note(
+            run_width=run, denoise_rows=int(active.sum()),
+            commit_rows=int(commit.sum()),
+            ctx_tokens_full=int((pos0[fed] + qlen[fed]).sum()),
+            # (query, key) pairs the block mask keeps: a query sees every
+            # position up to its own block's end.
+            attn_pairs=int((run * (blocks * pos0[fed] + run * blocks
+                                   * (blocks + 1) // 2)).sum()),
+            **self._attn_tiles(qlen, run))
 
     def _count_moe(self, rows, fed: int) -> None:
         """`rows` (L_moe, E): what each expert of each expert layer took
@@ -4405,6 +4644,10 @@ class ContinuousGenerator:
         prev = self._inflight
         pool = self._pool
         B = self.n_slots
+        # A block-decoding lane (`ModelSpec.block_decode`): a generating
+        # row feeds its block of `run` tokens every tick, a denoise pass
+        # or the commit; everywhere else a row feeds one token.
+        blockwise, run = self._block, self._run
         self._clock.begin()
         if prev is not None:
             self._clock.probe(prev.nxt.is_ready())
@@ -4418,15 +4661,15 @@ class ContinuousGenerator:
                 continue
             if req.eos_id >= 0:
                 eos_vec[r] = req.eos_id
-            if req.rep_penalty != 1.0 or req.stop_tokens:
-                controls = True
+            if blockwise is None and (req.rep_penalty != 1.0
+                                      or req.stop_tokens):
+                controls = True  # a block's stop tokens are the host's
             if self._held[r]:
                 continue  # parked handoff rows: no budget, no decode slot
             if self._prefilling[r]:
                 prefill_rows.append(r)
             elif (prev is not None and prev.sampled(r, req)
-                  and self._row_ends(req, len(self._row_emitted[r]) + 1,
-                                     int(self._pos[r]))):
+                  and self._last_sample_in_flight(r, req)):
                 # The tick in flight brings this row's last token, by
                 # the rule `_maybe_complete` will end it with there.
                 ending[r] = True
@@ -4440,14 +4683,16 @@ class ContinuousGenerator:
             else:
                 self._clock.idle()
             return
-        budget_left = max(1, self._effective_mixed_budget() - n_decode)
+        budget_left = max(run, self._effective_mixed_budget()
+                          - n_decode * run)
         chunk = np.zeros((B,), np.int32)
         for r in prefill_rows:
             remaining = max(self._row_L[r], 1) - self._row_w0[r]
             c = min(remaining, self._chunk_cap, budget_left)
-            chunk[r] = max(0, c)
+            chunk[r] = max(0, c) // run * run    # whole blocks
             budget_left -= chunk[r]
-        width = self._chunk_cap if prefill_rows and chunk.max() > 0 else 1
+        chunked = bool(prefill_rows) and chunk.max() > 0
+        width = self._chunk_cap if chunked else run
 
         tokens = np.zeros((B, width), np.int32)
         pos0 = np.zeros((B,), np.int32)
@@ -4457,6 +4702,8 @@ class ContinuousGenerator:
         active = np.zeros((B,), bool)
         from_prev = np.zeros((B,), bool)
         completing = np.zeros((B,), bool)
+        reveal = np.zeros((B,), np.int32)
+        commit = np.zeros((B,), bool) if blockwise is not None else None
         prefill_tokens = 0
         for r, req in enumerate(self._row_req):
             if req is None or ending[r]:
@@ -4476,9 +4723,28 @@ class ContinuousGenerator:
                         # slot Leff-1-w0 at logical position L (the exact
                         # `_first_token` rule of the dense path).
                         completing[r] = True
+                        if blockwise is not None:
+                            continue  # the first block brings the tokens
                         active[r] = True
                         sample_slot[r] = Leff - 1 - w0
                         fold_pos[r] = self._row_L[r]
+            elif blockwise is not None:
+                # The row's block at its start, every pass of it. Masked
+                # positions left (counts alone, known as ticks are
+                # formed): a denoise pass reveals some of them; none: the
+                # commit, whose K and V are the ones that stay.
+                pos0[r] = fold_pos[r] = self._pos[r]
+                qlen[r] = run
+                if prev is not None and prev.sampled(r, req):
+                    from_prev[r] = True  # its block is still on the device
+                else:
+                    tokens[r, :run] = self._blk_known[r]
+                if self._blk_masked[r] > 0:
+                    active[r] = not self._done[r]
+                    reveal[r] = min(int(self._blk_masked[r]),
+                                    blockwise.tokens_per_pass)
+                else:
+                    commit[r] = True
             else:
                 pos0[r] = self._pos[r]
                 qlen[r] = 1
@@ -4517,6 +4783,9 @@ class ContinuousGenerator:
                 pool_args = ((pool.caches,
                               self._spool.slab),)  # lint: lockfree-ok tick thread's alone
                 extra["state_rows"] = self._spool.rows
+            if blockwise is not None:
+                extra["reveal"] = reveal
+                self._note_block_work(pos0, qlen, active, commit)
             if controls:
                 extra.update(pens=self._pens, stops=self._stops)
             block = self._tick_block(width, controls).pack(
@@ -4566,16 +4835,34 @@ class ContinuousGenerator:
             self._row_w0[r] += int(chunk[r])
             if completing[r]:
                 self._prefilling[r] = False
-        for r in np.flatnonzero(decode):
-            self._pos[r] = min(int(self._pos[r]) + 1, self.max_seq - 1)
+        tail = None
+        if blockwise is None:
+            for r in np.flatnonzero(decode):
+                self._pos[r] = min(int(self._pos[r]) + 1, self.max_seq - 1)
+        else:
+            # A denoise pass leaves fewer masked; a commit opens the row's
+            # next block, all masked (the flight tick keeps this block's
+            # start and tail).
+            tick_pos, tail = self._pos.copy(), self._blk_tail.copy()
+            self._blk_masked -= reveal
+            for r in np.flatnonzero(commit):
+                self._pos[r] += run
+                self._blk_masked[r] = run
+                self._blk_tail[r] = 0
+                self._blk_known[r] = -1
         tick = _FlightTick(
             nxt=nxt, done=done, moe_rows=moe_rows,
             reqs=list(self._row_req), active=active, completing=completing,
-            decode=decode, pos=self._pos.copy(),
+            decode=decode,
+            pos=self._pos.copy() if blockwise is None else tick_pos,
             starved=self._tick_starved,
             sampler=self._tick_sampler, fed=int(qlen.sum()),
-            prefill_tokens=prefill_tokens, n_decode=n_decode, width=width,
-            overlapped=int(prev is not None))
+            prefill_tokens=prefill_tokens, n_decode=n_decode,
+            # The span's `width` is a chunk's compiled width: 1 where the
+            # tick holds no prompt chunk, a run's width beside it
+            # (`run_width`).
+            width=width if chunked else 1,
+            overlapped=int(prev is not None), commit=commit, tail=tail)
         if prev is not None:
             self._land_tick(behind=tick)
         else:
@@ -4611,15 +4898,30 @@ class ContinuousGenerator:
         m[f"sample_{t.sampler}_ticks"] += 1
         m["overlapped_ticks"] += t.overlapped
         m["prefill_tokens"] += t.prefill_tokens
-        m["decode_tokens"] += t.n_decode
+        if self._block is None:
+            m["decode_tokens"] += t.n_decode
+        else:
+            m["denoise_passes"] += int(t.decode.sum())
+            m["commit_passes"] += int(t.commit.sum())
         if t.prefill_tokens and t.n_decode:
             m["coscheduled_ticks"] += 1
 
+        blocks_finished = 0
         for r in range(self.n_slots):
             req = self._row_req[r]
             if req is None or req is not t.reqs[r]:
                 continue  # freed, or its slot given on, while the tick ran
-            if t.completing[r]:
+            if self._block is not None:
+                if t.completing[r]:
+                    self._prompt_consumed(r, req)
+                    self._maybe_complete(r, pos=int(t.pos[r]))
+                elif t.decode[r]:
+                    m["decode_tokens"] += self._land_block_row(t, r, req,
+                                                               nxt[r])
+                    blocks_finished += int(not (nxt[r] < 0).any())
+                else:
+                    continue  # mid-prompt, starved, or a commit pass
+            elif t.completing[r]:
                 self._complete_prefill_row(r, req, int(nxt[r]),
                                            bool(done_new[r]),
                                            pos=int(t.pos[r]))
@@ -4639,12 +4941,15 @@ class ContinuousGenerator:
             else:
                 continue  # mid-prompt, starved or parked: nothing sampled
             if (self._row_req[r] is None and behind is not None
-                    and behind.sampled(r, req)):
+                    and behind.stepped(r, req)):
                 # Ended by what only the device knew: the tick behind
                 # steps it once more, as a done row.
                 m["lagged_rows"] += 1
 
         self._wake_streams()  # one wake a tick, after the last row's put
+        if self._block is not None:
+            m["blocks_finished"] += blocks_finished
+            self._clock.note(blocks_finished=blocks_finished)
         if behind is not None:
             self._clock.probe(behind.nxt.is_ready())
         self._tick_done(t.prefill_tokens, t.n_decode, t.width,

@@ -946,6 +946,16 @@ def _print_runtime_banner(workers, front: str) -> None:
         stats = gen.stats() if gen is not None else {}
         for shape, tiles in stats.get("moe", {}).get("tilings", {}).items():
             print(f"  lane {w.node_id} expert tiles {shape}: {tiles}")
+        block = stats.get("mixed", {}).get("block_decode")
+        if block:
+            # A lane whose rows denoise blocks: its reveal rule, and
+            # whether its ticks run one ahead of their results.
+            print(f"  lane {w.node_id} decodes by blocks of "
+                  f"{block['block_length']} ({block['reveal']}, "
+                  f"{block['tokens_per_pass']} a pass): "
+                  + ("ticks run one ahead" if block["runs_ahead"] else
+                     "ticks in the drained order (the rule ends a block "
+                     "at a pass the host cannot foresee)"))
     print(f"  front: {front}")
     # The entry point exports the directory it placed (utils.checkpoint).
     print(f"  compile cache: "

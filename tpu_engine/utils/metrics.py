@@ -384,6 +384,20 @@ def render_prometheus(healths: List[Dict], stats: Optional[Dict] = None,
     metric("tpu_engine_mixed_token_budget", "gauge",
            "Per-tick new-token budget (--mixed-token-budget)",
            [(node(h), m.get("token_budget")) for h, m in mx])
+    # A block-decoding lane (ModelSpec.block_decode): its generating rows'
+    # ticks by the pass they ran, and the blocks they finished. Absent on
+    # every other lane (None values are skipped).
+    metric("tpu_engine_mixed_block_passes_total", "counter",
+           "Row-ticks of a block-decoding lane by pass (denoise | commit)",
+           [({**node(h), "pass": kind}, m.get(f"{kind}_passes"))
+            for h, m in mx for kind in ("denoise", "commit")])
+    metric("tpu_engine_mixed_blocks_finished_total", "counter",
+           "Blocks of tokens whose last denoise pass landed",
+           [(node(h), m.get("blocks_finished")) for h, m in mx])
+    metric("tpu_engine_mixed_block_length", "gauge",
+           "Tokens a generating row of a block-decoding lane feeds a tick",
+           [(node(h), (m.get("block_decode") or {}).get("block_length"))
+            for h, m in mx])
 
     # Speculative decoding — one family for BOTH lanes (the continuous
     # scheduler's --spec-k per-tick verify windows and the batch

@@ -862,8 +862,11 @@ class TickClock:
         """What the scheduler knows of the tick beyond its phases: counts
         the step brought back with its results (a routed model's
         `moe_assignments`, `moe_experts_touched`), the `sampler` body its
-        rows asked for. They ride the span of the tick whose phase is
-        open, beside `ctx_tokens`."""
+        rows asked for; on a block-decoding lane `run_width` (the tokens a
+        generating row feeds; the span's `width` stays a prompt chunk's
+        compiled width, 1 without one), `denoise_rows`, `commit_rows`,
+        `attn_pairs` and, when the tick lands, `blocks_finished`. They ride
+        the span of the tick whose phase is open, beside `ctx_tokens`."""
         self._marks.notes.update(attrs)
 
     def end(self, live: bool, node: str) -> Tuple[float, float, dict]:
