@@ -59,11 +59,13 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
-# Everything, compilation included, must end inside the 2700 s the chip
+# Everything, compilation included, must end inside the 3100 s the chip
 # call is given (measured, PR 49: device 18 s, kernels 990, serve 188,
 # cache 32; PR 50: the kernel phase alone 896 s with the machine's compile
-# cache warm and OVER 1400 s cold, its four new cases 125-160 s of it).
-DEADLINE = time.monotonic() + 2640
+# cache warm and OVER 1400 s cold, its four new cases 125-160 s of it;
+# PR 53: 2024 s cold; PR 54: 2258 s, of which the steps' live mixes 9 s
+# and `olmo_hybrid`'s two kernels 96 s).
+DEADLINE = time.monotonic() + 3040
 
 SERVE_FLAGS = ["--model", "gpt2", "--kv-block-size", "16",
                "--gen-prefill-chunk", "256", "--warmup"]
@@ -136,7 +138,7 @@ def say(**fields):
 
 def time_left(cap):
     left = DEADLINE - time.monotonic()
-    check(left > 0, "out of time: the run must end inside 2700 s")
+    check(left > 0, "out of time: the run must end inside 3100 s")
     return min(cap, left)
 
 
@@ -511,7 +513,7 @@ def main():
         # 46, 48, 50: the gather references of the cell and class cases
         # are most of it).
         run_child("kernels",
-                  [sys.executable, "-m", "tpu_engine.ops.kernel_check"], 2100)
+                  [sys.executable, "-m", "tpu_engine.ops.kernel_check"], 2500)
 
     with phase("serve"):
         cold_ready, drive_s, _ = serve_phase("serve", 1, 1)

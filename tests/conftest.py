@@ -87,18 +87,24 @@ def pytest_collection_modifyitems(session, config, items):
     # (`sched.form_ms`, `sched.form_transfers_per_tick`). A `benchmark` PR adds the metrics to the rehearsal files,
     # finds the cell by name and deletes this with the hook above
     # (PERF.md section 7).
-    for name in ("test_benchmark_reference_moonlight",
-                 "test_benchmark_reference_laguna",
-                 "test_benchmark_reference_olmo_hybrid"):
+    # PR 54's `kernel.state_step_live_share` lists the four cells whose
+    # rows own a recurrent state: ..._falcon_h1.py and ..._nemotron_h.py
+    # join for it (they find their cell by name: nothing is cut).
+    for name, cut in (("test_benchmark_reference_moonlight", True),
+                      ("test_benchmark_reference_laguna", True),
+                      ("test_benchmark_reference_olmo_hybrid", True),
+                      ("test_benchmark_reference_falcon_h1", False),
+                      ("test_benchmark_reference_nemotron_h", False)):
         module = sys.modules.get(name)
         if module is not None and not hasattr(module.json, "_cell"):
-            module.json = _AsTheCellWasWritten(module.json, module.CELL)
+            module.json = _AsTheCellWasWritten(module.json, module.CELL, cut)
 
 
 class _AsTheCellWasWritten:
     """The `json` module, whose `load` cuts a benchmark's `workloads`
-    after the cell named and takes that cell off the `workloads` of the
-    per-layer metrics appended since its rehearsal file was written."""
+    after the cell named (where `cut`) and takes that cell off the
+    `workloads` of the per-layer metrics appended since its rehearsal file
+    was written."""
 
     APPENDED_SINCE = ("step.sampler_sort_busy",
                       "step.sampler_sort_tick_share",   # PR 38
@@ -109,10 +115,11 @@ class _AsTheCellWasWritten:
                       "device.idle_stream", "step.gc_ms_per_s",  # PR 42
                       "front.stream_writer_share",      # PR 43
                       "sched.form_ms",
-                      "sched.form_transfers_per_tick")  # PR 47
+                      "sched.form_transfers_per_tick",  # PR 47
+                      "kernel.state_step_live_share")   # PR 54
 
-    def __init__(self, json_module, cell):
-        self._json, self._cell = json_module, cell
+    def __init__(self, json_module, cell, cut=True):
+        self._json, self._cell, self._cut = json_module, cell, cut
 
     def __getattr__(self, name):
         return getattr(self._json, name)
@@ -123,8 +130,9 @@ class _AsTheCellWasWritten:
             return data
         names = [w.get("name") for w in data.get("workloads", [])]
         if self._cell in names:
-            data["workloads"] = \
-                data["workloads"][:names.index(self._cell) + 1]
+            if self._cut:
+                data["workloads"] = \
+                    data["workloads"][:names.index(self._cell) + 1]
             for m in data.get("per_layer", []):
                 if m["name"] in self.APPENDED_SINCE:
                     m["workloads"] = [w for w in m["workloads"]

@@ -65,10 +65,14 @@ def test_the_latent_read_compiles_for_v5e_at_both_widths(v5e_devices):
             kernel_check.compile_for_topology(case, v5e_devices[0])
             names.append(case.name)
     # 16 heads, and 32 in a model whose other layers are recurrent: both
-    # forms of its channel-gated recurrence compile beside the read.
+    # forms of its channel-gated recurrence compile beside the read, the
+    # step also at the live rows of reason's chunk ticks and with none.
     assert names == ["moonlight/latent/W1", "moonlight/latent/W256",
                      "kimi_linear/latent/W1", "kimi_linear/latent/W256",
-                     "kimi_linear/kda_step/B128", "kimi_linear/kda_chunk/T256"]
+                     "kimi_linear/kda_step/B128",
+                     "kimi_linear/kda_step/B128/live69",
+                     "kimi_linear/kda_step/B128/live0",
+                     "kimi_linear/kda_chunk/T256"]
 
 
 def test_the_state_space_recurrence_compiles_for_v5e_in_both_forms(
@@ -80,11 +84,13 @@ def test_the_state_space_recurrence_compiles_for_v5e_in_both_forms(
     for case in kernel_check.kernel_cases("falcon_h1", interpret=False):
         kernel_check.compile_for_topology(case, v5e_devices[0])
         names.append(case.name)
-    assert names == ["falcon_h1/ssd_step/B64", "falcon_h1/ssd_chunk/T256"]
-    (step,) = [c for c in kernel_check.kernel_cases("falcon_h1")
-               if "ssd_step" in c.name]
-    jaxpr = jax.make_jaxpr(step.kernel)(*jax.eval_shape(step.operands))
-    assert _pallas_grids(jaxpr.jaxpr) == [(64, 2)]
+    assert names == ["falcon_h1/ssd_step/B64", "falcon_h1/ssd_step/B64/live0",
+                     "falcon_h1/ssd_chunk/T256"]
+    for step in kernel_check.kernel_cases("falcon_h1"):
+        if "ssd_step" in step.name:
+            jaxpr = jax.make_jaxpr(step.kernel)(
+                *jax.eval_shape(step.operands))
+            assert _pallas_grids(jaxpr.jaxpr) == [(64, 2)]
 
 
 def test_the_recurrence_compiles_for_v5e_at_128_heads_of_64_by_128(
@@ -97,9 +103,32 @@ def test_the_recurrence_compiles_for_v5e_at_128_heads_of_64_by_128(
     for case in kernel_check.kernel_cases("nemotron_h", interpret=False):
         kernel_check.compile_for_topology(case, v5e_devices[0])
         names.append(case.name)
-    assert names == ["nemotron_h/ssd_step/B64", "nemotron_h/ssd_chunk/T256"]
-    step, chunk = kernel_check.kernel_cases("nemotron_h")
-    for case, grid in ((step, (64, 8)), (chunk, (128,))):
+    assert names == ["nemotron_h/ssd_step/B64",
+                     "nemotron_h/ssd_step/B64/live40",
+                     "nemotron_h/ssd_step/B64/live0",
+                     "nemotron_h/ssd_chunk/T256"]
+    # The grid spans the lane's slots whatever is live: a dead row's steps
+    # stay, empty (agents' mix: 40 of 64).
+    for case in kernel_check.kernel_cases("nemotron_h"):
+        grid = (64, 8) if "ssd_step" in case.name else (128,)
+        jaxpr = jax.make_jaxpr(case.kernel)(*jax.eval_shape(case.operands))
+        assert _pallas_grids(jaxpr.jaxpr) == [grid]
+
+
+def test_the_delta_rule_compiles_for_v5e_under_a_gate_a_head(v5e_devices):
+    """`gdn_step` over digest's 16 slots (30 heads of 192 x 96, 15 a block:
+    one dead row, 14 live, none) and `gdn_chunk` over a run of 256 tokens,
+    at Olmo-Hybrid's heads and lanes."""
+    names = []
+    for case in kernel_check.kernel_cases("olmo_hybrid", interpret=False):
+        kernel_check.compile_for_topology(case, v5e_devices[0])
+        names.append(case.name)
+    assert names == ["olmo_hybrid/gdn_step/B16",
+                     "olmo_hybrid/gdn_step/B16/live14",
+                     "olmo_hybrid/gdn_step/B16/live0",
+                     "olmo_hybrid/gdn_chunk/T256"]
+    for case in kernel_check.kernel_cases("olmo_hybrid"):
+        grid = (16, 2) if "gdn_step" in case.name else (30,)
         jaxpr = jax.make_jaxpr(case.kernel)(*jax.eval_shape(case.operands))
         assert _pallas_grids(jaxpr.jaxpr) == [grid]
 

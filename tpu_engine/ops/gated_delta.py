@@ -39,9 +39,13 @@ On a TPU it is a Pallas kernel named `gdn_step` in a trace: the grid is
 (rows, blocks of heads), a step's block of the pool is chosen by the layer
 and the row's pool row in SMEM (neither the layer nor the gather ever
 materializes) and aliased to the output, so a state is read once and
-written once. A head's update is multiplies and lane sums on a (d_v, d_k)
-tile: k, q and the decay come as rows, b v as a column (the heads of a
-block on the lanes, so a column is a lane slice), o leaves as a column.
+written once. The grid takes the rows that step FIRST (`live_first`) and a
+grid step past the last of them names the blocks the step before it named
+(`step_at`): a row that takes no step (a free slot, a prompt that waits,
+the tick's chunk row) moves no byte. A head's update is multiplies and
+lane sums on a (d_v, d_k) tile: k, q and the decay come as rows, b v as a
+column (the heads of a block on the lanes, so a column is a lane slice), o
+leaves as a column.
 Elsewhere (and as the kernel's reference) it is `gdn_step` over a gather
 of the rows and a scatter back. `gdn_chunk_row` is a row's run of tokens
 from its state in the same pool: the kernel `gdn_chunk`, a head a grid
@@ -136,14 +140,52 @@ def gdn_step_rows_reference(q, k, v, g, beta, pool, layer, rows, live, fresh):
         jnp.where(live[:, None, None, None], new, old))
 
 
-def _step_kernel(rows_ref, layer_ref, live_ref, fresh_ref, vec_ref, bv_ref,
-                 s_ref, s_out, o_ref, *, heads):
-    del rows_ref, layer_ref                      # the index maps read them
-    b = pl.program_id(0)
-    keep = (fresh_ref[b] == 0).astype(jnp.float32)
+def live_first(live):
+    """The order a step call's grid takes a tick's rows in: (order (B,)
+    int32, the rows with `live` set first and among themselves as they
+    stand, the places behind them filled with the last of them (row 0 where
+    none is live); count (1,) int32, how many are live). A cumulative sum
+    and one scatter, and nothing of `live`'s layer: XLA shares it between
+    the layers of a step."""
+    b = live.shape[0]
+    at = jnp.cumsum(live.astype(jnp.int32))
+    place = jnp.arange(b, dtype=jnp.int32)
+    order = jnp.zeros(b, jnp.int32).at[jnp.where(live, at - 1, b)].set(
+        place, mode="drop")
+    count = at[-1:]
+    return jnp.where(place < count, order,
+                     order[jnp.maximum(count - 1, 0)]), count
 
-    @pl.when(live_ref[b] != 0)
+
+def step_at(i, j, order, count, blocks: int):
+    """The (row of the batch, block of heads) that step (i, j) of a step
+    call's grid names, `order` and `count` from `live_first`. The first
+    `count` rows of the grid are the live rows, a block a step. A step
+    past them names what the last live step named, so the pipeline fetches
+    nothing for it and writes nothing back: the last live block leaves
+    VMEM once, when the grid ends. With no live row every step names the
+    last block of row 0 of the batch, which the wrappers point at the
+    pool's null row."""
+    return order[i], jnp.where(i < count[0], j, blocks - 1)
+
+
+def _copy_null_block(count_ref, s_ref, s_out):
+    """With no live row the grid's one block, one of the null row's, goes
+    back as it came: the first step copies it, no other touches it."""
+    @pl.when((count_ref[0] == 0) & (pl.program_id(0) == 0)
+             & (pl.program_id(1) == 0))
     def _():
+        s_out[...] = s_ref[...]
+
+
+def _step_kernel(rows_ref, layer_ref, fresh_ref, order_ref, count_ref,
+                 vec_ref, bv_ref, s_ref, s_out, o_ref, *, heads):
+    del rows_ref, layer_ref, order_ref           # the index maps read them
+    b = pl.program_id(0)
+
+    @pl.when(b < count_ref[0])
+    def _():
+        keep = (fresh_ref[b] == 0).astype(jnp.float32)
         for i in range(heads):
             s = s_ref[0, 0, i] * keep                        # (d_v, d_k)
             k, q, a, kb = (vec_ref[0, 0, n, i:i + 1, :] for n in range(4))
@@ -153,11 +195,17 @@ def _step_kernel(rows_ref, layer_ref, live_ref, fresh_ref, vec_ref, bv_ref,
             s_out[0, 0, i] = s
             o_ref[0, 0, :, i:i + 1] = jnp.sum(s * q, axis=1, keepdims=True)
 
-    @pl.when(live_ref[b] == 0)
-    def _():
-        # The null row, copied onto itself.
-        s_out[...] = s_ref[...]
-        o_ref[...] = jnp.zeros_like(o_ref)
+    _copy_null_block(count_ref, s_ref, s_out)
+
+
+def _in_order(live, rows, *per_row):
+    """What a step call's scalar core reads: each row's pool row (the null
+    row for a row that takes no step) and `per_row`, by the grid's place
+    and not the batch's; then `live_first`'s order and count."""
+    order, count = live_first(live)
+    return tuple(v.astype(jnp.int32)[order]
+                 for v in (jnp.where(live, rows, 0),) + per_row) + (
+        order, count)
 
 
 def _step_call(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
@@ -168,6 +216,7 @@ def _step_call(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
     heads = max(p for p in range(1, h + 1) if h % p == 0
                 and (p == 1 or p * dv * lanes * 4 <= _STEP_BLOCK_BYTES))
     blocks = h // heads
+    rows, fresh, order, count = _in_order(live, rows, fresh)
     a = jnp.exp(g)
     channel = g.ndim == k.ndim
     # Rows of d_k lanes a head: k, q, the decay, and b a k (S k is wanted
@@ -182,20 +231,23 @@ def _step_call(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
     bv = (beta[..., None] * v).reshape(b, blocks, heads, dv)
     bv = bv.transpose(0, 1, 3, 2)
 
-    def state(b, j, rows, layer, *_):
-        return (layer[0], rows[b], j, 0, 0)
+    def state(i, j, rows, layer, fresh, order, count):
+        return (layer[0], rows[i], step_at(i, j, order, count, blocks)[1],
+                0, 0)
 
-    def column(b, j, *_):
-        return (b, j, 0, 0)
+    def column(i, j, rows, layer, fresh, order, count):
+        return step_at(i, j, order, count, blocks) + (0, 0)
+
+    def rows_of_lanes(i, j, *prefetch):
+        return column(i, j, *prefetch) + (0,)
 
     pool, o = pl.pallas_call(
         functools.partial(_step_kernel, heads=heads),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,            # rows, layer, live, fresh
+            num_scalar_prefetch=5,      # rows, layer, fresh, order, count
             grid=(b, blocks),
             in_specs=[
-                pl.BlockSpec((1, 1, 4, heads, dk),
-                             lambda b, j, *_: (b, j, 0, 0, 0)),
+                pl.BlockSpec((1, 1, 4, heads, dk), rows_of_lanes),
                 pl.BlockSpec((1, 1, dv, heads), column),
                 pl.BlockSpec((1, 1, heads, dv, dk), state)],
             out_specs=[pl.BlockSpec((1, 1, heads, dv, dk), state),
@@ -203,14 +255,15 @@ def _step_call(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                    jax.ShapeDtypeStruct((b, blocks, dv, heads),
                                         jnp.float32)],
-        input_output_aliases={6: 0},          # the pool, in place
+        input_output_aliases={7: 0},          # the pool, in place
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret, name="kda_step" if channel else "gdn_step",
-    )(jnp.where(live, rows, 0).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), live.astype(jnp.int32),
-      fresh.astype(jnp.int32), vec, bv, pool)
-    return o.transpose(0, 1, 3, 2).reshape(b, h, dv), pool
+    )(rows, jnp.asarray(layer, jnp.int32).reshape(1), fresh, order, count,
+      vec, bv, pool)
+    # A dead row's column was never written.
+    o = o.transpose(0, 1, 3, 2).reshape(b, h, dv)
+    return jnp.where(live[:, None, None], o, 0.0), pool
 
 
 def gdn_step_rows(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
@@ -219,9 +272,12 @@ def gdn_step_rows(q, k, v, g, beta, pool, layer, rows, live, fresh, *,
     d_k); v: (B, H, d_v); g: (B, H) or (B, H, d_k); beta: (B, H); pool: (L, R, H, d_v, d_k)
     float32, donated; layer: the pool's layer; rows: (B,) each row's pool
     row; live: (B,) rows that take the step (the others' states are left
-    as they are, their outputs garbage); fresh: (B,) rows whose state is
-    zero before the step. Returns (o (B, H, d_v), pool). The kernel is
-    named `kda_step` in a trace where the gate is a channel's.
+    as they are, their blocks never visited; their outputs mean nothing,
+    0 from the kernel); fresh: (B,) rows whose state is zero before the
+    step. Returns (o (B, H, d_v), pool). The kernel is named `kda_step` in
+    a trace where the gate is a channel's. With no live row the call is
+    still made: the one block its grid names, of the null row, is copied
+    onto itself (`step_at`).
     `interpret=None` picks the kernel on a TPU and the gather elsewhere;
     True runs the kernel in the Pallas interpreter."""
     if interpret is None and jax.default_backend() != "tpu":

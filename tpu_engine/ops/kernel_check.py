@@ -6,9 +6,11 @@ q_len 1 and at the prefill-chunk width, and the int8 variant of each) at the
 head geometry of a registered model; for a latent-attention model
 (`LATENT_MODELS`) it is the latent read at both widths instead, and for
 one whose rows also own a recurrent state (`RECURRENT_MODELS`) both forms
-of its recurrence (`kda_step`, `kda_chunk`; `ssd_step`, `ssd_chunk`),
-against the scan; `grouped_cases` the routed experts' grouped product
-over the four banks the benchmark's cells hold (`GROUPED_SHAPES`).
+of its recurrence (`gdn_step`, `gdn_chunk`; `kda_step`, `kda_chunk`;
+`ssd_step`, `ssd_chunk`), against the scan, the step also at the share of
+live rows its cell's ticks hold and with none (`STEP_LIVE`);
+`grouped_cases` the routed experts' grouped product over the four banks
+the benchmark's cells hold (`GROUPED_SHAPES`).
 `cell_cases` adds the ragged read at the shapes the benchmark's cells
 serve it at (`CELL_SHAPES`), `class_cases` the two calls a tick that
 carries a chunk makes of its rows, by the class of their runs
@@ -59,7 +61,14 @@ LATENT_MODELS = ("moonlight", "kimi_linear")
 # Mamba-2 cut the other way, 128 heads of (64, 128) in 8 groups (its read at
 # sixteen query heads a KV head a case of `CLASS_SHAPES`, its experts'
 # two-matrix grouped product `grouped_cases`).
-RECURRENT_MODELS = ("kimi_linear", "falcon_h1", "nemotron_h")
+RECURRENT_MODELS = ("kimi_linear", "falcon_h1", "nemotron_h", "olmo_hybrid")
+# The step over a lane's slots at the live rows a tick of the model's cell
+# holds beside the first case's (one dead row in 40 or 50: converse's mix):
+# reason's chunk ticks, agents' every tick, digest's. A dead row's blocks
+# are never named (`ops.gated_delta.step_at`); with no live row the pool
+# comes back as it was, the null row too.
+STEP_LIVE = {"kimi_linear": (69, 0), "falcon_h1": (0,), "nemotron_h": (40, 0),
+             "olmo_hybrid": (14, 0)}
 # Max |kernel - scan| accepted for the recurrence: float32 throughout, the
 # MXU's float32 passes.
 F32_TOLERANCE = 1e-3
@@ -73,6 +82,7 @@ N_BLOCKS = ROWS * TABLE_LEN + 1
 CHUNK = 256
 ROWS_RECURRENT = 128     # a lane whose rows' states are small: its slots
 ROWS_SSD = 64            # and one whose rows' states are 4.2 MB a layer
+ROWS_GDN = 16            # and one whose rows read contexts of 64 k tokens
 FLASH_BUCKETS = (16, 32, 64, 128, 256, 512, 1024)
 FLASH_BACKWARD_SEQ = 256
 # Max |kernel - reference| accepted for bf16 operands (and for the int8
@@ -327,20 +337,52 @@ def _latent_cases(model: str, interpret: bool):
                          workload, check)
 
 
+def _live_rows(slots: int, live):
+    """A step's rows over a pool of `slots` + 1: `live` None is one dead
+    row in 40 (in 50 over 128 slots), else that many live rows spread over
+    the slots; row 3 from a zero state. (rows, live, fresh)."""
+    at = jnp.arange(slots)
+    if live is None:
+        live = at % (50 if slots > 64 else 40) != 7
+    else:
+        live = (at * live) // slots != ((at + 1) * live) // slots
+    return jnp.where(live, at + 1, 0), live, at == 3
+
+
+def _step_error(out, want, operands) -> float:
+    """Max |kernel - reference| over the live rows' outputs and the whole
+    pool; NaN where a row that took no step (the null row, a dead row's,
+    the other layer's) is not bit for bit as it was."""
+    (o, pool), (o_want, pool_want) = out, want
+    old, layer, rows, live = operands[5:9]
+    stepped = jnp.zeros(old.shape[1], bool).at[rows].set(live)
+    same = (pool == old).all(axis=(2, 3, 4))
+    kept = same.at[layer].set(same[layer] | stepped).all()
+    err = jnp.maximum(
+        jnp.abs(jnp.where(live[:, None, None], o - o_want, 0.0)).max(),
+        jnp.abs(pool - pool_want).max())
+    return float(jnp.where(kept, err, jnp.nan))
+
+
+def _step_names(model: str, kernel: str, slots: int):
+    return [(f"{model}/{kernel}_step/B{slots}"
+             + ("" if live is None else f"/live{live}"), live)
+            for live in (None,) + STEP_LIVE[model]]
+
+
 def _recurrence_cases(model: str, interpret: bool):
-    """`kda_step` over 128 rows of a 129-row pool (three of them on the null
-    row, one from a zero state) and `kda_chunk` over a run of 256 tokens, at
-    `model`'s heads and lanes, the decays over the draw's range by head,
-    channel and token; each against `gdn_scan` from the same states."""
-    from tpu_engine.models.registry import (
-        _ensure_builtin_models_imported,
-        create_model,
-    )
+    """The delta rule's step over a lane's slots of a pool of slots + 1 rows
+    (`_live_rows`: 128 of kimi_linear's, 16 of olmo_hybrid's) and its chunked
+    form over a run of 256 tokens, at `model`'s heads and lanes and under its
+    gate (a key channel's, `kda_*`, or a head's, `gdn_*`), the decays over
+    the draw's range by head, channel and token; each against `gdn_scan`
+    from the same states."""
     from tpu_engine.ops import gated_delta as gd
 
-    _ensure_builtin_models_imported()
-    cfg = create_model(model).config
+    cfg = _config(model)
     h, dk, dv = cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim
+    kernel = cfg.recurrence
+    slots = ROWS_RECURRENT if kernel == "kda" else ROWS_GDN
 
     def operands(t, rows):
         ks = jax.random.split(jax.random.PRNGKey(t), 6)
@@ -351,25 +393,20 @@ def _recurrence_cases(model: str, interpret: bool):
         q = unit(jax.random.normal(ks[0], (t, h, dk))) / dk ** 0.5
         k = unit(jax.random.normal(ks[1], (t, h, dk)))
         v = jax.random.normal(ks[2], (t, h, dv))
-        g = jnp.log(jax.random.uniform(ks[3], (t, h, dk), minval=0.55,
-                                       maxval=1.0))
+        g = jnp.log(jax.random.uniform(
+            ks[3], (t, h, dk) if kernel == "kda" else (t, h), minval=0.55,
+            maxval=1.0))
         beta = jax.random.uniform(ks[4], (t, h))
         pool = jax.random.normal(ks[5], (2, rows, h, dv, dk))
         return q, k, v, g, beta, pool
 
-    def step_operands():
-        q, k, v, g, beta, pool = operands(ROWS_RECURRENT, ROWS_RECURRENT + 1)
-        at = jnp.arange(ROWS_RECURRENT)
-        live = at % 50 != 7
-        return (q, k, v, g, beta, pool, jnp.int32(1),
-                jnp.where(live, at + 1, 0), live, at == 3)
+    def step_operands(live):
+        return (operands(slots, slots + 1) + (jnp.int32(1),)
+                + _live_rows(slots, live))
 
     def step_check(out, operands):
-        (o, pool), live = out, operands[8]
-        o_want, want = gd.gdn_step_rows_reference(*operands)
-        return float(jnp.maximum(
-            jnp.abs(jnp.where(live[:, None, None], o - o_want, 0.0)).max(),
-            jnp.abs(pool - want).max()))
+        return _step_error(out, gd.gdn_step_rows_reference(*operands),
+                           operands)
 
     def chunk_operands():
         return operands(CHUNK, 3) + (jnp.int32(1), jnp.int32(2),
@@ -383,17 +420,20 @@ def _recurrence_cases(model: str, interpret: bool):
             jnp.abs(o - o_want).max(),
             jnp.abs(pool - old.at[layer, row].set(last)).max()))
 
-    yield KernelCase(f"{model}/kda_step/B{ROWS_RECURRENT}",
-                     functools.partial(gd.gdn_step_rows, interpret=interpret),
-                     step_operands, step_check)
-    yield KernelCase(f"{model}/kda_chunk/T{CHUNK}",
+    for name, live in _step_names(model, kernel, slots):
+        yield KernelCase(name,
+                         functools.partial(gd.gdn_step_rows,
+                                           interpret=interpret),
+                         functools.partial(step_operands, live), step_check)
+    yield KernelCase(f"{model}/{kernel}_chunk/T{CHUNK}",
                      functools.partial(gd.gdn_chunk_row, interpret=interpret),
                      chunk_operands, chunk_check)
 
 
 def _ssd_cases(model: str, interpret: bool):
-    """`ssd_step` over 64 rows of a 65-row pool (two of them on the null
-    row, one from a zero state) and `ssd_chunk` over a run of 256 tokens, at
+    """`ssd_step` over 64 rows of a 65-row pool (`_live_rows`: two of them
+    on the null row, then `STEP_LIVE`'s mixes; one from a zero state) and
+    `ssd_chunk` over a run of 256 tokens, at
     `model`'s heads, groups and lanes, the decays over the draw's range by
     head and token; each against `ssd_recurrent` from the same states."""
     from tpu_engine.ops import ssd
@@ -410,18 +450,13 @@ def _ssd_cases(model: str, interpret: bool):
         c = jax.random.normal(ks[4], (t, g, n))
         return x, dt, a, b, c, jax.random.normal(ks[5], (2, rows, h, p, n))
 
-    def step_operands():
-        at = jnp.arange(ROWS_SSD)
-        live = at % 40 != 7
-        return operands(ROWS_SSD, ROWS_SSD + 1) + (
-            jnp.int32(1), jnp.where(live, at + 1, 0), live, at == 3)
+    def step_operands(live):
+        return (operands(ROWS_SSD, ROWS_SSD + 1) + (jnp.int32(1),)
+                + _live_rows(ROWS_SSD, live))
 
     def step_check(out, operands):
-        (y, pool), live = out, operands[8]
-        y_want, want = ssd.ssd_step_rows_reference(*operands)
-        return float(jnp.maximum(
-            jnp.abs(jnp.where(live[:, None, None], y - y_want, 0.0)).max(),
-            jnp.abs(pool - want).max()))
+        return _step_error(out, ssd.ssd_step_rows_reference(*operands),
+                           operands)
 
     def chunk_operands():
         return operands(CHUNK, 3) + (jnp.int32(1), jnp.int32(2),
@@ -436,10 +471,11 @@ def _ssd_cases(model: str, interpret: bool):
             jnp.abs(y - y_want[0]).max(),
             jnp.abs(pool - old.at[layer, row].set(last[0])).max()))
 
-    yield KernelCase(f"{model}/ssd_step/B{ROWS_SSD}",
-                     functools.partial(ssd.ssd_step_rows,
-                                       interpret=interpret),
-                     step_operands, step_check)
+    for name, live in _step_names(model, "ssd", ROWS_SSD):
+        yield KernelCase(name,
+                         functools.partial(ssd.ssd_step_rows,
+                                           interpret=interpret),
+                         functools.partial(step_operands, live), step_check)
     yield KernelCase(f"{model}/ssd_chunk/T{CHUNK}",
                      functools.partial(ssd.ssd_chunk_row,
                                        interpret=interpret),
@@ -636,7 +672,8 @@ def kernel_cases(model: str, interpret: bool = False):
             yield from _latent_cases(model, interpret)
         if model in RECURRENT_MODELS:
             # By the kernels' names in a trace (the model's `recurrence`).
-            cases = {"kda": _recurrence_cases, "ssd": _ssd_cases}
+            cases = {"gdn": _recurrence_cases, "kda": _recurrence_cases,
+                     "ssd": _ssd_cases}
             yield from cases[_config(model).recurrence](model, interpret)
         return
     geo = _geometry(model)
@@ -683,8 +720,9 @@ def main() -> int:
             err = case.check(jax.block_until_ready(
                 jax.jit(case.kernel)(*operands)), operands)
             worst = max(worst, err)
-            limit = (F32_TOLERANCE if "/kda_" in case.name
-                     or "/ssd_" in case.name else BF16_TOLERANCE)
+            limit = (F32_TOLERANCE if any(
+                f"/{kernel}_" in case.name for kernel in ("gdn", "kda", "ssd"))
+                else BF16_TOLERANCE)
             if not err <= limit:            # NaN fails too
                 failed.append(case.name)
         print(json.dumps({"kernel": case.name, "interpret": False,

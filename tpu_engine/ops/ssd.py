@@ -47,10 +47,11 @@ of the rows ever materializes):
 
 - `ssd_step_rows`: one token a row, every row of a tick at once, the
   kernel `ssd_step` in a trace. The grid is (rows, blocks of a group's
-  heads); a head's update is multiplies and lane sums on its (p, n) tile:
-  the decay and the group's B and C come as rows, dt x as a column, y
-  leaves as a column. 2 x 4 p n bytes moved for 4 p n operations a head:
-  the memory bounds it by construction.
+  heads), the rows that step first and no byte moved for the others
+  (`ops.gated_delta.step_at`); a head's update is multiplies and lane sums
+  on its (p, n) tile: the decay and the group's B and C come as rows, dt x
+  as a column, y leaves as a column. 2 x 4 p n bytes moved for 4 p n
+  operations a head: the memory bounds it by construction.
 - `ssd_chunk_row`: a row's run of T tokens (a multiple of `SUB_CHUNK`, 64:
   the length this file's chunked kernel works in, whatever
   `mamba_chunk_size` a model states: the result does not depend on it)
@@ -77,7 +78,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_engine.ops.gated_delta import PRECISION, SUB_CHUNK, _dot
+from tpu_engine.ops.gated_delta import (
+    PRECISION,
+    SUB_CHUNK,
+    _copy_null_block,
+    _dot,
+    _in_order,
+    step_at,
+)
 
 # State a grid step of the step kernel holds (in and out, double-buffered:
 # four of these in VMEM): the 16 heads of one of Falcon-H1's two groups.
@@ -216,12 +224,11 @@ def ssd_step_rows_reference(x, dt, A, B, C, pool, layer, rows, live, fresh):
         jnp.where(live[:, None, None, None], new, old))
 
 
-def _step_kernel(rows_ref, layer_ref, live_ref, a_ref, dtx_ref, bc_ref,
-                 s_ref, s_out, y_ref, *, heads):
-    del rows_ref, layer_ref                      # the index maps read them
-    b = pl.program_id(0)
+def _step_kernel(rows_ref, layer_ref, order_ref, count_ref, a_ref, dtx_ref,
+                 bc_ref, s_ref, s_out, y_ref, *, heads):
+    del rows_ref, layer_ref, order_ref           # the index maps read them
 
-    @pl.when(live_ref[b] != 0)
+    @pl.when(pl.program_id(0) < count_ref[0])
     def _():
         b_row, c_row = bc_ref[0, 0, 0:1, :], bc_ref[0, 0, 1:2, :]
         for i in range(heads):
@@ -231,11 +238,7 @@ def _step_kernel(rows_ref, layer_ref, live_ref, a_ref, dtx_ref, bc_ref,
             y_ref[0, 0, :, i:i + 1] = jnp.sum(s * c_row, axis=1,
                                               keepdims=True)
 
-    @pl.when(live_ref[b] == 0)
-    def _():
-        # The null row, copied onto itself.
-        s_out[...] = s_ref[...]
-        y_ref[...] = jnp.zeros_like(y_ref)
+    _copy_null_block(count_ref, s_ref, s_out)
 
 
 def _step_call(x, dt, A, B, C, pool, layer, rows, live, fresh, *,
@@ -246,6 +249,7 @@ def _step_call(x, dt, A, B, C, pool, layer, rows, live, fresh, *,
     heads = max(e for e in range(1, h // g + 1) if (h // g) % e == 0
                 and (e == 1 or e * p * lanes * 4 <= _STEP_BLOCK_BYTES))
     blocks = h // heads                          # a block lies in ONE group
+    rows, order, count = _in_order(live, rows)
     # The decay a head as a row of the state's lanes, 0 where the row
     # starts from nothing; dt x as columns, a block's heads on the lanes.
     a = jnp.where(fresh[:, None], 0.0, jnp.exp(dt * A))
@@ -254,19 +258,21 @@ def _step_call(x, dt, A, B, C, pool, layer, rows, live, fresh, *,
     dtx = dtx.transpose(0, 1, 3, 2)
     bc = jnp.stack([B, C], axis=2)                          # (b, g, 2, n)
 
-    def state(b, j, rows, layer, *_):
-        return (layer[0], rows[b], j, 0, 0)
+    def state(i, j, rows, layer, order, count):
+        return (layer[0], rows[i], step_at(i, j, order, count, blocks)[1],
+                0, 0)
 
-    def block(b, j, *_):
-        return (b, j, 0, 0)
+    def block(i, j, rows, layer, order, count):
+        return step_at(i, j, order, count, blocks) + (0, 0)
 
-    def group(b, j, *_):
+    def group(i, j, rows, layer, order, count):
+        b, j = step_at(i, j, order, count, blocks)
         return (b, j * heads * g // h, 0, 0)
 
     pool, y = pl.pallas_call(
         functools.partial(_step_kernel, heads=heads),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,               # rows, layer, live
+            num_scalar_prefetch=4,               # rows, layer, order, count
             grid=(b, blocks),
             in_specs=[pl.BlockSpec((1, 1, heads, n), block),
                       pl.BlockSpec((1, 1, p, heads), block),
@@ -276,14 +282,15 @@ def _step_call(x, dt, A, B, C, pool, layer, rows, live, fresh, *,
                        pl.BlockSpec((1, 1, p, heads), block)]),
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                    jax.ShapeDtypeStruct((b, blocks, p, heads), jnp.float32)],
-        input_output_aliases={6: 0},             # the pool, in place
+        input_output_aliases={7: 0},             # the pool, in place
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret, name="ssd_step",
-    )(jnp.where(live, rows, 0).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), live.astype(jnp.int32),
+    )(rows, jnp.asarray(layer, jnp.int32).reshape(1), order, count,
       a, dtx, bc, pool)
-    return y.transpose(0, 1, 3, 2).reshape(b, h, p), pool
+    # A dead row's column was never written.
+    y = y.transpose(0, 1, 3, 2).reshape(b, h, p)
+    return jnp.where(live[:, None, None], y, 0.0), pool
 
 
 def ssd_step_rows(x, dt, A, B, C, pool, layer, rows, live, fresh, *,
@@ -292,8 +299,11 @@ def ssd_step_rows(x, dt, A, B, C, pool, layer, rows, live, fresh, *,
     dt: (B, h); A: (h,); B, C: (B, g, n); pool: (L, R, h, p, n) float32,
     donated; layer: the pool's layer; rows: (B,) each row's pool row;
     live: (B,) rows that take the step (the others' states are left as
-    they are, their outputs garbage); fresh: (B,) rows whose state is zero
-    before the step. Returns (y (B, h, p) without the D x skip, pool).
+    they are, their blocks never visited; their outputs mean nothing, 0
+    from the kernel); fresh: (B,) rows whose state is zero before the
+    step. Returns (y (B, h, p) without the D x skip, pool). With no live
+    row the call is still made: the one block its grid names, of the null
+    row, is copied onto itself (`ops.gated_delta.step_at`).
     `interpret=None` picks the kernel `ssd_step` on a TPU and the gather
     elsewhere; True runs the kernel in the Pallas interpreter."""
     if interpret is None and jax.default_backend() != "tpu":

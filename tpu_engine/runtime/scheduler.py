@@ -4519,9 +4519,11 @@ class ContinuousGenerator:
     def _note_state_work(self, pos0, qlen) -> None:
         """What a tick of a lane with both kinds of state asks of each, on
         its span: the tokens that go through the chunked form of the
-        recurrence (and the rows they belong to) and the rows that take
-        one step of it, under the kernels' names in a trace (the model's
-        `recurrence`: `gdn_*`, `kda_*` or `ssd_*`); the tokens the
+        recurrence (and the rows they belong to), the rows that take
+        one step of it and the rows the step call's grid spans (the
+        lane's slots: a row that takes no step costs an empty grid step
+        and moves no byte), under the kernels' names in a trace (the
+        model's `recurrence`: `gdn_*`, `kda_*` or `ssd_*`); the tokens the
         layers that attend read (`ctx_tokens_full` as a windowed lane's,
         with the tiles of each class that read them, or
         `ctx_tokens_latent` where the pool's two tensors differ in width:
@@ -4536,6 +4538,7 @@ class ContinuousGenerator:
             f"{kernel}_chunk_tokens": int(qlen[qlen > 1].sum()),
             f"{kernel}_chunk_rows": int((qlen > 1).sum()),
             f"{kernel}_step_rows": int((qlen == 1).sum()),
+            f"{kernel}_step_slots": len(qlen),
             read: int((pos0[fed] + qlen[fed]).sum()),
             "state_rows_held": self._spool.rows_held})
 
