@@ -109,6 +109,7 @@ from tpu_engine.ops.ssd import (
     ssd_chunked,
     ssd_step_rows,
 )
+from tpu_engine.utils.tracing import step_part
 
 
 class Mamba2Shapes:
@@ -394,12 +395,16 @@ def _run_layers(params, h, carry, cfg: FalconH1Config, mixers, dtype,
     that takes each layer's three writes into the stream (y_att, y_ssm,
     y_ffn)."""
     for layer, bp in enumerate(params["layers"]):
-        u = nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps)
+        # One norm feeds both mixers: it is put to the attention's part.
+        with step_part("attn/qkv"):
+            u = nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps)
         y_att, y_ssm, carry = mixers(layer, bp, u, carry)
-        h = (h + y_att + y_ssm).astype(dtype)
-        v = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
-        y_ffn = _ffn(bp["mlp"], v, cfg, dtype)
-        h = (h + y_ffn).astype(dtype)
+        with step_part("mixer/out"):
+            h = (h + y_att + y_ssm).astype(dtype)
+        with step_part("mlp"):
+            v = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
+            y_ffn = _ffn(bp["mlp"], v, cfg, dtype)
+            h = (h + y_ffn).astype(dtype)
         if branches is not None:
             branches.append((y_att, y_ssm, y_ffn))
     return h, carry
@@ -471,45 +476,57 @@ def falcon_h1_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     (pool, state), (table, rows) = caches, tables
     b, w = tokens.shape
     m = la.tiles_bound(b, w, 1, max_tokens)
-    plan = la.tile_plan(qlen, 1, m)
-    _, valid = la.tile_slots(plan, qlen, 1)
-    row, slot, valid = plan.row, jnp.minimum(plan.tile, w - 1), valid[:, 0]
     bs = pool.k.shape[2]
-    logical = pos0[row] + slot
-    cols = jnp.minimum(logical, table.shape[1] * bs - 1)
-    blk = jnp.where(valid, table[row, cols // bs], 0)  # invalid -> null block
-    classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
-                            max_tokens)
-    h = _embed(params, tokens[row, slot], cfg, dtype)
+    with step_part("plan"):
+        plan = la.tile_plan(qlen, 1, m)
+        _, valid = la.tile_slots(plan, qlen, 1)
+        row, slot, valid = (plan.row, jnp.minimum(plan.tile, w - 1),
+                            valid[:, 0])
+        logical = pos0[row] + slot
+        cols = jnp.minimum(logical, table.shape[1] * bs - 1)
+        # invalid -> null block
+        blk = jnp.where(valid, table[row, cols // bs], 0)
+        classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
+                                max_tokens)
+    with step_part("embed"):
+        h = _embed(params, tokens[row, slot], cfg, dtype)
 
     def mixers(layer, bp, u, carry):
         pool, state = carry
         at = cfg.pool_layer[layer]
-        q, k, v = _attn_inputs(bp["attn"], u, logical, cfg, dtype)
-        pool = _write_pool(pool, at, blk, cols % bs, k, v)
-        o = pa.ragged_read_by_class(attn_fn, q, pool, at, table, pos0,
-                                    classes, plan.start, row, slot)
+        with step_part("attn/qkv"):
+            q, k, v = _attn_inputs(bp["attn"], u, logical, cfg, dtype)
+        with step_part("attn/write"):
+            pool = _write_pool(pool, at, blk, cols % bs, k, v)
+        with step_part("attn/read"):
+            o = pa.ragged_read_by_class(attn_fn, q, pool, at, table, pos0,
+                                        classes, plan.start, row, slot)
         sp = bp["ssm"]
+        with step_part("mixer/in"):
+            step, chunk = _with_skip(sp, step_fn), _with_skip(sp, chunk_fn)
         y_ssm, state = _linear_rows(
             sp, u, state, at, plan.start, rows, pos0, qlen, w, cfg, dtype,
-            _with_skip(sp, step_fn), _with_skip(sp, chunk_fn),
-            inputs=_ssm_inputs, output=_ssm_output, conv=_ssm_conv)
-        return (_attn_output(bp["attn"], o.astype(dtype), cfg, dtype), y_ssm,
-                (pool, state))
+            step, chunk, inputs=_ssm_inputs, output=_ssm_output,
+            conv=_ssm_conv)
+        with step_part("attn/out"):
+            y_att = _attn_output(bp["attn"], o.astype(dtype), cfg, dtype)
+        return y_att, y_ssm, (pool, state)
 
     h, (pool, state) = _run_layers(params, h, (tuple(pool), tuple(state)),
                                    cfg, mixers, dtype)
-    if sample_slot is not None:
-        h = h[jnp.minimum(plan.start + jnp.minimum(sample_slot, w - 1),
-                          m - 1)]                                # (B, d)
-    else:
-        # Row b's new tokens in the list.
-        listed = jnp.minimum(plan.start[:, None] + jnp.arange(w)[None, :],
-                             m - 1)
-        h = jnp.where((jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
-                      h[listed], 0)
-    return (_head(params, h, cfg, dtype), (KVCache(*pool), state),
-            jnp.zeros((0, 1), jnp.int32))
+    with step_part("head"):
+        if sample_slot is not None:
+            h = h[jnp.minimum(plan.start + jnp.minimum(sample_slot, w - 1),
+                              m - 1)]                            # (B, d)
+        else:
+            # Row b's new tokens in the list.
+            listed = jnp.minimum(
+                plan.start[:, None] + jnp.arange(w)[None, :], m - 1)
+            h = jnp.where(
+                (jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
+                h[listed], 0)
+        return (_head(params, h, cfg, dtype), (KVCache(*pool), state),
+                jnp.zeros((0, 1), jnp.int32))
 
 
 # -- registry ----------------------------------------------------------------------

@@ -94,6 +94,7 @@ from tpu_engine.ops import nn
 from tpu_engine.ops.attention import KVCache
 from tpu_engine.ops.gated_delta import gdn_chunk, gdn_chunk_row, gdn_step_rows
 from tpu_engine.ops.latent_attention import PE_LANES, pad_rope_lanes
+from tpu_engine.utils.tracing import step_part
 
 
 @dataclasses.dataclass(frozen=True)
@@ -306,17 +307,25 @@ def _run_layers(params, h, carry, cfg: KimiLinearConfig, mixer, valid, dtype,
     (B, S, d). Returns (h, carry, rows (L_moe, n_routed))."""
     rows = []
     for layer, bp in enumerate(params["layers"]):
-        y, carry = mixer(layer, bp,
-                         nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps), carry)
-        h = (h + y).astype(dtype)
-        x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
+        enter, leave = (("mixer/in", "mixer/out") if cfg.linear[layer]
+                        else ("attn/qkv", "attn/out"))
+        with step_part(enter):
+            x = nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps)
+        y, carry = mixer(layer, bp, x, carry)
+        with step_part(leave):
+            h = (h + y).astype(dtype)
         if layer < cfg.n_dense_layers:
-            y = _mlp(bp["mlp"], x, dtype, cfg)
+            with step_part("mlp"):
+                x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
+                h = (h + _mlp(bp["mlp"], x, dtype, cfg)).astype(dtype)
         else:
+            with step_part("moe/route"):
+                x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
             y, taken = _moe_ffn(bp["mlp"], x, valid, cfg, dtype, held,
                                 max_tokens)
             rows.append(taken)
-        h = (h + y).astype(dtype)
+            with step_part("moe/shared"):
+                h = (h + y).astype(dtype)
     rows = (jnp.stack(rows) if rows
             else jnp.zeros((0, cfg.n_routed), jnp.int32))
     return h, carry, rows
@@ -393,17 +402,21 @@ def kimi_linear_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     b, w = tokens.shape
     bs = pool.k.shape[2]
     per_tile = la.slots_per_tile(cfg.n_heads, w)
-    plan = la.tile_plan(qlen, per_tile,
-                        la.tiles_bound(b, w, per_tile, max_tokens))
-    slot, valid = la.tile_slots(plan, qlen, per_tile)            # (N, S)
-    n = plan.row.shape[0]
-    row = plan.row[:, None]
-    slot = jnp.minimum(slot, w - 1)
-    cols = jnp.minimum(pos0[row] + slot, table.shape[1] * bs - 1)
-    blk = jnp.where(valid, table[row, cols // bs], 0)  # invalid -> null block
-    off = cols % bs
-    lengths = pos0 + qlen
-    h = nn.embedding(params["tok_embed"], tokens[row, slot]).astype(dtype)
+    with step_part("plan"):
+        plan = la.tile_plan(qlen, per_tile,
+                            la.tiles_bound(b, w, per_tile, max_tokens))
+        slot, valid = la.tile_slots(plan, qlen, per_tile)        # (N, S)
+        n = plan.row.shape[0]
+        row = plan.row[:, None]
+        slot = jnp.minimum(slot, w - 1)
+        cols = jnp.minimum(pos0[row] + slot, table.shape[1] * bs - 1)
+        # invalid -> null block
+        blk = jnp.where(valid, table[row, cols // bs], 0)
+        off = cols % bs
+        lengths = pos0 + qlen
+    with step_part("embed"):
+        h = nn.embedding(params["tok_embed"],
+                         tokens[row, slot]).astype(dtype)
 
     def mixer(layer, bp, x, carry):
         pool, state = carry
@@ -415,13 +428,19 @@ def kimi_linear_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
                 step_fn, chunk_fn, inputs=_kda_inputs, output=_kda_output)
             return y.reshape(x.shape), (pool, state)
         ap = bp["attn"]
-        q_nope, q_pe, c, k_pe = _attn_inputs(ap, x, None, cfg, dtype)
-        pool = _write_pool(pool, at, blk, off,
-                           pad_rope_lanes(k_pe)[:, :, None, :],
-                           c[:, :, None, :])
-        o_lat = attn_fn(_absorb(ap, q_nope, cfg, dtype), q_pe, *pool, at,
-                        table, plan, pos0, lengths, scale=cfg.attn_scale)
-        return _unabsorb(ap, o_lat, cfg, dtype), (pool, state)
+        with step_part("attn/qkv"):
+            q_nope, q_pe, c, k_pe = _attn_inputs(ap, x, None, cfg, dtype)
+        with step_part("attn/write"):
+            pool = _write_pool(pool, at, blk, off,
+                               pad_rope_lanes(k_pe)[:, :, None, :],
+                               c[:, :, None, :])
+        with step_part("attn/qkv"):
+            q_lat = _absorb(ap, q_nope, cfg, dtype)
+        with step_part("attn/read"):
+            o_lat = attn_fn(q_lat, q_pe, *pool, at, table, plan, pos0,
+                            lengths, scale=cfg.attn_scale)
+        with step_part("attn/out"):
+            return _unabsorb(ap, o_lat, cfg, dtype), (pool, state)
 
     h, (pool, state), taken = _run_layers(
         params, h, (tuple(pool), tuple(state)), cfg, mixer, valid, dtype,
@@ -434,12 +453,13 @@ def kimi_linear_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
         tile = jnp.minimum(start + slots // per_tile, n - 1)
         return h[tile, slots % per_tile]
 
-    if sample_slot is not None:
-        h = at(jnp.minimum(sample_slot, w - 1))                  # (B, d)
-    else:
-        every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
-        h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
-    return _head(params, h, cfg, dtype), (KVCache(*pool), state), taken
+    with step_part("head"):
+        if sample_slot is not None:
+            h = at(jnp.minimum(sample_slot, w - 1))              # (B, d)
+        else:
+            every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
+            h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
+        return _head(params, h, cfg, dtype), (KVCache(*pool), state), taken
 
 
 # -- registry ----------------------------------------------------------------------

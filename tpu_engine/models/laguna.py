@@ -75,6 +75,7 @@ from tpu_engine.models.transformer import (
 from tpu_engine.ops import nn
 from tpu_engine.ops.attention import KVCache, dot_product_attention
 from tpu_engine.ops.moe import routed_experts, sigmoid_topk_route
+from tpu_engine.utils.tracing import step_part
 
 # Slots a tile of the served step: 8 slots x 9 heads a KV head are 72 query
 # rows of the paged kernel's 128; a decode row in a tick that carries a
@@ -301,7 +302,8 @@ def _moe_ffn(mp, x, valid, cfg: LagunaConfig, dtype, held, max_tokens):
         flat, valid.reshape(-1), experts, weights, mp["experts"],
         first_group=-held[0], n_experts=cfg.n_routed, held=held,
         max_tokens=max_tokens, dtype=dtype)
-    return y.reshape(b, s, d) + _mlp(mp["shared"], x, dtype, cfg), rows
+    with step_part("moe/shared"):
+        return y.reshape(b, s, d) + _mlp(mp["shared"], x, dtype, cfg), rows
 
 
 def _run_layers(params, h, carry, cfg: LagunaConfig, attend, valid, dtype,
@@ -311,17 +313,23 @@ def _run_layers(params, h, carry, cfg: LagunaConfig, attend, valid, dtype,
     Returns (h, carry, rows (L_moe, n_routed))."""
     rows = []
     for layer, bp in enumerate(params["layers"]):
-        x = nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps)
+        with step_part("attn/qkv"):
+            x = nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps)
         o, gate, carry = attend(layer, bp["attn"], x, carry)
-        h = (h + _attn_output(bp["attn"], o, gate, dtype)).astype(dtype)
-        x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
+        with step_part("attn/out"):
+            h = (h + _attn_output(bp["attn"], o, gate, dtype)).astype(dtype)
         if layer < cfg.n_dense_layers:
-            y = _mlp(bp["mlp"], x, dtype, cfg)
+            with step_part("mlp"):
+                x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
+                h = (h + _mlp(bp["mlp"], x, dtype, cfg)).astype(dtype)
         else:
+            with step_part("moe/route"):
+                x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
             y, taken = _moe_ffn(bp["mlp"], x, valid, cfg, dtype, held,
                                 max_tokens)
             rows.append(taken)
-        h = (h + y).astype(dtype)
+            with step_part("moe/shared"):
+                h = (h + y).astype(dtype)
     rows = (jnp.stack(rows) if rows
             else jnp.zeros((0, cfg.n_routed), jnp.int32))
     return h, carry, rows
@@ -396,40 +404,47 @@ def laguna_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     held = held or cfg.held
     b, w = tokens.shape
     per_tile = min(w, _SLOTS_PER_TILE)
-    plan = la.tile_plan(qlen, per_tile,
-                        la.tiles_bound(b, w, per_tile, max_tokens))
-    slot, valid = la.tile_slots(plan, qlen, per_tile)            # (N, S)
-    row = plan.row[:, None]
-    slot = jnp.minimum(slot, w - 1)
-    logical = pos0[row] + slot
     bs = caches[0].k.shape[2]
-    cols = jnp.minimum(logical, tables[0].shape[1] * bs - 1)
-    off = cols % bs
-    # A tile is a row of the read: its own first column and new tokens.
-    tile_pos0 = pos0[plan.row] + plan.tile * per_tile
-    tile_qlen = valid.sum(-1).astype(jnp.int32)
-    # invalid -> the null block
-    blk = [jnp.where(valid, t[row, cols // bs], 0) for t in tables]
-    tile_tables = [t[plan.row] for t in tables]
-    # The full layers' read: the list flat, row b's run from its first tile.
-    classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
-                            max_tokens)
-    flat = (plan.start * per_tile, jnp.repeat(plan.row, per_tile),
-            slot.reshape(-1))
-    h = nn.embedding(params["tok_embed"], tokens[row, slot]).astype(dtype)
+    with step_part("plan"):
+        plan = la.tile_plan(qlen, per_tile,
+                            la.tiles_bound(b, w, per_tile, max_tokens))
+        slot, valid = la.tile_slots(plan, qlen, per_tile)        # (N, S)
+        row = plan.row[:, None]
+        slot = jnp.minimum(slot, w - 1)
+        logical = pos0[row] + slot
+        cols = jnp.minimum(logical, tables[0].shape[1] * bs - 1)
+        off = cols % bs
+        # A tile is a row of the read: its own first column and new tokens.
+        tile_pos0 = pos0[plan.row] + plan.tile * per_tile
+        tile_qlen = valid.sum(-1).astype(jnp.int32)
+        # invalid -> the null block
+        blk = [jnp.where(valid, t[row, cols // bs], 0) for t in tables]
+        tile_tables = [t[plan.row] for t in tables]
+        # The full layers' read: the list flat, row b's run from its first
+        # tile.
+        classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
+                                max_tokens)
+        flat = (plan.start * per_tile, jnp.repeat(plan.row, per_tile),
+                slot.reshape(-1))
+    with step_part("embed"):
+        h = nn.embedding(params["tok_embed"],
+                         tokens[row, slot]).astype(dtype)
 
     def attend(layer, ap, x, pools):
         kind = int(cfg.windowed[layer])
         at = cfg.pool_layer[layer]
-        q, k, v, gate = _attn_inputs(ap, x, logical, layer, cfg, dtype)
-        pool = _write_pool(pools[kind], at, blk[kind], off, k, v)
-        if kind:
-            o = attn_fn(q, *pool, at, tile_tables[kind], tile_pos0,
-                        tile_qlen, window=cfg.window)
-        else:
-            o = pa.ragged_read_by_class(
-                attn_fn, q.reshape((-1,) + q.shape[2:]), pool, at,
-                tables[0], pos0, classes, *flat).reshape(q.shape)
+        with step_part("attn/qkv"):
+            q, k, v, gate = _attn_inputs(ap, x, logical, layer, cfg, dtype)
+        with step_part("attn/write"):
+            pool = _write_pool(pools[kind], at, blk[kind], off, k, v)
+        with step_part("attn/read"):
+            if kind:
+                o = attn_fn(q, *pool, at, tile_tables[kind], tile_pos0,
+                            tile_qlen, window=cfg.window)
+            else:
+                o = pa.ragged_read_by_class(
+                    attn_fn, q.reshape((-1,) + q.shape[2:]), pool, at,
+                    tables[0], pos0, classes, *flat).reshape(q.shape)
         return o, gate, pools[:kind] + (pool,) + pools[kind + 1:]
 
     h, pools, rows = _run_layers(
@@ -443,13 +458,14 @@ def laguna_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
         tile = jnp.minimum(start + slots // per_tile, plan.row.shape[0] - 1)
         return h[tile, slots % per_tile]
 
-    if sample_slot is not None:
-        h = at(jnp.minimum(sample_slot, w - 1))                  # (B, d)
-    else:
-        every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
-        h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
-    return (_head(params, h, cfg, dtype),
-            tuple(KVCache(*p) for p in pools), rows)
+    with step_part("head"):
+        if sample_slot is not None:
+            h = at(jnp.minimum(sample_slot, w - 1))              # (B, d)
+        else:
+            every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
+            h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
+        return (_head(params, h, cfg, dtype),
+                tuple(KVCache(*p) for p in pools), rows)
 
 
 # -- registry ----------------------------------------------------------------------

@@ -58,6 +58,7 @@ from tpu_engine.ops import nn
 from tpu_engine.ops.attention import KVCache, rope
 from tpu_engine.ops.latent_attention import PE_LANES, pad_rope_lanes
 from tpu_engine.ops.moe import routed_experts, sigmoid_topk_route
+from tpu_engine.utils.tracing import step_part
 
 
 @dataclasses.dataclass(frozen=True)
@@ -276,7 +277,8 @@ def _moe_ffn(mp, x, valid, bank, first_group, cfg: MoonlightConfig, dtype,
         flat, valid.reshape(-1), experts, weights, bank,
         first_group=first_group, n_experts=cfg.n_routed, held=held,
         max_tokens=max_tokens, dtype=dtype)
-    return y.reshape(b, s, d) + _mlp(mp["shared"], x, dtype, cfg), rows
+    with step_part("moe/shared"):
+        return y.reshape(b, s, d) + _mlp(mp["shared"], x, dtype, cfg), rows
 
 
 def _whole_bank(params):
@@ -299,17 +301,20 @@ def _run_layers(params, h, carry, cfg: MoonlightConfig, layer_fn, valid,
     through both. Returns (h, carry, rows (L_moe, E))."""
 
     def attend(bp, h, carry, layer):
-        a, carry = layer_fn(bp, nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps),
-                            carry, layer)
-        return (h + a).astype(dtype), carry
+        with step_part("attn/qkv"):
+            x = nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps)
+        a, carry = layer_fn(bp, x, carry, layer)
+        with step_part("attn/out"):
+            return (h + a).astype(dtype), carry
 
     if cfg.n_dense_layers:
         def dense_body(state, xs):
             bp, layer = xs
             h, carry = attend(bp, *state, layer)
-            x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
-            return ((h + _mlp(bp["mlp"], x, dtype, cfg)).astype(dtype),
-                    carry), None
+            with step_part("mlp"):
+                x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
+                return ((h + _mlp(bp["mlp"], x, dtype, cfg)).astype(dtype),
+                        carry), None
 
         (h, carry), _ = jax.lax.scan(
             dense_body, (h, carry),
@@ -322,10 +327,12 @@ def _run_layers(params, h, carry, cfg: MoonlightConfig, layer_fn, valid,
         def moe_body(state, xs):
             bp, k = xs
             h, carry = attend(bp, *state, cfg.n_dense_layers + k)
-            x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
+            with step_part("moe/route"):
+                x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
             y, rows = _moe_ffn(bp["mlp"], x, valid, bank, k * cfg.n_routed,
                                cfg, dtype, held, max_tokens)
-            return ((h + y).astype(dtype), carry), rows
+            with step_part("moe/shared"):
+                return ((h + y).astype(dtype), carry), rows
 
         (h, carry), rows = jax.lax.scan(
             moe_body, (h, carry),
@@ -337,6 +344,7 @@ def _run_layers(params, h, carry, cfg: MoonlightConfig, layer_fn, valid,
 def _head(params, h, cfg: MoonlightConfig, dtype):
     h = nn.rmsnorm(params["ln_f"], h, eps=cfg.ln_eps)
     return nn.dense(params["head"], h, dtype=dtype).astype(jnp.float32)
+
 
 
 # -- the one-shot forward (expanded attention) -----------------------------------
@@ -414,28 +422,37 @@ def moonlight_step_rows_ragged(params, tokens, caches: KVCache, tables, pos0,
     b, w = tokens.shape
     bs = caches.k.shape[2]
     per_tile = la.slots_per_tile(cfg.n_heads, w)
-    plan = la.tile_plan(qlen, per_tile,
-                        la.tiles_bound(b, w, per_tile, max_tokens))
-    slot, valid = la.tile_slots(plan, qlen, per_tile)            # (N, S)
-    row = plan.row[:, None]
-    slot = jnp.minimum(slot, w - 1)
-    logical = pos0[row] + slot
-    cols = jnp.minimum(logical, tables.shape[1] * bs - 1)
-    blk = jnp.where(valid, tables[row, cols // bs], 0)  # invalid -> null block
-    off = cols % bs
-    lengths = pos0 + qlen
-    h = nn.embedding(params["tok_embed"], tokens[row, slot]).astype(dtype)
+    with step_part("plan"):
+        plan = la.tile_plan(qlen, per_tile,
+                            la.tiles_bound(b, w, per_tile, max_tokens))
+        slot, valid = la.tile_slots(plan, qlen, per_tile)        # (N, S)
+        row = plan.row[:, None]
+        slot = jnp.minimum(slot, w - 1)
+        logical = pos0[row] + slot
+        cols = jnp.minimum(logical, tables.shape[1] * bs - 1)
+        # invalid -> null block
+        blk = jnp.where(valid, tables[row, cols // bs], 0)
+        off = cols % bs
+        lengths = pos0 + qlen
+    with step_part("embed"):
+        h = nn.embedding(params["tok_embed"],
+                         tokens[row, slot]).astype(dtype)
 
     def layer_fn(bp, x, cache_kv, layer):
-        q_nope, q_pe, c, k_pe = _attn_inputs(bp["attn"], x, logical, cfg,
-                                             dtype)
-        cache_kv = _write_pool(cache_kv, layer, blk, off,
-                               pad_rope_lanes(k_pe)[:, :, None, :],
-                               c[:, :, None, :])
-        q_lat = _absorb(bp["attn"], q_nope, cfg, dtype)
-        o_lat = attn_fn(q_lat, q_pe, *cache_kv, layer, tables, plan, pos0,
-                        lengths, scale=cfg.attn_scale)
-        return _unabsorb(bp["attn"], o_lat, cfg, dtype), cache_kv
+        with step_part("attn/qkv"):
+            q_nope, q_pe, c, k_pe = _attn_inputs(bp["attn"], x, logical,
+                                                 cfg, dtype)
+        with step_part("attn/write"):
+            cache_kv = _write_pool(cache_kv, layer, blk, off,
+                                   pad_rope_lanes(k_pe)[:, :, None, :],
+                                   c[:, :, None, :])
+        with step_part("attn/qkv"):
+            q_lat = _absorb(bp["attn"], q_nope, cfg, dtype)
+        with step_part("attn/read"):
+            o_lat = attn_fn(q_lat, q_pe, *cache_kv, layer, tables, plan,
+                            pos0, lengths, scale=cfg.attn_scale)
+        with step_part("attn/out"):
+            return _unabsorb(bp["attn"], o_lat, cfg, dtype), cache_kv
 
     h, cache_kv, rows = _run_layers(params, h, tuple(caches), cfg, layer_fn,
                                     valid, dtype, held, max_tokens)
@@ -447,14 +464,15 @@ def moonlight_step_rows_ragged(params, tokens, caches: KVCache, tables, pos0,
         tile = jnp.minimum(start + slots // per_tile, plan.row.shape[0] - 1)
         return h[tile, slots % per_tile]
 
-    if sample_slot is not None:
-        h = at(jnp.minimum(sample_slot, w - 1))                  # (B, d)
-    else:
-        # Every slot's logits, as the transformer step returns them; a
-        # padding slot has no token in the list and reads zero.
-        every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
-        h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
-    return _head(params, h, cfg, dtype), KVCache(*cache_kv), rows
+    with step_part("head"):
+        if sample_slot is not None:
+            h = at(jnp.minimum(sample_slot, w - 1))              # (B, d)
+        else:
+            # Every slot's logits, as the transformer step returns them; a
+            # padding slot has no token in the list and reads zero.
+            every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
+            h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
+        return _head(params, h, cfg, dtype), KVCache(*cache_kv), rows
 
 
 # -- registry ----------------------------------------------------------------------

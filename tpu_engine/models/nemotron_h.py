@@ -106,6 +106,7 @@ from tpu_engine.ops import nn
 from tpu_engine.ops.attention import KVCache, dot_product_attention
 from tpu_engine.ops.moe import relu2, routed_experts, sigmoid_topk_route
 from tpu_engine.ops.ssd import ssd_chunk_row, ssd_step_rows
+from tpu_engine.utils.tracing import step_part
 
 # A layer's kind, as the published pattern writes it.
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
@@ -311,16 +312,19 @@ def _latent_moe(mp, u, valid, cfg: NemotronHConfig, dtype, held,
     rows (n_routed,): the rows each HELD expert took, zero elsewhere)."""
     experts, weights = sigmoid_topk_route(u, mp["router"], cfg.top_k,
                                           cfg.routed_scale)
-    latent = nn.dense(mp["latent_down"], u, dtype=dtype)
+    with step_part("moe/experts"):
+        latent = nn.dense(mp["latent_down"], u, dtype=dtype)
     # The bank's group 0 is expert `held[0]`.
     routed, rows = routed_experts(
         latent, valid, experts, weights, mp["experts"],
         first_group=-held[0], n_experts=cfg.n_routed, held=held,
         max_tokens=max_tokens, dtype=dtype, activation=relu2)
-    shared = nn.dense(mp["shared"]["proj"],
-                      relu2(nn.dense(mp["shared"]["up"], u, dtype=dtype)),
-                      dtype=dtype)
-    return nn.dense(mp["latent_up"], routed, dtype=dtype) + shared, rows
+    with step_part("moe/shared"):
+        shared = nn.dense(mp["shared"]["proj"],
+                          relu2(nn.dense(mp["shared"]["up"], u, dtype=dtype)),
+                          dtype=dtype)
+    with step_part("moe/experts"):
+        return nn.dense(mp["latent_up"], routed, dtype=dtype) + shared, rows
 
 
 def _run_layers(params, h, carry, cfg: NemotronHConfig, mamba, attend, valid,
@@ -330,8 +334,12 @@ def _run_layers(params, h, carry, cfg: NemotronHConfig, mamba, attend, valid,
     mixer's output, carry), `at` the layer of the kind's pool. Returns (h,
     carry, rows (L_moe, n_routed))."""
     rows = []
+    parts = {MAMBA: ("mixer/in", "mixer/out"),
+             ATTENTION: ("attn/qkv", "attn/out")}
     for kind, at, bp in zip(cfg.pattern, cfg.pool_layer, params["layers"]):
-        u = nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps)
+        enter, leave = parts.get(kind, ("moe/route", "moe/experts"))
+        with step_part(enter):
+            u = nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps)
         if kind == MAMBA:
             y, carry = mamba(at, bp["ssm"], u, carry)
         elif kind == ATTENTION:
@@ -340,7 +348,8 @@ def _run_layers(params, h, carry, cfg: NemotronHConfig, mamba, attend, valid,
             y, taken = _latent_moe(bp["mlp"], u, valid, cfg, dtype, held,
                                    max_tokens)
             rows.append(taken)
-        h = (h + y).astype(dtype)
+        with step_part(leave):
+            h = (h + y).astype(dtype)
     rows = (jnp.stack(rows) if rows
             else jnp.zeros((0, cfg.n_routed), jnp.int32))
     return h, carry, rows
@@ -413,45 +422,58 @@ def nemotron_h_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     (pool, state), (table, rows) = caches, tables
     b, w = tokens.shape
     m = la.tiles_bound(b, w, 1, max_tokens)
-    plan = la.tile_plan(qlen, 1, m)
-    _, valid = la.tile_slots(plan, qlen, 1)
-    row, slot, valid = plan.row, jnp.minimum(plan.tile, w - 1), valid[:, 0]
     bs = pool.k.shape[2]
-    cols = jnp.minimum(pos0[row] + slot, table.shape[1] * bs - 1)
-    blk = jnp.where(valid, table[row, cols // bs], 0)  # invalid -> null block
-    classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
-                            max_tokens)
-    h = nn.embedding(params["tok_embed"], tokens[row, slot]).astype(dtype)
+    with step_part("plan"):
+        plan = la.tile_plan(qlen, 1, m)
+        _, valid = la.tile_slots(plan, qlen, 1)
+        row, slot, valid = (plan.row, jnp.minimum(plan.tile, w - 1),
+                            valid[:, 0])
+        cols = jnp.minimum(pos0[row] + slot, table.shape[1] * bs - 1)
+        # invalid -> null block
+        blk = jnp.where(valid, table[row, cols // bs], 0)
+        classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
+                                max_tokens)
+    with step_part("embed"):
+        h = nn.embedding(params["tok_embed"],
+                         tokens[row, slot]).astype(dtype)
 
     def mamba(at, sp, u, carry):
         pool, state = carry
+        with step_part("mixer/in"):
+            step, chunk = _with_skip(sp, step_fn), _with_skip(sp, chunk_fn)
         y, state = _linear_rows(
             sp, u, state, at, plan.start, rows, pos0, qlen, w, cfg, dtype,
-            _with_skip(sp, step_fn), _with_skip(sp, chunk_fn),
-            inputs=_ssm_inputs, output=_ssm_output, conv=_ssm_conv)
+            step, chunk, inputs=_ssm_inputs, output=_ssm_output,
+            conv=_ssm_conv)
         return y, (pool, state)
 
     def attend(at, ap, u, carry):
         pool, state = carry
-        q, k, v = _attn_inputs(ap, u, cfg, dtype)
-        pool = _write_pool(pool, at, blk, cols % bs, k, v)
-        o = pa.ragged_read_by_class(attn_fn, q, pool, at, table, pos0,
-                                    classes, plan.start, row, slot)
-        return _attn_output(ap, o, dtype), (pool, state)
+        with step_part("attn/qkv"):
+            q, k, v = _attn_inputs(ap, u, cfg, dtype)
+        with step_part("attn/write"):
+            pool = _write_pool(pool, at, blk, cols % bs, k, v)
+        with step_part("attn/read"):
+            o = pa.ragged_read_by_class(attn_fn, q, pool, at, table, pos0,
+                                        classes, plan.start, row, slot)
+        with step_part("attn/out"):
+            return _attn_output(ap, o, dtype), (pool, state)
 
     h, (pool, state), taken = _run_layers(
         params, h, (tuple(pool), tuple(state)), cfg, mamba, attend, valid,
         dtype, held, max_tokens)
-    if sample_slot is not None:
-        h = h[jnp.minimum(plan.start + jnp.minimum(sample_slot, w - 1),
-                          m - 1)]                                # (B, d)
-    else:
-        # Row b's new tokens in the list.
-        listed = jnp.minimum(plan.start[:, None] + jnp.arange(w)[None, :],
-                             m - 1)
-        h = jnp.where((jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
-                      h[listed], 0)
-    return _head(params, h, cfg, dtype), (KVCache(*pool), state), taken
+    with step_part("head"):
+        if sample_slot is not None:
+            h = h[jnp.minimum(plan.start + jnp.minimum(sample_slot, w - 1),
+                              m - 1)]                            # (B, d)
+        else:
+            # Row b's new tokens in the list.
+            listed = jnp.minimum(
+                plan.start[:, None] + jnp.arange(w)[None, :], m - 1)
+            h = jnp.where(
+                (jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
+                h[listed], 0)
+        return _head(params, h, cfg, dtype), (KVCache(*pool), state), taken
 
 
 # -- registry ----------------------------------------------------------------------

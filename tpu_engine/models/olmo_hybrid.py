@@ -73,6 +73,7 @@ from tpu_engine.ops.gated_delta import (
     gdn_chunk_row,
     gdn_step_rows,
 )
+from tpu_engine.utils.tracing import step_part
 
 
 @dataclasses.dataclass(frozen=True)
@@ -244,9 +245,11 @@ def _run_layers(params, h, carry, cfg: OlmoHybridConfig, mixer, dtype):
     the layers in order (a Python loop: the layers differ in shape)."""
     for layer, bp in enumerate(params["layers"]):
         y, carry = mixer(layer, bp, h, carry)
-        h = (h + nn.rmsnorm(bp["ln1"], y, eps=cfg.ln_eps)).astype(dtype)
-        y = _mlp(bp["mlp"], h, dtype, cfg)
-        h = (h + nn.rmsnorm(bp["ln2"], y, eps=cfg.ln_eps)).astype(dtype)
+        with step_part("mixer/out" if cfg.linear[layer] else "attn/out"):
+            h = (h + nn.rmsnorm(bp["ln1"], y, eps=cfg.ln_eps)).astype(dtype)
+        with step_part("mlp"):
+            y = _mlp(bp["mlp"], h, dtype, cfg)
+            h = (h + nn.rmsnorm(bp["ln2"], y, eps=cfg.ln_eps)).astype(dtype)
     return h, carry
 
 
@@ -320,68 +323,73 @@ def _linear_rows(lp, x, state, at: int, start, rows, pos0, qlen, width: int,
     conv = conv or _conv_heads
     m = x.shape[0]
     s_pool, c_pool = state
-    mixed, z, g, beta = (inputs or _lin_inputs)(lp, x, cfg, dtype)
+    with step_part("mixer/in"):
+        mixed, z, g, beta = (inputs or _lin_inputs)(lp, x, cfg, dtype)
     fresh = pos0 == 0
 
-    # Rows that decode (or prefill a single token): one step each.
-    first = jnp.minimum(start, m - 1)
-    # A pool may keep a row's tail in another shape of as many numbers.
-    tail_shape = (cfg.conv_width - 1, mixed.shape[-1])
-    tail_old = c_pool[at, rows].reshape((-1,) + tail_shape)
-    ext = jnp.concatenate(
-        [jnp.where(fresh[:, None, None], 0.0, tail_old),
-         mixed[first][:, None]], axis=1)
-    q, k, v = conv(lp, ext, cfg)
-    steps = qlen == 1
-    o, s_pool = step_fn(q[:, 0], k[:, 0], v[:, 0], g[first], beta[first],
-                        s_pool, at, rows, steps, fresh)
-    c_pool = c_pool.at[at, rows].set(
-        jnp.where(steps[:, None, None], ext[:, 1:], tail_old)
-        .reshape((-1,) + c_pool.shape[2:]))
-    # One padded run behind the list: a run's slice never clamps, and a
-    # row that took no step writes there.
-    run = _pad_run(width)
-    o_all = jnp.zeros((m + run,) + o.shape[1:], jnp.float32)
-    o_all = o_all.at[jnp.where(steps, first, m)].set(o)
+    with step_part("mixer/step"):
+        # Rows that decode (or prefill a single token): one step each.
+        first = jnp.minimum(start, m - 1)
+        # A pool may keep a row's tail in another shape of as many numbers.
+        tail_shape = (cfg.conv_width - 1, mixed.shape[-1])
+        tail_old = c_pool[at, rows].reshape((-1,) + tail_shape)
+        ext = jnp.concatenate(
+            [jnp.where(fresh[:, None, None], 0.0, tail_old),
+             mixed[first][:, None]], axis=1)
+        q, k, v = conv(lp, ext, cfg)
+        steps = qlen == 1
+        o, s_pool = step_fn(q[:, 0], k[:, 0], v[:, 0], g[first], beta[first],
+                            s_pool, at, rows, steps, fresh)
+        c_pool = c_pool.at[at, rows].set(
+            jnp.where(steps[:, None, None], ext[:, 1:], tail_old)
+            .reshape((-1,) + c_pool.shape[2:]))
+        # One padded run behind the list: a run's slice never clamps, and a
+        # row that took no step writes there.
+        run = _pad_run(width)
+        o_all = jnp.zeros((m + run,) + o.shape[1:], jnp.float32)
+        o_all = o_all.at[jnp.where(steps, first, m)].set(o)
 
-    if width > 1:
-        mixed, g, beta = (jnp.pad(y, ((0, run),) + ((0, 0),) * (y.ndim - 1))
-                          for y in (mixed, g, beta))
-        chunks = qlen > 1
-        order = jnp.argsort(~chunks, stable=True)
+    with step_part("mixer/chunk"):
+        if width > 1:
+            mixed, g, beta = (
+                jnp.pad(y, ((0, run),) + ((0, 0),) * (y.ndim - 1))
+                for y in (mixed, g, beta))
+            chunks = qlen > 1
+            order = jnp.argsort(~chunks, stable=True)
 
-        def one_row(i, carry):
-            s_pool, c_pool, o_all = carry
-            b = order[i]
-            off, r, n = start[b], rows[b], qlen[b]
-            valid = (jnp.arange(run) < n)[:, None]
+            def one_row(i, carry):
+                s_pool, c_pool, o_all = carry
+                b = order[i]
+                off, r, n = start[b], rows[b], qlen[b]
+                valid = (jnp.arange(run) < n)[:, None]
 
-            def run_of(y):
-                return jax.lax.dynamic_slice_in_dim(y, off, run)
+                def run_of(y):
+                    return jax.lax.dynamic_slice_in_dim(y, off, run)
 
-            ext = jnp.concatenate(
-                [jnp.where(fresh[b], 0.0, c_pool[at, r].reshape(tail_shape)),
-                 run_of(mixed)])
-            q, k, v = conv(lp, ext, cfg)
-            # Past the row's last new token nothing decays and nothing
-            # is written.
-            g_run = run_of(g)
-            o, s_pool = chunk_fn(
-                q, k, v,
-                jnp.where(valid.reshape((run,) + (1,) * (g_run.ndim - 1)),
-                          g_run, 0.0),
-                jnp.where(valid, run_of(beta), 0.0), s_pool, at, r,
-                fresh[b])
-            tail = jax.lax.dynamic_slice_in_dim(ext, n, cfg.conv_width - 1)
-            o = jnp.where(valid[:, :, None], o, run_of(o_all))
-            return (s_pool,
-                    c_pool.at[at, r].set(tail.reshape(c_pool.shape[2:])),
-                    jax.lax.dynamic_update_slice_in_dim(o_all, o, off, 0))
+                old = c_pool[at, r].reshape(tail_shape)
+                ext = jnp.concatenate(
+                    [jnp.where(fresh[b], 0.0, old), run_of(mixed)])
+                q, k, v = conv(lp, ext, cfg)
+                # Past the row's last new token nothing decays and nothing
+                # is written.
+                g_run = run_of(g)
+                o, s_pool = chunk_fn(
+                    q, k, v,
+                    jnp.where(valid.reshape((run,) + (1,) * (g_run.ndim - 1)),
+                              g_run, 0.0),
+                    jnp.where(valid, run_of(beta), 0.0), s_pool, at, r,
+                    fresh[b])
+                tail = jax.lax.dynamic_slice_in_dim(ext, n, cfg.conv_width - 1)
+                o = jnp.where(valid[:, :, None], o, run_of(o_all))
+                return (s_pool,
+                        c_pool.at[at, r].set(tail.reshape(c_pool.shape[2:])),
+                        jax.lax.dynamic_update_slice_in_dim(o_all, o, off, 0))
 
-        s_pool, c_pool, o_all = jax.lax.fori_loop(
-            0, chunks.sum(), one_row, (s_pool, c_pool, o_all))
-    return ((output or _lin_output)(lp, o_all[:m], z, cfg, dtype),
-            (s_pool, c_pool))
+            s_pool, c_pool, o_all = jax.lax.fori_loop(
+                0, chunks.sum(), one_row, (s_pool, c_pool, o_all))
+    with step_part("mixer/out"):
+        return ((output or _lin_output)(lp, o_all[:m], z, cfg, dtype),
+                (s_pool, c_pool))
 
 
 def olmo_hybrid_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
@@ -422,15 +430,20 @@ def olmo_hybrid_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     (pool, state), (table, rows) = caches, tables
     b, w = tokens.shape
     m = la.tiles_bound(b, w, 1, max_tokens)
-    plan = la.tile_plan(qlen, 1, m)
-    _, valid = la.tile_slots(plan, qlen, 1)
-    row, slot, valid = plan.row, jnp.minimum(plan.tile, w - 1), valid[:, 0]
     bs = pool.k.shape[2]
-    cols = jnp.minimum(pos0[row] + slot, table.shape[1] * bs - 1)
-    blk = jnp.where(valid, table[row, cols // bs], 0)  # invalid -> null block
-    classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
-                            max_tokens)
-    h = nn.embedding(params["tok_embed"], tokens[row, slot]).astype(dtype)
+    with step_part("plan"):
+        plan = la.tile_plan(qlen, 1, m)
+        _, valid = la.tile_slots(plan, qlen, 1)
+        row, slot, valid = (plan.row, jnp.minimum(plan.tile, w - 1),
+                            valid[:, 0])
+        cols = jnp.minimum(pos0[row] + slot, table.shape[1] * bs - 1)
+        # invalid -> null block
+        blk = jnp.where(valid, table[row, cols // bs], 0)
+        classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
+                                max_tokens)
+    with step_part("embed"):
+        h = nn.embedding(params["tok_embed"],
+                         tokens[row, slot]).astype(dtype)
 
     def mixer(layer, bp, x, carry):
         pool, state = carry
@@ -440,26 +453,32 @@ def olmo_hybrid_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
                                     rows, pos0, qlen, w, cfg, dtype, step_fn,
                                     chunk_fn)
             return y, (pool, state)
-        q, k, v = _attn_inputs(bp["attn"], x, cfg, dtype)
-        pool = _write_pool(pool, at, blk, cols % bs, k, v)
-        o = pa.ragged_read_by_class(attn_fn, q, pool, at, table, pos0,
-                                    classes, plan.start, row, slot)
-        o = o.astype(dtype).reshape(m, -1)
-        return nn.dense(bp["attn"]["wo"], o, dtype=dtype), (pool, state)
+        with step_part("attn/qkv"):
+            q, k, v = _attn_inputs(bp["attn"], x, cfg, dtype)
+        with step_part("attn/write"):
+            pool = _write_pool(pool, at, blk, cols % bs, k, v)
+        with step_part("attn/read"):
+            o = pa.ragged_read_by_class(attn_fn, q, pool, at, table, pos0,
+                                        classes, plan.start, row, slot)
+            o = o.astype(dtype).reshape(m, -1)
+        with step_part("attn/out"):
+            return nn.dense(bp["attn"]["wo"], o, dtype=dtype), (pool, state)
 
     h, (pool, state) = _run_layers(params, h, (tuple(pool), tuple(state)),
                                    cfg, mixer, dtype)
-    if sample_slot is not None:
-        h = h[jnp.minimum(plan.start + jnp.minimum(sample_slot, w - 1),
-                          m - 1)]                                # (B, d)
-    else:
-        # Row b's new tokens in the list.
-        listed = jnp.minimum(plan.start[:, None] + jnp.arange(w)[None, :],
-                             m - 1)
-        h = jnp.where((jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
-                      h[listed], 0)
-    return (_head(params, h, cfg, dtype), (KVCache(*pool), state),
-            jnp.zeros((0, 1), jnp.int32))
+    with step_part("head"):
+        if sample_slot is not None:
+            h = h[jnp.minimum(plan.start + jnp.minimum(sample_slot, w - 1),
+                              m - 1)]                            # (B, d)
+        else:
+            # Row b's new tokens in the list.
+            listed = jnp.minimum(
+                plan.start[:, None] + jnp.arange(w)[None, :], m - 1)
+            h = jnp.where(
+                (jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
+                h[listed], 0)
+        return (_head(params, h, cfg, dtype), (KVCache(*pool), state),
+                jnp.zeros((0, 1), jnp.int32))
 
 
 # -- registry ----------------------------------------------------------------------

@@ -94,6 +94,7 @@ from tpu_engine.models.transformer import TransformerConfig, _write_pool
 from tpu_engine.ops import nn
 from tpu_engine.ops.attention import KVCache, dot_product_attention
 from tpu_engine.ops.moe import routed_experts, softmax_topk_route
+from tpu_engine.utils.tracing import step_part
 
 # The spread of the scores q.k / sqrt(D) as drawn (module docstring).
 _SCORE_SPREAD = 4.0
@@ -241,15 +242,19 @@ def _run_layers(params, h, carry, cfg: SdarConfig, attend, valid, dtype,
     layers in order. Returns (h, carry, rows (L, n_routed))."""
     rows = []
     for layer, bp in enumerate(params["layers"]):
-        x = nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps)
+        with step_part("attn/qkv"):
+            x = nn.rmsnorm(bp["ln1"], h, eps=cfg.ln_eps)
         o, carry = attend(layer, bp["attn"], x, carry)
-        o = nn.dense(bp["attn"]["wo"], o.reshape(o.shape[:2] + (-1,)),
-                     dtype=dtype)
-        h = (h + o).astype(dtype)
-        x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
+        with step_part("attn/out"):
+            o = nn.dense(bp["attn"]["wo"], o.reshape(o.shape[:2] + (-1,)),
+                         dtype=dtype)
+            h = (h + o).astype(dtype)
+        with step_part("moe/route"):
+            x = nn.rmsnorm(bp["ln2"], h, eps=cfg.ln_eps)
         y, taken = _moe_ffn(bp["mlp"], x, valid, cfg, dtype, max_tokens)
         rows.append(taken)
-        h = (h + y).astype(dtype)
+        with step_part("moe/experts"):
+            h = (h + y).astype(dtype)
     return h, carry, jnp.stack(rows)
 
 
@@ -321,26 +326,35 @@ def sdar_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     n_tiles = b * (w // run)
     if max_tokens is not None:
         n_tiles = min(n_tiles, -(-max_tokens // run))
-    plan = la.tile_plan(qlen, run, n_tiles)
-    slot, valid = la.tile_slots(plan, qlen, run)                 # (N, L)
-    row = plan.row[:, None]
-    slot = jnp.minimum(slot, w - 1)
-    logical = pos0[row] + slot
     bs = caches.k.shape[2]
-    cols = jnp.minimum(logical, tables.shape[1] * bs - 1)
-    blk = jnp.where(valid, tables[row, cols // bs], 0)   # invalid -> null
-    off = cols % bs
-    classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
-                            max_tokens, run_slots=run)
-    flat = (plan.start * run, jnp.repeat(plan.row, run), slot.reshape(-1))
-    h = nn.embedding(params["tok_embed"], tokens[row, slot]).astype(dtype)
+    with step_part("plan"):
+        plan = la.tile_plan(qlen, run, n_tiles)
+        slot, valid = la.tile_slots(plan, qlen, run)             # (N, L)
+        row = plan.row[:, None]
+        slot = jnp.minimum(slot, w - 1)
+        logical = pos0[row] + slot
+        cols = jnp.minimum(logical, tables.shape[1] * bs - 1)
+        # invalid -> null
+        blk = jnp.where(valid, tables[row, cols // bs], 0)
+        off = cols % bs
+        classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
+                                max_tokens, run_slots=run)
+        flat = (plan.start * run, jnp.repeat(plan.row, run),
+                slot.reshape(-1))
+    with step_part("embed"):
+        h = nn.embedding(params["tok_embed"],
+                         tokens[row, slot]).astype(dtype)
 
     def attend(layer, ap, x, pool):
-        q, k, v = _attn_inputs(ap, x, logical, cfg, dtype)
-        pool = _write_pool(pool, layer, blk, off, k, v)
-        o = pa.ragged_read_by_class(
-            attn_fn, q.reshape((-1,) + q.shape[2:]), pool, layer, tables,
-            pos0, classes, *flat, mask_block=run).reshape(q.shape)
+        with step_part("attn/qkv"):
+            q, k, v = _attn_inputs(ap, x, logical, cfg, dtype)
+        with step_part("attn/write"):
+            pool = _write_pool(pool, layer, blk, off, k, v)
+        with step_part("attn/read"):
+            o = pa.ragged_read_by_class(
+                attn_fn, q.reshape((-1,) + q.shape[2:]), pool, layer,
+                tables, pos0, classes, *flat,
+                mask_block=run).reshape(q.shape)
         return o, pool
 
     h, pool, rows = _run_layers(params, h, tuple(caches), cfg, attend,
@@ -353,16 +367,18 @@ def sdar_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
         tile = jnp.minimum(start + slots // run, plan.row.shape[0] - 1)
         return h[tile, slots % run]
 
-    if sample_slot is not None:
-        h = at(jnp.minimum(sample_slot, w - 1))
-        # (B, n) slots: the head's rows side by side, (B * n, vocab). A
-        # (B, n, vocab) result is re-laid out for its reader, 155 MB a copy
-        # at 64 x 4 x 151,936 (measured on the chip: 0.8 ms a tick).
-        h = h.reshape(-1, h.shape[-1]) if sample_slot.ndim == 2 else h
-    else:
-        every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
-        h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
-    return _head(params, h, cfg, dtype), KVCache(*pool), rows
+    with step_part("head"):
+        if sample_slot is not None:
+            h = at(jnp.minimum(sample_slot, w - 1))
+            # (B, n) slots: the head's rows side by side, (B * n, vocab).
+            # A (B, n, vocab) result is re-laid out for its reader, 155 MB
+            # a copy at 64 x 4 x 151,936 (measured on the chip: 0.8 ms a
+            # tick).
+            h = h.reshape(-1, h.shape[-1]) if sample_slot.ndim == 2 else h
+        else:
+            every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
+            h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
+        return _head(params, h, cfg, dtype), KVCache(*pool), rows
 
 
 # -- registry ----------------------------------------------------------------------

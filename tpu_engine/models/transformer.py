@@ -38,6 +38,7 @@ from tpu_engine.ops.attention import (
     rope,
     _split_heads,
 )
+from tpu_engine.utils.tracing import step_part
 
 
 @dataclasses.dataclass(frozen=True)
@@ -632,31 +633,40 @@ def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
     the padding slots' outputs are garbage the scheduler ignores."""
     bs = cache_kv[0].shape[2]
     b, w = h.shape[:2]
-    x = _norm(bp["ln1"], h, cfg)
     offs = jnp.arange(w)[None, :]
     logical = pos0[:, None] + offs                           # (B, W)
-    q, k, v = _project_qkv(bp, x, cfg, dtype=dtype, positions=logical)
-    if listed is not None:
-        blk, off = listed.blk, listed.off
-        # Whole rows of lanes out of the (B x W, H_kv * D) form the
-        # projection left: gathered by head, XLA first re-lays all B x W
-        # slots out with (H_kv, D) minor, a copy a tensor a layer.
-        k_new, v_new = (x.reshape(b * w, -1)[listed.at].reshape(
-            (-1,) + x.shape[2:]) for x in (k, v))
-    else:
-        rows = jnp.arange(b)[:, None]
-        max_col = tables.shape[1] * bs - 1
-        cols = jnp.minimum(logical, max_col)  # padding may run off the table
-        blk = tables[rows, cols // bs]
-        blk = jnp.where(offs < qlen[:, None], blk, 0)  # padding -> null block
-        off = cols % bs
-        k_new, v_new = k, v
-    cache_kv = _write_pool(cache_kv, layer, blk, off, k_new, v_new)
-    a = attn_fn(q, *cache_kv, layer, tables, pos0, qlen)  # grouped
-    a = a.astype(dtype)
-    h = h + nn.dense(bp["attn"]["wo"], a.reshape(b, w, -1), dtype=dtype)
-    h = h + _mlp(bp["mlp"], _norm(bp["ln2"], h, cfg), dtype, cfg)
-    return h.astype(dtype), cache_kv
+    with step_part("attn/qkv"):
+        x = _norm(bp["ln1"], h, cfg)
+        q, k, v = _project_qkv(bp, x, cfg, dtype=dtype, positions=logical)
+    with step_part("attn/write"):
+        if listed is not None:
+            blk, off = listed.blk, listed.off
+            # Whole rows of lanes out of the (B x W, H_kv * D) form the
+            # projection left: gathered by head, XLA first re-lays all
+            # B x W slots out with (H_kv, D) minor, a copy a tensor a
+            # layer.
+            k_new, v_new = (x.reshape(b * w, -1)[listed.at].reshape(
+                (-1,) + x.shape[2:]) for x in (k, v))
+        else:
+            rows = jnp.arange(b)[:, None]
+            max_col = tables.shape[1] * bs - 1
+            # padding may run off the table
+            cols = jnp.minimum(logical, max_col)
+            blk = tables[rows, cols // bs]
+            # padding -> null block
+            blk = jnp.where(offs < qlen[:, None], blk, 0)
+            off = cols % bs
+            k_new, v_new = k, v
+        cache_kv = _write_pool(cache_kv, layer, blk, off, k_new, v_new)
+    with step_part("attn/read"):
+        a = attn_fn(q, *cache_kv, layer, tables, pos0, qlen)  # grouped
+        a = a.astype(dtype)
+    with step_part("attn/out"):
+        h = h + nn.dense(bp["attn"]["wo"], a.reshape(b, w, -1), dtype=dtype)
+    with step_part("mlp"):
+        h = h + _mlp(bp["mlp"], _norm(bp["ln2"], h, cfg), dtype, cfg)
+        h = h.astype(dtype)
+    return h, cache_kv
 
 
 def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
@@ -718,18 +728,20 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
             "sliding_window models are not supported by the paged KV "
             "cache (use the dense scheduler)")
     b, w = tokens.shape
-    h = nn.embedding(params["tok_embed"], tokens)
-    if cfg.pos == "learned":
-        logical = jnp.clip(pos0[:, None] + jnp.arange(w)[None, :], 0,
-                           params["pos_embed"]["table"].shape[0] - 1)
-        h = h + params["pos_embed"]["table"][logical]
-    h = h.astype(dtype)
+    with step_part("embed"):
+        h = nn.embedding(params["tok_embed"], tokens)
+        if cfg.pos == "learned":
+            logical = jnp.clip(pos0[:, None] + jnp.arange(w)[None, :], 0,
+                               params["pos_embed"]["table"].shape[0] - 1)
+            h = h + params["pos_embed"]["table"][logical]
+        h = h.astype(dtype)
     # One list a step, outside the layer loop, where it is shorter than
     # the step's slots.
     n_listed = pool_write_slots(b, w, max_tokens)
-    listed = (_tick_tokens(tables, pos0, qlen, w, n_listed,
-                           caches.k.shape[2])
-              if n_listed < b * w else None)
+    with step_part("plan"):
+        listed = (_tick_tokens(tables, pos0, qlen, w, n_listed,
+                               caches.k.shape[2])
+                  if n_listed < b * w else None)
 
     def block(bp, h, cache_kv, layer):
         return _block_step_rows_ragged(
@@ -737,14 +749,16 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
             attn_fn=attn_fn, listed=listed)
 
     h, *pool = _scan_layers_paged(block, params, h, caches, scales)
-    if sample_slot is not None:
-        slots = jnp.minimum(sample_slot[:, None]
-                            + jnp.arange(sample_width)[None, :], w - 1)
-        h = h[jnp.arange(b)[:, None], slots]          # (B, S, d)
-    h = _norm(params["ln_f"], h, cfg)
-    logits = nn.dense(params["head"], h, dtype=dtype).astype(jnp.float32)
-    if sample_slot is not None and sample_width == 1:
-        logits = logits[:, 0]
+    with step_part("head"):
+        if sample_slot is not None:
+            slots = jnp.minimum(sample_slot[:, None]
+                                + jnp.arange(sample_width)[None, :], w - 1)
+            h = h[jnp.arange(b)[:, None], slots]          # (B, S, d)
+        h = _norm(params["ln_f"], h, cfg)
+        logits = nn.dense(params["head"], h,
+                          dtype=dtype).astype(jnp.float32)
+        if sample_slot is not None and sample_width == 1:
+            logits = logits[:, 0]
     return (logits, *pool)
 
 

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 from jax.experimental.xla_metadata import set_xla_metadata
 
 from tpu_engine.ops import nn
+from tpu_engine.utils.tracing import step_part
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,13 +192,14 @@ def sigmoid_topk_route(x, router, top_k: int, scale: float):
     `top_k` of score + bias are CHOSEN, the weights are the chosen
     scores themselves (not biased), normalised to sum one and times
     `scale`. Returns (experts (N, k) int32, weights (N, k) float32)."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router["kernel"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, experts = jax.lax.top_k(scores + router["bias"], top_k)
-    chosen = jnp.take_along_axis(scores, experts, axis=-1)
-    weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scale
-    return experts.astype(jnp.int32), weights
+    with step_part("moe/route"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router["kernel"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, experts = jax.lax.top_k(scores + router["bias"], top_k)
+        chosen = jnp.take_along_axis(scores, experts, axis=-1)
+        weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scale
+        return experts.astype(jnp.int32), weights
 
 
 def softmax_topk_route(x, router, top_k: int):
@@ -207,12 +209,13 @@ def softmax_topk_route(x, router, top_k: int):
     normalised to sum one (`norm_topk_prob`). No selection bias, no
     scaling factor. Returns (experts (N, k) int32, weights (N, k)
     float32)."""
-    probs = jax.nn.softmax(jnp.dot(
-        x.astype(jnp.float32), router["kernel"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST), axis=-1)
-    chosen, experts = jax.lax.top_k(probs, top_k)
-    weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
-    return experts.astype(jnp.int32), weights
+    with step_part("moe/route"):
+        probs = jax.nn.softmax(jnp.dot(
+            x.astype(jnp.float32), router["kernel"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST), axis=-1)
+        chosen, experts = jax.lax.top_k(probs, top_k)
+        weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+        return experts.astype(jnp.int32), weights
 
 
 def relu2(x):
@@ -324,36 +327,44 @@ def routed_experts(x, valid, experts, weights, bank, *, first_group,
     expert took)."""
     n, k = experts.shape
     first, count = held or (0, n_experts)
-    mine = (valid[:, None] & (experts >= first) & (experts < first + count))
-    # Pairs that form no row sort behind every expert.
-    eid = jnp.where(mine, experts, n_experts).reshape(-1)
-    pairs = min(n, max_tokens or n) * k
-    tile = row_tile(pairs, jnp.dtype(dtype).itemsize)
-    pairs = -(-pairs // tile) * tile
-    # Past the slots the list is padded with pairs that form no row.
-    eid = jnp.pad(eid, (0, max(0, pairs - n * k)), constant_values=n_experts)
-    order = jnp.argsort(eid, stable=True)[:pairs]
-    eid_sorted = eid[order]
-    # A padding pair reads the last slot's row and weight; `live` masks it.
-    order = jnp.minimum(order, n * k - 1)
-    token = order // k
-    rows = jnp.zeros((n_experts + 1,), jnp.int32).at[eid].add(1)[:n_experts]
-    gated = "gate_up" in bank
-    first_matrix = bank["gate_up" if gated else "up"]
-    sizes = jax.lax.dynamic_update_slice(
-        jnp.zeros((first_matrix.shape[0],), jnp.int32),
-        rows[first:first + count], (first_group + first,))
-    xs = x[token].astype(dtype)
-    hidden = grouped_dot(xs, first_matrix.astype(dtype), sizes)
-    if gated:
-        gate, up = jnp.split(hidden, 2, axis=-1)
-        hidden = jax.nn.silu(gate) * up
-    else:
-        hidden = activation(hidden)
-    hidden = hidden.astype(dtype)
-    out = grouped_dot(hidden, bank["down"].astype(dtype), sizes)
-    # Rows past the last group hold whatever the kernel left there.
-    live = (eid_sorted < n_experts)[:, None]
-    out = jnp.where(live, out * weights.reshape(-1)[order][:, None], 0.0)
-    y = jnp.zeros((n, x.shape[-1]), jnp.float32).at[token].add(out)
+    # The pair list's ordering goes with the router's part; the gather of
+    # pairs, the two products and the combine are the experts'.
+    with step_part("moe/route"):
+        mine = (valid[:, None] & (experts >= first)
+                & (experts < first + count))
+        # Pairs that form no row sort behind every expert.
+        eid = jnp.where(mine, experts, n_experts).reshape(-1)
+        pairs = min(n, max_tokens or n) * k
+        tile = row_tile(pairs, jnp.dtype(dtype).itemsize)
+        pairs = -(-pairs // tile) * tile
+        # Past the slots the list is padded with pairs that form no row.
+        eid = jnp.pad(eid, (0, max(0, pairs - n * k)),
+                      constant_values=n_experts)
+        order = jnp.argsort(eid, stable=True)[:pairs]
+        eid_sorted = eid[order]
+        # A padding pair reads the last slot's row and weight; `live` masks
+        # it.
+        order = jnp.minimum(order, n * k - 1)
+        token = order // k
+        rows = jnp.zeros((n_experts + 1,), jnp.int32).at[eid].add(
+            1)[:n_experts]
+        gated = "gate_up" in bank
+        first_matrix = bank["gate_up" if gated else "up"]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((first_matrix.shape[0],), jnp.int32),
+            rows[first:first + count], (first_group + first,))
+    with step_part("moe/experts"):
+        xs = x[token].astype(dtype)
+        hidden = grouped_dot(xs, first_matrix.astype(dtype), sizes)
+        if gated:
+            gate, up = jnp.split(hidden, 2, axis=-1)
+            hidden = jax.nn.silu(gate) * up
+        else:
+            hidden = activation(hidden)
+        hidden = hidden.astype(dtype)
+        out = grouped_dot(hidden, bank["down"].astype(dtype), sizes)
+        # Rows past the last group hold whatever the kernel left there.
+        live = (eid_sorted < n_experts)[:, None]
+        out = jnp.where(live, out * weights.reshape(-1)[order][:, None], 0.0)
+        y = jnp.zeros((n, x.shape[-1]), jnp.float32).at[token].add(out)
     return y, rows

@@ -108,6 +108,8 @@ from tpu_engine.utils.tracing import (
     TickClock,
     compile_counter,
     gc_counter,
+    step_part,
+    tick_name,
 )
 
 
@@ -1529,31 +1531,34 @@ class ContinuousGenerator:
 
                 def step_core(params, caches, scales, block, prev_nxt,
                               prev_done, counts):
-                    f = layout.unpack(block)
-                    # The rows' table; one of each a kind of block on a
-                    # windowed lane, (table, state rows) on a hybrid one.
-                    tables = f["tables"]
-                    if len(tables) == 1:
-                        tables, = tables
-                    if hybrid:
-                        tables = (tables, f["state_rows"])
-                    pos0, qlen, sample_slot, eos_vec = (
-                        f["pos0"], f["qlen"], f["sample_slot"], f["eos_vec"])
-                    if blockwise is not None:
-                        # A generating row's step is its block, carried
-                        # on the device from pass to pass; the head reads
-                        # the run's slots of every row.
-                        tokens, blk, done = take_block_from_prev(
-                            f["tokens"], f["done"], prev_nxt, prev_done,
-                            f["from_prev"], blockwise.block_length,
-                            blockwise.mask_id)
-                        sample_slot = jnp.broadcast_to(
-                            jnp.arange(blockwise.block_length)[None, :],
-                            blk.shape)
-                    else:
-                        tokens, done = take_from_prev(
-                            f["tokens"], f["done"], prev_nxt, prev_done,
-                            f["from_prev"])
+                    with step_part("plan"):
+                        f = layout.unpack(block)
+                        # The rows' table; one of each a kind of block on
+                        # a windowed lane, (table, state rows) on a hybrid
+                        # one.
+                        tables = f["tables"]
+                        if len(tables) == 1:
+                            tables, = tables
+                        if hybrid:
+                            tables = (tables, f["state_rows"])
+                        pos0, qlen, sample_slot, eos_vec = (
+                            f["pos0"], f["qlen"], f["sample_slot"],
+                            f["eos_vec"])
+                        if blockwise is not None:
+                            # A generating row's step is its block,
+                            # carried on the device from pass to pass; the
+                            # head reads the run's slots of every row.
+                            tokens, blk, done = take_block_from_prev(
+                                f["tokens"], f["done"], prev_nxt,
+                                prev_done, f["from_prev"],
+                                blockwise.block_length, blockwise.mask_id)
+                            sample_slot = jnp.broadcast_to(
+                                jnp.arange(blockwise.block_length)[None, :],
+                                blk.shape)
+                        else:
+                            tokens, done = take_from_prev(
+                                f["tokens"], f["done"], prev_nxt, prev_done,
+                                f["from_prev"])
                     # sample_slot gathers the hidden state BEFORE the LM
                     # head: one (B, vocab) projection per tick, not W.
                     if own_step is not None:
@@ -1575,14 +1580,6 @@ class ContinuousGenerator:
                             params, tokens, caches, tables, pos0, qlen,
                             cfg, dtype=dtype, attn_fn=attn_fn,
                             sample_slot=sample_slot, max_tokens=max_tokens)
-                    rows = jnp.arange(tokens.shape[0])
-                    if controls:
-                        logits = apply_repetition_penalty(logits, counts,
-                                                          f["pens"])
-                    # The sampler's body is chosen by the rows whose
-                    # sample is real: a released slot's controls stay
-                    # where admission put them.
-                    live = f["active"] & ~done
                     if blockwise is not None:
                         # A denoise pass: a proposal and its confidence at
                         # each of the run's positions, of which the rule
@@ -1590,25 +1587,38 @@ class ContinuousGenerator:
                         # row leave the block as it came. The host reads
                         # the block's end (EOS, a stop token) off the
                         # block itself.
-                        x0, conf = sample_block(
-                            logits, f["seeds"], pos0, f["temps"],
-                            f["topps"], f["topks"], f["minps"], live)
-                        nxt = reveal_block(
-                            blk, x0, conf, jnp.where(live, f["reveal"], 0),
-                            blockwise.reveal, blockwise.threshold)
+                        with step_part("sample"):
+                            live = f["active"] & ~done
+                            x0, conf = sample_block(
+                                logits, f["seeds"], pos0, f["temps"],
+                                f["topps"], f["topks"], f["minps"], live)
+                        with step_part("sample/reveal"):
+                            nxt = reveal_block(
+                                blk, x0, conf,
+                                jnp.where(live, f["reveal"], 0),
+                                blockwise.reveal, blockwise.threshold)
                         return (self._pin_pool_out(caches), nxt, done,
                                 moe_rows)
-                    nxt = _sample(logits, f["seeds"], f["fold_pos"],
-                                  f["temps"], f["topps"], f["topks"],
-                                  f["minps"], kept=live)
-                    nxt = jnp.where(live, nxt, eos_vec)
-                    if controls:
-                        counts = counts.at[rows, nxt].add(
-                            live.astype(jnp.int32))
-                    done = done | (live & (nxt == eos_vec))
-                    if controls:
-                        done = done | (live & jnp.any(
-                            nxt[:, None] == f["stops"], axis=1))
+                    with step_part("sample"):
+                        rows = jnp.arange(tokens.shape[0])
+                        if controls:
+                            logits = apply_repetition_penalty(
+                                logits, counts, f["pens"])
+                        # The sampler's body is chosen by the rows whose
+                        # sample is real: a released slot's controls stay
+                        # where admission put them.
+                        live = f["active"] & ~done
+                        nxt = _sample(logits, f["seeds"], f["fold_pos"],
+                                      f["temps"], f["topps"], f["topks"],
+                                      f["minps"], kept=live)
+                        nxt = jnp.where(live, nxt, eos_vec)
+                        if controls:
+                            counts = counts.at[rows, nxt].add(
+                                live.astype(jnp.int32))
+                        done = done | (live & (nxt == eos_vec))
+                        if controls:
+                            done = done | (live & jnp.any(
+                                nxt[:, None] == f["stops"], axis=1))
                     if quant:
                         caches, scales = self._pin_pool_out(caches,
                                                             scales)
@@ -1634,6 +1644,11 @@ class ContinuousGenerator:
                         return step_core(params, caches, None, block,
                                          prev_nxt, prev_done, counts)
                     donate = (1, 5) if controls else (1,)
+                # As the tick's span says it: a block-decoding lane's
+                # narrow tick is `run` slots wide and reads width 1.
+                run = blockwise.block_length if blockwise else 1
+                mixed_step.__name__ = tick_name(
+                    1 if width == run else width, run)
                 self._decode_exe[key] = jax.jit(mixed_step,
                                                 donate_argnums=donate)
             return self._decode_exe[key]
@@ -1704,73 +1719,74 @@ class ContinuousGenerator:
                             params, tokens, caches, tables, pos0, qlen,
                             cfg, dtype=dtype, attn_fn=attn_fn,
                             sample_slot=sample_slot, sample_width=S)
-                    b, w = tokens.shape
-                    rows = jnp.arange(b)
-                    run_counts = counts
-                    alive = active & ~done
-                    new_done = done
-                    n_emit = jnp.zeros((b,), jnp.int32)
-                    # Draft slots whose token the target kept (the chain
-                    # held). Counted on-device because the host cannot
-                    # infer it from n_emit alone: a stream that stops ON
-                    # an accepted draft token has no corrected/bonus
-                    # slot, so "emitted - 1" would undercount.
-                    n_acc = jnp.zeros((b,), jnp.int32)
-                    use_sto = stoch & (n_draft > 0)
-                    t_safe = jnp.maximum(temps, 1e-6)
-                    emitted = []
-                    for j in range(S):
-                        lg = logits[:, j]
-                        lg_p = (apply_repetition_penalty(lg, run_counts,
-                                                         pens)
-                                if controls else lg)
-                        fold = fold0 + j
-                        det = _sample(lg_p, seeds, fold, temps, topps,
-                                      topks, minps, kept=alive)
-                        # The draft token this slot must reproduce for
-                        # the chain to continue (decode rows: window slot
-                        # j+1; prefill/undrafted rows never chain).
-                        didx = jnp.minimum(sample_slot + j + 1, w - 1)
-                        d_next = tokens[rows, didx]
-                        has_draft = j < n_draft
-                        det_chain = has_draft & (d_next == det)
-                        if stochastic:
-                            # Rejection sampling vs the point-mass
-                            # proposal, for temp>0 drafted rows.
-                            p = jax.nn.softmax(lg / t_safe[:, None],
-                                               axis=-1)
-                            u = _tagged_uniform(seeds, fold, _TAG_ACCEPT)
-                            acc = has_draft & (u < p[rows, d_next])
-                            resid = p.at[rows, d_next].set(0.0)
-                            resid = jnp.where(has_draft[:, None],
-                                              resid, p)
-                            tot = jnp.sum(resid, axis=-1, keepdims=True)
-                            dist = jnp.where(
-                                tot > 0,
-                                resid / jnp.maximum(tot, 1e-30), p)
-                            corr = _tagged_categorical(
-                                seeds, fold, _TAG_RESID,
-                                jnp.log(jnp.maximum(dist, 1e-30)))
-                            sto_tok = jnp.where(acc, d_next, corr)
-                            tok_j = jnp.where(use_sto, sto_tok, det)
-                            chain = jnp.where(use_sto, acc, det_chain)
-                        else:
-                            tok_j = det
-                            chain = det_chain
-                        tok_j = jnp.where(alive, tok_j, eos_vec)
-                        if controls:
-                            run_counts = run_counts.at[rows, tok_j].add(
-                                alive.astype(jnp.int32))
-                        emitted.append(tok_j)
-                        n_emit = n_emit + alive.astype(jnp.int32)
-                        n_acc = n_acc + (alive & chain).astype(jnp.int32)
-                        stop_j = alive & (tok_j == eos_vec)
-                        if controls:
-                            stop_j = stop_j | (alive & jnp.any(
-                                tok_j[:, None] == stops, axis=1))
-                        new_done = new_done | stop_j
-                        alive = alive & ~stop_j & chain
-                    out = jnp.stack(emitted, axis=1)          # (B, S)
+                    with step_part("sample"):
+                        b, w = tokens.shape
+                        rows = jnp.arange(b)
+                        run_counts = counts
+                        alive = active & ~done
+                        new_done = done
+                        n_emit = jnp.zeros((b,), jnp.int32)
+                        # Draft slots whose token the target kept (the chain
+                        # held). Counted on-device because the host cannot
+                        # infer it from n_emit alone: a stream that stops ON
+                        # an accepted draft token has no corrected/bonus
+                        # slot, so "emitted - 1" would undercount.
+                        n_acc = jnp.zeros((b,), jnp.int32)
+                        use_sto = stoch & (n_draft > 0)
+                        t_safe = jnp.maximum(temps, 1e-6)
+                        emitted = []
+                        for j in range(S):
+                            lg = logits[:, j]
+                            lg_p = (apply_repetition_penalty(lg, run_counts,
+                                                             pens)
+                                    if controls else lg)
+                            fold = fold0 + j
+                            det = _sample(lg_p, seeds, fold, temps, topps,
+                                          topks, minps, kept=alive)
+                            # The draft token this slot must reproduce for
+                            # the chain to continue (decode rows: window slot
+                            # j+1; prefill/undrafted rows never chain).
+                            didx = jnp.minimum(sample_slot + j + 1, w - 1)
+                            d_next = tokens[rows, didx]
+                            has_draft = j < n_draft
+                            det_chain = has_draft & (d_next == det)
+                            if stochastic:
+                                # Rejection sampling vs the point-mass
+                                # proposal, for temp>0 drafted rows.
+                                p = jax.nn.softmax(lg / t_safe[:, None],
+                                                   axis=-1)
+                                u = _tagged_uniform(seeds, fold, _TAG_ACCEPT)
+                                acc = has_draft & (u < p[rows, d_next])
+                                resid = p.at[rows, d_next].set(0.0)
+                                resid = jnp.where(has_draft[:, None],
+                                                  resid, p)
+                                tot = jnp.sum(resid, axis=-1, keepdims=True)
+                                dist = jnp.where(
+                                    tot > 0,
+                                    resid / jnp.maximum(tot, 1e-30), p)
+                                corr = _tagged_categorical(
+                                    seeds, fold, _TAG_RESID,
+                                    jnp.log(jnp.maximum(dist, 1e-30)))
+                                sto_tok = jnp.where(acc, d_next, corr)
+                                tok_j = jnp.where(use_sto, sto_tok, det)
+                                chain = jnp.where(use_sto, acc, det_chain)
+                            else:
+                                tok_j = det
+                                chain = det_chain
+                            tok_j = jnp.where(alive, tok_j, eos_vec)
+                            if controls:
+                                run_counts = run_counts.at[rows, tok_j].add(
+                                    alive.astype(jnp.int32))
+                            emitted.append(tok_j)
+                            n_emit = n_emit + alive.astype(jnp.int32)
+                            n_acc = n_acc + (alive & chain).astype(jnp.int32)
+                            stop_j = alive & (tok_j == eos_vec)
+                            if controls:
+                                stop_j = stop_j | (alive & jnp.any(
+                                    tok_j[:, None] == stops, axis=1))
+                            new_done = new_done | stop_j
+                            alive = alive & ~stop_j & chain
+                        out = jnp.stack(emitted, axis=1)          # (B, S)
                     if quant:
                         caches, scales = self._pin_pool_out(caches,
                                                             scales)
@@ -1808,6 +1824,7 @@ class ContinuousGenerator:
                                          minps, eos_vec, counts, pens,
                                          stops)
                     donate = (1, 18) if controls else (1,)
+                spec_step.__name__ = tick_name(width, kind="spec")
                 self._decode_exe[key] = jax.jit(spec_step,
                                                 donate_argnums=donate)
             return self._decode_exe[key]

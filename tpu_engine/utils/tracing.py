@@ -45,6 +45,14 @@ request. This module now carries a real tracing subsystem:
 - `StreamClock` — a streamed request's token events on their way out,
   by the front's stream writer or the handler's thread: wake-up and
   delivery, summed onto its request span.
+- `STEP_PARTS` / `step_part` — the DEVICE's equivalent of the tick's
+  phases: the parts of a step (`attn/read`, `mixer/chunk`,
+  `moe/experts`, `head`, `sample` ...), each a named scope of JAX that
+  every family's step opens, so every op of a tick carries its part in
+  the device trace's metadata (``tf_op``); `tick_name` names a tick's
+  compiled program for its width (``jit_tick_w256`` on the trace's
+  "XLA Modules" line). ``benchmarks/lib/xplane_scopes.py`` sums a
+  trace by part.
 """
 
 from __future__ import annotations
@@ -87,6 +95,48 @@ CPU_CLOCK_EVERY = 8
 # The loop's statements between two ticks, in order (`TickClock.loop_part`):
 # `loop_<part>_us` attrs, `loop.admit.<part>` annotations under `loop.admit`.
 LOOP_PARTS = ("exports", "capacity", "admit", "expire")
+# The parts of a step on the DEVICE (`step_part`): what every family's
+# `*_step_rows_ragged` and the scheduler's `step_core` open as
+# named scopes, so an op's path in a profiler trace
+# (`jit(tick_w256)/while/body/closed_call/attn/read/dot_general`) says
+# which part of the model it belongs to. A norm and a residual add go with
+# the part they feed. `plan` is what a step works out once, outside the
+# layer loop, from the tables and the rows' lengths; `attn/read` is the
+# pool's read with everything the call needs around it (relayouts, the
+# gather of tall tiles), `mixer/chunk` and `mixer/step` the two forms of a
+# recurrent layer whole; `moe/experts` the gather of pairs, the grouped
+# products WHATEVER implements them, and the combine. A new mechanism adds
+# a part here and a reader under benchmarks/layer_metrics/
+# (benchmarks/lib/xplane_scopes.py spells the same tuple).
+STEP_PARTS = (
+    "embed", "plan",
+    "attn/qkv", "attn/write", "attn/read", "attn/out",
+    "mixer/in", "mixer/chunk", "mixer/step", "mixer/out",
+    "mlp",
+    "moe/route", "moe/experts", "moe/shared",
+    "head",
+    "sample", "sample/reveal",
+)
+
+
+def step_part(name: str):
+    """The scope of one of `STEP_PARTS`: metadata on the ops traced inside
+    it and nothing else (the executable, its fusions and the persistent
+    compile cache's key are the same with and without)."""
+    import jax
+
+    if name not in STEP_PARTS:
+        raise ValueError(f"{name!r} is not one of STEP_PARTS")
+    return jax.named_scope(name)
+
+
+def tick_name(width: int, run_width: int = 1, kind: str = "tick") -> str:
+    """The name of a tick's compiled program: `tick_w1`, `tick_w256`,
+    `spec_w5`; `tick_w1_r4` on a lane whose rows decode by blocks of 4. The
+    trace's "XLA Modules" line reads `jit_<name>(<program id>)`, so a
+    program's runs can be told by width."""
+    name = f"{kind}_w{width}"
+    return name if run_width == 1 else f"{name}_r{run_width}"
 
 
 def new_span_id() -> str:
