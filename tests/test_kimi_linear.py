@@ -9,6 +9,7 @@ forms against its scan at the strongest decays; the two pools' bookkeeping
 by the code that serves `olmo_hybrid_small`; the start-up fences; the two
 shares of the experts adding up to the uncut layer."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -269,25 +270,51 @@ def test_the_step_kernel_changes_the_rows_states_where_they_lie():
     assert float(jnp.abs(new[1, 3] - pool[1, 3]).max()) > 0.1
 
 
-@pytest.mark.parametrize("gate", ["drawn", "past_float32"])
+def _chunk_run(run):
+    """A run for the chunk kernel and how many of its tokens are live: 128
+    tokens under two of `GATES`, or 256 (four sub-chunks, sixteen diagonal
+    blocks: every block row of the blocked solve, all four solves before
+    the state's pass) with b drawn over (0, 2) and channel 0 of every head
+    at -5 a token beside the drawn ones (the module docstring's worst
+    case); `shorter_than_its_padding` ends at token 150 and is padded with
+    tokens of b = 0, g = 0."""
+    if run in GATES:
+        return _kda_inputs(GATES[run], t=128)[0], 128
+    (q, k, v, g, beta), _ = _kda_inputs(_drawn, t=256, seed=3)
+    g = g.at[..., 0].set(-5.0)
+    live = 150 if run == "shorter_than_its_padding" else 256
+    past = jnp.arange(256)[:, None] >= live
+    return (q, k, v, jnp.where(past[..., None], 0.0, g),
+            jnp.where(past, 0.0, 2.0 * beta)), live
+
+
+# Jitted once a shape: `fresh` is an operand, so a run's two cases share
+# the interpreter's program.
+_chunk_in_the_interpreter = jax.jit(
+    functools.partial(gd.gdn_chunk_row, interpret=True))
+
+
+@pytest.mark.parametrize("run", ["drawn", "past_float32", "four_sub_chunks",
+                                 "shorter_than_its_padding"])
 @pytest.mark.parametrize("fresh", [False, True])
-def test_the_chunk_kernel_equals_the_scan_from_the_row_s_state(fresh, gate):
+def test_the_chunk_kernel_equals_the_scan_from_the_row_s_state(fresh, run):
     """The Pallas chunk in the interpreter (the scalar gate's body, exp(G_C)
-    a lane vector) against the token-by-token scan from the pool's row; the
-    other rows and the other layer are left as they were."""
-    (q, k, v, g, beta), _ = _kda_inputs(GATES[gate], t=128)
+    a lane vector) against the token-by-token scan of the run's LIVE tokens
+    from the pool's row; the other rows and the other layer are left as
+    they were."""
+    (q, k, v, g, beta), live = _chunk_run(run)
     pool = _pool_case(1)
     with jax.default_matmul_precision("highest"):
-        o, new = gd.gdn_chunk_row(q, k, v, g, beta, pool, 1, 4, fresh,
-                                  interpret=True)
+        o, new = _chunk_in_the_interpreter(q, k, v, g, beta, pool, 1, 4,
+                                           fresh)
         o_want, last = gd.gdn_scan(
-            q, k, v, g, beta,
+            q[:live], k[:live], v[:live], g[:live], beta[:live],
             jnp.zeros_like(pool[1, 4]) if fresh else pool[1, 4])
         o_xla, same = gd.gdn_chunk_row(q, k, v, g, beta, pool, 1, 4, fresh)
-    atol = 1e-4 if gate == "past_float32" else 2e-5
-    np.testing.assert_allclose(o, o_want, atol=atol)
+    atol = 1e-4 if run == "past_float32" else 2e-5
+    np.testing.assert_allclose(o[:live], o_want, atol=atol)
     np.testing.assert_allclose(new, pool.at[1, 4].set(last), atol=atol)
-    np.testing.assert_allclose(o_xla, o_want, atol=atol)
+    np.testing.assert_allclose(o_xla[:live], o_want, atol=atol)
     np.testing.assert_allclose(same, new, atol=2e-5)
 
 

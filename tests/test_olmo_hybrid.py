@@ -5,6 +5,7 @@ a paged K/V chain for the fourth. `olmo_hybrid_small` (two periods L L L F,
 against the plain reference benchmarks/references/olmo_hybrid.py, on
 logits; the two pools' bookkeeping; the start-up fences."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -184,24 +185,51 @@ def test_the_step_kernel_changes_the_rows_states_where_they_lie():
     assert float(jnp.abs(new[1, 3] - pool[1, 3]).max()) > 0.1
 
 
+def _chunk_run(run):
+    """A run for the chunk kernel and how many of its tokens are live.
+    `two_sub_chunks`: 128 tokens as `_gdn_inputs` draws them. The runs of
+    256 (four sub-chunks, sixteen diagonal blocks: every block row of the
+    blocked solve and the solves of all four before the state's pass) draw
+    b over (0, 2) and let head 0 forget at exp(-5) a token;
+    `shorter_than_its_padding` ends at token 150 and is padded with tokens
+    of b = 0, g = 0."""
+    if run == "two_sub_chunks":
+        return _gdn_inputs(t=128)[0], 128
+    (q, k, v, g, beta), _ = _gdn_inputs(t=256, seed=3)
+    beta = jax.random.uniform(jax.random.PRNGKey(7), beta.shape, maxval=2.0)
+    g = g.at[:, 0].set(-5.0)
+    live = 150 if run == "shorter_than_its_padding" else 256
+    past = jnp.arange(256)[:, None] >= live
+    return (q, k, v, jnp.where(past, 0.0, g), jnp.where(past, 0.0, beta)), live
+
+
+# Jitted once a shape: `fresh` is an operand, so a run's two cases share
+# the interpreter's program.
+_chunk_in_the_interpreter = jax.jit(
+    functools.partial(gd.gdn_chunk_row, interpret=True))
+
+
+@pytest.mark.parametrize("run", ["two_sub_chunks", "four_sub_chunks",
+                                 "shorter_than_its_padding"])
 @pytest.mark.parametrize("fresh", [False, True])
-def test_the_chunk_kernel_equals_the_scan_from_the_row_s_state(fresh):
-    """The Pallas chunk in the interpreter (forward substitution for the
-    triangular solves, the state carried over two sub-chunks in VMEM)
-    against the token-by-token scan from the pool's row; the other rows
-    and the other layer are left as they were."""
-    (q, k, v, g, beta), _ = _gdn_inputs(t=128)
+def test_the_chunk_kernel_equals_the_scan_from_the_row_s_state(fresh, run):
+    """The Pallas chunk in the interpreter (the triangular systems solved
+    in blocks of 16 for all the sub-chunks, then the state carried over
+    them in VMEM) against the token-by-token scan of the run's LIVE tokens
+    from the pool's row; the other rows and the other layer are left as
+    they were."""
+    (q, k, v, g, beta), live = _chunk_run(run)
     pool = _pool_case(1)
     with jax.default_matmul_precision("highest"):
-        o, new = gd.gdn_chunk_row(q, k, v, g, beta, pool, 1, 4, fresh,
-                                  interpret=True)
+        o, new = _chunk_in_the_interpreter(q, k, v, g, beta, pool, 1, 4,
+                                           fresh)
         o_want, last = gd.gdn_scan(
-            q, k, v, g, beta,
+            q[:live], k[:live], v[:live], g[:live], beta[:live],
             jnp.zeros_like(pool[1, 4]) if fresh else pool[1, 4])
         o_xla, same = gd.gdn_chunk_row(q, k, v, g, beta, pool, 1, 4, fresh)
-    np.testing.assert_allclose(o, o_want, atol=2e-5)
+    np.testing.assert_allclose(o[:live], o_want, atol=2e-5)
     np.testing.assert_allclose(new, pool.at[1, 4].set(last), atol=2e-5)
-    np.testing.assert_allclose(o_xla, o_want, atol=2e-5)
+    np.testing.assert_allclose(o_xla[:live], o_want, atol=2e-5)
     np.testing.assert_allclose(same, new, atol=2e-5)
 
 

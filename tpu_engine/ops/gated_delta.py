@@ -49,9 +49,29 @@ leaves as a column.
 Elsewhere (and as the kernel's reference) it is `gdn_step` over a gather
 of the rows and a scatter back. `gdn_chunk_row` is a row's run of tokens
 from its state in the same pool: the kernel `gdn_chunk`, a head a grid
-step, solves each sub-chunk's triangular system by forward substitution
-(A handed over transposed, so its row is a lane slice) and carries the
-state through the sub-chunks' products in VMEM.
+step, solves the triangular systems of ALL the run's sub-chunks first
+(they wait for no state) and then carries the state through the
+sub-chunks' products in VMEM. The solve is BLOCKED, in blocks of
+`KDA_BLOCK` = 16 rows. Only the 16 x 16 diagonal blocks L_b are solved by
+forward substitution, every diagonal block of the run at once (16 blocks
+for 256 tokens, a block a sublane row: 16 dependent rows a head where a
+row-by-row substitution over the whole right-hand side takes 64 a
+sub-chunk, one sub-chunk after another). A block's right-hand side is its
+own rows of A left of the diagonal block beside the identity, 64 lanes,
+so what comes out is D_b = (I + L_b)^-1 and N = D A_below at once; a row
+is its right-hand side less L_b[r, j] times row j, a multiply and a
+subtract and no reduction (`_solve_diagonal_blocks`). What couples the
+blocks goes to the MXU: I + A = (I + A_diagonal)(I + N) and N is strictly
+block-lower over four blocks, N^4 = 0, so
+
+    (I + A)^-1 = (I + N^2)(I - N) D
+
+with nothing left out: two small products a sub-chunk, and U_v, W are one
+product each with that. The diagonal blocks are NOT inverted by a series
+(I - L + L^2 - ... or its doubling form): with b up to 2 and unit keys
+|L_ij| reaches 2, the sixteen powers grow to 2^15 before they cancel in
+float32; substitution never forms them. The powers of N are the products
+the two-level merge (T_21 = -T_22 A_21 T_11 at 16 -> 32 -> 64) would sum.
 
 **A gate a key CHANNEL** (Kimi Delta Attention, arXiv:2510.26692). Every
 form takes `g` a head, (..., H), or a key channel, (..., H, d_k): the decay
@@ -89,8 +109,10 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.scipy.linalg import solve_triangular
 
 SUB_CHUNK = 64
-# A channel gate's diagonal blocks inside a sub-chunk (module docstring).
+# A diagonal block inside a sub-chunk: a channel gate's, and the chunk
+# kernel's solve (module docstring), which counts on four to a sub-chunk.
 KDA_BLOCK = 16
+assert SUB_CHUNK == 4 * KDA_BLOCK
 PRECISION = jax.lax.Precision.HIGHEST
 # State a grid step of the step kernel holds (in and out, double-buffered:
 # four of these in VMEM): 15 of Olmo-Hybrid-7B's 30 heads, 1.47 MB.
@@ -427,28 +449,77 @@ def _dot(a, b, contract):
                                preferred_element_type=jnp.float32)
 
 
-def _chunk_kernel(row_ref, layer_ref, fresh_ref, at_ref, bv_ref, kb_ref,
-                  qk_ref, qin_ref, kout_ref, last_ref, s_ref, s_out, o_ref,
-                  u_v, w, *, n):
-    """A head of a row's run: its sub-chunks in turn. `at_ref` holds A
-    TRANSPOSED, so A's row i is a column, a lane slice."""
+def _solve_diagonal_blocks(a_ref, nd_ref):
+    """Forward substitution inside the diagonal blocks of a head's run, all
+    nb = T / `KDA_BLOCK` of them at once, a block a SUBLANE row. Block b
+    (block row p of its sub-chunk) solves (I + L_b) X = [A_below | I | 0],
+    its own `KDA_BLOCK` rows of A left of the diagonal block, the identity
+    on it, zeros right of it (the sub-chunk's 64 lanes): X = [N | D_b | 0],
+    D_b = (I + L_b)^-1 and N = D A_below, into the block's rows of
+    `nd_ref` (T, C). Row r of every block is its right-hand side less
+    L_b[r, j] times row j for j < r: a multiply and a subtract of two vregs
+    each, L_b[r, j] spread over the lanes, and no reduction at all: 16
+    dependent rows a head. L_b's row r lies in A's row at lane 16 p on:
+    four lane slices, a block taking its block row's. (A roll whose shift
+    grows by 16 a sublane row would do it in one: the v5e takes no such
+    stride, and the interpreter does.)"""
+    nb = a_ref.shape[1] // KDA_BLOCK
+    shape = (nb, SUB_CHUNK)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    first = KDA_BLOCK * (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                         % (SUB_CHUNK // KDA_BLOCK))
+    block_row = jax.lax.broadcasted_iota(
+        jnp.int32, (nb, KDA_BLOCK), 0) % (SUB_CHUNK // KDA_BLOCK)
+    rows = []
+    for r in range(KDA_BLOCK):
+        at = pl.ds(r, nb, stride=KDA_BLOCK)      # row r of every block
+        a_r = a_ref[0, at, :]
+        x = jnp.where(lane < first, a_r,
+                      (lane == first + r).astype(jnp.float32))
+        if r:
+            l_r = a_r[:, :KDA_BLOCK]
+            for p in range(1, SUB_CHUNK // KDA_BLOCK):
+                l_r = jnp.where(block_row == p,
+                                a_r[:, p * KDA_BLOCK:(p + 1) * KDA_BLOCK],
+                                l_r)
+        for j in range(r):
+            x = x - l_r[:, j:j + 1] * rows[j]
+        rows.append(x)
+        nd_ref[at, :] = x
+
+
+def _chunk_kernel(row_ref, layer_ref, fresh_ref, a_ref, bv_ref,
+                  kb_ref, qk_ref, qin_ref, kout_ref, last_ref, s_ref, s_out,
+                  o_ref, u_v, w, nd, *, n):
+    """A head of a row's run. The triangular systems of all its sub-chunks
+    first, in blocks of `KDA_BLOCK` (module docstring), then the pass that
+    carries the state."""
     del row_ref, layer_ref                       # the index maps read them
+    c = SUB_CHUNK
+    _solve_diagonal_blocks(a_ref, nd)
+    block = tuple(jax.lax.broadcasted_iota(jnp.int32, (c, c), axis)
+                  // KDA_BLOCK for axis in (0, 1))
+    for i in range(n):
+        at = slice(i * c, (i + 1) * c)
+        # I + A = (I + A_diagonal)(I + N): (I + A)^-1 = (I + N)^-1 D, and
+        # N is strictly block-lower over four blocks, N^4 = 0, so
+        # (I + N)^-1 = (I + N^2)(I - N) with nothing left out.
+        solved = nd[at, :]                       # N, and D on its blocks
+        d = jnp.where(block[0] == block[1], solved, 0.0)
+        nil = jnp.where(block[0] > block[1], solved, 0.0)
+        squared = _dot(nil, jnp.concatenate([nil, d], axis=1),
+                       ((1,), (0,)))             # N^2 | N D
+        t = d - squared[:, c:]                   # (I - N) D
+        t = t + _dot(squared[:, :c], t, ((1,), (0,)))   # (I + A)^-1
+        u_v[at, :] = _dot(t, bv_ref[0, i], ((1,), (0,)))
+        w[at, :] = _dot(t, kb_ref[0, i], ((1,), (0,)))
     s = s_ref[0, 0, 0] * (fresh_ref[0] == 0).astype(jnp.float32)
-    for c in range(n):
-        # (I + A) X = rhs by forward substitution: row i of X is rhs's less
-        # A's row i times the rows above it (the rows below are still 0).
-        u_v[...] = jnp.zeros_like(u_v)
-        w[...] = jnp.zeros_like(w)
-        for i in range(SUB_CHUNK):
-            above = at_ref[0, c, :, i:i + 1]                     # (C, 1)
-            u_v[i:i + 1, :] = bv_ref[0, c, i:i + 1, :] - jnp.sum(
-                above * u_v[...], axis=0, keepdims=True)
-            w[i:i + 1, :] = kb_ref[0, c, i:i + 1, :] - jnp.sum(
-                above * w[...], axis=0, keepdims=True)
-        u = u_v[...] - _dot(w[...], s, ((1,), (1,)))             # (C, d_v)
-        o_ref[0, c] = (_dot(qin_ref[0, c], s, ((1,), (1,)))
-                       + _dot(qk_ref[0, c], u, ((1,), (0,))))
-        s = s * last_ref[0, c] + _dot(u, kout_ref[0, c], ((0,), (0,)))
+    for i in range(n):
+        at = slice(i * c, (i + 1) * c)
+        u = u_v[at, :] - _dot(w[at, :], s, ((1,), (1,)))         # (C, d_v)
+        o_ref[0, i] = (_dot(qin_ref[0, i], s, ((1,), (1,)))
+                       + _dot(qk_ref[0, i], u, ((1,), (0,))))
+        s = s * last_ref[0, i] + _dot(u, kout_ref[0, i], ((0,), (0,)))
     s_out[0, 0, 0] = s
 
 
@@ -475,15 +546,16 @@ def _chunk_call(q, k, v, g, beta, pool, layer, row, fresh, *,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,                # row, layer, fresh
             grid=(h,),
-            in_specs=[sub_chunks(c, c), sub_chunks(c, dv),
-                      sub_chunks(c, dk), sub_chunks(c, c),
-                      sub_chunks(c, dk), sub_chunks(c, dk),
-                      sub_chunks(1, dk),
+            in_specs=[pl.BlockSpec((1, t, c), lambda j, *_: (j, 0, 0)),
+                      sub_chunks(c, dv), sub_chunks(c, dk),
+                      sub_chunks(c, c), sub_chunks(c, dk),
+                      sub_chunks(c, dk), sub_chunks(1, dk),
                       pl.BlockSpec((1, 1, 1, dv, dk), state)],
             out_specs=[pl.BlockSpec((1, 1, 1, dv, dk), state),
                        sub_chunks(c, dv)],
-            scratch_shapes=[pltpu.VMEM((c, dv), jnp.float32),
-                            pltpu.VMEM((c, dk), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((t, dv), jnp.float32),
+                            pltpu.VMEM((t, dk), jnp.float32),
+                            pltpu.VMEM((t, c), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                    jax.ShapeDtypeStruct((h, n, c, dv), jnp.float32)],
         input_output_aliases={10: 0},             # the pool, in place
@@ -492,7 +564,7 @@ def _chunk_call(q, k, v, g, beta, pool, layer, row, fresh, *,
         interpret=interpret,
         name="kda_chunk" if g.ndim == k.ndim else "gdn_chunk",
     )(*(jnp.asarray(x, jnp.int32).reshape(1) for x in (row, layer, fresh)),
-      jnp.swapaxes(a, -1, -2), bv, kb, qk, q_in, k_out, last, pool)
+      a.reshape(h, t, c), bv, kb, qk, q_in, k_out, last, pool)
     return jnp.moveaxis(o.reshape(h, t, dv), 0, 1), pool
 
 
@@ -502,13 +574,14 @@ def gdn_chunk_row(q, k, v, g, beta, pool, layer, row, fresh, *,
     `gdn_scan`) from the state at `pool[layer, row]`, which is changed
     where it lies (`fresh`: it counts as zero). pool: (L, R, H, d_v, d_k)
     float32, donated. Returns (o (T, H, d_v), pool). On a TPU the
-    triangular solves and the pass over the sub-chunks are a Pallas kernel
-    named `gdn_chunk` in a trace, a head a grid step, its state block
-    chosen by the layer and the row in SMEM and aliased to the output; what
-    the sub-chunks need before the state is touched (`_chunk_terms`) is
-    batched XLA. Where the gate is a channel's the kernel is named
-    `kda_chunk`. `interpret=None` picks the kernel on a TPU and `gdn_chunk`
-    elsewhere; True runs the kernel in the Pallas interpreter."""
+    triangular solves (blocked: module docstring) and the pass over the
+    sub-chunks are a Pallas kernel named `gdn_chunk` in a trace, a head a
+    grid step, its state block chosen by the layer and the row in SMEM and
+    aliased to the output; what the sub-chunks need before the state is
+    touched (`_chunk_terms`) is batched XLA. Where the gate is a channel's
+    the kernel is named `kda_chunk`. `interpret=None` picks the kernel on
+    a TPU and `gdn_chunk` elsewhere; True runs the kernel in the Pallas
+    interpreter."""
     if interpret is None and jax.default_backend() != "tpu":
         return gdn_chunk_row_reference(q, k, v, g, beta, pool, layer, row,
                                        fresh)
