@@ -32,6 +32,10 @@ that):
            The lane's read is the XLA gather here (`TPU_ENGINE_PAGED=0`:
            the test model's 32 lanes a token are below what Mosaic's DMA
            takes; the compiled block-mask kernel is in the kernel phase).
+  planes   one lane of `ouro-small-test` (3 layers applied 3 times over one
+           set of weights, a pool of 9 planes): a prompt across two chunks,
+           twice the same tokens, the planes and the layer applications
+           counted, the pool idle after. The XLA gather read, as `blocks`.
   cache    the same launch again must reach ready without adding an entry
            to the compile cache.
   lanes    with >= 4 devices: --lanes 0 gives four lanes on four distinct
@@ -106,6 +110,39 @@ for order in ("ahead", "drained"):
     out[order] = first
 assert out["ahead"] == out["drained"], out
 print(json.dumps({"blocks": "ok", "tokens": out["ahead"]}))
+"""
+
+_PLANES_CHILD = r"""
+import json
+import jax
+from tpu_engine.models.registry import (_ensure_builtin_models_imported,
+                                        create_model)
+from tpu_engine.runtime.scheduler import ContinuousGenerator
+
+assert jax.default_backend() == "tpu", jax.default_backend()
+_ensure_builtin_models_imported()
+spec = create_model("ouro-small-test")
+params = spec.init(jax.random.PRNGKey(0))
+prompt = list(range(7, 30))                 # 23 tokens: chunks of 16 + 7
+gen = ContinuousGenerator(spec, params=params, n_slots=4, dtype="float32",
+                          kv_block_size=16, prefill_chunk=16,
+                          prefix_sharing=False)
+try:
+    assert gen._pool.caches.k.shape[0] == 9, gen._pool.caches.k.shape
+    first = gen.submit(prompt, max_new_tokens=9).result(300)
+    again = gen.submit(prompt, max_new_tokens=9).result(300)
+    stats = gen.stats()
+finally:
+    gen.stop()
+assert first == again and len(first) == 9, (first, again)
+mixed, pool = stats["mixed"], stats["kv_pool"]
+assert (mixed["ut_steps"], mixed["kv_planes"]) == (3, 9), mixed
+assert mixed["layer_passes"] == 9 * mixed["ticks"] > 0, mixed
+assert mixed["ticks"] == mixed["dispatches"], mixed
+assert pool["blocks_free"] == pool["blocks_total"], pool
+print(json.dumps({"planes": "ok", "tokens": first,
+                  "kv_planes": mixed["kv_planes"],
+                  "layer_passes": mixed["layer_passes"]}))
 """
 
 _DEVICE_CHILD = r"""
@@ -539,6 +576,13 @@ def main():
         check(json.loads(out.strip().splitlines()[-1])["blocks"] == "ok",
               "the block-decoding lane's smoke did not end ok")
     say(phase="blocks", seconds=times["blocks"])
+
+    with phase("planes"):
+        out = run_child("planes", [sys.executable, "-c", _PLANES_CHILD], 300,
+                        env={"TPU_ENGINE_PAGED": "0"})
+        check(json.loads(out.strip().splitlines()[-1])["planes"] == "ok",
+              "the looped lane's smoke did not end ok")
+    say(phase="planes", seconds=times["planes"])
 
     if device["count"] >= 4:
         with phase("lanes"):

@@ -1167,6 +1167,127 @@ def test_block_decode_mixed_step_copies_no_pool_and_no_bank(v5e_devices,
     assert analysis.alias_size_in_bytes > 2.0e9      # the pool in place
 
 
+@pytest.mark.parametrize("name,q_lens,width,grid", [
+    # A decode tick: 8 rows, one query row a head, the 16 heads packed.
+    ("short", (1,) * 8, 1, (8, 1)),
+    # A tall tile of a prompt chunk: 128 slots x 1 head = one 128-row tile.
+    ("tall", (128,) * 10, 128, (10, 1)),
+])
+def test_the_looped_read_and_write_compile_for_v5e_under_a_traced_plane(
+        v5e_devices, name, q_lens, width, grid):
+    """The pool write and the paged read at the Ouro cell's geometry (G = 1,
+    16 KV heads of 128 lanes, blocks of 16, a table of 40 entries) over its
+    pool of 192 planes x 321 blocks (2.02 G elements a tensor, under 2^31)
+    with the plane index an OPERAND, as the scanned layer body hands it:
+    both classes of tile compile for a v5e with the query tiles their grid,
+    and the write updates the pool where it lies."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.transformer import _write_pool
+    from tpu_engine.ops import paged_attention as pa
+
+    del name
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+    shapes = jax.eval_shape(lambda: pa.parity_workload(
+        "ragged", q_lens, n_heads=16, n_kv_heads=16, d_head=128,
+        block_size=16, n_blocks=321, table_len=40, dtype=jnp.bfloat16)[0])
+    q, k_pool, v_pool, _, tables, pos0, qlen = shapes
+    assert q.shape == (len(q_lens), width, 16, 128)
+    planes = jax.ShapeDtypeStruct((192,) + k_pool.shape[1:], k_pool.dtype)
+    assert math.prod(planes.shape) == 192 * 321 * 16 * 2048 < 2 ** 31
+    plane = jax.ShapeDtypeStruct((), jnp.int32)
+    new = jax.ShapeDtypeStruct((len(q_lens) * width, 16, 128), jnp.bfloat16)
+    at = jax.ShapeDtypeStruct((len(q_lens) * width,), jnp.int32)
+
+    def write_then_read(q, k_pool, v_pool, plane, tables, pos0, qlen, k, v,
+                        blk, off):
+        pool = _write_pool((k_pool, v_pool), plane, blk, off, k, v)
+        return pa.ragged_paged_attention(q, *pool, plane, tables, pos0, qlen,
+                                         interpret=False), pool
+
+    operands = (q, planes, planes, plane, tables, pos0, qlen, new, new, at,
+                at)
+    compiled = jax.jit(write_then_read, donate_argnums=(1, 2)).lower(*(
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+        for x in operands)).compile()
+    assert "_paged_call" in compiled.as_text()
+    assert not _moved(compiled.as_text(), {math.prod(planes.shape),
+                                           math.prod(planes.shape[1:])})
+    assert compiled.memory_analysis().alias_size_in_bytes > 8.0e9
+    assert _pallas_grids(jax.make_jaxpr(write_then_read)(
+        *operands).jaxpr) == [grid]
+
+
+@pytest.mark.parametrize("width", [1, 256])
+def test_looped_mixed_step_holds_one_layer_body_and_copies_no_pool(
+        v5e_devices, width):
+    """The Ouro cell's mixed step at its serving shapes (shapes only), the
+    pool of 192 planes donated, compiled for one v5e: 48 layers x 4 passes
+    are ONE layer body under two loops (a paged call a class of tile, seven
+    products and the head: a Python loop would hold 192 of each); no
+    `copy`, `slice`, `dynamic-slice` or `dynamic-update-slice` whose result
+    is the pool or a plane of it; temporaries under 0.1 GB beside 13.4 GB
+    of weights and pool."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.ouro import ouro_step_rows_ragged
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "ouro-2.6b.json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    assert width in (1, serving["gen_prefill_chunk"])
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg = spec.config
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    (kind,) = cfg.kv_block_kinds
+    one = placed(jax.ShapeDtypeStruct(
+        (kind.n_layers, serving["gen_kv_blocks"], bs, kind.kv_lanes[0]),
+        jnp.bfloat16))
+    assert one.shape == (192, 321, 16, 2048)
+    pool = KVCache(one, one)
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return ouro_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=functools.partial(ragged_paged_attention,
+                                      interpret=False),
+            sample_slot=jnp.zeros_like(pos0),
+            max_tokens=serving["gen_prefill_chunk"] + rows)
+
+    def host(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    step, behind = _behind_a_step(tick, host(rows))
+    lowered = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pool, host(rows, -(-cfg.max_seq // bs)), host(rows, width),
+        host(rows), host(rows), *behind)
+    text = lowered.as_text()
+    assert text.count("stablehlo.dot_general") == 8
+    assert text.count("tpu_custom_call") == (1 if width == 1 else 2)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert "_paged_call" in hlo
+    assert not _moved(hlo, {math.prod(one.shape),
+                            math.prod(one.shape[1:])})
+    analysis = compiled.memory_analysis()
+    assert analysis.temp_size_in_bytes < 0.1e9
+    assert analysis.alias_size_in_bytes > 8.0e9      # the pool in place
+
+
 def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
     """Where JAX_COMPILATION_CACHE_DIR is set, no code names a cache
     directory (JAX reads the variable itself); unset, the directory is

@@ -46,6 +46,8 @@ FAMILIES = {
     # every layer routes, none has a shared expert; a block is revealed
     "sdar-small-test": ({"prefix_sharing": False},
                         {"moe/route", "moe/experts", "sample/reveal"}, 4),
+    # one layer body under two scans: the parts are opened once
+    "ouro-small-test": ({"prefix_sharing": False}, {"mlp"}, 1),
 }
 
 

@@ -65,6 +65,16 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 # speculative verify, int8 payloads (a rewritten slot would be requantized
 # a pass), the host tier, prefix sharing, `--tp`, migration and handoff
 # (a chain carries no block in denoising) are ABSENT.
+# "kv_looped": a kv_paged chain whose pool is DEEPER than the model's
+# weights: the layers are applied `ModelSpec.passes` times a token with one
+# set of weights and a block holds a plane a (pass, layer)
+# (models.ouro; the depth is the model's `kv_block_kinds[0]`). Served by
+# the mixed tick alone, through the model's own step. A block's planes
+# depend on the tokens up to its end alone, so prefix sharing holds as for
+# any chain; the own step takes no int8 scales and no verify window, its
+# scan is run a chip whole, and a chain of that depth rides no wire yet
+# (the format would carry it; it is not tested), so int8 payloads, the
+# host tier, speculative verify, `--tp`, migration and handoff are ABSENT.
 FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
     # "two_path": the dense per-slot cache (`kv_block_size` 0) and its
     # prefill-thread / chunk-loop stepping. Every other lane that
@@ -80,6 +90,7 @@ FAMILY_CAPABILITIES: Dict[str, Tuple[str, ...]] = {
     "kv_windowed": ("generate", "paged_kv", "oneshot_rows"),
     "kv_and_state": ("generate", "paged_kv", "oneshot_rows"),
     "kv_block_decode": ("generate", "paged_kv", "oneshot_rows"),
+    "kv_looped": ("generate", "paged_kv", "prefix_sharing", "oneshot_rows"),
 }
 
 # -- tensor-parallel partition rules ------------------------------------------
@@ -293,6 +304,11 @@ class ModelSpec:
     # scheduler reads this declaration, never the model. None: one token
     # a row a tick.
     block_decode: Optional[BlockDecode] = None
+    # How many times the model applies its layers to a token, with one set
+    # of weights (models.ouro): its pool holds a plane a (pass, layer),
+    # `passes` times as deep as its weights, and a tick runs `passes` x
+    # layers layer applications. 1: a layer is applied once.
+    passes: int = 1
 
     def __post_init__(self):
         if not self.state_family:
@@ -317,7 +333,7 @@ class ModelSpec:
             if rule is None:
                 if self.state_family in ("kv_paged", "kv_latent",
                                          "kv_windowed", "kv_and_state",
-                                         "kv_block_decode"):
+                                         "kv_block_decode", "kv_looped"):
                     rule = "transformer"
                 elif self.state_family == "state_slab":
                     # Defensive default for undeclared recurrent models:
@@ -383,6 +399,6 @@ def _ensure_builtin_models_imported():
 
     for optional in ("bert", "gpt2", "llama", "yolo", "ssd", "moonlight",
                      "laguna", "olmo_hybrid", "kimi_linear", "falcon_h1",
-                     "nemotron_h", "sdar"):
+                     "nemotron_h", "sdar", "ouro"):
         if importlib.util.find_spec(f"tpu_engine.models.{optional}") is not None:
             importlib.import_module(f"tpu_engine.models.{optional}")

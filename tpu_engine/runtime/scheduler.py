@@ -601,6 +601,10 @@ class ContinuousGenerator:
         # feeds a tick.
         self._block = getattr(model, "block_decode", None)
         self._run = self._block.block_length if self._block else 1
+        # A model that applies its layers several times a token declares
+        # how often (registry `ModelSpec.passes`; 1: once). Its own step
+        # runs the passes; here they are counted.
+        self._passes = int(getattr(model, "passes", 1) or 1)
         # Unified stateless serving (DESIGN.md): score/infer/embed
         # models admit as SINGLE-TICK rows — no autoregressive state at
         # all, so every state-machinery branch below is skipped and the
@@ -816,11 +820,14 @@ class ContinuousGenerator:
             if int(kv_host_blocks) > 0 and not prefix_sharing:
                 raise ValueError("kv_host_blocks requires prefix_sharing "
                                  "(the host tier holds radix entries)")
-            # A family with layers of several kinds: `_pool` holds its
-            # full-attention layers alone, at the lanes the model states.
-            self._pool = BlockPool(self.cfg.kv_block_kinds[0]
-                                   if self._windowed or self._hybrid
-                                   else self.cfg,
+            # A model that STATES what its pool holds (`kv_block_kinds`:
+            # a family with layers of several kinds, whose `_pool` holds
+            # its full-attention layers alone at the lanes the model
+            # states; a model applied several times, whose pool is deeper
+            # than its weights) sizes the pool by that, any other by its
+            # own config.
+            kinds = getattr(self.cfg, "kv_block_kinds", None)
+            self._pool = BlockPool(kinds[0] if kinds else self.cfg,
                                    nb, bs, self._dtype, device,
                                    host_blocks=int(kv_host_blocks),
                                    quantize=str(kv_quantize),
@@ -1082,6 +1089,16 @@ class ContinuousGenerator:
                                           np.int32)
                 self._blk_masked = np.zeros((self.n_slots,), np.int32)
                 self._blk_tail = np.zeros((self.n_slots,), np.int32)
+            if self._passes > 1:
+                # A lane whose model applies its layers several times a
+                # token: the planes of its pool (a plane a (pass, layer))
+                # with the bytes a token takes in them, and the layer
+                # applications its ticks ran, `kv_planes` a tick.
+                self._stats["mixed"].update(
+                    ut_steps=self._passes,
+                    kv_planes=self._pool.cfg.n_layers,
+                    kv_bytes_per_token=self._pool.bytes_per_block() // bs,
+                    layer_passes=0)
             if self._ragged_step is not None and getattr(
                     self.cfg, "n_moe_layers", 0):
                 # What the expert layers routed, summed over ticks: the
@@ -1405,6 +1422,12 @@ class ContinuousGenerator:
                             "tier, and prefix reuse at block-aligned "
                             "boundaries is not wired",
                             "block-causal read of a run"),
+        "kv_looped": ("the model's own step scans one set of layers "
+                      "several times over a pool a plane a (pass, layer) "
+                      "deep: it takes no int8 scales and no verify "
+                      "window, and a chain of that depth is carried by "
+                      "no tested wire format or host tier",
+                      "read of a plane a pass and layer"),
     }
 
     def _fence_tick_only_family(self, model, fam, *, kv_host_blocks,
@@ -4806,6 +4829,9 @@ class ContinuousGenerator:
             if blockwise is not None:
                 extra["reveal"] = reveal
                 self._note_block_work(pos0, qlen, active, commit)
+            if self._passes > 1:
+                self._clock.note(ut_steps=self._passes,
+                                 kv_planes=self._pool.cfg.n_layers)
             if controls:
                 extra.update(pens=self._pens, stops=self._stops)
             block = self._tick_block(width, controls).pack(
@@ -4923,6 +4949,8 @@ class ContinuousGenerator:
         else:
             m["denoise_passes"] += int(t.decode.sum())
             m["commit_passes"] += int(t.commit.sum())
+        if self._passes > 1:
+            m["layer_passes"] += m["kv_planes"]
         if t.prefill_tokens and t.n_decode:
             m["coscheduled_ticks"] += 1
 

@@ -946,7 +946,15 @@ def _print_runtime_banner(workers, front: str) -> None:
         stats = gen.stats() if gen is not None else {}
         for shape, tiles in stats.get("moe", {}).get("tilings", {}).items():
             print(f"  lane {w.node_id} expert tiles {shape}: {tiles}")
-        block = stats.get("mixed", {}).get("block_decode")
+        mixed = stats.get("mixed", {})
+        if mixed.get("kv_planes"):
+            # A lane whose model applies its layers several times a token
+            # over a pool a plane a (pass, layer) deep.
+            passes, planes = mixed["ut_steps"], mixed["kv_planes"]
+            print(f"  lane {w.node_id} {passes} passes x "
+                  f"{planes // passes} layers, {planes} planes, "
+                  f"{mixed['kv_bytes_per_token'] / 1e6:.2f} MB a token")
+        block = mixed.get("block_decode")
         if block:
             # A lane whose rows denoise blocks: its reveal rule, and
             # whether its ticks run one ahead of their results.
