@@ -441,6 +441,30 @@ def _cell_tick_shapes(v5e_devices, config, width):
         host(rows, width), host(rows), host(rows))
 
 
+_CELL_TICKS = {}
+
+
+def _compiled_cell_tick(v5e_devices, config, width):
+    """(cfg, the master tree's shapes, the tick's arguments, the lane's
+    bound on a tick's tokens, the step compiled for one v5e with the pool
+    donated): `_cell_tick_shapes` under the bound the lane states (a
+    budget of 256 plus a token a row), compiled once a (cell, width)."""
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+
+    if (config, width) not in _CELL_TICKS:
+        cfg, master, args = _cell_tick_shapes(v5e_devices, config, width)
+        bound = 256 + args[-1].shape[0]
+        tick = _mixed_tick(
+            cfg, functools.partial(ragged_paged_attention, interpret=False),
+            max_tokens=bound)
+        step, behind = _behind_a_step(tick, args[-1])
+        _CELL_TICKS[config, width] = (
+            cfg, master, args, bound,
+            jax.jit(step, donate_argnums=(1,)).lower(
+                *args, *behind).compile())
+    return _CELL_TICKS[config, width]
+
+
 @pytest.mark.parametrize("width", [1, 256])
 @pytest.mark.parametrize("config", ["gpt2-large", "mistral-7b-v0.2-8l"])
 def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
@@ -462,17 +486,9 @@ def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
     (272) listed tokens, whole rows of lanes, and the program holds no
     scatter over the pool flattened to (L x NB x bs, lanes), the form XLA
     gave the write of all rows x 256 slots."""
-    from tpu_engine.ops.paged_attention import ragged_paged_attention
-
-    cfg, master, args = _cell_tick_shapes(v5e_devices, config, width)
+    cfg, master, args, bound, compiled = _compiled_cell_tick(
+        v5e_devices, config, width)
     pool = args[1].k
-    bound = 256 + args[-1].shape[0]
-    tick = _mixed_tick(
-        cfg, functools.partial(ragged_paged_attention, interpret=False),
-        max_tokens=bound)
-    step, behind = _behind_a_step(tick, args[-1])
-    compiled = jax.jit(step, donate_argnums=(1,)).lower(
-        *args, *behind).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     lanes = f"{cfg.kv_heads * cfg.d_head}|{cfg.kv_heads},{cfg.d_head}"
@@ -490,6 +506,38 @@ def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
     assert not cast, cast
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2 * whole * pool.dtype.itemsize, temp
+
+
+@pytest.mark.parametrize("config", ["gpt2-large", "mistral-7b-v0.2-8l"])
+def test_chunk_step_feeds_forward_its_listed_tokens(v5e_devices, config):
+    """Since PR 59 a chunk tick's feed-forward (gelu at gpt2-large's
+    widths, SwiGLU at Mistral's) runs over the tick's token list: compiled
+    for one v5e at the cell's serving shapes, the program's products with
+    a `d_ff` side have 288 (272) rows and none has rows x 256, and the
+    gather of the residual's rows and their scatter back are ops of the
+    `mlp` part, so `step.ffn_busy` reads them and `step.unscoped_busy`
+    does not."""
+    cfg, _, args, bound, compiled = _compiled_cell_tick(
+        v5e_devices, config, 256)
+    rows = args[-1].shape[0]
+    hlo = compiled.as_text()
+    shapes = {tuple(map(int, dims.split(",")))
+              for dims in re.findall(r"= \w+\[([\d,]+)\]", hlo)}
+    wide = {(rows, 256, cfg.d_ff), (rows * 256, cfg.d_ff)}
+    # Mistral's 16 x 256 slots are as many as its d_model: a weight's shape.
+    wide -= {(cfg.d_model, cfg.d_ff)}
+    assert (bound, cfg.d_ff) in shapes and not wide & shapes
+    paths = re.findall(
+        rf"= \w+\[(?:{bound}|{rows * 256}),{cfg.d_model}\]\S* "
+        r"(?:fusion|gather|scatter)\(.*"
+        r'op_name="([^"]*/(?:gather|scatter))"', hlo)
+    # Rows of d_model lanes are also what the embedding looks up and, at
+    # gpt2-large's heads, what the pool write gathers of K and V.
+    tails = {re.sub(r"^jit\(step\)/(while/body/closed_call/)?", "", p)
+             for p in paths}
+    assert {"mlp/gather", "mlp/scatter"} <= tails <= {
+        "mlp/gather", "mlp/scatter", "attn/write/gather",
+        "embed/gather"}, tails
 
 
 _CALLED = re.compile(

@@ -169,3 +169,22 @@ def test_the_speculative_step_is_named_for_its_window():
         assert exe.__name__ == "spec_w3"
     finally:
         gen.stop()
+
+
+def test_the_listed_feed_forward_s_gather_and_write_back_lie_under_mlp(
+        lowered):
+    """A chunk tick's feed-forward runs over the tick's token list (PR 59,
+    `models.transformer.mlp_slots`): the gather of the residual's rows and
+    their write-back are ops of `mlp`, so `step.ffn_busy` reads the whole
+    part and nothing of it lands under no part; a width-1 tick has
+    neither."""
+    def ops(chunk, part):
+        _, paths = lowered("gpt2-small-test", chunk)
+        return {p.rsplit("/", 1)[-1] for p in paths if part_of(p) == part}
+
+    assert {"gather", "scatter"} <= ops(True, "mlp")
+    assert not {"gather", "scatter"} & ops(False, "mlp")
+    # The scatters inside the layer scan: the pool's and the residual's.
+    _, paths = lowered("gpt2-small-test", True)
+    assert {part_of(p) for p in paths if p.endswith("/scatter")
+            and not p.startswith("jit(")} == {"attn/write", "mlp"}

@@ -580,11 +580,24 @@ def pool_write_slots(batch: int, width: int,
     return min(max_tokens, slots)
 
 
+def mlp_slots(cfg: TransformerConfig, batch: int, width: int,
+              max_tokens: Optional[int] = None) -> int:
+    """The rows ONE layer's feed-forward of the uniform step computes:
+    the step's token list where it has one (`pool_write_slots`) and the
+    feed-forward is dense, so a row's value is its own whatever rows sit
+    beside it; every slot under routed experts, whose capacity counts the
+    rows of the operand (`ops.moe.moe_apply`)."""
+    if cfg.n_experts > 0:
+        return batch * width
+    return pool_write_slots(batch, width, max_tokens)
+
+
 class _TickTokens(NamedTuple):
     """A tick's valid (row, slot) pairs, compacted in row order into a
     list of the caller's static bound (`_tick_tokens`), and where each
     one's K/V goes in the pool. Entries past the tick's live count go to
-    the null block."""
+    the null block, and REPEAT the last live pair (`tile_plan`): what
+    is written back at `at` must be the same value at every repeat."""
     at: jax.Array    # (max_tokens,) int32 row * W + slot
     blk: jax.Array   # (max_tokens,) int32 pool block, 0 past the live count
     off: jax.Array   # (max_tokens,) int32 offset in the block
@@ -611,7 +624,8 @@ def _tick_tokens(tables, pos0, qlen, width: int, max_tokens: int,
 
 def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
                             cfg: TransformerConfig, *, dtype, attn_fn,
-                            listed: Optional[_TickTokens] = None):
+                            listed: Optional[_TickTokens] = None,
+                            mlp_at: Optional[jax.Array] = None):
     """One ragged mixed step against the PAGED pool: cache_kv arrays are
     the whole (L, NB, bs, H_kv*D) block pools shared by every row and
     layer (`runtime.kv_blocks.BlockPool` states the layout); ``tables``
@@ -629,8 +643,13 @@ def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
     scatter on a TPU pays by the index; without it all B x W slots, the
     padding ones (i >= qlen) sent to the null block. The values and the
     places are the same either way, the null block's contents apart;
-    q, the read and the dense products keep their (B, W) shapes, and
-    the padding slots' outputs are garbage the scheduler ignores."""
+    q, the read and the attention's dense products keep their (B, W)
+    shapes, and the padding slots' outputs are garbage the scheduler
+    ignores. With ``mlp_at`` (the list's `at`) the layer's second half
+    (the norm, the feed-forward, the residual add: all row-wise) runs
+    over those rows of the residual alone and is written back where they
+    came from; a padding slot then keeps the first half's residual,
+    garbage as before."""
     bs = cache_kv[0].shape[2]
     b, w = h.shape[:2]
     offs = jnp.arange(w)[None, :]
@@ -664,7 +683,15 @@ def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
     with step_part("attn/out"):
         h = h + nn.dense(bp["attn"]["wo"], a.reshape(b, w, -1), dtype=dtype)
     with step_part("mlp"):
-        h = h + _mlp(bp["mlp"], _norm(bp["ln2"], h, cfg), dtype, cfg)
+        if mlp_at is not None:
+            flat = h.reshape(b * w, -1)
+            x = flat[mlp_at]
+            x = x + _mlp(bp["mlp"], _norm(bp["ln2"], x, cfg), dtype, cfg)
+            # `.set`, not `.add`: the list's tail repeats its last live
+            # entry, and every repeat carries the same new row.
+            h = flat.at[mlp_at].set(x.astype(flat.dtype)).reshape(h.shape)
+        else:
+            h = h + _mlp(bp["mlp"], _norm(bp["ln2"], h, cfg), dtype, cfg)
         h = h.astype(dtype)
     return h, cache_kv
 
@@ -712,9 +739,12 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
     row). Stated, and at a width above 1, each layer's pool write
     scatters that many indices — the tick's tokens, listed once a step
     (`_tick_tokens`) — where it otherwise scatters all B x W slots,
-    most of them padding sent to the null block. Logits and every block
-    but the null one are the same either way; tokens past a bound the
-    caller broke would be lost, so the caller checks it."""
+    most of them padding sent to the null block, and each layer's dense
+    feed-forward runs over that many rows of the residual (`mlp_slots`)
+    where it otherwise runs over all B x W. Logits at the valid slots
+    and every block but the null one are the same either way; tokens
+    past a bound the caller broke would be lost, so the caller checks
+    it."""
     if attn_fn is None:
         from tpu_engine.ops.paged_attention import (
             default_quant_ragged_attention,
@@ -742,11 +772,12 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
         listed = (_tick_tokens(tables, pos0, qlen, w, n_listed,
                                caches.k.shape[2])
                   if n_listed < b * w else None)
+    mlp_at = listed.at if mlp_slots(cfg, b, w, max_tokens) < b * w else None
 
     def block(bp, h, cache_kv, layer):
         return _block_step_rows_ragged(
             bp, h, cache_kv, layer, tables, pos0, qlen, cfg, dtype=dtype,
-            attn_fn=attn_fn, listed=listed)
+            attn_fn=attn_fn, listed=listed, mlp_at=mlp_at)
 
     h, *pool = _scan_layers_paged(block, params, h, caches, scales)
     with step_part("head"):

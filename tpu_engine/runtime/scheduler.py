@@ -66,6 +66,7 @@ from tpu_engine.models.ssd import (
 from tpu_engine.models.transformer import (
     TransformerConfig,
     init_caches,
+    mlp_slots,
     pool_write_slots,
     transformer_decode_rows,
     transformer_decode_window,
@@ -4466,8 +4467,10 @@ class ContinuousGenerator:
         scatters (`write_slots`, `models.transformer.pool_write_slots`:
         the step's token list where the tick's caller states its bound,
         `max_tokens`, else every slot of the step) beside the tokens the
-        tick holds (`write_tokens`). A tick over a stated bound would lose
-        the K/V of the tokens past it: it raises here, before the
+        tick holds (`write_tokens`), and the rows ONE layer's feed-forward
+        computes (`mlp_slots`, `models.transformer.mlp_slots`: the same
+        list under a dense feed-forward). A tick over a stated bound
+        would lose the K/V of the tokens past it: it raises here, before the
         dispatch, and the loop counts it with the device's failures
         (`_recover`). `active`:
         the rows whose sample is real; the body of `_sample` they ask for
@@ -4504,7 +4507,9 @@ class ContinuousGenerator:
                 walk_live_tiles=live, walk_warm_tiles=warm,
                 walk_tokens_fetched=fetched, write_tokens=n_tokens,
                 write_slots=pool_write_slots(qlen.shape[0], width,
-                                             max_tokens))
+                                             max_tokens),
+                mlp_slots=mlp_slots(self.cfg, qlen.shape[0], width,
+                                    max_tokens))
         self._clock.dispatch(width, int(fed.sum()), ctx_tokens)
 
     def _slide_window_blocks(self, pos0, qlen) -> None:
