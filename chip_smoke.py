@@ -36,6 +36,15 @@ that):
            set of weights, a pool of 9 planes): a prompt across two chunks,
            twice the same tokens, the planes and the layer applications
            counted, the pool idle after. The XLA gather read, as `blocks`.
+  tails    one lane of `lfm2-small-test` (a gated conv of three taps whose
+           state row is ONE array, GQA, experts with no shared one): a
+           prompt across three chunks and a prompt of one token, the tokens
+           the one-shot forward's arg-max token for token (the conv over the
+           token list against the conv over a padded sequence, on the
+           chip), twice the same, every state row given back. The XLA
+           gather read, as `blocks`; the compiled read at the cell's G = 4
+           x 64 lanes and its grouped product are in the kernel phase
+           (`kernel_check.CLASS_SHAPES`, `GROUPED_SHAPES`).
   cache    the same launch again must reach ready without adding an entry
            to the compile cache.
   lanes    with >= 4 devices: --lanes 0 gives four lanes on four distinct
@@ -143,6 +152,47 @@ assert pool["blocks_free"] == pool["blocks_total"], pool
 print(json.dumps({"planes": "ok", "tokens": first,
                   "kv_planes": mixed["kv_planes"],
                   "layer_passes": mixed["layer_passes"]}))
+"""
+
+_TAILS_CHILD = r"""
+import json
+import jax
+import jax.numpy as jnp
+from tpu_engine.models.lfm2 import lfm2_apply
+from tpu_engine.models.registry import (_ensure_builtin_models_imported,
+                                        create_model)
+from tpu_engine.runtime.scheduler import ContinuousGenerator
+
+assert jax.default_backend() == "tpu", jax.default_backend()
+_ensure_builtin_models_imported()
+spec = create_model("lfm2-small-test")
+params = spec.init(jax.random.PRNGKey(0))
+prompts = [list(range(7, 47)), [5]]         # chunks of 16 + 16 + 8; one token
+gen = ContinuousGenerator(spec, params=params, n_slots=4, dtype="float32",
+                          kv_block_size=16, prefill_chunk=16,
+                          prefix_sharing=False)
+try:
+    assert [x.shape for x in gen._spool.slab] == [(3, 5, 2, 48)]
+    first = [f.result(300) for f in
+             [gen.submit(p, max_new_tokens=9) for p in prompts]]
+    again = [f.result(300) for f in
+             [gen.submit(p, max_new_tokens=9) for p in prompts]]
+    stats = gen.stats()
+finally:
+    gen.stop()
+assert first == again and [len(t) for t in first] == [9, 9], (first, again)
+with jax.default_matmul_precision("highest"):
+    for prompt, tokens in zip(prompts, first):
+        seq = jnp.asarray([prompt + tokens[:-1]], jnp.int32)
+        logits = lfm2_apply(params, seq, spec.config, dtype=jnp.float32)[0]
+        want = logits[len(prompt) - 1:].argmax(-1).tolist()
+        assert tokens == want, (tokens, want)
+mixed, state, pool = stats["mixed"], stats["state_pool"], stats["kv_pool"]
+assert mixed["ticks"] == mixed["dispatches"] > 0, mixed
+assert state["rows_held"] == 0 and state["bytes_per_row"] == 1152, state
+assert pool["blocks_free"] == pool["blocks_total"], pool
+print(json.dumps({"tails": "ok", "tokens": first[0],
+                  "distinct": len(set(first[0] + first[1]))}))
 """
 
 _DEVICE_CHILD = r"""
@@ -583,6 +633,13 @@ def main():
         check(json.loads(out.strip().splitlines()[-1])["planes"] == "ok",
               "the looped lane's smoke did not end ok")
     say(phase="planes", seconds=times["planes"])
+
+    with phase("tails"):
+        out = run_child("tails", [sys.executable, "-c", _TAILS_CHILD], 300,
+                        env={"TPU_ENGINE_PAGED": "0"})
+        check(json.loads(out.strip().splitlines()[-1])["tails"] == "ok",
+              "the conv-tail lane's smoke did not end ok")
+    say(phase="tails", seconds=times["tails"])
 
     if device["count"] >= 4:
         with phase("lanes"):

@@ -135,7 +135,7 @@ def test_the_delta_rule_compiles_for_v5e_under_a_gate_a_head(v5e_devices):
 
 @pytest.mark.parametrize("name", list(kernel_check.GROUPED_SHAPES))
 def test_the_grouped_product_states_its_tiles_for_v5e(v5e_devices, name):
-    """`ops.moe.routed_experts` over the four banks the cells hold, each at
+    """`ops.moe.routed_experts` over the banks the cells hold, each at
     its decode-only and its chunk-tick list length
     (`kernel_check.GROUPED_SHAPES`), compiled for one v5e: both products
     are still XLA's grouped kernel (`ragged-dot-none` behind one
@@ -275,6 +275,10 @@ def test_the_walk_s_own_cases_compile_for_v5e(v5e_devices):
     # the grid; 16 rows and ceil(320 / 8) more tall tiles.
     ("nemotron-3-super-120b-a12b-11l.agents/classes/W256",
      [(16, 1), (56, 1)]),
+    # G = 4 over 8 KV heads of 64 lanes: a decode row's 8 x 4 query rows
+    # are one packed tile, a tall tile is 32 slots = 128 query rows = one
+    # tile of the grid; 16 rows and ceil(384 / 32) more tall tiles.
+    ("lfm2-24b-a2b-9l.assist/classes/W256", [(16, 1), (28, 1)]),
 ])
 def test_the_two_classes_of_tile_are_two_calls_with_grids_of_their_own(
         v5e_devices, name, grids):
@@ -1090,6 +1094,92 @@ def test_one_mixer_a_layer_mixed_step_copies_no_pool_state_or_bank(
           analysis.temp_size_in_bytes, "alias", analysis.alias_size_in_bytes)
     assert analysis.temp_size_in_bytes < 1.0e9
     assert analysis.alias_size_in_bytes > 1.9e9      # both pools in place
+
+
+@pytest.mark.parametrize("width", [1, 256])
+def test_conv_operator_mixed_step_copies_no_pool_tail_or_bank(v5e_devices,
+                                                              width):
+    """The LFM2 cell's mixed step at its serving shapes (shapes only: 128
+    rows, nine layers: seven gated convs of three taps whose tails are the
+    state pool's ONE array, two read a K/V chain at G = 4 over 8 KV heads of
+    64 lanes, eight route 4 of 64 experts over banks held whole), both pools
+    donated, compiled for one v5e: the paged calls are Pallas calls in it
+    and NOTHING of the conv operator is (no kernel, no loop a chunk row: the
+    only loops left are the grouped product's own); no `copy`, `slice`,
+    `dynamic-slice` or `dynamic-update-slice` whose result is a pool, the
+    tails' array, an expert bank or a layer of one; both pools in place."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.lfm2 import lfm2_step_rows_ragged
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "lfm2-24b-a2b-9l.json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    assert width in (1, serving["gen_prefill_chunk"])
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg = spec.config
+    assert (cfg.n_heads // cfg.kv_heads, cfg.d_head) == (4, 64)
+    assert (cfg.n_linear_layers, cfg.n_moe_layers, cfg.n_full_layers) == (
+        7, 8, 2)
+    assert cfg.state_row_shapes == ((2, 2048),)
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    (kind,) = cfg.kv_block_kinds
+    one = placed(jax.ShapeDtypeStruct(
+        (kind.n_layers, serving["gen_kv_blocks"], bs, kind.kv_lanes[0]),
+        jnp.bfloat16))
+    pools = (KVCache(one, one),
+             tuple(placed(jax.ShapeDtypeStruct(
+                 (cfg.n_linear_layers, rows + 1) + shape, jnp.float32))
+                 for shape in cfg.state_row_shapes))
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return lfm2_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=functools.partial(ragged_paged_attention,
+                                      interpret=False),
+            sample_slot=jnp.zeros_like(pos0), held=spec.held,
+            max_tokens=serving["gen_prefill_chunk"] + rows)
+
+    def host(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    step, behind = _behind_a_step(tick, host(rows))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, (host(rows, -(-cfg.max_seq // bs)), host(rows)),
+        host(rows, width), host(rows), host(rows), *behind).compile()
+    hlo = compiled.as_text()
+    assert "_paged_call" in hlo
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", hlo)) == 16
+    banks = [bp["mlp"]["experts"] for bp in params["layers"]
+             if "experts" in bp["mlp"]]
+    assert len(banks) == 8
+    sizes = {math.prod(x.shape) for x in jax.tree.leaves(banks)}
+    for x in list(pools[0]) + list(pools[1]):
+        sizes |= {math.prod(x.shape), math.prod(x.shape[1:])}
+    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
+                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
+    moved = {op for dims, op in movers.findall(hlo)
+             if math.prod(map(int, dims.split(","))) in sizes}
+    assert not moved, moved
+    analysis = compiled.memory_analysis()
+    print("lfm2 step width", width, "temp bytes",
+          analysis.temp_size_in_bytes, "alias", analysis.alias_size_in_bytes)
+    assert analysis.temp_size_in_bytes < 1.0e9
+    assert analysis.alias_size_in_bytes > 2.6e9      # both pools in place
 
 
 @pytest.mark.parametrize("name,q_lens,width,grid", [

@@ -9,7 +9,7 @@ one whose rows also own a recurrent state (`RECURRENT_MODELS`) both forms
 of its recurrence (`gdn_step`, `gdn_chunk`; `kda_step`, `kda_chunk`;
 `ssd_step`, `ssd_chunk`), against the scan, the step also at the share of
 live rows its cell's ticks hold and with none (`STEP_LIVE`);
-`grouped_cases` the routed experts' grouped product over the four banks
+`grouped_cases` the routed experts' grouped product over the banks
 the benchmark's cells hold (`GROUPED_SHAPES`).
 `cell_cases` adds the ragged read at the shapes the benchmark's cells
 serve it at (`CELL_SHAPES`), `class_cases` the two calls a tick that
@@ -191,10 +191,23 @@ CLASS_SHAPES = {
         n_blocks=35841, max_tokens=320,
         rows=((1, 300), (242, 3000), (1, 2900), (0, 0))
         + tuple((1, 130 + 250 * r) for r in range(12))),
+    # assist (benchmarks/configs/lfm2-24b-a2b-9l.json): G = 4 over 8 KV
+    # heads of 64 lanes, the narrowest head a grouped cell reads (512 lanes
+    # a token): a decode row packs 8 x 4 query rows in one score tile, a
+    # tall tile is 32 slots = one grid tile of 128 query rows, so the
+    # 242-slot chunk is 8 tiles that each walk the row's 3 k columns. Under the
+    # cell's table of 5,120 columns and its pool (16 of the lane's 128
+    # rows, contexts to 3.2 k: the check's gather reference holds every
+    # row's scores at once).
+    "lfm2-24b-a2b-9l.assist/classes/W256": dict(
+        geo=dict(n_heads=32, n_kv_heads=8, d_head=64), table_len=320,
+        n_blocks=40961, max_tokens=384,
+        rows=((1, 300), (242, 3000), (1, 2900), (0, 0))
+        + tuple((1, 130 + 250 * r) for r in range(12))),
 }
 
 # The grouped product of the served expert layers (`ops.moe.routed_experts`)
-# at the four banks the benchmark's cells hold, each at its lane's two list
+# at the banks the benchmark's cells hold, each at its lane's two list
 # lengths: a decode-only tick (the slots alone) and a tick that carries a
 # chunk of 256 prompt tokens. `slots` x `top_k` pairs, of which the share
 # routed to the `held` experts form rows. `gated`: a SwiGLU bank {"gate_up"
@@ -225,6 +238,13 @@ GROUPED_SHAPES = {
     "nemotron_h/grouped/latent1024x2688/chunk": dict(
         slots=320, valid=300, top_k=22, n_experts=512, held=(0, 128),
         lanes=1024, hidden=2688, gated=False),
+    # assist: every one of 64 experts held, 8 rows an expert a decode tick.
+    "lfm2/grouped/2048x1536/decode": dict(
+        slots=128, valid=128, top_k=4, n_experts=64, held=(0, 64),
+        lanes=2048, hidden=1536, gated=True),
+    "lfm2/grouped/2048x1536/chunk": dict(
+        slots=384, valid=370, top_k=4, n_experts=64, held=(0, 64),
+        lanes=2048, hidden=1536, gated=True),
 }
 
 
