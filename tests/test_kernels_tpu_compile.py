@@ -263,6 +263,78 @@ def test_the_walk_s_own_cases_compile_for_v5e(v5e_devices):
         f"walk/window/{n}" for n in pa.WINDOW_CASES]
 
 
+# The packed call of every cell that makes one, at the lane's own rows:
+# name -> (q dtype, rows, slots a row, H, H_kv, D, the table's blocks, the
+# pool's (layers, blocks), what else the call takes). The pool is bfloat16.
+PACKED_CALLS = {
+    # gpt2-large's q is float32 (its projections' accumulators).
+    "gpt2-large.batch": ("float32", 32, 1, 20, 20, 64, 64, (36, 1537), {}),
+    "lfm2-24b-a2b-9l.assist": ("bfloat16", 128, 1, 32, 8, 64, 320,
+                               (2, 40961), {}),
+    "olmo-hybrid-7b-12l.digest": ("bfloat16", 16, 1, 30, 30, 128, 1024,
+                                  (3, 1537), {}),
+    "ouro-2.6b.think": ("bfloat16", 8, 1, 16, 16, 128, 321, (192, 321), {}),
+    "falcon-h1-34b-6l.converse": ("bfloat16", 64, 1, 20, 4, 128, 128,
+                                  (6, 6145), {}),
+    "laguna-s-2.1-5l.repo/full": ("bfloat16", 32, 1, 48, 8, 128, 1024,
+                                  (2, 2049), {}),
+    "laguna-s-2.1-5l.repo/window": ("bfloat16", 32, 1, 72, 8, 128, 1024,
+                                    (3, 2049), {"window": 512}),
+    "nemotron-3-super-120b-a12b-11l.agents": (
+        "bfloat16", 64, 1, 32, 2, 128, 576, (2, 35841), {}),
+    "sdar-30b-a3b-chat-7l.reply": ("bfloat16", 64, 4, 32, 4, 128, 128,
+                                   (7, 8193), {"mask_block": 4}),
+    "mistral-7b-v0.2-8l.docqa/W1": ("bfloat16", 16, 1, 32, 8, 128, 2048,
+                                    (8, 2817), {}),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PACKED_CALLS))
+def test_the_packed_fold_compiles_for_v5e_at_every_cell_s_shape(
+        v5e_devices, cell):
+    """Since PR 61 a packed tile's fold at D = 64 is two products a chunk of
+    heads: (M, pack x D) x (span, pack x D) scores from the block-diagonal
+    queries and (M, span) x (span, pack x D) values into an accumulator
+    (H_kv / pack, M, pack x D) (the queries' rows are stored at a lane
+    offset of 64); at D = 128 it stays a product a head into (H_kv, M, D)
+    (`pa._product_heads`). Mosaic takes the call at every packed shape a
+    cell makes (digest's accumulator is 460 KB), and the body holds two
+    `dot_general`s a product."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.ops import paged_attention as pa
+
+    dtype, rows, slots, h, h_kv, d, table, (layers, blocks), more = \
+        PACKED_CALLS[cell]
+    one = SingleDeviceSharding(v5e_devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dtype), sharding=one)
+
+    pool = shape((layers, blocks, 16, h_kv * d), "bfloat16")
+    operands = (shape((rows, slots, h, d), dtype), pool, pool, None, None,
+                shape((1,), "int32"), shape((rows, table), "int32"),
+                shape((rows,), "int32"), shape((rows,), "int32"))
+    call = functools.partial(pa._paged_call, interpret=False, **more)
+    jax.jit(call).lower(*operands).compile()
+    tile, pack, _ = pa._tile_geometry(slots * h // h_kv, h_kv)
+    assert pack > 1 and pack * tile <= 128
+    width = pack if d == 64 else 1
+    assert pa._product_heads(pack, d) == width
+    (params,) = _pallas_calls(jax.make_jaxpr(call)(*operands).jaxpr)
+
+    def products(jaxpr):
+        return sum((eqn.primitive.name == "dot_general")
+                   + sum(products(sub)
+                         for sub in jax.core.jaxprs_in_params(eqn.params))
+                   for eqn in jaxpr.eqns)
+
+    assert products(params["jaxpr"]) == 2 * h_kv // width
+    accumulator = str(params["grid_mapping"].scratch_avals[-1])
+    assert f"float32[{h_kv // width},{pack * tile},{width * d}]" in \
+        accumulator.replace(" ", ""), accumulator
+
+
 @pytest.mark.parametrize("name, grids", [
     ("olmo-hybrid-7b-12l.digest/classes/W256", [(16, 1), (19, 1)]),
     ("laguna-s-2.1-5l.repo/full/classes/W256", [(8, 1), (13, 3)]),

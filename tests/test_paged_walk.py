@@ -196,3 +196,114 @@ def test_a_decode_tick_of_batch_fetches_a_block_s_slack_and_no_more():
     ctx = int((pos0 + 1).sum())
     assert ctx <= fetched < 1.10 * ctx
     assert -(-(pos0 + 1) // 128).sum() * 128 > 1.25 * ctx
+
+
+# -- the packed fold: two products a chunk of heads (PR 61) -------------------
+#
+# The packed shapes the benchmark's cells run, small: name -> (H_kv, G, D,
+# q_lens, pos0, table_len, what else the call takes). A call one slot wide
+# packs H_kv x G query rows into one score tile; `mask_block` 4 at G = 8 is
+# reply's tile (4 slots x 8 heads = 32 rows a KV head, 4 heads packed);
+# sixteen slots at G = 4 over 4 heads is two chunks of two heads. At D = 64
+# ONE product takes a chunk's heads, at D = 128 a head (`pa._product_heads`).
+DECODE_ROWS = ((1, 1, 0, 1), (37, 130, 9, 191), 13)
+WINDOW_ROWS = ((1, 1, 1, 1), (5, 39, 40, 700), 48)
+RUNS_OF_4 = ((4, 4, 0, 4, 4), (0, 36, 9, 124, 128), 16)
+PACKED_SHAPES = {
+    "5x1x64-an-odd-count-of-64-lane-heads": (5, 1, 64, *DECODE_ROWS, {}),
+    "8x4x64": (8, 4, 64, *DECODE_ROWS, {}),
+    "8x4x64-int8-pool": (8, 4, 64, *DECODE_ROWS, {"kind": "quant_ragged"}),
+    "8x4x64-window": (8, 4, 64, *WINDOW_ROWS, {"window": 40}),
+    "4x8x64-mask-block-4-at-rows-32": (4, 8, 64, *RUNS_OF_4,
+                                       {"mask_block": 4}),
+    "4x4x64-two-chunks-of-two-heads": (4, 4, 64, (16, 3, 0, 16),
+                                       (0, 37, 9, 120), 13, {}),
+    "4x5x128": (4, 5, 128, *DECODE_ROWS, {}),
+    "8x6x128-window": (8, 6, 128, *WINDOW_ROWS, {"window": 40}),
+    "6x1x128": (6, 1, 128, *DECODE_ROWS, {}),
+    "2x16x128": (2, 16, 128, *DECODE_ROWS, {}),
+    "2x16x128-int8-pool": (2, 16, 128, *DECODE_ROWS,
+                           {"kind": "quant_ragged"}),
+    "4x8x128-mask-block-4-at-rows-32": (4, 8, 128, *RUNS_OF_4,
+                                        {"mask_block": 4}),
+}
+
+
+def _packed_workload(name):
+    """(read path, q_lens, `parity_workload`'s shape, what else the call
+    takes) of one of `PACKED_SHAPES`."""
+    h_kv, group, d_head, q_lens, pos0, table_len, more = PACKED_SHAPES[name]
+    more = dict(more)
+    shape = dict(n_heads=h_kv * group, n_kv_heads=h_kv, d_head=d_head,
+                 block_size=16, n_blocks=1 + len(q_lens) * table_len,
+                 table_len=table_len, pos0=pos0)
+    return more.pop("kind", "ragged"), q_lens, shape, more
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", sorted(PACKED_SHAPES))
+def test_the_packed_fold_equals_the_reference_at_the_cells_packed_shapes(
+        name, dtype):
+    """One block-diagonal score product and one value product a chunk of
+    packed heads (D = 64), or a product a head into one score tile (D =
+    128), against the gather reference: the products that are added up are
+    products with exact zeros, so float32 holds to rounding and bfloat16
+    to the pool's own."""
+    kind, q_lens, shape, more = _packed_workload(name)
+    rows, pack, _ = pa._tile_geometry(
+        max(q_lens) * shape["n_heads"] // shape["n_kv_heads"],
+        shape["n_kv_heads"])
+    assert pack > 1 and pack * rows <= 128
+    limit = {"float32": 5e-5, "bfloat16": 3e-2}[dtype]
+    assert pa._parity(kind, q_lens, dtype=jnp.dtype(dtype), interpret=True,
+                      **shape, **more) < limit
+
+
+def test_a_product_takes_the_chunk_s_heads_where_a_head_is_half_a_lane_tile():
+    """`_product_heads` reads the call's shapes and nothing else: every
+    packed head at D = 64 (and at the test models' narrower heads), one at
+    a multiple of 128, where the chip showed nothing to gain."""
+    assert [pa._product_heads(pack, d) for pack, d in
+            ((20, 64), (8, 64), (2, 16), (5, 96), (1, 64))] == [20, 8, 2, 5, 1]
+    assert [pa._product_heads(pack, d) for pack, d in
+            ((30, 128), (8, 128), (4, 256), (1, 128))] == [1, 1, 1, 1]
+
+
+@pytest.mark.parametrize("name", [
+    "5x1x64-an-odd-count-of-64-lane-heads", "8x4x64", "8x4x64-window",
+    "4x8x64-mask-block-4-at-rows-32", "4x4x64-two-chunks-of-two-heads",
+    "4x5x128", "2x16x128"])
+def test_a_packed_fold_holds_two_products_a_product_s_heads(name):
+    """The `dot_general`s of the kernel's body (the fold is its only loop),
+    nested jaxprs included. At D = 64 they are 2 x H_kv / pack, not the
+    2 x H_kv of a product a head: a score product (M, pack x D) x (span,
+    pack x D) and a value product (M, span) x (span, pack x D) a chunk. At
+    D = 128 a head's product contracts a whole lane tile of an aligned
+    slice already: 2 x H_kv products of (M, D) tiles, the parent's
+    program."""
+    import jax
+
+    _, q_lens, shape, more = _packed_workload(name)
+    operands, _ = pa.parity_workload("ragged", q_lens, dtype=jnp.bfloat16,
+                                     window=more.get("window"), **shape)
+
+    def eqns(jaxpr, primitive):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == primitive:
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub, primitive)
+
+    (call,) = eqns(jax.make_jaxpr(functools.partial(
+        pa.ragged_paged_attention, interpret=False, **more))(
+            *operands).jaxpr, "pallas_call")
+    found = [tuple(tuple(v.aval.shape) for v in eqn.invars)
+             for eqn in eqns(call.params["jaxpr"], "dot_general")]
+    h_kv, d_head = shape["n_kv_heads"], shape["d_head"]
+    rows, pack, blocks = pa._tile_geometry(
+        max(q_lens) * shape["n_heads"] // h_kv, h_kv)
+    width = pack if d_head == 64 else 1
+    m, lanes, span = pack * rows, width * d_head, blocks * 16
+    assert sorted(found) == sorted(
+        [((m, lanes), (span, lanes)), ((m, span), (span, lanes))]
+        * (h_kv // width))

@@ -88,9 +88,22 @@ Tile geometry comes from the shapes the call sees (`_tile_geometry`),
 one walk for both: a chunk's tile is 128 query rows a KV head against
 groups of 16 blocks, (128, D) x (D, 256) a head; a decode row has ONE
 query row a head, so its heads are packed into one score tile (row i =
-head i: each head's product with its own key lanes, queries zero in the
-other heads' rows, added up) against groups of 8 blocks. VMEM holds
-O(H_kv * _ROW_TILE * D) whatever W*G is.
+head i) against groups of 8 blocks. **Where a head's D lanes are half a
+lane tile (D = 64) a packed tile's fold is two products a group** (PR 61,
+`_product_heads`): the packed heads' lanes of a buffer row are ONE
+contraction. The queries are laid block-diagonally once a tile,
+(M, pack * D) with head h's rows holding its D values in lanes
+[h*D, (h+1)*D) and zero elsewhere, so one (M, pack * D) x (span, pack * D)
+product gives every head's scores against its own keys (the zeros do
+what a product a head and their sum did, every MXU pass contracts whole
+lane tiles, no head's key slice is cut out of the buffer), and one
+(M, span) x (span, pack * D) product gives the values, of which head h's
+own rows and lanes are its output. Where D is a multiple of 128 a
+product a head already contracts whole lane tiles of an aligned slice,
+and the packed tile keeps it: head h's queries in their rows of a zero
+(M, D) tile, the heads' products added up. One loop, over the products
+of a chunk of heads; which a call takes follows from its shapes. VMEM
+holds O(H_kv * _ROW_TILE * D) whatever W*G is.
 
 **Two classes of tile.** The geometry is a CALL's, chosen by its width,
 so a tick that carries a chunk would read its decode rows through the
@@ -265,7 +278,8 @@ def _tile_geometry(rows_a_row: int, n_kv_heads: int):
     keeps it within `_ROW_TILE`: 1 for a chunk (128 rows a head fill the
     MXU's rows alone), every head for a decode row (gpt2-large: 20 rows,
     Mistral: 8 x 4), so a head with one query row is not a product and a
-    softmax of its own. `blocks`: physical blocks a group of the walk."""
+    softmax of its own (how many of them ONE product takes:
+    `_product_heads`). `blocks`: physical blocks a group of the walk."""
     rows = min(rows_a_row, _ROW_TILE)
     pack = max(p for p in range(1, n_kv_heads + 1)
                if n_kv_heads % p == 0 and p * rows <= _ROW_TILE)
@@ -273,10 +287,28 @@ def _tile_geometry(rows_a_row: int, n_kv_heads: int):
                         else _BLOCKS_PER_GROUP_PACKED)
 
 
+def _product_heads(pack: int, d_head: int) -> int:
+    """KV heads ONE product of a tile's fold takes, of the `pack` that share
+    its score tile: all of them where a head's D lanes are not whole lane
+    tiles (D = 64: gpt2-large's 20 heads, LFM2's 8 x 4 rows), one where
+    they are (D a multiple of 128). Measured, one call alone on a v5e at
+    the cells' shapes (PERF.md section 6, PR 61), a product a head against
+    a product a chunk: D = 64 98.7 -> 84.8 us (32 rows x 20 heads) and
+    1554 -> 1243 (128 rows x 8 heads x 4): a head's product had contracted
+    half a lane tile, from a key slice that starts mid-tile for every odd
+    head. D = 128, where the MXU's passes are the same in number either
+    way: 1502 | 1502 (30 heads), 264 | 261 (16), 537 | 537 (4 x 32 rows,
+    the block mask), 336 | 337 (4 x 5), 618 | 617 (2 x 16), 154 | 153 (8 x
+    4), and 967 -> 993, 595 -> 611 (8 x 6, two sets of contexts): nothing
+    to gain and one shape that loses 2.7 %, so those keep a product a
+    head."""
+    return pack if d_head % 128 else 1
+
+
 def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
                   v_hbm, *rest, block_size: int, blocks: int, scale: float,
-                  group: int, pack: int, quant: bool, window=None,
-                  mask_block: int = 1):
+                  group: int, pack: int, width: int, quant: bool,
+                  window=None, mask_block: int = 1):
     """One query TILE a grid step (b, t): `rows` query rows of batch row
     b (row r = slot r // G, group head r % G) for ALL its KV heads.
     q_ref/o_ref (1, H_kv, rows, D); k_hbm/v_hbm: the whole pools, left in
@@ -291,13 +323,26 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
     started for it, and into which buffer), so only a tile after a dead
     step, or the call's first, waits for a fetch with nothing to fold.
 
-    `pack` KV heads share a score tile of M = pack * rows rows (row
-    i = head i // rows of the chunk, query row i % rows): head h's
-    queries sit in their rows of a zero (M, D) tile, the heads'
-    products with their own key lanes add up to the (M, span) scores,
-    and of the (M, D) product with head h's values its rows alone are
-    kept. Statistics (m/l: (H_kv/pack, M, 1)) stay columns, so no step
-    moves a vector between lanes and sublanes; acc: (H_kv, M, D) f32.
+    `pack` KV heads, a CHUNK, share a score tile of M = pack * rows rows
+    (row i = head i // rows of the chunk, query row i % rows) and one
+    softmax; the chunk's scores and values are the products of `width`
+    heads each (`_product_heads`; `qx_sc` and acc: (H_kv/width, M,
+    width * D), the same bytes either way). width == pack (D = 64): two
+    products a chunk and group. The chunk's queries are laid block-
+    diagonally once a tile (head h's rows hold its D values in its own
+    lanes and zero in the other heads'), so ONE product over the chunk's
+    pack * D lanes of the K buffer's rows is every head's scores against
+    its own keys, and ONE product of the probabilities with the same
+    lanes of the V buffer's rows goes into acc, of which head h's own
+    rows and lanes are its output at the tile's end (the rest, a head's
+    probabilities against another head's values, is never read).
+    width == 1 (D a multiple of 128): head h's queries sit in their rows
+    of a zero (M, D) tile, the heads' products with their own key lanes
+    add up to the (M, span) scores, and of the (M, D) product with head
+    h's values its rows alone are kept. pack == 1 is the same loop, a
+    head a chunk and `q_ref` itself the queries.
+    Statistics (m/l: (H_kv/pack, M, 1)) stay columns, so no step moves
+    a vector between lanes and sublanes.
     Causal masking within the new-token window: query row r keeps
     kpos <= pos0 + r // G; a padding row (a slot past q_len) reads as
     the tile's last valid one, so no row keeps a column past the
@@ -317,8 +362,9 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
     scales of the row's table, a group's tokens on the lanes (Mosaic
     takes no DMA whose minor dimension is H_kv, so the call gathers
     them). int8 -> f32 is exact and the slot's scale multiplies the
-    head's score and probability instead of its D key and value lanes:
-    the reference's product, rounded once at the int8 write."""
+    head's score and probability instead of its D key and value lanes
+    (a row of a packed tile belongs to one head, `head_rows`): the
+    reference's product, rounded once at the int8 write."""
     if quant:
         ks_ref, vs_ref, *rest = rest
     o_ref, k_buf, v_buf, sems, warm_sc, *scratch = rest
@@ -328,7 +374,14 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
     n_b, n_t = pl.num_programs(0), pl.num_programs(1)
     n_kv_heads, rows, d_head = q_ref.shape[1:]
     m_rows = pack * rows
+    lanes = width * d_head          # what ONE product contracts, or makes
     span = blocks * block_size
+
+    def own_rows(h):                # head h's rows of its chunk's M
+        return pl.ds(h % pack * rows, rows)
+
+    def own_lanes(h):               # and its lanes of its product's
+        return pl.ds(h % width * d_head, d_head)
 
     def tile(b, t):
         """(live, horizon, lower, first group) of grid step (b, t).
@@ -416,9 +469,12 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
         if pack > 1:
+            # Head h's query rows hold its D values in its own lanes of
+            # its product's (block-diagonal where a product takes the
+            # chunk) and zero in every other row.
             qx_sc[...] = jnp.zeros(qx_sc.shape, qx_sc.dtype)
             for h in range(n_kv_heads):
-                qx_sc[h, pl.ds(h % pack * rows, rows), :] = q_ref[0, h]
+                qx_sc[h // width, own_rows(h), own_lanes(h)] = q_ref[0, h]
         # qpos - (column inside a group): a column of group g is kept
         # where this reaches g * span. qpos stops at the horizon's last
         # column (a valid row's is below it already).
@@ -430,11 +486,24 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
         reach = jnp.minimum(qpos, horizon - 1) \
             - jax.lax.broadcasted_iota(jnp.int32, shape, 1)
 
-        def head(buf, slot, h):                 # -> (span, D)
-            x = buf[slot, :, :, h * d_head:(h + 1) * d_head]
+        def heads(buf, slot, j):                # -> (span, width * D)
+            x = buf[slot, :, :, j * lanes:(j + 1) * lanes]
             if quant:
                 x = x.astype(jnp.float32)
-            return x.reshape(span, d_head)
+            return x.reshape(span, lanes)
+
+        def head_rows(x, j):
+            """(H_kv', span), a head a row -> product j's (M, span): row i
+            the row of ITS head (a product of one head: every row that
+            head's, the other heads' rows hold zeros)."""
+            first = j * width
+            if width == 1 or rows == 1:
+                return x[first:first + width]
+            own = jax.lax.broadcasted_iota(jnp.int32, (m_rows, 1), 0) // rows
+            out = x[first:first + 1]
+            for i in range(1, width):
+                out = jnp.where(own == i, x[first + i:first + i + 1], out)
+            return out
 
         def fold(g, slot):
             # What the pipeline fetches while group g is folded: this
@@ -470,18 +539,20 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
                 if window is not None:
                     seen &= column >= lower      # given back: the null block
             for c in range(n_kv_heads // pack):
-                heads = range(c * pack, (c + 1) * pack)
+                # The chunk's products, `width` heads each: ONE where a
+                # product takes the chunk's lanes whole, else one a head.
+                products = range(c * pack // width, (c + 1) * pack // width)
                 s = None
-                for h in heads:
-                    q = q_ref[0, h] if pack == 1 else qx_sc[h]
+                for j in products:
+                    q = q_ref[0, j] if pack == 1 else qx_sc[j]
                     if quant:
                         q = q.astype(jnp.float32)
                     part = jax.lax.dot_general(
-                        q, head(k_buf, slot, h),
+                        q, heads(k_buf, slot, j),
                         dimension_numbers=(((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
                     if quant:
-                        part = part * ks[h:h + 1]
+                        part = part * head_rows(ks, j)
                     s = part if s is None else s + part
                 s = jnp.where(keep, s * scale, _NEG_INF)       # (M, span)
                 m = m_sc[c]                                    # (M, 1)
@@ -491,24 +562,23 @@ def _paged_kernel(tables_ref, pos0_ref, lengths_ref, layer_ref, q_ref, k_hbm,
                 corr = jnp.where(m == _NEG_INF, 0.0, jnp.exp(m - safe_m))
                 l_sc[c] = l_sc[c] * corr + jnp.sum(p, axis=-1, keepdims=True)
                 m_sc[c] = m_new
-                for h in heads:
-                    v = head(v_buf, slot, h)
+                for j in products:
+                    v = heads(v_buf, slot, j)
                     if quant:
-                        pv = p * vs[h:h + 1]
+                        pv = p * head_rows(vs, j)
                     else:
                         v = jnp.where(seen, v, 0).astype(v.dtype)
                         pv = p.astype(v.dtype)
-                    acc_sc[h] = acc_sc[h] * corr + jax.lax.dot_general(
+                    acc_sc[j] = acc_sc[j] * corr + jax.lax.dot_general(
                         pv, v, dimension_numbers=(((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)
             return 1 - slot
 
         jax.lax.fori_loop(first_group, groups, fold, first_slot)
         for h in range(n_kv_heads):
-            own = pl.ds(h % pack * rows, rows)    # head h's rows of the M
-            l = l_sc[h // pack, own, :]
-            o_ref[0, h] = (acc_sc[h, own, :] / jnp.where(l == 0.0, 1.0, l)
-                           ).astype(o_ref.dtype)
+            l = l_sc[h // pack, own_rows(h), :]
+            o_ref[0, h] = (acc_sc[h // width, own_rows(h), own_lanes(h)]
+                           / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def walk_tiles(pos0, qlen, *, width: int, group: int, kv_heads: int,
@@ -576,6 +646,7 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
     qh = (q.reshape(b, w, h_kv, g, d).transpose(0, 2, 1, 3, 4)
           .reshape(b, h_kv, r, d))
     rows, pack, blocks = _tile_geometry(r, h_kv)
+    width = _product_heads(pack, d)
     r_pad = pl.cdiv(r, rows) * rows
     if r_pad != r:
         # Zero rows past W*G: computed like padding slots, sliced off.
@@ -613,7 +684,8 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
                                   lambda b, t, *_: (b, 0, 0, 0))] * 2
     kernel = functools.partial(
         _paged_kernel, block_size=bs, blocks=blocks,
-        scale=1.0 / math.sqrt(d), group=g, pack=pack, quant=quant,
+        scale=1.0 / math.sqrt(d), group=g, pack=pack, width=width,
+        quant=quant,
         window=window, **({"mask_block": mask_block} if mask_block > 1
                           else {}))
     m_rows = pack * rows
@@ -629,10 +701,11 @@ def _paged_call(q, k_pool, v_pool, k_scale, v_scale, layer, tables, pos0,
                 pltpu.VMEM((2, blocks, bs, h_kv * d), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((2,), jnp.int32),
-            ] + [pltpu.VMEM((h_kv, m_rows, d), q.dtype)] * (pack > 1) + [
+            ] + [pltpu.VMEM((h_kv // width, m_rows, width * d), q.dtype)
+                 ] * (pack > 1) + [
                 pltpu.VMEM((h_kv // pack, m_rows, 1), jnp.float32),
                 pltpu.VMEM((h_kv // pack, m_rows, 1), jnp.float32),
-                pltpu.VMEM((h_kv, m_rows, d), jnp.float32),
+                pltpu.VMEM((h_kv // width, m_rows, width * d), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h_kv, r_pad, d), q.dtype),
