@@ -151,6 +151,31 @@ def test_nothing_cites_a_deleted_bench_arm_or_a_pre_chip_record():
     assert not bad, "\n".join(bad)
 
 
+_MODELS = os.path.join(REPO_ROOT, "tpu_engine", "models")
+# What builds a `ModelSpec` beside `registry.causal_lm_spec`: the models
+# that are no language model, and `ssd`, whose one-shot `apply` is a scan
+# over a state that yields one row of logits, not a full forward.
+_OWN_SPEC = {"registry.py", "bert.py", "mlp.py", "resnet.py", "yolo.py",
+             "onnx_graph.py", "ssd.py"}
+
+
+@pytest.mark.parametrize("name", sorted(
+    f for f in os.listdir(_MODELS)
+    if f.endswith(".py") and f != "tick_tokens.py"))
+def test_a_model_file_lists_no_tokens_and_wraps_no_wire_contract(name):
+    """How a tick's tokens are listed is `models/tick_tokens.py`'s to say,
+    and a causal LM's one-shot wire wrapper `registry.causal_lm_spec`'s
+    (PR 62): a family file that calls the plans under the list itself, or
+    builds its own `ModelSpec`, has begun a copy."""
+    with open(os.path.join(_MODELS, name), encoding="utf-8") as f:
+        tree = ast.parse(f.read())
+    called = {getattr(n.func, "attr", getattr(n.func, "id", None))
+              for n in ast.walk(tree) if isinstance(n, ast.Call)}
+    assert not called & {"tile_plan", "tile_slots", "tiles_bound",
+                         "class_plan"}, name
+    assert ("ModelSpec" in called) <= (name in _OWN_SPEC), name
+
+
 # -- lock discipline ----------------------------------------------------------
 
 _LOCK_VIOLATING = '''

@@ -95,10 +95,10 @@ from tpu_engine.models.moonlight import (
     _swiglu_init,
 )
 from tpu_engine.models.olmo_hybrid import _linear_rows
-from tpu_engine.models.registry import ModelSpec, register
+from tpu_engine.models.registry import ModelSpec, causal_lm_spec, register
+from tpu_engine.models.tick_tokens import lm_head, tick_tokens
 from tpu_engine.models.transformer import (
     TransformerConfig,
-    _write_pool,
     kv_kind_config,
 )
 from tpu_engine.ops import nn
@@ -273,8 +273,8 @@ def falcon_h1_init(key, cfg: FalconH1Config):
 
 # -- one layer's pieces ----------------------------------------------------------
 
-def _embed(params, ids, cfg: FalconH1Config, dtype):
-    h = nn.embedding(params["tok_embed"], ids).astype(jnp.float32)
+def _scaled(h, cfg: FalconH1Config, dtype):
+    """h: the embedding's rows in float32."""
     return (h * cfg.embedding_multiplier).astype(dtype)
 
 
@@ -410,10 +410,10 @@ def _run_layers(params, h, carry, cfg: FalconH1Config, mixers, dtype,
     return h, carry
 
 
-def _head(params, h, cfg: FalconH1Config, dtype):
-    h = nn.rmsnorm(params["ln_f"], h, eps=cfg.ln_eps)
-    logits = nn.dense(params["head"], h, dtype=dtype).astype(jnp.float32)
-    return logits * cfg.lm_head_multiplier
+def _logits(params, h, cfg: FalconH1Config, dtype):
+    logits = lm_head(params, h, cfg.ln_eps, dtype)
+    with step_part("head"):
+        return logits * cfg.lm_head_multiplier
 
 
 # -- the one-shot forward --------------------------------------------------------
@@ -424,7 +424,8 @@ def falcon_h1_apply(params, tokens, cfg: FalconH1Config, *,
     int32 -> logits (B, S, vocab) float32. `branches`: a list that takes
     each layer's (y_att, y_ssm, y_ffn), for the test that pins their ratio."""
     b, s = tokens.shape
-    h = _embed(params, tokens, cfg, dtype)
+    h = _scaled(nn.embedding(params["tok_embed"], tokens)
+                .astype(jnp.float32), cfg, dtype)
     positions = jnp.arange(s)
 
     def one_row(bp, u):
@@ -439,7 +440,7 @@ def falcon_h1_apply(params, tokens, cfg: FalconH1Config, *,
         return y_att, y_ssm, carry
 
     h, _ = _run_layers(params, h, (), cfg, mixers, dtype, branches)
-    return _head(params, h, cfg, dtype)
+    return _logits(params, h, cfg, dtype)
 
 
 # -- the served step: the mixed tick over the block pool and the state pool -------
@@ -449,63 +450,43 @@ def falcon_h1_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
                                attn_fn=None, step_fn=ssd_step_rows,
                                chunk_fn=ssd_chunk_row, sample_slot=None,
                                held=None, max_tokens: Optional[int] = None):
-    """`models.olmo_hybrid.olmo_hybrid_step_rows_ragged` for this family:
-    one ragged batch where row b consumes qlen[b] >= 0 new tokens at
-    logical columns [pos0[b], pos0[b] + qlen[b]), run over the tick's
-    TOKENS, a slot a tile of the list.
+    """This family's step of the mixed tick, over the tick's token list
+    (`models.tick_tokens`, a token an entry).
 
     caches: (the block pool's K/V pair, (layers, NB, bs, H_kv*D); the state
     pool's arrays, `_linear_rows`), both `n_layers` deep and updated in
     place (donate them); tables: (the rows' block table (B, nb); the rows'
-    state row (B,), the null row 0 for a free slot). EVERY layer scatters
-    each token's K and V into its row's blocks, reads each row by the
-    class of its run (`ops.paged_attention.ragged_read_by_class` at G =
-    n_heads / n_kv_heads: a row with one new token as a row of a width-1
-    call, its KV heads packed; a longer run in tall tiles), AND runs the
+    state row (B,), the null row 0 for a free slot). EVERY layer attends
+    (`PagedKV.attend` at G = n_heads / n_kv_heads) AND runs the
     recurrence over the same rows (`ssd_step`, `ssd_chunk`), both from
     the same normed input. `step_fn`, `chunk_fn`: `ops.ssd`'s
     `ssd_step_rows` and `ssd_chunk_row` or stand-ins of their signatures.
 
     Returns (logits, caches, rows (0, 1): the family routes no experts)."""
-    from tpu_engine.ops import latent_attention as la
     from tpu_engine.ops import paged_attention as pa
 
     del held
     if attn_fn is None:
         attn_fn = pa.default_ragged_attention()
     (pool, state), (table, rows) = caches, tables
-    b, w = tokens.shape
-    m = la.tiles_bound(b, w, 1, max_tokens)
-    bs = pool.k.shape[2]
-    with step_part("plan"):
-        plan = la.tile_plan(qlen, 1, m)
-        _, valid = la.tile_slots(plan, qlen, 1)
-        row, slot, valid = (plan.row, jnp.minimum(plan.tile, w - 1),
-                            valid[:, 0])
-        logical = pos0[row] + slot
-        cols = jnp.minimum(logical, table.shape[1] * bs - 1)
-        # invalid -> null block
-        blk = jnp.where(valid, table[row, cols // bs], 0)
-        classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
-                                max_tokens)
+    w = tokens.shape[1]
+    tt = tick_tokens(pos0, qlen, w, max_tokens)
+    kv = tt.paged_kv(table, pool.k.shape[2], cfg.n_heads // cfg.kv_heads)
+    h = tt.embed(params, tokens, jnp.float32)
     with step_part("embed"):
-        h = _embed(params, tokens[row, slot], cfg, dtype)
+        h = _scaled(h, cfg, dtype)
 
     def mixers(layer, bp, u, carry):
         pool, state = carry
         at = cfg.pool_layer[layer]
         with step_part("attn/qkv"):
-            q, k, v = _attn_inputs(bp["attn"], u, logical, cfg, dtype)
-        with step_part("attn/write"):
-            pool = _write_pool(pool, at, blk, cols % bs, k, v)
-        with step_part("attn/read"):
-            o = pa.ragged_read_by_class(attn_fn, q, pool, at, table, pos0,
-                                        classes, plan.start, row, slot)
+            q, k, v = _attn_inputs(bp["attn"], u, tt.logical, cfg, dtype)
+        o, pool = kv.attend(attn_fn, q, k, v, pool, at)
         sp = bp["ssm"]
         with step_part("mixer/in"):
             step, chunk = _with_skip(sp, step_fn), _with_skip(sp, chunk_fn)
         y_ssm, state = _linear_rows(
-            sp, u, state, at, plan.start, rows, pos0, qlen, w, cfg, dtype,
+            sp, u, state, at, tt.plan.start, rows, pos0, qlen, w, cfg, dtype,
             step, chunk, inputs=_ssm_inputs, output=_ssm_output,
             conv=_ssm_conv)
         with step_part("attn/out"):
@@ -514,39 +495,15 @@ def falcon_h1_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
 
     h, (pool, state) = _run_layers(params, h, (tuple(pool), tuple(state)),
                                    cfg, mixers, dtype)
-    with step_part("head"):
-        if sample_slot is not None:
-            h = h[jnp.minimum(plan.start + jnp.minimum(sample_slot, w - 1),
-                              m - 1)]                            # (B, d)
-        else:
-            # Row b's new tokens in the list.
-            listed = jnp.minimum(
-                plan.start[:, None] + jnp.arange(w)[None, :], m - 1)
-            h = jnp.where(
-                (jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
-                h[listed], 0)
-        return (_head(params, h, cfg, dtype), (KVCache(*pool), state),
-                jnp.zeros((0, 1), jnp.int32))
+    return (_logits(params, tt.head_rows(h, sample_slot), cfg, dtype),
+            (KVCache(*pool), state), jnp.zeros((0, 1), jnp.int32))
 
 
 # -- registry ----------------------------------------------------------------------
 
-def _spec(name: str, cfg: FalconH1Config, seq_len: int) -> ModelSpec:
-    def init(rng):
-        return falcon_h1_init(rng, cfg)
-
-    def apply(params, x, dtype=jnp.bfloat16):
-        # The one-shot wire contract of models.gpt2: (B, seq) float token
-        # ids -> (B, vocab) logits of the last non-pad position.
-        tokens = jnp.clip(x.astype(jnp.int32), 0, cfg.vocab - 1)
-        last = jnp.max(jnp.where(tokens > 0, jnp.arange(seq_len)[None, :],
-                                 0), axis=1)
-        logits = falcon_h1_apply(params, tokens, cfg, dtype=dtype)
-        return jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
-
-    return ModelSpec(name=name, apply=apply, init=init,
-                     input_shape=(seq_len,), output_shape=(cfg.vocab,),
-                     config=cfg, ragged_step=falcon_h1_step_rows_ragged)
+def _lm_spec(name: str, cfg: FalconH1Config, seq_len: int) -> ModelSpec:
+    return causal_lm_spec(name, cfg, seq_len, falcon_h1_init, falcon_h1_apply,
+                          ragged_step=falcon_h1_step_rows_ragged)
 
 
 def _cfg(**kw) -> FalconH1Config:
@@ -594,8 +551,8 @@ def make_falcon_h1(seq_len: int = 128, vocab: int = 261120,
                    max_seq: int = 16384, ln_eps: float = 1e-5,
                    param_dtype: str = "bfloat16") -> ModelSpec:
     """Falcon-H1-34B's published geometry; every width a keyword."""
-    return _spec("falcon_h1", _cfg(**{k: v for k, v in locals().items()
-                                      if k != "seq_len"}), seq_len)
+    return _lm_spec("falcon_h1", _cfg(**{k: v for k, v in locals().items()
+                                         if k != "seq_len"}), seq_len)
 
 
 @register("falcon_h1_small")
@@ -623,6 +580,6 @@ def make_falcon_h1_small(seq_len: int = 16, vocab: int = 256,
     """Tiny config for tests: three parallel layers, 5 query heads over 1
     KV head of 8 lanes (the odd group), 4 SSM heads of 8 lanes in 2 groups,
     a state of 16 lanes, conv 4, the published multipliers, float32."""
-    return _spec("falcon_h1_small",
-                 _cfg(**{k: v for k, v in locals().items()
-                         if k != "seq_len"}), seq_len)
+    return _lm_spec("falcon_h1_small",
+                    _cfg(**{k: v for k, v in locals().items()
+                            if k != "seq_len"}), seq_len)

@@ -15,10 +15,7 @@ next-token logits, shape (vocab,). The generation HTTP surface
 
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-
-from tpu_engine.models.registry import ModelSpec, register
+from tpu_engine.models.registry import ModelSpec, causal_lm_spec, register
 from tpu_engine.models.transformer import (
     TransformerConfig,
     step_weights,
@@ -28,28 +25,8 @@ from tpu_engine.models.transformer import (
 
 
 def _spec_from_config(name: str, cfg: TransformerConfig, seq_len: int) -> ModelSpec:
-    def init(rng):
-        return transformer_init(rng, cfg)
-
-    def apply(params, x, dtype=jnp.bfloat16):
-        # x: (B, seq) float token ids (wire format) → (B, vocab) logits of
-        # the last real (non-pad) position. Pad id 0 after the first token
-        # is treated as padding, matching the engine's zero-padding.
-        tokens = jnp.clip(x.astype(jnp.int32), 0, cfg.vocab - 1)
-        positions = jnp.arange(seq_len)[None, :]
-        nonpad = jnp.where(tokens > 0, positions, 0)
-        last = jnp.max(nonpad, axis=1)  # 0 if prompt is a single token
-        logits = transformer_apply(params, tokens, cfg, dtype=dtype)
-        return jnp.take_along_axis(
-            logits, last[:, None, None], axis=1)[:, 0]
-
-    return ModelSpec(
-        name=name,
-        apply=apply,
-        init=init,
-        input_shape=(seq_len,),
-        output_shape=(cfg.vocab,),
-        config=cfg,  # generation service needs the architecture
+    return causal_lm_spec(
+        name, cfg, seq_len, transformer_init, transformer_apply,
         # Megatron-style heads-axis placement (registry.TP_RULES): QKV /
         # MLP-up column-parallel, wo / proj row-parallel, head on vocab,
         # norms + embeddings replicated. Covers every family built on
@@ -59,8 +36,7 @@ def _spec_from_config(name: str, cfg: TransformerConfig, seq_len: int) -> ModelS
         # The dense step's kernels are cast to the lane's dtype once, not
         # every tick (transformer.step_weights); an MoE FFN casts its own
         # expert banks (ops.moe), so that family's steps read `params`.
-        step_weights=step_weights if cfg.n_experts == 0 else None,
-    )
+        step_weights=step_weights if cfg.n_experts == 0 else None)
 
 
 @register("gpt2")

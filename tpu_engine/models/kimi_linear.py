@@ -75,14 +75,14 @@ from tpu_engine.models.moonlight import (
     _attn_expanded,
     _attn_inputs,
     _dense_init,
-    _head,
     _normal,
     _residual_gain,
     _swiglu_init,
     _unabsorb,
 )
 from tpu_engine.models.olmo_hybrid import _conv_heads, _linear_rows, _pad_run
-from tpu_engine.models.registry import ModelSpec, register
+from tpu_engine.models.registry import ModelSpec, causal_lm_spec, register
+from tpu_engine.models.tick_tokens import lm_head, tick_tokens
 from tpu_engine.models.transformer import (
     TransformerConfig,
     _mlp,
@@ -361,7 +361,7 @@ def kimi_linear_apply(params, tokens, cfg: KimiLinearConfig, *,
 
     h, _, _ = _run_layers(params, h, (), cfg, mixer, jnp.ones((b, s), bool),
                           dtype, cfg.held, None)
-    return _head(params, h, cfg, dtype)
+    return lm_head(params, h, cfg.ln_eps, dtype)
 
 
 # -- the served step: the mixed tick over the latent pool and the state pool ------
@@ -373,14 +373,13 @@ def kimi_linear_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
                                  chunk_fn=gdn_chunk_row, sample_slot=None,
                                  held=None,
                                  max_tokens: Optional[int] = None):
-    """`models.moonlight.moonlight_step_rows_ragged` for this family: one
-    ragged batch where row b consumes qlen[b] >= 0 new tokens at logical
-    columns [pos0[b], pos0[b] + qlen[b]), run over the tick's TOKENS in
-    tiles of `ops.latent_attention.slots_per_tile` slots (32 heads: 4
-    slots at a chunk's width, 1 at a decode tick's). A row's tiles lie
-    side by side in the list, so flattened it is the token list a KDA
-    layer takes (`models.olmo_hybrid._linear_rows`), a row's new tokens
-    from `plan.start[b]` tiles on.
+    """This family's step of the mixed tick, over the tick's token list
+    (`models.tick_tokens`) in tiles of
+    `ops.latent_attention.slots_per_tile` slots (32 heads: 4 slots at a
+    chunk's width, 1 at a decode tick's). A row's tiles lie side by side
+    in the list, so flattened it is the token list a KDA layer takes
+    (`models.olmo_hybrid._linear_rows`), a row's new tokens from
+    `plan.start[b]` tiles on.
 
     caches: (the latent pool's pair, k (MLA layers, NB, bs, PE_LANES) the
     shared key lanes and v (MLA layers, NB, bs, C) the latents; the state
@@ -399,32 +398,21 @@ def kimi_linear_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
         attn_fn = la.default_latent_attention()
     held = held or cfg.held
     (pool, state), (table, rows) = caches, tables
-    b, w = tokens.shape
-    bs = pool.k.shape[2]
+    w = tokens.shape[1]
     per_tile = la.slots_per_tile(cfg.n_heads, w)
+    tt = tick_tokens(pos0, qlen, w, max_tokens, per_tile=per_tile)
+    blk, off = tt.blocks(table, pool.k.shape[2])
     with step_part("plan"):
-        plan = la.tile_plan(qlen, per_tile,
-                            la.tiles_bound(b, w, per_tile, max_tokens))
-        slot, valid = la.tile_slots(plan, qlen, per_tile)        # (N, S)
-        n = plan.row.shape[0]
-        row = plan.row[:, None]
-        slot = jnp.minimum(slot, w - 1)
-        cols = jnp.minimum(pos0[row] + slot, table.shape[1] * bs - 1)
-        # invalid -> null block
-        blk = jnp.where(valid, table[row, cols // bs], 0)
-        off = cols % bs
         lengths = pos0 + qlen
-    with step_part("embed"):
-        h = nn.embedding(params["tok_embed"],
-                         tokens[row, slot]).astype(dtype)
+    h = tt.embed(params, tokens, dtype)
 
     def mixer(layer, bp, x, carry):
         pool, state = carry
         at = cfg.pool_layer[layer]
         if cfg.linear[layer]:
             y, state = _linear_rows(
-                bp["lin"], x.reshape(n * per_tile, -1), state, at,
-                plan.start * per_tile, rows, pos0, qlen, w, cfg, dtype,
+                bp["lin"], x.reshape(tt.n * per_tile, -1), state, at,
+                tt.plan.start * per_tile, rows, pos0, qlen, w, cfg, dtype,
                 step_fn, chunk_fn, inputs=_kda_inputs, output=_kda_output)
             return y.reshape(x.shape), (pool, state)
         ap = bp["attn"]
@@ -437,50 +425,25 @@ def kimi_linear_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
         with step_part("attn/qkv"):
             q_lat = _absorb(ap, q_nope, cfg, dtype)
         with step_part("attn/read"):
-            o_lat = attn_fn(q_lat, q_pe, *pool, at, table, plan, pos0,
+            o_lat = attn_fn(q_lat, q_pe, *pool, at, table, tt.plan, pos0,
                             lengths, scale=cfg.attn_scale)
         with step_part("attn/out"):
             return _unabsorb(ap, o_lat, cfg, dtype), (pool, state)
 
     h, (pool, state), taken = _run_layers(
-        params, h, (tuple(pool), tuple(state)), cfg, mixer, valid, dtype,
+        params, h, (tuple(pool), tuple(state)), cfg, mixer, tt.valid, dtype,
         held, max_tokens)
-
-    def at(slots):
-        """The rows' new tokens at `slots` ((B,) or (B, W)), found in the
-        tile list."""
-        start = plan.start.reshape((b,) + (1,) * (slots.ndim - 1))
-        tile = jnp.minimum(start + slots // per_tile, n - 1)
-        return h[tile, slots % per_tile]
-
-    with step_part("head"):
-        if sample_slot is not None:
-            h = at(jnp.minimum(sample_slot, w - 1))              # (B, d)
-        else:
-            every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
-            h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
-        return _head(params, h, cfg, dtype), (KVCache(*pool), state), taken
+    return (lm_head(params, tt.head_rows(h, sample_slot), cfg.ln_eps, dtype),
+            (KVCache(*pool), state), taken)
 
 
 # -- registry ----------------------------------------------------------------------
 
-def _spec(name: str, cfg: KimiLinearConfig, seq_len: int) -> ModelSpec:
-    def init(rng):
-        return kimi_linear_init(rng, cfg)
-
-    def apply(params, x, dtype=jnp.bfloat16):
-        # The one-shot wire contract of models.gpt2: (B, seq) float token
-        # ids -> (B, vocab) logits of the last non-pad position.
-        tokens = jnp.clip(x.astype(jnp.int32), 0, cfg.vocab - 1)
-        last = jnp.max(jnp.where(tokens > 0, jnp.arange(seq_len)[None, :],
-                                 0), axis=1)
-        logits = kimi_linear_apply(params, tokens, cfg, dtype=dtype)
-        return jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
-
-    return ModelSpec(name=name, apply=apply, init=init,
-                     input_shape=(seq_len,), output_shape=(cfg.vocab,),
-                     config=cfg, ragged_step=kimi_linear_step_rows_ragged,
-                     held=cfg.held)
+def _lm_spec(name: str, cfg: KimiLinearConfig, seq_len: int) -> ModelSpec:
+    return causal_lm_spec(name, cfg, seq_len, kimi_linear_init,
+                          kimi_linear_apply,
+                          ragged_step=kimi_linear_step_rows_ragged,
+                          held=cfg.held)
 
 
 def _cfg(**kw) -> KimiLinearConfig:
@@ -533,8 +496,8 @@ def make_kimi_linear(seq_len: int = 128, vocab: int = 163840,
                      param_dtype: str = "bfloat16") -> ModelSpec:
     """Kimi-Linear-48B-A3B's published geometry; every width a keyword.
     `held_count` 0 holds every expert."""
-    return _spec("kimi_linear", _cfg(**{k: v for k, v in locals().items()
-                                        if k != "seq_len"}), seq_len)
+    return _lm_spec("kimi_linear", _cfg(**{k: v for k, v in locals().items()
+                                           if k != "seq_len"}), seq_len)
 
 
 @register("kimi_linear_small")
@@ -557,6 +520,6 @@ def make_kimi_linear_small(seq_len: int = 16, vocab: int = 256,
     """Tiny config for tests: the cell's five layers (KDA dense; KDA, KDA,
     MLA, KDA with experts), 4 heads, keys and values of 8 lanes, conv 4, 8
     of 16 experts held, float32."""
-    return _spec("kimi_linear_small",
-                 _cfg(**{k: v for k, v in locals().items()
-                         if k != "seq_len"}), seq_len)
+    return _lm_spec("kimi_linear_small",
+                    _cfg(**{k: v for k, v in locals().items()
+                            if k != "seq_len"}), seq_len)

@@ -64,7 +64,8 @@ from tpu_engine.models.moonlight import (
     _residual_gain,
     _swiglu_init,
 )
-from tpu_engine.models.registry import ModelSpec, register
+from tpu_engine.models.registry import ModelSpec, causal_lm_spec, register
+from tpu_engine.models.tick_tokens import lm_head, tick_tokens
 from tpu_engine.models.transformer import (
     TransformerConfig,
     _mlp,
@@ -335,11 +336,6 @@ def _run_layers(params, h, carry, cfg: LagunaConfig, attend, valid, dtype,
     return h, carry, rows
 
 
-def _head(params, h, cfg: LagunaConfig, dtype):
-    h = nn.rmsnorm(params["ln_f"], h, eps=cfg.ln_eps)
-    return nn.dense(params["head"], h, dtype=dtype).astype(jnp.float32)
-
-
 # -- the one-shot forward --------------------------------------------------------
 
 def laguna_apply(params, tokens, cfg: LagunaConfig, *, dtype=jnp.bfloat16):
@@ -362,7 +358,7 @@ def laguna_apply(params, tokens, cfg: LagunaConfig, *, dtype=jnp.bfloat16):
 
     h, _, _ = _run_layers(params, h, (), cfg, attend,
                           jnp.ones((b, s), bool), dtype, cfg.held, None)
-    return _head(params, h, cfg, dtype)
+    return lm_head(params, h, cfg.ln_eps, dtype)
 
 
 # -- the served step: the mixed tick over the two pools ---------------------------
@@ -371,10 +367,8 @@ def laguna_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
                             cfg: LagunaConfig, *, dtype=jnp.bfloat16,
                             attn_fn=None, sample_slot=None, held=None,
                             max_tokens: Optional[int] = None):
-    """`models.moonlight.moonlight_step_rows_ragged` for this family: one
-    ragged batch where row b consumes qlen[b] >= 0 new tokens at logical
-    columns [pos0[b], pos0[b] + qlen[b]), run over the tick's TOKENS in
-    tiles of `_SLOTS_PER_TILE` slots (`ops.latent_attention.tile_plan`).
+    """This family's step of the mixed tick, over the tick's token list
+    (`models.tick_tokens`) in tiles of `_SLOTS_PER_TILE` slots.
 
     caches: (full, window), a K/V pair each, (layers of the kind, NB, bs,
     H_kv*D), updated in place (donate both); tables: (full, window), each
@@ -383,110 +377,65 @@ def laguna_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     blocks it gave back): the read is `ops.paged_attention`'s ragged read
     with `window`, a TILE of the list a row of the call, so a tile walks
     only the columns its own slots see. A FULL layer walks a row's whole
-    context, so its tiles follow the row's run
-    (`ops.latent_attention.class_plan`,
+    context, so its tiles follow the row's run (`PagedKV.attend`,
     `ops.paged_attention.ragged_read_by_class`): a row with one new token
     is a row of a width-1 call, the 8 KV heads packed, and a longer run is
     cut in tall tiles of up to 64 slots (8 list tiles; at G = 6, 384 query
     rows a KV head, three tiles of the call's grid), each a row of a second
     call, so a 256-token chunk walks its context 12 times and not once a
-    list tile, 32 times. Every token's K and V are scattered into its
-    row's blocks BEFORE the read (write-before-attend).
+    list tile, 32 times.
 
     ``held`` = (first, count): the experts `params` holds (default
-    `cfg.held`). Returns (logits, caches, rows) as the Moonlight step:
-    rows (L_moe, n_routed) int32, the rows each held expert took."""
-    from tpu_engine.ops import latent_attention as la
+    `cfg.held`). Returns (logits, caches, rows (L_moe, n_routed) int32:
+    the rows each held expert took)."""
     from tpu_engine.ops import paged_attention as pa
 
     if attn_fn is None:
         attn_fn = pa.default_ragged_attention()
     held = held or cfg.held
-    b, w = tokens.shape
+    w = tokens.shape[1]
     per_tile = min(w, _SLOTS_PER_TILE)
     bs = caches[0].k.shape[2]
+    tt = tick_tokens(pos0, qlen, w, max_tokens, per_tile=per_tile)
+    # The full layers' read takes the list flat, by the class of a row's
+    # run; a window layer's a TILE of the list as a row of its call, with
+    # its own first column, new tokens and table row.
+    full = tt.paged_kv(tables[0], bs, cfg.n_heads // cfg.kv_heads)
+    blk, off = tt.blocks(tables[1], bs)
     with step_part("plan"):
-        plan = la.tile_plan(qlen, per_tile,
-                            la.tiles_bound(b, w, per_tile, max_tokens))
-        slot, valid = la.tile_slots(plan, qlen, per_tile)        # (N, S)
-        row = plan.row[:, None]
-        slot = jnp.minimum(slot, w - 1)
-        logical = pos0[row] + slot
-        cols = jnp.minimum(logical, tables[0].shape[1] * bs - 1)
-        off = cols % bs
-        # A tile is a row of the read: its own first column and new tokens.
-        tile_pos0 = pos0[plan.row] + plan.tile * per_tile
-        tile_qlen = valid.sum(-1).astype(jnp.int32)
-        # invalid -> the null block
-        blk = [jnp.where(valid, t[row, cols // bs], 0) for t in tables]
-        tile_tables = [t[plan.row] for t in tables]
-        # The full layers' read: the list flat, row b's run from its first
-        # tile.
-        classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
-                                max_tokens)
-        flat = (plan.start * per_tile, jnp.repeat(plan.row, per_tile),
-                slot.reshape(-1))
-    with step_part("embed"):
-        h = nn.embedding(params["tok_embed"],
-                         tokens[row, slot]).astype(dtype)
+        tile_pos0 = pos0[tt.plan.row] + tt.plan.tile * per_tile
+        tile_qlen = tt.valid.sum(-1).astype(jnp.int32)
+        tile_table = tables[1][tt.plan.row]
+    h = tt.embed(params, tokens, dtype)
 
     def attend(layer, ap, x, pools):
         kind = int(cfg.windowed[layer])
         at = cfg.pool_layer[layer]
         with step_part("attn/qkv"):
-            q, k, v, gate = _attn_inputs(ap, x, logical, layer, cfg, dtype)
-        with step_part("attn/write"):
-            pool = _write_pool(pools[kind], at, blk[kind], off, k, v)
-        with step_part("attn/read"):
-            if kind:
-                o = attn_fn(q, *pool, at, tile_tables[kind], tile_pos0,
-                            tile_qlen, window=cfg.window)
-            else:
-                o = pa.ragged_read_by_class(
-                    attn_fn, q.reshape((-1,) + q.shape[2:]), pool, at,
-                    tables[0], pos0, classes, *flat).reshape(q.shape)
+            q, k, v, gate = _attn_inputs(ap, x, tt.logical, layer, cfg,
+                                         dtype)
+        if kind:
+            with step_part("attn/write"):
+                pool = _write_pool(pools[kind], at, blk, off, k, v)
+            with step_part("attn/read"):
+                o = attn_fn(q, *pool, at, tile_table, tile_pos0, tile_qlen,
+                            window=cfg.window)
+        else:
+            o, pool = full.attend(attn_fn, q, k, v, pools[kind], at)
         return o, gate, pools[:kind] + (pool,) + pools[kind + 1:]
 
     h, pools, rows = _run_layers(
-        params, h, tuple(tuple(c) for c in caches), cfg, attend, valid,
+        params, h, tuple(tuple(c) for c in caches), cfg, attend, tt.valid,
         dtype, held, max_tokens)
-
-    def at(slots):
-        """The rows' new tokens at `slots` ((B,) or (B, W)), found in the
-        tile list."""
-        start = plan.start.reshape((b,) + (1,) * (slots.ndim - 1))
-        tile = jnp.minimum(start + slots // per_tile, plan.row.shape[0] - 1)
-        return h[tile, slots % per_tile]
-
-    with step_part("head"):
-        if sample_slot is not None:
-            h = at(jnp.minimum(sample_slot, w - 1))              # (B, d)
-        else:
-            every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
-            h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
-        return (_head(params, h, cfg, dtype),
-                tuple(KVCache(*p) for p in pools), rows)
+    return (lm_head(params, tt.head_rows(h, sample_slot), cfg.ln_eps, dtype),
+            tuple(KVCache(*p) for p in pools), rows)
 
 
 # -- registry ----------------------------------------------------------------------
 
-def _spec(name: str, cfg: LagunaConfig, seq_len: int) -> ModelSpec:
-    def init(rng):
-        return laguna_init(rng, cfg)
-
-    def apply(params, x, dtype=jnp.bfloat16):
-        # The one-shot wire contract of models.gpt2: (B, seq) float token
-        # ids -> (B, vocab) logits of the last non-pad position.
-        tokens = jnp.clip(x.astype(jnp.int32), 0, cfg.vocab - 1)
-        last = jnp.max(jnp.where(tokens > 0, jnp.arange(seq_len)[None, :],
-                                 0), axis=1)
-        logits = laguna_apply(params, tokens, cfg, dtype=dtype)
-        return jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
-
-    return ModelSpec(name=name, apply=apply, init=init,
-                     input_shape=(seq_len,), output_shape=(cfg.vocab,),
-                     config=cfg, ragged_step=laguna_step_rows_ragged,
-                     held=cfg.held)
+def _lm_spec(name: str, cfg: LagunaConfig, seq_len: int) -> ModelSpec:
+    return causal_lm_spec(name, cfg, seq_len, laguna_init, laguna_apply,
+                          ragged_step=laguna_step_rows_ragged, held=cfg.held)
 
 
 def _cfg(**kw) -> LagunaConfig:
@@ -537,8 +486,8 @@ def make_laguna(seq_len: int = 128, vocab: int = 100352,
                 param_dtype: str = "bfloat16") -> ModelSpec:
     """Laguna-S-2.1's published geometry; every width a keyword.
     `held_count` 0 holds every expert."""
-    return _spec("laguna", _cfg(**{k: v for k, v in locals().items()
-                                   if k != "seq_len"}), seq_len)
+    return _lm_spec("laguna", _cfg(**{k: v for k, v in locals().items()
+                                      if k != "seq_len"}), seq_len)
 
 
 @register("laguna-small-test")
@@ -566,6 +515,6 @@ def make_laguna_small(seq_len: int = 16, vocab: int = 256,
     """Tiny config for tests: a dense full layer, three window layers and
     a full one (G = 6 and 9 over 2 KV heads), window 8, 8 of 16 experts
     held, float32."""
-    return _spec("laguna-small-test",
-                 _cfg(**{k: v for k, v in locals().items()
-                         if k != "seq_len"}), seq_len)
+    return _lm_spec("laguna-small-test",
+                    _cfg(**{k: v for k, v in locals().items()
+                            if k != "seq_len"}), seq_len)

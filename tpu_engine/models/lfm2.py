@@ -81,18 +81,17 @@ import jax.numpy as jnp
 from tpu_engine.models.laguna import _bank, _rope
 from tpu_engine.models.moonlight import (
     _dense_init,
-    _head,
     _normal,
     _residual_gain,
     _swiglu_init,
 )
 from tpu_engine.models.nemotron_h import _attn_output
-from tpu_engine.models.registry import ModelSpec, register
+from tpu_engine.models.registry import ModelSpec, causal_lm_spec, register
+from tpu_engine.models.tick_tokens import lm_head, tick_tokens
 from tpu_engine.models.sdar import _SCORE_SPREAD, _inv_freq, _norm_scale
 from tpu_engine.models.transformer import (
     TransformerConfig,
     _mlp,
-    _write_pool,
     index_in_kind,
     kv_kind_config,
 )
@@ -341,7 +340,7 @@ def lfm2_apply(params, tokens, cfg: Lfm2Config, *, dtype=jnp.bfloat16):
     h, _, _ = _run_layers(params, h.reshape(b * s, -1), (), cfg,
                           by_row(conv), by_row(attend),
                           jnp.ones((b * s,), bool), dtype, cfg.held, None)
-    return _head(params, h.reshape(b, s, -1), cfg, dtype)
+    return lm_head(params, h.reshape(b, s, -1), cfg.ln_eps, dtype)
 
 
 # -- the served step: the mixed tick over the block pool and the state pool -------
@@ -386,104 +385,56 @@ def lfm2_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
                           cfg: Lfm2Config, *, dtype=jnp.bfloat16,
                           attn_fn=None, sample_slot=None, held=None,
                           max_tokens: Optional[int] = None):
-    """`models.nemotron_h.nemotron_h_step_rows_ragged` for this family: one
-    ragged batch where row b consumes qlen[b] >= 0 new tokens at logical
-    columns [pos0[b], pos0[b] + qlen[b]), run over the tick's TOKENS, a
-    slot a tile of the list.
+    """This family's step of the mixed tick, over the tick's token list
+    (`models.tick_tokens`, a token an entry).
 
     caches: (the block pool's K/V pair, (attention layers, NB, bs,
     H_kv*D); the state pool's arrays, a tuple of ONE: the conv tails,
     `_conv_rows`), both updated in place (donate them); tables: (the rows'
     block table (B, nb); the rows' state row (B,), the null row 0 for a
-    free slot). An attention layer scatters each token's K and V into its
-    row's blocks BEFORE the read and reads each row by the class of its
-    run (`ops.paged_attention.ragged_read_by_class` at G = n_heads /
-    n_kv_heads); a conv layer is `_conv_rows`, one body whatever the rows'
+    free slot). An attention layer is `PagedKV.attend` at G = n_heads /
+    n_kv_heads; a conv layer is `_conv_rows`, one body whatever the rows'
     runs.
 
     ``held`` = (first, count): the experts `params` holds (default
     `cfg.held`). Returns (logits, caches, rows (L_moe, n_routed) int32:
     the rows each held expert took)."""
-    from tpu_engine.ops import latent_attention as la
     from tpu_engine.ops import paged_attention as pa
 
     if attn_fn is None:
         attn_fn = pa.default_ragged_attention()
     held = held or cfg.held
     (pool, (tails,)), (table, rows) = caches, tables
-    b, w = tokens.shape
-    m = la.tiles_bound(b, w, 1, max_tokens)
-    bs = pool.k.shape[2]
-    with step_part("plan"):
-        plan = la.tile_plan(qlen, 1, m)
-        _, valid = la.tile_slots(plan, qlen, 1)
-        row, slot, valid = (plan.row, jnp.minimum(plan.tile, w - 1),
-                            valid[:, 0])
-        logical = pos0[row] + slot
-        cols = jnp.minimum(logical, table.shape[1] * bs - 1)
-        # invalid -> null block
-        blk = jnp.where(valid, table[row, cols // bs], 0)
-        classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
-                                max_tokens)
-    with step_part("embed"):
-        h = nn.embedding(params["tok_embed"],
-                         tokens[row, slot]).astype(dtype)
+    tt = tick_tokens(pos0, qlen, tokens.shape[1], max_tokens)
+    kv = tt.paged_kv(table, pool.k.shape[2], cfg.n_heads // cfg.kv_heads)
+    h = tt.embed(params, tokens, dtype)
 
     def conv(at, cp, r, carry):
         pool, tails = carry
-        y, tails = _conv_rows(cp, r, tails, at, plan.start, rows, row, slot,
-                              pos0, qlen, cfg, dtype)
+        y, tails = _conv_rows(cp, r, tails, at, tt.plan.start, rows, tt.row,
+                              tt.slot, pos0, qlen, cfg, dtype)
         return y, (pool, tails)
 
     def attend(at, ap, r, carry):
         pool, tails = carry
         with step_part("attn/qkv"):
-            q, k, v = _attn_inputs(ap, r, logical, cfg, dtype)
-        with step_part("attn/write"):
-            pool = _write_pool(pool, at, blk, cols % bs, k, v)
-        with step_part("attn/read"):
-            o = pa.ragged_read_by_class(attn_fn, q, pool, at, table, pos0,
-                                        classes, plan.start, row, slot)
+            q, k, v = _attn_inputs(ap, r, tt.logical, cfg, dtype)
+        o, pool = kv.attend(attn_fn, q, k, v, pool, at)
         with step_part("attn/out"):
             return _attn_output(ap, o, dtype), (pool, tails)
 
     h, (pool, tails), taken = _run_layers(
-        params, h, (tuple(pool), tails), cfg, conv, attend, valid, dtype,
+        params, h, (tuple(pool), tails), cfg, conv, attend, tt.valid, dtype,
         held, max_tokens)
-    with step_part("head"):
-        if sample_slot is not None:
-            h = h[jnp.minimum(plan.start + jnp.minimum(sample_slot, w - 1),
-                              m - 1)]                            # (B, d)
-        else:
-            # Row b's new tokens in the list.
-            listed = jnp.minimum(
-                plan.start[:, None] + jnp.arange(w)[None, :], m - 1)
-            h = jnp.where(
-                (jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
-                h[listed], 0)
-        return (_head(params, h, cfg, dtype), (KVCache(*pool), (tails,)),
-                taken)
+    return (lm_head(params, tt.head_rows(h, sample_slot), cfg.ln_eps, dtype),
+            (KVCache(*pool), (tails,)), taken)
 
 
 # -- registry ----------------------------------------------------------------------
 
-def _spec(name: str, cfg: Lfm2Config, seq_len: int) -> ModelSpec:
-    def init(rng):
-        return lfm2_init(rng, cfg)
-
-    def apply(params, x, dtype=jnp.bfloat16):
-        # The one-shot wire contract of models.gpt2: (B, seq) float token
-        # ids -> (B, vocab) logits of the last non-pad position.
-        tokens = jnp.clip(x.astype(jnp.int32), 0, cfg.vocab - 1)
-        last = jnp.max(jnp.where(tokens > 0, jnp.arange(seq_len)[None, :],
-                                 0), axis=1)
-        logits = lfm2_apply(params, tokens, cfg, dtype=dtype)
-        return jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
-
-    return ModelSpec(name=name, apply=apply, init=init,
-                     input_shape=(seq_len,), output_shape=(cfg.vocab,),
-                     config=cfg, ragged_step=lfm2_step_rows_ragged,
-                     held=cfg.held)
+def _lm_spec(name: str, cfg: Lfm2Config, seq_len: int) -> ModelSpec:
+    return causal_lm_spec(name, cfg, seq_len, lfm2_init, lfm2_apply,
+                          ragged_step=lfm2_step_rows_ragged, held=cfg.held)
 
 
 def _cfg(**kw) -> Lfm2Config:
@@ -520,8 +471,8 @@ def make_lfm2(seq_len: int = 128, vocab: int = 65536, n_layers: int = 40,
               param_dtype: str = "bfloat16") -> ModelSpec:
     """LFM2-24B-A2B's published geometry; every width a keyword.
     `held_count` 0 holds every expert."""
-    return _spec("lfm2", _cfg(**{k: v for k, v in locals().items()
-                                 if k != "seq_len"}), seq_len)
+    return _lm_spec("lfm2", _cfg(**{k: v for k, v in locals().items()
+                                    if k != "seq_len"}), seq_len)
 
 
 @register("lfm2-small-test")
@@ -539,6 +490,6 @@ def make_lfm2_small(seq_len: int = 16, vocab: int = 256, n_layers: int = 5,
     """Tiny config for tests: a conv layer with the dense feed-forward,
     then attention, conv, conv, attention with 16 experts top 4, 4 query
     heads over 2 KV heads of 12 lanes, float32."""
-    return _spec("lfm2-small-test",
-                 _cfg(**{k: v for k, v in locals().items()
-                         if k != "seq_len"}), seq_len)
+    return _lm_spec("lfm2-small-test",
+                    _cfg(**{k: v for k, v in locals().items()
+                            if k != "seq_len"}), seq_len)

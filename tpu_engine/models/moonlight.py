@@ -48,7 +48,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from tpu_engine.models.registry import ModelSpec, register
+from tpu_engine.models.registry import ModelSpec, causal_lm_spec, register
+from tpu_engine.models.tick_tokens import lm_head, tick_tokens
 from tpu_engine.models.transformer import (
     TransformerConfig,
     _mlp,
@@ -341,12 +342,6 @@ def _run_layers(params, h, carry, cfg: MoonlightConfig, layer_fn, valid,
     return h, carry, rows
 
 
-def _head(params, h, cfg: MoonlightConfig, dtype):
-    h = nn.rmsnorm(params["ln_f"], h, eps=cfg.ln_eps)
-    return nn.dense(params["head"], h, dtype=dtype).astype(jnp.float32)
-
-
-
 # -- the one-shot forward (expanded attention) -----------------------------------
 
 def moonlight_apply(params, tokens, cfg: MoonlightConfig, *,
@@ -379,7 +374,7 @@ def moonlight_apply(params, tokens, cfg: MoonlightConfig, *,
 
     h, _, _ = _run_layers(params, h, (), cfg, layer_fn,
                           jnp.ones((b, s), bool), dtype, None, None)
-    return _head(params, h, cfg, dtype)
+    return lm_head(params, h, cfg.ln_eps, dtype)
 
 
 # -- the served step: the mixed tick over the latent pool -------------------------
@@ -389,23 +384,18 @@ def moonlight_step_rows_ragged(params, tokens, caches: KVCache, tables, pos0,
                                dtype=jnp.bfloat16, attn_fn=None,
                                sample_slot=None, held=None,
                                max_tokens: Optional[int] = None):
-    """`models.transformer.transformer_step_rows_ragged` for this family:
-    one ragged batch where row b consumes qlen[b] >= 0 new tokens at
-    logical columns [pos0[b], pos0[b] + qlen[b]). caches: the latent pool
-    pair, k (L, NB, bs, PE_LANES) rope keys and v (L, NB, bs, C) latents,
-    updated in place through both layer loops (donate it and a tick copies
-    no layer of it): every token's (c, k_pe) is scattered into the row's
-    blocks BEFORE the absorbed read (write-before-attend).
+    """This family's step of the mixed tick
+    (`models.transformer.transformer_step_rows_ragged`'s arguments), over
+    the tick's token list (`models.tick_tokens`) in tiles of
+    `ops.latent_attention.slots_per_tile` slots: embedding, projections,
+    cache writes, the read, the FFNs and the router all run over
+    (n_tiles, S), 68 x 8 tokens where the slots are 32 x 256.
 
-    **The step runs over the tick's TOKENS, not its slots.** A tick of 32
-    rows x 256 slots holds at most `max_tokens` valid ones (a static
-    bound: the scheduler's token budget plus a token a row). The rows' new
-    tokens are cut into tiles of a few slots (`ops.latent_attention`
-    `tile_plan`), and embedding, projections, cache writes, the read, the
-    FFNs and the router all run over (n_tiles, S): 68 x 8 tokens where the
-    slots are 8192. A tile's unused slots and the list's dead tiles write
-    into the null block, reach no expert and count in no load.
-    ``attn_fn`` defaults to
+    caches: the latent pool pair, k (L, NB, bs, PE_LANES) rope keys and
+    v (L, NB, bs, C) latents, updated in place through both layer loops
+    (donate it and a tick copies no layer of it): every token's (c, k_pe)
+    is scattered into the row's blocks BEFORE the absorbed read
+    (write-before-attend). ``attn_fn`` defaults to
     `ops.latent_attention.default_latent_attention()`.
 
     ``held`` = (first, count): the experts this shard holds (default
@@ -419,28 +409,17 @@ def moonlight_step_rows_ragged(params, tokens, caches: KVCache, tables, pos0,
 
     if attn_fn is None:
         attn_fn = la.default_latent_attention()
-    b, w = tokens.shape
-    bs = caches.k.shape[2]
-    per_tile = la.slots_per_tile(cfg.n_heads, w)
+    w = tokens.shape[1]
+    tt = tick_tokens(pos0, qlen, w, max_tokens,
+                     per_tile=la.slots_per_tile(cfg.n_heads, w))
+    blk, off = tt.blocks(tables, caches.k.shape[2])
     with step_part("plan"):
-        plan = la.tile_plan(qlen, per_tile,
-                            la.tiles_bound(b, w, per_tile, max_tokens))
-        slot, valid = la.tile_slots(plan, qlen, per_tile)        # (N, S)
-        row = plan.row[:, None]
-        slot = jnp.minimum(slot, w - 1)
-        logical = pos0[row] + slot
-        cols = jnp.minimum(logical, tables.shape[1] * bs - 1)
-        # invalid -> null block
-        blk = jnp.where(valid, tables[row, cols // bs], 0)
-        off = cols % bs
         lengths = pos0 + qlen
-    with step_part("embed"):
-        h = nn.embedding(params["tok_embed"],
-                         tokens[row, slot]).astype(dtype)
+    h = tt.embed(params, tokens, dtype)
 
     def layer_fn(bp, x, cache_kv, layer):
         with step_part("attn/qkv"):
-            q_nope, q_pe, c, k_pe = _attn_inputs(bp["attn"], x, logical,
+            q_nope, q_pe, c, k_pe = _attn_inputs(bp["attn"], x, tt.logical,
                                                  cfg, dtype)
         with step_part("attn/write"):
             cache_kv = _write_pool(cache_kv, layer, blk, off,
@@ -449,50 +428,22 @@ def moonlight_step_rows_ragged(params, tokens, caches: KVCache, tables, pos0,
         with step_part("attn/qkv"):
             q_lat = _absorb(bp["attn"], q_nope, cfg, dtype)
         with step_part("attn/read"):
-            o_lat = attn_fn(q_lat, q_pe, *cache_kv, layer, tables, plan,
+            o_lat = attn_fn(q_lat, q_pe, *cache_kv, layer, tables, tt.plan,
                             pos0, lengths, scale=cfg.attn_scale)
         with step_part("attn/out"):
             return _unabsorb(bp["attn"], o_lat, cfg, dtype), cache_kv
 
     h, cache_kv, rows = _run_layers(params, h, tuple(caches), cfg, layer_fn,
-                                    valid, dtype, held, max_tokens)
-
-    def at(slots):
-        """The rows' new tokens at `slots` ((B,) or (B, W)), found in the
-        tile list."""
-        start = plan.start.reshape((b,) + (1,) * (slots.ndim - 1))
-        tile = jnp.minimum(start + slots // per_tile, plan.row.shape[0] - 1)
-        return h[tile, slots % per_tile]
-
-    with step_part("head"):
-        if sample_slot is not None:
-            h = at(jnp.minimum(sample_slot, w - 1))              # (B, d)
-        else:
-            # Every slot's logits, as the transformer step returns them; a
-            # padding slot has no token in the list and reads zero.
-            every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
-            h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
-        return _head(params, h, cfg, dtype), KVCache(*cache_kv), rows
+                                    tt.valid, dtype, held, max_tokens)
+    return (lm_head(params, tt.head_rows(h, sample_slot), cfg.ln_eps, dtype),
+            KVCache(*cache_kv), rows)
 
 
 # -- registry ----------------------------------------------------------------------
 
-def _spec(name: str, cfg: MoonlightConfig, seq_len: int) -> ModelSpec:
-    def init(rng):
-        return moonlight_init(rng, cfg)
-
-    def apply(params, x, dtype=jnp.bfloat16):
-        # The one-shot wire contract of models.gpt2: (B, seq) float token
-        # ids -> (B, vocab) logits of the last non-pad position.
-        tokens = jnp.clip(x.astype(jnp.int32), 0, cfg.vocab - 1)
-        last = jnp.max(jnp.where(tokens > 0, jnp.arange(seq_len)[None, :],
-                                 0), axis=1)
-        logits = moonlight_apply(params, tokens, cfg, dtype=dtype)
-        return jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
-
-    return ModelSpec(name=name, apply=apply, init=init,
-                     input_shape=(seq_len,), output_shape=(cfg.vocab,),
-                     config=cfg, ragged_step=moonlight_step_rows_ragged)
+def _lm_spec(name: str, cfg: MoonlightConfig, seq_len: int) -> ModelSpec:
+    return causal_lm_spec(name, cfg, seq_len, moonlight_init, moonlight_apply,
+                          ragged_step=moonlight_step_rows_ragged)
 
 
 def _cfg(**kw) -> MoonlightConfig:
@@ -521,8 +472,8 @@ def make_moonlight(seq_len: int = 128, vocab: int = 163840,
                    ln_eps: float = 1e-5, kv_norm_eps: float = 1e-6,
                    param_dtype: str = "bfloat16") -> ModelSpec:
     """Moonlight-16B-A3B's published geometry; every width a keyword."""
-    return _spec("moonlight", _cfg(**{k: v for k, v in locals().items()
-                                      if k != "seq_len"}), seq_len)
+    return _lm_spec("moonlight", _cfg(**{k: v for k, v in locals().items()
+                                         if k != "seq_len"}), seq_len)
 
 
 @register("moonlight-small-test")
@@ -539,6 +490,6 @@ def make_moonlight_small(seq_len: int = 16, vocab: int = 256,
                          kv_norm_eps: float = 1e-6,
                          param_dtype: str = "float32") -> ModelSpec:
     """Tiny config for tests: one dense and two expert layers, float32."""
-    return _spec("moonlight-small-test",
-                 _cfg(**{k: v for k, v in locals().items()
-                         if k != "seq_len"}), seq_len)
+    return _lm_spec("moonlight-small-test",
+                    _cfg(**{k: v for k, v in locals().items()
+                            if k != "seq_len"}), seq_len)

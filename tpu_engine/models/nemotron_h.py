@@ -93,12 +93,12 @@ from tpu_engine.models.falcon_h1 import (
     _with_skip,
 )
 from tpu_engine.models.laguna import _bank
-from tpu_engine.models.moonlight import _dense_init, _head, _normal
+from tpu_engine.models.moonlight import _dense_init, _normal
 from tpu_engine.models.olmo_hybrid import _linear_rows
-from tpu_engine.models.registry import ModelSpec, register
+from tpu_engine.models.registry import ModelSpec, causal_lm_spec, register
+from tpu_engine.models.tick_tokens import lm_head, tick_tokens
 from tpu_engine.models.transformer import (
     TransformerConfig,
-    _write_pool,
     index_in_kind,
     kv_kind_config,
 )
@@ -382,7 +382,7 @@ def nemotron_h_apply(params, tokens, cfg: NemotronHConfig, *,
     h, _, _ = _run_layers(params, h.reshape(b * s, -1), (), cfg,
                           by_row(mamba), by_row(attend),
                           jnp.ones((b * s,), bool), dtype, cfg.held, None)
-    return _head(params, h.reshape(b, s, -1), cfg, dtype)
+    return lm_head(params, h.reshape(b, s, -1), cfg.ln_eps, dtype)
 
 
 # -- the served step: the mixed tick over the block pool and the state pool -------
@@ -393,19 +393,15 @@ def nemotron_h_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
                                 chunk_fn=ssd_chunk_row, sample_slot=None,
                                 held=None,
                                 max_tokens: Optional[int] = None):
-    """`models.olmo_hybrid.olmo_hybrid_step_rows_ragged` for this family:
-    one ragged batch where row b consumes qlen[b] >= 0 new tokens at
-    logical columns [pos0[b], pos0[b] + qlen[b]), run over the tick's
-    TOKENS, a slot a tile of the list.
+    """This family's step of the mixed tick, over the tick's token list
+    (`models.tick_tokens`, a token an entry).
 
     caches: (the block pool's K/V pair, (* layers, NB, bs, H_kv*D); the
     state pool's arrays, `_linear_rows`, M layers deep), both updated in
     place (donate them); tables: (the rows' block table (B, nb); the rows'
-    state row (B,), the null row 0 for a free slot). A * layer scatters
-    each token's K and V into its row's blocks BEFORE the read and reads
-    each row by the class of its run
-    (`ops.paged_attention.ragged_read_by_class` at G = n_heads /
-    n_kv_heads); an M layer runs the recurrence over the same rows
+    state row (B,), the null row 0 for a free slot). A * layer is
+    `PagedKV.attend` at G = n_heads / n_kv_heads; an M layer runs the
+    recurrence over the same rows
     (`ssd_step`, `ssd_chunk`); an E layer touches neither pool. `step_fn`,
     `chunk_fn`: `ops.ssd`'s `ssd_step_rows` and `ssd_chunk_row` or
     stand-ins of their signatures.
@@ -413,36 +409,23 @@ def nemotron_h_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     ``held`` = (first, count): the experts `params` holds (default
     `cfg.held`). Returns (logits, caches, rows (L_moe, n_routed) int32:
     the rows each held expert took)."""
-    from tpu_engine.ops import latent_attention as la
     from tpu_engine.ops import paged_attention as pa
 
     if attn_fn is None:
         attn_fn = pa.default_ragged_attention()
     held = held or cfg.held
     (pool, state), (table, rows) = caches, tables
-    b, w = tokens.shape
-    m = la.tiles_bound(b, w, 1, max_tokens)
-    bs = pool.k.shape[2]
-    with step_part("plan"):
-        plan = la.tile_plan(qlen, 1, m)
-        _, valid = la.tile_slots(plan, qlen, 1)
-        row, slot, valid = (plan.row, jnp.minimum(plan.tile, w - 1),
-                            valid[:, 0])
-        cols = jnp.minimum(pos0[row] + slot, table.shape[1] * bs - 1)
-        # invalid -> null block
-        blk = jnp.where(valid, table[row, cols // bs], 0)
-        classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
-                                max_tokens)
-    with step_part("embed"):
-        h = nn.embedding(params["tok_embed"],
-                         tokens[row, slot]).astype(dtype)
+    w = tokens.shape[1]
+    tt = tick_tokens(pos0, qlen, w, max_tokens)
+    kv = tt.paged_kv(table, pool.k.shape[2], cfg.n_heads // cfg.kv_heads)
+    h = tt.embed(params, tokens, dtype)
 
     def mamba(at, sp, u, carry):
         pool, state = carry
         with step_part("mixer/in"):
             step, chunk = _with_skip(sp, step_fn), _with_skip(sp, chunk_fn)
         y, state = _linear_rows(
-            sp, u, state, at, plan.start, rows, pos0, qlen, w, cfg, dtype,
+            sp, u, state, at, tt.plan.start, rows, pos0, qlen, w, cfg, dtype,
             step, chunk, inputs=_ssm_inputs, output=_ssm_output,
             conv=_ssm_conv)
         return y, (pool, state)
@@ -451,50 +434,24 @@ def nemotron_h_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
         pool, state = carry
         with step_part("attn/qkv"):
             q, k, v = _attn_inputs(ap, u, cfg, dtype)
-        with step_part("attn/write"):
-            pool = _write_pool(pool, at, blk, cols % bs, k, v)
-        with step_part("attn/read"):
-            o = pa.ragged_read_by_class(attn_fn, q, pool, at, table, pos0,
-                                        classes, plan.start, row, slot)
+        o, pool = kv.attend(attn_fn, q, k, v, pool, at)
         with step_part("attn/out"):
             return _attn_output(ap, o, dtype), (pool, state)
 
     h, (pool, state), taken = _run_layers(
-        params, h, (tuple(pool), tuple(state)), cfg, mamba, attend, valid,
+        params, h, (tuple(pool), tuple(state)), cfg, mamba, attend, tt.valid,
         dtype, held, max_tokens)
-    with step_part("head"):
-        if sample_slot is not None:
-            h = h[jnp.minimum(plan.start + jnp.minimum(sample_slot, w - 1),
-                              m - 1)]                            # (B, d)
-        else:
-            # Row b's new tokens in the list.
-            listed = jnp.minimum(
-                plan.start[:, None] + jnp.arange(w)[None, :], m - 1)
-            h = jnp.where(
-                (jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
-                h[listed], 0)
-        return _head(params, h, cfg, dtype), (KVCache(*pool), state), taken
+    return (lm_head(params, tt.head_rows(h, sample_slot), cfg.ln_eps, dtype),
+            (KVCache(*pool), state), taken)
 
 
 # -- registry ----------------------------------------------------------------------
 
-def _spec(name: str, cfg: NemotronHConfig, seq_len: int) -> ModelSpec:
-    def init(rng):
-        return nemotron_h_init(rng, cfg)
-
-    def apply(params, x, dtype=jnp.bfloat16):
-        # The one-shot wire contract of models.gpt2: (B, seq) float token
-        # ids -> (B, vocab) logits of the last non-pad position.
-        tokens = jnp.clip(x.astype(jnp.int32), 0, cfg.vocab - 1)
-        last = jnp.max(jnp.where(tokens > 0, jnp.arange(seq_len)[None, :],
-                                 0), axis=1)
-        logits = nemotron_h_apply(params, tokens, cfg, dtype=dtype)
-        return jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
-
-    return ModelSpec(name=name, apply=apply, init=init,
-                     input_shape=(seq_len,), output_shape=(cfg.vocab,),
-                     config=cfg, ragged_step=nemotron_h_step_rows_ragged,
-                     held=cfg.held)
+def _lm_spec(name: str, cfg: NemotronHConfig, seq_len: int) -> ModelSpec:
+    return causal_lm_spec(name, cfg, seq_len, nemotron_h_init,
+                          nemotron_h_apply,
+                          ragged_step=nemotron_h_step_rows_ragged,
+                          held=cfg.held)
 
 
 def _cfg(**kw) -> NemotronHConfig:
@@ -536,8 +493,8 @@ def make_nemotron_h(seq_len: int = 128, vocab: int = 131072,
                     param_dtype: str = "bfloat16") -> ModelSpec:
     """Nemotron-3-Super-120B-A12B's published geometry; every width a
     keyword. `held_count` 0 holds every expert."""
-    return _spec("nemotron_h", _cfg(**{k: v for k, v in locals().items()
-                                       if k != "seq_len"}), seq_len)
+    return _lm_spec("nemotron_h", _cfg(**{k: v for k, v in locals().items()
+                                          if k != "seq_len"}), seq_len)
 
 
 @register("nemotron_h_small")
@@ -559,6 +516,6 @@ def make_nemotron_h_small(seq_len: int = 16, vocab: int = 256,
     query heads over 1 KV head of 8 lanes, 8 SSM heads of 4 lanes in 2
     groups, a state of 16 lanes, conv 4, a latent of 16 lanes, 4 of 16
     experts held (one of four chips' share), top 6, float32."""
-    return _spec("nemotron_h_small",
-                 _cfg(**{k: v for k, v in locals().items()
-                         if k != "seq_len"}), seq_len)
+    return _lm_spec("nemotron_h_small",
+                    _cfg(**{k: v for k, v in locals().items()
+                            if k != "seq_len"}), seq_len)

@@ -57,11 +57,11 @@ import jax
 import jax.numpy as jnp
 
 from tpu_engine.models.moonlight import _dense_init, _normal, _swiglu_init
-from tpu_engine.models.registry import ModelSpec, register
+from tpu_engine.models.registry import ModelSpec, causal_lm_spec, register
+from tpu_engine.models.tick_tokens import lm_head, tick_tokens
 from tpu_engine.models.transformer import (
     TransformerConfig,
     _mlp,
-    _write_pool,
     index_in_kind,
     kv_kind_config,
 )
@@ -253,11 +253,6 @@ def _run_layers(params, h, carry, cfg: OlmoHybridConfig, mixer, dtype):
     return h, carry
 
 
-def _head(params, h, cfg: OlmoHybridConfig, dtype):
-    h = nn.rmsnorm(params["ln_f"], h, eps=cfg.ln_eps)
-    return nn.dense(params["head"], h, dtype=dtype).astype(jnp.float32)
-
-
 def _pad_run(n: int) -> int:
     return -(-n // SUB_CHUNK) * SUB_CHUNK
 
@@ -295,7 +290,7 @@ def olmo_hybrid_apply(params, tokens, cfg: OlmoHybridConfig, *,
         return _lin_output(lp, o, z, cfg, dtype), carry
 
     h, _ = _run_layers(params, h, (), cfg, mixer, dtype)
-    return _head(params, h, cfg, dtype)
+    return lm_head(params, h, cfg.ln_eps, dtype)
 
 
 # -- the served step: the mixed tick over the block pool and the state pool -------
@@ -399,106 +394,62 @@ def olmo_hybrid_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
                                  chunk_fn=gdn_chunk_row, sample_slot=None,
                                  held=None,
                                  max_tokens: Optional[int] = None):
-    """`models.laguna.laguna_step_rows_ragged` for this family: one ragged
-    batch where row b consumes qlen[b] >= 0 new tokens at logical columns
-    [pos0[b], pos0[b] + qlen[b]), run over the tick's TOKENS
-    (`ops.latent_attention.tile_plan` with a slot a tile: the list holds
-    each row's new tokens side by side and nothing else).
+    """This family's step of the mixed tick, over the tick's token list
+    (`models.tick_tokens`, a token an entry: the list holds each row's new
+    tokens side by side and nothing else).
 
     caches: (the block pool's K/V pair, (full layers, NB, bs, H*D); the
     state pool's arrays, `_linear_rows`), both updated in place (donate
     them); tables: (the rows' block table (B, nb); the rows' state row
-    (B,), the null row 0 for a free slot). A full layer scatters every
-    token's K and V into its row's blocks BEFORE the read
-    (write-before-attend) and reads each row by the class of its run
-    (`ops.latent_attention.class_plan`,
-    `ops.paged_attention.ragged_read_by_class`): a row with one new token
-    as a row of a width-1 call, the 30 heads packed, and a longer run in
-    tall tiles of up to 128 slots, each a row of a second call; a step a
-    slot wide makes the first call alone.
+    (B,), the null row 0 for a free slot). A full layer is
+    `PagedKV.attend`: a row with one new token a row of a width-1 call,
+    the 30 heads packed, a longer run in tall tiles of up to 128 slots,
+    each a row of a second call; a step a slot wide makes the first call
+    alone. A linear layer is `_linear_rows` over the same list.
     `step_fn`, `chunk_fn`: `ops.gated_delta`'s `gdn_step_rows` and
     `gdn_chunk_row` or stand-ins of their signatures (a test compiling the
     kernels for a chip that is not there).
 
     Returns (logits, caches, rows (0, 1): the family routes no experts)."""
-    from tpu_engine.ops import latent_attention as la
     from tpu_engine.ops import paged_attention as pa
 
     del held
     if attn_fn is None:
         attn_fn = pa.default_ragged_attention()
     (pool, state), (table, rows) = caches, tables
-    b, w = tokens.shape
-    m = la.tiles_bound(b, w, 1, max_tokens)
-    bs = pool.k.shape[2]
-    with step_part("plan"):
-        plan = la.tile_plan(qlen, 1, m)
-        _, valid = la.tile_slots(plan, qlen, 1)
-        row, slot, valid = (plan.row, jnp.minimum(plan.tile, w - 1),
-                            valid[:, 0])
-        cols = jnp.minimum(pos0[row] + slot, table.shape[1] * bs - 1)
-        # invalid -> null block
-        blk = jnp.where(valid, table[row, cols // bs], 0)
-        classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
-                                max_tokens)
-    with step_part("embed"):
-        h = nn.embedding(params["tok_embed"],
-                         tokens[row, slot]).astype(dtype)
+    w = tokens.shape[1]
+    tt = tick_tokens(pos0, qlen, w, max_tokens)
+    kv = tt.paged_kv(table, pool.k.shape[2], cfg.n_heads // cfg.kv_heads)
+    h = tt.embed(params, tokens, dtype)
 
     def mixer(layer, bp, x, carry):
         pool, state = carry
         at = cfg.pool_layer[layer]
         if cfg.linear[layer]:
-            y, state = _linear_rows(bp["lin"], x, state, at, plan.start,
+            y, state = _linear_rows(bp["lin"], x, state, at, tt.plan.start,
                                     rows, pos0, qlen, w, cfg, dtype, step_fn,
                                     chunk_fn)
             return y, (pool, state)
         with step_part("attn/qkv"):
             q, k, v = _attn_inputs(bp["attn"], x, cfg, dtype)
-        with step_part("attn/write"):
-            pool = _write_pool(pool, at, blk, cols % bs, k, v)
+        o, pool = kv.attend(attn_fn, q, k, v, pool, at)
         with step_part("attn/read"):
-            o = pa.ragged_read_by_class(attn_fn, q, pool, at, table, pos0,
-                                        classes, plan.start, row, slot)
-            o = o.astype(dtype).reshape(m, -1)
+            o = o.astype(dtype).reshape(tt.n, -1)
         with step_part("attn/out"):
             return nn.dense(bp["attn"]["wo"], o, dtype=dtype), (pool, state)
 
     h, (pool, state) = _run_layers(params, h, (tuple(pool), tuple(state)),
                                    cfg, mixer, dtype)
-    with step_part("head"):
-        if sample_slot is not None:
-            h = h[jnp.minimum(plan.start + jnp.minimum(sample_slot, w - 1),
-                              m - 1)]                            # (B, d)
-        else:
-            # Row b's new tokens in the list.
-            listed = jnp.minimum(
-                plan.start[:, None] + jnp.arange(w)[None, :], m - 1)
-            h = jnp.where(
-                (jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
-                h[listed], 0)
-        return (_head(params, h, cfg, dtype), (KVCache(*pool), state),
-                jnp.zeros((0, 1), jnp.int32))
+    return (lm_head(params, tt.head_rows(h, sample_slot), cfg.ln_eps, dtype),
+            (KVCache(*pool), state), jnp.zeros((0, 1), jnp.int32))
 
 
 # -- registry ----------------------------------------------------------------------
 
-def _spec(name: str, cfg: OlmoHybridConfig, seq_len: int) -> ModelSpec:
-    def init(rng):
-        return olmo_hybrid_init(rng, cfg)
-
-    def apply(params, x, dtype=jnp.bfloat16):
-        # The one-shot wire contract of models.gpt2: (B, seq) float token
-        # ids -> (B, vocab) logits of the last non-pad position.
-        tokens = jnp.clip(x.astype(jnp.int32), 0, cfg.vocab - 1)
-        last = jnp.max(jnp.where(tokens > 0, jnp.arange(seq_len)[None, :],
-                                 0), axis=1)
-        logits = olmo_hybrid_apply(params, tokens, cfg, dtype=dtype)
-        return jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
-
-    return ModelSpec(name=name, apply=apply, init=init,
-                     input_shape=(seq_len,), output_shape=(cfg.vocab,),
-                     config=cfg, ragged_step=olmo_hybrid_step_rows_ragged)
+def _lm_spec(name: str, cfg: OlmoHybridConfig, seq_len: int) -> ModelSpec:
+    return causal_lm_spec(name, cfg, seq_len, olmo_hybrid_init,
+                          olmo_hybrid_apply,
+                          ragged_step=olmo_hybrid_step_rows_ragged)
 
 
 def _cfg(**kw) -> OlmoHybridConfig:
@@ -529,8 +480,8 @@ def make_olmo_hybrid(seq_len: int = 128, vocab: int = 100352,
                      ln_eps: float = 1e-6,
                      param_dtype: str = "bfloat16") -> ModelSpec:
     """Olmo-Hybrid-7B's published geometry; every width a keyword."""
-    return _spec("olmo_hybrid", _cfg(**{k: v for k, v in locals().items()
-                                        if k != "seq_len"}), seq_len)
+    return _lm_spec("olmo_hybrid", _cfg(**{k: v for k, v in locals().items()
+                                           if k != "seq_len"}), seq_len)
 
 
 @register("olmo_hybrid_small")
@@ -545,6 +496,6 @@ def make_olmo_hybrid_small(seq_len: int = 16, vocab: int = 256,
                            param_dtype: str = "float32") -> ModelSpec:
     """Tiny config for tests: two periods (L L L F x 2), 3 heads, keys of
     8 and values of 16 lanes, conv 4, MHA at head size 16, float32."""
-    return _spec("olmo_hybrid_small",
-                 _cfg(**{k: v for k, v in locals().items()
-                         if k != "seq_len"}), seq_len)
+    return _lm_spec("olmo_hybrid_small",
+                    _cfg(**{k: v for k, v in locals().items()
+                            if k != "seq_len"}), seq_len)

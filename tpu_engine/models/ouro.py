@@ -94,11 +94,11 @@ import jax
 import jax.numpy as jnp
 
 from tpu_engine.models.moonlight import _dense_init, _swiglu_init
-from tpu_engine.models.registry import ModelSpec, register
+from tpu_engine.models.registry import ModelSpec, causal_lm_spec, register
+from tpu_engine.models.tick_tokens import tick_tokens
 from tpu_engine.models.transformer import (
     TransformerConfig,
     _mlp,
-    _write_pool,
     kv_kind_config,
 )
 from tpu_engine.ops import nn
@@ -244,9 +244,11 @@ def exit_pass(gate, streams, threshold: float):
                      steps - 1).astype(jnp.int32)
 
 
-def _head(params, h, streams, cfg: OuroConfig, dtype):
+def _logits(params, h, streams, cfg: OuroConfig, dtype):
     """h: (..., d) the last pass's normed stream; streams: (T, ..., d) or
-    None (`exit_threshold` >= 1: no gate op is traced)."""
+    None (`exit_threshold` >= 1: no gate op is traced). The final norm is
+    `_passes`'s, after every pass: `models.tick_tokens.lm_head` would
+    norm the last stream twice."""
     if streams is not None:
         at = exit_pass(params["gate"], streams, cfg.exit_threshold)
         h = jnp.take_along_axis(streams, at[None, ..., None], axis=0)[0]
@@ -276,7 +278,7 @@ def ouro_apply(params, tokens, cfg: OuroConfig, *, dtype=jnp.bfloat16):
 
     h, _, streams = _passes(params, h, (), cfg, attend, dtype,
                             kept=(lambda x: x) if _gated(cfg) else None)
-    return _head(params, h, streams, cfg, dtype)
+    return _logits(params, h, streams, cfg, dtype)
 
 
 # -- the served step: the mixed tick over the pool of T x L planes ----------------
@@ -285,95 +287,50 @@ def ouro_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
                           cfg: OuroConfig, *, dtype=jnp.bfloat16,
                           attn_fn=None, sample_slot=None, held=None,
                           max_tokens: Optional[int] = None):
-    """`models.olmo_hybrid.olmo_hybrid_step_rows_ragged` for this family:
-    one ragged batch where row b consumes qlen[b] >= 0 new tokens at
-    logical columns [pos0[b], pos0[b] + qlen[b]), run over the tick's
-    TOKENS (`ops.latent_attention.tile_plan` with a slot a tile: the list
-    holds each row's new tokens side by side and nothing else).
+    """This family's step of the mixed tick, over the tick's token list
+    (`models.tick_tokens`, a token an entry).
 
     caches: the block pool's K/V pair, (T x L planes, NB, bs, H*D),
-    updated in place (donate it); tables: (B, nb). Pass t of layer l
-    scatters every token's K and V into plane t * L + l of its row's
-    blocks BEFORE the read (write-before-attend) and reads each row by
-    the class of its run (`ops.latent_attention.class_plan`,
-    `ops.paged_attention.ragged_read_by_class`): a row with one new token
-    as a row of a width-1 call, the heads packed, a longer run in tall
-    tiles of up to 128 slots. The plane index is TRACED: the layers are
+    updated in place (donate it); tables: (B, nb). Pass t of layer l is
+    `PagedKV.attend` at plane t * L + l (G = 1: a row with one new token
+    a row of a width-1 call, the heads packed, a longer run in tall tiles
+    of up to 128 slots). The plane index is TRACED: the layers are
     scanned inside a scan over the passes with the pool in both carries
     (module docstring), so the program holds one layer body.
 
     Returns (logits, caches, rows (0, 1): the family routes no experts)."""
-    from tpu_engine.ops import latent_attention as la
     from tpu_engine.ops import paged_attention as pa
 
     del held
     if attn_fn is None:
         attn_fn = pa.default_ragged_attention()
-    b, w = tokens.shape
-    m = la.tiles_bound(b, w, 1, max_tokens)
-    bs = caches.k.shape[2]
-    with step_part("plan"):
-        plan = la.tile_plan(qlen, 1, m)
-        _, valid = la.tile_slots(plan, qlen, 1)
-        row, slot, valid = (plan.row, jnp.minimum(plan.tile, w - 1),
-                            valid[:, 0])
-        logical = pos0[row] + slot
-        cols = jnp.minimum(logical, tables.shape[1] * bs - 1)
-        # invalid -> null block
-        blk = jnp.where(valid, tables[row, cols // bs], 0)
-        off = cols % bs
-        classes = la.class_plan(qlen, w, 1, max_tokens)
-        if sample_slot is not None:
-            sampled = jnp.minimum(
-                plan.start + jnp.minimum(sample_slot, w - 1), m - 1)
-    with step_part("embed"):
-        h = nn.embedding(params["tok_embed"],
-                         tokens[row, slot]).astype(jnp.float32)
+    tt = tick_tokens(pos0, qlen, tokens.shape[1], max_tokens)
+    kv = tt.paged_kv(tables, caches.k.shape[2], 1)
+    h = tt.embed(params, tokens, jnp.float32)
 
     def attend(plane, q, k, v, pool):
         with step_part("attn/qkv"):
-            q, k = (rope(x[None], logical[None], cfg.rope_theta)[0]
+            q, k = (rope(x[None], tt.logical[None], cfg.rope_theta)[0]
                     for x in (q, k))
-        with step_part("attn/write"):
-            pool = _write_pool(pool, plane, blk, off, k, v)
-        with step_part("attn/read"):
-            o = pa.ragged_read_by_class(attn_fn, q, pool, plane, tables,
-                                        pos0, classes, plan.start, row, slot)
-        return o, pool
+        return kv.attend(attn_fn, q, k, v, pool, plane)
 
-    def listed(x):
-        """Row b's new tokens out of the list, (B, W, d)."""
-        at = jnp.minimum(plan.start[:, None] + jnp.arange(w)[None, :], m - 1)
-        return jnp.where((jnp.arange(w)[None, :] < qlen[:, None])[:, :, None],
-                         x[at], 0)
+    def pick(x):
+        return tt.head_rows(x, sample_slot)
 
-    pick = (lambda x: x[sampled]) if sample_slot is not None else listed
     h, pool, streams = _passes(params, h, tuple(caches), cfg, attend, dtype,
                                kept=pick if _gated(cfg) else None)
+    h = pick(h)
     with step_part("head"):
-        return (_head(params, pick(h), streams, cfg, dtype), KVCache(*pool),
+        return (_logits(params, h, streams, cfg, dtype), KVCache(*pool),
                 jnp.zeros((0, 1), jnp.int32))
 
 
 # -- registry ----------------------------------------------------------------------
 
-def _spec(name: str, cfg: OuroConfig, seq_len: int) -> ModelSpec:
-    def init(rng):
-        return ouro_init(rng, cfg)
-
-    def apply(params, x, dtype=jnp.bfloat16):
-        # The one-shot wire contract of models.gpt2: (B, seq) float token
-        # ids -> (B, vocab) logits of the last non-pad position.
-        tokens = jnp.clip(x.astype(jnp.int32), 0, cfg.vocab - 1)
-        last = jnp.max(jnp.where(tokens > 0, jnp.arange(seq_len)[None, :],
-                                 0), axis=1)
-        logits = ouro_apply(params, tokens, cfg, dtype=dtype)
-        return jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
-
-    return ModelSpec(name=name, apply=apply, init=init,
-                     input_shape=(seq_len,), output_shape=(cfg.vocab,),
-                     config=cfg, ragged_step=ouro_step_rows_ragged,
-                     passes=cfg.ut_steps)
+def _lm_spec(name: str, cfg: OuroConfig, seq_len: int) -> ModelSpec:
+    return causal_lm_spec(name, cfg, seq_len, ouro_init, ouro_apply,
+                          ragged_step=ouro_step_rows_ragged,
+                          passes=cfg.ut_steps)
 
 
 def _cfg(**kw) -> OuroConfig:
@@ -395,8 +352,8 @@ def make_ouro(seq_len: int = 128, vocab: int = 49152, n_layers: int = 48,
               max_seq: int = 65536, ln_eps: float = 1e-6,
               param_dtype: str = "bfloat16") -> ModelSpec:
     """Ouro-2.6B's published geometry; every width a keyword."""
-    return _spec("ouro", _cfg(**{k: v for k, v in locals().items()
-                                 if k != "seq_len"}), seq_len)
+    return _lm_spec("ouro", _cfg(**{k: v for k, v in locals().items()
+                                    if k != "seq_len"}), seq_len)
 
 
 @register("ouro-small-test")
@@ -408,6 +365,6 @@ def make_ouro_small(seq_len: int = 16, vocab: int = 256, n_layers: int = 3,
                     param_dtype: str = "float32") -> ModelSpec:
     """Tiny config for tests: 3 layers x 3 passes (9 planes), 4 heads of
     16 lanes, float32."""
-    return _spec("ouro-small-test",
-                 _cfg(**{k: v for k, v in locals().items()
-                         if k != "seq_len"}), seq_len)
+    return _lm_spec("ouro-small-test",
+                    _cfg(**{k: v for k, v in locals().items()
+                            if k != "seq_len"}), seq_len)

@@ -367,6 +367,33 @@ class ModelSpec:
         return n
 
 
+def causal_lm_spec(name: str, cfg, seq_len: int, init, apply,
+                   **declared) -> ModelSpec:
+    """The spec of a causal language model from its `init(rng, cfg)` and
+    its full-sequence forward `apply(params, tokens (B, S) int32, cfg, *,
+    dtype) -> (B, S, vocab)`. The spec's own `apply` is the one-shot
+    `/infer` wire contract, written once: x (B, seq_len) float token ids
+    (the engine pads with zeros) -> (B, vocab) logits of the last real
+    position, a pad id 0 after the first token read as padding.
+    `declared`: the fields of `ModelSpec` the family states
+    (`ragged_step`, `held`, `block_decode`, `passes`, `tp_rule`, ...)."""
+    def wire_apply(params, x, dtype=None):
+        import jax.numpy as jnp
+
+        tokens = jnp.clip(x.astype(jnp.int32), 0, cfg.vocab - 1)
+        last = jnp.max(jnp.where(tokens > 0, jnp.arange(seq_len)[None, :],
+                                 0), axis=1)  # 0 if a single token
+        logits = apply(params, tokens, cfg,
+                       dtype=jnp.bfloat16 if dtype is None else dtype)
+        return jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
+
+    return ModelSpec(name=name, apply=wire_apply,
+                     init=lambda rng: init(rng, cfg),
+                     input_shape=(seq_len,), output_shape=(cfg.vocab,),
+                     config=cfg,  # the generation service reads it
+                     **declared)
+
+
 _REGISTRY: Dict[str, Callable[..., ModelSpec]] = {}
 
 

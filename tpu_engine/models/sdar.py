@@ -89,8 +89,14 @@ import numpy as np
 
 from tpu_engine.models.laguna import _bank, _rope
 from tpu_engine.models.moonlight import _dense_init, _normal, _residual_gain
-from tpu_engine.models.registry import BlockDecode, ModelSpec, register
-from tpu_engine.models.transformer import TransformerConfig, _write_pool
+from tpu_engine.models.registry import (
+    BlockDecode,
+    ModelSpec,
+    causal_lm_spec,
+    register,
+)
+from tpu_engine.models.tick_tokens import lm_head, tick_tokens
+from tpu_engine.models.transformer import TransformerConfig
 from tpu_engine.ops import nn
 from tpu_engine.ops.attention import KVCache, dot_product_attention
 from tpu_engine.ops.moe import routed_experts, softmax_topk_route
@@ -258,11 +264,6 @@ def _run_layers(params, h, carry, cfg: SdarConfig, attend, valid, dtype,
     return h, carry, jnp.stack(rows)
 
 
-def _head(params, h, cfg: SdarConfig, dtype):
-    h = nn.rmsnorm(params["ln_f"], h, eps=cfg.ln_eps)
-    return nn.dense(params["head"], h, dtype=dtype).astype(jnp.float32)
-
-
 # -- the one-shot forward --------------------------------------------------------
 
 def sdar_apply(params, tokens, cfg: SdarConfig, *, dtype=jnp.bfloat16):
@@ -282,7 +283,7 @@ def sdar_apply(params, tokens, cfg: SdarConfig, *, dtype=jnp.bfloat16):
 
     h, _, _ = _run_layers(params, h, (), cfg, attend,
                           jnp.ones((b, s), bool), dtype, None)
-    return _head(params, h, cfg, dtype)
+    return lm_head(params, h, cfg.ln_eps, dtype)
 
 
 # -- the served step: runs of L tokens and prompt chunks over the pool ------------
@@ -291,28 +292,24 @@ def sdar_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
                           cfg: SdarConfig, *, dtype=jnp.bfloat16,
                           attn_fn=None, sample_slot=None, held=None,
                           max_tokens: Optional[int] = None):
-    """One ragged batch where row b consumes qlen[b] >= 0 new tokens at
-    logical columns [pos0[b], pos0[b] + qlen[b]): a generating row its
+    """This family's step of the mixed tick: a generating row feeds its
     block of L = `cfg.block_length` tokens (every pass of it), a
     prefilling row a prompt chunk. `qlen` is a multiple of L and `pos0`
     too (the scheduler cuts chunks so): the step runs over the tick's
-    TOKENS in tiles of L slots with none half full.
+    token list (`models.tick_tokens`) in tiles of L slots with none part
+    full, so the list needs no tile a row beside the budget's.
 
     caches: the pool's K/V pair, (layers, NB, bs, H_kv*D), updated in
-    place (donate it); tables: (B, nb) by logical column // bs. Every
-    token's K and V are scattered into its row's blocks BEFORE the read
-    (write-before-attend), so a run reads its own block back through the
-    pool under the block mask: `ops.paged_attention.ragged_read_by_class`
-    with `mask_block` L, a run of up to L tokens a row of a call L slots
-    wide (L x G query rows a KV head, the heads packed), a longer chunk in
-    tall tiles.
+    place (donate it); tables: (B, nb) by logical column // bs. A run
+    reads its own block back through the pool under the block mask:
+    `PagedKV.attend` with `mask_block` L, a run of up to L tokens a row of
+    a call L slots wide (L x G query rows a KV head, the heads packed), a
+    longer chunk in tall tiles.
 
-    `sample_slot` (B,) or (B, n): the slots whose hidden state goes to the
-    head (gathered BEFORE it), logits (B, vocab) or (B * n, vocab), a
-    row's n positions side by side; None: every slot, (B, W, vocab).
+    `sample_slot` (B, n): the head's rows side by side, logits (B * n,
+    vocab) (`TickTokens.head_rows`).
     Returns (logits, caches, rows (layers, n_routed) int32: the rows each
     expert took)."""
-    from tpu_engine.ops import latent_attention as la
     from tpu_engine.ops import paged_attention as pa
 
     del held                    # every expert is held
@@ -323,83 +320,33 @@ def sdar_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     if w % run:
         raise ValueError(f"a step {w} slots wide holds no whole blocks of "
                          f"{run}")
+    # No tile is part full: the bound needs no tile a row beside.
     n_tiles = b * (w // run)
     if max_tokens is not None:
         n_tiles = min(n_tiles, -(-max_tokens // run))
-    bs = caches.k.shape[2]
-    with step_part("plan"):
-        plan = la.tile_plan(qlen, run, n_tiles)
-        slot, valid = la.tile_slots(plan, qlen, run)             # (N, L)
-        row = plan.row[:, None]
-        slot = jnp.minimum(slot, w - 1)
-        logical = pos0[row] + slot
-        cols = jnp.minimum(logical, tables.shape[1] * bs - 1)
-        # invalid -> null
-        blk = jnp.where(valid, tables[row, cols // bs], 0)
-        off = cols % bs
-        classes = la.class_plan(qlen, w, cfg.n_heads // cfg.kv_heads,
-                                max_tokens, run_slots=run)
-        flat = (plan.start * run, jnp.repeat(plan.row, run),
-                slot.reshape(-1))
-    with step_part("embed"):
-        h = nn.embedding(params["tok_embed"],
-                         tokens[row, slot]).astype(dtype)
+    tt = tick_tokens(pos0, qlen, w, max_tokens, per_tile=run,
+                     n_tiles=n_tiles)
+    kv = tt.paged_kv(tables, caches.k.shape[2], cfg.n_heads // cfg.kv_heads,
+                     run_slots=run)
+    h = tt.embed(params, tokens, dtype)
 
     def attend(layer, ap, x, pool):
         with step_part("attn/qkv"):
-            q, k, v = _attn_inputs(ap, x, logical, cfg, dtype)
-        with step_part("attn/write"):
-            pool = _write_pool(pool, layer, blk, off, k, v)
-        with step_part("attn/read"):
-            o = pa.ragged_read_by_class(
-                attn_fn, q.reshape((-1,) + q.shape[2:]), pool, layer,
-                tables, pos0, classes, *flat,
-                mask_block=run).reshape(q.shape)
-        return o, pool
+            q, k, v = _attn_inputs(ap, x, tt.logical, cfg, dtype)
+        return kv.attend(attn_fn, q, k, v, pool, layer, mask_block=run)
 
     h, pool, rows = _run_layers(params, h, tuple(caches), cfg, attend,
-                                valid, dtype, max_tokens)
-
-    def at(slots):
-        """The rows' new tokens at `slots` ((B,) or (B, n)), found in the
-        tile list."""
-        start = plan.start.reshape((b,) + (1,) * (slots.ndim - 1))
-        tile = jnp.minimum(start + slots // run, plan.row.shape[0] - 1)
-        return h[tile, slots % run]
-
-    with step_part("head"):
-        if sample_slot is not None:
-            h = at(jnp.minimum(sample_slot, w - 1))
-            # (B, n) slots: the head's rows side by side, (B * n, vocab).
-            # A (B, n, vocab) result is re-laid out for its reader, 155 MB
-            # a copy at 64 x 4 x 151,936 (measured on the chip: 0.8 ms a
-            # tick).
-            h = h.reshape(-1, h.shape[-1]) if sample_slot.ndim == 2 else h
-        else:
-            every = jnp.broadcast_to(jnp.arange(w)[None, :], (b, w))
-            h = jnp.where((every < qlen[:, None])[:, :, None], at(every), 0)
-        return _head(params, h, cfg, dtype), KVCache(*pool), rows
+                                tt.valid, dtype, max_tokens)
+    return (lm_head(params, tt.head_rows(h, sample_slot), cfg.ln_eps, dtype),
+            KVCache(*pool), rows)
 
 
 # -- registry ----------------------------------------------------------------------
 
-def _spec(name: str, cfg: SdarConfig, seq_len: int) -> ModelSpec:
-    def init(rng):
-        return sdar_init(rng, cfg)
-
-    def apply(params, x, dtype=jnp.bfloat16):
-        # The one-shot wire contract of models.gpt2: (B, seq) float token
-        # ids -> (B, vocab) logits of the last non-pad position.
-        tokens = jnp.clip(x.astype(jnp.int32), 0, cfg.vocab - 1)
-        last = jnp.max(jnp.where(tokens > 0, jnp.arange(seq_len)[None, :],
-                                 0), axis=1)
-        logits = sdar_apply(params, tokens, cfg, dtype=dtype)
-        return jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
-
-    return ModelSpec(name=name, apply=apply, init=init,
-                     input_shape=(seq_len,), output_shape=(cfg.vocab,),
-                     config=cfg, ragged_step=sdar_step_rows_ragged,
-                     block_decode=cfg.block_decode)
+def _lm_spec(name: str, cfg: SdarConfig, seq_len: int) -> ModelSpec:
+    return causal_lm_spec(name, cfg, seq_len, sdar_init, sdar_apply,
+                          ragged_step=sdar_step_rows_ragged,
+                          block_decode=cfg.block_decode)
 
 
 def _cfg(**kw) -> SdarConfig:
@@ -429,8 +376,8 @@ def make_sdar(seq_len: int = 128, vocab: int = 151936, n_layers: int = 48,
               confidence_threshold: float = 0.9,
               param_dtype: str = "bfloat16") -> ModelSpec:
     """SDAR-30B-A3B-Chat's published geometry; every width a keyword."""
-    return _spec("sdar", _cfg(**{k: v for k, v in locals().items()
-                                 if k != "seq_len"}), seq_len)
+    return _lm_spec("sdar", _cfg(**{k: v for k, v in locals().items()
+                                    if k != "seq_len"}), seq_len)
 
 
 @register("sdar-small-test")
@@ -446,6 +393,6 @@ def make_sdar_small(seq_len: int = 16, vocab: int = 256, n_layers: int = 2,
                     param_dtype: str = "float32") -> ModelSpec:
     """Tiny config for tests: 2 layers, 8 experts top 2 of width 32, 4
     query heads over 2 KV heads of 16 lanes, blocks of 4, float32."""
-    return _spec("sdar-small-test",
-                 _cfg(**{k: v for k, v in locals().items()
-                         if k != "seq_len"}), seq_len)
+    return _lm_spec("sdar-small-test",
+                    _cfg(**{k: v for k, v in locals().items()
+                            if k != "seq_len"}), seq_len)

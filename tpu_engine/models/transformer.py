@@ -24,7 +24,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import re
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -592,39 +592,9 @@ def mlp_slots(cfg: TransformerConfig, batch: int, width: int,
     return pool_write_slots(batch, width, max_tokens)
 
 
-class _TickTokens(NamedTuple):
-    """A tick's valid (row, slot) pairs, compacted in row order into a
-    list of the caller's static bound (`_tick_tokens`), and where each
-    one's K/V goes in the pool. Entries past the tick's live count go to
-    the null block, and REPEAT the last live pair (`tile_plan`): what
-    is written back at `at` must be the same value at every repeat."""
-    at: jax.Array    # (max_tokens,) int32 row * W + slot
-    blk: jax.Array   # (max_tokens,) int32 pool block, 0 past the live count
-    off: jax.Array   # (max_tokens,) int32 offset in the block
-
-
-def _tick_tokens(tables, pos0, qlen, width: int, max_tokens: int,
-                 block_size: int) -> _TickTokens:
-    """The list the uniform step's pool write scatters when its caller
-    states how many valid slots a tick can hold: a cumulative sum over
-    `qlen` and a `searchsorted` (`ops.latent_attention.tile_plan` at one
-    slot a tile, the six family steps' token list). A tick that holds
-    more than `max_tokens` valid slots would lose the rest, so the
-    caller that states the bound holds its ticks to it
-    (`runtime.scheduler` `_tick_formed`)."""
-    from tpu_engine.ops import latent_attention as la
-
-    plan = la.tile_plan(qlen, 1, max_tokens)
-    _, valid = la.tile_slots(plan, qlen, 1)
-    row, slot = plan.row, plan.tile
-    cols = jnp.minimum(pos0[row] + slot, tables.shape[1] * block_size - 1)
-    blk = jnp.where(valid[:, 0], tables[row, cols // block_size], 0)
-    return _TickTokens(row * width + slot, blk, cols % block_size)
-
-
 def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
                             cfg: TransformerConfig, *, dtype, attn_fn,
-                            listed: Optional[_TickTokens] = None,
+                            listed=None,
                             mlp_at: Optional[jax.Array] = None):
     """One ragged mixed step against the PAGED pool: cache_kv arrays are
     the whole (L, NB, bs, H_kv*D) block pools shared by every row and
@@ -638,8 +608,10 @@ def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
     tokens' K/V scatter into the rows' pool blocks of layer ``layer``
     BEFORE the attention read (write-before-attend; a quantized pool
     quantizes at THIS write, `_write_pool`). What the scatter takes:
-    with ``listed`` the tick's valid slots alone, gathered out of the
-    (B, W) products into the list's order — an index a token, and XLA's
+    with ``listed`` = (at, blk, off), each a `models.tick_tokens` list
+    long (an entry's place row * W + slot among the slots, its pool block
+    and its offset there), the tick's valid slots alone, gathered out of
+    the (B, W) products into the list's order — an index a token, and XLA's
     scatter on a TPU pays by the index; without it all B x W slots, the
     padding ones (i >= qlen) sent to the null block. The values and the
     places are the same either way, the null block's contents apart;
@@ -659,12 +631,12 @@ def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
         q, k, v = _project_qkv(bp, x, cfg, dtype=dtype, positions=logical)
     with step_part("attn/write"):
         if listed is not None:
-            blk, off = listed.blk, listed.off
+            at, blk, off = listed
             # Whole rows of lanes out of the (B x W, H_kv * D) form the
             # projection left: gathered by head, XLA first re-lays all
             # B x W slots out with (H_kv, D) minor, a copy a tensor a
             # layer.
-            k_new, v_new = (x.reshape(b * w, -1)[listed.at].reshape(
+            k_new, v_new = (x.reshape(b * w, -1)[at].reshape(
                 (-1,) + x.shape[2:]) for x in (k, v))
         else:
             rows = jnp.arange(b)[:, None]
@@ -738,7 +710,7 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
     sum(qlen) <= max_tokens (the scheduler's token budget plus a token a
     row). Stated, and at a width above 1, each layer's pool write
     scatters that many indices — the tick's tokens, listed once a step
-    (`_tick_tokens`) — where it otherwise scatters all B x W slots,
+    (`models.tick_tokens`) — where it otherwise scatters all B x W slots,
     most of them padding sent to the null block, and each layer's dense
     feed-forward runs over that many rows of the residual (`mlp_slots`)
     where it otherwise runs over all B x W. Logits at the valid slots
@@ -768,11 +740,15 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
     # One list a step, outside the layer loop, where it is shorter than
     # the step's slots.
     n_listed = pool_write_slots(b, w, max_tokens)
-    with step_part("plan"):
-        listed = (_tick_tokens(tables, pos0, qlen, w, n_listed,
-                               caches.k.shape[2])
-                  if n_listed < b * w else None)
-    mlp_at = listed.at if mlp_slots(cfg, b, w, max_tokens) < b * w else None
+    listed = None
+    if n_listed < b * w:
+        from tpu_engine.models.tick_tokens import tick_tokens
+
+        tt = tick_tokens(pos0, qlen, w, max_tokens, n_tiles=n_listed)
+        blk, off = tt.blocks(tables, caches.k.shape[2])
+        with step_part("plan"):
+            listed = (tt.row * w + tt.slot, blk, off)
+    mlp_at = listed[0] if mlp_slots(cfg, b, w, max_tokens) < b * w else None
 
     def block(bp, h, cache_kv, layer):
         return _block_step_rows_ragged(

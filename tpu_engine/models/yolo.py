@@ -104,14 +104,14 @@ def _upsample2x(x):
     return x.reshape(b, 2 * h, 2 * w, c)
 
 
-def _head_branch_init(key, cin: int, mid: int, cout: int):
+def _branch_init(key, cin: int, mid: int, cout: int):
     k1, k2, k3 = jax.random.split(key, 3)
     return {"cv1": _conv_init(k1, 3, cin, mid),
             "cv2": _conv_init(k2, 3, mid, mid),
             "out": nn.conv_init(k3, 1, 1, mid, cout)}
 
 
-def _head_branch(p, x, dtype=None):
+def _branch(p, x, dtype=None):
     x = _conv(p["cv2"], _conv(p["cv1"], x, dtype=dtype), dtype=dtype)
     return nn.conv2d(p["out"], x, dtype=dtype)
 
@@ -144,9 +144,9 @@ def yolo_init(key, cfg: YoloConfig):
     hk = jax.random.split(jax.random.fold_in(key, 1), 3)
     mid = max(w[2], cfg.head_ch // 4)
     params["head"] = [
-        _head_branch_init(hk[0], w[2], mid, cfg.head_ch),
-        _head_branch_init(hk[1], w[3], mid, cfg.head_ch),
-        _head_branch_init(hk[2], w[4], mid, cfg.head_ch),
+        _branch_init(hk[0], w[2], mid, cfg.head_ch),
+        _branch_init(hk[1], w[3], mid, cfg.head_ch),
+        _branch_init(hk[2], w[4], mid, cfg.head_ch),
     ]
     return params
 
@@ -184,7 +184,7 @@ def yolo_apply(params, x, cfg: YoloConfig, dtype=jnp.bfloat16):
 
     outs = []
     for p, feat in zip(params["head"], (f3, n4, n5)):
-        y = _head_branch(p, feat, dtype=dtype)  # (B, h, w, head_ch)
+        y = _branch(p, feat, dtype=dtype)  # (B, h, w, head_ch)
         b, h, w, c = y.shape
         outs.append(y.reshape(b, h * w, c))
     return jnp.concatenate(outs, axis=1).astype(jnp.float32)
