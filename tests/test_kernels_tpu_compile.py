@@ -587,12 +587,14 @@ def test_mixed_step_never_copies_the_pool(v5e_devices, config, width):
 @pytest.mark.parametrize("config", ["gpt2-large", "mistral-7b-v0.2-8l"])
 def test_chunk_step_feeds_forward_its_listed_tokens(v5e_devices, config):
     """Since PR 59 a chunk tick's feed-forward (gelu at gpt2-large's
-    widths, SwiGLU at Mistral's) runs over the tick's token list: compiled
-    for one v5e at the cell's serving shapes, the program's products with
-    a `d_ff` side have 288 (272) rows and none has rows x 256, and the
-    gather of the residual's rows and their scatter back are ops of the
-    `mlp` part, so `step.ffn_busy` reads them and `step.unscoped_busy`
-    does not."""
+    widths, SwiGLU at Mistral's) runs over the tick's token list, since
+    PR 63 its output projection too: compiled for one v5e at the cell's
+    serving shapes, the program's products with a `d_ff` side have 288
+    (272) rows and none has rows x 256, `wo`'s product under `attn/out`
+    has as many, and the gather of the residual's rows and their one
+    scatter back are ops of the `attn/out` and `mlp` parts, so
+    `step.attn_busy` and `step.ffn_busy` read them and
+    `step.unscoped_busy` does not."""
     cfg, _, args, bound, compiled = _compiled_cell_tick(
         v5e_devices, config, 256)
     rows = args[-1].shape[0]
@@ -603,6 +605,10 @@ def test_chunk_step_feeds_forward_its_listed_tokens(v5e_devices, config):
     # Mistral's 16 x 256 slots are as many as its d_model: a weight's shape.
     wide -= {(cfg.d_model, cfg.d_ff)}
     assert (bound, cfg.d_ff) in shapes and not wide & shapes
+    out_products = set(re.findall(
+        r"= \w+\[([\d,]+)\]\S* convolution\(.*"
+        r'op_name="[^"]*/attn/out/dot_general"', hlo))
+    assert out_products == {f"{bound},{cfg.d_model}"}, out_products
     paths = re.findall(
         rf"= \w+\[(?:{bound}|{rows * 256}),{cfg.d_model}\]\S* "
         r"(?:fusion|gather|scatter)\(.*"
@@ -611,8 +617,8 @@ def test_chunk_step_feeds_forward_its_listed_tokens(v5e_devices, config):
     # gpt2-large's heads, what the pool write gathers of K and V.
     tails = {re.sub(r"^jit\(step\)/(while/body/closed_call/)?", "", p)
              for p in paths}
-    assert {"mlp/gather", "mlp/scatter"} <= tails <= {
-        "mlp/gather", "mlp/scatter", "attn/write/gather",
+    assert {"attn/out/gather", "mlp/scatter"} <= tails <= {
+        "attn/out/gather", "mlp/scatter", "attn/write/gather",
         "embed/gather"}, tails
 
 

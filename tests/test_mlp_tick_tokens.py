@@ -1,17 +1,19 @@
-"""The uniform step's feed-forward over the tick's TOKENS against the
-feed-forward over all of its slots (`models.transformer`
-`transformer_step_rows_ragged`, `max_tokens`; `mlp_slots`): the norm, the
-MLP and the residual add are row-wise, so a valid slot's logits and the
-K/V every later layer writes for it are the all-slot step's whichever rows
-sit beside it in the operand.
+"""The uniform step's second half over the tick's TOKENS against the
+second half over all of its slots (`models.transformer`
+`transformer_step_rows_ragged`, `max_tokens`; `second_half_slots`): the
+output projection and its residual add, the norm, the MLP and its add are
+row-wise, so a valid slot's logits and the K/V every later layer writes for
+it are the all-slot step's whichever rows sit beside it in the operand.
 
-One random tick a case, over gelu and SwiGLU, all heads and grouped ones,
-the plain pool and the int8 one: decode rows beside one chunk and beside
-several, a tick with no live token, a tick at exactly the bound, a chunk's
-tail of 1 to 3 tokens. Then where the step keeps the all-slot form (a slot
-wide, no bound stated, routed experts), a lane's served tokens with the
-bound and without, and what the tick's span says the feed-forward computed
-(its reader-side twin for the pool write:
+One random tick a case, over gelu and SwiGLU, learned positions over all
+heads and rotated grouped ones, the plain pool and the int8 one: decode
+rows alone, beside one chunk and beside several, a chunk whose last token
+the list's tail repeats, a tick with no live token, a tick at exactly the
+bound, a chunk's tail of 1 to 3 tokens. Then which products the step holds
+and over how many rows, where it keeps the all-slot form (a slot wide, no
+bound stated, routed experts), a lane's served tokens with the bound and
+without, and what the tick's span says the two parts computed (its
+reader-side twin for the pool write:
 tests/benchmarks/test_benchmark_layer_metrics_poolwrite.py).
 """
 
@@ -25,8 +27,8 @@ from tpu_engine.models.registry import (
     create_model,
 )
 from tpu_engine.models.transformer import (
-    mlp_slots,
     pool_write_slots,
+    second_half_slots,
     transformer_step_rows_ragged,
 )
 from tpu_engine.runtime.kv_blocks import BlockPool
@@ -43,7 +45,10 @@ MODELS = ("gpt2-small-test", "llama-small-test")
 
 # mix: (q_lens, pos0, max_tokens)
 MIXES = {
+    "decode-rows-alone": ((1, 1, 1, 1), (21, 16, 3, 40), 20),
     "decode-rows-beside-one-chunk": ((1, 16, 1, 0), (21, 16, 3, 0), 24),
+    # the list's tail repeats the CHUNK's last token, 9 times
+    "a-chunk-last-in-the-list": ((1, 0, 1, 13), (21, 0, 3, 16), 24),
     "decode-rows-beside-several-chunks": ((1, 9, 1, 7), (30, 16, 3, 0), 24),
     "no-live-token": ((0, 0, 0, 0), (0, 0, 0, 0), 20),
     "at-exactly-the-bound": ((1, 16, 2, 1), (40, 0, 30, 7), 20),
@@ -113,12 +118,12 @@ def _tick(spec, params, q_lens, pos0, width, quant, seed):
 @pytest.mark.parametrize("quant", ["", "int8"])
 @pytest.mark.parametrize("mix", sorted(MIXES))
 @pytest.mark.parametrize("model", MODELS)
-def test_the_listed_feed_forward_is_the_all_slot_one(models, model, mix,
-                                                     quant):
+def test_the_listed_second_half_is_the_all_slot_one(models, model, mix,
+                                                    quant):
     """Logits at every valid slot, and every block of every pool array but
     the null one, layer by layer: a later layer's K/V is made from what the
     layers under it left in the residual, so the pool holds every layer's
-    feed-forward to account, not the last one's alone."""
+    second half to account, not the last one's alone."""
     q_lens, pos0, max_tokens = MIXES[mix]
     assert sum(q_lens) <= max_tokens < len(q_lens) * WIDTH
     spec, params = models[model]
@@ -141,41 +146,75 @@ def test_the_listed_feed_forward_is_the_all_slot_one(models, model, mix,
             np.testing.assert_allclose(new, old, **TOL)
 
 
-def _graph(spec, params, rows, width, bound):
+def _jaxpr(spec, params, rows, width, bound):
     pool = BlockPool(spec.config, N_BLOCKS, BS, jnp.float32)
-    return str(jax.make_jaxpr(
+    return jax.make_jaxpr(
         lambda c, tokens, tables, pos0, qlen:
         transformer_step_rows_ragged(
             params, tokens, c, tables, pos0, qlen, spec.config,
             dtype=jnp.float32, max_tokens=bound))(
         pool.caches, jnp.zeros((rows, width), jnp.int32),
         jnp.zeros((rows, 3), jnp.int32), jnp.zeros((rows,), jnp.int32),
-        jnp.zeros((rows,), jnp.int32)))
+        jnp.zeros((rows,), jnp.int32))
+
+
+def _graph(spec, params, rows, width, bound):
+    return str(_jaxpr(spec, params, rows, width, bound))
+
+
+def _products(closed):
+    """part of the step -> the shapes of the left operands of its
+    `dot_general`s, the layer scan's body included."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                part = str(eqn.source_info.name_stack)
+                found.setdefault(part, []).append(eqn.invars[0].aval.shape)
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                walk(inner)
+
+    walk(closed.jaxpr)
+    return found
 
 
 @pytest.mark.parametrize("model", MODELS)
 def test_a_chunk_tick_multiplies_the_list_and_no_slot_more(models, model):
     """With the bound stated and a width above 1 no product of the step
     has a (rows x width, d_ff) or (rows, width, d_ff) side: the
-    feed-forward's are (max_tokens, d_ff)."""
+    feed-forward's are (max_tokens, d_ff). `wo`'s product, the one
+    `dot_general` under `attn/out`, has max_tokens rows too, and none of
+    the second half's has rows x width."""
     spec, params = models[model]
     cfg, rows, bound = spec.config, GRAPH_ROWS, 20
-    assert mlp_slots(cfg, rows, WIDTH, bound) == bound
-    listed, every = (_graph(spec, params, rows, WIDTH, b)
-                     for b in (bound, None))
+    assert second_half_slots(cfg, rows, WIDTH, bound) == bound
+    traced = [_jaxpr(spec, params, rows, WIDTH, b) for b in (bound, None)]
+    listed, every = map(str, traced)
     wide = (f"f32[{rows},{WIDTH},{cfg.d_ff}]", f"f32[{rows * WIDTH},"
             f"{cfg.d_ff}]")
     assert f"f32[{bound},{cfg.d_ff}]" in listed
     assert not any(shape in listed for shape in wide)
     assert f"f32[{bound},{cfg.d_ff}]" not in every
     assert any(shape in every for shape in wide)
+    listed, every = map(_products, traced)
+    heads = cfg.n_heads * cfg.d_head
+    assert listed["attn/out"] == [(bound, heads)]
+    assert every["attn/out"] == [(rows, WIDTH, heads)]
+    assert all(shape[0] == bound and len(shape) == 2
+               for shape in listed["mlp"])
+    # The first half keeps its slots: q, k and v are products over all.
+    assert listed["attn/qkv"] == every["attn/qkv"]
+    assert all(shape[:2] == (rows, WIDTH) for shape in listed["attn/qkv"])
 
 
 @pytest.mark.parametrize("model,width,bound", [
     ("gpt2-small-test", 1, 20),          # a slot wide: a token a slot
     ("llama-small-test", 1, 20),
     ("gpt2-small-test", WIDTH, None),    # no bound stated
+    ("llama-small-test", WIDTH, None),
     ("gpt2-small-test", WIDTH, 64),      # a bound no shorter than the slots
+    ("llama-small-test", WIDTH, 64),
     ("gpt2-moe-test", 1, 20),
 ])
 def test_the_step_keeps_the_all_slot_program(models, model, width, bound):
@@ -184,27 +223,34 @@ def test_the_step_keeps_the_all_slot_program(models, model, width, bound):
     step's with no list at all, to the letter."""
     spec, params = models[model]
     rows = 4
-    assert mlp_slots(spec.config, rows, width, bound) == rows * width
-    assert (_graph(spec, params, rows, width, bound)
-            == _graph(spec, params, rows, width, None))
+    assert second_half_slots(spec.config, rows, width, bound) == rows * width
+    stated = _jaxpr(spec, params, rows, width, bound)
+    assert str(stated) == _graph(spec, params, rows, width, None)
+    cfg = spec.config
+    assert _products(stated)["attn/out"] == [
+        (rows, width, cfg.n_heads * cfg.d_head)]
 
 
 def test_routed_experts_keep_every_slot(models):
     """An expert's capacity counts the rows of the operand
     (`ops.moe.moe_apply`), so a row's value is NOT its own there: the
     routed feed-forward sees all rows x width slots with the bound stated
-    as without, while the pool write takes the list all the same."""
+    as without, and so does `wo` (listed alone it would be written back
+    once more a layer), while the pool write takes the list all the
+    same."""
     spec, params = models["gpt2-moe-test"]
     cfg, rows, bound = spec.config, 4, 20
     assert cfg.n_experts > 0
-    assert mlp_slots(cfg, rows, WIDTH, bound) == rows * WIDTH
+    assert second_half_slots(cfg, rows, WIDTH, bound) == rows * WIDTH
     assert pool_write_slots(rows, WIDTH, bound) == bound
-    listed, every = (_graph(spec, params, rows, WIDTH, b)
-                     for b in (bound, None))
+    stated = _jaxpr(spec, params, rows, WIDTH, bound)
+    listed, every = str(stated), _graph(spec, params, rows, WIDTH, None)
     # K and V are gathered at the list; nothing is scattered but the pool.
     lanes = cfg.kv_heads * cfg.d_head
     assert listed.count(f"f32[{bound},{lanes}] = gather") == 2
     assert listed.count(" scatter[") == every.count(" scatter[") > 0
+    assert _products(stated)["attn/out"] == [
+        (rows, WIDTH, cfg.n_heads * cfg.d_head)]
     call, valid = _tick(spec, params, (1, 16, 1, 0), (21, 16, 3, 0), WIDTH,
                         "", 0)
     want, got = call(None), call(bound)
@@ -248,15 +294,27 @@ def test_a_lane_serves_the_same_tokens_with_the_bound_and_without(
     assert all(len(tokens) == 6 for tokens in served[0])
 
 
-def test_the_span_says_what_the_feed_forward_computed(models):
-    """`mlp_slots` on a uniform lane's `mixed_step` spans: the list's
-    length (token budget + rows) on a chunk tick, a row a slot on a
-    width-1 tick; the same function the step asks
-    (`models.transformer.mlp_slots`)."""
-    spec, params = models["gpt2-small-test"]
+@pytest.mark.parametrize("model,bound_stated,listed", [
+    ("gpt2-small-test", True, 16 + 4),
+    ("llama-small-test", True, 16 + 4),
+    ("gpt2-moe-test", True, 4 * 16),      # routed experts: every slot
+    ("gpt2-small-test", False, 4 * 16),   # no bound: every slot
+])
+def test_the_span_says_what_the_feed_forward_computed(models, model,
+                                                      bound_stated, listed):
+    """`out_slots` and `mlp_slots` on a uniform lane's `mixed_step` spans,
+    the rows a layer's `wo` and its feed-forward multiplied: the list's
+    length (token budget + rows) on a chunk tick, rows x width under
+    routed experts or with no bound stated, a row a slot on a width-1
+    tick; the same function the step asks
+    (`models.transformer.second_half_slots`)."""
+    spec, params = models[model]
     gen = _lane(spec, params)
     gen.tracer = SpanRecorder(256)
     try:
+        assert gen._tick_max_tokens == 20
+        if not bound_stated:
+            gen._tick_max_tokens = None
         gen.submit(prompt=[5, 9, 3, 7, 2] * 5,
                    max_new_tokens=5).result(timeout=120)
         spans = [s["attrs"] for s in gen.tracer.snapshot()
@@ -267,8 +325,8 @@ def test_the_span_says_what_the_feed_forward_computed(models):
     decode = [a for a in spans if a["width"] == 1]
     assert chunk and decode
     for attrs in chunk:
-        assert attrs["mlp_slots"] == gen._tick_max_tokens == 20
-        assert attrs["mlp_slots"] == attrs["write_slots"]
+        assert attrs["out_slots"] == attrs["mlp_slots"] == listed
+        assert attrs["write_slots"] == (20 if bound_stated else 64)
         assert attrs["write_tokens"] <= attrs["mlp_slots"]
     for attrs in decode:
-        assert attrs["mlp_slots"] == gen.n_slots == 4
+        assert attrs["out_slots"] == attrs["mlp_slots"] == gen.n_slots == 4

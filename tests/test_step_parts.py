@@ -171,20 +171,36 @@ def test_the_speculative_step_is_named_for_its_window():
         gen.stop()
 
 
-def test_the_listed_feed_forward_s_gather_and_write_back_lie_under_mlp(
+def test_the_listed_second_half_s_gathers_and_write_back_lie_under_its_parts(
         lowered):
-    """A chunk tick's feed-forward runs over the tick's token list (PR 59,
-    `models.transformer.mlp_slots`): the gather of the residual's rows and
-    their write-back are ops of `mlp`, so `step.ffn_busy` reads the whole
-    part and nothing of it lands under no part; a width-1 tick has
-    neither."""
+    """A chunk tick's second half runs over the tick's token list (PRs 59
+    and 63, `models.transformer.second_half_slots`): the gather of the
+    residual's rows is an op of `attn/out` beside `wo`'s product and its
+    add (that of the read's output one of `attn/read`, before it is
+    converted), the one write-back an op of `mlp`, so `step.attn_busy`
+    and `step.ffn_busy` read their whole parts and nothing of either
+    lands under no part; a width-1 tick has neither."""
     def ops(chunk, part):
         _, paths = lowered("gpt2-small-test", chunk)
         return {p.rsplit("/", 1)[-1] for p in paths if part_of(p) == part}
 
-    assert {"gather", "scatter"} <= ops(True, "mlp")
-    assert not {"gather", "scatter"} & ops(False, "mlp")
+    assert "gather" in ops(True, "attn/out")
+    assert "scatter" in ops(True, "mlp")
+    assert "scatter" not in ops(True, "attn/out")
+    assert not {"gather", "scatter"} & (ops(False, "mlp")
+                                        | ops(False, "attn/out"))
     # The scatters inside the layer scan: the pool's and the residual's.
     _, paths = lowered("gpt2-small-test", True)
     assert {part_of(p) for p in paths if p.endswith("/scatter")
             and not p.startswith("jit(")} == {"attn/write", "mlp"}
+
+
+@pytest.mark.parametrize("chunk", [False, True], ids=["narrow", "chunk"])
+def test_attn_out_names_wo_s_product_and_its_add(lowered, chunk):
+    """Over the list as over every slot, `attn/out` holds one product,
+    `wo`'s, and the add of its result to the residual."""
+    _, paths = lowered("gpt2-small-test", chunk)
+    under = [p.rsplit("/", 1)[-1] for p in paths
+             if part_of(p) == "attn/out"]
+    assert under.count("dot_general") == 1
+    assert "add" in under

@@ -580,13 +580,16 @@ def pool_write_slots(batch: int, width: int,
     return min(max_tokens, slots)
 
 
-def mlp_slots(cfg: TransformerConfig, batch: int, width: int,
-              max_tokens: Optional[int] = None) -> int:
-    """The rows ONE layer's feed-forward of the uniform step computes:
-    the step's token list where it has one (`pool_write_slots`) and the
-    feed-forward is dense, so a row's value is its own whatever rows sit
-    beside it; every slot under routed experts, whose capacity counts the
-    rows of the operand (`ops.moe.moe_apply`)."""
+def second_half_slots(cfg: TransformerConfig, batch: int, width: int,
+                      max_tokens: Optional[int] = None) -> int:
+    """The rows ONE layer's second half of the uniform step computes —
+    the output projection's product and its residual add, the norm, the
+    feed-forward and its add: the step's token list where it has one
+    (`pool_write_slots`) and the feed-forward is dense, so a row's value
+    is its own whatever rows sit beside it; every slot, for `wo` too,
+    under routed experts, whose capacity counts the rows of the operand
+    (`ops.moe.moe_apply`): a listed `wo` there would be a second
+    write-back a layer."""
     if cfg.n_experts > 0:
         return batch * width
     return pool_write_slots(batch, width, max_tokens)
@@ -595,7 +598,7 @@ def mlp_slots(cfg: TransformerConfig, batch: int, width: int,
 def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
                             cfg: TransformerConfig, *, dtype, attn_fn,
                             listed=None,
-                            mlp_at: Optional[jax.Array] = None):
+                            half_at: Optional[jax.Array] = None):
     """One ragged mixed step against the PAGED pool: cache_kv arrays are
     the whole (L, NB, bs, H_kv*D) block pools shared by every row and
     layer (`runtime.kv_blocks.BlockPool` states the layout); ``tables``
@@ -615,13 +618,16 @@ def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
     scatter on a TPU pays by the index; without it all B x W slots, the
     padding ones (i >= qlen) sent to the null block. The values and the
     places are the same either way, the null block's contents apart;
-    q, the read and the attention's dense products keep their (B, W)
-    shapes, and the padding slots' outputs are garbage the scheduler
-    ignores. With ``mlp_at`` (the list's `at`) the layer's second half
-    (the norm, the feed-forward, the residual add: all row-wise) runs
-    over those rows of the residual alone and is written back where they
-    came from; a padding slot then keeps the first half's residual,
-    garbage as before."""
+    q, k, v and the read keep their (B, W) shapes, and the padding
+    slots' outputs are garbage the scheduler ignores. With ``half_at``
+    (the list's `at`) the layer's second half — `wo`'s product over the
+    read's output and its residual add, the norm, the feed-forward and
+    its add: all row-wise — runs over the list's rows alone, gathered
+    out of the read's output (as it comes, (B x W, H, D): its conversion
+    and its laying out for `wo` then pass over the list too) and out of
+    the residual, and is written back where they came from, once; a
+    padding slot then keeps the layer's INPUT residual, garbage as
+    before."""
     bs = cache_kv[0].shape[2]
     b, w = h.shape[:2]
     offs = jnp.arange(w)[None, :]
@@ -651,21 +657,25 @@ def _block_step_rows_ragged(bp, h, cache_kv, layer, tables, pos0, qlen,
         cache_kv = _write_pool(cache_kv, layer, blk, off, k_new, v_new)
     with step_part("attn/read"):
         a = attn_fn(q, *cache_kv, layer, tables, pos0, qlen)  # grouped
+        if half_at is not None:
+            # Whole tokens out of the read's (B, W, H, D) output, before
+            # it is converted and laid (B x W, H * D) for `wo`: those
+            # passes then cover the list's rows, not every slot.
+            a = a.reshape((b * w,) + a.shape[2:])[half_at]
         a = a.astype(dtype)
     with step_part("attn/out"):
-        h = h + nn.dense(bp["attn"]["wo"], a.reshape(b, w, -1), dtype=dtype)
+        x = h if half_at is None else h.reshape(b * w, -1)[half_at]
+        x = x + nn.dense(bp["attn"]["wo"],
+                         a.reshape(x.shape[:-1] + (-1,)), dtype=dtype)
     with step_part("mlp"):
-        if mlp_at is not None:
-            flat = h.reshape(b * w, -1)
-            x = flat[mlp_at]
-            x = x + _mlp(bp["mlp"], _norm(bp["ln2"], x, cfg), dtype, cfg)
-            # `.set`, not `.add`: the list's tail repeats its last live
-            # entry, and every repeat carries the same new row.
-            h = flat.at[mlp_at].set(x.astype(flat.dtype)).reshape(h.shape)
-        else:
-            h = h + _mlp(bp["mlp"], _norm(bp["ln2"], h, cfg), dtype, cfg)
-        h = h.astype(dtype)
-    return h, cache_kv
+        x = x + _mlp(bp["mlp"], _norm(bp["ln2"], x, cfg), dtype, cfg)
+        x = x.astype(dtype)
+        if half_at is None:
+            return x, cache_kv
+        # `.set`, not `.add`: the list's tail repeats its last live entry,
+        # and every repeat carries the same new row.
+        return (h.reshape(b * w, -1).at[half_at].set(x).reshape(h.shape),
+                cache_kv)
 
 
 def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
@@ -711,12 +721,13 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
     row). Stated, and at a width above 1, each layer's pool write
     scatters that many indices — the tick's tokens, listed once a step
     (`models.tick_tokens`) — where it otherwise scatters all B x W slots,
-    most of them padding sent to the null block, and each layer's dense
-    feed-forward runs over that many rows of the residual (`mlp_slots`)
-    where it otherwise runs over all B x W. Logits at the valid slots
-    and every block but the null one are the same either way; tokens
-    past a bound the caller broke would be lost, so the caller checks
-    it."""
+    most of them padding sent to the null block, and each layer's
+    output projection and dense feed-forward run over that many rows
+    (`second_half_slots`: three consumers of the list a layer, one
+    write-back) where they otherwise run over all B x W. Logits at the
+    valid slots and every block but the null one are the same either
+    way; tokens past a bound the caller broke would be lost, so the
+    caller checks it."""
     if attn_fn is None:
         from tpu_engine.ops.paged_attention import (
             default_quant_ragged_attention,
@@ -748,12 +759,13 @@ def transformer_step_rows_ragged(params, tokens, caches: KVCache, tables,
         blk, off = tt.blocks(tables, caches.k.shape[2])
         with step_part("plan"):
             listed = (tt.row * w + tt.slot, blk, off)
-    mlp_at = listed[0] if mlp_slots(cfg, b, w, max_tokens) < b * w else None
+    half_at = (listed[0] if second_half_slots(cfg, b, w, max_tokens) < b * w
+               else None)
 
     def block(bp, h, cache_kv, layer):
         return _block_step_rows_ragged(
             bp, h, cache_kv, layer, tables, pos0, qlen, cfg, dtype=dtype,
-            attn_fn=attn_fn, listed=listed, mlp_at=mlp_at)
+            attn_fn=attn_fn, listed=listed, half_at=half_at)
 
     h, *pool = _scan_layers_paged(block, params, h, caches, scales)
     with step_part("head"):

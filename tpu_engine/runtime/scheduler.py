@@ -66,8 +66,8 @@ from tpu_engine.models.ssd import (
 from tpu_engine.models.transformer import (
     TransformerConfig,
     init_caches,
-    mlp_slots,
     pool_write_slots,
+    second_half_slots,
     transformer_decode_rows,
     transformer_decode_window,
     transformer_prefill,
@@ -4467,9 +4467,10 @@ class ContinuousGenerator:
         scatters (`write_slots`, `models.transformer.pool_write_slots`:
         the step's token list where the tick's caller states its bound,
         `max_tokens`, else every slot of the step) beside the tokens the
-        tick holds (`write_tokens`), and the rows ONE layer's feed-forward
-        computes (`mlp_slots`, `models.transformer.mlp_slots`: the same
-        list under a dense feed-forward). A tick over a stated bound
+        tick holds (`write_tokens`), and the rows ONE layer's output
+        projection and feed-forward compute (`out_slots`, `mlp_slots`:
+        both `models.transformer.second_half_slots`, the same list under
+        a dense feed-forward). A tick over a stated bound
         would lose the K/V of the tokens past it: it raises here, before the
         dispatch, and the loop counts it with the device's failures
         (`_recover`). `active`:
@@ -4503,13 +4504,14 @@ class ContinuousGenerator:
                 group=self.cfg.n_heads // self.cfg.kv_heads,
                 kv_heads=self.cfg.kv_heads,
                 block_size=self._pool.block_size)
+            half_slots = second_half_slots(self.cfg, qlen.shape[0], width,
+                                           max_tokens)
             self._clock.note(
                 walk_live_tiles=live, walk_warm_tiles=warm,
                 walk_tokens_fetched=fetched, write_tokens=n_tokens,
                 write_slots=pool_write_slots(qlen.shape[0], width,
                                              max_tokens),
-                mlp_slots=mlp_slots(self.cfg, qlen.shape[0], width,
-                                    max_tokens))
+                out_slots=half_slots, mlp_slots=half_slots)
         self._clock.dispatch(width, int(fed.sum()), ctx_tokens)
 
     def _slide_window_blocks(self, pos0, qlen) -> None:
