@@ -115,6 +115,28 @@ def test_the_recurrence_compiles_for_v5e_at_128_heads_of_64_by_128(
         assert _pallas_grids(jaxpr.jaxpr) == [grid]
 
 
+def test_the_recurrence_compiles_for_v5e_at_one_group(v5e_devices):
+    """`ssd_step` and `ssd_chunk` at Granite-4.0-H's shape: the same 128
+    heads of (64, 128) with B and C ONE group that every head reads. A
+    block of the step is 64 heads (2 MB of state, its dt x block (1, 1, 64,
+    64)), so a row is two grid steps that both name group 0; the chunk
+    kernel a head a grid step, every head's B and C block the same one."""
+    names = []
+    for case in kernel_check.kernel_cases("granite_hybrid", interpret=False):
+        kernel_check.compile_for_topology(case, v5e_devices[0])
+        names.append(case.name)
+    assert names == ["granite_hybrid/ssd_step/B64",
+                     "granite_hybrid/ssd_step/B64/live63",
+                     "granite_hybrid/ssd_step/B64/live0",
+                     "granite_hybrid/ssd_chunk/T256"]
+    for case in kernel_check.kernel_cases("granite_hybrid"):
+        grid = (64, 2) if "ssd_step" in case.name else (128,)
+        operands = jax.eval_shape(case.operands)
+        assert operands[3].shape[1:] == (1, 128)         # B: one group
+        jaxpr = jax.make_jaxpr(case.kernel)(*operands)
+        assert _pallas_grids(jaxpr.jaxpr) == [grid]
+
+
 def test_the_delta_rule_compiles_for_v5e_under_a_gate_a_head(v5e_devices):
     """`gdn_step` over digest's 16 slots (30 heads of 192 x 96, 15 a block:
     one dead row, 14 live, none) and `gdn_chunk` over a run of 256 tokens,
@@ -1258,6 +1280,99 @@ def test_conv_operator_mixed_step_copies_no_pool_tail_or_bank(v5e_devices,
           analysis.temp_size_in_bytes, "alias", analysis.alias_size_in_bytes)
     assert analysis.temp_size_in_bytes < 1.0e9
     assert analysis.alias_size_in_bytes > 2.6e9      # both pools in place
+
+
+@pytest.mark.parametrize("width", [1, 256])
+def test_mixer_and_experts_a_layer_mixed_step_copies_no_pool_state_or_bank(
+        v5e_devices, width):
+    """The Granite-4.0-H cell's mixed step at its serving shapes (shapes
+    only: 64 rows, ten layers of two shapes: nine step or chunk a 4.2 MB
+    state of 128 heads of (64, 128) at ONE group, one reads a K/V chain at
+    G = 4 over 8 KV heads of 128 lanes, and EVERY one then routes 10 of 72
+    experts over a bank of 36 held SwiGLU experts of 768 lanes beside a
+    shared one), both pools donated, compiled for one v5e: the paged calls
+    and both forms of the recurrence are Pallas calls in it, twenty grouped
+    products; no `copy`, `slice` or `dynamic-slice` whose result is a pool,
+    a state array, an expert bank or a layer of one, a
+    `dynamic-update-slice` of that size only as a chunk row's write of its
+    conv tail into its own state row; both pools in place."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.granite_hybrid import (
+        granite_hybrid_step_rows_ragged,
+    )
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+    from tpu_engine.ops.ssd import ssd_chunk_row, ssd_step_rows
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "granite-4.0-h-small-10l.json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    assert width in (1, serving["gen_prefill_chunk"])
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg = spec.config
+    assert (cfg.n_heads // cfg.kv_heads, cfg.d_head) == (4, 128)
+    assert (cfg.n_linear_layers, cfg.n_moe_layers, cfg.n_full_layers) == (
+        9, 10, 1)
+    assert cfg.state_row_shapes == ((128, 64, 128), (8, 3168))
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    (kind,) = cfg.kv_block_kinds
+    one = placed(jax.ShapeDtypeStruct(
+        (kind.n_layers, serving["gen_kv_blocks"], bs, kind.kv_lanes[0]),
+        jnp.bfloat16))
+    pools = (KVCache(one, one),
+             tuple(placed(jax.ShapeDtypeStruct(
+                 (cfg.n_linear_layers, rows + 1) + shape, jnp.float32))
+                 for shape in cfg.state_row_shapes))
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return granite_hybrid_step_rows_ragged(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=functools.partial(ragged_paged_attention,
+                                      interpret=False),
+            step_fn=functools.partial(ssd_step_rows, interpret=False),
+            chunk_fn=functools.partial(ssd_chunk_row, interpret=False),
+            sample_slot=jnp.zeros_like(pos0), held=spec.held,
+            max_tokens=serving["gen_prefill_chunk"] + rows)
+
+    def host(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    step, behind = _behind_a_step(tick, host(rows))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, (host(rows, -(-cfg.max_seq // bs)), host(rows)),
+        host(rows, width), host(rows), host(rows), *behind).compile()
+    hlo = compiled.as_text()
+    assert "_paged_call" in hlo and "ssd_step" in hlo
+    assert ("ssd_chunk" in hlo) == (width > 1)
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", hlo)) == 20
+    banks = [bp["mlp"]["experts"] for bp in params["layers"]]
+    assert len(banks) == 10
+    sizes = {math.prod(x.shape) for x in jax.tree.leaves(banks)}
+    for x in list(pools[0]) + list(pools[1]):
+        sizes |= {math.prod(x.shape), math.prod(x.shape[1:])}
+    movers = re.compile(r"= \w+\[([\d,]+)\]\S* "
+                        r"(copy|slice|dynamic-slice|dynamic-update-slice)\(")
+    moved = {op for dims, op in movers.findall(hlo)
+             if math.prod(map(int, dims.split(","))) in sizes}
+    assert moved <= ({"dynamic-update-slice"} if width > 1 else set()), moved
+    analysis = compiled.memory_analysis()
+    print("granite_hybrid step width", width, "temp bytes",
+          analysis.temp_size_in_bytes, "alias", analysis.alias_size_in_bytes)
+    assert analysis.temp_size_in_bytes < 1.0e9
+    assert analysis.alias_size_in_bytes > 3.8e9      # both pools in place
 
 
 @pytest.mark.parametrize("name,q_lens,width,grid", [

@@ -426,6 +426,6 @@ def _ensure_builtin_models_imported():
 
     for optional in ("bert", "gpt2", "llama", "yolo", "ssd", "moonlight",
                      "laguna", "olmo_hybrid", "kimi_linear", "falcon_h1",
-                     "nemotron_h", "sdar", "ouro", "lfm2"):
+                     "nemotron_h", "sdar", "ouro", "lfm2", "granite_hybrid"):
         if importlib.util.find_spec(f"tpu_engine.models.{optional}") is not None:
             importlib.import_module(f"tpu_engine.models.{optional}")
