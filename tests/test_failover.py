@@ -790,7 +790,10 @@ def test_gateway_resumes_past_recover_event(shared_worker):
     flaky = KillLane(shared_worker, "flaky")
     stable = RealLane(shared_worker, "stable")
     gw = make_gw([flaky, stable])
-    req = {"prompt_tokens": [3, 1, 4, 1, 5], "max_new_tokens": 12,
+    # Long enough that the lane, which steps ahead of the reader, cannot
+    # finish the stream before the failure is installed (12 tokens did,
+    # once in a loaded run: the stream ended whole, nothing to resume).
+    req = {"prompt_tokens": [3, 1, 4, 1, 5], "max_new_tokens": 48,
            "temperature": 0.7, "seed": 23}
     control = shared_worker.handle_generate(
         dict(req, request_id="ctl2"))["tokens"]

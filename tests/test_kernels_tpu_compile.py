@@ -408,6 +408,31 @@ def _mixed_tick(cfg, attn_fn, max_tokens=None):
     return tick
 
 
+def _input_products(hlo):
+    """[(name, result shape, opcode)] of the ENTRY computation's
+    instructions whose `op_name` ends in `mixer/in/dot_general`: a
+    recurrent layer's input projection and whatever XLA made of it, none
+    of them a COPY (a name with `remat` in it): XLA computes a projection
+    again for a consumer two parts away sooner than keep its result, where
+    a slice of it is fused into that consumer (`models.falcon_h1.
+    _ssm_inputs` writes the parts once, where the projection is split)."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    under = re.findall(
+        r"^\s*(?:ROOT )?%(\S+) = (\w+\[[\d,]*\])\S* ([\w-]+)\("
+        r".*op_name=\"[^\"]*mixer/in/dot_general\"", entry, re.M)
+    assert not [name for name, _, _ in under if "remat" in name], under
+    return under
+
+
+def _one_product_a_layer(hlo, lanes, layers):
+    """The projection's products, fusions of result f32[rows, `lanes`],
+    number the recurrent layers, and none is a copy."""
+    products = [name for name, shape, op in _input_products(hlo)
+                if op == "fusion"
+                and re.fullmatch(rf"f32\[\d+,{lanes}\]", shape)]
+    assert len(products) == layers, products
+
+
 def _behind_a_step(tick, rows):
     """(`tick` as a lane's compiled step calls it since PR 40, the shapes
     of what it takes besides): a row's first token and its end come from
@@ -927,6 +952,7 @@ def test_hybrid_mixed_step_copies_neither_the_pool_nor_the_states(v5e_devices,
     hlo = compiled.as_text()
     assert "_paged_call" in hlo and "gdn_step" in hlo
     assert ("gdn_chunk" in hlo) == (width > 1)
+    assert _input_products(hlo)                 # and none a copy
     sizes = set()
     for x in list(pools[0]) + list(pools[1]):
         sizes |= {math.prod(x.shape), math.prod(x.shape[1:])}
@@ -1010,6 +1036,7 @@ def test_latent_and_state_mixed_step_copies_no_pool_state_or_bank(v5e_devices,
     assert "mla_latent_read" in hlo and "kda_step" in hlo
     assert ("kda_chunk" in hlo) == (width > 1)
     assert '"gdn_step"' not in hlo and '"gdn_chunk"' not in hlo
+    assert _input_products(hlo)                 # and none a copy
     # A bank whole; a pool or a state array whole or a layer of it (ONE
     # expert's 2304 x 2048 is as many numbers as 128 rows' conv tails).
     banks = [bp["mlp"]["experts"] for bp in params["layers"][1:]]
@@ -1093,6 +1120,9 @@ def test_two_mixers_a_layer_mixed_step_copies_neither_pool(v5e_devices, width):
     hlo = compiled.as_text()
     assert "_paged_call" in hlo and "ssd_step" in hlo
     assert ("ssd_chunk" in hlo) == (width > 1)
+    w_in = params["layers"][0]["ssm"]["w_in"]["kernel"]
+    assert w_in.shape[1] == 9248
+    _one_product_a_layer(hlo, w_in.shape[1], cfg.n_linear_layers)
     sizes = set()
     for x in list(pools[0]) + list(pools[1]):
         sizes |= {math.prod(x.shape), math.prod(x.shape[1:])}
@@ -1179,6 +1209,7 @@ def test_one_mixer_a_layer_mixed_step_copies_no_pool_state_or_bank(
     hlo = compiled.as_text()
     assert "_paged_call" in hlo and "ssd_step" in hlo
     assert ("ssd_chunk" in hlo) == (width > 1)
+    assert _input_products(hlo)                 # and none a copy
     banks = [bp["mlp"]["experts"] for bp in params["layers"] if "mlp" in bp]
     assert len(banks) == 5
     sizes = {math.prod(x.shape) for x in jax.tree.leaves(banks)}
@@ -1263,6 +1294,7 @@ def test_conv_operator_mixed_step_copies_no_pool_tail_or_bank(v5e_devices,
         host(rows, width), host(rows), host(rows), *behind).compile()
     hlo = compiled.as_text()
     assert "_paged_call" in hlo
+    assert _input_products(hlo)                 # and none a copy
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", hlo)) == 16
     banks = [bp["mlp"]["experts"] for bp in params["layers"]
              if "experts" in bp["mlp"]]
@@ -1357,6 +1389,9 @@ def test_mixer_and_experts_a_layer_mixed_step_copies_no_pool_state_or_bank(
     hlo = compiled.as_text()
     assert "_paged_call" in hlo and "ssd_step" in hlo
     assert ("ssd_chunk" in hlo) == (width > 1)
+    w_in = params["layers"][0]["ssm"]["w_in"]["kernel"]
+    assert w_in.shape[1] == 16768
+    _one_product_a_layer(hlo, w_in.shape[1], cfg.n_linear_layers)
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", hlo)) == 20
     banks = [bp["mlp"]["experts"] for bp in params["layers"]]
     assert len(banks) == 10

@@ -317,6 +317,16 @@ def _ssm_inputs(sp, u, cfg: FalconH1Config, dtype):
     z = proj[..., :cfg.d_ssm]
     mixed = proj[..., cfg.d_ssm:cfg.d_ssm + cfg.conv_lanes]
     dt = jax.nn.softplus(proj[..., -cfg.lin_heads:] + sp["dt_bias"])
+    # A projection that several consumers read is materialised where it is
+    # split. `z` is read two parts later (`models.olmo_hybrid._linear_rows`:
+    # behind the state step and the chunk loop), `mixed` and dt by both of
+    # those: left as slices, XLA fuses each into its consumer and, sooner
+    # than keep W_in's product that long, computes the WHOLE product again
+    # for a far one, up to three times a layer of a chunk tick. Behind the
+    # barrier each part is written once
+    # (`tests/test_kernels_tpu_compile.py` `_one_product_a_layer` counts a
+    # compiled tick's products with no chip).
+    mixed, z, dt = jax.lax.optimization_barrier((mixed, z, dt))
     return mixed, z, dt, dt
 
 

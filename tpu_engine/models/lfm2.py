@@ -359,7 +359,9 @@ def _conv_rows(cp, r, tails, at: int, start, rows, row, slot, pos0, qlen,
     keeps it. Returns (the operator's output (M, d), tails)."""
     m, keep = r.shape[0], cfg.conv_width - 1
     with step_part("mixer/in"):
-        u, gate_c = _conv_inputs(cp, r, dtype)
+        # Written here, once: `gate_c` is read two parts later
+        # (`models.falcon_h1._ssm_inputs` says why).
+        u, gate_c = jax.lax.optimization_barrier(_conv_inputs(cp, r, dtype))
     with step_part("mixer/step"):
         old = jnp.where((pos0 == 0)[:, None, None], 0.0, tails[at, rows])
         at_list = jnp.arange(m)

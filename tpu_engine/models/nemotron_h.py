@@ -281,6 +281,8 @@ def _ssm_inputs(sp, u, cfg: NemotronHConfig, dtype):
     z = proj[..., :cfg.d_ssm]
     mixed = proj[..., cfg.d_ssm:cfg.d_ssm + cfg.conv_lanes]
     dt = jax.nn.softplus(proj[..., -cfg.lin_heads:] + sp["dt_bias"])
+    # Written here, once: `models.falcon_h1._ssm_inputs` says why.
+    mixed, z, dt = jax.lax.optimization_barrier((mixed, z, dt))
     return mixed, z, dt, dt
 
 

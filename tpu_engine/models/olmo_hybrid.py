@@ -302,10 +302,14 @@ def _linear_rows(lp, x, state, at: int, start, rows, pos0, qlen, width: int,
     tokens at [start[b], start[b] + qlen[b]); state: the state pool's
     (S (L_lin, R, H, d_v, d_k), conv tails (L_lin, R, width - 1, lanes) or
     as many numbers a row in another shape), row b's at `rows[b]` of layer
-    `at`. A row with ONE new token goes through `gdn_step_rows`, all such rows at once; a row with more through
-    `gdn_chunk_row`, a row at a time, from the state its last tick left
-    (zero where the row starts at position 0). Returns (the mixer's
-    output (M, d), state).
+    `at`. A row with ONE new token goes through `gdn_step_rows`, all such
+    rows at once; a row with more through `gdn_chunk_row`, a row at a time,
+    from the state its last tick left (zero where the row starts at
+    position 0). Returns (the mixer's output (M, d), state).
+
+    `z` is read two parts after `inputs` made it, behind the state step
+    and the chunk loop: an `inputs` that SPLITS one product into the parts
+    writes `z` where it splits (`models.falcon_h1._ssm_inputs` says why).
 
     `inputs`, `output`: this family's `_lin_inputs` and `_lin_output`, or
     another's of their signatures whose `cfg` has the same `lin_*` and
