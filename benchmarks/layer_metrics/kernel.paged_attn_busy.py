@@ -1,17 +1,16 @@
-"""Share of the device's busy time spent in the paged-attention Pallas
-kernel, in percent: the trace's operations whose name carries the kernel's
-name, over the union of all operation intervals. Layer: kernels. Moves
-tokens_per_s."""
+"""Share of the device's busy time spent in the paged read, the Pallas call
+that attends a tick's rows over the block pool, in percent: the trace's
+operations whose name carries the call's name, over the union of all
+operation intervals. Every family's read goes through the pool, and
+`sizes(run["config"])["attention"]["kernel"]` (lib/roofline_sizes.py) says
+which call the configuration's step makes: `_paged_call`
+(tpu_engine/ops/paged_attention.py; Mosaic names the custom call after it),
+`block_mask_read` there where rows decode by blocks, `mla_latent_read`
+(ops/latent_attention.py) where the pool holds a latent. Layer: kernels.
+Moves tokens_per_s."""
 
-# The kernel body in tpu_engine/ops/paged_attention.py is `_paged_kernel`;
-# Mosaic names the custom call after it.
-PATTERN = "paged"
+from lib.roofline_kinds import busy_share
 
 
 def compute(run):
-    trace = run["trace"]
-    if not trace or not trace["busy_s"]:
-        return None
-    seconds = sum(s for name, s in trace["op_seconds"].items()
-                  if PATTERN in name.lower())
-    return 100.0 * seconds / trace["busy_s"]
+    return busy_share(run, "attention")

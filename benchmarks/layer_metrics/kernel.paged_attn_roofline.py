@@ -1,56 +1,37 @@
-"""The paged-attention kernel against its roofline, in percent: the time
-one chip needs at its peaks for the work the kernel could not avoid, over
-the kernel's measured self seconds in the traced slice (the same ops
-`kernel.paged_attn_busy` sums). Layer: kernels. Moves tokens_per_s.
+"""The paged read against its roofline, in percent: the time one chip
+needs at its peaks for the work the read could not avoid, over its
+measured self seconds in the traced slice (the ops `kernel.paged_attn_busy`
+sums). Layer: kernels. Moves tokens_per_s.
 
 The work, from the `mixed_step` spans of the ticks that ran WHOLLY inside
 the slice (`run["slice"]`; a tick cut by an edge of the slice has part of
 its kernel time outside the trace, so it is left out whole) and the sizes
-of `run["config"]` (lib/roofline.py):
+of `run["config"]` (lib/roofline_sizes.py, lib/roofline_kinds.py):
 
-  bytes   `ctx_tokens` x layers x 2 x H_kv*D x the pool's bytes an element:
-          every context token's K and V read once a layer
-  FLOPs   `ctx_tokens` (query, key) pairs: each row's newest query against
-          its context. Exact in a width-1 tick. A wider tick's chunk of c
-          queries after w tokens attends c*w + c*(c+1)/2 pairs, but the
-          span says neither which rows hold chunks nor how many, so only
-          the last query of each is counted: an under-count, of FLOPs
-          alone (the bytes are whole), by about the chunk's width. So the
-          metric is listed only for cells whose ticks are mostly width 1:
-          where every tick carries a chunk the share would come from the
-          bytes alone and could not move with the chunked kernel's speed.
+  bytes   context tokens x layers that attend (planes, under a loop) x the
+          lanes a token takes in the pool (K and V of the KV heads, or a
+          latent and its rope key) x the pool's bytes an element: every
+          context token read once a layer, however many query tiles of a
+          chunk walk it again
+  FLOPs   (query, key) pairs x layers x query heads x 4 x head size (over a
+          latent: 2 x ((latent + rope) + latent)). Pairs: each row's newest
+          query against its context, exact in a width-1 tick. A wider
+          tick's chunk of c queries after w tokens attends c*w + c*(c+1)/2
+          pairs, but the span says neither which rows hold chunks nor how
+          many, so only the last query of each is counted: an under-count,
+          of FLOPs alone (the bytes are whole), by about the chunk's width.
+          Under a block-causal mask the lane counts the pairs
+          (`attn_pairs`) and they are whole.
 
-Under-counted throughout (queries, outputs and cache writes are not in the
-bytes), so the share reads a little low and never high. Over several
-lanes: the work of all lanes' ticks over the number of device planes,
-against the kernel's seconds a plane (lib/xplane_reduce.py averages)."""
+So the metric is listed only for cells some of whose ticks are width 1 or
+whose read is bound by its bytes: where every tick carries a chunk of an
+MHA model the share would come from the bytes alone and could not move
+with the chunked kernel's speed. Under-counted throughout (queries,
+outputs and cache writes are not in the bytes), so the share reads a
+little low and never high."""
 
-from lib import roofline
-from lib.metrics import lane_spans
-
-# As kernel.paged_attn_busy: Mosaic names the custom call after the kernel
-# body `_paged_kernel` in tpu_engine/ops/paged_attention.py.
-PATTERN = "paged"
+from lib.roofline_kinds import attention_roofline
 
 
 def compute(run):
-    trace, window, peaks = run["trace"], run.get("slice"), run["peaks"]
-    if not trace or not trace["busy_s"] or not window or not peaks:
-        return None
-    kernel_s = sum(s for name, s in trace["op_seconds"].items()
-                   if PATTERN in name.lower())
-    ctx_tokens = sum(
-        s["attrs"]["ctx_tokens"] for s in lane_spans(run, "mixed_step")
-        if "ctx_tokens" in s["attrs"] and "start_ts" in s
-        and window["begin"] <= s["start_ts"]
-        and s["start_ts"] + s["duration_us"] / 1e6 <= window["end"])
-    if not kernel_s or not ctx_tokens:
-        return None
-    size = roofline.attention_sizes(run["config"])
-    floor_s = roofline.floor_seconds(
-        roofline.attention_bytes(ctx_tokens, size["layers"], size["kv_heads"],
-                                 size["head_dim"], size["bytes_per_element"]),
-        roofline.attention_flops(ctx_tokens, size["layers"], size["heads"],
-                                 size["head_dim"]),
-        peaks)
-    return 100.0 * floor_s / trace["planes"] / kernel_s
+    return attention_roofline(run)

@@ -1,10 +1,9 @@
-"""Bytes of recurrent state held over bytes of K/V held, at the K/V pool's
-fullest sample, on a lane whose rows own both: `state_bytes_held` /
-`kv_bytes_held` of the pool's counters (sampled every half second, one
-reading of the two). How much of what the rows hold no longer grows with
-their length: at full depth a hybrid's point; here 9 state layers of 2.35 MB
-stand beside 3 K/V layers of 15 KB a token. Layer: state pool. Moves
-tokens_per_s."""
+"""Bytes of fixed state held over bytes of cache held (K and V, or a
+latent), at the block pool's fullest sample, on a lane whose rows own both:
+`state_bytes_held` / `kv_bytes_held` of the pool's counters (sampled every
+half second, one reading of the two). How much of what the rows hold no
+longer grows with their length: at full depth a hybrid's point. Layer:
+state pool. Moves tokens_per_s."""
 
 
 def compute(run):

@@ -5,7 +5,8 @@ the kernel's measured seconds.
 Kept with the benchmark, so that no later PR changes what a kernel is held
 against. Each function is a pure function of sizes (tests pin them by
 hand-computed cases); the sizes come from the configuration file the run
-object carries (`run["config"]`) and from what a span carries.
+object carries (`run["config"]`, through `lib/roofline_sizes.py` `sizes`)
+and from what a span carries.
 
 Count only work no implementation could avoid. Padding, a second pass over
 the same bytes, a layout copy: all avoidable, none counted. Where a span
@@ -15,21 +16,6 @@ it.
 """
 
 DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
-
-
-def attention_sizes(config):
-    """What the attention counts need, from a configuration file's dict:
-    the factory's keyword arguments as run (`n_layers`, `n_heads`,
-    `n_kv_heads` if grouped, `d_head` if it is not d_model / n_heads) and
-    the lane's pool type (`serving.gen_kv_quantize`, else `serving.dtype`)."""
-    kwargs, serving = config["kwargs"], config["serving"]
-    heads = int(kwargs["n_heads"])
-    return {"layers": int(kwargs["n_layers"]), "heads": heads,
-            "kv_heads": int(kwargs.get("n_kv_heads", heads)),
-            "head_dim": int(kwargs.get("d_head",
-                                       int(kwargs["d_model"]) // heads)),
-            "bytes_per_element": DTYPE_BYTES[
-                serving.get("gen_kv_quantize") or serving["dtype"]]}
 
 
 def attention_bytes(ctx_tokens, layers, kv_heads, head_dim,

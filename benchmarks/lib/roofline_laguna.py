@@ -1,12 +1,12 @@
-"""The operations and bytes that the kernels of a model with window and
-full attention layers and a held share of routed experts cannot avoid:
-what `kernel.swa_attn_roofline`, `kernel.full_attn_roofline` and
-`kernel.moe_held_roofline` divide by the kernels' measured seconds
-(`lib/roofline.py` has the rules, the attention counts and `floor_seconds`;
-`lib/roofline_moe_mla.py` the expert counts, the kernel's seconds and the
-ticks wholly inside the slice; this file adds what is this model's own and
-edits nothing there). Pure functions of sizes, pinned by hand-computed
-cases.
+"""The operations and bytes that the TWO attention reads of a model with
+window and full attention layers cannot avoid: what
+`kernel.swa_attn_roofline` and `kernel.full_attn_roofline` divide by the
+kernels' measured seconds (`lib/roofline.py` has the rules, the attention
+counts and `floor_seconds`; `lib/roofline_moe_mla.py` the kernel's seconds
+and the ticks wholly inside the slice; the held experts' product is
+`kernel.moe_experts_roofline`'s, `lib/roofline_kinds.py`). One cell reads
+two classes of attention, so these readers are its own and take their
+sizes here. Pure functions of sizes, pinned by hand-computed cases.
 
 Count only what no implementation could avoid. A window layer reads, for a
 row, the keys and values its new tokens still see, ONCE (the span's

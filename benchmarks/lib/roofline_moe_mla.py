@@ -1,19 +1,17 @@
 """The operations and bytes that a latent-attention read and a grouped
-expert product cannot avoid: what `kernel.mla_attn_roofline` and
-`kernel.moe_experts_roofline` divide by the kernels' measured seconds
-(`lib/roofline.py` has the rules and `floor_seconds`; this file adds the
-counting for the two kernels of a latent, routed model and edits nothing
-there). Pure functions of sizes, pinned by hand-computed cases.
+expert product cannot avoid, and what every reader of a kernel takes from a
+trace: the kernel's seconds, its share of the busy time, and the ticks that
+ran wholly inside the traced slice (`lib/roofline.py` has the rules and
+`floor_seconds`). Pure functions of sizes, pinned by hand-computed cases.
 
 Count only what no implementation could avoid. The latent pool stores 640
 lanes a token where 576 are used: 576 are counted. The grouped product
-reads an expert's three matrices once however many row tiles it takes, and
-pads no row: touched experts and real assignments are counted. So a share
-reads low and never over 100 %.
+reads an expert's matrices once however many row tiles it takes, and pads
+no row: touched experts and real assignments are counted. So a share reads
+low and never over 100 %.
 """
 
 from lib.metrics import lane_spans
-from lib.roofline import DTYPE_BYTES
 
 
 def kernel_seconds(run, pattern):
@@ -27,6 +25,13 @@ def kernel_seconds(run, pattern):
                if pattern in name.lower()) or None
 
 
+def busy_share(run, pattern):
+    """Percent of the device's busy time in the operations whose name
+    carries `pattern`; None where the trace holds no such operation."""
+    seconds = kernel_seconds(run, pattern)
+    return 100.0 * seconds / run["trace"]["busy_s"] if seconds else None
+
+
 def whole_ticks(run):
     """The attrs of the `mixed_step` spans of the ticks that ran WHOLLY
     inside the traced slice (a tick cut by an edge of the slice has part
@@ -37,19 +42,6 @@ def whole_ticks(run):
     return [s["attrs"] for s in lane_spans(run, "mixed_step")
             if "start_ts" in s and window["begin"] <= s["start_ts"]
             and s["start_ts"] + s["duration_us"] / 1e6 <= window["end"]]
-
-
-def sizes(config):
-    """What the counts need, from a configuration file's dict: the
-    factory's keyword arguments as run and the lane's type."""
-    kwargs = config["kwargs"]
-    return {"layers": int(kwargs["n_layers"]),
-            "heads": int(kwargs["n_heads"]),
-            "latent": int(kwargs["kv_lora_rank"]),
-            "rope": int(kwargs["qk_rope"]),
-            "d_model": int(kwargs["d_model"]),
-            "d_expert": int(kwargs["d_ff_expert"]),
-            "bytes_per_element": DTYPE_BYTES[config["serving"]["dtype"]]}
 
 
 def latent_bytes(ctx_tokens, layers, latent, rope, bytes_per_element):
@@ -66,13 +58,15 @@ def latent_flops(pairs, layers, heads, latent, rope):
     return pairs * layers * heads * 2 * ((latent + rope) + latent)
 
 
-def expert_bytes(experts_touched, d_model, d_expert, bytes_per_element):
-    """Bytes of expert weights read: the gate, up and down matrices of
-    every (layer, expert) that took at least one row, once."""
-    return experts_touched * 3 * d_model * d_expert * bytes_per_element
+def expert_bytes(experts_touched, rows, cols, bytes_per_element, matrices=3):
+    """Bytes of expert weights read: the `matrices` matrices of rows x cols
+    (gate, up and down in the model's width; up and down where the experts
+    work in a latent) of every (layer, expert) that took at least one row,
+    once."""
+    return experts_touched * matrices * rows * cols * bytes_per_element
 
 
-def expert_flops(assignments, d_model, d_expert):
+def expert_flops(assignments, rows, cols, matrices=3):
     """Floating-point operations of the routed experts: a (token, expert)
-    assignment is three matrix-vector products of d_model x d_expert."""
-    return assignments * 3 * 2 * d_model * d_expert
+    assignment is `matrices` matrix-vector products of rows x cols."""
+    return assignments * matrices * 2 * rows * cols

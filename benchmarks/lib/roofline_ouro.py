@@ -1,11 +1,11 @@
 """The bytes and operations that the step of a LOOPED model (one stack of
 layers applied `ut_steps` times over one set of weights, a cache plane a
-(pass, layer)) cannot avoid: what `kernel.mha16_attn_roofline` and
-`step.loop_decode_hbm_roofline` divide (`lib/roofline.py` has the rules and
-`floor_seconds`; `lib/roofline_moe_mla.py` the seconds of a kernel with a
-name of its own and the ticks wholly inside the slice; this file adds what
-is this model's own and edits nothing there). The counts are pure functions
-of sizes, pinned by hand-computed cases.
+(pass, layer)) cannot avoid: what `step.loop_decode_hbm_roofline` divides
+(`lib/roofline.py` has the rules; `lib/roofline_moe_mla.py` the ticks wholly
+inside the slice; the paged read's own roofline is
+`kernel.paged_attn_roofline`'s, `lib/roofline_kinds.py`, which counts a plane
+as a layer). The counts are pure functions of sizes, pinned by hand-computed
+cases.
 
 Count only what no implementation could avoid.
 
@@ -24,12 +24,9 @@ Count only what no implementation could avoid.
 Under-counted throughout, so a share reads low and never over 100 %.
 """
 
-from lib import roofline, roofline_moe_mla
+from lib import roofline_moe_mla
 from lib.metrics import percentile
 from lib.roofline import DTYPE_BYTES
-
-# The Pallas call behind every paged read is named after `_paged_call`.
-PAGED = "paged"
 
 
 def sizes(config):
@@ -68,14 +65,6 @@ def read_bytes(ctx_tokens, planes, size):
     return ctx_tokens * planes * plane_token_bytes(size)
 
 
-def read_flops(ctx_tokens, planes, size):
-    """A (query, key) pair a context token of a row with one new token (an
-    under-count for a chunk), every plane and head: the score and the
-    weighted value."""
-    return roofline.attention_flops(ctx_tokens, planes, size["heads"],
-                                    size["head_dim"])
-
-
 def decode_tick_bytes(ctx_tokens, passes, planes, size):
     """What a decode-only tick must move (module docstring)."""
     return (passes * size["layers"] * layer_bytes(size)
@@ -86,31 +75,6 @@ def looped_ticks(run):
     """The attrs of the ticks wholly inside the traced slice that ran a
     looped model's step (`kv_planes` on the span)."""
     return [a for a in roofline_moe_mla.whole_ticks(run) if "kv_planes" in a]
-
-
-def busy_share(run):
-    """Percent of the device's busy time in the paged read's calls, on a
-    looped lane; None elsewhere."""
-    if not looped_ticks(run):
-        return None
-    seconds = roofline_moe_mla.kernel_seconds(run, PAGED)
-    return 100.0 * seconds / run["trace"]["busy_s"] if seconds else None
-
-
-def attention_roofline(run):
-    """Percent of its roofline that the paged reads reach: the floor
-    seconds of the keys and values the slice's whole ticks had to read,
-    against the calls' self seconds there."""
-    ticks = looped_ticks(run)
-    seconds = roofline_moe_mla.kernel_seconds(run, PAGED)
-    if not ticks or not seconds or not run["peaks"]:
-        return None
-    size = sizes(run["config"])
-    floor_s = sum(roofline.floor_seconds(
-        read_bytes(a.get("ctx_tokens", 0), a["kv_planes"], size),
-        read_flops(a.get("ctx_tokens", 0), a["kv_planes"], size),
-        run["peaks"]) for a in ticks)
-    return 100.0 * floor_s / run["trace"]["planes"] / seconds
 
 
 def decode_hbm_roofline(run, run_ms):

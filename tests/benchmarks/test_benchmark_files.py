@@ -167,10 +167,9 @@ def test_a_metric_bound_to_a_mechanism_names_its_cells(bench):
     without = [m["name"] for m in bench["per_layer"] if "workloads" not in m]
     assert sorted(without) == [
         "device.hbm_peak_gb", "device.idle", "device.idle_host",
-        "sched.decode_rows_per_tick", "sched.host_gap_ms",
+        "sched.decode_rows_per_tick",
         "sched.itl_prefill_share", "sched.prefill_tick_share",
-        "step.compiles",
-        "step.prefill_device_ms", "step.prefill_ms"]
+        "step.compiles", "step.prefill_ms"]
 
 
 def test_every_cell_reports_what_the_contract_asks(bench):
@@ -187,7 +186,12 @@ def test_every_cell_reports_what_the_contract_asks(bench):
 
 
 def test_per_layer_metrics_have_readers_and_one_spelling_per_layer(bench):
+    # 72 since PR 68 (a reader a KIND of kernel, pool and counter); what is
+    # left under the contract's 128 is the room of the PRs that add readers.
     assert 1 <= len(bench["per_layer"]) <= 128
+    assert sorted(m["name"] + ".py" for m in bench["per_layer"]) == sorted(
+        name for name in os.listdir(os.path.join(BENCH, "layer_metrics"))
+        if name.endswith(".py"))
     for m in bench["per_layer"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
                                           "layer", "moves"}
@@ -200,6 +204,38 @@ def test_per_layer_metrics_have_readers_and_one_spelling_per_layer(bench):
                    for n in tree.body), path
     layers = {m["layer"] for m in bench["per_layer"]}
     assert len({layer.lower() for layer in layers}) == len(layers)
+
+
+def _code_outside_docstrings(path):
+    """The file's text with its docstrings (the module's, a function's, a
+    class's) blanked: its code and its comments."""
+    with open(path) as f:
+        source = f.read()
+    lines = source.splitlines()
+    for node in ast.walk(ast.parse(source)):
+        if isinstance(node, (ast.Module, ast.FunctionDef, ast.ClassDef)) \
+                and ast.get_docstring(node, clean=False) is not None:
+            doc = node.body[0]
+            for n in range(doc.lineno - 1, doc.end_lineno):
+                lines[n] = ""
+    return "\n".join(lines)
+
+
+def test_no_reader_names_a_cell(bench):
+    """A reader, or a library it counts with, that branched on which cell
+    or configuration it serves would have to be edited for the next one
+    (ISSUE 68): every size comes from what `run["config"]` STATES
+    (lib/roofline_sizes.py). A docstring may say where a number was seen."""
+    names = [e["name"] for kind in ("configs", "workloads")
+             for e in bench[kind]]
+    for folder in ("layer_metrics", "lib"):
+        for file_name in sorted(os.listdir(os.path.join(BENCH, folder))):
+            if not file_name.endswith(".py"):
+                continue
+            code = _code_outside_docstrings(
+                os.path.join(BENCH, folder, file_name))
+            for name in names:
+                assert name not in code, f"{folder}/{file_name}: {name!r}"
 
 
 def test_widths_are_the_sources(bench):

@@ -1,8 +1,12 @@
-"""The ten per-layer readers PR 46 lists for `falcon-h1-34b-6l.converse`
-(`kernel.ssd_step_*`, `kernel.ssd_chunk_*`, `kernel.gqa5_attn_*`,
-`state.ssd_rows_peak_share`, `state.ssd_bytes_over_kv_bytes`,
-`kv.ssd_blocks_peak_share`, `step.ssd_decode_ms`) on a made-up run, and the
-counting of lib/roofline_falcon_h1.py by hand-computed cases.
+"""The ten merged per-layer readers `falcon-h1-34b-6l.converse` is listed on
+since PR 68 (`kernel.state_step_*`, `kernel.state_chunk_*`,
+`kernel.paged_attn_*`, `state.rows_peak_share`,
+`state.bytes_over_cache_bytes`, `kv.blocks_peak_share`, `step.decode_ms`),
+on the made-up run and at the hand-computed values that pinned PR 46's
+copies of them (`kernel.ssd_*`, `kernel.gqa5_attn_*`, `state.ssd_*`,
+`kv.ssd_blocks_peak_share`, `step.ssd_decode_ms`): the merged readers at
+THIS configuration's sizes. And the counting of lib/roofline_falcon_h1.py by
+hand-computed cases.
 
 `WANT` is this file's part of the table of pins: the hook in
 tests/conftest.py joins every `test_benchmark_layer_metrics_*.py`'s `WANT`
@@ -18,7 +22,8 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from bench_paths import BENCH  # noqa: E402
 
-from lib import roofline, roofline_falcon_h1  # noqa: E402
+from lib import roofline, roofline_falcon_h1, roofline_gated_delta  # noqa: E402
+from lib.roofline_sizes import sizes  # noqa: E402
 
 V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 CELL = "falcon-h1-34b-6l.converse"
@@ -91,31 +96,31 @@ RUN = {
                      _pool(2.0, 1200, 64)],
 }
 WANT = {
-    "kernel.ssd_step_busy": 24.0,
-    "kernel.ssd_chunk_busy": 2.0,
-    "kernel.gqa5_attn_busy": 8.0,
+    "kernel.state_step_busy": 24.0,
+    "kernel.state_chunk_busy": 2.0,
+    "kernel.paged_attn_busy": 8.0,
     # 120 rows x 6 layers x (2 x 4.19 MB + 37 KB) = 6.07 GB: 7.41 ms at the
     # HBM peak (the recurrence's 0.09 TFLOP take 0.5 ms), of 0.6 s
-    "kernel.ssd_step_roofline":
+    "kernel.state_step_roofline":
         100 * (6 * 120 * (2 * STATE + TOKEN) / 819e9) / 0.6,
     # 2 rows x 6 layers x 2 x 4.19 MB and 300 tokens x 6 x 37 KB
-    "kernel.ssd_chunk_roofline":
+    "kernel.state_chunk_roofline":
         100 * (6 * (2 * 2 * STATE + 300 * TOKEN) / 819e9) / 0.05,
     # 61 000 tokens x 6 layers x 2 x 4 x 128 x 2 B = 0.75 GB: 0.92 ms; their
     # FLOPs (x 20 heads x 4 x 128) 3.7 GFLOP: 0.02 ms. Of 0.2 s
-    "kernel.gqa5_attn_roofline":
+    "kernel.paged_attn_roofline":
         100 * (61000 * 6 * 2048 / 819e9) / 0.2,
-    "state.ssd_rows_peak_share": 93.75,
+    "state.rows_peak_share": 93.75,
     # 60 rows x 25.5 MB over 1536 blocks x 196,608 B
-    "state.ssd_bytes_over_kv_bytes": 60 * ROW / (1536 * BLOCK),
-    "kv.ssd_blocks_peak_share": 25.0,
-    "step.ssd_decode_ms": 17.0,
+    "state.bytes_over_cache_bytes": 60 * ROW / (1536 * BLOCK),
+    "kv.blocks_peak_share": 25.0,
+    "step.decode_ms": 17.0,
 }
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_reader_arithmetic(name):
-    assert _reader(name)(RUN) == pytest.approx(WANT[name])
+    assert _reader(name)(RUN) == pytest.approx(WANT[name], rel=1e-9)
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
@@ -126,58 +131,50 @@ def test_no_share_of_the_made_up_run_passes_its_peak(name):
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_a_reader_finds_nothing_in_a_program_without_the_mechanism(name):
-    """The parent's program on its nearest cell: a state pool beside a pool
-    of K and V of another width under another configuration, `gdn_*` and
-    `ctx_tokens_full` on its spans, kernels named after the delta rule; and
-    a run with no trace. A reader returns None and does not raise."""
-    run = dict(RUN, config=OTHER, trace=dict(RUN["trace"], op_seconds={
-        "%gdn_step (tuple)": 0.3, "%gdn_chunk (tuple)": 0.2}))
-    run["spans"] = {"gateway": [], "worker_1": [
-        _tick(100.5, 50, gdn_chunk_tokens=241, gdn_chunk_rows=1,
-              gdn_step_rows=15, ctx_tokens_full=48000),
-        _tick(100.6, 20, width=1, gdn_step_rows=16)]}
-    run["stats_after"] = {"worker_1": {
-        "mixed": {"ticks": 9},
-        "state_pool": {"rows_total": 16, "rows_peak": 12},
-        "kv_pool": {"kv_bytes_held": 5, "state_bytes_held": 7,
-                    "block_lanes": [3840, 3840]}}}
-    run["pool_samples"] = [_pool(1.0, 4352, 15, lanes=(3840, 3840)),
-                           {"t": 1.5, "kv_pool": {"worker_1": {
-                               "blocks_total": 8704, "blocks_free": 100,
-                               "kv_bytes_held": 5, "state_bytes_held": 7}}}]
+    """A program that states these sizes and runs none of it: no kernel of
+    the recurrence or the read in its trace, no counter on its spans, no
+    state pool and no sample of a block pool; and a run with no trace. A
+    reader returns None and does not raise."""
+    run = dict(RUN, trace=dict(RUN["trace"], op_seconds={
+        "%fusion f32[64,261120]": 0.4}))
+    run["spans"] = {"gateway": [], "worker_1": [_tick(100.5, 50, width=256)]}
+    run["stats_after"] = {"worker_1": {"mixed": {"ticks": 9}}}
+    run["pool_samples"] = [{"t": 1.0, "kv_pool": {"worker_1": None}}]
     assert _reader(name)(run) is None
     run["trace"] = run["slice"] = run["peaks"] = None
     assert _reader(name)(run) is None
 
 
-def test_the_pool_readers_tell_this_lane_by_its_blocks_and_configuration():
-    """`holds_ssd`: the configuration states a Mamba-2 recurrence, the lane
-    reports state bytes beside its blocks, a block holds 4 KV heads x 128
-    lanes of K and of V."""
-    pool = RUN["pool_samples"][0]["kv_pool"]["worker_1"]
-    assert roofline_falcon_h1.holds_ssd(pool, CONFIG)
-    assert not roofline_falcon_h1.holds_ssd(pool, OTHER)
-    assert not roofline_falcon_h1.holds_ssd(
-        dict(pool, block_lanes=[128, 512]), CONFIG)
-    assert not roofline_falcon_h1.holds_ssd(
-        {k: v for k, v in pool.items() if k != "state_bytes_held"}, CONFIG)
-    assert not roofline_falcon_h1.holds_ssd(None, CONFIG)
+def test_the_readers_take_this_configuration_s_sizes_not_another_s():
+    """The same spans and trace under the configuration whose recurrence is
+    a delta rule: the step readers look for `gdn_step`, which this trace
+    does not hold, and the paged read is counted at 30 KV heads in 3 layers
+    where this configuration has 4 in 6."""
+    other = dict(RUN, config=OTHER)
+    assert _reader("kernel.state_step_busy")(other) is None
+    assert _reader("kernel.state_step_roofline")(other) is None
+    assert _reader("kernel.paged_attn_roofline")(other) == pytest.approx(
+        WANT["kernel.paged_attn_roofline"] * (3 * 30) / (6 * 4), rel=1e-9)
 
 
-# -- the counting ----------------------------------------------------------------
+SIZE = sizes(CONFIG)["recurrence"]
+
 
 def test_sizes_of_the_configuration_as_run():
-    assert roofline_falcon_h1.sizes(CONFIG) == {
-        "layers": 6, "heads": 20, "kv_heads": 4, "head_dim": 128,
-        "ssm_heads": 32, "ssm_head_dim": 128, "d_state": 256, "groups": 2,
-        "bytes_per_element": 2}
+    assert sizes(CONFIG) == {
+        "attention": {"kernel": "paged", "layers": 6, "heads": 20,
+                      "kv_heads": 4, "head_dim": 128, "lanes": 1024,
+                      "bytes_per_element": 2},
+        "experts": None,
+        "recurrence": {"kind": "ssd", "layers": 6, "heads": 32,
+                       "state": (128, 256), "groups": 2, "step": "ssd_step",
+                       "chunk": "ssd_chunk"}}
 
 
 def test_a_state_is_4_19_mb_and_a_token_of_k_v_12_288_bytes():
     """ISSUE 46's figures: 32 x 128 x 256 float32 a row and layer; 2 x 4 x
     128 x 2 B a token and layer, six layers."""
-    size = roofline_falcon_h1.sizes(CONFIG)
-    assert roofline_falcon_h1.state_bytes(size) == STATE == 4194304
+    assert roofline_gated_delta.state_bytes(SIZE) == STATE == 4194304
     assert roofline.attention_bytes(1, 6, 4, 128, 2) == 12288
     assert ROW == 25534464 and BLOCK == 16 * 12288
 
@@ -186,11 +183,10 @@ def test_a_decode_tick_s_steps_are_bound_by_their_states():
     """64 rows x 6 layers: 2 x 4.19 MB of state each and 37 KB of x, dt, B,
     C and read, 3.24 GB, 3.95 ms at the HBM peak (ISSUE 46's 3.9 ms); 2 x 2
     x 32 x 128 x 256 operations a row and layer, 1.6 GFLOP, 8 us."""
-    size = roofline_falcon_h1.sizes(CONFIG)
-    n_bytes = roofline_falcon_h1.recurrence_bytes(64, 64, size)
+    n_bytes = roofline_falcon_h1.recurrence_bytes(64, 64, SIZE)
     assert n_bytes == 64 * 6 * (2 * STATE + TOKEN)
     assert TOKEN == (32 * 257 + 1024) * 4
-    flops = roofline_falcon_h1.recurrence_flops(64, size)
+    flops = roofline_falcon_h1.recurrence_flops(64, SIZE)
     assert flops == 64 * 6 * 32 * 4 * 128 * 256
     assert roofline.floor_seconds(n_bytes, flops, V5E) == pytest.approx(
         n_bytes / 819e9)
@@ -199,16 +195,17 @@ def test_a_decode_tick_s_steps_are_bound_by_their_states():
 
 
 def test_a_chunk_s_state_is_read_once_a_row_not_once_a_token():
-    size = roofline_falcon_h1.sizes(CONFIG)
-    one = roofline_falcon_h1.recurrence_bytes(1, 200, size)
+    one = roofline_falcon_h1.recurrence_bytes(1, 200, SIZE)
     assert one == 6 * (2 * STATE + 200 * TOKEN)
-    assert one < roofline_falcon_h1.recurrence_bytes(200, 200, size) / 5
+    assert one < roofline_falcon_h1.recurrence_bytes(200, 200, SIZE) / 5
 
 
-def test_the_attention_roofline_counts_this_family_s_ticks_alone():
-    """A tick of another lane (no `ssd_step_rows` on its span) adds no
-    context to the paged reads' floor."""
-    run = dict(RUN, spans={"gateway": [], "worker_1": RUN["spans"]["worker_1"]
-                           + [_tick(101.5, 10, ctx_tokens_full=10 ** 6)]})
-    assert _reader("kernel.gqa5_attn_roofline")(run) == pytest.approx(
-        WANT["kernel.gqa5_attn_roofline"])
+def test_a_tick_s_context_is_read_under_the_name_the_lane_notes_it_by():
+    """A lane with state rows notes what its attending layers read as
+    `ctx_tokens_full` beside every tick's `ctx_tokens`: the same number,
+    read once."""
+    run = dict(RUN, spans={"gateway": [], "worker_1": [
+        dict(s, attrs=dict(s["attrs"], ctx_tokens=s["attrs"].get(
+            "ctx_tokens_full", 0))) for s in RUN["spans"]["worker_1"]]})
+    assert _reader("kernel.paged_attn_roofline")(run) == pytest.approx(
+        WANT["kernel.paged_attn_roofline"], rel=1e-9)

@@ -1,12 +1,16 @@
-"""The eleven per-layer readers PR 44 lists for
-`kimi-linear-48b-a3b-5l.reason` (`kernel.kda_step_*`,
-`kernel.mla_nope_attn_*`, `kernel.moe_held2304_*`,
-`moe.held_rows_per_touched_expert`, `state.kda_rows_peak_share`,
-`state.kda_bytes_over_latent_bytes`, `kv.latent_state_blocks_peak_share`,
-`step.kda_decode_ms`) and the two it brings and lists for no cell yet
-(`kernel.kda_chunk_*`: the cell's traced slice holds no chunk tick) on a
-made-up run, and the counting of lib/roofline_kimi_linear.py by
-hand-computed cases.
+"""The eleven merged per-layer readers `kimi-linear-48b-a3b-5l.reason` is
+listed on since PR 68 (`kernel.state_step_*`, `kernel.paged_attn_*`,
+`kernel.moe_experts_*`, `moe.rows_per_touched_expert`,
+`state.rows_peak_share`, `state.bytes_over_cache_bytes`,
+`kv.blocks_peak_share`, `step.decode_ms`) and the chunked form's pair, which
+the cell is not listed on (its traced slice holds no chunk tick), on the
+made-up run and at the hand-computed values that pinned PR 44's copies of
+them (`kernel.kda_*`, `kernel.mla_nope_attn_*`, `kernel.moe_held2304_*`,
+`moe.held_rows_per_touched_expert`, `state.kda_*`,
+`kv.latent_state_blocks_peak_share`, `step.kda_decode_ms`): the merged
+readers at THIS configuration's sizes (a delta rule gated by channel, a
+latent pool, half the experts held). And the counting of a channel-gated
+state by hand-computed cases.
 
 `WANT` is this file's part of the table of pins: the hook in
 tests/conftest.py joins every `test_benchmark_layer_metrics_*.py`'s `WANT`
@@ -22,7 +26,8 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from bench_paths import BENCH  # noqa: E402
 
-from lib import roofline, roofline_gated_delta, roofline_kimi_linear  # noqa: E402
+from lib import roofline, roofline_gated_delta, roofline_moe_mla  # noqa: E402
+from lib.roofline_sizes import sizes  # noqa: E402
 
 V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 CELL = "kimi-linear-48b-a3b-5l.reason"
@@ -103,36 +108,36 @@ RUN = {
                      _pool(2.0, 20000, 128)],
 }
 WANT = {
-    "kernel.kda_step_busy": 16.0,
-    "kernel.kda_chunk_busy": 8.0,
-    "kernel.mla_nope_attn_busy": 4.0,
-    "kernel.moe_held2304_busy": 40.0,
+    "kernel.state_step_busy": 16.0,
+    "kernel.state_chunk_busy": 8.0,
+    "kernel.paged_attn_busy": 4.0,
+    "kernel.moe_experts_busy": 40.0,
     # 220 rows x 4 layers x (2 x 2.10 MB + 80 KB) = 3.76 GB: 4.59 ms at the
     # HBM peak (the recurrence's 0.09 TFLOP take 0.4 ms), of 0.4 s
-    "kernel.kda_step_roofline":
+    "kernel.state_step_roofline":
         100 * (4 * 220 * (2 * STATE + TOKEN) / 819e9) / 0.4,
     # 2 rows x 4 layers x 2 x 2.10 MB and 150 tokens x 4 x 80 KB
-    "kernel.kda_chunk_roofline":
+    "kernel.state_chunk_roofline":
         100 * (4 * (2 * 2 * STATE + 150 * TOKEN) / 819e9) / 0.2,
     # 440 000 tokens x 1 MLA layer x 1152 B = 0.51 GB: 0.62 ms; their FLOPs
     # (x 32 heads x 2 x 1088) 30.6 GFLOP: 0.16 ms. Of 0.1 s
-    "kernel.mla_nope_attn_roofline":
+    "kernel.paged_attn_roofline":
         100 * (440000 * 1152 / 819e9) / 0.1,
     # 980 touched experts x 14.2 MB = 13.9 GB: 16.9 ms (5920 held pairs x
     # 14.2 MFLOP = 84 GFLOP: 0.43 ms), of 1.0 s
-    "kernel.moe_held2304_roofline": 100 * (980 * EXPERT / 819e9) / 1.0,
-    "moe.held_rows_per_touched_expert": 4.0,
-    "state.kda_rows_peak_share": 93.75,
+    "kernel.moe_experts_roofline": 100 * (980 * EXPERT / 819e9) / 1.0,
+    "moe.rows_per_touched_expert": 4.0,
+    "state.rows_peak_share": 93.75,
     # 120 rows x 8.98 MB over 22 528 blocks x 20 480 B
-    "state.kda_bytes_over_latent_bytes": 120 * ROW / (22528 * BLOCK),
-    "kv.latent_state_blocks_peak_share": 25.0,
-    "step.kda_decode_ms": 26.0,
+    "state.bytes_over_cache_bytes": 120 * ROW / (22528 * BLOCK),
+    "kv.blocks_peak_share": 25.0,
+    "step.decode_ms": 26.0,
 }
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_reader_arithmetic(name):
-    assert _reader(name)(RUN) == pytest.approx(WANT[name])
+    assert _reader(name)(RUN) == pytest.approx(WANT[name], rel=1e-9)
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
@@ -143,47 +148,43 @@ def test_no_share_of_the_made_up_run_passes_its_peak(name):
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_a_reader_finds_nothing_in_a_program_without_the_mechanism(name):
-    """The parent's program on its nearest cell: a state pool beside a pool
-    of K and V (equal lanes), `gdn_*` and `ctx_tokens_full` on its spans,
-    kernels named after the scalar rule, no experts; and a run with no
-    trace. A reader returns None and does not raise."""
+    """A program that states these sizes and runs none of it: no kernel of
+    the recurrence, no latent read and no grouped product in its trace, no
+    counter on its spans, no `moe` group, no state pool and no sample of a
+    block pool; and a run with no trace. A reader returns None and does not
+    raise."""
     run = dict(RUN, trace=dict(RUN["trace"], op_seconds={
-        "%gdn_step (tuple)": 0.3, "%gdn_chunk (tuple)": 0.2,
-        "%_paged_call bf16[16,30,1,128]": 1.0}))
-    run["spans"] = {"gateway": [], "worker_1": [
-        _tick(100.5, 50, gdn_chunk_tokens=241, gdn_chunk_rows=1,
-              gdn_step_rows=15, ctx_tokens_full=48000),
-        _tick(100.6, 20, width=1, gdn_step_rows=16)]}
+        "%fusion bf16[384,9216]": 0.8}))
+    run["spans"] = {"gateway": [], "worker_1": [_tick(100.5, 50, width=256)]}
     run["stats_before"] = {"worker_1": {}}
-    run["stats_after"] = {"worker_1": {
-        "mixed": {"ticks": 9},
-        "state_pool": {"rows_total": 16, "rows_peak": 12},
-        "kv_pool": {"kv_bytes_held": 5, "state_bytes_held": 7}}}
-    run["pool_samples"] = [_pool(1.0, 4352, 15, lanes=(3840, 3840)),
-                           {"t": 1.5, "kv_pool": {"worker_1": {
-                               "blocks_total": 8704, "blocks_free": 100,
-                               "kv_bytes_held": 5, "state_bytes_held": 7}}}]
+    run["stats_after"] = {"worker_1": {"mixed": {"ticks": 9}}}
+    run["pool_samples"] = [{"t": 1.0, "kv_pool": {"worker_1": None}}]
     assert _reader(name)(run) is None
     run["trace"] = run["slice"] = run["peaks"] = None
     assert _reader(name)(run) is None
 
 
-# -- the counting ----------------------------------------------------------------
+SIZES = sizes(CONFIG)
+SIZE = SIZES["recurrence"]
+
 
 def test_sizes_of_the_configuration_as_run():
-    assert roofline_kimi_linear.sizes(CONFIG) == {
-        "layers": (1, 4), "heads": 32, "latent": 512, "rope": 64,
-        "lin_heads": 32, "key_dim": 128, "value_dim": 128, "d_model": 2304,
-        "d_expert": 1024, "bytes_per_element": 2}
+    assert SIZES == {
+        "attention": {"kernel": "mla_latent", "layers": 1, "heads": 32,
+                      "latent": 512, "rope": 64, "lanes": 576,
+                      "bytes_per_element": 2},
+        "experts": {"kernel": "ragged-dot", "matrices": 3, "rows": 2304,
+                    "cols": 1024, "held": (0, 128),
+                    "bytes_per_element": 2},
+        "recurrence": {"kind": "kda", "layers": 4, "heads": 32,
+                       "state": (128, 128), "gate_lanes": 128,
+                       "step": "kda_step", "chunk": "kda_chunk"}}
 
 
 def test_a_state_is_2_10_mb_and_a_token_of_latent_1152_bytes():
     """ISSUE 44's figures: 32 x 128 x 128 float32 a row and KDA layer; 576
     used lanes x 2 B a token and MLA layer (1,280 B are stored)."""
-    size = roofline_kimi_linear.sizes(CONFIG)
-    assert roofline_gated_delta.state_bytes(size) == STATE == 2097152
-    from lib import roofline_moe_mla
-
+    assert roofline_gated_delta.state_bytes(SIZE) == STATE == 2097152
     assert roofline_moe_mla.latent_bytes(1, 1, 512, 64, 2) == 1152
     assert roofline_moe_mla.expert_bytes(1, 2304, 1024, 2) == EXPERT
     assert round(EXPERT / 1e6, 1) == 14.2
@@ -193,11 +194,10 @@ def test_a_decode_tick_s_steps_are_bound_by_their_states():
     """128 rows x 4 layers: 2 x 2.10 MB of state each and 80 KB of q, k, v,
     gates and read, 2.19 GB, 2.67 ms at the HBM peak; 3 x 2 x 32 x 128 x
     128 operations a row and layer, 1.6 GFLOP, 8 us."""
-    size = roofline_kimi_linear.sizes(CONFIG)
-    n_bytes = roofline_kimi_linear.recurrence_bytes(128, 128, size)
+    n_bytes = roofline_gated_delta.recurrence_bytes(128, 128, SIZE)
     assert n_bytes == 128 * 4 * (2 * STATE + TOKEN)
     assert TOKEN == 32 * 640 * 4
-    flops = roofline_gated_delta.recurrence_flops(128, size)
+    flops = roofline_gated_delta.recurrence_flops(128, SIZE)
     assert flops == 128 * 4 * 32 * 3 * 2 * 128 * 128
     assert roofline.floor_seconds(n_bytes, flops, V5E) == pytest.approx(
         n_bytes / 819e9)
@@ -206,7 +206,6 @@ def test_a_decode_tick_s_steps_are_bound_by_their_states():
 
 
 def test_a_chunk_s_state_is_read_once_a_row_not_once_a_token():
-    size = roofline_kimi_linear.sizes(CONFIG)
-    one = roofline_kimi_linear.recurrence_bytes(1, 200, size)
+    one = roofline_gated_delta.recurrence_bytes(1, 200, SIZE)
     assert one == 4 * (2 * STATE + 200 * TOKEN)
-    assert one < roofline_kimi_linear.recurrence_bytes(200, 200, size) / 5
+    assert one < roofline_gated_delta.recurrence_bytes(200, 200, SIZE) / 5

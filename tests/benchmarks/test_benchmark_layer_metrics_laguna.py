@@ -1,7 +1,10 @@
-"""The nine per-layer readers PR 36 lists for `laguna-s-2.1-5l.repo`
-(`kernel.swa_attn_*`, `kernel.full_attn_*`, `kernel.moe_held_*`,
-`moe.held_assignment_share`, `kv.window_over_full_tokens`,
-`kv.full_blocks_peak_share`) on a made-up run.
+"""The seven per-layer readers of two attention classes and a held share
+that PR 36 lists for `laguna-s-2.1-5l.repo` (`kernel.swa_attn_*`,
+`kernel.full_attn_*`, `moe.held_assignment_share`,
+`kv.window_over_full_tokens`, `kv.full_blocks_peak_share`) and the merged
+`kernel.moe_experts_*` the cell is listed on since PR 68, on the made-up run
+and at the hand-computed values that pinned PR 36's `kernel.moe_held_*`:
+the merged pair at THIS configuration's sizes (half of 256 experts held).
 
 `WANT` is this file's part of the table of pins: the hook in
 tests/conftest.py joins every `test_benchmark_layer_metrics_*.py`'s `WANT`
@@ -18,6 +21,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from bench_paths import BENCH  # noqa: E402
 
 V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
+CELL = "laguna-s-2.1-5l.repo"
 
 
 def _reader(metric):
@@ -86,7 +90,7 @@ EXPERT = 3 * 3072 * 1024 * 2           # one expert's three matrices, bf16
 WANT = {
     "kernel.swa_attn_busy": 5.0,
     "kernel.full_attn_busy": 10.0,
-    "kernel.moe_held_busy": 50.0,
+    "kernel.moe_experts_busy": 50.0,
     # 32 384 tokens x 3 window layers x 4096 B = 0.40 GB: 0.486 ms at the
     # HBM peak (the pairs' FLOPs over 72 heads take 0.018 ms), of 0.1 s
     "kernel.swa_attn_roofline":
@@ -96,7 +100,7 @@ WANT = {
         100 * (186000 * 2 * 4096 / 819e9) / 0.2,
     # 882 touched experts x 18.9 MB = 16.6 GB: 20.3 ms (the 6440 held
     # assignments' FLOPs take 0.6 ms), of 1.0 s
-    "kernel.moe_held_roofline": 100 * (882 * EXPERT / 819e9) / 1.0,
+    "kernel.moe_experts_roofline": 100 * (882 * EXPERT / 819e9) / 1.0,
     "moe.held_assignment_share": 51.0,
     "kv.window_over_full_tokens": 0.125,
     "kv.full_blocks_peak_share": 50.0,
@@ -105,14 +109,16 @@ WANT = {
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_reader_arithmetic(name):
-    assert _reader(name)(RUN) == pytest.approx(WANT[name])
+    assert _reader(name)(RUN) == pytest.approx(WANT[name], rel=1e-9)
 
 
 def test_the_window_call_is_not_counted_with_the_full_layers_calls():
-    """`swa_window_read` carries neither the kernel's older name nor is it
-    summed into `kernel.full_attn_busy`; the accepted `paged` reader (not
-    listed for this cell) sees the full layers' calls alone."""
-    assert _reader("kernel.paged_attn_busy")(RUN) == pytest.approx(10.0)
+    """`swa_window_read` is not summed into `kernel.full_attn_busy`, and a
+    configuration whose layers attend in two classes states no single read:
+    the merged paged readers (not listed for this cell) read nothing."""
+    assert _reader("kernel.full_attn_busy")(RUN) == pytest.approx(10.0)
+    assert _reader("kernel.paged_attn_busy")(RUN) is None
+    assert _reader("kernel.paged_attn_roofline")(RUN) is None
 
 
 @pytest.mark.parametrize("name", sorted(WANT))

@@ -78,27 +78,23 @@ def test_the_reader_finds_nothing_where_there_is_nothing(spans):
     assert _compute({"spans": spans}) is None
 
 
-def test_the_metric_is_listed_last_for_the_four_cells_with_a_state_row():
-    """ISSUE 54: appended for the cells whose lanes call a state step; the
-    layer as the kernels' other metrics spell it."""
+def test_the_metric_is_listed_for_the_cells_whose_lanes_call_a_state_step():
+    """ISSUE 54: listed for the cells whose lanes call a state step, which
+    are the cells on the step's merged roofline since PR 68; the layer as
+    the kernels' other metrics spell it."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names.index(NAME) > names.index("kv.block_pool_peak_share")
-    m = bench["per_layer"][names.index(NAME)]
-    assert m["workloads"] == [
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    m = by_name[NAME]
+    assert m["workloads"][:4] == [
         "olmo-hybrid-7b-12l.digest", "kimi-linear-48b-a3b-5l.reason",
         "falcon-h1-34b-6l.converse", "nemotron-3-super-120b-a12b-11l.agents"]
-    # Each of them lists its own step's roofline.
-    for cell, roofline in zip(m["workloads"], (
-            "kernel.gdn_step_roofline", "kernel.kda_step_roofline",
-            "kernel.ssd_step_roofline", "kernel.ssd64_step_roofline")):
-        assert bench["per_layer"][names.index(roofline)]["workloads"] == [
-            cell]
+    for kind in ("busy", "roofline"):
+        assert by_name[f"kernel.state_step_{kind}"]["workloads"] == \
+            m["workloads"]
     assert (m["layer"], m["moves"], m["better"], m["unit"], m["source"]) == (
         "kernels", "tokens_per_s", "higher", "%", "program_span")
-    assert m["layer"] == bench["per_layer"][names.index(
-        "kernel.ssd64_step_roofline")]["layer"]
+    assert m["layer"] == by_name["kernel.state_step_roofline"]["layer"]
     assert sorted(m) == ["better", "layer", "moves", "name", "source",
                          "unit", "workloads"]
 

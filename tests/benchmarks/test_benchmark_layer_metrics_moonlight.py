@@ -1,5 +1,9 @@
-"""The six per-layer readers PR 28 lists for `moonlight-16b-a3b-7l.solve`
-(`kernel.mla_attn_*`, `kernel.moe_experts_*`, `moe.*`) on a made-up run.
+"""The six merged per-layer readers `moonlight-16b-a3b-7l.solve` is listed on
+(`kernel.paged_attn_*` since PR 68, PR 28's `kernel.mla_attn_*`;
+`kernel.moe_experts_*`, `moe.*`, whose names PR 28 gave them) on the made-up
+run and at the hand-computed values that pinned them: the merged readers at
+THIS configuration's sizes (a latent pool, 64 whole experts of three
+matrices).
 
 `WANT` is this file's part of the table of pins: test_benchmark_layer_metrics.py
 refuses a `per_layer` list that names a metric the table does not pin, a PR
@@ -18,6 +22,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from bench_paths import BENCH  # noqa: E402
 
 V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
+CELL = "moonlight-16b-a3b-7l.solve"
 
 
 def _reader(metric):
@@ -70,11 +75,11 @@ RUN = {
     "stats_after": _stats([[15, 5, 10, 10], [3, 3, 3, 3]], 52, 15),
 }
 WANT = {
-    "kernel.mla_attn_busy": 20.0,
+    "kernel.paged_attn_busy": 20.0,
     "kernel.moe_experts_busy": 40.0,
     # 98 000 context tokens x 7 layers x 1152 B = 0.79 GB: 0.965 ms at the
     # HBM peak (the FLOPs take 0.12 ms), against 0.4 s of kernel
-    "kernel.mla_attn_roofline": 100 * (98000 * 7 * 576 * 2 / 819e9) / 0.4,
+    "kernel.paged_attn_roofline": 100 * (98000 * 7 * 576 * 2 / 819e9) / 0.4,
     # 754 touched experts x 17.3 MB = 13.0 GB: 15.9 ms (the 11 520
     # assignments' FLOPs take 1.0 ms), against 0.8 s of kernel
     "kernel.moe_experts_roofline": 100 * (754 * 17301504 / 819e9) / 0.8,
@@ -85,21 +90,26 @@ WANT = {
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_reader_arithmetic(name):
-    assert _reader(name)(RUN) == pytest.approx(WANT[name])
+    assert _reader(name)(RUN) == pytest.approx(WANT[name], rel=1e-9)
 
 
-def test_the_accepted_paged_readers_match_neither_kernel():
-    assert _reader("kernel.paged_attn_busy")(RUN) == 0.0
+def test_the_paged_reader_reads_the_kernel_the_configuration_states():
+    """A configuration that states a latent reads `mla_latent_read`; the
+    same trace under one that states K and V heads looks for `_paged_call`,
+    which this program does not make."""
+    with open(os.path.join(BENCH, "configs", "gpt2-large.json")) as f:
+        other = json.load(f)
+    assert _reader("kernel.paged_attn_busy")(dict(RUN, config=other)) is None
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_a_reader_finds_nothing_in_a_program_without_the_mechanism(name):
     """The parent's program: the paged kernel only, no `moe` group, no
-    `moe_*` attrs on its spans; and a run with no trace at all."""
+    `moe_*` attrs and no context on its spans; and a run with no trace at
+    all."""
     run = dict(RUN, trace=dict(RUN["trace"], op_seconds={
         "%_paged_call f32[32,20,256,64]": 1.0}))
-    run["spans"] = {"gateway": [], "worker_1": [
-        _tick(100.5, 50, ctx_tokens=48000)]}
+    run["spans"] = {"gateway": [], "worker_1": [_tick(100.5, 50, width=256)]}
     run["stats_before"] = {"worker_1": {"mixed": {"ticks": 1}}}
     run["stats_after"] = {"worker_1": {"mixed": {"ticks": 9}}}
     assert _reader(name)(run) is None

@@ -1,11 +1,16 @@
-"""The fourteen per-layer readers PR 50 lists for
-`nemotron-3-super-120b-a12b-11l.agents` (`kernel.moe_latent_*`,
-`moe.latent_rows_per_touched_expert`, `moe.latent_load_imbalance`,
-`moe.route_sort_busy`, `kernel.ssd64_step_*`, `kernel.ssd64_chunk_*`,
-`kernel.gqa16_attn_*`, `state.ssd64_rows_peak_share`,
-`kv.ssd64_blocks_peak_share`, `state.ssd64_bytes_over_kv_bytes`) on a
-made-up run, and the counting of lib/roofline_nemotron_h.py by hand-computed
-cases.
+"""The thirteen merged per-layer readers
+`nemotron-3-super-120b-a12b-11l.agents` is listed on since PR 68
+(`kernel.moe_experts_*`, `moe.rows_per_touched_expert`,
+`moe.expert_load_imbalance`, `kernel.state_step_*`, `kernel.state_chunk_*`,
+`kernel.paged_attn_*`, `state.rows_peak_share`, `kv.blocks_peak_share`,
+`state.bytes_over_cache_bytes`) and `moe.route_sort_busy`, on the made-up
+run and at the hand-computed values that pinned PR 50's copies of them
+(`kernel.moe_latent_*`, `moe.latent_*`, `kernel.ssd64_*`,
+`kernel.gqa16_attn_*`, `state.ssd64_*`, `kv.ssd64_blocks_peak_share`): the
+merged readers at THIS configuration's sizes (two matrices an expert in a
+latent, a quarter of the experts held, one attention layer in eleven). And
+the counting of an expert in a latent and of the M layers' states by
+hand-computed cases.
 
 `WANT` is this file's part of the table of pins: the hook in
 tests/conftest.py joins every `test_benchmark_layer_metrics_*.py`'s `WANT`
@@ -21,7 +26,13 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from bench_paths import BENCH  # noqa: E402
 
-from lib import roofline, roofline_falcon_h1, roofline_nemotron_h  # noqa: E402
+from lib import (  # noqa: E402
+    roofline,
+    roofline_falcon_h1,
+    roofline_gated_delta,
+    roofline_moe_mla,
+)
+from lib.roofline_sizes import sizes  # noqa: E402
 
 V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 CELL = "nemotron-3-super-120b-a12b-11l.agents"
@@ -120,35 +131,35 @@ RUN = {
                      _pool(2.0, 8000, 64)],
 }
 WANT = {
-    "kernel.moe_latent_busy": 100 * 1.6 / 2.9,
-    "kernel.ssd64_step_busy": 100 * 0.3 / 2.9,
-    "kernel.ssd64_chunk_busy": 100 * 0.07 / 2.9,
-    "kernel.gqa16_attn_busy": 100 * 0.05 / 2.9,
+    "kernel.moe_experts_busy": 100 * 1.6 / 2.9,
+    "kernel.state_step_busy": 100 * 0.3 / 2.9,
+    "kernel.state_chunk_busy": 100 * 0.07 / 2.9,
+    "kernel.paged_attn_busy": 100 * 0.05 / 2.9,
     "moe.route_sort_busy": 100 * 0.02 / 2.9,
     # 1270 touched experts x 11.0 MB = 13.98 GB: 17.07 ms at the HBM peak
     # (13,600 pairs' 0.15 TFLOP take 0.8 ms), of 1.6 s
-    "kernel.moe_latent_roofline": 100 * (1270 * EXPERT / 819e9) / 1.6,
+    "kernel.moe_experts_roofline": 100 * (1270 * EXPERT / 819e9) / 1.6,
     # 83 rows x 5 layers x (2 x 4.19 MB + 74 KB) = 3.51 GB: 4.29 ms of 0.3 s
-    "kernel.ssd64_step_roofline":
+    "kernel.state_step_roofline":
         100 * (5 * 83 * (2 * STATE + TOKEN) / 819e9) / 0.3,
     # 3 rows x 5 layers x 2 x 4.19 MB and 413 tokens x 5 x 74 KB
-    "kernel.ssd64_chunk_roofline":
+    "kernel.state_chunk_roofline":
         100 * (5 * (3 * 2 * STATE + 413 * TOKEN) / 819e9) / 0.07,
     # 160,000 tokens x 1 layer x 2 x 2 x 128 x 2 B = 0.164 GB: 0.2 ms; their
     # FLOPs (x 32 heads x 4 x 128) 2.6 GFLOP: 0.013 ms. Of 0.05 s
-    "kernel.gqa16_attn_roofline": 100 * (160000 * 1024 / 819e9) / 0.05,
-    "moe.latent_rows_per_touched_expert": 25720 / 2572,
-    "moe.latent_load_imbalance": (300 * 128 / 12800 + 220 * 128 / 12920) / 2,
-    "state.ssd64_rows_peak_share": 100.0,
+    "kernel.paged_attn_roofline": 100 * (160000 * 1024 / 819e9) / 0.05,
+    "moe.rows_per_touched_expert": 25720 / 2572,
+    "moe.expert_load_imbalance": (300 * 128 / 12800 + 220 * 128 / 12920) / 2,
+    "state.rows_peak_share": 100.0,
     # 62 rows x 21.59 MB over 8960 blocks x 16,384 B
-    "state.ssd64_bytes_over_kv_bytes": 62 * ROW / (8960 * BLOCK),
-    "kv.ssd64_blocks_peak_share": 25.0,
+    "state.bytes_over_cache_bytes": 62 * ROW / (8960 * BLOCK),
+    "kv.blocks_peak_share": 25.0,
 }
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_reader_arithmetic(name):
-    assert _reader(name)(RUN) == pytest.approx(WANT[name])
+    assert _reader(name)(RUN) == pytest.approx(WANT[name], rel=1e-9)
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
@@ -159,70 +170,62 @@ def test_no_share_of_the_made_up_run_passes_its_peak(name):
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_a_reader_finds_nothing_in_a_program_without_the_mechanism(name):
-    """The parent's program on its nearest cell: a state pool beside a pool
-    of K and V of another width under another configuration (no latent, no
-    pattern), `ssd_*` on its spans but no `moe_*`, no grouped product and no
-    sort in its trace; and a run with no trace. A reader returns None and
-    does not raise. (The three `kernel.ssd64_*_busy` / `gqa16` busy readers
-    name a kernel the parent's nearest cell also runs: they are listed for
-    this cell alone, and read what the trace holds.)"""
-    run = dict(RUN, config=OTHER, trace=dict(RUN["trace"], op_seconds={
+    """A program that states these sizes and runs none of it: no grouped
+    product, no kernel of the recurrence or the read and no sort in its
+    trace, no counter on its spans, no `moe` group, no state pool and no
+    sample of a block pool; and a run with no trace. A reader returns None
+    and does not raise."""
+    run = dict(RUN, trace=dict(RUN["trace"], op_seconds={
         "%fusion f32[64,261120]": 0.4}))
-    run["spans"] = {"gateway": [], "worker_1": [
-        _tick(100.5, 30, ssd_chunk_tokens=300, ssd_chunk_rows=2,
-              ssd_step_rows=58, ctx_tokens_full=30000),
-        _tick(100.6, 20, width=1, ssd_step_rows=62)]}
+    run["spans"] = {"gateway": [], "worker_1": [_tick(100.5, 50, width=256)]}
     run["stats_before"] = {"worker_1": {}}
-    run["stats_after"] = {"worker_1": {
-        "mixed": {"ticks": 9},
-        "state_pool": {"rows_total": 64, "rows_peak": 60},
-        "kv_pool": {"kv_bytes_held": 5, "state_bytes_held": 7,
-                    "block_lanes": [3840, 3840]}}}
-    run["pool_samples"] = [_pool(1.0, 4352, 15, lanes=(3840, 3840)),
-                           {"t": 1.5, "kv_pool": {"worker_1": {
-                               "blocks_total": 8704, "blocks_free": 100,
-                               "kv_bytes_held": 5, "state_bytes_held": 7}}}]
+    run["stats_after"] = {"worker_1": {"mixed": {"ticks": 9}}}
+    run["pool_samples"] = [{"t": 1.0, "kv_pool": {"worker_1": None}}]
     assert _reader(name)(run) is None
-    # The same run with the parent's kernels in its trace: the rooflines,
-    # which need this configuration's sizes, still read nothing.
-    run["trace"] = dict(RUN["trace"])
-    if name.endswith("_roofline") or name.startswith("moe."):
-        assert _reader(name)(run) is None
     run["trace"] = run["slice"] = run["peaks"] = None
     assert _reader(name)(run) is None
 
 
-def test_the_pool_readers_tell_this_lane_by_its_blocks_and_configuration():
-    """`holds_ssd` (lib/roofline_falcon_h1.py): the configuration states a
-    Mamba-2 recurrence, the lane reports state bytes beside its blocks, a
-    block holds 2 KV heads x 128 lanes of K and of V."""
-    pool = RUN["pool_samples"][0]["kv_pool"]["worker_1"]
-    assert roofline_nemotron_h.holds_ssd is roofline_falcon_h1.holds_ssd
-    assert roofline_nemotron_h.holds_ssd(pool, CONFIG)
-    assert not roofline_nemotron_h.holds_ssd(pool, OTHER)
-    assert not roofline_nemotron_h.holds_ssd(
-        dict(pool, block_lanes=[512, 512]), CONFIG)
-    assert not roofline_nemotron_h.holds_ssd(None, CONFIG)
+def test_the_readers_take_this_configuration_s_sizes_not_another_s():
+    """The same spans and trace under the configuration whose EVERY layer
+    has both mixers and no experts: the recurrence is counted in 6 layers
+    of 32 heads of (128, 256) where this one has 5 of 128 of (64, 128), the
+    paged read at 4 KV heads in 6 layers where this one has 2 in 1, and the
+    experts' readers find no experts stated."""
+    other = dict(RUN, config=OTHER)
+    assert _reader("kernel.moe_experts_roofline")(other) is None
+    assert _reader("moe.expert_load_imbalance")(other) is None
+    assert _reader("kernel.paged_attn_roofline")(other) == pytest.approx(
+        WANT["kernel.paged_attn_roofline"] * (6 * 4) / (1 * 2), rel=1e-9)
+    state, token = 32 * 128 * 256 * 4, (32 * 257 + 2 * 2 * 256) * 4
+    assert _reader("kernel.state_step_roofline")(other) == pytest.approx(
+        100 * (6 * 83 * (2 * state + token) / 819e9) / 0.3, rel=1e-9)
 
 
-# -- the counting ----------------------------------------------------------------
+SIZES = sizes(CONFIG)
+
 
 def test_sizes_of_the_configuration_as_run():
-    assert roofline_nemotron_h.sizes(CONFIG) == {
-        "layers": {"M": 5, "E": 5, "*": 1}, "heads": 32, "kv_heads": 2,
-        "head_dim": 128, "d_latent": 1024, "d_expert": 2688,
-        "held": (0, 128), "bytes_per_element": 2,
-        "mamba": {"layers": 5, "ssm_heads": 128, "ssm_head_dim": 64,
-                  "d_state": 128, "groups": 8}}
+    assert SIZES == {
+        "attention": {"kernel": "paged", "layers": 1, "heads": 32,
+                      "kv_heads": 2, "head_dim": 128, "lanes": 512,
+                      "bytes_per_element": 2},
+        "experts": {"kernel": "ragged-dot", "matrices": 2, "rows": 1024,
+                    "cols": 2688, "held": (0, 128),
+                    "bytes_per_element": 2},
+        "recurrence": {"kind": "ssd", "layers": 5, "heads": 128,
+                       "state": (64, 128), "groups": 8, "step": "ssd_step",
+                       "chunk": "ssd_chunk"}}
 
 
 def test_a_state_is_4_19_mb_a_token_of_k_v_1024_bytes_an_expert_11_mb():
     """ISSUE 50's figures: 128 x 64 x 128 float32 a row and M layer; 2 x 2 x
     128 x 2 B a token in the one * layer; 2 x 1024 x 2688 x 2 B an expert."""
-    size = roofline_nemotron_h.sizes(CONFIG)
-    assert roofline_falcon_h1.state_bytes(size["mamba"]) == STATE == 4194304
+    assert roofline_gated_delta.state_bytes(
+        SIZES["recurrence"]) == STATE == 4194304
     assert roofline.attention_bytes(1, 1, 2, 128, 2) == 1024
-    assert roofline_nemotron_h.expert_bytes(1, size) == EXPERT == 11010048
+    assert roofline_moe_mla.expert_bytes(1, 1024, 2688, 2, 2) == EXPERT \
+        == 11010048
     assert ROW == 21585920 and BLOCK == 16 * 1024
 
 
@@ -231,9 +234,12 @@ def test_a_tick_s_experts_are_bound_by_their_matrices():
     GB, 8.6 ms at the HBM peak (ISSUE 50's three fifths of a tick's bytes);
     its ~7,040 held pairs are 2 x 2 x 1024 x 2688 operations each, 77.5
     GFLOP, 0.39 ms: ~11 rows an expert against a ridge near 240."""
-    size = roofline_nemotron_h.sizes(CONFIG)
-    n_bytes = roofline_nemotron_h.expert_bytes(640, size)
-    flops = roofline_nemotron_h.expert_flops(7040, size)
+    size = SIZES["experts"]
+    n_bytes = roofline_moe_mla.expert_bytes(
+        640, size["rows"], size["cols"], size["bytes_per_element"],
+        size["matrices"])
+    flops = roofline_moe_mla.expert_flops(7040, size["rows"], size["cols"],
+                                          size["matrices"])
     assert n_bytes == 640 * EXPERT and 7.04e9 < n_bytes < 7.06e9
     assert flops == 7040 * 4 * 1024 * 2688
     assert roofline.floor_seconds(n_bytes, flops, V5E) == pytest.approx(
@@ -245,7 +251,7 @@ def test_a_tick_s_experts_are_bound_by_their_matrices():
 def test_a_tick_s_steps_are_bound_by_their_states_in_the_m_layers_alone():
     """43 rows x 5 M layers (not 11): 2 x 4.19 MB of state each and 74 KB of
     x, dt, B, C and read: 1.82 GB, 2.2 ms at the HBM peak."""
-    mamba = roofline_nemotron_h.sizes(CONFIG)["mamba"]
+    mamba = SIZES["recurrence"]
     n_bytes = roofline_falcon_h1.recurrence_bytes(43, 43, mamba)
     assert n_bytes == 43 * 5 * (2 * STATE + TOKEN)
     assert TOKEN == (128 * 129 + 2048) * 4
@@ -256,8 +262,10 @@ def test_a_tick_s_steps_are_bound_by_their_states_in_the_m_layers_alone():
 
 def test_the_imbalance_is_read_over_the_held_experts_alone():
     """Over all 512 experts the 384 that are another chip's read 0 and the
-    busiest held expert would read 4 times as uneven."""
-    held = _reader("moe.latent_load_imbalance")(RUN)
-    assert held == pytest.approx(WANT["moe.latent_load_imbalance"])
-    assert _reader("moe.expert_load_imbalance")(RUN) == pytest.approx(
-        4 * held)
+    busiest held expert would read 4 times as uneven: what the reader reads
+    under a configuration that states the same experts all held."""
+    held = _reader("moe.expert_load_imbalance")(RUN)
+    assert held == pytest.approx(WANT["moe.expert_load_imbalance"])
+    whole = dict(CONFIG, kwargs=dict(CONFIG["kwargs"], held_count=0))
+    assert _reader("moe.expert_load_imbalance")(
+        dict(RUN, config=whole)) == pytest.approx(4 * held)

@@ -1,8 +1,14 @@
-"""The eleven per-layer readers PR 39 lists for `olmo-hybrid-7b-12l.digest`
-(`kernel.gdn_chunk_*`, `kernel.gdn_step_*`, `kernel.mha128_attn_*`,
-`state.rows_peak_share`, `state.bytes_over_kv_bytes`,
-`kv.hybrid_blocks_peak_share`, `step.hybrid_decode_ms`,
-`step.hybrid_decode_device_ms`) on a made-up run.
+"""The ten merged per-layer readers `olmo-hybrid-7b-12l.digest` is listed on
+since PR 68 (`kernel.state_chunk_*`, `kernel.state_step_*`,
+`kernel.paged_attn_*`, `state.rows_peak_share`,
+`state.bytes_over_cache_bytes`, `kv.blocks_peak_share`, `step.decode_ms`),
+on the made-up run and at the hand-computed values that pinned PR 39's
+copies of them (`kernel.gdn_*`, `kernel.mha128_attn_*`,
+`state.bytes_over_kv_bytes`, `kv.hybrid_blocks_peak_share`,
+`step.hybrid_decode_ms`): the merged readers at THIS configuration's sizes
+(a delta rule gated by head in nine layers, MHA at 30 heads in three).
+(`step.hybrid_decode_device_ms`, the time the host was blocked on a lane
+that runs ahead, went with PR 68: `step.decode_run_ms` has the device's.)
 
 `WANT` is this file's part of the table of pins: the hook in
 tests/conftest.py joins every `test_benchmark_layer_metrics_*.py`'s `WANT`
@@ -84,48 +90,44 @@ RUN = {
                      _pool(2.0, 4000, 16)],
 }
 WANT = {
-    "kernel.gdn_chunk_busy": 10.0,
-    "kernel.gdn_step_busy": 15.0,
-    "kernel.mha128_attn_busy": 20.0,
+    "kernel.state_chunk_busy": 10.0,
+    "kernel.state_step_busy": 15.0,
+    "kernel.paged_attn_busy": 20.0,
     # 3 rows x 9 layers x 2 x 2.21 MB and 541 tokens x 9 x 30 x 576 lanes x
     # 4 B = 0.46 GB: 0.56 ms at the HBM peak (the recurrence's 16 GFLOP
     # take 0.08 ms), of 0.2 s
-    "kernel.gdn_chunk_roofline":
+    "kernel.state_chunk_roofline":
         100 * (9 * (3 * 2 * STATE + 541 * 30 * 576 * 4) / 819e9) / 0.2,
     # 29 rows x 9 layers x (2 x 2.21 MB + 69 KB) = 1.17 GB: 1.43 ms, of 0.3 s
-    "kernel.gdn_step_roofline":
+    "kernel.state_step_roofline":
         100 * (9 * 29 * (2 * STATE + 30 * 576 * 4) / 819e9) / 0.3,
     # 124 000 tokens x 3 full layers x 15 360 B = 5.71 GB: 6.98 ms, of 0.4 s
-    "kernel.mha128_attn_roofline":
+    "kernel.paged_attn_roofline":
         100 * (124000 * 3 * 15360 / 819e9) / 0.4,
     "state.rows_peak_share": 75.0,
     # 15 rows x 21.15 MB over 4352 blocks x 737 280 B
-    "state.bytes_over_kv_bytes": 15 * 21150720 / (4352 * 737280),
-    "kv.hybrid_blocks_peak_share": 50.0,
-    "step.hybrid_decode_ms": 26.0,
-    "step.hybrid_decode_device_ms": 20.0,
+    "state.bytes_over_cache_bytes": 15 * 21150720 / (4352 * 737280),
+    "kv.blocks_peak_share": 50.0,
+    "step.decode_ms": 26.0,
 }
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_reader_arithmetic(name):
-    assert _reader(name)(RUN) == pytest.approx(WANT[name])
+    assert _reader(name)(RUN) == pytest.approx(WANT[name], rel=1e-9)
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_a_reader_finds_nothing_in_a_program_without_the_mechanism(name):
-    """The parent's program: one pool of blocks, no state pool, no kernel
-    named after the recurrence in its trace, no `gdn_*` or
-    `ctx_tokens_full` on its spans; and a run with no trace. A reader
-    returns None and does not raise."""
+    """A program that states these sizes and runs none of it: no kernel of
+    the recurrence and no paged call in its trace, no counter on its spans,
+    no state pool and no sample of a block pool; and a run with no trace. A
+    reader returns None and does not raise."""
     run = dict(RUN, trace=dict(RUN["trace"], op_seconds={
-        "%mla_latent_read bf16[68,128,512]": 1.0}))
-    run["spans"] = {"gateway": [], "worker_1": [
-        _tick(100.5, 50, ctx_tokens=48000),
-        _tick(100.6, 20, width=1, dispatch_us=9000, wait_us=2000)]}
+        "%fusion bf16[288,11008]": 1.0}))
+    run["spans"] = {"gateway": [], "worker_1": [_tick(100.5, 50, width=256)]}
     run["stats_after"] = {"worker_1": {"mixed": {"ticks": 9}}}
-    run["pool_samples"] = [{"t": 1.0, "kv_pool": {"worker_1": {
-        "blocks_total": 5120, "blocks_free": 100}}}]
+    run["pool_samples"] = [{"t": 1.0, "kv_pool": {"worker_1": None}}]
     assert _reader(name)(run) is None
     run["trace"] = run["slice"] = run["peaks"] = None
     assert _reader(name)(run) is None

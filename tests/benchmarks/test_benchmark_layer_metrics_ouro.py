@@ -1,8 +1,12 @@
-"""The four per-layer readers PR 58 lists for `ouro-2.6b.think`
-(`kernel.mha16_attn_busy`, `kernel.mha16_attn_roofline`,
-`step.loop_decode_hbm_roofline`, `kv.loop_planes_peak_share`) on a made-up
-run, the counting of lib/roofline_ouro.py by hand-computed cases, and the
-rehearsal of a small cell through benchmarks/run.py.
+"""`step.loop_decode_hbm_roofline`, which PR 58 lists for `ouro-2.6b.think`,
+and the three merged readers the cell is listed on since PR 68
+(`kernel.paged_attn_busy`, `kernel.paged_attn_roofline`,
+`kv.blocks_peak_share`), on the made-up run and at the hand-computed values
+that pinned PR 58's copies of them (`kernel.mha16_attn_*`,
+`kv.loop_planes_peak_share`): the merged readers at THIS configuration's
+sizes (a cache plane a (pass, layer), 192 of them, counted as layers). The
+counting of lib/roofline_ouro.py by hand-computed cases, and the rehearsal
+of a small cell through benchmarks/run.py.
 
 `WANT` is this file's part of the table of pins: the hook in
 tests/conftest.py joins every `test_benchmark_layer_metrics_*.py`'s `WANT`
@@ -17,9 +21,10 @@ import sys
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from bench_paths import BENCH  # noqa: E402
+from bench_paths import BENCH, rehearsal_cells  # noqa: E402
 
-from lib import roofline_ouro  # noqa: E402
+from lib import roofline, roofline_ouro  # noqa: E402
+from lib.roofline_sizes import sizes  # noqa: E402
 
 ROOT = os.path.dirname(BENCH)
 V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
@@ -92,24 +97,24 @@ RUN = {
     "pool_samples": [_pool(1.0, 150), _pool(1.5, 200), _pool(2.0, 180)],
 }
 WANT = {
-    "kernel.mha16_attn_busy": 100 * 0.5 / 2.95,
+    "kernel.paged_attn_busy": 100 * 0.5 / 2.95,
     # 8,700 context tokens x 192 planes x 8,192 B = 13.7 GB: 16.7 ms at the
     # HBM peak (their 8,700 x 192 x 16 x 512 = 13.7 GFLOP take 0.07 ms), of
     # 0.5 s
-    "kernel.mha16_attn_roofline":
+    "kernel.paged_attn_roofline":
         100 * (8700 * 192 * PLANE_TOKEN / 819e9) / 0.5,
     # the two width-1 ticks' bytes: 4 x 48 layers, the planes of 2800 and
     # 3000 tokens, the head; nearest-rank median: the first; over 37 ms
     "step.loop_decode_hbm_roofline":
         100 * ((4 * 48 * LAYER + 2800 * 192 * PLANE_TOKEN + HEAD) / 819e9)
         / 37e-3,
-    "kv.loop_planes_peak_share": 62.5,
+    "kv.blocks_peak_share": 62.5,
 }
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_reader_arithmetic(name):
-    assert _reader(name)(RUN) == pytest.approx(WANT[name])
+    assert _reader(name)(RUN) == pytest.approx(WANT[name], rel=1e-9)
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
@@ -119,32 +124,35 @@ def test_no_share_of_the_made_up_run_passes_its_peak(name):
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_a_reader_finds_nothing_in_a_program_without_the_mechanism(name):
-    """Another configuration's lane (Mistral's uniform step: `ctx_tokens`
-    on its spans, the paged call in its trace, no `kv_planes`); a run with
-    no trace; and this configuration's file over a program that notes no
-    pass. A reader returns None and does not raise."""
-    run = dict(RUN, config=OTHER)
+    """This configuration's file over a program that notes no pass, reads
+    through no paged call and samples no pool; and a run with no trace. A
+    reader returns None and does not raise."""
+    run = dict(RUN, trace=dict(RUN["trace"], op_seconds={
+        "%fusion f32[8,5632]": 0.5}))
     run["spans"] = {"gateway": [], "worker_1": [
-        _tick(100.5, 30, width=256, prefill_tokens=200, ctx_tokens=30000),
-        _tick(100.6, 20, width=1, ctx_tokens=62000)]}
+        _tick(100.5, 30, width=256, prefill_tokens=200),
+        _tick(100.6, 20, width=1)]}
     run["stats_before"] = {"worker_1": {"mixed": {"ticks": 1}}}
     run["stats_after"] = {"worker_1": {"mixed": {"ticks": 9}}}
+    run["pool_samples"] = []
     assert _reader(name)(run) is None
     run["trace"] = run["slice"] = run["peaks"] = None
     run["scopes"] = {}
     assert _reader(name)(run) is None
-    bare = dict(run, config=CONFIG, trace=RUN["trace"], slice=RUN["slice"],
-                peaks=V5E, scopes=RUN["scopes"])
-    assert _reader(name)(bare) is None
 
-
-# -- the counting ----------------------------------------------------------------
 
 def test_sizes_of_the_configuration_as_run():
     assert roofline_ouro.sizes(CONFIG) == {
         "layers": 48, "passes": 4, "heads": 16, "head_dim": 128,
         "d_model": 2048, "d_ff": 5632, "vocab": 49152,
         "bytes_per_element": 2}
+    assert sizes(CONFIG) == {
+        "attention": {"kernel": "paged", "layers": 192, "heads": 16,
+                      "kv_heads": 16, "head_dim": 128, "lanes": 4096,
+                      "bytes_per_element": 2},
+        "experts": None, "recurrence": None}
+    # Another configuration's uniform step has a plane a layer.
+    assert sizes(OTHER)["attention"]["layers"] == 8
 
 
 def test_issue_58_s_figures_by_hand():
@@ -164,22 +172,25 @@ def test_issue_58_s_figures_by_hand():
     planes = roofline_ouro.read_bytes(8 * 350, 192, size)
     assert 5.3e-3 < planes / 819e9 < 5.4e-3
     # the read is bound by its bytes: a pair is 512 operations a head
-    assert roofline_ouro.read_flops(2800, 192, size) == 2800 * 192 * 16 * 512
-    assert (roofline_ouro.read_flops(2800, 192, size) / 197e12
-            < planes / 819e9 / 50)
+    flops = roofline.attention_flops(2800, 192, 16, 128)
+    assert flops == 2800 * 192 * 16 * 512
+    assert flops / 197e12 < planes / 819e9 / 50
+    # a plane counted as a layer is the same bytes
+    assert roofline.attention_bytes(8 * 350, 192, 16, 128, 2) == planes
 
 
 # -- the rehearsal ------------------------------------------------------------------
 
-def test_a_small_cell_reads_every_reader_through_the_harness():
+def test_a_small_cell_reads_every_reader_through_the_harness(
+        tmp_path):
     """benchmarks/run.py on tests/benchmarks/data/BENCHMARK.ouro.test.json
+    with what BENCHMARK.json lists for the cell today
     (ouro-small-test behind the HTTP front, a closed loop): `correct` is
     true against references/ouro.py, the start-up line states the passes
     and the planes, and the reader that needs no device reads a number."""
     out = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--benchmark-file",
-         os.path.join(ROOT, "tests", "benchmarks", "data",
-                      "BENCHMARK.ouro.test.json"),
+         rehearsal_cells(tmp_path, "ouro", CELL),
          "--workload", "ouro.closed", "--seed", "5", "--seconds", "2",
          "--trace", "1"],
         env=dict(os.environ, TPU_ENGINE_PLATFORM="cpu", JAX_PLATFORMS="cpu"),
@@ -188,9 +199,9 @@ def test_a_small_cell_reads_every_reader_through_the_harness():
     line = json.loads(out.stdout.strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0
     got = {name: m["value"] for name, m in line["metrics"].items()}
-    for name in ("kv.loop_planes_peak_share", "sched.decode_rows_per_tick",
+    for name in ("kv.blocks_peak_share", "sched.decode_rows_per_tick",
                  "step.prefill_ms", "sched.prefill_tick_share",
                  "sched.itl_prefill_share"):
         assert name in got, name
-    assert 0 < got["kv.loop_planes_peak_share"] <= 100
+    assert 0 < got["kv.blocks_peak_share"] <= 100
     assert "lane worker_1 3 passes x 3 layers, 9 planes, " in out.stdout

@@ -164,19 +164,22 @@ def test_the_readers_read_the_newest_trace_of_the_run(monkeypatch):
     assert kernel("gdn_") <= got["step.mixer_busy"]
 
 
-def test_the_twelve_are_listed_last_with_their_cells():
+def test_the_twelve_are_listed_together_with_their_cells():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["traffic"]: w["name"] for w in bench["workloads"]}
+    # The ten cells there were when the twelve were listed (PR 55): a cell
+    # that came later joins the lists its step opens, and is not held here.
     every = [w["name"] for w in bench["workloads"]]
-    last = bench["per_layer"][-12:]
+    every = every[:every.index(cells["reply"]) + 1]
+    first = [m["name"] for m in bench["per_layer"]].index("step.attn_busy")
+    last = bench["per_layer"][first:first + 12]
     assert [m["name"] for m in last] == [
         "step.attn_busy", "step.attn_read_busy", "step.ffn_busy",
         "step.moe_experts_busy", "step.mixer_busy", "step.mixer_chunk_busy",
         "step.head_busy", "step.sample_busy", "step.reveal_busy",
         "step.unscoped_busy", "step.decode_run_ms", "step.chunk_run_ms"]
     assert sorted(m["name"] for m in last) == sorted(WANT)
-    assert len(bench["per_layer"]) == 124
     by_name = {m["name"]: m for m in last}
     for m in last:
         assert sorted(m) == ["better", "layer", "moves", "name", "source",
@@ -190,7 +193,7 @@ def test_the_twelve_are_listed_last_with_their_cells():
                                            m["name"] + ".py"))
 
     def listed(name):
-        return by_name[name]["workloads"]
+        return [cell for cell in by_name[name]["workloads"] if cell in every]
 
     for name in ("step.attn_busy", "step.attn_read_busy", "step.ffn_busy",
                  "step.head_busy", "step.sample_busy", "step.unscoped_busy"):
@@ -211,14 +214,8 @@ def test_the_twelve_are_listed_last_with_their_cells():
     assert listed("step.reveal_busy") == [cells["reply"]]
     # each cell that reads a part's share lists the kernel it is read beside
     for metric, kernels in (
-            ("step.mixer_chunk_busy", ("kernel.gdn_chunk_busy",
-                                       "kernel.ssd_chunk_busy",
-                                       "kernel.ssd64_chunk_busy")),
-            ("step.moe_experts_busy", ("kernel.moe_experts_busy",
-                                       "kernel.moe_held_busy",
-                                       "kernel.moe_held2304_busy",
-                                       "kernel.moe_latent_busy",
-                                       "kernel.moe_e128_busy"))):
+            ("step.mixer_chunk_busy", ("kernel.state_chunk_busy",)),
+            ("step.moe_experts_busy", ("kernel.moe_experts_busy",))):
         beside = {cell for m in bench["per_layer"] if m["name"] in kernels
-                  for cell in m["workloads"]}
+                  for cell in m["workloads"] if cell in every}
         assert set(listed(metric)) == beside

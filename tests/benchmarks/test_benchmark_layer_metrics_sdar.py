@@ -1,10 +1,16 @@
-"""The eleven per-layer readers PR 53 lists for `sdar-30b-a3b-chat-7l.reply`
-(`sched.passes_per_block`, `sched.tokens_per_row_tick`,
-`sched.commit_pass_share`, `step.block_decode_ms`, `kernel.block_attn_*`,
-`kernel.moe_e128_*`, `moe.e128_rows_per_touched_expert`,
-`moe.e128_load_imbalance`, `kv.block_pool_peak_share`) on a made-up run,
-the counting of lib/roofline_sdar.py by hand-computed cases, and the
-rehearsal of a small cell through benchmarks/run.py.
+"""The four per-layer readers of a block-decoding lane's passes that PR 53
+lists for `sdar-30b-a3b-chat-7l.reply` (`sched.passes_per_block`,
+`sched.tokens_per_row_tick`, `sched.commit_pass_share`,
+`step.block_decode_ms`) and the seven merged readers the cell is listed on
+since PR 68 (`kernel.paged_attn_*`, `kernel.moe_experts_*`,
+`moe.rows_per_touched_expert`, `moe.expert_load_imbalance`,
+`kv.blocks_peak_share`), on the made-up run and at the hand-computed values
+that pinned PR 53's copies of them (`kernel.block_attn_*`,
+`kernel.moe_e128_*`, `moe.e128_*`, `kv.block_pool_peak_share`): the merged
+readers at THIS configuration's sizes (the read under a block-causal mask,
+whose pairs the lane counts; 128 whole experts). The counting by
+hand-computed cases, and the rehearsal of a small cell through
+benchmarks/run.py.
 
 `WANT` is this file's part of the table of pins: the hook in
 tests/conftest.py joins every `test_benchmark_layer_metrics_*.py`'s `WANT`
@@ -19,9 +25,10 @@ import sys
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from bench_paths import BENCH  # noqa: E402
+from bench_paths import BENCH, rehearsal_cells  # noqa: E402
 
 from lib import roofline, roofline_moe_mla, roofline_sdar  # noqa: E402
+from lib.roofline_sizes import sizes  # noqa: E402
 
 ROOT = os.path.dirname(BENCH)
 V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
@@ -128,23 +135,23 @@ WANT = {
     "sched.commit_pass_share": 100 * 37 / (64 * 2 + 64 + 62 + 64),
     # the ticks with no chunk aboard: 16, 18, 20, 20 ms
     "step.block_decode_ms": 18.0,
-    "kernel.block_attn_busy": 100 * 0.4 / 2.9,
-    "kernel.moe_e128_busy": 100 * 1.5 / 2.9,
+    "kernel.paged_attn_busy": 100 * 0.4 / 2.9,
+    "kernel.moe_experts_busy": 100 * 1.5 / 2.9,
     # 127,000 tokens x 14,336 B = 1.82 GB: 2.22 ms at the HBM peak (566,000
     # pairs x 7 x 32 x 512 = 65 GFLOP: 0.33 ms), of 0.4 s
-    "kernel.block_attn_roofline": 100 * (127000 * TOKEN / 819e9) / 0.4,
+    "kernel.paged_attn_roofline": 100 * (127000 * TOKEN / 819e9) / 0.4,
     # 1792 touched experts x 9.4 MB = 16.9 GB: 20.6 ms at the HBM peak
     # (42,560 pairs' 0.4 TFLOP take 2 ms), of 1.5 s
-    "kernel.moe_e128_roofline": 100 * (1792 * EXPERT / 819e9) / 1.5,
-    "moe.e128_rows_per_touched_expert": 102400 / 5120,
-    "moe.e128_load_imbalance": (400 * 128 / 12800 + 250 * 128 / 12950) / 2,
-    "kv.block_pool_peak_share": 40.0,
+    "kernel.moe_experts_roofline": 100 * (1792 * EXPERT / 819e9) / 1.5,
+    "moe.rows_per_touched_expert": 102400 / 5120,
+    "moe.expert_load_imbalance": (400 * 128 / 12800 + 250 * 128 / 12950) / 2,
+    "kv.blocks_peak_share": 40.0,
 }
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_reader_arithmetic(name):
-    assert _reader(name)(RUN) == pytest.approx(WANT[name])
+    assert _reader(name)(RUN) == pytest.approx(WANT[name], rel=1e-9)
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
@@ -155,44 +162,33 @@ def test_no_share_of_the_made_up_run_passes_its_peak(name):
 
 @pytest.mark.parametrize("name", sorted(WANT))
 def test_a_reader_finds_nothing_in_a_program_without_the_mechanism(name):
-    """The parent's program on its nearest cell (GQA over a pool, routed
-    experts, another configuration: no `block_length`), `moe_*` and
-    `ctx_tokens_full` on its spans but no `run_width`, the grouped product
-    in its trace but no block-mask read; and a run with no trace. A reader
-    returns None and does not raise."""
-    run = dict(RUN, config=OTHER)
-    run["spans"] = {"gateway": [], "worker_1": [
-        _tick(100.5, 30, width=256, prefill_tokens=200,
-              ctx_tokens_full=30000, moe_assignments=5000,
-              moe_experts_touched=400),
-        _tick(100.6, 20, width=1, ctx_tokens_full=62000)]}
-    run["stats_before"] = {"worker_1": {
-        "mixed": {"ticks": 1, "decode_tokens": 5},
-        "moe": _moe(0, 0, [[0] * 128])}}
-    run["stats_after"] = {"worker_1": {
-        "mixed": {"ticks": 9, "decode_tokens": 500},
-        "moe": _moe(900, 90, [[7] * 128])}}
-    assert _reader(name)(run) is None
-    run["trace"] = run["slice"] = run["peaks"] = None
-    assert _reader(name)(run) is None
-    # This configuration under the PARENT's program (the driver lays the
-    # benchmark's files over the parent's checkout): no counter, no span
-    # attr, no kernel of that name.
-    bare = dict(run, config=CONFIG, trace=dict(RUN["trace"], op_seconds={
-        "%fusion f32[64,151936]": 0.4}), slice=RUN["slice"], peaks=V5E)
+    """This configuration under the PARENT's program (the driver lays the
+    benchmark's files over the parent's checkout): no counter, no span
+    attr, no kernel of that name, no sample of a pool; and a run with no
+    trace. A reader returns None and does not raise."""
+    bare = dict(RUN, trace=dict(RUN["trace"], op_seconds={
+        "%fusion f32[64,151936]": 0.4}))
+    bare["spans"] = {"gateway": [], "worker_1": [
+        _tick(100.5, 30, width=256, prefill_tokens=200)]}
     bare["pool_samples"] = []
+    bare["stats_before"] = {"worker_1": {"mixed": {"ticks": 1,
+                                                   "decode_tokens": 5}}}
     bare["stats_after"] = {"worker_1": {"mixed": {"ticks": 9,
                                                   "decode_tokens": 500}}}
     assert _reader(name)(bare) is None
+    bare["trace"] = bare["slice"] = bare["peaks"] = None
+    assert _reader(name)(bare) is None
 
-
-# -- the counting ----------------------------------------------------------------
 
 def test_sizes_of_the_configuration_as_run():
-    assert roofline_sdar.sizes(CONFIG) == {
-        "layers": 7, "heads": 32, "kv_heads": 4, "head_dim": 128,
-        "d_model": 2048, "d_expert": 768, "experts": 128, "top_k": 8,
-        "block_length": 4, "bytes_per_element": 2}
+    assert sizes(CONFIG) == {
+        "attention": {"kernel": "block_mask_read", "layers": 7, "heads": 32,
+                      "kv_heads": 4, "head_dim": 128, "lanes": 1024,
+                      "bytes_per_element": 2},
+        "experts": {"kernel": "ragged-dot", "matrices": 3, "rows": 2048,
+                    "cols": 768, "held": (0, 128),
+                    "bytes_per_element": 2},
+        "recurrence": None}
     assert roofline_sdar.decodes_by_blocks({"config": CONFIG})
     assert not roofline_sdar.decodes_by_blocks({"config": OTHER})
 
@@ -236,15 +232,16 @@ def test_a_tick_of_runs_is_bound_by_its_experts_matrices_then_its_context():
 
 # -- the rehearsal ------------------------------------------------------------------
 
-def test_a_small_cell_reads_every_reader_through_the_harness():
+def test_a_small_cell_reads_every_reader_through_the_harness(
+        tmp_path):
     """benchmarks/run.py on tests/benchmarks/data/BENCHMARK.sdar.test.json
+    with what BENCHMARK.json lists for the cell today
     (sdar-small-test behind the HTTP front, a closed loop): `correct` is
     true against references/sdar.py's replay, and every reader that needs
     no device reads a number."""
     out = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--benchmark-file",
-         os.path.join(ROOT, "tests", "benchmarks", "data",
-                      "BENCHMARK.sdar.test.json"),
+         rehearsal_cells(tmp_path, "sdar", CELL),
          "--workload", "sdar.closed", "--seed", "5", "--seconds", "2",
          "--trace", "1"],
         env=dict(os.environ, TPU_ENGINE_PLATFORM="cpu", JAX_PLATFORMS="cpu"),
@@ -255,8 +252,8 @@ def test_a_small_cell_reads_every_reader_through_the_harness():
     got = {name: m["value"] for name, m in line["metrics"].items()}
     for name in ("sched.passes_per_block", "sched.tokens_per_row_tick",
                  "sched.commit_pass_share", "step.block_decode_ms",
-                 "moe.e128_rows_per_touched_expert",
-                 "moe.e128_load_imbalance", "kv.block_pool_peak_share",
+                 "moe.rows_per_touched_expert",
+                 "moe.expert_load_imbalance", "kv.blocks_peak_share",
                  "sched.decode_rows_per_tick", "step.prefill_ms",
                  "sched.prefill_tick_share", "sched.itl_prefill_share"):
         assert name in got, name

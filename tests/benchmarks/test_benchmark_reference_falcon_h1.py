@@ -17,17 +17,27 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from bench_paths import BENCH, DATA, ROOT  # noqa: E402
+from bench_paths import (  # noqa: E402
+    BENCH,
+    DATA,
+    ROOT,
+    listed as metrics_listed,
+    load_benchmark,
+    read_without_a_device,
+    rehearsal_cells,
+)
 
 from lib import reference  # noqa: E402
 
 CELL = "falcon-h1-34b-6l.converse"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-NEW = ["kernel.ssd_step_busy", "kernel.ssd_step_roofline",
-       "kernel.ssd_chunk_busy", "kernel.ssd_chunk_roofline",
-       "kernel.gqa5_attn_busy", "kernel.gqa5_attn_roofline",
-       "state.ssd_rows_peak_share", "state.ssd_bytes_over_kv_bytes",
-       "kv.ssd_blocks_peak_share", "step.ssd_decode_ms"]
+# PR 46's readers, under the names of the merged readers that took their
+# place in PR 68.
+NEW = ["kernel.state_step_busy", "kernel.state_step_roofline",
+       "kernel.state_chunk_busy", "kernel.state_chunk_roofline",
+       "kernel.paged_attn_busy", "kernel.paged_attn_roofline",
+       "state.rows_peak_share", "state.bytes_over_cache_bytes",
+       "kv.blocks_peak_share", "step.decode_ms"]
 MULTIPLIERS = ("embedding_multiplier", "attention_in_multiplier",
                "key_multiplier", "attention_out_multiplier",
                "ssm_in_multiplier", "ssm_out_multiplier",
@@ -280,16 +290,12 @@ def test_the_benchmark_lists_the_cell_and_its_ten_metrics():
                                  "max_position_embeddings"]
     assert config["source"].endswith("tiiuae/Falcon-H1-34B-Instruct/blob/"
                                      "main/config.json")
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == NEW
-    at = bench["per_layer"].index(mine[0])
-    assert bench["per_layer"][at:at + 10] == mine
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    mine = [by_name[name] for name in NEW]
+    assert all(CELL in m["workloads"] for m in mine)
     assert {m["layer"] for m in mine} == {"kernels", "state pool", "KV pool",
                                        "step function"}
     assert all(m["moves"] == "tokens_per_s" for m in mine)
-    # No accepted metric's list gained the cell.
-    for m in bench["end_to_end"] + bench["per_layer"][:at]:
-        assert CELL not in m.get("workloads", [])
     with open(os.path.join(BENCH, "traffic", "converse.json")) as f:
         traffic = json.load(f)
     assert (traffic["loop"], traffic["clients"], traffic["block"],
@@ -302,24 +308,17 @@ def test_the_benchmark_lists_the_cell_and_its_ten_metrics():
     assert traffic["sharing"] == {"share": 0.0}
 
 
-def test_the_rehearsal_lists_every_metric_of_the_new_cell():
+def test_the_rehearsal_lists_every_metric_of_the_new_cell(tmp_path):
     """run.py --trace 1 on the CPU at the small size, a cell list of its own
     with the ten keyless per-layer metrics and the cell's own ten: the span
     and counter metrics print, what only a device trace gives is left out
     and said so."""
-    cells = os.path.join(DATA, "BENCHMARK.falcon.test.json")
-    with open(cells) as f:
-        listed = json.load(f)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        real = json.load(f)
-    want = [m["name"] for m in real["per_layer"]
-            if CELL in m.get("workloads", [CELL])]
-    assert [m["name"] for m in listed["per_layer"]] == want
-    assert len(want) == 20 and want[10:] == NEW
-    assert [m["name"] for m in listed["end_to_end"]] == [
-        m["name"] for m in real["end_to_end"]
-        if CELL in m.get("workloads", [CELL])] == [
-        "itl_p95_ms", "tokens_per_s", "setup_s"]
+    cells = rehearsal_cells(tmp_path, "falcon", CELL)
+    real = load_benchmark()
+    want = [m["name"] for m in metrics_listed(real, CELL)]
+    assert set(NEW) <= set(want)
+    assert [m["name"] for m in metrics_listed(real, CELL, "end_to_end")] \
+        == ["itl_p95_ms", "tokens_per_s", "setup_s"]
     proc = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"),
          "--benchmark-file", cells, "--workload", "falcon.closed",
@@ -330,12 +329,12 @@ def test_the_rehearsal_lists_every_metric_of_the_new_cell():
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0
     got = line["metrics"]
-    device_only = {"device.idle", "device.idle_host", "device.hbm_peak_gb",
-                   *(name for name in NEW if name.startswith("kernel."))}
-    assert set(got) == set(want) - device_only
+    assert set(got) == read_without_a_device(real, CELL)
+    assert not {name for name in NEW if name.startswith("kernel.")} \
+        & set(got)
     assert got["step.compiles"] == {"value": 0, "unit": "compilations"}
     # Three clients of four slots; states and blocks of the same rows.
-    assert got["state.ssd_rows_peak_share"]["value"] == 75.0
-    assert 0.2 < got["state.ssd_bytes_over_kv_bytes"]["value"] < 3.0
-    assert 5.0 < got["kv.ssd_blocks_peak_share"]["value"] < 40.0
-    assert got["step.ssd_decode_ms"]["value"] > 0
+    assert got["state.rows_peak_share"]["value"] == 75.0
+    assert 0.2 < got["state.bytes_over_cache_bytes"]["value"] < 3.0
+    assert 5.0 < got["kv.blocks_peak_share"]["value"] < 40.0
+    assert got["step.decode_ms"]["value"] > 0

@@ -21,22 +21,31 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from bench_paths import BENCH, DATA, ROOT  # noqa: E402
+from bench_paths import (  # noqa: E402
+    BENCH,
+    DATA,
+    ROOT,
+    listed as metrics_listed,
+    load_benchmark,
+    read_without_a_device,
+    rehearsal_cells,
+)
 
 from lib import reference  # noqa: E402
 
 CELL = "kimi-linear-48b-a3b-5l.reason"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-NEW = ["kernel.kda_step_busy", "kernel.kda_step_roofline",
-       "kernel.mla_nope_attn_busy", "kernel.mla_nope_attn_roofline",
-       "kernel.moe_held2304_busy", "kernel.moe_held2304_roofline",
-       "moe.held_rows_per_touched_expert", "state.kda_rows_peak_share",
-       "state.kda_bytes_over_latent_bytes",
-       "kv.latent_state_blocks_peak_share", "step.kda_decode_ms"]
+# PR 44's readers, under the names of the merged readers that took their
+# place in PR 68.
+NEW = ["kernel.state_step_busy", "kernel.state_step_roofline",
+       "kernel.paged_attn_busy", "kernel.paged_attn_roofline",
+       "kernel.moe_experts_busy", "kernel.moe_experts_roofline",
+       "moe.rows_per_touched_expert", "state.rows_peak_share",
+       "state.bytes_over_cache_bytes", "kv.blocks_peak_share",
+       "step.decode_ms"]
 KEYLESS = ["sched.decode_rows_per_tick", "sched.prefill_tick_share",
            "step.prefill_ms", "device.idle", "device.hbm_peak_gb",
-           "sched.host_gap_ms", "step.prefill_device_ms", "step.compiles",
-           "device.idle_host", "sched.itl_prefill_share"]
+           "step.compiles", "device.idle_host", "sched.itl_prefill_share"]
 REDUCED = ["num_hidden_layers", "num_experts", "vocab_size",
            "model_max_length"]
 
@@ -112,11 +121,27 @@ def test_reference_logits_equal_the_program_s_in_float32(small):
     assert np.abs(ours - theirs).max() < 2e-4 * np.abs(theirs).max()
 
 
+@pytest.fixture(scope="module")
+def served(small):
+    """Three prompts and the program's eight greedy tokens after each,
+    decoded once for all the controls."""
+    _, spec, _, program, _ = small
+    rng = np.random.default_rng(1)
+    samples = []
+    for length in (5, 20, 50):
+        prompt = [int(t) for t in rng.integers(0, spec.config.vocab, length)]
+        seq = list(prompt)
+        for _ in range(8):
+            seq.append(int(program(np.asarray(seq, np.int32))[-1].argmax()))
+        samples.append((prompt, seq[length:]))
+    return samples
+
+
 @pytest.mark.parametrize("control", [
     {"drop": "decay"}, {"drop": "gate_mean"}, {"drop": "conv_tail"},
     {"drop": "state"}, {"drop": "other_half"}, {"drop": "shared"}])
-def test_check_served_accepts_greedy_tokens_and_refuses_a_control(small,
-                                                                  control):
+def test_check_served_accepts_greedy_tokens_and_refuses_a_control(
+        small, served, control):
     """The served tokens against the reference, then against the reference
     with the decay left out, the channel gate replaced by its head's mean,
     the conv tail or the state dropped at every chunk boundary, the other
@@ -126,14 +151,7 @@ def test_check_served_accepts_greedy_tokens_and_refuses_a_control(small,
     read on the chip at the published widths; tests/test_kimi_linear.py
     holds that each moves the reference's logits.)"""
     config, spec, params, program, forward = small
-    rng = np.random.default_rng(1)
-    samples = []
-    for length in (5, 20, 50):
-        prompt = [int(t) for t in rng.integers(0, spec.config.vocab, length)]
-        seq = list(prompt)
-        for _ in range(8):
-            seq.append(int(program(np.asarray(seq, np.int32))[-1].argmax()))
-        samples.append((prompt, seq[length:]))
+    samples = served
     ok, details = reference.check_served(forward, params, config["reference"],
                                          samples, 0.05, 0.9, pad_to=64)
     assert ok, details
@@ -311,25 +329,20 @@ def test_the_benchmark_lists_the_cell_and_its_eleven_metrics():
     assert config["source"].endswith(
         "moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/main/config.json")
     assert config["file"] == "benchmarks/configs/kimi-linear-48b-a3b-5l.json"
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == NEW
-    # The chunked form's two readers exist and are pinned, and NO cell lists
-    # them: the traced slice (seconds 24-27 of the window) falls between the
-    # first prompts' chunk ticks and the first completions' successors, so
-    # it holds decode ticks alone and the readers find nothing there; a
-    # line that lacks a listed metric is refused (PERF.md section 7).
-    for name in ("kernel.kda_chunk_busy", "kernel.kda_chunk_roofline"):
-        assert os.path.exists(os.path.join(BENCH, "layer_metrics",
-                                           name + ".py"))
-        assert name not in [m["name"] for m in bench["per_layer"]]
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    mine = [by_name[name] for name in NEW]
+    assert all(CELL in m["workloads"] for m in mine)
+    # The chunked form's merged pair does NOT list the cell: the traced
+    # slice (seconds 24-27 of the window) falls between the first prompts'
+    # chunk ticks and the first completions' successors, so it holds decode
+    # ticks alone and the readers find nothing there; a line that lacks a
+    # listed metric is refused (PERF.md section 7).
+    for name in ("kernel.state_chunk_busy", "kernel.state_chunk_roofline"):
+        assert CELL not in by_name[name]["workloads"]
     assert {m["layer"] for m in mine} == {"kernels", "expert layer",
                                        "state pool", "KV pool",
                                        "step function"}
     assert all(m["moves"] == "tokens_per_s" for m in mine)
-    # No list an earlier PR wrote names the cell: nothing there was edited.
-    first = bench["per_layer"].index(mine[0])
-    for m in bench["end_to_end"] + bench["per_layer"][:first]:
-        assert CELL not in m.get("workloads", [])
     assert [m["name"] for m in bench["per_layer"]
             if "workloads" not in m] == KEYLESS
     assert [m["name"] for m in bench["end_to_end"]
@@ -355,19 +368,18 @@ def test_the_benchmark_lists_the_cell_and_its_eleven_metrics():
 
 # -- the rehearsal -------------------------------------------------------------------
 
-def test_the_rehearsal_lists_every_metric_of_the_new_cell():
+def test_the_rehearsal_lists_every_metric_of_the_new_cell(tmp_path):
     """run.py --trace 1 on the CPU at the small size, a cell list of its
     own with the ten keyless per-layer metrics and the cell's own eleven:
     the span and counter metrics print, what only a device trace gives is
     left out and said so; the untraced run prints the three end-to-end
     ones."""
-    cells = os.path.join(DATA, "BENCHMARK.kimi.test.json")
-    with open(cells) as f:
-        listed = json.load(f)
-    names = [m["name"] for m in listed["per_layer"]]
-    assert names == KEYLESS + NEW
-    assert [m["name"] for m in listed["end_to_end"]] == [
-        "itl_p95_ms", "tokens_per_s", "setup_s"]
+    cells = rehearsal_cells(tmp_path, "kimi", CELL)
+    real = load_benchmark()
+    names = [m["name"] for m in metrics_listed(real, CELL)]
+    assert set(KEYLESS + NEW) <= set(names)
+    assert [m["name"] for m in metrics_listed(real, CELL, "end_to_end")] \
+        == ["itl_p95_ms", "tokens_per_s", "setup_s"]
     env = dict(os.environ, TPU_ENGINE_PLATFORM="cpu")
     lines = {}
     for trace in ("1", "0"):
@@ -383,14 +395,14 @@ def test_the_rehearsal_lists_every_metric_of_the_new_cell():
     assert set(lines["0"]["metrics"]) == {"itl_p95_ms", "tokens_per_s",
                                           "setup_s"}
     got = lines["1"]["metrics"]
-    device_only = {"device.idle", "device.idle_host", "device.hbm_peak_gb",
-                   *(name for name in NEW if name.startswith("kernel."))}
-    assert set(got) == set(names) - device_only
+    assert set(got) == read_without_a_device(real, CELL)
+    assert not {name for name in NEW if name.startswith("kernel.")} \
+        & set(got)
     assert got["step.compiles"] == {"value": 0, "unit": "compilations"}
     # Three clients of four slots; states and latent blocks of the same
     # rows; about half of 4 pairs a token over 8 held experts.
-    assert got["state.kda_rows_peak_share"]["value"] == 75.0
-    assert 0.1 < got["state.kda_bytes_over_latent_bytes"]["value"] < 2.0
-    assert 5.0 < got["kv.latent_state_blocks_peak_share"]["value"] < 40.0
-    assert 1.0 <= got["moe.held_rows_per_touched_expert"]["value"] < 8.0
-    assert got["step.kda_decode_ms"]["value"] > 0.0
+    assert got["state.rows_peak_share"]["value"] == 75.0
+    assert 0.1 < got["state.bytes_over_cache_bytes"]["value"] < 2.0
+    assert 5.0 < got["kv.blocks_peak_share"]["value"] < 40.0
+    assert 1.0 <= got["moe.rows_per_touched_expert"]["value"] < 8.0
+    assert got["step.decode_ms"]["value"] > 0.0

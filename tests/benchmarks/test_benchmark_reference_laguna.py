@@ -16,11 +16,26 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from bench_paths import BENCH, DATA, ROOT  # noqa: E402
+from bench_paths import (  # noqa: E402
+    BENCH,
+    DATA,
+    ROOT,
+    listed as metrics_listed,
+    load_benchmark,
+    read_without_a_device,
+    rehearsal_cells,
+)
 
 from lib import reference, roofline, roofline_laguna, roofline_moe_mla  # noqa: E402,E501
 
 CELL = "laguna-s-2.1-5l.repo"
+# PR 36's readers; its `kernel.moe_held_*` under the merged pair's names
+# since PR 68.
+MINE = ["kernel.swa_attn_busy", "kernel.swa_attn_roofline",
+        "kernel.full_attn_busy", "kernel.full_attn_roofline",
+        "kernel.moe_experts_busy", "kernel.moe_experts_roofline",
+        "moe.held_assignment_share", "kv.window_over_full_tokens",
+        "kv.full_blocks_peak_share"]
 V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 
@@ -245,20 +260,12 @@ def test_the_benchmark_lists_the_cell_and_its_nine_metrics():
     config = next(c for c in bench["configs"] if c["name"] == cell["config"])
     assert config["reduced"] == ["num_hidden_layers", "num_experts",
                                  "vocab_size", "max_position_embeddings"]
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == [
-        "kernel.swa_attn_busy", "kernel.swa_attn_roofline",
-        "kernel.full_attn_busy", "kernel.full_attn_roofline",
-        "kernel.moe_held_busy", "kernel.moe_held_roofline",
-        "moe.held_assignment_share", "kv.window_over_full_tokens",
-        "kv.full_blocks_peak_share"]
-    at = bench["per_layer"].index(mine[0])
-    assert bench["per_layer"][at:at + 9] == mine
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    mine = [by_name[name] for name in MINE]
+    assert all(CELL in m["workloads"] for m in mine)
     assert {m["layer"] for m in mine} == {"kernels", "expert layer",
                                           "KV pool"}
     assert all(m["moves"] == "tokens_per_s" for m in mine)
-    for m in bench["end_to_end"] + bench["per_layer"][:at]:
-        assert CELL not in m.get("workloads", [])
     with open(os.path.join(BENCH, "traffic", "repo.json")) as f:
         traffic = json.load(f)
     assert (traffic["loop"], traffic["clients"], traffic["block"],
@@ -327,24 +334,17 @@ def test_the_full_layers_seconds_leave_the_window_call_out():
 
 # -- the rehearsal -------------------------------------------------------------------
 
-def test_the_rehearsal_lists_every_metric_of_the_new_cell():
+def test_the_rehearsal_lists_every_metric_of_the_new_cell(tmp_path):
     """run.py --trace 1 on the CPU at the small size, a cell list of its
     own with the ten keyless per-layer metrics and the cell's own nine: the
     span and counter metrics print, what only a device trace gives is left
     out and said so; the untraced run prints the three end-to-end ones."""
-    cells = os.path.join(DATA, "BENCHMARK.laguna.test.json")
-    with open(cells) as f:
-        listed = json.load(f)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        real = json.load(f)
-    want = [m["name"] for m in real["per_layer"]
-            if CELL in m.get("workloads", [CELL])]
-    assert [m["name"] for m in listed["per_layer"]] == want
-    assert len(want) == 19
-    assert [m["name"] for m in listed["end_to_end"]] == [
-        m["name"] for m in real["end_to_end"]
-        if CELL in m.get("workloads", [CELL])] == [
-        "itl_p95_ms", "tokens_per_s", "setup_s"]
+    cells = rehearsal_cells(tmp_path, "laguna", CELL)
+    real = load_benchmark()
+    want = [m["name"] for m in metrics_listed(real, CELL)]
+    assert set(MINE) <= set(want)
+    assert [m["name"] for m in metrics_listed(real, CELL, "end_to_end")] \
+        == ["itl_p95_ms", "tokens_per_s", "setup_s"]
     env = dict(os.environ, TPU_ENGINE_PLATFORM="cpu")
     lines, said = {}, {}
     for trace in ("1", "0"):
@@ -361,11 +361,10 @@ def test_the_rehearsal_lists_every_metric_of_the_new_cell():
     assert set(lines["0"]["metrics"]) == {"itl_p95_ms", "tokens_per_s",
                                           "setup_s"}
     got = lines["1"]["metrics"]
-    device_only = {"device.idle", "device.idle_host", "device.hbm_peak_gb",
-                   "kernel.swa_attn_busy", "kernel.swa_attn_roofline",
-                   "kernel.full_attn_busy", "kernel.full_attn_roofline",
-                   "kernel.moe_held_busy", "kernel.moe_held_roofline"}
-    assert set(got) == set(want) - device_only
+    assert set(got) == read_without_a_device(real, CELL)
+    device_only = set(want) - set(got)
+    assert {name for name in MINE if name.startswith("kernel.")} \
+        <= device_only
     assert got["step.compiles"] == {"value": 0, "unit": "compilations"}
     assert 35.0 < got["moe.held_assignment_share"]["value"] < 65.0
     assert 0.0 < got["kv.window_over_full_tokens"]["value"] <= 1.0

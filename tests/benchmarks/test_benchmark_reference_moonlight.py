@@ -15,11 +15,23 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from bench_paths import BENCH, DATA, ROOT  # noqa: E402
+from bench_paths import (  # noqa: E402
+    BENCH,
+    DATA,
+    ROOT,
+    listed as metrics_listed,
+    load_benchmark,
+    read_without_a_device,
+    rehearsal_cells,
+)
 
 from lib import reference, roofline, roofline_moe_mla  # noqa: E402
+from lib.roofline_sizes import sizes  # noqa: E402
 
 CELL = "moonlight-16b-a3b-7l.solve"
+MINE = ["kernel.paged_attn_busy", "kernel.moe_experts_busy",
+        "kernel.paged_attn_roofline", "kernel.moe_experts_roofline",
+        "moe.expert_load_imbalance", "moe.rows_per_touched_expert"]
 V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 
 
@@ -83,17 +95,11 @@ def test_reference_logits_equal_the_program_s_in_float32(small):
     assert np.abs(ours - theirs).max() < 2e-4 * np.abs(theirs).max()
 
 
-@pytest.mark.parametrize("control", [{"drop": "shared"}, {"drop": "bias"},
-                                     {"drop": "k_pe"}])
-def test_check_served_accepts_greedy_tokens_and_refuses_a_control(small,
-                                                                  control):
-    """The served tokens against the reference, then against the reference
-    with the shared expert, the selection bias or the rope-key term left
-    out: each reads NOT correct, by the exact share or by the gap. (The
-    control one precision down, `experts_as: float8_e4m3fn`, is read on the
-    chip at the published widths: three layers of 32-wide experts in
-    float32 do not flip a router's choice.)"""
-    config, spec, params, program, forward = small
+@pytest.fixture(scope="module")
+def served(small):
+    """Three prompts and the program's eight greedy tokens after each,
+    decoded once for the three controls (24 lengths, a compilation each)."""
+    _, spec, _, program, _ = small
     rng = np.random.default_rng(1)
     samples = []
     for length in (5, 19, 33):
@@ -102,6 +108,21 @@ def test_check_served_accepts_greedy_tokens_and_refuses_a_control(small,
         for _ in range(8):
             seq.append(int(program(np.asarray(seq, np.int32))[-1].argmax()))
         samples.append((prompt, seq[length:]))
+    return samples
+
+
+@pytest.mark.parametrize("control", [{"drop": "shared"}, {"drop": "bias"},
+                                     {"drop": "k_pe"}])
+def test_check_served_accepts_greedy_tokens_and_refuses_a_control(
+        small, served, control):
+    """The served tokens against the reference, then against the reference
+    with the shared expert, the selection bias or the rope-key term left
+    out: each reads NOT correct, by the exact share or by the gap. (The
+    control one precision down, `experts_as: float8_e4m3fn`, is read on the
+    chip at the published widths: three layers of 32-wide experts in
+    float32 do not flip a router's choice.)"""
+    config, spec, params, program, forward = small
+    samples = served
     ok, details = reference.check_served(forward, params, config["reference"],
                                          samples, 0.05, 0.9, pad_to=48)
     assert ok, details
@@ -178,20 +199,17 @@ def test_the_configuration_builds_the_model_the_arithmetic_describes(
 def test_the_benchmark_lists_the_cell_and_its_six_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["workloads"][-1] == {
+    cell, = [w for w in bench["workloads"] if w["name"] == CELL]
+    assert cell == {
         "name": CELL, "config": "moonlight-16b-a3b-7l", "traffic": "solve",
-        "chips": 1, "why": bench["workloads"][-1]["why"]}
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == [
-        "kernel.mla_attn_busy", "kernel.moe_experts_busy",
-        "kernel.mla_attn_roofline", "kernel.moe_experts_roofline",
-        "moe.expert_load_imbalance", "moe.rows_per_touched_expert"]
-    at = bench["per_layer"].index(mine[0])
-    assert bench["per_layer"][at:at + 6] == mine
+        "chips": 1, "why": cell["why"]}
+    # PR 28's six readers, under the names of the merged readers that took
+    # the two latent-attention copies' place in PR 68.
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    mine = [by_name[name] for name in MINE]
+    assert all(CELL in m["workloads"] for m in mine)
     assert {m["layer"] for m in mine} == {"kernels", "expert layer"}
     assert all(m["moves"] == "tokens_per_s" for m in mine)
-    for m in bench["end_to_end"] + bench["per_layer"][:at]:
-        assert CELL not in m.get("workloads", [])
     with open(os.path.join(BENCH, "traffic", "solve.json")) as f:
         traffic = json.load(f)
     assert (traffic["loop"], traffic["clients"], traffic["block"],
@@ -206,9 +224,14 @@ def test_the_benchmark_lists_the_cell_and_its_six_metrics():
 # -- the counting ----------------------------------------------------------------
 
 def test_sizes_of_the_configuration_as_run(published):
-    assert roofline_moe_mla.sizes(published) == {
-        "layers": 7, "heads": 16, "latent": 512, "rope": 64,
-        "d_model": 2048, "d_expert": 1408, "bytes_per_element": 2}
+    assert sizes(published) == {
+        "attention": {"kernel": "mla_latent", "layers": 7, "heads": 16,
+                      "latent": 512, "rope": 64, "lanes": 576,
+                      "bytes_per_element": 2},
+        "experts": {"kernel": "ragged-dot", "matrices": 3, "rows": 2048,
+                    "cols": 1408, "held": (0, 64),
+                    "bytes_per_element": 2},
+        "recurrence": None}
 
 
 @pytest.mark.parametrize("args, want", [
@@ -262,24 +285,17 @@ def test_a_decode_tick_s_experts_are_bound_by_their_weights():
 
 # -- the rehearsal -------------------------------------------------------------------
 
-def test_the_rehearsal_lists_every_metric_of_the_new_cell():
+def test_the_rehearsal_lists_every_metric_of_the_new_cell(tmp_path):
     """run.py --trace 1 on the CPU at the small size, a cell list of its
     own with the ten keyless per-layer metrics and the cell's own six: the
     span and counter metrics print, what only a device trace gives is left
     out and said so; the untraced run prints the three end-to-end ones."""
-    cells = os.path.join(DATA, "BENCHMARK.moonlight.test.json")
-    with open(cells) as f:
-        listed = json.load(f)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        real = json.load(f)
-    want = [m["name"] for m in real["per_layer"]
-            if CELL in m.get("workloads", [CELL])]
-    assert [m["name"] for m in listed["per_layer"]] == want
-    assert len(want) == 16
-    assert [m["name"] for m in listed["end_to_end"]] == [
-        m["name"] for m in real["end_to_end"]
-        if CELL in m.get("workloads", [CELL])] == [
-        "itl_p95_ms", "tokens_per_s", "setup_s"]
+    cells = rehearsal_cells(tmp_path, "moonlight", CELL)
+    real = load_benchmark()
+    want = [m["name"] for m in metrics_listed(real, CELL)]
+    assert set(MINE) <= set(want)
+    assert [m["name"] for m in metrics_listed(real, CELL, "end_to_end")] \
+        == ["itl_p95_ms", "tokens_per_s", "setup_s"]
     env = dict(os.environ, TPU_ENGINE_PLATFORM="cpu")
     lines, said = {}, {}
     for trace in ("1", "0"):
@@ -296,10 +312,10 @@ def test_the_rehearsal_lists_every_metric_of_the_new_cell():
     assert set(lines["0"]["metrics"]) == {"itl_p95_ms", "tokens_per_s",
                                           "setup_s"}
     got = lines["1"]["metrics"]
-    device_only = {"device.idle", "device.idle_host", "device.hbm_peak_gb",
-                   "kernel.mla_attn_busy", "kernel.moe_experts_busy",
-                   "kernel.mla_attn_roofline", "kernel.moe_experts_roofline"}
-    assert set(got) == set(want) - device_only
+    assert set(got) == read_without_a_device(real, CELL)
+    device_only = set(want) - set(got)
+    assert {name for name in MINE if name.startswith("kernel.")} \
+        <= device_only
     assert got["step.compiles"] == {"value": 0, "unit": "compilations"}
     assert got["moe.expert_load_imbalance"]["value"] >= 1.0
     assert 1.0 <= got["moe.rows_per_touched_expert"]["value"] <= 4 * 16 * 2

@@ -17,7 +17,15 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from bench_paths import BENCH, DATA, ROOT  # noqa: E402
+from bench_paths import (  # noqa: E402
+    BENCH,
+    DATA,
+    ROOT,
+    listed as metrics_listed,
+    load_benchmark,
+    read_without_a_device,
+    rehearsal_cells,
+)
 
 from lib import reference  # noqa: E402
 
@@ -26,13 +34,15 @@ CONFIG = "nemotron-3-super-120b-a12b-11l"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = ["num_hidden_layers", "n_routed_experts", "vocab_size",
            "max_position_embeddings"]
-NEW = ["kernel.moe_latent_busy", "kernel.moe_latent_roofline",
-       "moe.latent_rows_per_touched_expert", "moe.latent_load_imbalance",
-       "moe.route_sort_busy", "kernel.ssd64_step_busy",
-       "kernel.ssd64_step_roofline", "kernel.ssd64_chunk_busy",
-       "kernel.ssd64_chunk_roofline", "kernel.gqa16_attn_busy",
-       "kernel.gqa16_attn_roofline", "state.ssd64_rows_peak_share",
-       "kv.ssd64_blocks_peak_share", "state.ssd64_bytes_over_kv_bytes"]
+# PR 50's readers, under the names of the merged readers that took their
+# place in PR 68 (`moe.route_sort_busy` is the cell's own still).
+NEW = ["kernel.moe_experts_busy", "kernel.moe_experts_roofline",
+       "moe.rows_per_touched_expert", "moe.expert_load_imbalance",
+       "moe.route_sort_busy", "kernel.state_step_busy",
+       "kernel.state_step_roofline", "kernel.state_chunk_busy",
+       "kernel.state_chunk_roofline", "kernel.paged_attn_busy",
+       "kernel.paged_attn_roofline", "state.rows_peak_share",
+       "kv.blocks_peak_share", "state.bytes_over_cache_bytes"]
 
 
 def _load(path, name):
@@ -331,18 +341,14 @@ def test_the_benchmark_lists_the_cell_and_its_fourteen_metrics():
     assert config["source"].endswith(
         "nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16/blob/main/config.json")
     assert config["file"] == f"benchmarks/configs/{CONFIG}.json"
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == NEW
-    at = bench["per_layer"].index(mine[0])
-    assert bench["per_layer"][at:at + len(NEW)] == mine
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    mine = [by_name[name] for name in NEW]
+    assert all(CELL in m["workloads"] for m in mine)
     assert {m["layer"] for m in mine} == {"kernels", "state pool", "KV pool",
                                        "expert layer"}
     assert all(m["moves"] == "tokens_per_s" for m in mine)
     assert all(m["unit"] == "%" for m in mine
                if m["name"].endswith(("_roofline", "_busy")))
-    # No accepted metric's list gained the cell.
-    for m in bench["end_to_end"] + bench["per_layer"][:at]:
-        assert CELL not in m.get("workloads", [])
     with open(os.path.join(BENCH, "traffic", "agents.json")) as f:
         traffic = json.load(f)
     assert (traffic["loop"], traffic["clients"], traffic["block"],
@@ -356,24 +362,17 @@ def test_the_benchmark_lists_the_cell_and_its_fourteen_metrics():
     assert traffic["sharing"] == {"share": 0.0}
 
 
-def test_the_rehearsal_lists_every_metric_of_the_new_cell():
+def test_the_rehearsal_lists_every_metric_of_the_new_cell(tmp_path):
     """run.py --trace 1 on the CPU at the small size, a cell list of its own
     with the ten keyless per-layer metrics and the cell's own fourteen: the
     span and counter metrics print, what only a device trace gives is left
     out and said so."""
-    cells = os.path.join(DATA, "BENCHMARK.nemotron.test.json")
-    with open(cells) as f:
-        listed = json.load(f)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        real = json.load(f)
-    want = [m["name"] for m in real["per_layer"]
-            if CELL in m.get("workloads", [CELL])]
-    assert [m["name"] for m in listed["per_layer"]] == want
-    assert len(want) == 24 and want[10:] == NEW
-    assert [m["name"] for m in listed["end_to_end"]] == [
-        m["name"] for m in real["end_to_end"]
-        if CELL in m.get("workloads", [CELL])] == [
-        "itl_p95_ms", "tokens_per_s", "setup_s"]
+    cells = rehearsal_cells(tmp_path, "nemotron", CELL)
+    real = load_benchmark()
+    want = [m["name"] for m in metrics_listed(real, CELL)]
+    assert set(NEW) <= set(want)
+    assert [m["name"] for m in metrics_listed(real, CELL, "end_to_end")] \
+        == ["itl_p95_ms", "tokens_per_s", "setup_s"]
     proc = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"),
          "--benchmark-file", cells, "--workload", "nemotron.closed",
@@ -384,15 +383,15 @@ def test_the_rehearsal_lists_every_metric_of_the_new_cell():
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0
     got = line["metrics"]
-    device_only = {"device.idle", "device.idle_host", "device.hbm_peak_gb",
-                   "moe.route_sort_busy",
-                   *(name for name in NEW if name.startswith("kernel."))}
-    assert set(got) == set(want) - device_only
+    assert set(got) == read_without_a_device(real, CELL)
+    assert not {name for name in NEW if name.startswith("kernel.")} \
+        & set(got)
+    assert "moe.route_sort_busy" not in got
     assert got["step.compiles"] == {"value": 0, "unit": "compilations"}
     # Three clients of four slots; states and blocks of the same rows; a
     # quarter of the 16 experts held, top 6.
-    assert got["state.ssd64_rows_peak_share"]["value"] == 75.0
-    assert 1.0 < got["state.ssd64_bytes_over_kv_bytes"]["value"] < 40.0
-    assert 3.0 < got["kv.ssd64_blocks_peak_share"]["value"] < 40.0
-    assert 1.0 < got["moe.latent_rows_per_touched_expert"]["value"] < 20.0
-    assert 1.0 <= got["moe.latent_load_imbalance"]["value"] < 3.0
+    assert got["state.rows_peak_share"]["value"] == 75.0
+    assert 1.0 < got["state.bytes_over_cache_bytes"]["value"] < 40.0
+    assert 3.0 < got["kv.blocks_peak_share"]["value"] < 40.0
+    assert 1.0 < got["moe.rows_per_touched_expert"]["value"] < 20.0
+    assert 1.0 <= got["moe.expert_load_imbalance"]["value"] < 3.0

@@ -18,19 +18,29 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from bench_paths import BENCH, DATA, ROOT  # noqa: E402
+from bench_paths import (  # noqa: E402
+    BENCH,
+    DATA,
+    ROOT,
+    listed as metrics_listed,
+    load_benchmark,
+    read_without_a_device,
+    rehearsal_cells,
+)
 
 from lib import reference, roofline, roofline_gated_delta  # noqa: E402
+from lib.roofline_sizes import sizes  # noqa: E402
 
 CELL = "olmo-hybrid-7b-12l.digest"
 V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-NEW = ["kernel.gdn_chunk_busy", "kernel.gdn_chunk_roofline",
-       "kernel.gdn_step_busy", "kernel.gdn_step_roofline",
-       "kernel.mha128_attn_busy", "kernel.mha128_attn_roofline",
-       "state.rows_peak_share", "state.bytes_over_kv_bytes",
-       "kv.hybrid_blocks_peak_share", "step.hybrid_decode_ms",
-       "step.hybrid_decode_device_ms"]
+# PR 39's readers, under the names of the merged readers that took their
+# place in PR 68 (`step.hybrid_decode_device_ms` went with no successor).
+NEW = ["kernel.state_chunk_busy", "kernel.state_chunk_roofline",
+       "kernel.state_step_busy", "kernel.state_step_roofline",
+       "kernel.paged_attn_busy", "kernel.paged_attn_roofline",
+       "state.rows_peak_share", "state.bytes_over_cache_bytes",
+       "kv.blocks_peak_share", "step.decode_ms"]
 
 
 def _load(path, name):
@@ -95,17 +105,11 @@ def test_reference_logits_equal_the_program_s_in_float32(small):
     assert np.abs(ours - theirs).max() < 2e-4 * np.abs(theirs).max()
 
 
-@pytest.mark.parametrize("control", [
-    {"drop": "decay"}, {"drop": "double"}, {"drop": "conv_tail"},
-    {"drop": "state"}])
-def test_check_served_accepts_greedy_tokens_and_refuses_a_control(small,
-                                                                  control):
-    """The served tokens against the reference, then against the reference
-    with the decay left out, b not doubled, the conv tail or the state
-    dropped at every chunk boundary: each reads NOT correct. (The controls
-    one precision down, `drop: state_bf16` and `weights_as: float8_e4m3fn`,
-    are read on the chip at the published widths.)"""
-    config, spec, params, program, forward = small
+@pytest.fixture(scope="module")
+def served(small):
+    """Three prompts and the program's eight greedy tokens after each,
+    decoded once for all the controls."""
+    _, spec, _, program, _ = small
     rng = np.random.default_rng(1)
     samples = []
     for length in (5, 20, 50):
@@ -114,6 +118,21 @@ def test_check_served_accepts_greedy_tokens_and_refuses_a_control(small,
         for _ in range(8):
             seq.append(int(program(np.asarray(seq, np.int32))[-1].argmax()))
         samples.append((prompt, seq[length:]))
+    return samples
+
+
+@pytest.mark.parametrize("control", [
+    {"drop": "decay"}, {"drop": "double"}, {"drop": "conv_tail"},
+    {"drop": "state"}])
+def test_check_served_accepts_greedy_tokens_and_refuses_a_control(
+        small, served, control):
+    """The served tokens against the reference, then against the reference
+    with the decay left out, b not doubled, the conv tail or the state
+    dropped at every chunk boundary: each reads NOT correct. (The controls
+    one precision down, `drop: state_bf16` and `weights_as: float8_e4m3fn`,
+    are read on the chip at the published widths.)"""
+    config, spec, params, program, forward = small
+    samples = served
     ok, details = reference.check_served(forward, params, config["reference"],
                                          samples, 0.05, 0.9, pad_to=64)
     assert ok, details
@@ -254,15 +273,12 @@ def test_the_benchmark_lists_the_cell_and_its_eleven_metrics():
                                  "max_position_embeddings"]
     assert config["source"].endswith("allenai/Olmo-Hybrid-7B/blob/main/"
                                      "config.json")
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == NEW
-    at = bench["per_layer"].index(mine[0])
-    assert bench["per_layer"][at:at + 11] == mine
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    mine = [by_name[name] for name in NEW]
+    assert all(CELL in m["workloads"] for m in mine)
     assert {m["layer"] for m in mine} == {"kernels", "state pool", "KV pool",
                                        "step function"}
     assert all(m["moves"] == "tokens_per_s" for m in mine)
-    for m in bench["end_to_end"] + bench["per_layer"][:at]:
-        assert CELL not in m.get("workloads", [])
     with open(os.path.join(BENCH, "traffic", "digest.json")) as f:
         traffic = json.load(f)
     assert (traffic["loop"], traffic["clients"], traffic["block"],
@@ -278,15 +294,20 @@ def test_the_benchmark_lists_the_cell_and_its_eleven_metrics():
 # -- the counting ----------------------------------------------------------------
 
 def test_sizes_of_the_configuration_as_run(published):
-    assert roofline_gated_delta.sizes(published) == {
-        "layers": (3, 9), "heads": 30, "head_dim": 128, "lin_heads": 30,
-        "key_dim": 96, "value_dim": 192, "bytes_per_element": 2}
+    assert sizes(published) == {
+        "attention": {"kernel": "paged", "layers": 3, "heads": 30,
+                      "kv_heads": 30, "head_dim": 128, "lanes": 7680,
+                      "bytes_per_element": 2},
+        "experts": None,
+        "recurrence": {"kind": "gdn", "layers": 9, "heads": 30,
+                       "state": (192, 96), "gate_lanes": 0,
+                       "step": "gdn_step", "chunk": "gdn_chunk"}}
 
 
 def test_a_state_is_2_21_mb_and_a_token_of_k_v_15_360_bytes(published):
     """ISSUE 39's figures: 30 x 192 x 96 float32 a row and linear layer;
     2 x 30 heads x 128 lanes x 2 B a token and full layer."""
-    size = roofline_gated_delta.sizes(published)
+    size = sizes(published)["recurrence"]
     assert roofline_gated_delta.state_bytes(size) == 2211840
     assert roofline.attention_bytes(1, 1, 30, 128, 2) == 15360
     assert roofline.attention_bytes(1, 3, 30, 128, 2) == 46080
@@ -296,7 +317,7 @@ def test_a_decode_tick_s_steps_are_bound_by_their_states(published):
     """15 rows x 9 layers: 2 x 2.21 MB of state each and 69 KB of q, k, v
     and read, 0.61 GB, 0.74 ms at the HBM peak; 3 x 2 x 30 x 96 x 192
     operations a row and layer, 0.45 GFLOP, 2 us."""
-    size = roofline_gated_delta.sizes(published)
+    size = sizes(published)["recurrence"]
     n_bytes = roofline_gated_delta.recurrence_bytes(15, 15, size)
     assert n_bytes == 15 * 9 * (2 * 2211840 + 30 * 576 * 4)
     flops = roofline_gated_delta.recurrence_flops(15, size)
@@ -307,7 +328,7 @@ def test_a_decode_tick_s_steps_are_bound_by_their_states(published):
 
 
 def test_a_chunk_s_state_is_read_once_a_row_not_once_a_token(published):
-    size = roofline_gated_delta.sizes(published)
+    size = sizes(published)["recurrence"]
     one = roofline_gated_delta.recurrence_bytes(1, 241, size)
     assert one == 9 * (2 * 2211840 + 241 * 30 * 576 * 4)
     assert one < roofline_gated_delta.recurrence_bytes(241, 241, size) / 5
@@ -315,24 +336,17 @@ def test_a_chunk_s_state_is_read_once_a_row_not_once_a_token(published):
 
 # -- the rehearsal -------------------------------------------------------------------
 
-def test_the_rehearsal_lists_every_metric_of_the_new_cell():
+def test_the_rehearsal_lists_every_metric_of_the_new_cell(tmp_path):
     """run.py --trace 1 on the CPU at the small size, a cell list of its
     own with the ten keyless per-layer metrics and the cell's own eleven: the
     span and counter metrics print, what only a device trace gives is left
     out and said so; the untraced run prints the three end-to-end ones."""
-    cells = os.path.join(DATA, "BENCHMARK.olmo.test.json")
-    with open(cells) as f:
-        listed = json.load(f)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        real = json.load(f)
-    want = [m["name"] for m in real["per_layer"]
-            if CELL in m.get("workloads", [CELL])]
-    assert [m["name"] for m in listed["per_layer"]] == want
-    assert len(want) == 21 and want[10:] == NEW
-    assert [m["name"] for m in listed["end_to_end"]] == [
-        m["name"] for m in real["end_to_end"]
-        if CELL in m.get("workloads", [CELL])] == [
-        "itl_p95_ms", "tokens_per_s", "setup_s"]
+    cells = rehearsal_cells(tmp_path, "olmo", CELL)
+    real = load_benchmark()
+    want = [m["name"] for m in metrics_listed(real, CELL)]
+    assert set(NEW) <= set(want)
+    assert [m["name"] for m in metrics_listed(real, CELL, "end_to_end")] \
+        == ["itl_p95_ms", "tokens_per_s", "setup_s"]
     env = dict(os.environ, TPU_ENGINE_PLATFORM="cpu")
     lines = {}
     for trace in ("1", "0"):
@@ -348,11 +362,11 @@ def test_the_rehearsal_lists_every_metric_of_the_new_cell():
     assert set(lines["0"]["metrics"]) == {"itl_p95_ms", "tokens_per_s",
                                           "setup_s"}
     got = lines["1"]["metrics"]
-    device_only = {"device.idle", "device.idle_host", "device.hbm_peak_gb",
-                   *(name for name in NEW if name.startswith("kernel."))}
-    assert set(got) == set(want) - device_only
+    assert set(got) == read_without_a_device(real, CELL)
+    assert not {name for name in NEW if name.startswith("kernel.")} \
+        & set(got)
     assert got["step.compiles"] == {"value": 0, "unit": "compilations"}
     # Three clients of four slots; states and blocks of the same rows.
     assert got["state.rows_peak_share"]["value"] == 75.0
-    assert 0.2 < got["state.bytes_over_kv_bytes"]["value"] < 2.0
-    assert 5.0 < got["kv.hybrid_blocks_peak_share"]["value"] < 40.0
+    assert 0.2 < got["state.bytes_over_cache_bytes"]["value"] < 2.0
+    assert 5.0 < got["kv.blocks_peak_share"]["value"] < 40.0

@@ -164,20 +164,23 @@ def test_the_cell_and_its_files():
         "gen_prefill_chunk"]                        # crosses two chunks
     assert (max(correct["prompt_lens"]) + correct["new_tokens"]
             <= correct["pad_to"] == 640)
+    # PR 58's four readers: the whole decode tick's is the cell's own; the
+    # paged read's pair and the pool's share are the merged readers' since
+    # PR 68 (`kernel.mha16_attn_*`, `kv.loop_planes_peak_share` before).
     listed = [m["name"] for m in BENCHMARK["per_layer"]
               if m.get("workloads") == [CELL]]
-    assert sorted(listed) == ["kernel.mha16_attn_busy",
-                              "kernel.mha16_attn_roofline",
-                              "kv.loop_planes_peak_share",
-                              "step.loop_decode_hbm_roofline"]
-    for name in listed:
+    assert "step.loop_decode_hbm_roofline" in listed
+    for name in ("step.loop_decode_hbm_roofline", "kernel.paged_attn_busy",
+                 "kernel.paged_attn_roofline", "kv.blocks_peak_share"):
+        metric, = [m for m in BENCHMARK["per_layer"] if m["name"] == name]
+        assert CELL in metric["workloads"]
         assert os.path.exists(os.path.join(BENCH, "layer_metrics",
                                            name + ".py"))
-    # The readers by part that were there list the cell as their last.
+    # The readers by part that were there list the cell.
     for name in ("step.attn_busy", "step.attn_read_busy", "step.ffn_busy",
                  "step.decode_run_ms"):
         metric, = [m for m in BENCHMARK["per_layer"] if m["name"] == name]
-        assert metric["workloads"][-1] == CELL
+        assert CELL in metric["workloads"]
     assert len(BENCHMARK["per_layer"]) <= 128
     assert os.path.exists(os.path.join(BENCH, "references", "ouro.py"))
 

@@ -162,10 +162,23 @@ def test_the_cell_and_its_files():
     assert len(correct["prompt_lens"]) >= 4
     assert max(correct["prompt_lens"]) + correct["new_tokens"] <= correct[
         "pad_to"]
+    # PR 53's eleven readers: the four of a block-decoding lane's passes
+    # are the cell's own; its two kernels' pairs, its experts' two counters
+    # and its pool's share are the merged readers' since PR 68.
     listed = [m["name"] for m in BENCHMARK["per_layer"]
               if m.get("workloads") == [CELL]]
-    assert len(listed) == 11
-    for name in listed:
+    own = ["sched.passes_per_block", "sched.tokens_per_row_tick",
+           "sched.commit_pass_share", "step.block_decode_ms"]
+    assert set(own) <= set(listed)
+    for name in own + ["kernel.paged_attn_busy",
+                          "kernel.paged_attn_roofline",
+                          "kernel.moe_experts_busy",
+                          "kernel.moe_experts_roofline",
+                          "moe.rows_per_touched_expert",
+                          "moe.expert_load_imbalance",
+                          "kv.blocks_peak_share"]:
+        metric, = [m for m in BENCHMARK["per_layer"] if m["name"] == name]
+        assert CELL in metric["workloads"]
         assert os.path.exists(os.path.join(BENCH, "layer_metrics",
                                            name + ".py"))
     assert os.path.exists(os.path.join(BENCH, "references", "sdar.py"))

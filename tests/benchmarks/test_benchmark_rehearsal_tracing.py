@@ -16,7 +16,6 @@ from bench_paths import BENCH, DATA, ROOT  # noqa: E402
 RUN = os.path.join(BENCH, "run.py")
 CELLS = os.path.join(DATA, "BENCHMARK.tracing.test.json")
 NEW_FROM_SPANS_AND_COUNTERS = {
-    "sched.host_gap_ms", "step.decode_device_ms", "step.prefill_device_ms",
     "sched.budget_wait_ms", "lane.slot_wait_ms", "lane.ttft_p50_ms",
     "step.compiles"}
 
@@ -59,12 +58,10 @@ def test_traced_run_reads_every_new_span_and_counter_metric():
     assert "device.idle_host found nothing to read" in proc.stderr
     # The window is warm: the step programs were compiled in set-up.
     assert got["step.compiles"] == {"value": 0, "unit": "compilations"}
-    # The step is inside the tick, and a tick's host work is not nothing.
-    assert 0 < got["step.decode_device_ms"]["value"] \
-        < got["step.decode_ms"]["value"]
-    assert 0 < got["step.prefill_device_ms"]["value"] \
-        < got["step.prefill_ms"]["value"]
-    assert got["sched.host_gap_ms"]["value"] > 0
+    # A tick's spans are read (the three readers of the phases' marks,
+    # `sched.host_gap_ms` and `step.*_device_ms`, went with PR 68).
+    assert 0 < got["step.decode_ms"]["value"]
+    assert 0 < got["step.prefill_ms"]["value"]
     assert got["lane.slot_wait_ms"]["value"] > 0
     assert got["sched.budget_wait_ms"]["value"] >= 0
     # The lane's first token lies inside the client's.

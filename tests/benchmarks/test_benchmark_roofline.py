@@ -1,5 +1,5 @@
-"""lib/roofline.py pinned by cases computed by hand, and the traced slice
-as the sampler hands it to the readers."""
+"""lib/roofline.py and lib/roofline_sizes.py pinned by cases computed by
+hand, and the traced slice as the sampler hands it to the readers."""
 
 import json
 import os
@@ -12,6 +12,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from bench_paths import BENCH  # noqa: E402
 
 from lib import roofline  # noqa: E402
+from lib.roofline_sizes import sizes  # noqa: E402
 
 V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 
@@ -21,23 +22,118 @@ def _config(name):
         return json.load(f)
 
 
-@pytest.mark.parametrize("name, want", [
-    ("gpt2-large", {"layers": 36, "heads": 20, "kv_heads": 20,
-                    "head_dim": 64, "bytes_per_element": 2}),
-    ("mistral-7b-v0.2-8l", {"layers": 8, "heads": 32, "kv_heads": 8,
-                            "head_dim": 128, "bytes_per_element": 2}),
-])
-def test_attention_sizes_of_the_configurations_as_run(name, want):
-    assert roofline.attention_sizes(_config(name)) == want
+def _kv(layers, heads, kv_heads, head_dim, kernel="paged"):
+    return {"kernel": kernel, "layers": layers, "heads": heads,
+            "kv_heads": kv_heads, "head_dim": head_dim,
+            "lanes": 2 * kv_heads * head_dim, "bytes_per_element": 2}
 
 
-def test_attention_sizes_takes_a_stated_head_size_and_a_quantized_pool():
+def _latent(layers, heads):
+    return {"kernel": "mla_latent", "layers": layers, "heads": heads,
+            "latent": 512, "rope": 64, "lanes": 576, "bytes_per_element": 2}
+
+
+def _experts(rows, cols, held, matrices=3):
+    return {"kernel": "ragged-dot", "matrices": matrices, "rows": rows,
+            "cols": cols, "held": held, "bytes_per_element": 2}
+
+
+def _ssd(layers, heads, state, groups):
+    return {"kind": "ssd", "layers": layers, "heads": heads, "state": state,
+            "groups": groups, "step": "ssd_step", "chunk": "ssd_chunk"}
+
+
+def _delta(kind, layers, heads, state, gate_lanes):
+    return {"kind": kind, "layers": layers, "heads": heads, "state": state,
+            "gate_lanes": gate_lanes, "step": kind + "_step",
+            "chunk": kind + "_chunk"}
+
+
+# Every committed configuration: the sizes its family's library of PRs
+# 27-64 read from it (lib/roofline.py `attention_sizes`, the `sizes` of
+# lib/roofline_<family>.py), and for the two configurations that came when
+# `per_layer` was full, the sizes their PRs' PERF.md entries counted by hand.
+SIZES = {
+    "gpt2-large": (_kv(36, 20, 20, 64), None, None),
+    "mistral-7b-v0.2-8l": (_kv(8, 32, 8, 128), None, None),
+    "moonlight-16b-a3b-7l": (_latent(7, 16),
+                             _experts(2048, 1408, (0, 64)), None),
+    # two attention classes: lib/roofline_laguna.py has their sizes
+    "laguna-s-2.1-5l": (None, _experts(3072, 1024, (0, 128)), None),
+    "olmo-hybrid-7b-12l": (_kv(3, 30, 30, 128), None,
+                           _delta("gdn", 9, 30, (192, 96), 0)),
+    "kimi-linear-48b-a3b-5l": (_latent(1, 32),
+                               _experts(2304, 1024, (0, 128)),
+                               _delta("kda", 4, 32, (128, 128), 128)),
+    "falcon-h1-34b-6l": (_kv(6, 20, 4, 128), None,
+                         _ssd(6, 32, (128, 256), 2)),
+    "nemotron-3-super-120b-a12b-11l": (
+        _kv(1, 32, 2, 128), _experts(1024, 2688, (0, 128), matrices=2),
+        _ssd(5, 128, (64, 128), 8)),
+    "sdar-30b-a3b-chat-7l": (_kv(7, 32, 4, 128, kernel="block_mask_read"),
+                             _experts(2048, 768, (0, 128)), None),
+    # a cache plane a (pass, layer): 4 x 48
+    "ouro-2.6b": (_kv(192, 16, 16, 128), None, None),
+    # 2 attention layers of the 9 as run (the 7 conv layers keep a tail,
+    # no recurrence with a kernel of its own); every expert held
+    "lfm2-24b-a2b-9l": (_kv(2, 32, 8, 64),
+                        _experts(2048, 1536, (0, 64)), None),
+    # 9 mamba layers and 1 attention layer of the 10 as run; half held
+    "granite-4.0-h-small-10l": (_kv(1, 32, 8, 128),
+                                _experts(4096, 768, (0, 36)),
+                                _ssd(9, 128, (64, 128), 1)),
+}
+
+
+def test_every_configuration_pinned_here_is_committed():
+    """The twelve of PR 68. A configuration that comes later pins its sizes
+    in its own test file (test_benchmark_layer_metrics_merged.py demands a
+    made-up run at them of every cell on a merged list)."""
+    committed = {name[:-len(".json")]
+                 for name in os.listdir(os.path.join(BENCH, "configs"))}
+    assert set(SIZES) <= committed
+
+
+@pytest.mark.parametrize("name", sorted(SIZES))
+def test_sizes_of_the_configurations_as_run(name):
+    attention, experts, recurrence = SIZES[name]
+    assert sizes(_config(name)) == {"attention": attention,
+                                    "experts": experts,
+                                    "recurrence": recurrence}
+
+
+def test_sizes_take_a_stated_head_size_and_a_quantized_pool():
     config = {"kwargs": {"n_layers": 16, "d_model": 2048, "n_heads": 16,
                          "d_head": 96},
               "serving": {"dtype": "bfloat16", "gen_kv_quantize": "int8"}}
-    assert roofline.attention_sizes(config) == {
-        "layers": 16, "heads": 16, "kv_heads": 16, "head_dim": 96,
-        "bytes_per_element": 1}
+    assert sizes(config)["attention"] == {
+        "kernel": "paged", "layers": 16, "heads": 16, "kv_heads": 16,
+        "head_dim": 96, "lanes": 3072, "bytes_per_element": 1}
+    # A run object that carries no configuration states nothing.
+    assert sizes({}) == {"attention": None, "experts": None,
+                         "recurrence": None}
+
+
+def test_sizes_of_a_family_in_words_it_does_not_know_are_none_by_part():
+    """The next family edits no file: a part `sizes` has no word for reads
+    None (the family brings that kernel's reader and counts), and the parts
+    it does state are still read, so its cell can join those merged lists."""
+    config = {"kwargs": {"n_layers": 4, "d_model": 512, "n_heads": 8,
+                         "layer_types": ["attention", "retention",
+                                         "retention", "retention"],
+                         "retention_heads": 8, "n_experts": 16,
+                         "expert_shape": [512, 256]},
+              "serving": {"dtype": "bfloat16"}}
+    got = sizes(config)
+    assert got["experts"] is None and got["recurrence"] is None
+    assert got["attention"] == _kv(1, 8, 8, 64)
+    # Layers of a kind it does not know, with a recurrence it does not know.
+    config["kwargs"]["layer_types"][1:] = ["mamba"] * 3
+    assert sizes(config)["recurrence"] is None
+    # No depth stated at all: nothing attends as far as it can tell.
+    assert sizes({"kwargs": {"d_model": 512}, "serving": {
+        "dtype": "bfloat16"}}) == {"attention": None, "experts": None,
+                                   "recurrence": None}
 
 
 @pytest.mark.parametrize("args, want", [
