@@ -53,6 +53,17 @@ that):
            forward's largest logit (the XLA chunked form, no kernel), twice
            the same, every layer routed, every state row given back. The
            XLA gather read, as `blocks`.
+  routes   one lane of `smallthinker-small-test` (experts chosen from the
+           layer's input before its attention, ReGLU, three rotated window
+           layers to one un-rotated full layer at G = 7, window 48): a
+           prompt of 100 tokens across seven chunks, past the window,
+           beside a prompt of one token, every served token within 0.05 of
+           the one-shot forward's largest logit, twice the same, window
+           blocks given back while the row ran and both pools idle after.
+           The XLA gather read, as `blocks`; the compiled window read by
+           class at the cell's 28 / 4 x 128, window 4096, and its banks'
+           grouped product are in the kernel phase (`kernel_check.
+           CELL_SHAPES`, `CLASS_SHAPES`, `GROUPED_SHAPES`).
   cache    the same launch again must reach ready without adding an entry
            to the compile cache.
   lanes    with >= 4 devices: --lanes 0 gives four lanes on four distinct
@@ -264,6 +275,54 @@ print(json.dumps({"groups": "ok", "tokens": first[0],
                   "distinct": len(set(first[0] + first[1]))}))
 """
 
+_ROUTES_CHILD = r"""
+import json
+import jax
+import jax.numpy as jnp
+import numpy as np
+from tpu_engine.models.registry import (_ensure_builtin_models_imported,
+                                        create_model)
+from tpu_engine.models.smallthinker import smallthinker_apply
+from tpu_engine.runtime.scheduler import ContinuousGenerator
+assert jax.default_backend() == "tpu", jax.default_backend()
+_ensure_builtin_models_imported()
+spec = create_model("smallthinker-small-test")
+cfg = spec.config
+params = jax.jit(spec.init)(jax.random.PRNGKey(0))
+prompts = [list(range(7, 107)), [5]]        # past the window of 48; one token
+gen = ContinuousGenerator(spec, params=params, n_slots=4, dtype="float32",
+                          kv_block_size=16, prefill_chunk=16,
+                          prefix_sharing=False)
+try:
+    first = [f.result(300) for f in
+             [gen.submit(p, max_new_tokens=9) for p in prompts]]
+    again = [f.result(300) for f in
+             [gen.submit(p, max_new_tokens=9) for p in prompts]]
+    stats = gen.stats()
+finally:
+    gen.stop()
+assert first == again and [len(t) for t in first] == [9, 9], (first, again)
+worst = 0.0
+with jax.default_matmul_precision("highest"):
+    for prompt, tokens in zip(prompts, first):
+        seq = jnp.asarray([prompt + tokens[:-1]], jnp.int32)
+        logits = np.asarray(smallthinker_apply(
+            params, seq, cfg, dtype=jnp.float32)[0])[len(prompt) - 1:]
+        gap = logits.max(-1) - logits[np.arange(len(tokens)), tokens]
+        worst = max(worst, float((gap / logits.std(-1)).max()))
+assert worst < 0.05, worst
+mixed, pool, moe = stats["mixed"], stats["kv_pool"], stats["moe"]
+assert mixed["ticks"] == mixed["dispatches"] > 0, mixed
+assert pool["blocks_free"] == pool["blocks_total"], pool
+assert pool["window_blocks_held"] == pool["full_blocks_held"] == 0, pool
+assert pool["window_blocks_freed"] > 0, pool
+assert np.asarray(moe["rows_by_expert"]).shape == (8, 8), moe
+assert moe["assignments"] == moe["assignments_held"], moe
+print(json.dumps({"routes": "ok", "tokens": first[0],
+                  "worst_gap_in_logit_std": worst,
+                  "window_blocks_freed": pool["window_blocks_freed"],
+                  "distinct": len(set(first[0] + first[1]))}))
+"""
 _DEVICE_CHILD = r"""
 import importlib.metadata as md, json, sys
 import jax, jaxlib
@@ -716,6 +775,13 @@ def main():
         check(json.loads(out.strip().splitlines()[-1])["groups"] == "ok",
               "the one-group recurrence lane's smoke did not end ok")
     say(phase="groups", seconds=times["groups"])
+
+    with phase("routes"):
+        out = run_child("routes", [sys.executable, "-c", _ROUTES_CHILD], 300,
+                        env={"TPU_ENGINE_PAGED": "0"})
+        check(json.loads(out.strip().splitlines()[-1])["routes"] == "ok",
+              "the early-route lane's smoke did not end ok")
+    say(phase="routes", seconds=times["routes"])
 
     if device["count"] >= 4:
         with phase("lanes"):

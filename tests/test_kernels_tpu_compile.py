@@ -373,6 +373,12 @@ def test_the_packed_fold_compiles_for_v5e_at_every_cell_s_shape(
     # are one packed tile, a tall tile is 32 slots = 128 query rows = one
     # tile of the grid; 16 rows and ceil(384 / 32) more tall tiles.
     ("lfm2-24b-a2b-9l.assist/classes/W256", [(16, 1), (28, 1)]),
+    # G = 7 over 4 KV heads under a window of 4096: a decode row's 4 x 7
+    # query rows are one packed tile, a tall tile of 128 slots 896 query
+    # rows = seven tiles of the grid; 8 rows and ceil(288 / 128) more tall
+    # tiles. Both calls carry the lower bound.
+    ("smallthinker-21b-a3b-8l.history/window/classes/W256",
+     [(8, 1), (11, 7)]),
 ])
 def test_the_two_classes_of_tile_are_two_calls_with_grids_of_their_own(
         v5e_devices, name, grids):
@@ -881,6 +887,81 @@ def test_windowed_mixed_step_copies_no_pool_and_no_bank(v5e_devices, width):
         sizes |= {math.prod(x.k.shape), math.prod(x.k.shape[1:])}
     assert not _moved(hlo, sizes)
     assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+@pytest.mark.parametrize("width", [1, 256])
+def test_early_route_mixed_step_copies_no_pool_and_no_bank(v5e_devices,
+                                                           width):
+    """The SmallThinker cell's mixed step at its serving shapes (shapes
+    only), both pools donated, compiled for one v5e: at width 1 one packed
+    call a layer (4 x 7 query rows a tile), at width 256 a second call a
+    layer in tall tiles of 128 slots, the window layers' under
+    `swa_window_read` (the lower bound handed to both classes), and the
+    ReGLU banks' grouped product; no `copy`, `slice`, `dynamic-slice` or
+    `dynamic-update-slice` whose result is a pool, a layer of one, or a
+    layer's bank (377 MB and 189 MB); temporaries of tens of MB beside 7.9
+    GB of weights and 3.5 GB of pools."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_engine.models.registry import (
+        _ensure_builtin_models_imported,
+        create_model,
+    )
+    from tpu_engine.ops.paged_attention import ragged_paged_attention
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "smallthinker-21b-a3b-8l.json")) as f:
+        bench = json.load(f)
+    serving = bench["serving"]
+    assert width in (1, serving["gen_prefill_chunk"])
+    _ensure_builtin_models_imported()
+    spec = create_model(bench["factory"], **bench["kwargs"])
+    cfg = spec.config
+    rows, bs = serving["gen_max_batch_size"], serving["gen_kv_block_size"]
+    on_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip)
+
+    def pool(kind, blocks):
+        one = placed(jax.ShapeDtypeStruct(
+            (kind.n_layers, blocks, bs, kind.kv_lanes[0]), jnp.bfloat16))
+        return KVCache(one, one)
+
+    per_row = -(-(cfg.window + serving["gen_prefill_chunk"]) // bs) + 1
+    pools = (pool(cfg.kv_block_kinds[0], serving["gen_kv_blocks"]),
+             pool(cfg.kv_block_kinds[1], rows * per_row + 1))
+    assert pools[1].k.shape == (6, 8737, 16, 512)
+    params = jax.tree.map(placed,
+                          jax.eval_shape(spec.init, jax.random.PRNGKey(0)))
+
+    def tick(params, caches, tables, tokens, pos0, qlen):
+        return spec.ragged_step(
+            params, tokens, caches, tables, pos0, qlen, cfg,
+            attn_fn=functools.partial(ragged_paged_attention,
+                                      interpret=False),
+            sample_slot=jnp.zeros_like(pos0),
+            max_tokens=serving["gen_prefill_chunk"] + rows)
+
+    def host(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    table = host(rows, -(-cfg.max_seq // bs))
+    step, behind = _behind_a_step(tick, host(rows))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, (table, table), host(rows, width), host(rows),
+        host(rows), *behind).compile()
+    hlo = compiled.as_text()
+    calls = (1 if width == 1 else 2) * cfg.n_layers
+    assert len(re.findall(r"= \S+ custom-call\(.*tpu_custom_call", hlo)) \
+        >= calls
+    assert "swa_window_read" in hlo and "ragged-dot" in hlo
+    banks = jax.tree.leaves(params["layers"][1]["mlp"]["experts"])
+    sizes = {math.prod(x.shape) for x in banks}
+    for x in pools:
+        sizes |= {math.prod(x.k.shape), math.prod(x.k.shape[1:])}
+    assert not _moved(hlo, sizes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 96e6
 
 
 @pytest.mark.parametrize("width", [1, 256])

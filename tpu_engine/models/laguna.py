@@ -376,7 +376,9 @@ def laguna_step_rows_ragged(params, tokens, caches, tables, pos0, qlen,
     null block wherever the row's first new token no longer sees (the
     blocks it gave back): the read is `ops.paged_attention`'s ragged read
     with `window`, a TILE of the list a row of the call, so a tile walks
-    only the columns its own slots see. A FULL layer walks a row's whole
+    only the columns its own slots see (~520 at this window of 512; a
+    model whose window is thousands of columns reads its window layers by
+    class too: `models.smallthinker`). A FULL layer walks a row's whole
     context, so its tiles follow the row's run (`PagedKV.attend`,
     `ops.paged_attention.ragged_read_by_class`): a row with one new token
     is a row of a width-1 call, the 8 KV heads packed, and a longer run is

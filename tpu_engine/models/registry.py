@@ -34,12 +34,13 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 # (migration, handoff, prefix fetch) carry a K and a V of equal width,
 # speculative verify and the dense per-slot cache have no latent read.
 # "kv_windowed": a kv_paged chain whose sliding-window layers keep only
-# the blocks their window still sees (models.laguna): a row holds blocks of
-# two kinds, and the window kind's are given back as the row's position
-# passes them. Served by the mixed tick alone. A freed block can serve no
-# prefix hit, be demoted to no host tier and ride no chain, so prefix
-# sharing, the host tier, migration and handoff are ABSENT, with int8
-# (the window read takes no scales), `--tp` and speculative verify.
+# the blocks their window still sees (models.laguna, models.smallthinker):
+# a row holds blocks of two kinds, and the window kind's are given back as
+# the row's position passes them. Served by the mixed tick alone. A freed
+# block can serve no prefix hit, be demoted to no host tier and ride no
+# chain, so prefix sharing, the host tier, migration and handoff are
+# ABSENT, with int8 (the window read takes no scales), `--tp` and
+# speculative verify.
 # "kv_and_state": a row holds a chain over the block pool, which holds the
 # layers that attend, AND one row of a state pool (the recurrent mixers'
 # fixed-size state and conv tail), admitted, parked and released as one:
@@ -426,6 +427,7 @@ def _ensure_builtin_models_imported():
 
     for optional in ("bert", "gpt2", "llama", "yolo", "ssd", "moonlight",
                      "laguna", "olmo_hybrid", "kimi_linear", "falcon_h1",
-                     "nemotron_h", "sdar", "ouro", "lfm2", "granite_hybrid"):
+                     "nemotron_h", "sdar", "ouro", "lfm2", "granite_hybrid",
+                     "smallthinker"):
         if importlib.util.find_spec(f"tpu_engine.models.{optional}") is not None:
             importlib.import_module(f"tpu_engine.models.{optional}")

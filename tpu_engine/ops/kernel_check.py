@@ -22,7 +22,10 @@ pipeline can get wrong. Two consumers:
   and compare it with its XLA reference (`dot_product_attention`, the
   `*_reference` gathers) run on f32 copies of the same operands at the
   highest matmul precision. A miss, a compile error or a non-TPU backend
-  exits non-zero.
+  exits non-zero. With names after it, `... kernel_check smallthinker
+  several-groups`, only the cases whose name holds one of them run: a PR
+  checks the cases it adds in minutes, where the whole list no longer
+  fits a cold chip call (ROADMAP A8).
 - tests/test_kernels_tpu_compile.py — AOT-compiles the same cases for a
   v5e topology from this sandbox, no chip attached
   (`compile_for_topology`). That is also the recipe for kernel work:
@@ -140,6 +143,15 @@ CELL_SHAPES = {
         n_blocks=2049, window=512,
         rows=tuple((8, 4096 + 8 * t) for t in range(8))
         + ((1, 700), (1, 5000), (5, 0), (0, 0))),
+    # history (benchmarks/configs/smallthinker-21b-a3b-8l.json): G = 7 over
+    # 4 KV heads, a window of 4096 (the walk's lower bound up to nine
+    # thousand columns in), under a table of 16384 columns. Width 1: 32
+    # decode rows of 2-13 k columns, the 4 x 7 query rows packed; a row in
+    # eight is short of the window. (The tall tile: `CLASS_SHAPES`.)
+    "smallthinker-21b-a3b-8l.history/window/W1": dict(
+        geo=dict(n_heads=28, n_kv_heads=4, d_head=128), table_len=1024,
+        n_blocks=8737, window=4096,
+        rows=tuple((1, 2000 + 355 * r) for r in range(32))),
     # digest (benchmarks/configs/olmo-hybrid-7b-12l.json): the full layers
     # of a model whose other layers are recurrent, G = 1 at head size 128,
     # 30 KV heads (3840 lanes a token: the walk's groups are 8 blocks).
@@ -213,6 +225,20 @@ CLASS_SHAPES = {
         n_blocks=40961, max_tokens=384,
         rows=((1, 300), (242, 3000), (1, 2900), (0, 0))
         + tuple((1, 130 + 250 * r) for r in range(12))),
+    # history (benchmarks/configs/smallthinker-21b-a3b-8l.json): the WINDOW
+    # layers' two calls at G = 7 over 4 KV heads, `window` 4096 handed to
+    # both: a decode row's 4 x 7 query rows are one packed tile, a tall tile
+    # of 128 slots 896 query rows = seven tiles of the grid whose edges a
+    # slot's heads straddle. A 242-slot chunk at column 5000 (its lower
+    # bound 900 columns in, the table behind it null) beside decode rows
+    # past the window, short of it and a free slot (8 of the lane's 32
+    # rows, contexts to 5.3 k: the check's gather reference holds every
+    # row's scores at once).
+    "smallthinker-21b-a3b-8l.history/window/classes/W256": dict(
+        geo=dict(n_heads=28, n_kv_heads=4, d_head=128), table_len=1024,
+        n_blocks=8737, max_tokens=288, window=4096,
+        rows=((1, 300), (242, 5000), (1, 4095), (0, 0), (1, 5200),
+              (1, 4096), (1, 2900), (1, 4500))),
 }
 
 # The grouped product of the served expert layers (`ops.moe.routed_experts`)
@@ -221,7 +247,8 @@ CLASS_SHAPES = {
 # chunk of 256 prompt tokens. `slots` x `top_k` pairs, of which the share
 # routed to the `held` experts form rows. `gated`: a SwiGLU bank {"gate_up"
 # (G, lanes, 2 hidden), "down"}; else two matrices {"up", "down"} with
-# relu^2 between them (the agents cell's, in a 1024-lane latent).
+# relu^2 between them (the agents cell's, in a 1024-lane latent). `gate`:
+# a gated bank's activation where it is not SiLU ("relu": a ReGLU).
 GROUPED_SHAPES = {
     "moonlight/grouped/2048x1408/decode": dict(
         slots=32, valid=32, top_k=6, n_experts=64, held=(0, 64),
@@ -262,6 +289,14 @@ GROUPED_SHAPES = {
     "granite_hybrid/grouped/4096x768/chunk": dict(
         slots=320, valid=300, top_k=10, n_experts=72, held=(0, 36),
         lanes=4096, hidden=768, gated=True),
+    # history: every one of 64 ReGLU experts held, banks of 2560 x 1536
+    # and 768 x 2560; 3 rows an expert a decode tick, ~26 in a chunk tick.
+    "smallthinker/grouped/2560x768/decode": dict(
+        slots=32, valid=32, top_k=6, n_experts=64, held=(0, 64),
+        lanes=2560, hidden=768, gated=True, gate="relu"),
+    "smallthinker/grouped/2560x768/chunk": dict(
+        slots=288, valid=280, top_k=6, n_experts=64, held=(0, 64),
+        lanes=2560, hidden=768, gated=True, gate="relu"),
 }
 
 
@@ -532,6 +567,8 @@ def grouped_cases():
         held, lanes, hidden = shape["held"], shape["lanes"], shape["hidden"]
         gated = shape["gated"]
         first = "gate_up" if gated else "up"
+        act = (jax.nn.relu if shape.get("gate") == "relu"
+               else jax.nn.silu if gated else moe.relu2)
 
         def operands(n=n, k=k, e=e, held=held, lanes=lanes, hidden=hidden,
                      live=shape["valid"], gated=gated, first=first):
@@ -551,9 +588,10 @@ def grouped_cases():
 
         kernel = functools.partial(
             moe.routed_experts, first_group=-held[0], n_experts=e, held=held,
-            max_tokens=n, activation=None if gated else moe.relu2)
+            max_tokens=n, activation=act)
 
-        def check(out, operands, e=e, held=held, gated=gated, first=first):
+        def check(out, operands, e=e, held=held, gated=gated, first=first,
+                  act=act):
             (y, rows), (x, valid, experts, weights, bank) = out, operands
             gates = jnp.zeros((x.shape[0], e)).at[
                 jnp.arange(x.shape[0])[:, None], experts].set(weights)
@@ -568,9 +606,9 @@ def grouped_cases():
                 hid = x @ up
                 if gated:
                     gate, lin = jnp.split(hid, 2, axis=-1)
-                    hid = jax.nn.silu(gate) * lin
+                    hid = act(gate) * lin
                 else:
-                    hid = moe.relu2(hid)
+                    hid = act(hid)
                 return want + mine * (hid @ down), None
 
             with jax.default_matmul_precision("highest"):
@@ -623,15 +661,21 @@ def class_cases(interpret: bool = False):
             n_blocks=shape["n_blocks"], table_len=shape["table_len"],
             dtype=jnp.bfloat16, **shape["geo"])
         reach = -(-max(q + p for q, p in shape["rows"]) // BLOCK_SIZE)
+        # A window layer's calls: the same reads with a lower bound.
+        window = shape.get("window")
+        if window is not None:
+            workload = functools.partial(workload, window=window)
 
-        def check(out, operands, reach=reach):
+        def check(out, operands, reach=reach, window=window):
             return pa.class_read_error(
-                out, operands[:4] + (operands[4][:, :reach],) + operands[5:])
+                out, operands[:4] + (operands[4][:, :reach],) + operands[5:],
+                window=window)
 
         yield KernelCase(
             name, functools.partial(pa.class_read, width=CHUNK,
                                     max_tokens=shape["max_tokens"],
-                                    interpret=interpret), workload, check)
+                                    interpret=interpret, window=window),
+            workload, check)
 
 
 # The cases the paged walk can get wrong (`pa.WALK_CASES`, `pa.WINDOW_CASES`:
@@ -733,7 +777,7 @@ def compile_for_topology(case: KernelCase, device):
     return jax.jit(case.kernel).lower(*shapes).compile()
 
 
-def main() -> int:
+def main(only=()) -> int:
     from tpu_engine.utils.checkpoint import enable_compilation_cache
 
     backend = jax.default_backend()
@@ -748,6 +792,8 @@ def main() -> int:
                 MODELS + LATENT_MODELS + RECURRENT_MODELS)),
             cell_cases(), class_cases(), walk_cases(), block_mask_cases(),
             grouped_cases()):
+        if only and not any(part in case.name for part in only):
+            continue
         t0 = time.monotonic()
         operands = case.operands()
         if case.check is None:
@@ -777,4 +823,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(tuple(sys.argv[1:])))

@@ -312,8 +312,11 @@ def routed_experts(x, valid, experts, weights, bank, *, first_group,
     the caller projected to: `y` comes back as wide); valid: (N,) bool,
     padding slots False; experts, weights: (N, k) from the router. The
     bank states its expert by its keys: {"gate_up" (G, d, 2f), "down"
-    (G, f, d)} a SwiGLU, or {"up" (G, d, f), "down" (G, f, d)} an ungated
-    expert of two matrices whose nonlinearity is `activation` (f32 -> f32).
+    (G, f, d)} a gated expert, `activation(gate) * up` with the gate the
+    first half of `gate_up` (default SiLU: a SwiGLU; `jax.nn.relu`: a
+    ReGLU), or {"up" (G, d, f), "down" (G, f, d)} an ungated expert of two
+    matrices with `activation` between them, which that bank must state.
+    `activation` is f32 -> f32 for either bank.
     G >= n_experts groups, this layer's expert e at group
     `first_group + e` (a traced scalar: the bank of every layer, whole).
     `held` = (first, count): the experts this shard holds (default all);
@@ -358,7 +361,7 @@ def routed_experts(x, valid, experts, weights, bank, *, first_group,
         hidden = grouped_dot(xs, first_matrix.astype(dtype), sizes)
         if gated:
             gate, up = jnp.split(hidden, 2, axis=-1)
-            hidden = jax.nn.silu(gate) * up
+            hidden = (activation or jax.nn.silu)(gate) * up
         else:
             hidden = activation(hidden)
         hidden = hidden.astype(dtype)

@@ -113,12 +113,19 @@ and calls the read twice: the SHORT rows (one new token) as
 (B, 1, H, D), a row a tile, the packed geometry a decode-only tick
 runs; every longer run in TALL tiles of 128 query rows a KV head or a
 few times that (`tall_slots`), a TILE a row of the call with its own
-first column, q_len and table row. Who calls which: the steps that run
-over the tick's tokens (`models.olmo_hybrid`, and `models.laguna` for
-its full layers; its window layers' tiles walk only their window and
-stay a list tile a row) call `ragged_read_by_class`; the uniform step
-(`models.transformer`, every slot of every row) calls a read path
-below once, a ROW a row of the call.
+first column, q_len and table row. Both calls take what the read path
+takes besides (`window`, `mask_block`). Who calls which: the steps that
+run over the tick's tokens call `ragged_read_by_class`, through
+`models.tick_tokens` `PagedKV.attend`: every layer that attends of
+`models.olmo_hybrid`, `falcon_h1`, `nemotron_h`, `lfm2`, `granite_hybrid`,
+`ouro` and `sdar`; `models.laguna` for its FULL layers alone (its window
+layers, at a window of 512, stay a list tile of 8 slots a row of ONE
+call: a tile walks ~520 columns); `models.smallthinker` for BOTH kinds
+of layer, the window kind's calls handed `window` (at a window of 4096 a
+list tile a row would walk the window once a tile of 8 slots, 32 times a
+chunk; a tall tile of 128 slots walks it twice). The uniform step
+(`models.transformer`, every slot of every row) calls a read path below
+once, a ROW a row of the call.
 
 An int8 pool's scales, (L, NB, bs, H_kv), cannot be fetched by the walk
 (Mosaic refuses a DMA whose minor dimension is H_kv): the call gathers
@@ -1055,6 +1062,10 @@ WINDOW_CASES = {
     # A window wider than a group of the walk (256 columns): two or three
     # groups are read, the first and the last partly.
     "window-wider-than-a-group": ((64, 1), (600, 650), 300, 48),
+    # A window of 700 columns: a chunk's tiles walk three or four groups
+    # of 256, a decode row's packed tile six of 128, each from a lower
+    # bound inside its first group; the table behind it is null.
+    "window-wider-than-several-groups": ((64, 1), (900, 1040), 700, 72),
     # Tiles of eight slots as rows of the call, as a step over the tick's
     # tokens hands them over: any pos0, a short last tile.
     "tiles-of-eight-slots": ((8, 8, 1, 5), (0, 37, 520, 100), 40, 48),
@@ -1082,13 +1093,15 @@ def window_parity_check(case: str, group: int, *, interpret=None,
 
 def class_read(q, k_pool, v_pool, layer, tables, pos0, qlen, *, width: int,
                max_tokens=None, attn_fn=None, interpret=None,
-               mask_block: int = 1):
+               mask_block: int = 1, window=None):
     """`ragged_read_by_class` over a token list laid out a slot a tile, as
     `models.olmo_hybrid` lays its tick out: q (M, H, D), the rows' new
     tokens side by side in row order (`class_workload`). With `mask_block`
     L > 1 the read is a block-decoding model's: the block mask, and runs of
-    up to L tokens the short class. Returns (M, H, D). What the parity
-    checks and `ops.kernel_check` run."""
+    up to L tokens the short class; with `window` a sliding-window
+    layer's, both classes' calls handed the lower bound
+    (`models.smallthinker`). Returns (M, H, D). What the parity checks and
+    `ops.kernel_check` run."""
     from tpu_engine.ops import latent_attention as la
 
     if attn_fn is None:
@@ -1097,6 +1110,8 @@ def class_read(q, k_pool, v_pool, layer, tables, pos0, qlen, *, width: int,
     plan = la.tile_plan(qlen, 1, q.shape[0])
     group = q.shape[1] * q.shape[2] // k_pool.shape[3]
     read = {"mask_block": mask_block} if mask_block > 1 else {}
+    if window is not None:
+        read["window"] = window
     return ragged_read_by_class(
         attn_fn, q, (k_pool, v_pool), layer, tables, pos0,
         la.class_plan(qlen, width, group, max_tokens, run_slots=mask_block),
@@ -1115,7 +1130,8 @@ def class_workload(q_lens, pos0, *, width: int, max_tokens=None, **shape):
     return (q[plan.row, jnp.minimum(plan.tile, q.shape[1] - 1)], *rest)
 
 
-def class_read_error(out, operands, mask_block: int = 1) -> float:
+def class_read_error(out, operands, mask_block: int = 1,
+                     window=None) -> float:
     """Max |out - reference| over the list entries that hold a token: the
     gather reference on the WHOLE batch, a row a row, in f32
     (`reference_error`)."""
@@ -1128,7 +1144,7 @@ def class_read_error(out, operands, mask_block: int = 1) -> float:
                          q.shape[0] - 1)
     return reference_error(
         functools.partial(ragged_paged_attention_reference,
-                          mask_block=mask_block),
+                          mask_block=mask_block, window=window),
         out[listed], (q[listed], *rest, qlen), qlen)
 
 
@@ -1174,6 +1190,37 @@ def class_parity_check(case: str, group: int, *, interpret=None,
     return class_read_error(
         class_read(*operands, width=256, max_tokens=max_tokens,
                    interpret=interpret), operands)
+
+
+# The two classes under a window, one tick each: name -> (q_lens, pos0,
+# window, max_tokens) in a step of 256 slots a row at block size 16 under a
+# table of 24 blocks, the table behind each row's window null as the
+# scheduler leaves it. Run at seven query heads a KV head (a group coprime
+# to the 128-row tile: tall tiles of 128 slots, seven tiles of the call's
+# grid whose edges a slot's heads straddle).
+WINDOW_CLASS_CASES = {
+    # A first chunk, two tall tiles: the window (100) opens inside the
+    # first. Beside it decode rows past their window (null blocks behind
+    # them), short of it and on its edge.
+    "a-chunk-the-window-opens-in-beside-decode-rows-around-the-edge": (
+        (256, 1, 1, 1), (0, 370, 30, 99), 100, None),
+}
+
+
+def window_class_parity_check(case: str, group: int = 7, *, interpret=None,
+                              dtype=jnp.float32, seed: int = 0) -> float:
+    """Max |short + tall reads - reference| with a lower bound over one of
+    `WINDOW_CLASS_CASES`, `group` query heads a KV head."""
+    q_lens, pos0, window, max_tokens = WINDOW_CLASS_CASES[case]
+    operands = class_workload(
+        q_lens, pos0, width=256, max_tokens=max_tokens, n_heads=2 * group,
+        n_kv_heads=2, d_head=16, block_size=16,
+        n_blocks=1 + len(q_lens) * 24, table_len=24, dtype=dtype, seed=seed,
+        window=window)
+    return class_read_error(
+        class_read(*operands, width=256, max_tokens=max_tokens,
+                   interpret=interpret, window=window), operands,
+        window=window)
 
 
 # What the block-causal mask can get wrong, one workload each: name ->

@@ -4523,8 +4523,10 @@ class ContinuousGenerator:
         null block, which the window read never walks. The tick's span
         says what the two kinds of layer read (`ctx_tokens_full`,
         `ctx_tokens_window`: the rooflines' bytes), in how many tiles of
-        each class the full layers read it (`_attn_tiles`) and what was
-        freed."""
+        each class the full layers read it (`_attn_tiles`), what was
+        freed, and how many of the rows it feeds (`rows_fed`) the window
+        binds (`rows_past_window`: their first new column is at or past
+        it)."""
         pool, window = self._wpool, self.cfg.window
         bs, width = pool.block_size, self._wtables.shape[1]
         freed = read = 0
@@ -4550,6 +4552,8 @@ class ContinuousGenerator:
         fed = qlen > 0
         self._clock.note(ctx_tokens_full=int((pos0[fed] + qlen[fed]).sum()),
                          ctx_tokens_window=read, window_blocks_freed=freed,
+                         rows_fed=int(fed.sum()),
+                         rows_past_window=int((pos0[fed] >= window).sum()),
                          **self._attn_tiles(qlen))
 
     def _attn_tiles(self, qlen, run_slots: int = 1) -> dict:
